@@ -17,3 +17,9 @@ import jax as _jax  # noqa: E402
 
 _jax.config.update("jax_enable_x64", True)
 
+# Every matmul in the query path multiplies sample values by 0/1 weights (band
+# windows, one-hot first/last-sample selects, group folds): it must be exact in
+# f32. XLA's default on the TPU runs an f32 dot as ONE bf16 pass — 8 bits of
+# mantissa. First seen on the chip (PR 22): a raw selector read back 92160 for a
+# stored 92181. The CPU backend is exact either way, so no CPU test could show it.
+_jax.config.update("jax_default_matmul_precision", "highest")

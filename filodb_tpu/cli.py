@@ -190,6 +190,8 @@ def _serve(args) -> int:
     from .http.api import FiloHttpServer
     from .query.engine import QueryEngine
 
+    from .utils import compilecache
+    compilecache.configure()            # before the first compile
     ms = TimeSeriesMemStore()
     sink = FileColumnStore(args.data_dir) if args.data_dir else None
     for shard in range(args.shards):

@@ -42,6 +42,38 @@ def _scatter_append(ts, val, n, rows, cols, new_ts, new_val, counts_add):
     return ts, val, n
 
 
+# A scatter into a multi-GB block does not run in place on the TPU: XLA
+# flattens the [S, C] operand to one dimension (a relayout copy in and out,
+# temp = the block), and every program that takes the s64 timestamp block
+# splits it into two u32 planes first (temp = the block again). Compiled for
+# a v5e at 2^20 x 768 the one-program flush above asks for 9 GB of temp
+# beside its 9 GB of donated arguments — "Ran out of memory in memory space
+# hbm. Used 18.03G of 15.75G". So from DENSE_APPEND_BYTES up, when each row
+# gains at most DENSE_APPEND_MAX_K samples (a scrape), the flush is a
+# per-row select instead: the new sample's column per row, -1 for none —
+# elementwise and donated, so the f32 block updates in place with no temp,
+# and the two blocks go through separate programs, so the s64 split (6 GB at
+# this shape) is the only temp alive at any moment.
+DENSE_APPEND_BYTES = 2 << 30
+DENSE_APPEND_MAX_K = 4
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _dense_set(block, col, new):
+    """``block[s, col[s]] = new[s]`` for every row with ``col[s] >= 0``."""
+    hit = jax.lax.broadcasted_iota(jnp.int32, block.shape[:2], 1) \
+        == col[:, None]
+    new = new[:, None]
+    if block.ndim == 3:         # histogram block [S, C, B], new [S, B]
+        hit = hit[:, :, None]
+    return jnp.where(hit, new.astype(block.dtype), block)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _add_counts(n, counts_add):
+    return n + counts_add
+
+
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
 def _scatter_append_multi(ts, val, extra, n, rows, cols, new_ts, new_val,
                           new_extra, counts_add):
@@ -390,7 +422,7 @@ class SeriesStore:
         self.stats = SeriesStoreStats()
         # backpressure: device mutations are dispatched asynchronously; an
         # unthrottled ingest loop would queue scatters faster than the device
-        # (or a tunneled link) retires them, building an unbounded backlog
+        # retires them, building an unbounded backlog
         # that every query fetch then waits behind — and eventually blocking
         # the dispatcher itself INSIDE the shard lock. Callers drain via
         # throttle() after releasing the lock.
@@ -760,6 +792,7 @@ class SeriesStore:
         if over.any():
             self.stats.capacity_dropped += int(over.sum())
             r, t, v, cols = r[~over], t[~over], v[~over], cols[~over]
+            occ = occ[~over]
         m = len(r)
         if m == 0:
             return 0
@@ -781,7 +814,10 @@ class SeriesStore:
         rp = np.full(P, self.S, np.int32); rp[:m] = r
         cp = np.zeros(P, np.int32); cp[:m] = cols
         tp = np.zeros(P, np.int64); tp[:m] = t
-        if self.layout is None:
+        if (self.layout is None and int(occ.max()) < DENSE_APPEND_MAX_K
+                and self.ts.nbytes + self.val.nbytes >= DENSE_APPEND_BYTES):
+            self._append_dense(r, cols, t, v, occ, counts)
+        elif self.layout is None:
             vp = np.zeros((P,) + v.shape[1:], v.dtype); vp[:m] = v
             self.ts, self.val, self.n = _scatter_append(
                 self.ts, self.val, self.n,
@@ -810,6 +846,21 @@ class SeriesStore:
         self.stats.samples_appended += m
         self._appends_since_sync += 1
         return m
+
+    def _append_dense(self, r, cols, t, v, occ, counts) -> None:
+        """The flush of a large store (see DENSE_APPEND_BYTES): one donated
+        per-row select per block and per in-batch occurrence, instead of
+        one scatter over both blocks."""
+        for k in range(int(occ.max()) + 1):
+            sel = occ == k
+            rk = r[sel]
+            col = np.full(self.S, -1, np.int32); col[rk] = cols[sel]
+            nt = np.zeros(self.S, np.int64); nt[rk] = t[sel]
+            nv = np.zeros((self.S,) + v.shape[1:], v.dtype); nv[rk] = v[sel]
+            col_d = jnp.asarray(col)
+            self.ts = _dense_set(self.ts, col_d, jnp.asarray(nt))
+            self.val = _dense_set(self.val, col_d, jnp.asarray(nv))
+        self.n = _add_counts(self.n, jnp.asarray(counts))
 
     def throttle(self) -> None:
         """Bound the in-flight device mutations (call OUTSIDE the shard

@@ -3,20 +3,25 @@
 ``NativePartSet`` is the ingest hot-path part-key table (ref:
 core/.../memstore/PartitionSet.scala — zero-alloc open-addressing probes
 against ingest records, under getOrAddPartitionAndIngest,
-TimeSeriesShard.scala:1183). The shard keeps a Python-dict fallback when the
-toolchain is unavailable (``available()`` False).
+TimeSeriesShard.scala:1183). The library is built from ``partset.cpp`` on
+first use (utils/nativebuild.py: keyed on source, flags and host CPU). The
+shard keeps a Python-dict fallback when the toolchain is unavailable
+(``available()`` False) — said once in the log, never in silence.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
-import subprocess
 
 import numpy as np
 
-_DIR = os.path.dirname(__file__)
-_LIB_PATH = os.path.join(_DIR, "libfilodb_partset.so")
+from ...utils import nativebuild
+
+log = logging.getLogger("filodb.native")
+
+_SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "partset.cpp")
 
 _lib = None
 _load_failed = False
@@ -26,20 +31,12 @@ def _load():
     global _lib, _load_failed
     if _lib is not None or _load_failed:
         return _lib
-    src = os.path.join(_DIR, "partset.cpp")
-    stale = (not os.path.exists(_LIB_PATH)
-             or os.path.getmtime(_LIB_PATH) < os.path.getmtime(src))
-    if stale:   # built per host (-march=native): never ship binaries
-        try:
-            subprocess.run(["sh", os.path.join(_DIR, "build.sh")], check=True,
-                           capture_output=True)
-        except Exception:
-            _load_failed = True   # no toolchain: don't re-fork per build()
-            return None
     try:
-        lib = ctypes.CDLL(_LIB_PATH)
-    except OSError:
-        _load_failed = True
+        lib = nativebuild.load(_SRC, "filodb_partset")
+    except nativebuild.NativeBuildError as e:
+        _load_failed = True       # no toolchain: don't re-fork per build()
+        log.warning("native partset unavailable, using the Python dict "
+                    "(slower ingest): %s", e)
         return None
     lib.ps_new.restype = ctypes.c_void_p
     lib.ps_new.argtypes = [ctypes.c_uint64]

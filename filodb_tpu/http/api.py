@@ -392,6 +392,9 @@ class FiloHttpServer:
                 # per-query resource accounting, aggregated across every
                 # participating shard and peer (reference QueryStats shape)
                 body["stats"] = res.stats.to_dict()
+                # the route this query took (QueryResult.exec_path), beside
+                # the counters it explains
+                body["stats"]["exec_path"] = res.exec_path
             h._send(200, body)
             return
 

@@ -2,20 +2,25 @@
 
 The numpy implementations in ``memory/nibblepack.py`` are the spec reference;
 these native functions are bit-identical and used on ingest/persistence hot
-paths. If the toolchain is unavailable the package degrades gracefully:
-``available`` is False and callers fall back to numpy.
+paths. The library is built from ``codecs.cpp`` on first use
+(utils/nativebuild.py: keyed on source, flags and host CPU). If the
+toolchain is unavailable ``available`` is False and callers fall back to
+numpy — said once in the log, never in silence.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
-import subprocess
 
 import numpy as np
 
-_DIR = os.path.dirname(__file__)
-_LIB_PATH = os.path.join(_DIR, "libfilodb_codecs.so")
+from ...utils import nativebuild
+
+log = logging.getLogger("filodb.native")
+
+_SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "codecs.cpp")
 
 _lib = None
 _load_failed = False
@@ -25,20 +30,12 @@ def _load():
     global _lib, _load_failed
     if _lib is not None or _load_failed:
         return _lib
-    src = os.path.join(_DIR, "codecs.cpp")
-    stale = (not os.path.exists(_LIB_PATH)
-             or os.path.getmtime(_LIB_PATH) < os.path.getmtime(src))
-    if stale:   # built per host (-march=native): never ship binaries
-        try:
-            subprocess.run(["sh", os.path.join(_DIR, "build.sh")], check=True,
-                           capture_output=True)
-        except Exception:
-            _load_failed = True
-            return None
     try:
-        lib = ctypes.CDLL(_LIB_PATH)
-    except OSError:
+        lib = nativebuild.load(_SRC, "filodb_codecs")
+    except nativebuild.NativeBuildError as e:
         _load_failed = True
+        log.warning("native codecs unavailable, using the numpy twins "
+                    "(slower encode/decode): %s", e)
         return None
     lib.np_pack_u64.restype = ctypes.c_size_t
     lib.np_pack_u64.argtypes = [ctypes.c_void_p, ctypes.c_size_t, ctypes.c_void_p]

@@ -6,8 +6,9 @@ and the iterator chain decodes on access). This module is the TPU analog:
 every narrow-resident block format the fused kernels can stream is a
 registered :class:`DecodeVariant` naming BOTH backend decode twins — the
 ``pallas`` one the kernel body calls on its VMEM refs and the ``xla`` one
-the scan twin calls on its tile slices. Both are built from the same jnp
-expressions, so variant parity is by construction; filolint's
+the scan twin calls on its tile slices. Both compute the same exact
+integer arithmetic (where Mosaic lacks a primitive the Pallas twin spells
+it with what the chip has), so variant parity holds bit for bit; filolint's
 ``surface-decode-variant-twin`` rule makes one-sided additions (a variant
 registered with only one backend) fail tier-1.
 
@@ -17,8 +18,8 @@ Variants registered here:
   -------  -----------  ----------------  ---------------------------------
   raw      f32 [S,C]    —                 identity
   quant16  i16 [S,C]    vmin, scale       vmin + (q + 32768) * scale
-  delta16  i16 [S,C]    anchor            anchor + cumsum(dv)  (full cols)
-  delta8   i8  [S,C]    anchor            anchor + cumsum(dv)  (full cols)
+  delta16  i16 [S,C]    anchor            anchor + cumsum(dv)  (full cols;
+  delta8   i8  [S,C]    anchor              Pallas: prefix sum on the MXU)
   hist16   i16 [S,C,B]  first_d           dd -> f32 (cumsums in tile math)
   hist8    i8  [S,C,B]  first_d           dd -> f32 (cumsums in tile math)
 
@@ -34,6 +35,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable
 
+import jax
 import jax.numpy as jnp
 
 
@@ -117,11 +119,58 @@ def decode_delta(dv, anchor):
     return anchor + jnp.cumsum(dv.astype(jnp.float32), axis=1)
 
 
+_LANE_BLOCK = 128
+
+
+def _cumsum_lanes_mxu(x):
+    """Prefix sum along the minor (lane) axis of ``x [Sb, C]`` — bf16
+    holding small exact integers — as 128-wide triangular matmuls on the
+    MXU plus a running per-row carry. Mosaic has no cumsum primitive
+    (the TPU compiler's words: "Unimplemented primitive in Pallas TPU
+    lowering ... cumsum"). bf16 operands x 0/1 weights with f32
+    accumulation are exact at ANY matmul precision, so the result equals
+    jnp.cumsum's bit for bit while every prefix stays below 2^24."""
+    Sb, C = x.shape
+    lb = min(_LANE_BLOCK, C)
+    r = jax.lax.broadcasted_iota(jnp.int32, (lb, lb), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (lb, lb), 1)
+    tri = (r <= c).astype(jnp.bfloat16)
+    outs, carry = [], None
+    for k0 in range(0, C, lb):
+        w = min(lb, C - k0)
+        # DEFAULT, spelled out: the package-wide "highest" would ask Mosaic
+        # for an fp32 contraction of bf16 operands ("Bad lhs type")
+        blk = jnp.dot(x[:, k0:k0 + w], tri[:w, :w],
+                      precision=jax.lax.Precision.DEFAULT,
+                      preferred_element_type=jnp.float32)
+        if carry is not None:
+            blk = blk + carry
+        carry = blk[:, w - 1:w]
+        outs.append(blk)
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+
+
+def decode_delta_mxu(dv, anchor):
+    """The Pallas twin of :func:`decode_delta`: the same value sequence
+    with the prefix sum on the MXU. i8 deltas are exact in bf16 as they
+    are; i16 deltas split into a signed high byte and an unsigned low byte
+    (both exact in bf16), summed separately and recombined — all integer
+    arithmetic below 2^24, so the twins agree bit for bit."""
+    d = dv.astype(jnp.int32)
+    if dv.dtype == jnp.int8:
+        pre = _cumsum_lanes_mxu(d.astype(jnp.float32).astype(jnp.bfloat16))
+    else:
+        hi = (d >> 8).astype(jnp.float32).astype(jnp.bfloat16)
+        lo = (d & 0xFF).astype(jnp.float32).astype(jnp.bfloat16)
+        pre = _cumsum_lanes_mxu(hi) * 256.0 + _cumsum_lanes_mxu(lo)
+    return anchor + pre
+
+
 def decode_hist(dd, first_d):
-    """Hist 2D-delta widen: the tile math (hist_tile_contrib) consumes the
-    narrow dd frames directly — its band matmuls and bucket cumsums ARE the
-    decode — so the per-tile step is just the i8/i16 -> f32 cast. first_d
-    rides as a row operand into the same tile math."""
+    """Hist 2D-delta widen: the per-series math (hist_series_contrib)
+    consumes the narrow dd frames directly — its band matmuls and bucket
+    cumsums ARE the decode — so the decode step is just the i8/i16 -> f32
+    cast. first_d rides as a row operand into the same math."""
     return dd.astype(jnp.float32)
 
 
@@ -131,10 +180,10 @@ register_variant("raw", pallas=decode_raw, xla=decode_raw,
 register_variant("quant16", pallas=decode_quant16, xla=decode_quant16,
                  row_operands=2, block_dtype="int16",
                  full_columns=False, value_bytes=2)
-register_variant("delta16", pallas=decode_delta, xla=decode_delta,
+register_variant("delta16", pallas=decode_delta_mxu, xla=decode_delta,
                  row_operands=1, block_dtype="int16",
                  full_columns=True, value_bytes=2)
-register_variant("delta8", pallas=decode_delta, xla=decode_delta,
+register_variant("delta8", pallas=decode_delta_mxu, xla=decode_delta,
                  row_operands=1, block_dtype="int8",
                  full_columns=True, value_bytes=1)
 register_variant("hist16", pallas=decode_hist, xla=decode_hist,

@@ -17,7 +17,7 @@ same tiling plan and selected at plan time by ``query.fused_kernels``:
   rate_sum        sum/avg/...(rate|increase|delta)    fusedgrid.tile_contrib
   window_reduce   sum/...(avg_over_time|sum_over_time fusedgrid.tile_contrib
                   |count_over_time)
-  hist_quantile   histogram_quantile(q, sum(fn(h[w])) hist_tile_contrib
+  hist_quantile   histogram_quantile(q, sum(fn(h[w])) hist_series_contrib
                   over i8/i16 2D-delta-resident blocks  (this module)
 
 Backends per shape:
@@ -61,7 +61,7 @@ MODES = ("off", "xla", "pallas")
 _mode: str = "pallas"
 
 HIST_FUSED_FNS = frozenset({"rate", "increase", "delta"})
-MAX_BUCKETS = 64    # [Sb, C, B] tile + [G, Tp*B] accumulators stay in VMEM
+MAX_BUCKETS = 64    # [Sb, B, C] tile + [G, B, Tp] accumulators stay in VMEM
 
 # the declarative registry: shape name -> (window fns, reduce ops) it serves.
 # exec.py / engine.py consult it for plan-time eligibility; the bench suite
@@ -88,6 +88,13 @@ def set_mode(m: str) -> None:
         raise ValueError(f"query.fused_kernels must be one of {MODES}, "
                          f"got {m!r}")
     _mode = m
+
+
+def tag() -> str:
+    """The active mode as exec paths and plan-cache keys name it: "xla",
+    "pallas" (compiled by Mosaic) or "pallas-interpret" — see
+    fusedgrid.kernel_tag."""
+    return fusedgrid.kernel_tag(_mode)
 
 
 def scalar_shape_of(fn: str) -> str | None:
@@ -140,179 +147,228 @@ def scalar_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
 _roundup = fusedgrid._roundup
 
 
+# accumulators are [G, B, Tp] f32 in VMEM, twice (sum, count): the gate
+# keeps G * Tp * B * 4 bytes per accumulator at or below 2 MiB
+MAX_HIST_ACC_CELLS = 1 << 19
+
+
+def hist_rows_per_tile(S: int) -> int:
+    """Series per grid step of the hist kernel. One series' [B, C] dd frame
+    is 48 KiB at 64 x 768 i8 (96 KiB at i16), so 16 of them double-buffered
+    stay under 3 MiB; the old 512-row [Sb, C, B] tile was 24 MiB for one
+    buffer before lane padding, and never met a TPU."""
+    return 16 if S % 16 == 0 else 8
+
+
 def hist_fusable(S: int, C: int, T: int, B: int, num_groups: int) -> bool:
-    """Shape gate: per-tile operands + [G, Tp*B] accumulators stay in VMEM.
+    """Shape gate: per-tile operands + [G, B, Tp] accumulators stay in VMEM.
     Unlike the scalar tier there is no active-column slicing: the quantile's
     first-sample prefix bands need every column from cell 0."""
+    Tp = _roundup(max(T, 1), 128)
+    G = _roundup(max(num_groups, 8), 8)
     return (C <= fusedgrid.MAX_CAPACITY
-            and _roundup(max(T, 1), 128) * B <= fusedgrid.MAX_STEPS * 8
+            and Tp <= fusedgrid.MAX_STEPS
+            and G * Tp * B <= MAX_HIST_ACC_CELLS
             and num_groups <= fusedgrid.MAX_GROUPS
             and 0 < B <= MAX_BUCKETS
-            and (S % 512 == 0 or (S <= 512 and S % 8 == 0)))
+            and (S % 1024 == 0 or (S <= 512 and S % 8 == 0)))
 
 
-def hist_tile_contrib(fn: str, window_ms: int, interval_ms: int, B: int,
-                      ddf, first_d, n, band_open, prefix_lo, lo, hi, rel):
-    """Shared per-tile math of the hist_quantile shape: the decoded 2D-delta
-    tile ``ddf [Sb, Ca, B]`` (+ ``first_d [Sb, B]`` first-frame bucket
-    deltas, ``n [Sb, 1]`` valid counts) -> ``(contrib, okf)`` both
-    ``[Sb, Tp*B]`` flat in the aggregators layout (t*B + b). Both backends
-    call this — the Pallas body on VMEM refs, the XLA twin inside its scan.
+def hist_series_contrib(fn: str, window_ms: int, interval_ms: int,
+                        x, fd_row, n, band_open, prefix_lo, lo, hi, rel):
+    """Shared per-series math of the hist_quantile shape: one series'
+    decoded 2D-delta frames ``x [B, C]`` — buckets on sublanes, cells on
+    lanes — (+ ``fd_row [1, B]`` first-frame bucket deltas, ``n`` its valid
+    count, an i32 scalar) -> ``(contrib, okb)`` both ``[B, Tp]``. Steps ride
+    the lanes, so the per-step edge vectors stay ``[1, Tp]`` rows and
+    nothing is transposed or reshaped across the lane axis in the kernel
+    (Mosaic lowers neither for a 64-wide minor dimension). Both backends
+    call this — the Pallas body per series of its VMEM tile, the XLA twin
+    inside its scan.
 
     The bucket-cumsum commutation (ops/gridfns.py narrow-hist notes): the
     window delta of cumulative buckets equals ``cumsum_b(dd @ band_open)``
-    and the first-sample value ``F + cumsum_b(dd @ prefix_lo)`` — every
-    reduction is LINEAR in the frames, so the per-tile matmuls read the
-    NARROW dd encoding directly and the per-(series, step) extrapolation
-    algebra is identical to _grid_hist_kernel_narrow elementwise."""
+    and the first-sample value ``cumsum_b(first_d + dd @ prefix_lo)`` —
+    every reduction is LINEAR in the frames, so the matmuls read the NARROW
+    dd encoding directly. The bucket cumsum is a lower-triangular matmul
+    (Mosaic has no cumsum primitive); every operand of these four matmuls
+    is an integer below 2^24 times a 0/1 weight, so at HIGHEST precision
+    they are exact in any accumulation order. The per-(series, step)
+    extrapolation algebra is _grid_hist_kernel_narrow's, elementwise."""
     f32 = jnp.float32
-    Sb, Ca, _B = ddf.shape
-    Tp = band_open.shape[1]
-    flat = ddf.transpose(0, 2, 1).reshape(Sb * B, Ca)         # [Sb*B, Ca]
-    delta = jnp.cumsum(
-        jnp.dot(flat, band_open, preferred_element_type=f32)
-        .reshape(Sb, B, Tp), axis=1)                          # [Sb, B, Tp]
-    F = jnp.cumsum(first_d, axis=1)                           # [Sb, B]
-    f_v = F[:, :, None] + jnp.cumsum(
-        jnp.dot(flat, prefix_lo, preferred_element_type=f32)
-        .reshape(Sb, B, Tp), axis=1)
+    hp = jax.lax.Precision.HIGHEST
+    B = x.shape[0]
+    db = jnp.dot(x, band_open, precision=hp,
+                 preferred_element_type=f32)                  # [B, Tp]
+    fb = jnp.dot(x, prefix_lo, precision=hp, preferred_element_type=f32)
+    r = jax.lax.broadcasted_iota(jnp.int32, (B, B), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (B, B), 1)
+    tri = (c <= r).astype(f32)                                # cumsum over b
+    # the row of first-frame deltas as a column, without a transpose: mask
+    # its sublane broadcast with the identity and reduce along lanes
+    fd_col = jnp.sum(jnp.where(r == c, jnp.broadcast_to(fd_row, (B, B)), 0.0),
+                     axis=1, keepdims=True)                   # [B, 1]
+    delta = jnp.dot(tri, db, precision=hp, preferred_element_type=f32)
+    f_v = jnp.dot(tri, fd_col + fb, precision=hp,
+                  preferred_element_type=f32)
 
-    last_cell = n - 1                                         # [Sb, 1]
+    last_cell = n - 1                                         # scalar
     f_idx = jnp.maximum(lo, 0)                                # [1, Tp]
-    l_idx = jnp.minimum(hi, last_cell)                        # [Sb, Tp]
+    l_idx = jnp.minimum(hi, last_cell)
     cnt = jnp.maximum(l_idx - f_idx + 1, 0)
     cnt_f = cnt.astype(f32)
     relf = rel.astype(f32)
     f_rel = (f_idx * interval_ms).astype(f32)
     l_rel = (l_idx * interval_ms).astype(f32)
-    dur_start = (f_rel - (relf - window_ms)) / 1000.0         # [Sb, Tp]
+    dur_start = (f_rel - (relf - window_ms)) / 1000.0         # [1, Tp]
     dur_end = (relf - l_rel) / 1000.0
     sampled = (l_rel - f_rel) / 1000.0
     avg_dur = sampled / (cnt_f - 1.0)
     thresh = avg_dur * 1.1
     if fn != "delta":
         # per-bucket counter zero-clamp — same expressions as the composed
-        # narrow kernel (_grid_hist_kernel_narrow), per tile
-        dur_zero = jnp.where(delta > 0,
-                             sampled[:, None, :] * (f_v / delta), jnp.inf)
-        ds = jnp.broadcast_to(dur_start[:, None, :], delta.shape)
+        # narrow kernel (_grid_hist_kernel_narrow)
+        dur_zero = jnp.where(delta > 0, sampled * (f_v / delta), jnp.inf)
+        ds = jnp.broadcast_to(dur_start, delta.shape)
         ds = jnp.where((delta > 0) & (f_v >= 0) & (dur_zero < ds),
                        dur_zero, ds)
-        extrap = sampled[:, None, :] \
-            + jnp.where(ds < thresh[:, None, :], ds,
-                        avg_dur[:, None, :] / 2) \
-            + jnp.where(dur_end[:, None, :] < thresh[:, None, :],
-                        dur_end[:, None, :], avg_dur[:, None, :] / 2)
-        factor = extrap / sampled[:, None, :]
+        extrap = sampled \
+            + jnp.where(ds < thresh, ds, avg_dur / 2) \
+            + jnp.where(dur_end < thresh, dur_end, avg_dur / 2)
+        factor = extrap / sampled                             # [B, Tp]
     else:
         extrap = sampled \
             + jnp.where(dur_start < thresh, dur_start, avg_dur / 2) \
             + jnp.where(dur_end < thresh, dur_end, avg_dur / 2)
-        factor = (extrap / sampled)[:, None, :]
+        factor = extrap / sampled                             # [1, Tp]
     scaled = delta * factor
     if fn == "rate":
         scaled = scaled * (1000.0 / window_ms)
 
-    ok = cnt >= 2                                             # [Sb, Tp]
-    contrib = jnp.where(ok[:, None, :], scaled, 0.0)          # [Sb, B, Tp]
-    okb = jnp.broadcast_to(ok[:, None, :], contrib.shape).astype(f32)
-    # aggregators layout: [G, T*B] with flat index t*B + b
-    return (contrib.transpose(0, 2, 1).reshape(Sb, Tp * B),
-            okb.transpose(0, 2, 1).reshape(Sb, Tp * B))
-
-
-def _hist_fold(Sb: int, G: int, gid, contrib, okf):
-    """Per-group fold of one tile's flat [Sb, Tp*B] contributions on the
-    MXU — identical in both backends."""
-    f32 = jnp.float32
-    gcol = jax.lax.broadcasted_iota(jnp.int32, (Sb, G), 1)
-    oh = (gcol == gid).astype(f32)
-    dn = (((0,), (0,)), ((), ()))
-    return (jax.lax.dot_general(oh, contrib, dn, preferred_element_type=f32),
-            jax.lax.dot_general(oh, okf, dn, preferred_element_type=f32))
+    ok = cnt >= 2                                             # [1, Tp]
+    contrib = jnp.where(ok, scaled, 0.0)                      # [B, Tp]
+    okb = jnp.broadcast_to(ok, contrib.shape).astype(f32)
+    return contrib, okb
 
 
 def _hist_kernel_body(fn: str, window_ms: int, interval_ms: int, Sb: int,
-                      Ca: int, Tp: int, B: int, G: int,
-                      dd_ref, fd_ref, n_ref, gid_ref, band_ref, plo_ref,
-                      lo_ref, hi_ref, rel_ref, sum_ref, cnt_ref):
-    i = pl.program_id(0)
-    # i8/i16 decode in VMEM via the registered hist twin (ops/decodereg.py)
-    ddf = decodereg.decode_hist(dd_ref[:], fd_ref[:])
-    contrib, okf = hist_tile_contrib(fn, window_ms, interval_ms, B,
-                                     ddf, fd_ref[:], n_ref[:], band_ref[:],
-                                     plo_ref[:], lo_ref[:], hi_ref[:],
-                                     rel_ref[:])
-    psum, pcnt = _hist_fold(Sb, G, gid_ref[:], contrib, okf)
-
-    @pl.when(i == 0)
+                      per: int, G: int, n_ref, gid_ref, dd_ref, fd_ref,
+                      band_ref, plo_ref, lo_ref, hi_ref, rel_ref, sum_ref,
+                      cnt_ref):
+    """One grid step = ``Sb`` series: n/gid are SMEM scalars, so a series
+    folds into its group's [B, Tp] accumulator by a dynamic first-axis
+    index — the fold is sequential in series order, which the XLA twin
+    repeats add for add. One SMEM scalar block spans ``per`` consecutive
+    grid steps (XLA lays a rank-1 i32 array out in tiles of 1024, and Mosaic
+    wants the SMEM block to match it — or to be the whole array)."""
+    base = (pl.program_id(0) % per) * Sb
+    @pl.when(pl.program_id(0) == 0)
     def _():
         sum_ref[:] = jnp.zeros_like(sum_ref)
         cnt_ref[:] = jnp.zeros_like(cnt_ref)
 
-    sum_ref[:] += psum
-    cnt_ref[:] += pcnt
+    def one_series(s, carry):
+        n = n_ref[base + s]
+        g = gid_ref[base + s]
+
+        # empty slots and excluded rows (cohort pool: gid out of range)
+        # contribute nothing — skip their matmuls altogether
+        @pl.when((n > 0) & (g >= 0) & (g < G))
+        def _():
+            # i8/i16 decode in VMEM via the registered hist twin
+            x = decodereg.decode_hist(dd_ref[s], None)        # [B, C]
+            contrib, okb = hist_series_contrib(
+                fn, window_ms, interval_ms, x, fd_ref[pl.ds(s, 1), :], n,
+                band_ref[:], plo_ref[:], lo_ref[:], hi_ref[:], rel_ref[:])
+            sum_ref[g] += contrib
+            cnt_ref[g] += okb
+        return carry
+
+    jax.lax.fori_loop(0, Sb, one_series, 0)
+
+
+def _pad(x: int, m: int) -> int:
+    return _roundup(max(x, 1), m)
 
 
 @functools.lru_cache(maxsize=32)
 def build_hist_pallas(fn: str, window_ms: int, interval_ms: int, S: int,
                       Sb: int, C: int, Tp: int, B: int, G: int,
-                      interpret: bool):
+                      interpret: bool, dd_bytes: int = 1):
     """The raw (traceable) fused hist-quantile map-phase pallas_call: grid
-    over [Sb] row tiles of the [S, C, B] dd block, [G, Tp*B] partial-state
-    accumulators resident in VMEM across the sequential grid. The compiled
-    (non-interpret) path targets TPU with the lane-dim caveat documented in
-    COMPONENTS.md (B rides the minor axis of the tile; pad B to the lane
-    multiple on real hardware when Mosaic requires it)."""
+    over [Sb] series tiles of the dd block, [G, B, Tp] partial-state
+    accumulators resident in VMEM across the sequential grid. Operands: n
+    [S] i32 and gids [S] i32 (SMEM), dd as [S, B, C] — cells on the lane
+    axis, which is how the TPU already lays a resident [S, C, 64] block out
+    in HBM (minor-to-major {1,2,0}: asked for row-major [S, C, B] instead,
+    XLA transposes the whole store into a 2x lane-padded copy per query,
+    8.6 GB of temp at 2^16 x 768 x 64 i8) — first_d [S, B], bands [C, Tp],
+    edges [1, Tp]. The scoped-VMEM limit is stated explicitly from the tile
+    footprint (everything double-buffered) instead of leaning on the
+    16 MiB default."""
+    sblk = 1024 if S % 1024 == 0 else S
+    per = sblk // Sb
     body = functools.partial(_hist_kernel_body, fn, window_ms, interval_ms,
-                             Sb, C, Tp, B, G)
-    acc = pl.BlockSpec((G, Tp * B), lambda i: (0, 0), memory_space=pltpu.VMEM)
+                             Sb, per, G)
+    acc = pl.BlockSpec((G, B, Tp), lambda i: (0, 0, 0),
+                       memory_space=pltpu.VMEM)
     const = functools.partial(pl.BlockSpec, index_map=lambda i: (0, 0),
                               memory_space=pltpu.VMEM)
-    row = lambda shape: pl.BlockSpec(shape, lambda i: (i, 0),  # noqa: E731
-                                     memory_space=pltpu.VMEM)
+    scalars = pl.BlockSpec((sblk,), lambda i: (i // per,),
+                           memory_space=pltpu.SMEM)
     in_specs = [
-        pl.BlockSpec((Sb, C, B), lambda i: (i, 0, 0),
+        scalars, scalars,                                       # n, gid
+        pl.BlockSpec((Sb, B, C), lambda i: (i, 0, 0),
                      memory_space=pltpu.VMEM),                  # dd
-        row((Sb, B)),                                           # first_d
-        row((Sb, 1)), row((Sb, 1)),                             # n, gid
+        pl.BlockSpec((Sb, B), lambda i: (i, 0),
+                     memory_space=pltpu.VMEM),                  # first_d
         const((C, Tp)), const((C, Tp)),                         # bands
         const((1, Tp)), const((1, Tp)), const((1, Tp)),         # lo, hi, rel
     ]
+    Cp, Bp = _pad(C, 128), _pad(B, 32)
+    footprint = 2 * (Sb * Bp * Cp * dd_bytes                    # dd tile
+                     + _pad(Sb, 8) * _pad(B, 128) * 4           # first_d
+                     + 2 * _pad(C, 8) * Tp * 4                  # bands
+                     + 2 * G * Bp * Tp * 4)                     # accumulators
+    # + the per-series f32 working set (decoded frame, matmul results)
+    footprint += Bp * Cp * 4 + 12 * Bp * Tp * 4
     return pl.pallas_call(
         body,
         grid=(S // Sb,),
         in_specs=in_specs,
         out_specs=(acc, acc),
-        out_shape=tuple(jax.ShapeDtypeStruct((G, Tp * B), jnp.float32)
+        out_shape=tuple(jax.ShapeDtypeStruct((G, B, Tp), jnp.float32)
                         for _ in range(2)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=min(fusedgrid.VMEM_CAP,
+                                 max(32 << 20, 2 * footprint))),
         interpret=interpret,
     )
 
 
 def build_hist_xla_tiles(fn: str, window_ms: int, interval_ms: int, S: int,
                          Sb: int, C: int, Tp: int, B: int, G: int):
-    """XLA-fused twin of :func:`build_hist_pallas` from the same tiling
-    plan: lax.scan over the identical [Sb, C, B] tiles through the identical
-    hist_tile_contrib + fold; intermediates bounded by one tile."""
+    """XLA twin of :func:`build_hist_pallas`: one lax.scan walks the series
+    in the same order through the same hist_series_contrib and folds each
+    into its group's accumulator with the same adds; intermediates are
+    bounded by one series."""
     f32 = jnp.float32
-    nt = S // Sb
 
-    def call(dd, first_d, n2, g2, band, plo, lo, hi, rel):
-        tiles = (dd.reshape(nt, Sb, C, B), first_d.reshape(nt, Sb, B),
-                 n2.reshape(nt, Sb, 1), g2.reshape(nt, Sb, 1))
-
+    def call(n, gids, dd, first_d, band, plo, lo, hi, rel):
         def fold(carry, xs):
-            dd_t, fd_t, n_t, g_t = xs
-            contrib, okf = hist_tile_contrib(
-                fn, window_ms, interval_ms, B,
-                decodereg.decode_hist(dd_t, fd_t), fd_t, n_t,
-                band, plo, lo, hi, rel)
-            psum, pcnt = _hist_fold(Sb, G, g_t, contrib, okf)
-            return (carry[0] + psum, carry[1] + pcnt), None
+            n_s, g_s, dd_s, fd_s = xs
+            contrib, okb = hist_series_contrib(
+                fn, window_ms, interval_ms, decodereg.decode_hist(dd_s, None),
+                fd_s, n_s, band, plo, lo, hi, rel)
+            live = (n_s > 0) & (g_s >= 0) & (g_s < G)
+            gi = jnp.clip(g_s, 0, G - 1)
+            return (carry[0].at[gi].add(jnp.where(live, contrib, 0.0)),
+                    carry[1].at[gi].add(jnp.where(live, okb, 0.0))), None
 
-        init = (jnp.zeros((G, Tp * B), f32), jnp.zeros((G, Tp * B), f32))
-        outs, _ = jax.lax.scan(fold, init, tiles)
+        init = (jnp.zeros((G, B, Tp), f32), jnp.zeros((G, B, Tp), f32))
+        outs, _ = jax.lax.scan(fold, init,
+                               (n, gids, dd, first_d[:, None, :]))
         return outs
 
     return call
@@ -347,9 +403,10 @@ def _hist_device_operands(C: int, Tp: int, out_ts_key: bytes, window_ms: int,
 def _hist_map_program(variant: str, fn: str, window_ms: int, interval_ms: int,
                       S: int, Sb: int, C: int, Tp: int, B: int, G: int,
                       dd_dtype: str):
-    """The cached map-phase program (variant is part of the key: the two
-    backends are distinct compiled kernels). Wrapped so dtype casts and
-    [S] -> [S, 1] reshapes ride the one dispatch."""
+    """The cached map-phase program. ``variant`` is fusedgrid.kernel_tag's
+    name — "xla" | "pallas" | "pallas-interpret" — and part of the key: the
+    backends, and a compiled and an interpreted kernel, are distinct
+    programs. Wrapped so dtype casts and reshapes ride the one dispatch."""
     from ..query.plancache import plan_cache
 
     def build():
@@ -358,14 +415,15 @@ def _hist_map_program(variant: str, fn: str, window_ms: int, interval_ms: int,
                                         S, Sb, C, Tp, B, G)
         else:
             call = build_hist_pallas(fn, window_ms, interval_ms, S, Sb, C,
-                                     Tp, B, G,
-                                     jax.default_backend() != "tpu")
+                                     Tp, B, G, variant != "pallas",
+                                     jnp.dtype(dd_dtype).itemsize)
 
         def wrapped(dd, first_d, n, gids, band, plo, lo, hi, rel):
-            return call(dd, first_d,
-                        n.astype(jnp.int32).reshape(S, 1),
-                        gids.astype(jnp.int32).reshape(S, 1),
-                        band, plo, lo, hi, rel)
+            # [S, C, B] -> [S, B, C]: on the TPU a relabelling of the
+            # resident block's own layout, not a copy (build_hist_pallas)
+            return call(n.astype(jnp.int32), gids.astype(jnp.int32),
+                        dd.transpose(0, 2, 1), first_d, band, plo, lo, hi,
+                        rel)
         return wrapped
 
     return plan_cache.program(
@@ -384,8 +442,10 @@ def _hist_finish_program(G: int, T: int, Tp: int, B: int, has_corr: bool,
 
     def build():
         def fin(q, les, psum, pcnt, corr_sum, corr_cnt):
-            ps = psum.reshape(G, Tp, B)[:, :T, :].reshape(G, T * B)
-            pc = pcnt.reshape(G, Tp, B)[:, :T, :].reshape(G, T * B)
+            # kernel layout [G, B, Tp] -> aggregators layout [G, T*B]
+            # (flat index t*B + b); a few KiB, transposed here by XLA
+            ps = psum.transpose(0, 2, 1)[:, :T, :].reshape(G, T * B)
+            pc = pcnt.transpose(0, 2, 1)[:, :T, :].reshape(G, T * B)
             if has_corr:
                 ps = ps + corr_sum
                 pc = pc + corr_cnt
@@ -414,9 +474,10 @@ def fused_hist_quantile_resident(q: float, les, dd, first_d, n, gids,
     G = _roundup(max(num_groups, 8), 8)
     assert hist_fusable(S, C, T, B, G), (S, C, T, B, G)
     Tp = _roundup(max(T, 1), 128)
-    Sb = 512 if S % 512 == 0 else S
+    Sb = hist_rows_per_tile(S)
     variant = variant or _mode
     assert variant in ("xla", "pallas")
+    variant = fusedgrid.kernel_tag(variant)
 
     band, plo, lo_d, hi_d, rel_d = _hist_device_operands(
         C, Tp, np.ascontiguousarray(np.asarray(out_ts, np.int64)).tobytes(),
@@ -426,8 +487,7 @@ def fused_hist_quantile_resident(q: float, les, dd, first_d, n, gids,
     # x64 tracing injects i64 scalars Mosaic rejects (grid index maps); the
     # map phase is pure f32/i32 — trace it with x64 off, exactly like the
     # scalar fused tier. The f64 quantile finish traces under default x64.
-    from ..utils import enable_x64
-    with enable_x64(False):
+    with jax.enable_x64(False):
         psum, pcnt = prog(dd, first_d, jnp.asarray(n), jnp.asarray(gids),
                           band, plo, lo_d, hi_d, rel_d)
     if corr is None:

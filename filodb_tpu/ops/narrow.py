@@ -108,10 +108,23 @@ def build_narrow_hist(val, n):
     dd = jnp.diff(d, axis=1, prepend=0.0)          # 2D delta along time
     pair = (valid & (col > 0))[:, :, None]
     dd = jnp.where(pair, dd, 0.0)
-    # bit-exact round trip: integer components stay exact through both
-    # cumsums as long as every partial sum is f32-representable
-    v_rec = jnp.cumsum(first_d[:, None, :] + jnp.cumsum(dd, axis=1), axis=2)
+    # the block is STORED as integers, so the contract is decided on those:
+    # every dd and first-frame delta must be integer-valued and every value
+    # within 2^23 (the bound the scalar delta form puts on its prefixes).
+    # Then every partial sum of either cumsum is an exactly representable
+    # integer, and the round trip below holds in ANY association order — the
+    # kernels' band matmuls and another backend's cumsum included. (A round
+    # trip of the unrounded f32 dd alone passes for fractional rows whenever
+    # one backend's cumsum happens to undo its own diff, and the integer
+    # cast then truncates them.)
+    dd_q = jnp.round(dd)
+    integral = (jnp.all(dd == dd_q, axis=1)
+                & (first_d == jnp.round(first_d))
+                & jnp.all(jnp.abs(v) <= 8388608.0, axis=1))        # [S, B]
+    v_rec = jnp.cumsum(first_d[:, None, :] + jnp.cumsum(dd_q, axis=1), axis=2)
     exact = jnp.where(valid[:, :, None], v_rec == v, True)
+    exact &= integral[:, None, :]
+    dd = dd_q
     # counter-reset detection: any negative per-step bucket increment
     # (inc = cumsum_b dd) disqualifies the row — see contract above
     inc = jnp.cumsum(dd, axis=2)

@@ -461,6 +461,17 @@ class QueryEngine:
         if ctx is not None:
             ctx.exec_path = path
 
+    def _note_fused(self, ctx: QueryContext, base: str) -> None:
+        """Name the fused-tier programs a local plan's leaves ran in the
+        exec path (see QueryContext.kernels); a plan that ran none keeps
+        the bare ``base``."""
+        if not ctx.kernels:
+            return
+        kinds = sorted({k for k, _ in ctx.kernels} - {"raw"})
+        tags = sorted({t for _, t in ctx.kernels})
+        self._set_path(ctx, f"{base}-fused{'-narrow' if kinds else ''}"
+                            f"[{','.join(kinds + tags)}]")
+
     def query_range(self, promql_text: str, start_ms: int, end_ms: int,
                     step_ms: int, tenant: str | None = None,
                     resolution: str | None = None,
@@ -952,7 +963,9 @@ class QueryEngine:
         with span(SPAN_QUERY_PLAN), ctx.stats.stage("plan"):
             exec_plan = self.planner.materialize(plan)
         try:
-            return exec_plan.run(ctx)
+            res = exec_plan.run(ctx)
+            self._note_fused(ctx, "local")
+            return res
         except Exception as e:
             from .wire import RemoteLeafExec, RemotePeerError
             if not isinstance(e, RemotePeerError) or self.cluster is None:
@@ -977,7 +990,9 @@ class QueryEngine:
             # response stats stay cluster-total, not attempt-total
             ctx.stats.reset_counters()
             try:
-                return retry.run(ctx)
+                res = retry.run(ctx)
+                self._note_fused(ctx, "local-replanned")
+                return res
             except QueryError as e2:
                 # e.g. the reassigned shard's takeover recovery still lags
                 # the map update: name both failures, stay retryable
@@ -1105,7 +1120,7 @@ class QueryEngine:
                         q, np.asarray(data.bucket_les, np.float64), dd,
                         first_d, data.n, gids, Gp, out_eval, window, fn,
                         base_ts, interval_ms, corr=corr)
-                    path = f"fused-hist-narrow[{fusedresident.mode()}]"
+                    path = f"fused-hist-narrow[{fusedresident.tag()}]"
                     ctx.stats.add("fused_kernels")
                     fusedresident.count_served("hist_quantile")
                 else:

@@ -149,6 +149,13 @@ class CompiledPlanCache:
         with self._lock:
             return len(self._entries)
 
+    def keys(self) -> list[tuple]:
+        """The cached programs' full keys ``(kernel, *key)`` — fused-tier
+        keys carry fusedgrid.kernel_tag's variant name, so what is compiled
+        and what is interpreted can be read off the cache."""
+        with self._lock:
+            return list(self._entries)
+
     def stats(self) -> dict:
         with self._lock:
             return {"size": len(self._entries), "capacity": self.capacity,

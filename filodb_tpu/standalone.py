@@ -26,8 +26,7 @@ from .parallel.shardmapper import ShardMapper
 from .query.engine import QueryEngine
 from .query.rangevector import QueryError
 from .utils.metrics import (FILODB_INGEST_DECODE_ERRORS,
-                            FILODB_INGESTED_ROWS, FILODB_SWALLOWED_ERRORS,
-                            ShardHealthStats, registry)
+                            FILODB_INGESTED_ROWS, ShardHealthStats, registry)
 from .utils.tracing import SPAN_INGEST_CONSUME, span, tracer
 
 log = logging.getLogger("filodb_tpu.server")
@@ -320,16 +319,12 @@ class FiloServer:
     def _shard_device(self, shard_num: int):
         """Mesh placement: with multiple local devices, shard stores go
         round-robin so aggregate queries can execute via shard_map/psum
-        (the reference's per-shard data nodes; here devices ARE the nodes)."""
-        try:
-            import jax
-            devs = jax.devices()
-        except Exception:  # noqa: BLE001 — no usable backend: single-device
-            # placement is the correct fallback, but count the probe failure
-            # so a mis-provisioned multi-chip node is visible in /metrics
-            registry.counter(FILODB_SWALLOWED_ERRORS,
-                             {"site": "shard-device-probe"}).increment()
-            return None
+        (the reference's per-shard data nodes; here devices ARE the nodes).
+        A backend that cannot be probed fails the shard's start: placing
+        everything on one device instead would hide a mis-provisioned
+        multi-chip node behind a server that looks healthy."""
+        import jax
+        devs = jax.devices()
         return devs[shard_num % len(devs)] if len(devs) > 1 else None
 
     def _start_shard_claimed(self, dataset: str, shard_num: int) -> None:
@@ -716,6 +711,13 @@ class FiloServer:
 
     def start(self) -> "FiloServer":
         cfg = self.config
+        from .utils import compilecache
+        compilecache.configure()        # before the first compile
+        from .core import native as _partset
+        from .memory import native as _codecs
+        log.info("native libraries: partset=%s codecs=%s",
+                 "native" if _partset.available() else "python twin",
+                 "native" if _codecs.available() else "python twin")
         # unconditional: the flag is process-global, so a later server in the
         # same process must be able to turn it back off
         from .utils import diagnostics
@@ -845,18 +847,17 @@ class FiloServer:
         # shards spread round-robin over local devices (>= 1 per device) =>
         # PromQL aggregates run on the mesh (query/engine.py _try_mesh); any
         # other topology (peer-owned shards, indivisible counts) stays on the
-        # in-process / cross-node dispatch paths
+        # in-process / cross-node dispatch paths. A mesh that should exist
+        # and cannot be built is a start-up error, not a reason to carry on
+        # in-process
+        import jax
         mesh = None
-        try:
-            import jax
-            devs = jax.devices()
-            owned = self.manager.shards_of_node(dataset, self.node)
-            if (1 < num_shards == len(owned) and len(devs) > 1
-                    and num_shards % len(devs) == 0):
-                from .parallel.distributed import make_mesh
-                mesh = make_mesh(devs)
-        except Exception:
-            mesh = None
+        devs = jax.devices()
+        owned = self.manager.shards_of_node(dataset, self.node)
+        if (1 < num_shards == len(owned) and len(devs) > 1
+                and num_shards % len(devs) == 0):
+            from .parallel.distributed import make_mesh
+            mesh = make_mesh(devs)
         # cluster + endpoint resolver: leaves for peer-owned shards dispatch
         # over HTTP /exec (query/wire.py RemoteLeafExec) instead of erroring
         self.engines[dataset] = QueryEngine(

@@ -1,28 +1,1 @@
-"""Shared utilities (diagnostics, metrics, tracing) + small compat shims."""
-
-from __future__ import annotations
-
-
-def enable_x64(flag: bool):
-    """Version-portable ``jax.enable_x64`` context manager: newer jax removed
-    the top-level alias (kernels trace with x64 off because Mosaic rejects the
-    i64 scalars x64 tracing injects — see ops/fusedgrid.py)."""
-    import jax
-    cm = getattr(jax, "enable_x64", None)
-    if cm is not None:
-        return cm(flag)
-    from jax.experimental import enable_x64 as _cm
-    return _cm(flag)
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma=None):
-    """Version-portable ``jax.shard_map``: older jax ships it under
-    jax.experimental.shard_map with the replication check named check_rep."""
-    import jax
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+"""Shared utilities (diagnostics, metrics, tracing, native build, compile cache)."""

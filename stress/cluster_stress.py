@@ -10,6 +10,12 @@ node dies, the survivor takes over, and queries must keep answering (with at
 most a bounded takeover gap).
 
 Run: python stress/cluster_stress.py [seconds] [records_per_sec]
+
+One process, no children: both FiloServers, the broker, the producers and the
+query threads are threads of this process, which is the only one that touches
+JAX. It asks for two virtual host devices (XLA_FLAGS below) and is meant for
+the CPU; on a chip host run it with ``JAX_PLATFORMS=cpu`` — it starts nothing
+that could contend for the chip.
 """
 
 import os

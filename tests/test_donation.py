@@ -118,3 +118,42 @@ def test_staged_row_commit_donates_through_the_shard():
     assert all(h.is_deleted() for h in old)
     r = sh.store.series_snapshot(0)
     np.testing.assert_array_equal(r[1], np.arange(8, dtype=np.float32))
+
+
+@pytest.mark.parametrize("nbuckets", [0, 4])
+def test_dense_append_matches_the_scatter_and_donates(monkeypatch, nbuckets):
+    """Large stores flush through per-row selects (chunkstore
+    DENSE_APPEND_BYTES: the one-program scatter needs a store-sized temp on
+    the TPU). Same batches through both paths — ragged starts, two samples
+    per row in one batch, repeated (dropped) samples — must leave identical
+    stores, and the dense programs must donate too."""
+    from filodb_tpu.core import chunkstore
+    rng = np.random.default_rng(3)
+    batches = []
+    for step in range(5):
+        rows = np.sort(rng.choice(48, 30, replace=False)).astype(np.int32)
+        pid = np.concatenate([rows, rows[:7]])            # K = 2 for seven rows
+        ts = np.concatenate([np.full(30, BASE + 2 * step * IV),
+                             np.full(7, BASE + (2 * step + 1) * IV)])
+        if step:
+            pid[0], ts[0] = batches[-1][0][0], batches[-1][1][0]   # a repeat
+        shape = (len(pid), nbuckets) if nbuckets else (len(pid),)
+        batches.append((pid, ts.astype(np.int64),
+                        rng.integers(0, 1000, shape).astype(np.float32)))
+
+    def run(dense: bool):
+        monkeypatch.setattr(chunkstore, "DENSE_APPEND_BYTES",
+                            0 if dense else 1 << 60)
+        st = SeriesStore(64, 16, nbuckets=nbuckets)
+        for pid, ts, v in batches:
+            old = (st.ts, st.val, st.n)
+            st.append(pid, ts, v)
+            assert all(h.is_deleted() for h in old)
+        return st
+
+    a, b = run(False), run(True)
+    np.testing.assert_array_equal(np.asarray(a.ts), np.asarray(b.ts))
+    np.testing.assert_array_equal(np.asarray(a.val), np.asarray(b.val))
+    np.testing.assert_array_equal(np.asarray(a.n), np.asarray(b.n))
+    np.testing.assert_array_equal(a.n_host, b.n_host)
+    assert a.stats.out_of_order_dropped == b.stats.out_of_order_dropped >= 4

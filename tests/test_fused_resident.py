@@ -26,7 +26,7 @@ import pytest
 from filodb_tpu.core.memstore import StoreConfig, TimeSeriesMemStore
 from filodb_tpu.core.record import RecordBuilder
 from filodb_tpu.core.schemas import PROM_COUNTER, PROM_HISTOGRAM
-from filodb_tpu.ops import fusedresident
+from filodb_tpu.ops import fusedgrid, fusedresident
 from filodb_tpu.query.engine import QueryEngine
 
 START = 1_000_000
@@ -166,7 +166,7 @@ def test_hist_grid_all_modes_exact_vs_oracle(tier, bursty):
         for m in ("xla", "pallas"):
             with fused_mode(m):
                 r = _range(eng, q)
-            assert r.exec_path == f"fused-hist-narrow[{m}]", (q, r.exec_path)
+            assert r.exec_path == f"fused-hist-narrow[{fusedgrid.kernel_tag(m)}]", (q, r.exec_path)
             assert r.stats.fused_kernels >= 1
             res[m] = np.asarray(r.matrix.values)
         np.testing.assert_array_equal(res["xla"], res["pallas"], err_msg=q)
@@ -195,7 +195,7 @@ def test_hist_counter_reset_rows_fold_through_the_pool():
         for m in ("xla", "pallas"):
             with fused_mode(m):
                 r = _range(eng, q)
-            assert r.exec_path == f"fused-hist-narrow[{m}]"
+            assert r.exec_path == f"fused-hist-narrow[{fusedgrid.kernel_tag(m)}]"
             np.testing.assert_allclose(np.asarray(r.matrix.values), want,
                                        rtol=1e-5, atol=1e-6, equal_nan=True,
                                        err_msg=(q, m))

@@ -1,0 +1,144 @@
+"""The main path's kernels compiled for the chip, without the chip.
+
+The TPU's compiler is installed in the sandbox and compiles for a chip that is
+described, not attached (on-chip-measurement guide, section 2.3). Interpret
+mode — what every other test runs — cannot show what Mosaic refuses: this file
+found ``cumsum`` unimplemented in the Pallas TPU lowering (delta8/delta16
+decode, the hist kernel), the hist kernel's [Sb, C, 64] tile transposing the
+whole store into a 2x lane-padded copy per query, and a rank-1 SMEM block that
+must match XLA's 1024-wide tiling. Each case compiles one kernel at the shapes
+``chip_smoke.py`` serves (2^20 series, capacity 768/1024, 8..64 groups) with
+``interpret=False`` passed directly — code that asks ``jax.default_backend()``
+sees the CPU here — under ``enable_x64(False)`` like the call sites.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may hold libtpu, every xdist worker imports every test file,
+and all of these tests live in this one file so one worker gets them all.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+from filodb_tpu.ops import decodereg, fusedgrid, fusedresident
+from filodb_tpu.parallel import distributed
+
+S = 1 << 20
+WINDOW, IV = 300_000, 10_000
+f32, i32 = jnp.float32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-device compile can be written to the persistent cache but
+    # never read back: keep the cache off around these tests
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, args):
+    with jax.enable_x64(False):
+        compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _scalar_args(sh, C, Tp, residency):
+    var = decodereg.variant(residency)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)  # noqa: E731
+    return ([sds((S, C), var.block_dtype)]
+            + [sds((S, 1), f32)] * var.row_operands
+            + [sds((S, 1), i32), sds((S, 1), i32),
+               sds((C, Tp), f32), sds((C, Tp), f32),
+               sds((1, Tp), i32), sds((1, Tp), i32), sds((1, Tp), i32)])
+
+
+@pytest.mark.parametrize("fn,sumsq,C,Tp,G,residency", [
+    ("rate", False, 768, 128, 8, "raw"),            # sum(rate), sum by (g)
+    ("rate", False, 1024, 512, 64, "raw"),          # every cap at once
+    ("avg_over_time", False, 768, 128, 8, "raw"),   # window_reduce
+    ("sum_over_time", True, 768, 128, 8, "raw"),    # stddev: sumsq plane
+    ("rate", False, 768, 128, 8, "delta8"),
+    ("rate", False, 768, 128, 8, "quant16"),
+    ("rate", False, 768, 128, 8, "delta16"),
+])
+def test_scalar_kernel_compiles_for_v5e(one_chip, fn, sumsq, C, Tp, G,
+                                        residency):
+    call = fusedgrid.build_pallas(fn, sumsq, WINDOW, IV, S, 512, C, Tp, G,
+                                  False, residency, 0, 0)
+    _compile(call, _scalar_args(one_chip, C, Tp, residency))
+
+
+@pytest.mark.parametrize("dd_dtype", [jnp.int8, jnp.int16])
+def test_hist_kernel_compiles_for_v5e_without_copying_the_store(one_chip,
+                                                                dd_dtype):
+    """B = 64 buckets, 2^16 series x 768 cells (3.2 GB at i8). The resident
+    [S, C, B] block must reach the kernel as a relabelling of its own HBM
+    layout: no temp — the old row-major tile made XLA transpose and lane-pad
+    the whole store per query (8.6 GB of temp at i8, out of memory at i16)."""
+    Sh, C, Tp, B, G = 1 << 16, 768, 128, 64, 8
+    assert fusedresident.hist_fusable(Sh, C, Tp, B, G)
+    Sb = fusedresident.hist_rows_per_tile(Sh)
+    call = fusedresident.build_hist_pallas(
+        "rate", WINDOW, IV, Sh, Sb, C, Tp, B, G, False,
+        jnp.dtype(dd_dtype).itemsize)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    args = [sds((Sh,), i32), sds((Sh,), i32), sds((Sh, C, B), dd_dtype),
+            sds((Sh, B), f32), sds((C, Tp), f32), sds((C, Tp), f32),
+            sds((1, Tp), i32), sds((1, Tp), i32), sds((1, Tp), i32)]
+    # the wrapper's own spelling (fusedresident._hist_map_program)
+    compiled = _compile(
+        lambda n, g, dd, fd, *rest: call(n, g, dd.transpose(0, 2, 1), fd,
+                                         *rest), args)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < (64 << 20), mem
+
+
+def test_mesh_fused_program_compiles_for_four_chips(topo):
+    """One pjit ``dist_fused`` program on the described 2x2: four shards of
+    2^18 x 768 f32, one per device, explicit NamedShardings both ways, the
+    Mosaic kernel inside shard_map."""
+    per, C, Tp, G = 1 << 18, 768, 128, 8
+    mesh = Mesh(np.asarray(topo.devices), ("shard",))
+    nd = mesh.devices.size
+    assert nd == 4
+    impl = distributed._dist_fused_aggregate_impl
+    fn = lambda *a: impl("rate", "sum", G, mesh, WINDOW, IV, per, C, Tp,  # noqa: E731
+                         0, 0, "pallas", *a)
+    wrap = distributed._sharded_jit(mesh, distributed._FUSED_IN_SPECS,
+                                    P("shard"))
+    sh = NamedSharding(mesh, P("shard"))
+    rep = NamedSharding(mesh, P())
+    sds = jax.ShapeDtypeStruct
+    args = ((sds((nd, per, C), f32, sharding=sh),),
+            (sds((nd, per), i32, sharding=sh),),
+            (sds((nd, per), i32, sharding=sh),),
+            sds((C, Tp), f32, sharding=rep), sds((C, Tp), f32, sharding=rep),
+            sds((1, Tp), i32, sharding=rep), sds((1, Tp), i32, sharding=rep),
+            sds((1, Tp), i32, sharding=rep))
+    with jax.enable_x64(False):
+        compiled = wrap(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # each device holds its own shard and nothing of the others'
+    assert compiled.memory_analysis().argument_size_in_bytes \
+        < 1.05 * per * C * 4 + (8 << 20)
