@@ -1,0 +1,34 @@
+"""Bytes a fused window-aggregate query has to read, from its shapes.
+
+The kernel streams each selected store row once over the columns its
+windows touch: for fn(m[w]) at steps out_ts that is the first window's
+first cell to the last window's last cell. Per row: those columns of the
+f32 value block, plus the row's sample count and group id (i32 each). The
+band operands ([columns, steps] f32, twice) are read once per call. The
+program rounds the column range out to 128-column blocks
+(``ops/fusedgrid.active_columns``); that rounding is the kernel's own cost
+and is NOT counted as needed.
+"""
+
+from __future__ import annotations
+
+import numpy as np
+
+from . import reference
+
+
+def needed_columns(out_ts, window_ms: int, iv_ms: int, head_col: int,
+                   capacity: int) -> int:
+    lo, hi = reference.window_cells(out_ts, window_ms, iv_ms,
+                                    min(head_col, capacity - 1))
+    ok = hi >= lo
+    if not ok.any():
+        return 0
+    return int(hi[ok].max() - lo[ok].min() + 1)
+
+
+def query_bytes(rows: int, out_ts, window_ms: int, iv_ms: int, head_col: int,
+                capacity: int, value_bytes: int = 4) -> float:
+    cols = needed_columns(out_ts, window_ms, iv_ms, head_col, capacity)
+    steps = len(np.asarray(out_ts))
+    return float(rows * (cols * value_bytes + 8) + 2 * cols * steps * 4)
