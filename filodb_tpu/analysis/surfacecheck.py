@@ -37,10 +37,11 @@ surface, and these rules make drift impossible:
     their values interleave meaninglessly.
   * ``surface-metric-unused`` — a declared metric no code registers.
   * ``surface-trace-undeclared`` — every span name at a ``span(...)`` /
-    ``tracer.span(...)`` call site must be one of the declared ``SPAN_*``
-    constants in utils/tracing.py's ``TRACE_SPEC`` (a raw string literal
-    is flagged even when the name matches — the taxonomy has exactly one
-    spelling per span).
+    ``tracer.span(...)`` call site, and at a ``tracer.record(...)`` one (an
+    interval recorded after the fact), must be one of the declared
+    ``SPAN_*`` constants in utils/tracing.py's ``TRACE_SPEC`` (a raw string
+    literal is flagged even when the name matches — the taxonomy has
+    exactly one spelling per span).
   * ``surface-trace-unused`` — a declared span no code opens.
   * ``surface-cache-unbounded`` / ``surface-cache-no-eviction-metric`` —
     every class named ``*Cache`` must expose a capacity bound (a
@@ -523,13 +524,24 @@ class SurfaceChecker:
     @staticmethod
     def _is_span_call(node: ast.Call) -> bool:
         """A ``span(...)`` / ``<tracer>.span(...)`` call site with a
-        positional name argument (re.Match.span() and friends take none)."""
+        positional name argument (re.Match.span() and friends take none),
+        or a ``record(...)`` one: histograms and logs have a ``record``
+        too, so there the receiver must be spelled ``tracer`` or the name
+        a ``SPAN_*`` constant."""
         if not node.args:
             return False
         f = node.func
         if isinstance(f, ast.Name):
             return f.id == "span"
-        return isinstance(f, ast.Attribute) and f.attr == "span"
+        if not isinstance(f, ast.Attribute):
+            return False
+        if f.attr == "record":
+            def last(n):
+                return n.id if isinstance(n, ast.Name) else \
+                    getattr(n, "attr", "")
+            return last(f.value) == "tracer" or \
+                last(node.args[0]).startswith(SurfaceChecker.SPAN_CONST_PREFIX)
+        return f.attr == "span"
 
     def _check_traces(self) -> list[Finding]:
         meta = self._trace_constants()

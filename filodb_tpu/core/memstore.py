@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import logging
 import threading
+import time
 from collections import deque
 from dataclasses import dataclass, field
 
@@ -108,13 +109,13 @@ from .store import (INDEX_FLAG_UNPARSEABLE, INDEX_GENESIS_BUCKET,
                     INDEX_RETIRE_BUCKET, INDEX_TOMBSTONE_BUCKET,
                     ChunkSetRecord, ChunkSink, encode_index_bucket,
                     labels_from_blob)
-from ..utils.diagnostics import TimedRLock, assert_owned
+from ..utils.diagnostics import TimedRLock, assert_owned, lock_wait_ns
 from ..utils.metrics import (FILODB_INDEX_PERSISTED_BUCKETS,
                              FILODB_INDEX_RECOVER_MS,
                              FILODB_RETENTION_AGED_OUT_ROWS,
                              FILODB_RETENTION_ODP_ROWS,
                              FILODB_STORE_RESIDENCY_FALLBACK, registry)
-from ..utils.tracing import SPAN_ODP_DURABLE, span
+from ..utils.tracing import SPAN_INGEST_FLUSH, SPAN_ODP_DURABLE, span
 
 # _create_series_locked outcome distinct from "blocked, stage prefix first"
 # (None): the tenant's cardinality quota shed this NEW series — the caller
@@ -919,6 +920,18 @@ class TimeSeriesShard:
         Applies device backpressure OUTSIDE the lock (SeriesStore.throttle):
         a hot ingest loop must run at the device's retirement rate, or its
         dispatch backlog starves concurrent query fetches."""
+        if not self._staged:
+            # unlocked peek, like ingest()'s batch-size check: an idle tick
+            # opens no span (rows staged by another thread just now land
+            # untraced)
+            return self._flush({})
+        waited = lock_wait_ns()
+        with span(SPAN_INGEST_FLUSH, shard=self.shard_num) as tags:
+            tags["rows"] = written = self._flush(tags)
+            tags["lock_wait_ms"] = (lock_wait_ns() - waited) / 1e6
+        return written
+
+    def _flush(self, tags: dict) -> int:
         with self.lock:
             staged = bool(self._staged)
             written = self._flush_staged_locked() if staged else 0
@@ -930,7 +943,9 @@ class TimeSeriesShard:
             if residency != "off":
                 self._compress_resident_two_phase(residency)
             return 0
-        self.store.throttle()
+        t0 = time.perf_counter_ns()
+        self.store.throttle()   # the one wait of the write path for the device
+        tags["throttle_ms"] = (time.perf_counter_ns() - t0) / 1e6
         if self.config.narrow_mirror and residency == "off":
             # flush-time rebuild, outside the lock: the build streams the
             # whole store and fetches the ok flags — queries only CONSULT.

@@ -11,6 +11,7 @@ from __future__ import annotations
 import json
 import re
 import threading
+import time
 import traceback
 from contextlib import contextmanager
 from concurrent.futures import TimeoutError as FuturesTimeout
@@ -24,8 +25,10 @@ from ..promql.parser import ParseError
 from ..query.engine import QueryEngine, slow_query_log
 from ..query.rangevector import QueryError
 from ..query.scheduler import AdmissionRejected, Priority, SchedulerBusy
-from ..utils.tracing import (SPAN_QUERY_SERVE, SPAN_QUERY_SUBSCRIBE,
-                             SPAN_REMOTE_WRITE, span, tracer)
+from ..utils.tracing import (SPAN_HTTP_RENDER, SPAN_HTTP_REQUEST,
+                             SPAN_QUERY_QUEUE, SPAN_QUERY_SERVE,
+                             SPAN_QUERY_SUBSCRIBE, SPAN_REMOTE_WRITE, span,
+                             tracer)
 
 
 from ..query.rangevector import fmt_value as _fmt  # shared full-precision renderer
@@ -131,7 +134,8 @@ class FiloHttpServer:
             def log_message(self, fmt, *args):
                 pass
 
-            def _send(self, code: int, payload: dict, headers: dict | None = None):
+            def _send(self, code: int, payload: dict,
+                      headers: dict | None = None) -> int:
                 body = json.dumps(payload).encode()
                 self.send_response(code)
                 self.send_header("Content-Type", "application/json")
@@ -140,6 +144,7 @@ class FiloHttpServer:
                     self.send_header(k, v)
                 self.end_headers()
                 self.wfile.write(body)
+                return len(body)
 
             def do_GET(self):
                 from ..query.wire import PeerCircuitOpen
@@ -230,7 +235,9 @@ class FiloHttpServer:
         from dataclasses import asdict
 
         from ..utils.metrics import (FILODB_SHARD_LOCK_CONTENTIONS,
+                                     FILODB_SHARD_LOCK_HOLD_SECONDS,
                                      FILODB_SHARD_LOCK_LONG_HOLDS,
+                                     FILODB_SHARD_LOCK_WAIT_SECONDS,
                                      FILODB_SHARD_NUM_SERIES, registry)
         # snapshot: a downsample serving refresh adds family engines
         # concurrently (standalone ds_serve_loop)
@@ -248,6 +255,10 @@ class FiloHttpServer:
                         .update(float(s.lock.contentions))
                     registry.gauge(FILODB_SHARD_LOCK_LONG_HOLDS, tags) \
                         .update(float(s.lock.long_holds))
+                    registry.gauge(FILODB_SHARD_LOCK_WAIT_SECONDS, tags) \
+                        .update(s.lock.wait_s)
+                    registry.gauge(FILODB_SHARD_LOCK_HOLD_SECONDS, tags) \
+                        .update(s.lock.hold_s)
 
     @contextmanager
     def _leg_guard(self):
@@ -261,10 +272,20 @@ class FiloHttpServer:
             self._leg_sem.release()
 
     def _run(self, fn, priority: Priority):
-        """Run query work through the priority scheduler when configured."""
+        """Run query work through the priority scheduler when configured.
+        Under an active trace the work carries the handler thread's context
+        to its worker and records its wait in the heap as ``query.queue``."""
         if self.scheduler is None:
             return fn()
-        return self.scheduler.run(fn, priority)
+        if tracer.current_context() is None:
+            return self.scheduler.run(fn, priority)
+        t_enqueued = time.perf_counter_ns()
+
+        def scheduled():
+            tracer.record(SPAN_QUERY_QUEUE, t_enqueued,
+                          time.perf_counter_ns(), priority=priority.name)
+            return fn()
+        return self.scheduler.run(tracer.wrap(scheduled), priority)
 
     # -- routing -------------------------------------------------------------
 
@@ -372,30 +393,36 @@ class FiloHttpServer:
             # "1m" / ...) — validated by the engine against the configured
             # set (unknown values fail 422 with the available list)
             resolution = q.get("resolution") or None
-            if m.group(2) == "query_range":
-                res = self._run(
-                    lambda: engine.query_range(q["query"], _parse_time(q["start"]),
-                                               _parse_time(q["end"]),
-                                               _parse_step(q["step"]),
-                                               tenant=tenant,
-                                               resolution=resolution),
-                    Priority.QUERY)
-            else:
-                res = self._run(
-                    lambda: engine.query_instant(q["query"],
-                                                 _parse_time(q["time"]),
-                                                 tenant=tenant,
-                                                 resolution=resolution),
-                    Priority.QUERY)
-            body = {"status": "success", "data": matrix_to_prom_json(res)}
-            if res.stats is not None:
-                # per-query resource accounting, aggregated across every
-                # participating shard and peer (reference QueryStats shape)
-                body["stats"] = res.stats.to_dict()
-                # the route this query took (QueryResult.exec_path), beside
-                # the counters it explains
-                body["stats"]["exec_path"] = res.exec_path
-            h._send(200, body)
+            with span(SPAN_HTTP_REQUEST, route=m.group(2),
+                      status="error") as req:
+                if m.group(2) == "query_range":
+                    res = self._run(
+                        lambda: engine.query_range(
+                            q["query"], _parse_time(q["start"]),
+                            _parse_time(q["end"]), _parse_step(q["step"]),
+                            tenant=tenant, resolution=resolution),
+                        Priority.QUERY)
+                else:
+                    res = self._run(
+                        lambda: engine.query_instant(q["query"],
+                                                     _parse_time(q["time"]),
+                                                     tenant=tenant,
+                                                     resolution=resolution),
+                        Priority.QUERY)
+                with span(SPAN_HTTP_RENDER,
+                          series=res.matrix.num_series) as rendered:
+                    body = {"status": "success",
+                            "data": matrix_to_prom_json(res)}
+                    if res.stats is not None:
+                        # per-query resource accounting, aggregated across
+                        # every participating shard and peer (reference
+                        # QueryStats shape)
+                        body["stats"] = res.stats.to_dict()
+                        # the route this query took (QueryResult.exec_path),
+                        # beside the counters it explains
+                        body["stats"]["exec_path"] = res.exec_path
+                    rendered["bytes"] = h._send(200, body)
+                req.update(status=200, bytes=rendered["bytes"])
             return
 
         m = re.fullmatch(r"/promql/([^/]+)/api/v1/epochs", path)

@@ -35,6 +35,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils.tracing import SPAN_QUERY_KERNEL, span
 from . import decodereg, gridfns
 
 FUSED_FNS = {"rate", "increase", "delta"}
@@ -498,7 +499,9 @@ class PaddedPartials:
         return parts
 
     def resolve(self) -> dict:
-        return self.parts_of(jax.device_get(self._outs))
+        with span(SPAN_QUERY_KERNEL, phase="fetch"):
+            outs = jax.device_get(self._outs)
+        return self.parts_of(outs)
 
 
 def fused_grid_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
@@ -543,8 +546,12 @@ def fused_grid_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
                        S, Sb, C, Tp, G, kind, c0, Ck, kernel_tag(variant))
     # the framework runs with x64 on (int64 timestamps); Mosaic rejects the
     # i64 scalars x64 tracing injects (grid index maps, roll shifts), and the
-    # kernel itself is pure f32/i32 — so trace the call with x64 off
-    with jax.enable_x64(False):
+    # kernel itself is pure f32/i32 — so trace the call with x64 off.
+    # The span's tags are what ties a device event to its query and gives
+    # the bytes the kernel streams from inside (rows x cols from c0 on)
+    with span(SPAN_QUERY_KERNEL, phase="dispatch",
+              kernel=kernel_tag(variant), rows=S, c0=c0, cols=Ck, steps=T,
+              groups=num_groups), jax.enable_x64(False):
         if nops is not None:
             outs = call(*nops, jnp.asarray(n), jnp.asarray(gids),
                         band, ohlo, lo_d, hi_d, rel_d)

@@ -1,8 +1,8 @@
 """Grid fast path: range functions as static band matmuls on the MXU.
 
-Why: TPU microbenchmarks (scripts/profile_kernels.py) show per-row binary search
-and data-dependent [S, T] gathers are 20-2000x slower than streaming compares and
-matmuls. Prometheus-style series are scrape-interval regular, so the store tracks
+Why: TPU microbenchmarks showed per-row binary search and data-dependent
+[S, T] gathers 20-2000x slower than streaming compares and matmuls.
+Prometheus-style series are scrape-interval regular, so the store tracks
 a per-shard *grid* (base_ts, interval, uniform start): when every live series has
 sample k at timestamp base + k*interval, window edges are closed-form grid
 indices and window reductions become [S, C] x [C, T] matmuls with STATIC 0/1

@@ -722,13 +722,16 @@ class MeshQueryExecutor:
     grid-aligned to one common (base, interval) with a single uniform start
     cohort, and the shapes fit the fused kernel's VMEM gate, the per-shard
     map phase runs the single-pass fused Pallas kernel; otherwise the
-    general two-step kernels. ``last_path`` records the route taken and
-    ``last_mode`` the resolved mesh-program mode (pjit / shard_map)."""
+    general two-step kernels. ``last_path`` records the route taken,
+    ``last_mode`` the resolved mesh-program mode (pjit / shard_map) and
+    ``last_block`` the fused kernel's column block ``(c0, columns)``, None
+    on every other route."""
 
     def __init__(self, dstore: DistributedStore):
         self.dstore = dstore
         self.last_path: str | None = None
         self.last_mode: str = resolved_mesh_mode(dstore.mesh)
+        self.last_block: tuple[int, int] | None = None
 
     def _fused_grid(self):
         """Common (base_ts, interval_ms) when every shard qualifies for the
@@ -805,6 +808,7 @@ class MeshQueryExecutor:
             sfx = "-xla" if variant == "xla" else ""
             self.last_path = ("fused-narrow" if narrow is not None
                               else "fused") + sfx
+            self.last_block = (int(c0), int(Ck))
             res = LazyMeshResult(out, op, num_groups, T)
             return res.resolve() if fetch else res
         slot_tvn = tuple(self.dstore.arrays())

@@ -32,9 +32,12 @@ from ..utils.metrics import (FILODB_QUERY_LATENCY_MS,
                              FILODB_QUERY_RESULT_CACHE_MISSES,
                              FILODB_QUERY_SLOW, registry)
 from ..promql import parser as promql
+from ..utils.diagnostics import lock_wait_ns
 from ..utils.tracing import (SPAN_QUERY, SPAN_QUERY_ADMIT,
                              SPAN_QUERY_EXECUTE, SPAN_QUERY_FRAGMENT,
-                             SPAN_QUERY_PARSE, SPAN_QUERY_PLAN, span,
+                             SPAN_QUERY_GROUPIDS, SPAN_QUERY_KERNEL,
+                             SPAN_QUERY_LEAF, SPAN_QUERY_PARSE,
+                             SPAN_QUERY_PLAN, SPAN_QUERY_SELECT, span,
                              tracer)
 from . import logical as L
 from .exec import QueryContext, group_keys_of
@@ -524,16 +527,20 @@ class QueryEngine:
             promql_text,
             lambda: promql.query_to_logical_plan(promql_text, time_ms,
                                                  time_ms, 1),
-            tenant=tenant, min_window_ms=min_window_ms)
+            tenant=tenant, min_window_ms=min_window_ms,
+            instant_ms=int(time_ms))
         res.result_type = "vector"
         return res
 
     def _query_traced(self, promql_text: str, to_plan,
                       range_key: tuple | None = None,
                       tenant: str | None = None,
-                      min_window_ms: int | None = None) -> QueryResult:
-        """Shared query entry: ONE root span per query (every stage and
-        every participating node's spans hang off its trace id), the
+                      min_window_ms: int | None = None,
+                      instant_ms: int | None = None) -> QueryResult:
+        """Shared query entry: ONE ``query`` span per query (every stage and
+        every participating node's spans hang off its trace id; it carries
+        the range asked for, so a device event can be tied to its query,
+        and on close the route taken and the outcome), the
         end-to-end latency histogram (exemplar-tagged with that trace id),
         and the slow-query ring. Accounting runs in a FINALLY: the 30s
         query that then raises is exactly the one an operator opens the
@@ -551,12 +558,14 @@ class QueryEngine:
         invalidates the affected steps rather than racing them."""
         ctx = self._ctx()
         t0 = time.perf_counter_ns()
-        tctx = None
         err: BaseException | None = None
-        try:
-            with span(SPAN_QUERY, dataset=self.dataset,
-                      promql=promql_text[:200]):
-                tctx = tracer.current_context()
+        waited = lock_wait_ns()
+        start_ms, end_ms, step_ms = range_key or (instant_ms, instant_ms, 0)
+        with span(SPAN_QUERY, dataset=self.dataset, promql=promql_text[:200],
+                  start_ms=start_ms, end_ms=end_ms, step_ms=step_ms,
+                  tenant=tenant or "") as qtags:
+            tctx = tracer.current_context()
+            try:
                 neg_key = None
                 if range_key is not None and self.negative_cache is not None:
                     # probed FIRST: a negative hit needs no epoch scatter,
@@ -623,13 +632,19 @@ class QueryEngine:
                     # empty would mask them for the whole TTL
                     self.negative_cache.put(neg_key, range_key)
                 return res
-        except BaseException as e:
-            err = e                     # noted below, then re-raised
-            raise
-        finally:
-            self._note_query_done(promql_text, ctx,
-                                  (time.perf_counter_ns() - t0) / 1e6,
-                                  tctx, err)
+            except BaseException as e:
+                err = e                 # noted below, then re-raised
+                raise
+            finally:
+                qtags["exec_path"] = ctx.exec_path
+                qtags["status"] = ("ok" if err is None
+                                   else type(err).__name__)
+                # every wait of this thread for a shard lock: the leaf's
+                # (its own tag) and the epoch probe's before it
+                qtags["lock_wait_ms"] = (lock_wait_ns() - waited) / 1e6
+                self._note_query_done(promql_text, ctx,
+                                      (time.perf_counter_ns() - t0) / 1e6,
+                                      tctx, err)
 
     def _negative_hit(self, range_key: tuple,
                       ctx: QueryContext) -> QueryResult:
@@ -1232,17 +1247,25 @@ class QueryEngine:
         # in-process leaf) — and a flush's compress_commit landing between
         # an unlocked eligibility check and dispatch would swap the raw
         # blocks for compressed state mid-plan (the 500s VERDICT flagged)
+        waited = lock_wait_ns()
         with contextlib.ExitStack() as stack:
+            # the mesh route's one leaf: every shard's lock, taken in order
+            leaf = stack.enter_context(span(SPAN_QUERY_LEAF, shard="all",
+                                            route="mesh"))
             for sh in shards:
                 stack.enter_context(sh.lock)
+            leaf["lock_wait_ms"] = (lock_wait_ns() - waited) / 1e6
             ex = self._mesh_executor(shards)
             if ex is None:
                 return None      # residency/shape changed: host path
             matched_total = 0    # committed to ctx.stats only when the mesh
             for sh in shards:    # path actually serves (a later fallback to
                 # the host path must not double-count its own leaf counts)
-                pids = sh.part_ids_from_filters(filters, from_ms, to_ms)
-                if sh.needs_paging(pids, from_ms):
+                with span(SPAN_QUERY_SELECT, shard=sh.shard_num) as sel:
+                    pids = sh.part_ids_from_filters(filters, from_ms, to_ms)
+                    sel["series"] = len(pids)
+                    paging = sh.needs_paging(pids, from_ms)
+                if paging:
                     # cold data: host ODP path handles it
                     distributed.count_mesh_fallback("paging")
                     return None
@@ -1253,10 +1276,13 @@ class QueryEngine:
                         g[pids] = 0
                         uniq.setdefault(RangeVectorKey(()), 0)
                     else:
-                        keys = [sh.rv_key_of(int(p)) for p in pids]
-                        for p, gk in zip(pids, group_keys_of(keys, plan.by,
-                                                             plan.without)):
-                            g[p] = uniq.setdefault(gk, len(uniq))
+                        with span(SPAN_QUERY_GROUPIDS,
+                                  keys=len(pids)) as walk:
+                            keys = [sh.rv_key_of(int(p)) for p in pids]
+                            for p, gk in zip(pids, group_keys_of(
+                                    keys, plan.by, plan.without)):
+                                g[p] = uniq.setdefault(gk, len(uniq))
+                            walk["groups"] = len(uniq)
                 gids_list.append(g)
             if not uniq:
                 self._set_path(ctx, "mesh-empty")
@@ -1278,7 +1304,11 @@ class QueryEngine:
             # collective never stalls ingest across every shard. The FIRST
             # query of a new (fn, op, G-bucket, T-bucket) shape still traces
             # and compiles here — step-count bucketing inside the executor
-            # bounds that compile space exactly like the in-process path
+            # bounds that compile space exactly like the in-process path.
+            # The span closes with the stack, just before the locks release
+            kern = stack.enter_context(span(
+                SPAN_QUERY_KERNEL, phase="dispatch", steps=len(out_ts),
+                rows=sum(sh.store.S for sh in shards), groups=G))
             if op == "quantile":
                 # same safety gates as the in-process order-stat map: group
                 # cap + dense-sketch memory cap (every device allocates the
@@ -1303,6 +1333,11 @@ class QueryEngine:
             else:
                 lazy = ex.aggregate(fn, op, out_ts, window, gids_list,
                                     G, args=(a0, a1), fetch=False)
+            # the program that ran and, for a fused one, its column block:
+            # what ties a device event to this query
+            kern["kernel"] = f"{ex.last_mode}-{ex.last_path}"
+            if ex.last_block is not None:
+                kern["c0"], kern["cols"] = ex.last_block
             if ctx is not None:     # committed: the mesh path serves this
                 ctx.stats.add("series_matched", matched_total)
                 if ex.last_path.startswith("fused"):
@@ -1320,7 +1355,9 @@ class QueryEngine:
             m = self._present_mesh_topk(lazy, shards, epochs, out_ts,
                                         list(uniq))
         else:
-            m = ResultMatrix(out_ts, lazy.resolve(), list(uniq))
+            with span(SPAN_QUERY_KERNEL, phase="fetch"):
+                vals = lazy.resolve()
+            m = ResultMatrix(out_ts, vals, list(uniq))
         from .exec import check_sample_limit
         check_sample_limit(m.num_series, len(out_ts), self.config.sample_limit)
         return QueryResult(m)
@@ -1333,7 +1370,8 @@ class QueryEngine:
         shard's lock and validates its release epoch — a purge/eviction
         since dispatch could have re-assigned the row to a new series."""
         from .exec import QueryError, TopKPartial, _present_topk
-        vals, shard_ids, rows, ok = lazy.resolve()
+        with span(SPAN_QUERY_KERNEL, phase="fetch"):
+            vals, shard_ids, rows, ok = lazy.resolve()
         G, k, T = vals.shape
         flat_ok = ok.ravel()
         pairs = (shard_ids.ravel()[flat_ok].astype(np.int64) << 32) \

@@ -29,8 +29,10 @@ import numpy as np
 
 from ..core.filters import Filter
 from ..ops import aggregators, binop, instantfns, rangefns
-from ..utils.tracing import (SPAN_QUERY_LEAF, SPAN_QUERY_ODP,
-                             SPAN_QUERY_REDUCE, span)
+from ..utils.diagnostics import lock_wait_ns
+from ..utils.tracing import (SPAN_QUERY_GROUPIDS, SPAN_QUERY_KERNEL,
+                             SPAN_QUERY_LEAF, SPAN_QUERY_ODP,
+                             SPAN_QUERY_REDUCE, SPAN_QUERY_SELECT, span)
 from .rangevector import (QueryError, QueryResult, QueryStats,
                           RangeVectorKey, ResultMatrix, fmt_value)
 
@@ -506,12 +508,14 @@ def _group_ids_for(keys, rows, R, by, without):
     if len(keys) and not by and not without:
         # global aggregation: one group, keys never materialized
         return np.zeros(R, np.int32), [RangeVectorKey(())], 1
-    gkeys = group_keys_of(keys, by, without)
-    uniq: dict[RangeVectorKey, int] = {}
-    gid_of_key = np.empty(len(gkeys), np.int32)
-    for i, gk in enumerate(gkeys):
-        gid_of_key[i] = uniq.setdefault(gk, len(uniq))
-    G = max(len(uniq), 1)
+    # the walk: one Python step a selected series, lazy keys materialized
+    with span(SPAN_QUERY_GROUPIDS, keys=len(keys)) as walk:
+        gkeys = group_keys_of(keys, by, without)
+        uniq: dict[RangeVectorKey, int] = {}
+        gid_of_key = np.empty(len(gkeys), np.int32)
+        for i, gk in enumerate(gkeys):
+            gid_of_key[i] = uniq.setdefault(gk, len(uniq))
+        G = walk["groups"] = max(len(uniq), 1)
     if not gkeys:
         gids = np.zeros(R, np.int32)
     elif rows is None:
@@ -1153,8 +1157,14 @@ class SelectRawPartitionsExec(ExecPlan):
         return _shard_of_ctx(ctx, self.shard, self.column)
 
     def execute(self, ctx: QueryContext):
-        with span(SPAN_QUERY_LEAF, shard=self.shard):
-            return self._execute_leaf(ctx)
+        waited = lock_wait_ns()
+        with span(SPAN_QUERY_LEAF, shard=self.shard) as tags:
+            try:
+                return self._execute_leaf(ctx)
+            finally:
+                # a waiting thread is not what the host was doing: the wait
+                # for the shard lock is a tag of the leaf, not a span
+                tags["lock_wait_ms"] = (lock_wait_ns() - waited) / 1e6
 
     def _execute_leaf(self, ctx: QueryContext):
         # hold the shard lock across array capture AND the transformer chain's
@@ -1267,6 +1277,14 @@ class SelectRawPartitionsExec(ExecPlan):
         return merged
 
     def do_execute(self, ctx) -> SeriesSelection:
+        with span(SPAN_QUERY_SELECT, shard=self.shard) as tags:
+            sel = self._select(ctx)
+            tags["series"] = len(sel.pids if isinstance(sel, _WideODP)
+                                 else sel.keys)
+            return sel
+
+    def _select(self, ctx):
+        """Index select + array capture (the snapshot the chain runs on)."""
         shard, col = self._shard_of(ctx)
         if shard.store is None:   # histogram shard with no data yet
             z = jnp.zeros((8, 8), jnp.float32)
@@ -1583,8 +1601,9 @@ def _merge_partials(op: str, partials: list[AggPartial]) -> AggPartial:
     # device bundles (PaddedPartials) contribute their raw outputs to the
     # same fetch — calling their resolve() here would round-trip per shard
     raw = [p.parts for p in partials]
-    fetched = jax.device_get([r._outs if hasattr(r, "parts_of") else r
-                              for r in raw])
+    with span(SPAN_QUERY_KERNEL, phase="fetch"):
+        fetched = jax.device_get([r._outs if hasattr(r, "parts_of") else r
+                                  for r in raw])
     resolved = [r.parts_of(f) if hasattr(r, "parts_of") else f
                 for r, f in zip(raw, fetched)]
     merged: dict[str, object] = {}

@@ -25,6 +25,7 @@ from .parallel.cluster import ShardManager, ShardStatus
 from .parallel.shardmapper import ShardMapper
 from .query.engine import QueryEngine
 from .query.rangevector import QueryError
+from .utils.diagnostics import lock_wait_ns
 from .utils.metrics import (FILODB_INGEST_DECODE_ERRORS,
                             FILODB_INGESTED_ROWS, ShardHealthStats, registry)
 from .utils.tracing import SPAN_INGEST_CONSUME, span, tracer
@@ -207,6 +208,7 @@ class IngestionConsumer(threading.Thread):
                         # the scatter leg of the ingest path, tagged with
                         # how much it moved
                         n_rows = 0
+                        waited = lock_wait_ns()
                         try:
                             with span(SPAN_INGEST_CONSUME,
                                       dataset=self.dataset,
@@ -220,6 +222,8 @@ class IngestionConsumer(threading.Thread):
                                         n_rows += len(container)
                                     self._offset = off + 1
                                 tags["rows"] = n_rows
+                                tags["lock_wait_ms"] = (
+                                    lock_wait_ns() - waited) / 1e6
                         finally:
                             if isinstance(src, _DecodeAhead):
                                 src.close()
@@ -295,6 +299,7 @@ class FiloServer:
         self._endpoints: dict[str, str] = {}
         self._endpoints_at = 0.0
         self._zipkin = None
+        self._gc_hooked = False
 
     def _start_shard(self, dataset: str, shard_num: int) -> None:
         """Bring up one owned shard: store + (optionally) its bus consumer
@@ -1260,6 +1265,11 @@ class FiloServer:
         # the decision propagates to peers in the trace context
         tracer.enabled = bool(cfg.get("trace.enabled", True))
         tracer.sample_rate = float(cfg.get("trace.sample_rate", 1.0))
+        if not self._gc_hooked:
+            # full garbage collections as runtime.gc spans while this
+            # server lives (shutdown() removes the hook)
+            tracer.install_gc_hook()
+            self._gc_hooked = True
         from .query.engine import slow_query_log
         slow_query_log.resize(int(cfg["query.slow_log_size"]))
         # fused compressed-resident kernel tier: pick the backend BEFORE the
@@ -1365,6 +1375,9 @@ class FiloServer:
             self.profiler.stop()
         if self._zipkin is not None:
             self._zipkin.stop()
+        if self._gc_hooked:
+            tracer.remove_gc_hook()
+            self._gc_hooked = False
 
 
 def _pow2(n: int) -> int:

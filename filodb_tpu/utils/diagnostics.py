@@ -161,6 +161,13 @@ def _held_locks() -> list:
     return held
 
 
+def lock_wait_ns() -> int:
+    """Nanoseconds the CALLING thread has spent blocked in contended
+    ``TimedRLock.acquire`` calls since it started. A span tags the lock
+    wait it contained as the difference of two readings."""
+    return getattr(_tls, "wait_ns", 0)
+
+
 class DiagnosticsError(AssertionError):
     """A violated concurrency invariant (only raised when diagnostics on)."""
 
@@ -229,7 +236,10 @@ class TimedRLock:
 
     Drop-in for ``threading.RLock()`` (context manager + acquire/release +
     _is_owned); stats are cheap enough to keep even when diagnostics are off,
-    the long-hold stack capture only happens when on.
+    the long-hold stack capture only happens when on. ``wait_s`` totals the
+    time threads blocked in contended acquires (the uncontended path reads
+    no clock for it) and ``hold_s`` the first-depth holds, so
+    ``rate(hold_s)`` is the lock's utilisation without FILODB_LOCK_DEBUG.
 
     ``order_class`` names the lock's class in the global acquisition order
     (LOCK_ORDER). Under FILODB_LOCK_DEBUG=1 every acquisition checks the
@@ -250,6 +260,8 @@ class TimedRLock:
         self.order_index = order_index
         self.contentions = 0
         self.long_holds = 0
+        self.wait_s = 0.0
+        self.hold_s = 0.0
         self._acquired_at = 0.0
         self._depth = 0
         self._registered = False        # in the hold watchdog's held set
@@ -297,7 +309,12 @@ class TimedRLock:
                 self.contentions += 1
             if not blocking:
                 return False
+            t0 = time.perf_counter_ns()
             got = self._lock.acquire(True, timeout)
+            waited = time.perf_counter_ns() - t0
+            _tls.wait_ns = getattr(_tls, "wait_ns", 0) + waited
+            with self._stats_lock:
+                self.wait_s += waited / 1e9
             if not got:
                 return False
         self._depth += 1
@@ -330,6 +347,7 @@ class TimedRLock:
     def release(self):
         if self._depth == 1:
             held = time.monotonic() - self._acquired_at
+            self.hold_s += held         # serialized by the lock itself
             if self._registered:
                 _watchdog.unregister(self)
                 self._registered = False

@@ -44,6 +44,8 @@ FILODB_SHARD_STATUS = "filodb_shard_status"
 FILODB_SHARD_NUM_SERIES = "filodb_shard_num_series"
 FILODB_SHARD_LOCK_CONTENTIONS = "filodb_shard_lock_contentions"
 FILODB_SHARD_LOCK_LONG_HOLDS = "filodb_shard_lock_long_holds"
+FILODB_SHARD_LOCK_WAIT_SECONDS = "filodb_shard_lock_wait_seconds"
+FILODB_SHARD_LOCK_HOLD_SECONDS = "filodb_shard_lock_hold_seconds"
 FILODB_LOCK_HOLD_MS = "filodb_lock_hold_ms"
 FILODB_QUERY_LATENCY_MS = "filodb_query_latency_ms"
 FILODB_QUERY_SLOW = "filodb_query_slow"
@@ -155,6 +157,12 @@ METRICS_SPEC: dict[str, tuple[str, str]] = {
         "gauge", "TimedRLock contention count per shard (diagnostics)."),
     FILODB_SHARD_LOCK_LONG_HOLDS: (
         "gauge", "TimedRLock long-hold count per shard (diagnostics)."),
+    FILODB_SHARD_LOCK_WAIT_SECONDS: (
+        "gauge", "Seconds threads have blocked in contended acquires of the "
+                 "shard lock, total since start."),
+    FILODB_SHARD_LOCK_HOLD_SECONDS: (
+        "gauge", "Seconds the shard lock has been held, total since start; "
+                 "its rate is the lock's utilisation."),
     FILODB_LOCK_HOLD_MS: (
         "histogram", "TimedRLock hold time per lock class, recorded under "
                      "FILODB_LOCK_DEBUG=1 — the runtime twin of filolint's "

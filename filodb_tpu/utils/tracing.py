@@ -11,11 +11,24 @@ header and the broker PUBLISH_BATCH / OP_REPLICATE trace-header blocks), so
 one query or one publish yields ONE trace id with spans from every
 participating node.
 
-Clock discipline: ``time.time()`` is read ONCE per span, for the start
-timestamp only (Zipkin needs an epoch anchor); every DURATION comes from
-``time.perf_counter_ns()`` — the same no-wall-clock rule the fault plans and
-broker follow (a stepped system clock must never produce negative or
-million-second spans).
+Clock discipline: a span's start (``start_ns``) and its duration both come
+from ``time.perf_counter_ns()``, so everything that consumes spans inside
+one process (trace assembly, the benchmark's readers, a span that tags the
+lock wait it contained) orders and subtracts them on ONE monotonic clock —
+the same no-wall-clock rule the fault plans and broker follow (a stepped
+system clock must never produce negative or million-second spans). The
+wall clock is the EXPORTER's anchor only: it is read once per recorded
+span, at close, and ``start_us`` (what Zipkin needs) is that reading minus
+the monotonic time since the start.
+
+The profiler's clock: every recorded span also runs inside a
+``jax.profiler.TraceAnnotation`` of the same name (argument ``trace_id``),
+so any ``jax.profiler`` trace of the process holds the program's spans in
+its host planes, on the trace's own clock, beside the device operations.
+An annotation outside a profiler session costs a fraction of a
+microsecond; a sampled-out or disabled span enters none. Intervals handed
+to ``record()`` after the fact (a queue wait, a garbage collection) cannot
+enter one and live in the ring only.
 
 Sampling: the decision is made once at the trace ROOT (``sample_rate``) and
 rides the context, so either every participating node records a trace or
@@ -27,6 +40,7 @@ disabled (the root decided).
 from __future__ import annotations
 
 import contextlib
+import gc
 import json
 import logging
 import os
@@ -35,6 +49,8 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
+
+from jax.profiler import TraceAnnotation
 
 from .metrics import FILODB_SWALLOWED_ERRORS, FILODB_TRACE_SPANS, registry
 
@@ -51,11 +67,17 @@ log = logging.getLogger("filodb_tpu.trace")
 # dict so docs cannot drift from code.
 # ---------------------------------------------------------------------------
 
+SPAN_HTTP_REQUEST = "http.request"
+SPAN_HTTP_RENDER = "http.render"
+SPAN_QUERY_QUEUE = "query.queue"
 SPAN_QUERY = "query"
 SPAN_QUERY_PARSE = "query.parse"
 SPAN_QUERY_PLAN = "query.plan"
 SPAN_QUERY_EXECUTE = "query.execute"
 SPAN_QUERY_LEAF = "query.exec.leaf"
+SPAN_QUERY_SELECT = "query.exec.select"
+SPAN_QUERY_GROUPIDS = "query.exec.groupids"
+SPAN_QUERY_KERNEL = "query.exec.kernel"
 SPAN_QUERY_REDUCE = "query.exec.reduce"
 SPAN_QUERY_DISPATCH = "query.exec.dispatch"
 SPAN_QUERY_SERVE = "query.exec.serve"
@@ -70,6 +92,7 @@ SPAN_BROKER_APPEND = "ingest.broker.append"
 SPAN_REPLICATE = "ingest.replicate"
 SPAN_REPLICATE_SERVE = "ingest.replicate.serve"
 SPAN_INGEST_CONSUME = "ingest.consume"
+SPAN_INGEST_FLUSH = "ingest.flush"
 SPAN_QUERY_RETENTION = "query.retention"
 SPAN_QUERY_FRAGMENT = "query.fragment"
 SPAN_QUERY_SUBSCRIBE = "query.subscribe"
@@ -79,16 +102,42 @@ SPAN_CLUSTER_GOSSIP = "cluster.gossip"
 SPAN_CLUSTER_LEAD = "cluster.epoch.lead"
 SPAN_CLUSTER_REJOIN = "cluster.rejoin"
 SPAN_CLUSTER_REBALANCE = "cluster.rebalance"
+SPAN_RUNTIME_GC = "runtime.gc"
 
 TRACE_SPEC: dict[str, str] = {
-    SPAN_QUERY: "Root span of one PromQL query (tags: dataset, promql).",
+    SPAN_HTTP_REQUEST: "Root span of one HTTP query_range/query request on "
+                       "its handler thread: request parsed -> response "
+                       "written (tags: route, status, bytes; an error "
+                       "answer is written after it closes).",
+    SPAN_HTTP_RENDER: "Answer of a query rendered and written: Prometheus "
+                      "JSON shape + json.dumps + socket write (tags: "
+                      "series, bytes).",
+    SPAN_QUERY_QUEUE: "Wait of one scheduled query in the QueryScheduler's "
+                      "heap, enqueue -> a worker starts it (recorded after "
+                      "the fact; tags: priority).",
+    SPAN_QUERY: "One PromQL query; the root unless an http.request opened "
+                "first (tags: dataset, promql, start_ms, end_ms, step_ms, "
+                "tenant; on close exec_path, status and lock_wait_ms = "
+                "every wait of its thread for a shard lock).",
     SPAN_QUERY_PARSE: "PromQL text -> LogicalPlan.",
     SPAN_QUERY_PLAN: "LogicalPlan -> ExecPlan materialization + remote "
                      "collapse.",
     SPAN_QUERY_EXECUTE: "ExecPlan execution (mesh, fused, or scatter-gather "
                         "path; tags: path).",
-    SPAN_QUERY_LEAF: "One data-reading leaf under its shard lock "
-                     "(tags: shard).",
+    SPAN_QUERY_LEAF: "One data-reading leaf under its shard lock; on the "
+                     "mesh route one span under every shard's lock (tags: "
+                     "shard, or shard=all route=mesh; lock_wait_ms = what "
+                     "the thread waited for shard locks inside it).",
+    SPAN_QUERY_SELECT: "Index select + array capture of one leaf; per shard "
+                       "on the mesh route (tags: shard, series).",
+    SPAN_QUERY_GROUPIDS: "The by/without group-id walk over the selected "
+                         "series' keys, lazy key materialisation included; "
+                         "a global aggregate opens none (tags: keys, "
+                         "groups).",
+    SPAN_QUERY_KERNEL: "Host side of one fused kernel: phase=dispatch is "
+                       "the call under the shard lock, phase=fetch the "
+                       "blocking fetch of its result outside it (dispatch "
+                       "tags: kernel, rows, c0, cols, steps, groups).",
     SPAN_QUERY_REDUCE: "Cross-shard reduce merge of child partials.",
     SPAN_QUERY_DISPATCH: "One cross-node /exec POST (tags: endpoint, "
                          "shards).",
@@ -115,7 +164,13 @@ TRACE_SPEC: dict[str, str] = {
     SPAN_REPLICATE_SERVE: "Follower side of OP_REPLICATE: CRC check + "
                           "append (tags: partition, broker).",
     SPAN_INGEST_CONSUME: "One consumer drain: bus containers scattered "
-                         "into the shard store (tags: dataset, shard).",
+                         "into the shard store (tags: dataset, shard, rows, "
+                         "lock_wait_ms).",
+    SPAN_INGEST_FLUSH: "One shard flush that had staged rows to land: "
+                       "device scatter, backpressure, residency upkeep; an "
+                       "idle flush opens none (tags: shard, rows, "
+                       "lock_wait_ms, throttle_ms = the wait for the "
+                       "device).",
     SPAN_QUERY_RETENTION: "Downsample-aware routing of one query: the "
                           "resolution decision and its routed/stitched "
                           "leg queries hang under it (tags: dataset, "
@@ -144,6 +199,9 @@ TRACE_SPEC: dict[str, str] = {
     SPAN_CLUSTER_REBALANCE: "Operator-triggered live shard move: "
                             "flush→handoff→catch-up→cutover (tags: dataset, "
                             "shard, to).",
+    SPAN_RUNTIME_GC: "One full (generation 2) garbage collection of the "
+                     "server process, every thread stopped (recorded after "
+                     "the fact by the server's gc hook; tags: collected).",
 }
 
 
@@ -169,11 +227,16 @@ class SpanRecord:
     # monotonic record sequence (per tracer): exporters keep a watermark
     # against it instead of draining the shared ring
     seq: int = 0
+    # the start on this process's monotonic clock (time.perf_counter_ns):
+    # what orders and subtracts spans in-process; start_us is the same
+    # instant on the wall clock, for the exporter
+    start_ns: int = 0
 
     def to_dict(self) -> dict:
         return {"trace_id": self.trace_id, "span_id": self.span_id,
                 "parent_id": self.parent_id, "name": self.name,
-                "start_us": self.start_us, "duration_us": self.duration_us,
+                "start_us": self.start_us, "start_ns": self.start_ns,
+                "duration_us": self.duration_us,
                 "tags": {k: str(v) for k, v in self.tags.items()}}
 
     def to_zipkin(self) -> dict:
@@ -196,9 +259,15 @@ class Tracer:
 
     def __init__(self, capacity: int = 4096):
         self.spans: deque[SpanRecord] = deque(maxlen=capacity)
+        # finished intervals from record(), which takes no lock (it runs
+        # inside the gc hook, possibly on a thread that holds _lock); the
+        # next commit, snapshot or drain moves them into the ring
+        self._handoff: deque[SpanRecord] = deque()
         self._local = threading.local()
         self._lock = threading.Lock()
         self._seq = 0
+        self._gc_users = 0
+        self._gc_t0: int | None = None
         self.log_spans = False
         self.enabled = True
         self.sample_rate = 1.0
@@ -279,59 +348,120 @@ class Tracer:
 
     # -- spans --------------------------------------------------------------
 
-    @contextlib.contextmanager
-    def span(self, name: str, **tags):
-        """Record one span. Yields the TAGS dict so callers can attach
-        outcome tags discovered mid-span (e.g. a publish that failed over
-        leaders) — mutations land in the recorded span."""
-        stack = self._stack()
-        if stack:
-            trace_id, parent_id, sampled = stack[-1]
-        elif not self.enabled:
-            # no active context and tracing off: stay out of the clocks
-            yield tags
-            return
-        else:
-            trace_id = self._new_id()
-            parent_id = None
-            sampled = (self.sample_rate >= 1.0
-                       or self._local.rng.random() < self.sample_rate)
-        # sampled-out spans skip id generation too: the frame still
-        # propagates (children and peers must inherit the decision) but
-        # nothing will ever reference its span id
-        span_id = self._new_id() if sampled else "0"
-        stack.append((trace_id, span_id, sampled))
-        if sampled:
-            # wall clock ONCE, for the epoch anchor; duration is monotonic
-            t0_wall_us = int(time.time() * 1e6)
-            t0 = time.perf_counter_ns()
-        try:
-            yield tags
-        finally:
-            stack.pop()
-            if sampled:
-                dur_us = (time.perf_counter_ns() - t0) // 1000
-                rec = SpanRecord(trace_id, span_id, parent_id, name,
-                                 t0_wall_us, int(dur_us), tags)
-                with self._lock:
-                    self._seq += 1
-                    rec.seq = self._seq
-                    self.spans.append(rec)
-                self._span_counter.increment()
-                if self.log_spans:
-                    log.info("span %s %.1fms %s", name, dur_us / 1000, tags)
+    def _root(self) -> tuple:
+        """``(trace_id, None, sampled)``: a fresh trace and its sampling
+        decision, made once here and inherited by everything under it."""
+        trace_id = self._new_id()
+        return (trace_id, None, self.sample_rate >= 1.0
+                or self._local.rng.random() < self.sample_rate)
 
-    def last_trace_id(self) -> str | None:
+    def span(self, name: str, **tags) -> "_OpenSpan":
+        """Record one span: ``with span(NAME, k=v) as tags``. Yields the
+        TAGS dict so callers can attach outcome tags discovered mid-span
+        (e.g. a publish that failed over leaders) — mutations land in the
+        recorded span."""
+        return _OpenSpan(self, name, tags)
+
+    def _commit(self, rec: SpanRecord) -> None:
         with self._lock:
-            return self.spans[-1].trace_id if self.spans else None
+            self._seq += 1
+            rec.seq = self._seq
+            self.spans.append(rec)
+        self._span_counter.increment()
+        if self.log_spans:
+            log.info("span %s %.1fms %s", rec.name, rec.duration_us / 1000,
+                     rec.tags)
+        if self._handoff:
+            self._sync_handoff()
+
+    def record(self, name: str, t0_ns: int, t1_ns: int, **tags) -> None:
+        """Record a FINISHED interval (``time.perf_counter_ns`` readings)
+        under the calling thread's current context, by span()'s sampling
+        and ``enabled`` rules: for the waits no ``with`` block on one thread
+        can bracket (the scheduler queue, a garbage collection). Takes no
+        lock and touches no metric — the gc hook calls it from wherever a
+        collection happened to start — so the record reaches the ring and
+        the span counter with the next span, snapshot or drain."""
+        stack = self._stack()
+        if not stack and not self.enabled:
+            return
+        trace_id, parent_id, sampled = stack[-1] if stack else self._root()
+        if sampled:
+            self._handoff.append(self._finished(
+                trace_id, self._new_id(), parent_id, name, t0_ns, t1_ns,
+                time.perf_counter_ns(), tags))
+
+    @staticmethod
+    def _finished(trace_id, span_id, parent_id, name, t0_ns: int, t1_ns: int,
+                  now_ns: int, tags: dict) -> SpanRecord:
+        # the wall clock, read ONCE a span and only as the exporter's
+        # anchor: "now" on it, less the monotonic time since the start
+        start_us = int(time.time() * 1e6) - (now_ns - t0_ns) // 1000
+        return SpanRecord(trace_id, span_id, parent_id, name, start_us,
+                          (t1_ns - t0_ns) // 1000, tags, 0, t0_ns)
+
+    def _sync_handoff(self) -> None:
+        """Move what record() handed over into the ring and the counter.
+        The deque is lock-free on both sides (appends and pops are atomic)."""
+        late = []
+        try:
+            while True:
+                late.append(self._handoff.popleft())
+        except IndexError:
+            if not late:
+                return
+        with self._lock:
+            for r in late:
+                self._seq += 1
+                r.seq = self._seq
+                self.spans.append(r)
+        self._span_counter.increment(len(late))
+        if self.log_spans:
+            for r in late:
+                log.info("span %s %.1fms %s", r.name, r.duration_us / 1000,
+                         r.tags)
+
+    # -- garbage collections ------------------------------------------------
+
+    def install_gc_hook(self) -> None:
+        """``runtime.gc`` spans from here on: one ``gc.callbacks`` hook for
+        the process, shared by every server that asked (FiloServer.start /
+        stop pair it with :meth:`remove_gc_hook`)."""
+        with self._lock:
+            self._gc_users += 1
+            if self._gc_users == 1:
+                gc.callbacks.append(self._on_gc)
+
+    def remove_gc_hook(self) -> None:
+        with self._lock:
+            if self._gc_users:
+                self._gc_users -= 1
+                if not self._gc_users:
+                    gc.callbacks.remove(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """Full (generation 2) collections only: with ~10^7 index objects
+        alive one of them stops every thread for a third of a second.
+        Runs wherever the interpreter chose to collect — also on a thread
+        inside this tracer's critical section — hence record(), lock-free."""
+        if info.get("generation") != 2:
+            return
+        if phase == "start":
+            self._gc_t0 = time.perf_counter_ns()
+        elif self._gc_t0 is not None:
+            t0, self._gc_t0 = self._gc_t0, None
+            self.record(SPAN_RUNTIME_GC, t0, time.perf_counter_ns(),
+                        collected=info.get("collected", 0))
 
     # -- assembly / export --------------------------------------------------
 
     def snapshot(self) -> list[SpanRecord]:
+        self._sync_handoff()
         with self._lock:
             return list(self.spans)
 
     def drain(self) -> list[SpanRecord]:
+        self._sync_handoff()
         with self._lock:
             out = list(self.spans)
             self.spans.clear()
@@ -363,12 +493,12 @@ class Tracer:
                 else:
                     roots.append(s)
             ordered: list[SpanRecord] = []
-            stack = list(reversed(sorted(roots, key=lambda s: s.start_us)))
+            stack = list(reversed(sorted(roots, key=lambda s: s.start_ns)))
             while stack:
                 s = stack.pop()
                 ordered.append(s)
                 kids = sorted(children.get(s.span_id, ()),
-                              key=lambda c: c.start_us)
+                              key=lambda c: c.start_ns)
                 stack.extend(reversed(kids))
             out.append({"trace_id": tid,
                         "duration_us": max((s.duration_us for s in roots),
@@ -398,6 +528,55 @@ class Tracer:
         with urllib.request.urlopen(req, timeout=5.0) as r:
             r.read()
         return len(spans)
+
+
+class _OpenSpan:
+    """The context manager behind ``Tracer.span`` (a class, not a generator:
+    a span is opened ~13 times a served query and a generator-based manager
+    costs a microsecond more each)."""
+
+    __slots__ = ("_tracer", "_name", "_tags", "_frame", "_stack", "_ann",
+                 "_t0")
+
+    def __init__(self, tracer_: Tracer, name: str, tags: dict):
+        self._tracer, self._name, self._tags = tracer_, name, tags
+        self._frame = None
+
+    def __enter__(self) -> dict:
+        tr = self._tracer
+        stack = self._stack = tr._stack()
+        if stack:
+            trace_id, parent_id, sampled = stack[-1]
+        elif not tr.enabled:
+            # no active context and tracing off: stay out of the clocks
+            return self._tags
+        else:
+            trace_id, parent_id, sampled = tr._root()
+        # sampled-out spans skip id generation too: the frame still
+        # propagates (children and peers must inherit the decision) but
+        # nothing will ever reference its span id
+        span_id = tr._new_id() if sampled else "0"
+        self._frame = (trace_id, span_id, parent_id, sampled)
+        stack.append((trace_id, span_id, sampled))
+        if sampled:
+            # the same interval on the profiler's clock, when one is tracing
+            self._ann = TraceAnnotation(self._name, trace_id=trace_id)
+            self._ann.__enter__()
+            self._t0 = time.perf_counter_ns()
+        return self._tags
+
+    def __exit__(self, *exc) -> bool:
+        if self._frame is None:
+            return False
+        trace_id, span_id, parent_id, sampled = self._frame
+        self._stack.pop()
+        if sampled:
+            t1 = time.perf_counter_ns()
+            self._ann.__exit__(None, None, None)
+            self._tracer._commit(Tracer._finished(
+                trace_id, span_id, parent_id, self._name, self._t0, t1, t1,
+                self._tags))
+        return False
 
 
 class ZipkinReporter:

@@ -12,7 +12,8 @@ TRACE_SPEC = {
 }
 
 
-def work(span):
+def work(span, tracer):
+    tracer.record("fixture.late", 0, 1)     # literal name through record()
     with span(SPAN_GOOD):
         pass
     with span("fixture.literal"):    # literal name: one-spelling rule

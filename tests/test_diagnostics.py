@@ -74,6 +74,70 @@ def test_timed_rlock_counts_contention(diag):
             pass
 
 
+def test_timed_rlock_times_waits_and_holds():
+    """Contended: the waiter's blocked time lands in the lock's ``wait_s``
+    and in the waiting thread's own total; the holder's in ``hold_s``.
+    Uncontended: the hold is added, the wait totals are untouched."""
+    lock = diagnostics.TimedRLock("t")
+    mine = diagnostics.lock_wait_ns()
+    t0 = time.perf_counter()
+    with lock:
+        with lock:                  # a re-entry is not a second hold
+            time.sleep(0.05)
+    quiet = time.perf_counter() - t0
+    assert lock.wait_s == 0 and lock.contentions == 0
+    assert diagnostics.lock_wait_ns() == mine
+    assert 0.05 <= lock.hold_s <= quiet
+
+    held, got = threading.Event(), {}
+
+    def holder():
+        with lock:
+            held.set()
+            time.sleep(0.3)
+
+    def waiter():
+        before = diagnostics.lock_wait_ns()
+        with lock:
+            pass
+        got["ns"] = diagnostics.lock_wait_ns() - before
+
+    th = threading.Thread(target=holder)
+    th.start()
+    assert held.wait(5)
+    tw = threading.Thread(target=waiter)
+    tw.start()
+    th.join(5)
+    tw.join(5)
+    assert not th.is_alive() and not tw.is_alive()
+    assert lock.contentions == 1
+    assert 0.2 <= lock.wait_s <= 0.35, lock.wait_s
+    assert abs(got["ns"] / 1e9 - lock.wait_s) < 1e-6   # the waiter's alone
+    assert diagnostics.lock_wait_ns() == mine           # not this thread's
+    assert lock.hold_s >= 0.05 + 0.3
+
+
+def test_timed_rlock_counts_a_wait_that_timed_out():
+    lock = diagnostics.TimedRLock("t")
+    held, release = threading.Event(), threading.Event()
+
+    def holder():
+        with lock:
+            held.set()
+            release.wait(5)
+
+    th = threading.Thread(target=holder)
+    th.start()
+    assert held.wait(5)
+    before = diagnostics.lock_wait_ns()
+    assert lock.acquire(timeout=0.1) is False
+    release.set()
+    th.join(5)
+    assert not th.is_alive()
+    assert lock.wait_s >= 0.1
+    assert diagnostics.lock_wait_ns() - before >= 100_000_000
+
+
 def test_donation_detective_explains(diag):
     det = diagnostics.DonationDetective()
     det.record("flush")

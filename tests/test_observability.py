@@ -4,6 +4,7 @@ ingest trace surviving a fault-injected leader failover."""
 
 import contextlib
 import json
+import time
 import urllib.request
 
 import numpy as np
@@ -110,11 +111,20 @@ def test_http_response_carries_stats_and_slow_log(server):
     assert e["plan"] == "local"
     assert e["stats"]["series_matched"] == 6
     assert e["trace_id"] and len(e["trace_id"]) == 16
-    # the slow query's trace is queryable by exactly that id
-    data = json.loads(_get(
-        server, f"/api/v1/debug/traces?trace_id={e['trace_id']}"))["data"]
-    assert len(data) == 1
-    assert data[0]["spans"][0]["name"] == "query"
+    # the slow query's trace is queryable by exactly that id; the request
+    # on its handler thread is the root and the query hangs under it (its
+    # span closes once the answer is written, a moment after we read it)
+    for _ in range(200):
+        data = json.loads(_get(
+            server, f"/api/v1/debug/traces?trace_id={e['trace_id']}"))["data"]
+        assert len(data) == 1
+        names = [s["name"] for s in data[0]["spans"]]
+        if "http.request" in names:
+            break
+        time.sleep(0.01)
+    assert names[0] == "http.request" and "query" in names
+    query = next(s for s in data[0]["spans"] if s["name"] == "query")
+    assert query["parent_id"] == data[0]["spans"][0]["span_id"]
 
 
 def test_metrics_exemplar_carries_trace_id(server):

@@ -128,6 +128,17 @@ def test_good_twin_is_clean(name):
         f"{good} must be clean:\n" + "\n".join(f.render() for f in findings))
 
 
+def test_tracer_record_sites_count_as_span_sites():
+    """An interval recorded after the fact names its span like an opened
+    one: ``tracer.record("literal", ...)`` is flagged, and a declared span
+    that only ``tracer.record(SPAN_X, ...)`` uses is not dead surface."""
+    bad = {f.detail for f in analyze_file(FIXTURES / "bad_trace_span.py",
+                                          root=REPO)}
+    assert "literal:fixture.late" in bad
+    good = analyze_file(FIXTURES / "good_trace_span.py", root=REPO)
+    assert not [f for f in good if "fixture.late" in f.detail], good
+
+
 def _wire_findings(codec: str, classifier: str | None = None):
     spec = {
         "wire_module": codec,

@@ -1,13 +1,23 @@
-"""Tracer mechanics (no device): monotonic durations, nested and
-cross-thread parentage, root-decided sampling, ring bounds, Zipkin shape."""
+"""Tracer mechanics (no device): monotonic starts and durations, nested and
+cross-thread parentage, root-decided sampling, ring bounds, Zipkin shape,
+intervals recorded after the fact; and the served query's spans through an
+in-process server: one root, the profiler's clock, lock wait, the gc hook."""
 
+import gc
+import glob
 import json
 import threading
 import time
+import urllib.parse
+import urllib.request
 
 import pytest
 
-from filodb_tpu.utils.tracing import Tracer
+from filodb_tpu.utils.tracing import (SPAN_HTTP_RENDER, SPAN_HTTP_REQUEST,
+                                      SPAN_QUERY, SPAN_QUERY_GROUPIDS,
+                                      SPAN_QUERY_KERNEL, SPAN_QUERY_LEAF,
+                                      SPAN_QUERY_QUEUE, SPAN_QUERY_SELECT,
+                                      SPAN_RUNTIME_GC, Tracer, tracer)
 
 
 @pytest.fixture()
@@ -35,8 +45,8 @@ def test_nested_parentage_single_trace(tr):
 
 def test_duration_is_monotonic_not_wall_clock(tr, monkeypatch):
     """A stepped (frozen) system clock must not zero span durations: only
-    the START timestamp reads time.time(); the duration comes from
-    perf_counter_ns (the PR-7 no-wall-clock satellite)."""
+    the exporter's anchor reads time.time(), once, at close; start and
+    duration come from perf_counter_ns (the PR-7 no-wall-clock satellite)."""
     frozen = time.time()
     monkeypatch.setattr(time, "time", lambda: frozen)
     with tr.span("work"):
@@ -45,8 +55,82 @@ def test_duration_is_monotonic_not_wall_clock(tr, monkeypatch):
         while time.perf_counter_ns() - t0 < 2_000_000:
             pass
     rec = tr.snapshot()[0]
-    assert rec.start_us == int(frozen * 1e6)
     assert rec.duration_us >= 1_000
+    # the wall anchor is "now" at close less the monotonic time since start
+    assert rec.start_us == int(frozen * 1e6) - rec.duration_us
+
+
+def test_start_ns_is_monotonic_and_consistent_with_duration(tr):
+    before = time.perf_counter_ns()
+    with tr.span("outer"):
+        with tr.span("inner"):
+            time.sleep(0.002)
+    after = time.perf_counter_ns()
+    sp = _by_name(tr)
+    outer, inner = sp["outer"], sp["inner"]
+    assert before <= outer.start_ns <= inner.start_ns
+    # each interval [start_ns, start_ns + duration] nests on the one clock
+    # (duration_us is floored, so an end is known to within a microsecond)
+    inner_end = inner.start_ns + inner.duration_us * 1000
+    outer_end = outer.start_ns + (outer.duration_us + 1) * 1000
+    assert inner.duration_us >= 2_000
+    assert inner_end <= outer_end <= after + 1000
+    # the debug plane carries it; the Zipkin shape does not change
+    assert tr.traces()[0]["spans"][0]["start_ns"] == outer.start_ns
+    assert "start_ns" not in outer.to_zipkin()
+
+
+@pytest.mark.parametrize("enabled,context,want", [
+    (True, "span", "child"),        # under an active span: its child
+    (True, None, "root"),           # no context: roots a trace of its own
+    (False, None, None),            # tracing off, no context: nothing
+    (False, "remote", "child"),     # a sampled remote context decides
+    (True, "sampled_out", None),    # the root said no
+])
+def test_record_follows_span_rules(tr, enabled, context, want):
+    tr.enabled = enabled
+    t0 = time.perf_counter_ns()
+    t1 = t0 + 5_000_000
+    if context == "span":
+        with tr.span("parent"):
+            tr.record("waited", t0, t1, priority="QUERY")
+        parent = _by_name(tr)["parent"]
+        ids = (parent.trace_id, parent.span_id)
+    elif context == "remote":
+        ids = ("a" * 16, "b" * 16)
+        with tr.activate({"trace_id": ids[0], "span_id": ids[1],
+                          "sampled": True}):
+            tr.record("waited", t0, t1, priority="QUERY")
+    elif context == "sampled_out":
+        tr.sample_rate = 0.0
+        with tr.span("parent"):
+            tr.record("waited", t0, t1, priority="QUERY")
+    else:
+        tr.record("waited", t0, t1, priority="QUERY")
+    rec = _by_name(tr).get("waited")
+    if want is None:
+        assert rec is None and not tr._handoff
+        return
+    assert (rec.start_ns, rec.duration_us) == (t0, 5_000)
+    assert rec.tags == {"priority": "QUERY"} and rec.seq > 0
+    if want == "child":
+        assert (rec.trace_id, rec.parent_id) == ids
+    else:
+        assert rec.parent_id is None and len(rec.trace_id) == 16
+
+
+def test_record_takes_no_lock_and_reaches_ring_with_next_span(tr):
+    """The gc hook calls record() from wherever a collection started, also
+    on a thread inside the tracer's critical section: it must not need the
+    tracer's lock. The record lands with the next span."""
+    with tr._lock:
+        tr.record("late", time.perf_counter_ns() - 1000,
+                  time.perf_counter_ns())
+    assert len(tr._handoff) == 1 and not tr.spans
+    with tr.span("next"):
+        pass
+    assert [s.name for s in tr.spans] == ["next", "late"]
+    assert [s.seq for s in tr.spans] == [1, 2]
 
 
 def test_cross_thread_activate_joins_trace(tr):
@@ -192,3 +276,213 @@ def test_zipkin_export_shape(tr):
     assert row["name"] == "z" and row["tags"] == {"endpoint": "e"}
     # filtered export by trace id
     assert json.loads(tr.export_zipkin_json(trace_id="nope")) == []
+
+
+# -- the served query, through an in-process server -------------------------
+
+BASE = 1_700_000_000_000
+N_SERIES, N_SAMPLES = 16, 90
+
+
+@pytest.fixture()
+def served():
+    """A FiloServer with the shipped defaults (scheduler, tracing on) and
+    16 series in 4 groups on a 10 s grid; yields (server, get)."""
+    from filodb_tpu.config import Config
+    from filodb_tpu.core.record import RecordBuilder
+    from filodb_tpu.core.schemas import GAUGE
+    from filodb_tpu.standalone import FiloServer
+    srv = FiloServer(Config({
+        "num_shards": 1, "http": {"port": 0},
+        "store": {"max_series_per_shard": 32, "samples_per_series": 128,
+                  "flush_batch_size": 10**9}})).start()
+    b = RecordBuilder(GAUGE)
+    for t in range(N_SAMPLES):
+        for i in range(N_SERIES):
+            b.add({"_metric_": "m", "host": f"h{i}", "g": f"g{i % 4}"},
+                  BASE + t * 10_000, float(i + 3 * t))
+    srv.memstore.ingest("prometheus", 0, b.build())
+    srv.memstore.flush_all()
+
+    def get(promql="sum by (g)(rate(m[2m]))", shift_ms=0):
+        # a shifted range is off every cached grid: it executes in full
+        q = urllib.parse.urlencode({
+            "query": promql, "start": (BASE + 300_000 + shift_ms) / 1000,
+            "end": (BASE + 800_000 + shift_ms) / 1000, "step": 10})
+        url = (f"http://127.0.0.1:{srv.http.port}/promql/prometheus/api/v1/"
+               f"query_range?{q}")
+        with urllib.request.urlopen(url, timeout=60) as r:
+            return json.load(r)
+    try:
+        yield srv, get
+    finally:
+        srv.shutdown()
+
+
+def _trace_of_last_query():
+    # the request's span closes after the client has read the answer
+    deadline = time.monotonic() + 5
+    while time.monotonic() < deadline:
+        roots = [s for s in tracer.snapshot() if s.name == SPAN_HTTP_REQUEST]
+        if roots:
+            return [s for s in tracer.snapshot()
+                    if s.trace_id == roots[-1].trace_id]
+        time.sleep(0.01)
+    raise AssertionError("no http.request span was recorded")
+
+
+def test_http_query_is_one_trace_rooted_at_the_request(served):
+    _srv, get = served
+    get()                                   # compiles; not the one we read
+    _trace_of_last_query()                  # ... and has closed its spans
+    tracer.drain()
+    body = get(shift_ms=1_000)
+    assert body["status"] == "success" and len(body["data"]["result"]) == 4
+    members = _trace_of_last_query()
+    names = [s.name for s in members]
+    roots = [s for s in members if s.parent_id is None]
+    assert [s.name for s in roots] == [SPAN_HTTP_REQUEST]
+    req = roots[0]
+    assert req.tags["route"] == "query_range" and req.tags["status"] == 200
+    for name in (SPAN_QUERY_QUEUE, SPAN_QUERY, SPAN_QUERY_LEAF,
+                 SPAN_QUERY_SELECT, SPAN_QUERY_GROUPIDS, SPAN_QUERY_KERNEL,
+                 SPAN_HTTP_RENDER):
+        assert name in names, (name, names)
+    assert len(members) <= 24, names
+    by = {s.name: s for s in members}
+    # queue and query hang under the request, on the worker's side of it
+    assert by[SPAN_QUERY_QUEUE].parent_id == req.span_id
+    assert by[SPAN_QUERY].parent_id == req.span_id
+    assert by[SPAN_QUERY_QUEUE].tags == {"priority": "QUERY"}
+    q = by[SPAN_QUERY].tags
+    assert (q["start_ms"], q["end_ms"], q["step_ms"]) == (
+        BASE + 301_000, BASE + 801_000, 10_000)
+    assert q["status"] == "ok" and q["exec_path"] \
+        == body["stats"]["exec_path"]
+    assert by[SPAN_QUERY_SELECT].tags["series"] == N_SERIES
+    assert by[SPAN_QUERY_GROUPIDS].tags == {"keys": N_SERIES, "groups": 4}
+    assert by[SPAN_HTTP_RENDER].tags["series"] == 4
+    assert by[SPAN_HTTP_RENDER].tags["bytes"] == req.tags["bytes"] > 0
+    kernels = [s for s in members if s.name == SPAN_QUERY_KERNEL]
+    assert {k.tags["phase"] for k in kernels} == {"dispatch", "fetch"}
+    disp = next(k for k in kernels if k.tags["phase"] == "dispatch")
+    assert disp.tags["groups"] >= 4 and disp.tags["steps"] == 51
+    assert disp.tags["cols"] > 0 and "interpret" in disp.tags["kernel"]
+    # the means add up because the parts nest: queue + query + render fit
+    # in the request, select + group ids + dispatch in the leaf
+    parts = sum(by[n].duration_us for n in
+                (SPAN_QUERY_QUEUE, SPAN_QUERY, SPAN_HTTP_RENDER))
+    assert parts <= req.duration_us + 3
+    leaf = by[SPAN_QUERY_LEAF]
+    inner = by[SPAN_QUERY_SELECT].duration_us \
+        + by[SPAN_QUERY_GROUPIDS].duration_us + disp.duration_us
+    assert inner <= leaf.duration_us + 3
+    assert leaf.tags["lock_wait_ms"] == 0
+
+
+def test_global_aggregate_opens_no_groupids_span(served):
+    _srv, get = served
+    tracer.drain()
+    get("sum(rate(m[2m]))")
+    assert SPAN_QUERY_LEAF in [s.name for s in _trace_of_last_query()]
+    assert SPAN_QUERY_GROUPIDS not in [s.name for s in _trace_of_last_query()]
+
+
+def test_spans_are_in_the_profiler_trace_on_its_clock(served, tmp_path):
+    """Every recorded span runs inside a TraceAnnotation of its name: a
+    jax.profiler trace of the process holds them in a host plane, nested by
+    start and end on the trace's own clock."""
+    import jax
+    _srv, get = served
+    get()                                   # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        get(shift_ms=1_000)
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                         / "*.xplane.pb"))[-1]
+    found = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in (SPAN_HTTP_REQUEST, SPAN_QUERY, SPAN_QUERY_LEAF,
+                               SPAN_QUERY_GROUPIDS):
+                    found[ev.name] = (ev.start_ns, ev.start_ns
+                                      + ev.duration_ns)
+    chain = [SPAN_HTTP_REQUEST, SPAN_QUERY, SPAN_QUERY_LEAF,
+             SPAN_QUERY_GROUPIDS]
+    assert list(found) and set(found) == set(chain), sorted(found)
+    for outer, inner in zip(chain, chain[1:]):
+        assert found[outer][0] <= found[inner][0] \
+            and found[inner][1] <= found[outer][1], (outer, inner, found)
+
+
+def test_leaf_tags_the_wait_for_a_held_shard_lock(served):
+    srv, _get = served
+    eng = srv.engines["prometheus"]
+    shard = srv.memstore.shards_of("prometheus")[0]
+    # with the caches off no epoch probe touches the shard lock before the
+    # leaf does (with them on, that probe is where a query waits first, and
+    # only the ``query`` span's tag holds that wait)
+    eng.result_cache = eng.fragment_cache = None
+
+    def ask():
+        return eng.query_range("sum by (g)(rate(m[2m]))", BASE + 300_000,
+                               BASE + 800_000, 10_000)
+    ask()                                   # compiled
+    quiet = [s for s in tracer.snapshot() if s.name == SPAN_QUERY_LEAF][-1]
+    assert quiet.tags["lock_wait_ms"] == 0  # uncontended: no clock read
+    held, hold_s = threading.Event(), 0.4
+    took = {}
+
+    def holder():
+        with shard.lock:
+            t0 = time.perf_counter()
+            held.set()
+            time.sleep(hold_s)
+            took["s"] = time.perf_counter() - t0
+
+    t = threading.Thread(target=holder)
+    t.start()
+    assert held.wait(5)
+    tracer.drain()
+    ask()
+    t.join(5)
+    assert not t.is_alive()
+    spans = {s.name: s for s in tracer.snapshot()}
+    leaf = spans[SPAN_QUERY_LEAF]
+    assert abs(leaf.tags["lock_wait_ms"] - took["s"] * 1e3) \
+        <= 0.2 * took["s"] * 1e3, (leaf.tags, took)
+    assert leaf.duration_us / 1e3 >= leaf.tags["lock_wait_ms"]
+    assert spans[SPAN_QUERY].tags["lock_wait_ms"] \
+        >= leaf.tags["lock_wait_ms"]
+    # the lock's own totals moved with it, and /metrics exports them
+    assert shard.lock.wait_s >= 0.8 * took["s"]
+    assert shard.lock.hold_s >= took["s"]
+    text = urllib.request.urlopen(
+        f"http://127.0.0.1:{srv.http.port}/metrics", timeout=10).read().decode()
+    for name in ("filodb_shard_lock_wait_seconds",
+                 "filodb_shard_lock_hold_seconds"):
+        line = next(ln for ln in text.splitlines()
+                    if ln.startswith(name + "{") and 'shard="0"' in ln)
+        assert float(line.rsplit(" ", 1)[1]) > 0, line
+
+
+def test_gc_hook_records_full_collections_and_leaves_with_the_server(served):
+    srv, _get = served
+    assert tracer._on_gc in gc.callbacks
+    gc.collect()                            # settle what start-up left
+    tracer.drain()
+    gc.collect()
+    gc.collect(1)                           # a younger generation: no span
+    spans = [s for s in tracer.snapshot() if s.name == SPAN_RUNTIME_GC]
+    assert len(spans) == 1 and spans[0].tags["collected"] >= 0
+    assert spans[0].duration_us > 0
+    srv.shutdown()
+    assert tracer._on_gc not in gc.callbacks
+    tracer.drain()
+    gc.collect()
+    assert not [s for s in tracer.snapshot() if s.name == SPAN_RUNTIME_GC]
