@@ -10,7 +10,8 @@ production shard does. This rule makes the contract structural: inside any
 module whose basename matches ``index*.py`` (fixture twins carry a
 ``bad_``/``good_`` prefix), a ``for`` statement or comprehension whose
 ITERABLE mentions a posting identifier (any name or attribute containing
-"posting", or a ``.tolist()`` of one) is a finding. Loops over terms,
+"posting" or "pid_col" — the postings' pid half — or a ``.tolist()`` of
+one) is a finding. Loops over terms,
 staged segment lists, or trigram codes are fine — only the posting arrays
 themselves are ops-only."""
 
@@ -28,7 +29,7 @@ _INDEX_MODULE = re.compile(
     r"(?:^|/)core/index[^/]*\.py$"
     r"|(?:^|/)fixtures/filolint/(?:bad_|good_)index[^/]*\.py$")
 
-_POSTING = re.compile("posting", re.IGNORECASE)
+_POSTING = re.compile("posting|pid_col", re.IGNORECASE)
 
 
 def _mentions_postings(expr: ast.expr) -> str | None:

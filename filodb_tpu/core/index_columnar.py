@@ -23,6 +23,11 @@ This module is the numpy equivalent, sized for the 1M-series-per-shard bar
     regex. A 1M-distinct-value label answers ``=~"checkout-.*"`` by looking
     at the handful of terms containing ``che``/``hec``/... instead of
     running the regex a million times.
+  * group ids — ``LabelPostings.dense_vids`` scatters one label's CSR into a
+    dense ``pid -> vid`` column; ``combine_codes`` folds several such columns
+    into one code per series and ``first_appearance_ids`` numbers the codes
+    the way a walk over the series would. A ``by (g)`` over 1M series is a
+    gather and a table lookup, and only the G group keys become objects.
 
 CONTRACT (enforced by filolint's ``index-pure-python-postings`` rule over
 ``core/index*.py`` modules): posting arrays are only ever touched by
@@ -43,6 +48,8 @@ _EMPTY_I64 = np.empty(0, np.int64)
 
 _PID_MASK = np.uint64(0xFFFFFFFF)
 _SHIFT = np.uint64(32)
+# dense pid -> vid columns: "this series has no such label"
+NO_VID = -1
 
 # numpy >= 2.0 has a native vectorized popcount; older builds fall back to
 # an unpackbits sum (same result, more memory traffic)
@@ -332,6 +339,17 @@ class LabelPostings:
         self._postings = self._postings[keep]
         self._reindex()
 
+    def dense_vids(self, nbits: int) -> np.ndarray:
+        """Dense ``pid -> vid`` int32 column over ``[0, nbits)``, ``NO_VID``
+        where the series carries no such label: ONE scatter of the CSR's
+        per-term vids through the pid column (a live pid holds at most one
+        term of a label, so the scatter never collides)."""
+        self.fold()
+        col = np.full(int(nbits), NO_VID, np.int32)
+        col[self._pid_col] = np.repeat(self._term_vids.astype(np.int32),
+                                       np.diff(self._term_offs))
+        return col
+
     def remap_vids(self, vid_map: np.ndarray) -> None:
         """Renumber term vids through ``vid_map`` (old vid -> new vid, -1
         drops) — the arena-compaction hook; one gather + one sort."""
@@ -345,6 +363,64 @@ class LabelPostings:
         keys = np.sort(keys[new >= 0])
         self._postings = keys
         self._reindex()
+
+
+# ---------------------------------------------------------------------------
+# Group ids from dense label columns (the by/without aggregation plane).
+# ---------------------------------------------------------------------------
+
+# a combined code must stay an exact int64: past this the running code is
+# compacted to dense ids before the next label joins
+_CODE_LIMIT = 1 << 62
+
+
+def combine_codes(vid_cols: list[np.ndarray],
+                  radices: list[int]) -> tuple[np.ndarray, int]:
+    """Mixed-radix fold of per-label vid columns (one int32 array per label,
+    ``NO_VID`` = absent) into ONE int64 code per row: rows get equal codes
+    iff they agree on every label, absence included. Returns (codes, space)
+    with every code in ``[0, space)``; ``radices[j]`` is label j's pool size
+    + 1. Where the product would pass 63 bits the running code is first
+    compacted to dense ids by one ``np.unique`` — the row-wise unique, taken
+    in stages."""
+    codes = vid_cols[0].astype(np.int64)
+    codes += 1
+    space = radices[0]
+    for col, radix in zip(vid_cols[1:], radices[1:]):
+        if space * radix > _CODE_LIMIT:
+            uniq, codes = np.unique(codes, return_inverse=True)
+            space = len(uniq)
+        codes *= radix
+        codes += col
+        codes += 1
+        space *= radix
+    return codes, space
+
+
+def first_appearance_ids(codes: np.ndarray,
+                         space: int) -> tuple[np.ndarray, np.ndarray]:
+    """Dense int32 ids for ``codes`` (ints in ``[0, space)``), numbered in
+    order of FIRST APPEARANCE — the numbering a dict walk over the rows
+    gives — plus each id's first row. A code space a few times the row count
+    goes through a presence table, a gather and one ``minimum.at`` (no
+    sort); a wide one through ``np.unique``."""
+    n = len(codes)
+    if space <= max(4 * n, 1 << 16):
+        present = np.zeros(space, bool)
+        present[codes] = True
+        distinct = np.flatnonzero(present)
+        lut = np.zeros(space, np.int32)
+        lut[distinct] = np.arange(len(distinct), dtype=np.int32)
+        dense = lut[codes]
+        first = np.full(len(distinct), n, np.int64)
+        np.minimum.at(first, dense, np.arange(n, dtype=np.int64))
+    else:
+        _, first, dense = np.unique(codes, return_index=True,
+                                    return_inverse=True)
+    order = np.argsort(first, kind="stable")
+    rank = np.empty(len(order), np.int32)
+    rank[order] = np.arange(len(order), dtype=np.int32)
+    return rank[dense], first[order]
 
 
 # ---------------------------------------------------------------------------

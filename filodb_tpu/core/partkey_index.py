@@ -30,7 +30,8 @@ from collections import Counter
 import numpy as np
 
 from .filters import Equals, EqualsRegex, Filter, In, NotEquals, NotEqualsRegex
-from .index_columnar import LabelPostings, SelectionBitmap, TrigramIndex
+from .index_columnar import (LabelPostings, SelectionBitmap, TrigramIndex,
+                             combine_codes, first_appearance_ids)
 
 _EMPTY = np.empty(0, dtype=np.int32)
 
@@ -145,6 +146,10 @@ class PartKeyIndex:
         self._postings_epoch: list[int] = []
         self._regex_union_cache: dict[tuple[str, str],
                                       tuple[int, int, np.ndarray]] = {}
+        # name_id -> (postings epoch, dense pid -> vid column): what
+        # group_ids gathers from; rebuilt when the label's postings change
+        # or the pid space grows
+        self._vid_cols: dict[int, tuple[int, np.ndarray]] = {}
         # whole-filter-set result cache (the Lucene QueryCache analog:
         # dashboards re-issue identical filter sets every refresh). Keyed by
         # the filter tuple, validated against a global index epoch that bumps
@@ -641,6 +646,50 @@ class PartKeyIndex:
             result = np.setdiff1d(result, neg, assume_unique=True)
         return result
 
+    def _vid_column(self, nid: int) -> np.ndarray:
+        """Label ``nid``'s dense ``pid -> vid`` column over the whole pid
+        space (``NO_VID`` = the series lacks the label), cached against the
+        label's postings epoch."""
+        hit = self._vid_cols.get(nid)
+        if (hit is not None and hit[0] == self._postings_epoch[nid]
+                and len(hit[1]) == len(self._off)):
+            return hit[1]
+        col = self._cols[nid].dense_vids(len(self._off))
+        self._vid_cols[nid] = (self._postings_epoch[nid], col)
+        return col
+
+    def group_ids(self, pids: np.ndarray, by=(), without=()) \
+            -> tuple[np.ndarray, list[tuple[tuple[str, str], ...]]]:
+        """Aggregation groups of the series ``pids`` for ``by``/``without``
+        (``_metric_`` never groups): (int32 group id per pid, the G groups'
+        sorted label pairs). Ids count up in order of first appearance in
+        ``pids``, as a dict walk over the series' keys numbers them; a series
+        that lacks a grouping label joins the group whose key omits it.
+        Gathers and table lookups over the interned label columns — only the
+        G group keys are decoded to strings."""
+        pids = np.asarray(pids)
+        if not len(pids):
+            return np.empty(0, np.int32), []
+        if by:
+            names = sorted(n for n in set(by)
+                           if n in self._name_id and n != "_metric_")
+        else:
+            drop = set(without) | {"_metric_"}
+            names = sorted(n for n in self._name_id if n not in drop)
+        if not names:
+            return np.zeros(len(pids), np.int32), [()]
+        nids = [self._name_id[n] for n in names]
+        vid_cols = [self._vid_column(nid)[pids] for nid in nids]
+        codes, space = combine_codes(
+            vid_cols, [len(self._val_pool[nid]) + 1 for nid in nids])
+        gids, first = first_appearance_ids(codes, space)
+        # each group's key, read at its first series: G rows, never S
+        pools = [self._val_pool[nid] for nid in nids]
+        rows = np.stack([v[first] for v in vid_cols], axis=1).tolist()
+        return gids, [tuple((name, pool[vid]) for name, pool, vid
+                            in zip(names, pools, row) if vid >= 0)
+                      for row in rows]
+
     def part_ids_ended_before(self, ts: int) -> np.ndarray:
         """For purge (ref: PartKeyLuceneIndex.partIdsEndedBefore)."""
         ends = self._end.view()
@@ -722,6 +771,7 @@ class PartKeyIndex:
         self._pool_blob.clear()
         self._regex_cache.clear()
         self._regex_union_cache.clear()
+        self._vid_cols.clear()
         return True
 
     def _label_value_counter(self, label: str, filters, start_time,

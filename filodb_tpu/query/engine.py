@@ -40,7 +40,7 @@ from ..utils.tracing import (SPAN_QUERY, SPAN_QUERY_ADMIT,
                              SPAN_QUERY_PLAN, SPAN_QUERY_SELECT, span,
                              tracer)
 from . import logical as L
-from .exec import QueryContext, group_keys_of
+from .exec import QueryContext, count_groupids
 from .planner import QueryPlanner
 from .rangevector import (QueryError, QueryResult, QueryStats,
                           RangeVectorKey, ResultMatrix)
@@ -1276,13 +1276,21 @@ class QueryEngine:
                         g[pids] = 0
                         uniq.setdefault(RangeVectorKey(()), 0)
                     else:
-                        with span(SPAN_QUERY_GROUPIDS,
-                                  keys=len(pids)) as walk:
-                            keys = [sh.rv_key_of(int(p)) for p in pids]
-                            for p, gk in zip(pids, group_keys_of(
-                                    keys, plan.by, plan.without)):
-                                g[p] = uniq.setdefault(gk, len(uniq))
-                            walk["groups"] = len(uniq)
+                        # the shard's own groups from its label columns
+                        # (vid pools are per shard), then G keys — not the
+                        # series — mapped onto the shared numbering
+                        with span(SPAN_QUERY_GROUPIDS, keys=len(pids),
+                                  route="index") as tags:
+                            local, groups = sh.index.group_ids(
+                                pids, plan.by, plan.without)
+                            shared = np.fromiter(
+                                (uniq.setdefault(RangeVectorKey(gk),
+                                                 len(uniq))
+                                 for gk in groups),
+                                np.int32, count=len(groups))
+                            g[pids] = shared[local]
+                            tags["groups"] = len(uniq)
+                        count_groupids("index")
                 gids_list.append(g)
             if not uniq:
                 self._set_path(ctx, "mesh-empty")

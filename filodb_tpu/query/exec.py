@@ -30,6 +30,7 @@ import numpy as np
 from ..core.filters import Filter
 from ..ops import aggregators, binop, instantfns, rangefns
 from ..utils.diagnostics import lock_wait_ns
+from ..utils.metrics import FILODB_GROUPIDS, registry
 from ..utils.tracing import (SPAN_QUERY_GROUPIDS, SPAN_QUERY_KERNEL,
                              SPAN_QUERY_LEAF, SPAN_QUERY_ODP,
                              SPAN_QUERY_REDUCE, SPAN_QUERY_SELECT, span)
@@ -500,30 +501,52 @@ class LazyKeys:
             keys = [self._shard.rv_key_of(int(p)) for p in self._pids]
         return iter(keys)
 
+    def group_ids(self, by, without):
+        """(group id per selected series, the G group keys) straight from
+        the index's label columns (PartKeyIndex.group_ids): no key of a
+        selected series is materialized. Same guard as reading the keys."""
+        with self._shard.lock:
+            self._check()
+            gids, groups = self._shard.index.group_ids(self._pids, by,
+                                                       without)
+        return gids, [RangeVectorKey(g) for g in groups]
+
+
+def count_groupids(route: str) -> None:
+    registry.counter(FILODB_GROUPIDS, {"route": route}).increment()
+
 
 def _group_ids_for(keys, rows, R, by, without):
     """Dense per-array-row group ids for aggregation: (gids [R], group key
     list, G). Rows outside the selection keep group 0 — harmless, their
-    values are all-NaN / zero-count."""
+    values are all-NaN / zero-count. The source follows the input: a
+    selection that is still pids (``LazyKeys``) groups by the index's label
+    columns, materialized keys (narrow or paged selections, matrices out of
+    joins and functions) by a walk over them."""
     if len(keys) and not by and not without:
         # global aggregation: one group, keys never materialized
         return np.zeros(R, np.int32), [RangeVectorKey(())], 1
-    # the walk: one Python step a selected series, lazy keys materialized
-    with span(SPAN_QUERY_GROUPIDS, keys=len(keys)) as walk:
-        gkeys = group_keys_of(keys, by, without)
-        uniq: dict[RangeVectorKey, int] = {}
-        gid_of_key = np.empty(len(gkeys), np.int32)
-        for i, gk in enumerate(gkeys):
-            gid_of_key[i] = uniq.setdefault(gk, len(uniq))
-        G = walk["groups"] = max(len(uniq), 1)
-    if not gkeys:
+    route = "index" if isinstance(keys, LazyKeys) else "walk"
+    with span(SPAN_QUERY_GROUPIDS, keys=len(keys), route=route) as tags:
+        if route == "index":
+            gid_of_key, uniq = keys.group_ids(by, without)
+        else:
+            seen: dict[RangeVectorKey, int] = {}
+            gid_of_key = np.fromiter(
+                (seen.setdefault(gk, len(seen))
+                 for gk in group_keys_of(keys, by, without)),
+                np.int32, count=len(keys))
+            uniq = list(seen)
+        G = tags["groups"] = max(len(uniq), 1)
+    count_groupids(route)
+    if not len(keys):
         gids = np.zeros(R, np.int32)
     elif rows is None:
         gids = gid_of_key
     else:
         gids = np.zeros(R, np.int32)
         gids[rows] = gid_of_key
-    return gids, list(uniq), G
+    return gids, uniq, G
 
 
 def group_keys_of(keys, by, without):
@@ -995,12 +1018,8 @@ class AggregatePresenter(Transformer):
             return ResultMatrix(data.out_ts, np.stack(rows), keys)
         # full-matrix aggregators
         m = _as_matrix(data)
-        gkeys = group_keys_of(m.keys, self.by, self.without)
-        uniq: dict[RangeVectorKey, int] = {}
-        gids = np.empty(len(gkeys), np.int32)
-        for i, gk in enumerate(gkeys):
-            gids[i] = uniq.setdefault(gk, len(uniq))
-        G = max(len(uniq), 1)
+        gids, uniq, G = _group_ids_for(m.keys, None, m.num_series,
+                                       self.by, self.without)
         if self.operator in ("topk", "bottomk"):
             k = int(self.params[0])
             mask = aggregators.topk_mask(jnp.asarray(m.values), jnp.asarray(gids), _pow2(G),
@@ -1011,9 +1030,10 @@ class AggregatePresenter(Transformer):
             q = float(self.params[0])
             vals = aggregators.group_quantile(jnp.asarray(m.values), jnp.asarray(gids),
                                               _pow2(G), q)
-            return ResultMatrix(m.out_ts, vals[:G], list(uniq))
+            return ResultMatrix(m.out_ts, vals[:G], uniq)
         if self.operator == "count_values":
-            return _count_values(m, gkeys, str(self.params[0]))
+            return _count_values(m, [uniq[g] for g in gids],
+                                 str(self.params[0]))
         raise QueryError(f"unknown aggregator {self.operator}")
 
 

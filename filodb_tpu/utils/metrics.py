@@ -47,6 +47,7 @@ FILODB_SHARD_LOCK_LONG_HOLDS = "filodb_shard_lock_long_holds"
 FILODB_SHARD_LOCK_WAIT_SECONDS = "filodb_shard_lock_wait_seconds"
 FILODB_SHARD_LOCK_HOLD_SECONDS = "filodb_shard_lock_hold_seconds"
 FILODB_LOCK_HOLD_MS = "filodb_lock_hold_ms"
+FILODB_GROUPIDS = "filodb_groupids"
 FILODB_QUERY_LATENCY_MS = "filodb_query_latency_ms"
 FILODB_QUERY_SLOW = "filodb_query_slow"
 FILODB_QUERY_COMPILE_CACHE_HITS = "filodb_query_compile_cache_hits"
@@ -168,6 +169,11 @@ METRICS_SPEC: dict[str, tuple[str, str]] = {
                      "FILODB_LOCK_DEBUG=1 — the runtime twin of filolint's "
                      "live-block-under-lock rule; soak runs alert on "
                      "hold-time regressions the static pass cannot see."),
+    FILODB_GROUPIDS: (
+        "counter", "by/without group-id computations under a shard lock, "
+                   "tagged by route: index = gathers over the part-key "
+                   "index's label columns (a selection still held as pids), "
+                   "walk = one Python step a materialized series key."),
     FILODB_QUERY_LATENCY_MS: (
         "histogram", "End-to-end PromQL latency per dataset; the /metrics "
                      "rendering carries the last query's trace id as an "

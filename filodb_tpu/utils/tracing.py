@@ -130,10 +130,13 @@ TRACE_SPEC: dict[str, str] = {
                      "the thread waited for shard locks inside it).",
     SPAN_QUERY_SELECT: "Index select + array capture of one leaf; per shard "
                        "on the mesh route (tags: shard, series).",
-    SPAN_QUERY_GROUPIDS: "The by/without group-id walk over the selected "
-                         "series' keys, lazy key materialisation included; "
-                         "a global aggregate opens none (tags: keys, "
-                         "groups).",
+    SPAN_QUERY_GROUPIDS: "Group ids of the selected series for a "
+                         "by/without aggregation: from the index's label "
+                         "columns where the selection is still pids "
+                         "(route=index), else one Python step a "
+                         "materialized key (route=walk); a global "
+                         "aggregate opens none (tags: keys, groups, "
+                         "route).",
     SPAN_QUERY_KERNEL: "Host side of one fused kernel: phase=dispatch is "
                        "the call under the shard lock, phase=fetch the "
                        "blocking fetch of its result outside it (dispatch "

@@ -364,6 +364,58 @@ def test_match_selector_regex_validated():
     assert _selector_to_filters('up{job=~"good.*"}')
 
 
+# -- group ids at the 1M bar: counted, not timed ------------------------------
+
+def test_group_ids_for_a_million_pids_build_exactly_g_keys(monkeypatch):
+    """2^20 series in 8 terms: the index answers with 8 label tuples and the
+    query side wraps exactly those — a key an input series would be 2^20
+    RangeVectorKeys. A count, so it holds under any load; the wall-clock
+    story is the chip benchmark's (PERF.md)."""
+    from filodb_tpu.query import exec as qexec
+    from filodb_tpu.query import rangevector
+    n, terms = 1 << 20, 8
+    idx = PartKeyIndex()
+    assert idx.add_part_keys_columnar(
+        np.arange(n), {"_metric_": "m"}, ["g", "host"],
+        [[f"g{i % terms}" for i in range(n)], [f"h{i}" for i in range(n)]],
+        BASE)
+
+    class Shard:                     # what LazyKeys reads of a shard
+        import threading
+        lock = threading.RLock()
+        index = idx
+        slot_epoch = np.zeros(n, np.uint32)
+
+        def rv_key_of(self, pid):
+            raise AssertionError("a series key was materialized")
+
+    built = []
+    real_init = rangevector.RangeVectorKey.__init__
+
+    def counting_init(self, labels):
+        built.append(labels)
+        real_init(self, labels)
+    monkeypatch.setattr(rangevector.RangeVectorKey, "__init__", counting_init)
+    pids = np.arange(n, dtype=np.int32)
+    gids, uniq, G = qexec._group_ids_for(qexec.LazyKeys(Shard(), pids),
+                                         None, n, ("g",), ())
+    assert G == terms and len(built) == terms
+    assert [k.labels for k in uniq] == [(("g", f"g{t}"),)
+                                        for t in range(terms)]
+    assert gids.dtype == np.int32
+    assert np.array_equal(gids, pids % terms)
+    # a permuted half of the pids: first appearance still numbers the groups
+    half = np.random.default_rng(1).permutation(n)[: n // 2].astype(np.int32)
+    del built[:]
+    gids, uniq, G = qexec._group_ids_for(qexec.LazyKeys(Shard(), half),
+                                         None, len(half), ("g",), ())
+    assert len(built) == terms
+    order = [int(k.labels[0][1][1:]) for k in uniq]
+    first_seen = list(dict.fromkeys((half % terms).tolist()))
+    assert order == first_seen
+    assert np.array_equal(np.asarray(order)[gids], half % terms)
+
+
 # -- scale (excluded from tier-1) --------------------------------------------
 
 @pytest.mark.slow

@@ -128,6 +128,41 @@ def test_good_twin_is_clean(name):
         f"{good} must be clean:\n" + "\n".join(f.render() for f in findings))
 
 
+LOOPED_COLUMN_BUILDERS = {
+    "pid-column": "        for i, pid in enumerate(self._pid_col.tolist()):\n"
+                  "            col[pid] = vids[i]\n",
+    "posting-keys": "        for key in self._postings.tolist():\n"
+                    "            col[key & 0xFFFFFFFF] = key >> 32\n",
+    "comprehension": "        col[self._pid_col] = "
+                     "[int(k) >> 32 for k in self._postings]\n",
+}
+
+
+@pytest.mark.parametrize("shape", [None, *sorted(LOOPED_COLUMN_BUILDERS)])
+def test_dense_vid_column_builder_stands_under_the_postings_contract(shape):
+    """``LabelPostings.dense_vids`` (what by/without group ids gather from)
+    is in the rule's scope: as checked in it is one scatter and clean; the
+    same column built one posting at a time is a finding IN that function."""
+    from filodb_tpu.analysis.indexcheck import IndexChecker
+    rel = "filodb_tpu/core/index_columnar.py"
+    src = (REPO / rel).read_text()
+    scatter = ("        col[self._pid_col] = np.repeat("
+               "self._term_vids.astype(np.int32),\n"
+               "                                       "
+               "np.diff(self._term_offs))\n")
+    assert src.count(scatter) == 1
+    if shape is not None:
+        src = src.replace(scatter, "        vids = np.repeat("
+                          "self._term_vids, np.diff(self._term_offs))\n"
+                          + LOOPED_COLUMN_BUILDERS[shape])
+    found = IndexChecker().check_module(rel, ast.parse(src))
+    if shape is None:
+        assert found == [], [f.render() for f in found]
+    else:
+        assert [(f.rule, f.symbol) for f in found] == [
+            ("index-pure-python-postings", "LabelPostings.dense_vids")], found
+
+
 def test_tracer_record_sites_count_as_span_sites():
     """An interval recorded after the fact names its span like an opened
     one: ``tracer.record("literal", ...)`` is flagged, and a declared span

@@ -360,7 +360,8 @@ def test_http_query_is_one_trace_rooted_at_the_request(served):
     assert q["status"] == "ok" and q["exec_path"] \
         == body["stats"]["exec_path"]
     assert by[SPAN_QUERY_SELECT].tags["series"] == N_SERIES
-    assert by[SPAN_QUERY_GROUPIDS].tags == {"keys": N_SERIES, "groups": 4}
+    assert by[SPAN_QUERY_GROUPIDS].tags == {"keys": N_SERIES, "groups": 4,
+                                            "route": "walk"}
     assert by[SPAN_HTTP_RENDER].tags["series"] == 4
     assert by[SPAN_HTTP_RENDER].tags["bytes"] == req.tags["bytes"] > 0
     kernels = [s for s in members if s.name == SPAN_QUERY_KERNEL]
@@ -386,6 +387,51 @@ def test_global_aggregate_opens_no_groupids_span(served):
     get("sum(rate(m[2m]))")
     assert SPAN_QUERY_LEAF in [s.name for s in _trace_of_last_query()]
     assert SPAN_QUERY_GROUPIDS not in [s.name for s in _trace_of_last_query()]
+
+
+def _groupids_counts(srv):
+    with urllib.request.urlopen(
+            f"http://127.0.0.1:{srv.http.port}/metrics", timeout=30) as r:
+        text = r.read().decode()
+    out = {"index": 0.0, "walk": 0.0}
+    for line in text.splitlines():
+        if line.startswith("filodb_groupids_total{"):
+            route = line.split('route="')[1].split('"')[0]
+            out[route] = float(line.rsplit(" ", 1)[1])
+    return out
+
+
+def test_groupids_route_follows_the_selection(served, monkeypatch):
+    """A selection wider than GATHER_THRESHOLD is still pids at the
+    aggregation and groups by the index's label columns; a narrow one has
+    its keys and walks them. The span's tag and the /metrics counter say
+    which."""
+    from filodb_tpu.query import exec as qexec
+    srv, get = served
+    monkeypatch.setattr(qexec, "GATHER_THRESHOLD", N_SERIES // 2)
+    get()                                   # compiles
+    _trace_of_last_query()
+    before = _groupids_counts(srv)
+    tracer.drain()
+    wide = get(shift_ms=1_000)
+    span_w, = [s for s in _trace_of_last_query()
+               if s.name == SPAN_QUERY_GROUPIDS]
+    assert span_w.tags == {"keys": N_SERIES, "groups": 4, "route": "index"}
+    mid = _groupids_counts(srv)
+    assert (mid["index"], mid["walk"]) == (before["index"] + 1,
+                                           before["walk"])
+    tracer.drain()
+    narrow = get('sum by (g)(rate(m{g=~"g0|g1"}[2m]))', shift_ms=2_000)
+    span_n, = [s for s in _trace_of_last_query()
+               if s.name == SPAN_QUERY_GROUPIDS]
+    assert span_n.tags == {"keys": N_SERIES // 2, "groups": 2,
+                           "route": "walk"}
+    after = _groupids_counts(srv)
+    assert (after["index"], after["walk"]) == (mid["index"], mid["walk"] + 1)
+    # the same groups either way, in the same order
+    assert [s["metric"] for s in wide["data"]["result"]][:2] \
+        == [s["metric"] for s in narrow["data"]["result"]] \
+        == [{"g": "g0"}, {"g": "g1"}]
 
 
 def test_spans_are_in_the_profiler_trace_on_its_clock(served, tmp_path):
