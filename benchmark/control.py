@@ -34,14 +34,16 @@ sys.path.insert(0, ROOT)
 import numpy as np                # noqa: E402
 
 
-def bf16_values(seed: int):
-    """The generator's values as one bf16 pass would read them."""
+def to_bf16(v) -> np.ndarray:
     import ml_dtypes
-    from benchmark import datagen
+    return (np.asarray(v).astype(np.float32).astype(ml_dtypes.bfloat16)
+            .astype(np.float64))
 
+
+def bf16_values(seed: int, data, deploy: dict):
+    """The data module's samples as one bf16 pass would read them."""
     def values(sids, cols):
-        v = datagen.counter_np(seed, sids, cols).astype(np.float32)
-        return v.astype(ml_dtypes.bfloat16).astype(np.float64)
+        return to_bf16(data.raw_values(seed, sids, cols, deploy))
     return values
 
 
@@ -50,16 +52,15 @@ def numpy_control(workload: str, seed: int, n_requests: int,
     """answers_err and readback_abs of the bf16 reference against the f64
     reference, over the first ``n_requests`` requests the cell's generator
     deals (one of every query text among them)."""
-    from benchmark import correct, datagen, reference, run, traffic
-    _bench, _cell, deploy, mix = run.load_cell(workload)
+    from benchmark import correct, run, traffic
+    _bench, _cell, deploy, mix, data = run.load_cell(workload)
     if series:
         deploy["series"] = series
-    iv = int(deploy["scrape_interval_ms"])
     head_col = int(deploy["fill_columns"])
     sids = np.arange(int(deploy["series"]))
-    gen = traffic.Generator(mix, seed, datagen.BASE_TS + head_col * iv)
+    gen = traffic.Generator(mix, seed, data.scrape_ms(head_col, deploy))
     g = deploy["guarantees"]
-    low = bf16_values(seed)
+    low = bf16_values(seed, data, deploy)
     worst, seen = 0.0, set()
     reqs = []
     while len(reqs) < n_requests:
@@ -69,25 +70,18 @@ def numpy_control(workload: str, seed: int, n_requests: int,
             reqs.append(r)
     for r in reqs:
         ref = mix["queries"][r.qi]["ref"]
-        groups = int(deploy["labels"]["groups"])
-        want = reference.evaluate(seed, sids, ref, r.out_ts(), iv, head_col,
-                                  groups)
-        got = reference.evaluate(seed, sids, ref, r.out_ts(), iv, head_col,
-                                 groups, values=low)
+        want = data.evaluate(seed, sids, ref, r.out_ts(), deploy, head_col)
+        got = data.evaluate(seed, sids, ref, r.out_ts(), deploy, head_col,
+                            values=low)
         e = correct.err_ratio(got, want, g["rtol"], g["atol"])
         print(f"control(numpy bf16): {r.promql} "
               f"[{(r.end_ms - r.start_ms) // 1000}s/{r.step_ms // 1000}s] "
               f"err={e:.4g} (limit 1)", flush=True)
         worst = max(worst, e)
-    rng = np.random.default_rng([seed & 0xFFFFFFFF, seed >> 32, 0x4EAD])
-    racks = rng.choice(sids, 2, replace=False) // int(deploy["labels"]["per_rack"])
-    cols = np.arange(head_col - 3, head_col + 1)
     rb = 0.0
-    for rack in racks:
-        per = int(deploy["labels"]["per_rack"])
-        ids = np.arange(rack * per, rack * per + per)
-        rb = max(rb, float(np.abs(low(ids, cols)
-                                  - reference.raw_values(seed, ids, cols)).max()))
+    for p in data.probes(seed, sids, head_col, deploy, 2):
+        for _labels, want in p["want"]:
+            rb = max(rb, float(np.abs(to_bf16(want) - want).max()))
     print(f"control(numpy bf16): answers_err = {worst:.6g} (limit 1), "
           f"readback_abs = {rb:g} (limit 0) -> correct = "
           f"{bool(worst <= 1 and rb <= 0)}", flush=True)

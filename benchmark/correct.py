@@ -9,17 +9,22 @@ readings each was set from):
   reference; a missing or extra series or step counts as infinite. Limit 1:
   the deployment's stated exactness (rtol 2e-4, atol 1e-4).
 - ``readback_abs``: the scrape last acknowledged in the window, read back
-  through a raw selector for seeded racks: worst ``|got - want|``. Limit 0.
+  through the data module's seeded raw-selector probes: worst
+  ``|got - want|``. Limit 0.
 - ``routes_off``: answers of the window whose ``stats.exec_path`` is none
   of the routes the mix expects (or names an interpreted kernel on a TPU).
   Limit 0.
+
+What an answer should be, and what to send to read a scrape back, is the
+deployment's data module's (``benchmark/data/``, passed in as ``data``); a
+mix's ``ref`` goes to it unread.
 """
 
 from __future__ import annotations
 
 import numpy as np
 
-from . import datagen, reference, served
+from . import served
 
 SAMPLE = 4
 
@@ -62,16 +67,14 @@ def sample_answers(records: list, seed: int, k: int = SAMPLE) -> list:
 
 
 def check_answers(picked: list, mix: dict, deploy: dict, seed: int, sids,
-                  head_col: int) -> tuple[float, list]:
+                  head_col: int, data) -> tuple[float, list]:
     g = deploy["guarantees"]
-    iv = int(deploy["scrape_interval_ms"])
     worst, lines = 0.0, []
     for r in picked:
         req = r["req"]
         out_ts = req.out_ts()
-        want = reference.evaluate(seed, sids, mix["queries"][req.qi]["ref"],
-                                  out_ts, iv, head_col,
-                                  int(deploy["labels"]["groups"]))
+        want = data.evaluate(seed, sids, mix["queries"][req.qi]["ref"],
+                             out_ts, deploy, head_col)
         got = served.answer_rows(r["body"], out_ts, req.step_ms)
         e = err_ratio(got, want, g["rtol"], g["atol"])
         worst = max(worst, e)
@@ -82,42 +85,33 @@ def check_answers(picked: list, mix: dict, deploy: dict, seed: int, sids,
 
 
 def readback(port: int, dataset: str, deploy: dict, seed: int, rec: dict,
-             n_racks: int = 2) -> tuple[float, list]:
+             n: int = 2) -> tuple[float, list]:
     """``rec``: the scraper's record of the last container that landed."""
-    iv = int(deploy["scrape_interval_ms"])
-    per = int(deploy["labels"]["per_rack"])
     w = rec["writer"]
-    lo = rec["row"]
-    ids = w.ids[lo:lo + rec["rows"]]
-    rng = np.random.default_rng([seed & 0xFFFFFFFF, seed >> 32, 0x4EAD])
+    ids = w.ids[rec["row"]:rec["row"] + rec["rows"]]
     worst, lines = 0.0, []
-    cols = np.arange(rec["col"] - 3, rec["col"] + 1)
-    out_ts = datagen.BASE_TS + cols * iv
-    for sid in rng.choice(ids, n_racks, replace=False):
-        rack = int(sid) // per
-        r = served.query_range(port, dataset,
-                               f'{deploy["metric"]}{{rack="r{rack}"}}',
-                               int(out_ts[0]), int(out_ts[-1]), iv)
+    for p in w.data.probes(seed, ids, rec["col"], deploy, n):
+        r = served.query_range(port, dataset, p["promql"], p["start_ms"],
+                               p["end_ms"], p["step_ms"])
         if r["code"] != 200:
-            return float("inf"), [f"read-back of rack r{rack}: HTTP {r['code']}"]
-        got = served.answer_rows(r["body"], out_ts, iv)
-        mine = {int(dict(k)["host"][1:]): v for k, v in got.items()}
-        # of the rack's series, those in this container (on a mesh the
-        # others live on other shards, whose containers of this scrape may
-        # not have been sent yet)
-        want_ids = np.intersect1d(np.arange(rack * per, rack * per + per),
-                                  ids).tolist()
-        if not set(want_ids) <= set(mine):
-            return float("inf"), [f"read-back of rack r{rack}: series "
-                                  f"{sorted(mine)}, expected {want_ids}"]
-        want = reference.raw_values(seed, want_ids, cols)
-        gotm = np.stack([mine[i] for i in want_ids])
-        d = np.abs(gotm - want)
-        e = float("inf") if not np.isfinite(d).all() else float(d.max())
+            return float("inf"), [f"read-back of {p['promql']}: HTTP "
+                                  f"{r['code']}"]
+        out_ts = np.arange(p["start_ms"], p["end_ms"] + 1, p["step_ms"])
+        got = [(set(k), v) for k, v in served.answer_rows(
+            r["body"], out_ts, p["step_ms"]).items()]
+        e = 0.0
+        for labels, want in p["want"]:
+            mine = [v for k, v in got if labels.items() <= k]
+            if len(mine) != 1:
+                return float("inf"), [
+                    f"read-back of {p['promql']}: {len(mine)} series with "
+                    f"{labels} among {len(got)} returned"]
+            d = np.abs(mine[0] - want)
+            e = max(e, float(d.max()) if np.isfinite(d).all()
+                    else float("inf"))
         worst = max(worst, e)
-        lines.append(f'{deploy["metric"]}{{rack="r{rack}"}} columns '
-                     f"{cols[0]}..{cols[-1]}: {len(want_ids)} series, "
-                     f"|got-want| max {e:g}")
+        lines.append(f"{p['promql']} stamps {p['start_ms']}..{p['end_ms']}: "
+                     f"{len(p['want'])} series, |got-want| max {e:g}")
     return worst, lines
 
 

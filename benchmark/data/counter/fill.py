@@ -19,7 +19,7 @@ import functools
 
 import numpy as np
 
-from . import datagen, served
+from . import datagen
 
 
 @functools.lru_cache(maxsize=None)
@@ -48,27 +48,6 @@ def _programs():
         return jnp.where(sid >= 0, c_hi, n).astype(n.dtype)
 
     return fill_val, fill_ts, fill_n
-
-
-def pid_series(shard, ids: np.ndarray, seed: int) -> np.ndarray:
-    """[S] series id held by each store row, -1 for unused rows. Scrape 0
-    registers a shard's series in the order published; a seeded sample of
-    rows is checked against the index's own labels."""
-    st = shard.store
-    if shard.num_series != len(ids):
-        raise RuntimeError(f"shard {shard.shard_num}: registered "
-                           f"{shard.num_series} of {len(ids)} series")
-    sid = np.full(st.S, -1, np.int64)
-    sid[:len(ids)] = ids
-    rng = np.random.default_rng(seed)
-    rows = np.unique(np.concatenate(
-        [[0, len(ids) - 1], rng.integers(0, len(ids), 254)]))
-    for p in rows:
-        host = shard.index.labels_of(int(p)).get("host")
-        if host != f"h{sid[p]}":
-            raise RuntimeError(f"shard {shard.shard_num}: row {p} holds "
-                               f"{host}, expected h{sid[p]}")
-    return sid
 
 
 def fill_history(shard, sid: np.ndarray, seed: int, fill_cols: int,
@@ -119,54 +98,3 @@ def check_filled(shard, sid: np.ndarray, fill_cols: int, iv: int) -> None:
             f"shard {shard.shard_num}: store not as the write path would "
             f"have left it: grid_ok={st.grid_ok} grid_info={st.grid_info()} "
             f"n_host={np.unique(st.n_host[live])}")
-
-
-def build(srv, deploy: dict, seed: int) -> dict:
-    """Register, fill and check every shard. Returns {"writers", "sids"
-    (sorted, the series written), "sid_of" {shard: [S]}, "seconds" {...}}."""
-    import time
-    nsh = int(deploy["server"]["num_shards"])
-    per = int(deploy["server"]["store"]["max_series_per_shard"])
-    n_series = int(deploy["series"])
-    iv = int(deploy["scrape_interval_ms"])
-    fill_cols = int(deploy["fill_columns"])
-    dataset = srv.config["dataset"]
-    t0 = time.perf_counter()
-    if nsh == 1:
-        ids_of = [np.arange(n_series)]
-    else:
-        # hashing spreads the series a little unevenly; a shard holds
-        # ``per`` at most, so the overflow of the fuller shards is not
-        # written (nor counted in the reference)
-        owner = served.owners(srv, n_series, deploy)
-        ids_of = [np.flatnonzero(owner == sh)[:per] for sh in range(nsh)]
-    writers = [served.Writer(srv, sh, ids_of[sh], deploy) for sh in range(nsh)]
-    t1 = time.perf_counter()
-    for w in writers:
-        for j in range(len(w.templates)):
-            w.publish(j, 0, seed)
-    for w in writers:
-        w.drain()
-    t2 = time.perf_counter()
-    sid_of = {}
-    homes = set()
-    for w in writers:
-        sid = pid_series(w.shard, w.ids, seed)
-        fill_history(w.shard, sid, seed, fill_cols, iv)
-        check_filled(w.shard, sid, fill_cols, iv)
-        sid_of[w.shard_num] = sid
-        homes |= set(w.shard.store.val.devices())
-        if w.shard.store.ts.devices() != w.shard.store.val.devices():
-            raise RuntimeError(f"shard {w.shard_num}: ts/val on two devices")
-    if len(homes) != nsh:
-        raise RuntimeError(f"{nsh} shards sit on {len(homes)} device(s)")
-    t3 = time.perf_counter()
-    written = np.sort(np.concatenate([w.ids for w in writers]))
-    served.log(f"fill: {len(written)} of {n_series} series over {nsh} "
-               f"shard(s) {[len(w.ids) for w in writers]}; templates "
-               f"{t1 - t0:.1f} s, registration (scrape 0 through the write "
-               f"path) {t2 - t1:.1f} s, {fill_cols - 1} columns on the "
-               f"device {t3 - t2:.1f} s; dataset {dataset}")
-    return {"writers": writers, "sids": written, "sid_of": sid_of,
-            "seconds": {"templates": t1 - t0, "registration": t2 - t1,
-                        "device_fill": t3 - t2}}

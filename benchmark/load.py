@@ -8,7 +8,7 @@ from __future__ import annotations
 import threading
 import time
 
-from . import datagen, served
+from . import served
 
 
 class Scraper(threading.Thread):
@@ -54,7 +54,7 @@ class Scraper(threading.Thread):
 
 class LagPoller(threading.Thread):
     """Every 10 ms: which acknowledged containers does the store hold now?
-    (``n_host`` of a container's first row has passed its column.)"""
+    (The data module says whether a container's first row has its scrape.)"""
 
     def __init__(self, scraper: Scraper):
         super().__init__(name="bench-lag", daemon=True)
@@ -66,7 +66,8 @@ class LagPoller(threading.Thread):
         now = time.perf_counter()
         for rec in list(self.scraper.sent):
             if rec["landed"] is None:
-                if rec["writer"].shard.store.n_host[rec["row"]] > rec["col"]:
+                w = rec["writer"]
+                if w.data.landed(w.shard, rec["row"], rec["col"]):
                     rec["landed"] = now
                 else:
                     pending += 1

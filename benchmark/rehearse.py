@@ -2,20 +2,25 @@
 """The CPU rehearsal: every part of a cell at a tiny size, no chip.
 
     JAX_PLATFORMS=cpu python3 benchmark/rehearse.py [--cell NAME ...] [--seconds S]
+                                                    [--no-double]
 
 It checks, and prints as counts only (never the contract's last line, never
 a time or a rate under a metric's name):
 
-1. generator parity: ``datagen.counter`` in numpy and in ``jax.numpy`` give
-   the same integers;
-2. the plain reference against the repo's golden model
+1. generator parity: ``counter``'s ``datagen.counter`` in numpy and in
+   ``jax.numpy`` give the same integers;
+2. ``counter``'s plain reference against the repo's golden model
    (``tests/prom_reference.py``), series by series on a sample;
 3. the trace reduction against the small recorded trace in ``fixtures/``;
 4. for each cell: the whole of ``run.run`` with the device check stubbed
    here — server, registration, device fill and its invariants (on 4
    virtual devices for a mesh cell), warm-up, load generators, live
    ingest, window, reference comparison, layer readers — at a few thousand
-   series with interpreted kernels.
+   series with interpreted kernels;
+5. the same pass for a deployment that is not the benchmark's: the test
+   double of ``benchmark/tests/double/`` (another data module, configuration
+   and mix) is dry-added, as new files and entries only, to a scratch copy
+   of the by-name files, and its cell runs from there.
 """
 
 from __future__ import annotations
@@ -23,7 +28,9 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import shutil
 import sys
+import tempfile
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -39,7 +46,7 @@ def generator_parity() -> int:
     import filodb_tpu  # noqa: F401 — turns x64 on, as the server does
     import jax
     import jax.numpy as jnp
-    from benchmark import datagen
+    from benchmark.data.counter import datagen
     n = 0
     for seed in (0, 7, 2**31 + 12345, 2**33 + 1):
         s, c = np.arange(2048), np.arange(768)
@@ -56,7 +63,7 @@ def generator_parity() -> int:
 
 
 def reference_tie(seed: int = 5) -> int:
-    from benchmark import datagen, reference
+    from benchmark.data.counter import datagen, reference
     from tests import prom_reference as pr
     iv, head, S = 10_000, 719, 64
     sids = np.arange(100, 100 + S)
@@ -99,14 +106,46 @@ def trace_fixture() -> dict:
     return got
 
 
-def rehearse_cell(name: str, seconds: float, seed: int, trace: int) -> dict:
+DOUBLE = os.path.join(HERE, "tests", "double")
+BY_NAME = ("configs", "traffic", "data", "layers")
+
+
+def dry_add(double: str, root: str) -> list[str]:
+    """What a ``model_config`` PR does, in the scratch directory ``root``:
+    the benchmark's by-name files and BENCHMARK.json as they are, plus the
+    files under ``double`` and its ``entries.json`` — nothing that exists
+    is edited. Returns the names of the cells added."""
+    home = os.path.join(root, os.path.basename(HERE))
+    for d in BY_NAME:
+        shutil.copytree(os.path.join(HERE, d), os.path.join(home, d),
+                        ignore=shutil.ignore_patterns("__pycache__"))
+        if os.path.isdir(os.path.join(double, d)):
+            for f in os.listdir(os.path.join(double, d)):
+                if os.path.exists(os.path.join(home, d, f)):
+                    raise RuntimeError(f"{d}/{f} exists: a deployment adds "
+                                       f"files, it edits none")
+                shutil.copy(os.path.join(double, d, f),
+                            os.path.join(home, d, f))
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    with open(os.path.join(double, "entries.json")) as f:
+        add = json.load(f)
+    for kind in ("configs", "workloads"):
+        bench[kind] += add[kind]
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+    return [w["name"] for w in add["workloads"]]
+
+
+def rehearse_cell(name: str, seconds: float, seed: int, trace: int,
+                  root: str = ROOT) -> dict:
     from benchmark import run as runmod
     args = argparse.Namespace(workload=name, seed=seed, seconds=seconds,
                               trace=trace)
     stub = {"platform": "cpu-rehearsal", "kind": "TPU v5 lite",
-            "count": runmod.chips_of(name)}
+            "count": runmod.chips_of(name, root)}
     res = runmod.run(args, stub, allow_interpret=True,
-                     shrink={"series": 4096})
+                     shrink={"series": 4096}, root=root)
     assert res is not None, f"{name}: set-up refused the system"
     assert res["correct"] is True, f"{name}: correct came out false"
     return {"attempted": res["attempted"], "failed": res["failed"],
@@ -120,6 +159,7 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=2**31 + 99)
     ap.add_argument("--trace", type=int, default=0)
     ap.add_argument("--skip-units", action="store_true")
+    ap.add_argument("--no-double", action="store_true")
     a = ap.parse_args()
     if not a.skip_units:
         print(f"generator parity: {generator_parity()} values equal")
@@ -130,6 +170,14 @@ def main() -> int:
         names = [w["name"] for w in json.load(f)["workloads"]]
     for name in a.cell or names:
         print(f"cell {name}: {rehearse_cell(name, a.seconds, a.seed, a.trace)}")
+    if not a.no_double and not a.cell:
+        root = tempfile.mkdtemp(prefix="filobench_double_")
+        try:
+            for name in dry_add(DOUBLE, root):
+                print(f"dry-added cell {name}: "
+                      f"{rehearse_cell(name, a.seconds, a.seed, a.trace, root)}")
+        finally:
+            shutil.rmtree(root, ignore_errors=True)
     print("rehearsal passed (counts only; nothing here is a device number)")
     return 0
 

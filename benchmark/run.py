@@ -11,9 +11,17 @@ against the plain reference, and prints the contract's JSON object as the
 last line of stdout. Without a TPU (or with fewer chips than the cell asks
 for) it prints no such line and exits non-zero.
 
-Everything that belongs to one configuration, one traffic mix or one
-per-layer metric is a file of its own, found by the name in BENCHMARK.json:
-``configs/<config>.json``, ``traffic/<traffic>.json``, ``layers/<metric>.py``.
+Everything that belongs to one configuration, one traffic mix, one
+per-layer metric or one kind of data is a file of its own, found by name:
+``configs/<config>.json``, ``traffic/<traffic>.json`` and
+``layers/<metric>.py`` by the names in BENCHMARK.json, and
+``data/<name>.py`` by the ``"data"`` key of the configuration's file: the
+module that says what the deployment's series, scrapes, device-resident
+history, reference answers, read-back probe and needed bytes are
+(``benchmark/data/__init__.py`` documents its interface). This file and
+``served.py``, ``load.py``, ``correct.py``, ``traffic.py`` know none of
+that themselves, so a deployment with another kind of store is added as
+new files only.
 """
 
 from __future__ import annotations
@@ -38,6 +46,11 @@ sys.path.insert(0, ROOT)
 import numpy as np                # noqa: E402
 
 DRAIN_S = 120.0                   # in-flight queries and containers may take this long
+# The profiler's trace of ONE chip over a 51 s window is whole (12-13 MB);
+# of four chips over 51 s (37 MB) it has lost seconds of a chip's events
+# (PERF.md §5, PR 26). Its volume grows with chips x seconds, so a cell on
+# n chips is traced for the last 51 / n seconds of its window.
+TRACE_CHIP_SECONDS = 51.0
 
 
 def find_device(chips: int) -> dict:
@@ -77,8 +90,18 @@ class CompileClock:
         return self.compiles, self.cache_hits, self.seconds
 
 
-def load_cell(name: str) -> tuple[dict, dict, dict, dict]:
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+def home_of(root: str) -> str:
+    """The benchmark's directory under ``root``: where files are found by name."""
+    return os.path.join(root, os.path.basename(HERE))
+
+
+def load_cell(name: str, root: str = ROOT) -> tuple:
+    """(BENCHMARK.json, the cell's entry, its configuration's file, its
+    mix, its data module), each found by name under ``root`` — the checkout,
+    or the scratch copy in which the rehearsal dry-adds a deployment. No JAX
+    is touched: a name with no file stops the run before the device check."""
+    home = home_of(root)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -86,15 +109,21 @@ def load_cell(name: str) -> tuple[dict, dict, dict, dict]:
                          f"has {sorted(cells)}")
     cell = cells[name]
     conf = {c["name"]: c for c in bench["configs"]}[cell["config"]]
-    with open(os.path.join(ROOT, conf["file"])) as f:
+    with open(os.path.join(root, conf["file"])) as f:
         deploy = json.load(f)
-    from benchmark import traffic
-    return bench, cell, deploy, traffic.load(cell["traffic"])
+    from benchmark import data, traffic
+    data_home = os.path.join(home, "data")
+    if "data" not in deploy:
+        raise SystemExit(f"benchmark: {conf['file']} names no data module "
+                         f"under \"data\"; {data_home} has "
+                         f"{data.names(data_home)}")
+    return (bench, cell, deploy, traffic.load(cell["traffic"], home),
+            data.load(deploy["data"], data_home))
 
 
-def chips_of(workload: str) -> int:
+def chips_of(workload: str, root: str = ROOT) -> int:
     """Chips the cell asks for (no JAX touched: the device check needs it)."""
-    return int(load_cell(workload)[1]["chips"])
+    return int(load_cell(workload, root)[1]["chips"])
 
 
 def metrics_of(bench: dict, cell: dict, kind: str) -> list[dict]:
@@ -102,8 +131,8 @@ def metrics_of(bench: dict, cell: dict, kind: str) -> list[dict]:
             if "workloads" not in m or cell["name"] in m["workloads"]]
 
 
-def load_layer(name: str):
-    path = os.path.join(HERE, "layers", f"{name}.py")
+def load_layer(name: str, home: str = HERE):
+    path = os.path.join(home, "layers", f"{name}.py")
     spec = importlib.util.spec_from_file_location(f"benchmark.layers.{name}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
@@ -163,15 +192,15 @@ def set_up(s: dict, deploy: dict, mix: dict, seed: int, run_dir: str,
     write path, the exact read-back, the warm-up. Everything the window
     needs goes into ``s`` as it comes to be, the server first, so that the
     caller can stop it whatever happens after."""
-    from benchmark import correct, datagen, fill, load, served, traffic
+    from benchmark import correct, load, served, traffic
     log = served.log
-    iv = int(deploy["scrape_interval_ms"])
+    data = s["data"]
     fill_cols = int(deploy["fill_columns"])
     t = time.perf_counter()
     srv = s["srv"] = served.start_server(deploy, run_dir)
     s.update(dataset=srv.config["dataset"], port=srv.http.port)
     t_server = time.perf_counter() - t
-    built = fill.build(srv, deploy, seed)
+    built = served.build(srv, deploy, seed, data)
     s.update(writers=built["writers"], sids=built["sids"])
 
     # the first live scrape goes through the write path, unpaced, and must
@@ -183,11 +212,12 @@ def set_up(s: dict, deploy: dict, mix: dict, seed: int, run_dir: str,
     for w in s["writers"]:
         w.drain()
         sid = built["sid_of"][w.shard_num]
-        if not (w.shard.store.n_host[sid >= 0] == fill_cols + 1).all():
+        if not data.landed(w.shard, np.flatnonzero(sid >= 0),
+                           fill_cols).all():
             raise Refused(f"shard {w.shard_num}: the first live scrape did "
                           f"not land in column {fill_cols}")
     s["head_col"] = fill_cols
-    s["head_ms"] = datagen.BASE_TS + fill_cols * iv
+    s["head_ms"] = data.scrape_ms(fill_cols, deploy)
     last = s["writers"][-1]
     probe = {"writer": last, "row": 0, "col": fill_cols,
              "rows": last.templates[0][1]}
@@ -252,11 +282,12 @@ class GcWatch:
 
 
 def measure(s: dict, deploy: dict, mix: dict, seed: int, seconds: float,
-            trace_dir: str | None, clock) -> dict:
+            trace_dir: str | None, clock, chips: int = 1) -> dict:
     """The window: closed-loop clients over live ingest, for ``seconds``;
     then no new queries, and those in flight and the containers on their
-    way get DRAIN_S to arrive. With ``trace_dir``: the profiler's trace of
-    the window and the program's spans."""
+    way get DRAIN_S to arrive. With ``trace_dir``: the program's spans of
+    the whole window, and the profiler's trace of its last
+    ``TRACE_CHIP_SECONDS / chips`` seconds (all of it on one chip)."""
     import jax
     from benchmark import load, served, tracedata
     log = served.log
@@ -264,13 +295,19 @@ def measure(s: dict, deploy: dict, mix: dict, seed: int, seconds: float,
     poller = load.LagPoller(scraper)
     spans = SpanDrain() if trace_dir else None
     w = {"sync_perf": None, "spans": spans}
-    if trace_dir:
+    untraced_s = max(0.0, seconds - TRACE_CHIP_SECONDS / chips)
+
+    def start_trace() -> None:
         po = jax.profiler.ProfileOptions()
         po.host_tracer_level, po.python_tracer_level = 1, 0
         jax.profiler.start_trace(trace_dir, profiler_options=po)
         w["sync_perf"] = time.perf_counter()
         with jax.profiler.TraceAnnotation(tracedata.SYNC):
             time.sleep(0.002)
+
+    if trace_dir:
+        if not untraced_s:
+            start_trace()
         spans.tracer.drain()
         spans.start()
     scraper.start()
@@ -281,6 +318,9 @@ def measure(s: dict, deploy: dict, mix: dict, seed: int, seconds: float,
     w["setup_s"] = t0 - _T_PROC
     clients = s["clients"]
     clients.start()
+    if trace_dir and untraced_s:
+        time.sleep(max(0.0, untraced_s - (time.perf_counter() - t0)))
+        start_trace()
     time.sleep(max(0.0, seconds - (time.perf_counter() - t0)))
     t1 = w["t1"] = time.perf_counter()
     in_flight = clients.stop(DRAIN_S)
@@ -352,15 +392,16 @@ def end_to_end(w: dict) -> dict:
 
 
 def decide_correct(s: dict, w: dict, deploy: dict, mix: dict, seed: int,
-                   allow_interpret: bool) -> bool:
-    """Each number compared, printed beside its limit (benchmark/correct.py)."""
+                   allow_interpret: bool) -> tuple[bool, dict]:
+    """(correct, {name: {"value", "limit"}}): each number compared, printed
+    beside its limit (benchmark/correct.py)."""
     from benchmark import correct
     from benchmark.served import log
     t = time.perf_counter()
     g = deploy["guarantees"]
     picked = correct.sample_answers(w["ok"], seed)
     err, lines = correct.check_answers(picked, mix, deploy, seed, s["sids"],
-                                       s["head_col"])
+                                       s["head_col"], s["data"])
     for ln in lines:
         log(f"compared: {ln}")
     if w["landed"]:
@@ -373,7 +414,7 @@ def decide_correct(s: dict, w: dict, deploy: dict, mix: dict, seed: int,
     off, seen = correct.routes_off(w["ok"], mix["expect_routes"],
                                    allow_interpret)
     log(f"routes: {seen}")
-    good = bool(picked)
+    good, numbers = bool(picked), {}
     for name, val, lim, what in (
             ("answers_err", err, 1.0, f"{len(picked)} answers of the window "
              f"against the f64 reference, in units of atol {g['atol']} + "
@@ -384,8 +425,9 @@ def decide_correct(s: dict, w: dict, deploy: dict, mix: dict, seed: int,
              "expect")):
         log(f"correct: {name} = {val:.6g} (limit {lim:g}) — {what}")
         good &= bool(val <= lim)
+        numbers[name] = {"value": min(float(val), 1e308), "limit": lim}
     log(f"reference and comparison took {time.perf_counter() - t:.1f} s")
-    return good
+    return good, numbers
 
 
 def per_layer(bench: dict, cell: dict, s: dict, w: dict, deploy: dict,
@@ -394,6 +436,7 @@ def per_layer(bench: dict, cell: dict, s: dict, w: dict, deploy: dict,
     """(metrics, device fields, breakdown) of the traced run: each metric
     from its own reader, ``benchmark/layers/<name>.py``."""
     from benchmark import tracedata
+    from benchmark.layers import _kernels
     from benchmark.served import log
     with open(os.path.join(HERE, "peaks.json")) as f:
         peaks = json.load(f)
@@ -411,41 +454,69 @@ def per_layer(bench: dict, cell: dict, s: dict, w: dict, deploy: dict,
         return tr["sync_ns"] + (perf_t - w["sync_perf"]) * 1e9
 
     w0, w1 = to_ns(t0), to_ns(t1)
+    tw0 = max(w0, tr["sync_ns"])      # the traced part of the window
     wall_to_perf = t0 - w["wall0"]
     spans = [{"name": x.name, "trace_id": x.trace_id,
               "t0": x.start_us / 1e6 + wall_to_perf,
               "dur_s": x.duration_us / 1e6, "tags": dict(x.tags)}
              for x in w["spans"].spans]
     spans = [x for x in spans if t0 <= x["t0"] <= t1]
+    # a query that executed ran a device operation between its span's ends:
+    # a chip that shows none while such queries came and went has lost them
+    proofs = [(to_ns(x["t0"]), to_ns(x["t0"] + x["dur_s"])) for x in spans
+              if x["name"] == "query" and x["tags"].get("status") == "ok"
+              and not str(x["tags"].get("exec_path", "")).startswith(
+                  _kernels.CACHE_ANSWERS)]
+    counts = tracedata.event_counts(tr)
+    lost = tracedata.holes(tr, tw0, w1, proofs)
+    log(f"trace: {tr['bytes']} bytes over the window's last "
+        f"{(w1 - tw0) / 1e9:.2f} s; operation events a chip {counts}; "
+        f"{len(proofs)} executed queries prove the device at work")
+    for a, b in lost:
+        log(f"trace: a chip shows no event for {(b - a) / 1e9:.2f} s from "
+            f"{(a - w0) / 1e9:.2f} s into the window while queries executed: "
+            f"its events are LOST there; that stretch is cut out of every "
+            f"chip's trace and of window_s, not read as idle")
+    tr = tracedata.without(tr, lost)
+    traced_ns = (w1 - tw0) - sum(b - a for a, b in lost)
+
+    def in_trace(perf_t: float) -> bool:
+        t = to_ns(perf_t)
+        return tw0 <= t <= w1 and not any(a <= t <= b for a, b in lost)
+
     writers = s["writers"]
     ctx = {"records": w["ok"], "done_in": w["done_in"], "spans": spans,
-           "trace": tr, "w0_ns": w0, "w1_ns": w1,
+           "trace": tr, "w0_ns": w0, "w1_ns": w1, "tw0_ns": tw0,
+           "done_traced": [r for r in w["done_in"] if in_trace(r["t1"])],
            "peaks": peaks, "peak": peaks["devices"][device["kind"]],
-           "deploy": deploy, "mix": mix, "head_col": s["head_col"],
+           "deploy": deploy, "mix": mix, "data": s["data"],
+           "head_col": s["head_col"],
            "rows_per_shard": [int(x.shard.store.S) for x in writers],
            "capacity": int(writers[0].shard.store.C)}
     out = {}
     for m in metrics_of(bench, cell, "per_layer"):
-        v = load_layer(m["name"]).read(ctx)
+        v = load_layer(m["name"], s["home"]).read(ctx)
         if v is None:
             log(f"layer metric {m['name']}: nothing to read")
             continue
         out[m["name"]] = {"value": float(v), "unit": m["unit"]}
         log(f"layer metric {m['name']} = {v:.6g} {m['unit']} [{m['layer']}]")
-    busy = tracedata.busy_seconds(tr, w0, w1)
-    dev = {"busy_s": busy, "window_s": (w1 - w0) / 1e9}
+    busy = tracedata.busy_seconds(tr, tw0, w1)
+    dev = {"busy_s": busy, "window_s": traced_ns / 1e9}
     host = [[x["name"], to_ns(x["t0"]), to_ns(x["t0"] + x["dur_s"])]
             for x in spans]
-    breakdown = {"device_ops": tracedata.top_ops(tr, w0, w1),
-                 "idle_gaps": tracedata.idle_gaps(tr, w0, w1, host)}
-    log(f"trace: {tr['bytes']} bytes, {len(spans)} program spans in the "
-        f"window; device busy {busy:.3f} s of {dev['window_s']:.2f} s (idle "
+    breakdown = {"device_ops": tracedata.top_ops(tr, tw0, w1),
+                 "idle_gaps": tracedata.idle_gaps(tr, tw0, w1, host,
+                                                  cuts=lost)}
+    log(f"trace: {len(spans)} program spans in the window; device busy "
+        f"{busy:.3f} s of {dev['window_s']:.2f} s traced (idle "
         f"{100 * (1 - busy / dev['window_s']):.1f} %)")
     return out, dev, breakdown
 
 
 def run(args, device: dict, allow_interpret: bool = False,
-        shrink: dict | None = None, strict_setup: bool = True) -> dict | None:
+        shrink: dict | None = None, strict_setup: bool = True,
+        root: str = ROOT) -> dict | None:
     """Everything after the device check. Returns the result object, or
     None when set-up found the system not as the cell needs it.
     ``allow_interpret`` and ``shrink`` are the CPU rehearsal's
@@ -453,14 +524,15 @@ def run(args, device: dict, allow_interpret: bool = False,
     the deployment's sizes are replaced by tiny ones. ``strict_setup``
     False is the control's (benchmark/control.py): set-up's exact read-back
     is printed and not enforced, so that the window's own answers get
-    compared under the lower precision."""
+    compared under the lower precision. ``root`` is where the cell's
+    files are found by name (``load_cell``)."""
     import jax
     from benchmark import served
     from filodb_tpu.core import native as partset
     from filodb_tpu.memory import native as codecs
     from filodb_tpu.utils import compilecache
     log = served.log
-    bench, cell, deploy, mix = load_cell(args.workload)
+    bench, cell, deploy, mix, data = load_cell(args.workload, root)
     chips = int(cell["chips"])
     if shrink:
         deploy["series"] = shrink["series"]
@@ -492,32 +564,38 @@ def run(args, device: dict, allow_interpret: bool = False,
     seed = int(args.seed)
     run_dir = tempfile.mkdtemp(prefix="filobench_")
     trace_dir = os.path.join(run_dir, "trace") if args.trace else None
-    s: dict = {"gc": GcWatch()}
+    s: dict = {"gc": GcWatch(), "data": data, "home": home_of(root)}
     try:
         set_up(s, deploy, mix, seed, run_dir, clock, strict_setup)
-        w = measure(s, deploy, mix, seed, args.seconds, trace_dir, clock)
+        w = measure(s, deploy, mix, seed, args.seconds, trace_dir, clock,
+                    chips)
         e2e = end_to_end(w)
         peak = memory_peak(chips)
         log(f"peak HBM on the fullest chip: {peak} bytes; "
             f"{host_memory(run_dir)}")
-        good = decide_correct(s, w, deploy, mix, seed, allow_interpret)
+        good, numbers = decide_correct(s, w, deploy, mix, seed,
+                                       allow_interpret)
         log(f"after the reference: {host_memory(run_dir)}")
         result = {"correct": good,
                   "attempted": len(w["recs"]) + len(w["sent"]),
                   "failed": (len(w["recs"]) - len(w["ok"]) + w["in_flight"]
                              + len(w["sent"]) - len(w["landed"]))}
         dev = dict(device, memory_peak_bytes=peak)
+        breakdown = None
         if args.trace:
-            result["metrics"], traced, result["breakdown"] = per_layer(
+            metrics, traced, breakdown = per_layer(
                 bench, cell, s, w, deploy, mix, device, trace_dir)
             dev.update(traced)
         else:
             units = {m["name"]: m["unit"] for m in bench["end_to_end"]}
-            result["metrics"] = {
+            metrics = {
                 m["name"]: {"value": e2e[m["name"]], "unit": units[m["name"]]}
                 for m in metrics_of(bench, cell, "end_to_end")
                 if m["name"] in e2e}
-        result["device"] = dev
+        result.update(metrics=metrics, device=dev)
+        if breakdown is not None:
+            result["breakdown"] = breakdown
+        result["compared"] = numbers      # the contract: it comes last
         return result
     except Refused as e:
         log(f"refused: {e}")
@@ -544,6 +622,9 @@ def main(argv=None) -> int:
         return 1
     sys.stdout.write(json.dumps(result) + "\n")
     sys.stdout.flush()
+    for name, c in result["compared"].items():
+        print(f"compared: {name} = {c['value']:.6g} (limit {c['limit']:g})",
+              file=sys.stderr, flush=True)
     return 0
 
 

@@ -41,7 +41,10 @@ def test_control_bf16_reference_fails_both_numbers():
 def test_sound_run_is_correct():
     res = _run()
     assert res is not None and res["correct"] is True and res["failed"] == 0
-    assert set(res) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(res) == ["correct", "attempted", "failed", "metrics",
+                         "device", "compared"]
+    assert {k: c["limit"] for k, c in res["compared"].items()} == {
+        "answers_err": 1.0, "readback_abs": 0.0, "routes_off": 0.0}
 
 
 def test_answer_altered_where_it_is_rendered(monkeypatch):

@@ -11,6 +11,7 @@ reads a chip run (``rehearse.py``).
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
@@ -106,21 +107,78 @@ def top_ops(tr: dict, t0_ns: float, t1_ns: float, k: int = 10) -> list[list]:
             sorted(acc.items(), key=lambda kv: -kv[1])[:k]]
 
 
-def idle_gaps(tr: dict, t0_ns: float, t1_ns: float, host_spans,
-              k: int = 10) -> list[list]:
-    """The longest idle gaps of the first chip, each named by the host span
-    (``[name, start_ns, end_ns]`` on the trace clock) that covers most of
-    it; "no span" where the program recorded nothing."""
-    planes = device_planes(tr)
-    if not planes:
-        return []
-    iv = union((s, s + d) for _n, s, d in op_events(planes[0])
-               if s + d > t0_ns and s < t1_ns)
+def _gaps(plane: dict, t0_ns: float, t1_ns: float,
+          also_busy=()) -> list[tuple[float, float]]:
+    """Intervals of [t0, t1] in which the chip's op line shows no event
+    (``also_busy``: intervals counted as covered)."""
+    iv = union([(s, s + d) for _n, s, d in op_events(plane)
+                if s + d > t0_ns and s < t1_ns] + list(also_busy))
     gaps, at = [], t0_ns
     for a, b in iv + [(t1_ns, t1_ns)]:
         if a > at:
-            gaps.append((at, a))
+            gaps.append((at, min(a, t1_ns)))
         at = max(at, b)
+    return gaps
+
+
+def event_counts(tr: dict) -> list[int]:
+    """Operation events per chip plane."""
+    return [len(op_events(p)) for p in device_planes(tr)]
+
+
+def holes(tr: dict, t0_ns: float, t1_ns: float, proofs,
+          margin_ns: float = 5e6, need: int = 2) -> list[tuple[float, float]]:
+    """Stretches of the trace in which a chip's events are LOST, not absent:
+    gaps of a chip's op line that wholly hold ``need`` or more of
+    ``proofs`` — intervals ``(start_ns, end_ns)`` on the trace clock inside
+    each of which the program ran a device operation on every chip of the
+    cell (a query that executed a kernel, from its span's start to its
+    end). ``margin_ns`` is what the two clocks may be apart. The union over
+    the chips: what is lost on one chip is not read on any."""
+    proofs = sorted(proofs)
+    if len(proofs) < need:
+        return []
+    starts = [s for s, _e in proofs]
+    shortest = min(e - s for s, e in proofs) + 2 * margin_ns
+    found = []
+    for p in device_planes(tr):
+        for a, b in _gaps(p, t0_ns, t1_ns):
+            if b - a < shortest:          # most gaps: between two operations
+                continue
+            inside = 0
+            for s, e in proofs[bisect.bisect_left(starts, a + margin_ns):]:
+                if s > b - margin_ns or inside >= need:
+                    break
+                inside += e <= b - margin_ns
+            if inside >= need:
+                found.append((a, b))
+    return union(found)
+
+
+def without(tr: dict, cuts) -> dict:
+    """The trace with every chip's events that touch a cut taken out."""
+    if not cuts:
+        return tr
+
+    def keep(e):
+        return not any(e[1] < b and e[1] + e[2] > a for a, b in cuts)
+    planes = [{"name": p["name"],
+               "lines": [{"name": ln["name"],
+                          "events": [e for e in ln["events"] if keep(e)]}
+                         for ln in p["lines"]]} for p in tr["planes"]]
+    return dict(tr, planes=planes)
+
+
+def idle_gaps(tr: dict, t0_ns: float, t1_ns: float, host_spans,
+              k: int = 10, cuts=()) -> list[list]:
+    """The longest idle gaps of the first chip, each named by the host span
+    (``[name, start_ns, end_ns]`` on the trace clock) that covers most of
+    it; "no span" where the program recorded nothing. ``cuts`` (``holes``)
+    are no gaps: nothing is known of them."""
+    planes = device_planes(tr)
+    if not planes:
+        return []
+    gaps = _gaps(planes[0], t0_ns, t1_ns, cuts)
     gaps.sort(key=lambda g: g[0] - g[1])
     out = []
     for a, b in gaps[:k]:

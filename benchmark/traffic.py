@@ -7,8 +7,9 @@ last one has been answered) over live ingest. Parameters:
 
 - ``clients``: how many.
 - ``tenant``: null, or a template with ``{client}`` (sent as X-Filo-Tenant).
-- ``queries``: [{"promql", "ref": {"agg", "fn", "window_s", "by"}}] — the
-  text sent and what the plain reference evaluates for it.
+- ``queries``: [{"promql", "ref": {...}}] — the text sent, and what the
+  deployment's data module (``benchmark/data/``) evaluates for it: ``ref``
+  goes through the harness unread, its keys are that module's.
 - ``ranges``: [{"range_s", "step_s", "end_back_s": [..]}] — a query covers
   ``range_s`` ending ``end_back_s`` before the head of the filled history.
 - ``order``:
@@ -79,8 +80,8 @@ class Request:
                          dtype=np.int64)
 
 
-def load(name: str) -> dict:
-    with open(os.path.join(HERE, "traffic", f"{name}.json")) as f:
+def load(name: str, home: str = HERE) -> dict:
+    with open(os.path.join(home, "traffic", f"{name}.json")) as f:
         return json.load(f)
 
 
