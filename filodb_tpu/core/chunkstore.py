@@ -53,7 +53,11 @@ def _scatter_append(ts, val, n, rows, cols, new_ts, new_val, counts_add):
 # per-row select instead: the new sample's column per row, -1 for none —
 # elementwise and donated, so the f32 block updates in place with no temp,
 # and the two blocks go through separate programs, so the s64 split (6 GB at
-# this shape) is the only temp alive at any moment.
+# this shape) is the only temp alive at any moment. A layout store takes
+# the same route, a select a block: compiled for a v5e at 2^15 x 768 x 64
+# (prom-histogram, 6.4 GB of buckets) _scatter_append_multi asks for 6.0 GB
+# of temp beside its 6.4 GB of donated arguments, and _dense_set on the
+# [S, C, B] block for none.
 DENSE_APPEND_BYTES = 2 << 30
 DENSE_APPEND_MAX_K = 4
 
@@ -814,9 +818,18 @@ class SeriesStore:
         rp = np.full(P, self.S, np.int32); rp[:m] = r
         cp = np.zeros(P, np.int32); cp[:m] = cols
         tp = np.zeros(P, np.int64); tp[:m] = t
-        if (self.layout is None and int(occ.max()) < DENSE_APPEND_MAX_K
+        # split the flat [m, W] ingest row by the schema layout: default
+        # column (scalar or histogram span) + named scalar columns
+        dv, ev = v, {}
+        for nm, off, w, _is_h in self.layout or ():
+            colv = v[:, off] if w == 1 else v[:, off:off + w]
+            if nm == self.default_col:
+                dv = colv
+            else:
+                ev[nm] = colv
+        if (int(occ.max()) < DENSE_APPEND_MAX_K
                 and self.ts.nbytes + self.val.nbytes >= DENSE_APPEND_BYTES):
-            self._append_dense(r, cols, t, v, occ, counts)
+            self._append_dense(r, cols, t, dv, ev, occ, counts)
         elif self.layout is None:
             vp = np.zeros((P,) + v.shape[1:], v.dtype); vp[:m] = v
             self.ts, self.val, self.n = _scatter_append(
@@ -824,16 +837,6 @@ class SeriesStore:
                 jnp.asarray(rp), jnp.asarray(cp), jnp.asarray(tp),
                 jnp.asarray(vp).astype(self.dtype), jnp.asarray(counts))
         else:
-            # split the flat [m, W] ingest row by the schema layout: default
-            # column (scalar or histogram span) + named scalar columns
-            dv = None
-            ev = {}
-            for nm, off, w, _is_h in self.layout:
-                colv = v[:, off] if w == 1 else v[:, off:off + w]
-                if nm == self.default_col:
-                    dv = colv
-                else:
-                    ev[nm] = colv
             vp = np.zeros((P,) + dv.shape[1:], dv.dtype); vp[:m] = dv
             evp = {}
             for k, a in ev.items():
@@ -847,19 +850,28 @@ class SeriesStore:
         self._appends_since_sync += 1
         return m
 
-    def _append_dense(self, r, cols, t, v, occ, counts) -> None:
+    def _append_dense(self, r, cols, t, v, extra, occ, counts) -> None:
         """The flush of a large store (see DENSE_APPEND_BYTES): one donated
         per-row select per block and per in-batch occurrence, instead of
-        one scatter over both blocks."""
+        one scatter over all blocks. ``v`` is the default column's values
+        ([m] or, for a histogram column, [m, B]); ``extra`` the named
+        scalar columns' of a layout store, each block through the same
+        select."""
+        def rows_of(a, sel, rk):
+            out = np.zeros((self.S,) + a.shape[1:], a.dtype)
+            out[rk] = a[sel]
+            return jnp.asarray(out)
+
         for k in range(int(occ.max()) + 1):
             sel = occ == k
             rk = r[sel]
             col = np.full(self.S, -1, np.int32); col[rk] = cols[sel]
-            nt = np.zeros(self.S, np.int64); nt[rk] = t[sel]
-            nv = np.zeros((self.S,) + v.shape[1:], v.dtype); nv[rk] = v[sel]
             col_d = jnp.asarray(col)
-            self.ts = _dense_set(self.ts, col_d, jnp.asarray(nt))
-            self.val = _dense_set(self.val, col_d, jnp.asarray(nv))
+            self.ts = _dense_set(self.ts, col_d, rows_of(t, sel, rk))
+            self.val = _dense_set(self.val, col_d, rows_of(v, sel, rk))
+            for nm, a in extra.items():
+                self.extra[nm] = _dense_set(self.extra[nm], col_d,
+                                            rows_of(a, sel, rk))
         self.n = _add_counts(self.n, jnp.asarray(counts))
 
     def throttle(self) -> None:

@@ -280,8 +280,8 @@ class RecordBuilder:
 
     def _flatten_value(self, value):
         """Multi-column flat row [W]: ``value`` may be a dict {col: scalar or
-        buckets}, or a bare bucket array (legacy histogram callers — sum is
-        unknowable, count = top bucket)."""
+        buckets}, a bare bucket array (legacy histogram callers — sum is
+        unknowable, count = top bucket), or a scalar (every column)."""
         layout, width, hist_col = self._layout_cache
         row = np.full(width, np.nan)
         if not isinstance(value, dict):
@@ -291,9 +291,15 @@ class RecordBuilder:
                     f"and no histogram column: pass a dict {{col: value}}, "
                     f"got {type(value).__name__}")
             arr = np.asarray(value, np.float64)
-            value = {hist_col: arr}
-            if any(nm == "count" for nm, _o, _w, _ih in layout) and len(arr):
-                value["count"] = float(arr[-1])
+            if arr.ndim == 0:
+                # a scalar is the registration sample (add_series_batch):
+                # every value column takes it, every bucket included
+                value = {nm: float(arr) for nm, _o, _w, _ih in layout}
+            else:
+                value = {hist_col: arr}
+                if any(nm == "count" for nm, _o, _w, _ih in layout) \
+                        and len(arr):
+                    value["count"] = float(arr[-1])
         for nm, off, w, _is_h in layout:
             v = value.get(nm)
             if v is None:

@@ -211,7 +211,20 @@ def hist_series_contrib(fn: str, window_ms: int, interval_ms: int,
     delta = jnp.dot(tri, db, precision=hp, preferred_element_type=f32)
     f_v = jnp.dot(tri, fd_col + fb, precision=hp,
                   preferred_element_type=f32)
+    return hist_extrapolate(fn, window_ms, interval_ms, delta, f_v, n,
+                            lo, hi, rel)
 
+
+def hist_extrapolate(fn: str, window_ms: int, interval_ms: int,
+                     delta, f_v, n, lo, hi, rel):
+    """One series' per-bucket window deltas ``delta [B, Tp]`` and
+    first-sample values ``f_v [B, Tp]`` (``n`` its valid count, an i32
+    scalar; ``lo``/``hi``/``rel`` the ``[1, Tp]`` step edges) ->
+    ``(contrib, okb)`` both ``[B, Tp]``: the per-(series, step)
+    extrapolation algebra of _grid_hist_kernel, elementwise. Shared by the
+    narrow tier above and the raw tier below: what differs between them is
+    only how ``delta`` and ``f_v`` come out of the resident block."""
+    f32 = jnp.float32
     last_cell = n - 1                                         # scalar
     f_idx = jnp.maximum(lo, 0)                                # [1, Tp]
     l_idx = jnp.minimum(hi, last_cell)
@@ -508,3 +521,348 @@ def fused_hist_quantile_resident(q: float, les, dd, first_d, n, gids,
     fin = _hist_finish_program(G, T, Tp, B, has_corr, int(les.shape[0]))
     return fin(jnp.float64(q), jnp.asarray(les), psum, pcnt,
                corr_sum, corr_cnt)
+
+
+# ---------------------------------------------------------------------------
+# hist_quantile over a RAW f32 [S, C, B] block (store.compressed_residency:
+# off, the shipped default): the same shape, streamed in row tiles. The
+# untiled composition (gridfns._grid_hist_kernel + partial_aggregate) builds
+# a masked copy, a shifted copy and two increment blocks, each [S, C, B] f32
+# — four times the store beside the store; it stays as the parity reference
+# and the path for shapes outside the gate below.
+# ---------------------------------------------------------------------------
+
+def raw_hist_rows_per_tile(S: int) -> int:
+    """Series per grid step of the raw hist kernel: one series' [B, C] f32
+    frame is 192 KiB at 64 x 768, so 16 of them (3 MiB, double-buffered)
+    with their masked copy and increments as scratch stay under 16 MiB, and
+    the tile's 16 x 64 = 1024 rows fill the MXU's streaming side."""
+    return 16 if S % 16 == 0 else 8
+
+
+def raw_hist_fusable(S: int, C: int, T: int, B: int, num_groups: int) -> bool:
+    """Shape gate of the raw tier: the narrow tier's VMEM bounds, and whole
+    sublane tiles of buckets (the tile is reshaped [Sb, B, Ca] ->
+    [Sb * B, Ca] for its matmuls, which is a relabelling only then)."""
+    return hist_fusable(S, C, T, B, num_groups) and B % 8 == 0
+
+
+def dot_exact01(x, w):
+    """``x [M, K] f32 @ w [K, N]`` for a 0/1 ``w`` held in bf16, exact to
+    f32: ``x`` splits into three bf16 pieces (8 mantissa bits each, the
+    rest taken off in f32 without rounding), each piece times a 0/1 weight
+    is exact and the MXU accumulates in f32. HIGHEST would split BOTH sides
+    and run six passes; the three that multiply the weight's (zero) low
+    pieces add nothing. Integers below 2^24 come out exact in any order."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    hi = x.astype(bf16)
+    r = x - hi.astype(f32)
+    mid = r.astype(bf16)
+    lo = (r - mid.astype(f32)).astype(bf16)
+
+    def dot(a):
+        # DEFAULT, spelled out: one pass a piece (and the package-wide
+        # "highest" would ask Mosaic for an fp32 contraction of bf16)
+        return jnp.dot(a, w, precision=jax.lax.Precision.DEFAULT,
+                       preferred_element_type=f32)
+    return dot(hi) + dot(mid) + dot(lo)
+
+
+def raw_hist_series_inc(fn: str, c0: int, x, n, roll):
+    """One series' raw cumulative buckets over the active columns ``x
+    [B, Ca]`` (buckets on sublanes, cells on lanes, ``c0`` the first
+    column's cell) and its valid count ``n`` -> ``(v, inc)``: the values
+    with absent cells zeroed, and the per-cell increments with the counter
+    clip — a reset cell adds 0 — for ``rate`` / ``increase``. The masks are
+    fusedgrid.tile_contrib's: a cell has a predecessor when it is valid and
+    not cell 0, and the roll's wrapped column is never read."""
+    B, Ca = x.shape
+    lcol = jax.lax.broadcasted_iota(jnp.int32, (B, Ca), 1)
+    col = lcol + c0
+    valid = col < n
+    v = jnp.where(valid, x, 0.0)
+    raw = v - roll(v)
+    inc = jnp.maximum(raw, 0.0) if fn != "delta" else raw
+    mask = valid & (col > 0)
+    if c0:
+        mask &= lcol > 0
+    return v, jnp.where(mask, inc, 0.0)
+
+
+def bucket_steps(contrib, roll):
+    """A series' cumulative-bucket contribution ``[B, Tp]`` as bucket STEPS
+    (bucket b minus bucket b - 1; bucket 0 as it is), which is what the raw
+    tier folds: a group's accumulator then holds each bucket at the size of
+    that bucket's own count, not of the cumulative count above it, and the
+    finish cumulates in f64. histogram_quantile divides by ONE bucket's
+    count: folded cumulative, a tail bucket holding a thousandth of a
+    group's count keeps three digits fewer than the sum around it (a p99
+    over 512 series a group came out 1.6e-4 off its f64 value)."""
+    row = jax.lax.broadcasted_iota(jnp.int32, contrib.shape, 0)
+    return contrib - jnp.where(row == 0, 0.0, roll(contrib))
+
+
+def kahan_add(total, comp, x):
+    """One compensated add (Kahan): ``(total, comp) + x`` -> the new pair;
+    the exact sum is ``total - comp``. A plain f32 fold of 32,768 series
+    rounds every add to the running sum's ulp, and series of one rate add
+    nearly the same amount, so the roundings do not cancel: the global p90
+    of histdev_raw_32k came out 0.4 of the deployment's tolerance off its
+    f64 value, on the chip and on the CPU alike. Compensated, the fold is
+    exact to a few ulps of the result whatever the count."""
+    y = x - comp
+    t = total + y
+    return t, (t - total) - y
+
+
+def _raw_hist_kernel_body(fn: str, window_ms: int, interval_ms: int, Sb: int,
+                          per: int, G: int, c0: int, n_ref, gid_ref, val_ref,
+                          band_ref, ohlo_ref, lo_ref, hi_ref, rel_ref,
+                          sum_ref, comp_ref, cnt_ref, v_scr, inc_scr, d_scr,
+                          f_scr):
+    """One grid step = ``Sb`` series in three passes over the VMEM tile:
+    mask and difference each series (its count is an SMEM scalar), ONE pair
+    of band matmuls over all ``Sb * B`` bucket rows, then extrapolate each
+    series and fold its bucket steps into its group's [B, Tp] accumulator
+    (a compensated pair), in series order. SMEM blocks as in
+    _hist_kernel_body."""
+    base = (pl.program_id(0) % per) * Sb
+    B, Ca = val_ref.shape[1], val_ref.shape[2]
+    Tp = band_ref.shape[1]
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        sum_ref[:] = jnp.zeros_like(sum_ref)
+        comp_ref[:] = jnp.zeros_like(comp_ref)
+        cnt_ref[:] = jnp.zeros_like(cnt_ref)
+
+    def prepare(s, carry):
+        v, inc = raw_hist_series_inc(
+            fn, c0, val_ref[s], n_ref[base + s],
+            roll=lambda a: pltpu.roll(a, jnp.int32(1), 1))
+        v_scr[s] = v
+        inc_scr[s] = inc
+        return carry
+
+    jax.lax.fori_loop(0, Sb, prepare, 0)
+    d_scr[:] = dot_exact01(inc_scr[:].reshape(Sb * B, Ca),
+                           band_ref[:]).reshape(Sb, B, Tp)
+    f_scr[:] = dot_exact01(v_scr[:].reshape(Sb * B, Ca),
+                           ohlo_ref[:]).reshape(Sb, B, Tp)
+
+    def fold(s, carry):
+        n = n_ref[base + s]
+        g = gid_ref[base + s]
+
+        @pl.when((n > 0) & (g >= 0) & (g < G))
+        def _():
+            contrib, okb = hist_extrapolate(
+                fn, window_ms, interval_ms, d_scr[s], f_scr[s], n,
+                lo_ref[:], hi_ref[:], rel_ref[:])
+            sum_ref[g], comp_ref[g] = kahan_add(
+                sum_ref[g], comp_ref[g], bucket_steps(
+                    contrib, lambda a: pltpu.roll(a, jnp.int32(1), 0)))
+            cnt_ref[g] += okb
+        return carry
+
+    jax.lax.fori_loop(0, Sb, fold, 0)
+
+
+def build_raw_hist_pallas(fn: str, window_ms: int, interval_ms: int, S: int,
+                          Sb: int, C: int, Tp: int, B: int, G: int,
+                          interpret: bool, c0: int, Ca: int):
+    """The raw (traceable) map-phase pallas_call of the raw hist tier: grid
+    over [Sb] series tiles of the f32 block, three [G, B, Tp] accumulators
+    (bucket-step sums, their compensation, series counts) in VMEM across
+    the sequential grid. Operands: n and gids [S] i32 (SMEM),
+    the block as [S, B, C] (the resident [S, C, 64] block's own HBM layout,
+    see build_hist_pallas), bf16 0/1 bands [Ca, Tp], edges [1, Tp].
+    ``(c0, Ca)`` is the active column range (fusedgrid.active_columns): a
+    sub-range query streams and multiplies only its own columns — unlike
+    the narrow tier, whose frames telescope from cell 0. Cached by its
+    caller's plan-cache entry."""
+    sblk = 1024 if S % 1024 == 0 else S
+    per = sblk // Sb
+    body = functools.partial(_raw_hist_kernel_body, fn, window_ms,
+                             interval_ms, Sb, per, G, c0)
+    acc = pl.BlockSpec((G, B, Tp), lambda i: (0, 0, 0),
+                       memory_space=pltpu.VMEM)
+    const = functools.partial(pl.BlockSpec, index_map=lambda i: (0, 0),
+                              memory_space=pltpu.VMEM)
+    scalars = pl.BlockSpec((sblk,), lambda i: (i // per,),
+                           memory_space=pltpu.SMEM)
+    kcol = c0 // Ca                     # active_columns: c0 % Ca == 0
+    in_specs = [
+        scalars, scalars,                                       # n, gid
+        pl.BlockSpec((Sb, B, Ca), lambda i: (i, 0, kcol),
+                     memory_space=pltpu.VMEM),                  # the block
+        const((Ca, Tp)), const((Ca, Tp)),                       # bands
+        const((1, Tp)), const((1, Tp)), const((1, Tp)),         # lo, hi, rel
+    ]
+    Cp, Bp = _pad(Ca, 128), _pad(B, 8)
+    tile = Sb * Bp * Cp * 4
+    footprint = (2 * tile                                       # the block
+                 + 2 * tile + 2 * Sb * Bp * Tp * 4              # scratch
+                 + 2 * 2 * _pad(Ca, 16) * Tp * 2                # bands
+                 + 2 * 3 * G * Bp * Tp * 4)                     # accumulators
+    # + the matmuls' working set: three bf16 pieces and the f32 remainder
+    # of one [Sb * B, Ca] operand, and a dozen [B, Tp] planes of the fold
+    footprint += 3 * tile + 12 * Bp * Tp * 4
+    return pl.pallas_call(
+        body,
+        grid=(S // Sb,),
+        in_specs=in_specs,
+        out_specs=(acc, acc, acc),
+        out_shape=tuple(jax.ShapeDtypeStruct((G, B, Tp), jnp.float32)
+                        for _ in range(3)),
+        scratch_shapes=[pltpu.VMEM((Sb, B, Ca), jnp.float32),
+                        pltpu.VMEM((Sb, B, Ca), jnp.float32),
+                        pltpu.VMEM((Sb, B, Tp), jnp.float32),
+                        pltpu.VMEM((Sb, B, Tp), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=min(fusedgrid.VMEM_CAP,
+                                 max(32 << 20, 2 * footprint))),
+        interpret=interpret,
+    )
+
+
+def build_raw_hist_xla_tiles(fn: str, window_ms: int, interval_ms: int,
+                             S: int, Sb: int, C: int, Tp: int, B: int, G: int,
+                             c0: int, Ca: int):
+    """XLA twin of :func:`build_raw_hist_pallas` from the same tiling plan:
+    a loop over the same [Sb, B, Ca] tiles (sliced out of the resident
+    block one at a time, never the active columns of the whole store),
+    through the same raw_hist_series_inc, dot_exact01 and hist_extrapolate,
+    folding series by series with the same compensated adds. Unlike the
+    other tiers' twins the two are not bit-equal: the compensation keeps
+    the last bit of every contribution, where the compilers differ (a
+    multiply contracted with the subtraction after it, or not) — they
+    agree to a few f32 ulps of the answer."""
+    f32 = jnp.float32
+
+    def call(n, gids, blk, band, ohlo, lo, hi, rel):
+        inc_of = jax.vmap(lambda x, n_s: raw_hist_series_inc(
+            fn, c0, x, n_s, roll=lambda a: jnp.roll(a, 1, axis=1)))
+        def extrap_one(d, f, n_s):
+            contrib, okb = hist_extrapolate(fn, window_ms, interval_ms, d, f,
+                                            n_s, lo, hi, rel)
+            return bucket_steps(contrib,
+                                lambda a: jnp.roll(a, 1, axis=0)), okb
+        extrap = jax.vmap(extrap_one)
+
+        def tile(i, carry):
+            n_t = jax.lax.dynamic_slice(n, (i * Sb,), (Sb,))
+            g_t = jax.lax.dynamic_slice(gids, (i * Sb,), (Sb,))
+            x_t = jax.lax.dynamic_slice(blk, (i * Sb, 0, c0), (Sb, B, Ca))
+            v, inc = inc_of(x_t, n_t)
+            d = dot_exact01(inc.reshape(Sb * B, Ca), band).reshape(Sb, B, Tp)
+            f = dot_exact01(v.reshape(Sb * B, Ca), ohlo).reshape(Sb, B, Tp)
+            contrib, okb = extrap(d, f, n_t)
+            live = (n_t > 0) & (g_t >= 0) & (g_t < G)
+            gi = jnp.clip(g_t, 0, G - 1)
+
+            def fold(acc, xs):
+                g_s, live_s, c_s, k_s = xs
+                t, comp = kahan_add(acc[0][g_s], acc[1][g_s], c_s)
+                return (acc[0].at[g_s].set(jnp.where(live_s, t, acc[0][g_s])),
+                        acc[1].at[g_s].set(jnp.where(live_s, comp,
+                                                     acc[1][g_s])),
+                        acc[2].at[g_s].add(jnp.where(live_s, k_s, 0.0))), None
+            return jax.lax.scan(fold, carry, (gi, live, contrib, okb))[0]
+
+        init = tuple(jnp.zeros((G, B, Tp), f32) for _ in range(3))
+        return jax.lax.fori_loop(0, S // Sb, tile, init)
+
+    return call
+
+
+def raw_hist_map_body(variant: str, fn: str, window_ms: int,
+                      interval_ms: int, S: int, Sb: int, C: int, Tp: int,
+                      B: int, G: int, c0: int, Ca: int):
+    """The traceable map phase of the raw tier as it is served: ``variant``
+    is fusedgrid.kernel_tag's name ("xla" | "pallas" | "pallas-interpret"),
+    and dtype casts and the relabelling ride the one dispatch."""
+    if variant == "xla":
+        call = build_raw_hist_xla_tiles(fn, window_ms, interval_ms, S, Sb, C,
+                                        Tp, B, G, c0, Ca)
+    else:
+        call = build_raw_hist_pallas(fn, window_ms, interval_ms, S, Sb, C,
+                                     Tp, B, G, variant != "pallas", c0, Ca)
+
+    def wrapped(val, n, gids, band, ohlo, lo, hi, rel):
+        # [S, C, B] -> [S, B, C]: a relabelling of the resident block's own
+        # layout on the TPU (build_hist_pallas); the 0/1 bands go in as
+        # bf16 (dot_exact01)
+        return call(n.astype(jnp.int32), gids.astype(jnp.int32),
+                    val.astype(jnp.float32).transpose(0, 2, 1),
+                    band.astype(jnp.bfloat16), ohlo.astype(jnp.bfloat16),
+                    lo, hi, rel)
+    return wrapped
+
+
+def raw_hist_finish(G: int, T: int, B: int):
+    """The raw tier's traceable finish: the compensated bucket-step sums
+    (``psum - pcomp``) of the true steps, cumulated over the buckets in
+    f64, empty groups masked, then the f64 Prometheus quantile of
+    _hist_finish_program (the same histogram_quantile program). The bucket
+    cumulation is log2(B) shifted adds, not ``cumsum``: the TPU emulates
+    f64, and its compiler takes 108 s over an f64 scan of this size (15 s
+    over the bare one; 4 s over a triangular contraction, which then runs
+    as five loops of small operations, 140 device events a query) and
+    under a second over the adds — a query waits 60 s for its answer."""
+    def fin(q, les, psum, pcomp, pcnt):
+        f64 = jnp.float64
+        x = (psum.astype(f64) - pcomp.astype(f64))[:, :, :T]      # [G, B, T]
+        k = 1
+        while k < B:                                              # along B
+            x = x + jnp.pad(x, ((0, 0), (k, 0), (0, 0)))[:, :B, :]
+            k *= 2
+        some = pcnt.transpose(0, 2, 1)[:, :T, :] > 0
+        return gridfns.histogram_quantile(
+            q, les, jnp.where(some, x.transpose(0, 2, 1), jnp.nan))
+    return fin
+
+
+def fused_hist_quantile_raw(q: float, les, val, n, gids, num_groups: int,
+                            out_ts: np.ndarray, window_ms: int, fn: str,
+                            base_ts: int, interval_ms: int,
+                            variant: str | None = None):
+    """histogram_quantile(q, sum by(...)(fn(m[w]))) over a RAW f32
+    ``[S, C, B]`` block, map phase per the active mode: no [S, C, B]-sized
+    temporary exists. Operands as fused_hist_quantile_resident's, with the
+    block itself in place of the 2D-delta state; the band and edge operands
+    are the scalar tier's own (fusedgrid's cache). Returns ``(out, tags)``:
+    the [G, T] device array, NOT fetched — the caller dispatches under the
+    shard lock and fetches outside it — and the dispatch's shape as the
+    ``query.exec.kernel`` span's tags."""
+    assert fn in HIST_FUSED_FNS
+    S, C, B = val.shape
+    T = len(out_ts)
+    G = _roundup(max(num_groups, 8), 8)
+    assert raw_hist_fusable(S, C, T, B, G), (S, C, T, B, G)
+    Tp = _roundup(max(T, 1), 128)
+    Sb = raw_hist_rows_per_tile(S)
+    variant = variant or _mode
+    assert variant in ("xla", "pallas")
+    variant = fusedgrid.kernel_tag(variant)
+
+    band, ohlo, lo_d, hi_d, rel_d, c0, Ca = fusedgrid._device_operands(
+        C, Tp, np.ascontiguousarray(np.asarray(out_ts, np.int64)).tobytes(),
+        int(window_ms), int(base_ts), int(interval_ms), "rate", False)
+    # a kernel variant of its own in the plan cache, as _hist_map_program
+    from ..query.plancache import plan_cache
+    key = (variant, fn, int(window_ms), int(interval_ms), S, Sb, C, Tp, B, G,
+           c0, Ca)
+    prog = plan_cache.program("fusedres-hist-raw", key,
+                              lambda: raw_hist_map_body(*key))
+    with jax.enable_x64(False):       # as fused_hist_quantile_resident
+        psum, pcomp, pcnt = prog(val, jnp.asarray(n), jnp.asarray(gids),
+                                 band, ohlo, lo_d, hi_d, rel_d)
+    fin = plan_cache.program("fusedres-hist-raw-finish",
+                             (G, T, Tp, B, int(les.shape[0])),
+                             lambda: raw_hist_finish(G, T, B))
+    out = fin(jnp.float64(q), jnp.asarray(les), psum, pcomp, pcnt)
+    return out, {"kernel": variant, "rows": S, "c0": c0, "cols": Ca,
+                 "steps": T, "groups": num_groups, "buckets": B,
+                 "variant": "hist-raw"}

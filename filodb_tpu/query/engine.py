@@ -1051,15 +1051,12 @@ class QueryEngine:
             return None
         if sh.store.grid_info() is None:
             return None              # off-grid store: general path outright
-        from .exec import (SelectRawPartitionsExec, SeriesSelection,
-                           _group_ids_for, _pad_steps, _pow2,
-                           check_sample_limit)
+        from .exec import SelectRawPartitionsExec, check_sample_limit
         step = max(inner.step_ms, 1)
         out_ts = np.arange(inner.start_ms, inner.end_ms + 1, step,
                            dtype=np.int64)
         if len(out_ts) == 0:
             return None
-        q = float(plan.function_args[0])
         leaf = SelectRawPartitionsExec(
             shard=sh.shard_num, filters=tuple(raw.filters),
             start_ms=raw.range_selector.from_ms,
@@ -1071,31 +1068,75 @@ class QueryEngine:
         # — commit the probe's stats only when the fused route serves (the
         # same only-when-committed rule as the mesh path)
         pctx = _dc_replace(ctx, stats=QueryStats())
-        with sh.lock:
-            # rare off-pattern outcomes below (cold data, churn minority)
-            # re-run the leaf on the general path — acceptable on the slow
-            # path; the common aligned case pays it once
-            data = leaf.do_execute(pctx)
-            if (not isinstance(data, SeriesSelection) or data.grid is None
-                    or data.bucket_les is None
-                    or (data.grid_minority is not None
-                        and len(data.grid_minority))):
-                return None          # cold/off-grid/churned: general path
-            out_eval, T = _pad_steps(out_ts)
-            window = inner.window_ms
-            if (max(abs(int(out_ts[0]) - data.grid[0]),
-                    abs(int(out_ts[-1]) - data.grid[0])) + window >= 2**31):
-                return None
-            R = data.val.shape[0]
-            gids, uniq, G = _group_ids_for(data.keys, data.rows, R,
-                                           agg.by, agg.without)
-            if not uniq:
-                self._set_path(ctx, "fused-hist")
-                ctx.stats.merge(pctx.stats)     # committed: fused serves
-                return QueryResult(ResultMatrix(
-                    out_ts, np.zeros((0, len(out_ts))), []))
-            base_ts, interval_ms = data.grid
-            path = "fused-hist"
+        waited = lock_wait_ns()
+        # the route's one leaf: lock wait (a tag), select, group ids and
+        # the kernel's dispatch inside it, as SelectRawPartitionsExec's
+        with span(SPAN_QUERY_LEAF, shard=sh.shard_num) as ltags:
+            try:
+                with sh.lock:
+                    # rare off-pattern outcomes (cold data, churn minority)
+                    # re-run the leaf on the general path — acceptable on
+                    # the slow path; the common aligned case pays it once
+                    got = self._fused_hist_dispatch(
+                        sh, leaf, pctx, ctx, plan, agg, inner, out_ts)
+            finally:
+                ltags["lock_wait_ms"] = (lock_wait_ns() - waited) / 1e6
+        if got is None:
+            return None
+        out, path, uniq, G, T = got
+        self._set_path(ctx, path)
+        ctx.stats.merge(pctx.stats)             # committed: fused serves
+        if out is None:
+            return QueryResult(ResultMatrix(
+                out_ts, np.zeros((0, len(out_ts))), []))
+        # the blocking fetch, outside the lock (the in-process leaf's rule)
+        with span(SPAN_QUERY_KERNEL, phase="fetch"):
+            vals = np.asarray(out)[:G, :T]
+        m = ResultMatrix(out_ts, vals, list(uniq))
+        check_sample_limit(m.num_series, T, self.config.sample_limit)
+        return QueryResult(m)
+
+    def _fused_hist_dispatch(self, sh, leaf, pctx, ctx, plan, agg, inner,
+                             out_ts):
+        """``_try_fused_hist`` under the shard lock: select, group ids and
+        the dispatch of one of three programs, chosen from the store's
+        shape — the tiled kernel over the 2D-delta state
+        (``fused-hist-narrow[...]``), the tiled kernel over the raw f32
+        block (``fused-hist[...]``), or the untiled composition (bare
+        ``fused-hist``) for what neither gate takes. Returns ``(out, path,
+        group keys, G, T)`` — ``out`` the [G, T] device array, not fetched,
+        None for an empty selection — or None: general path."""
+        from ..ops import fusedresident, gridfns
+        from .exec import (SeriesSelection, _group_ids_for, _pad_steps,
+                           _pow2)
+        fn = inner.function
+        q = float(plan.function_args[0])
+        data = leaf.do_execute(pctx)
+        if (not isinstance(data, SeriesSelection) or data.grid is None
+                or data.bucket_les is None
+                or (data.grid_minority is not None
+                    and len(data.grid_minority))):
+            return None          # cold/off-grid/churned: general path
+        out_eval, T = _pad_steps(out_ts)
+        window = inner.window_ms
+        if (max(abs(int(out_ts[0]) - data.grid[0]),
+                abs(int(out_ts[-1]) - data.grid[0])) + window >= 2**31):
+            return None
+        R = data.val.shape[0]
+        gids, uniq, G = _group_ids_for(data.keys, data.rows, R,
+                                       agg.by, agg.without)
+        if not uniq:
+            return None, "fused-hist", uniq, G, T
+        base_ts, interval_ms = data.grid
+        les = np.asarray(data.bucket_les, np.float64)
+        Gp = _pow2(G)
+        path = "fused-hist"
+        # what the dispatch span says of the program it covers; a tiled
+        # kernel adds its backend and column range
+        ktags = {"kernel": "xla", "rows": R, "cols": data.val.shape[1],
+                 "steps": T, "groups": G, "buckets": len(les),
+                 "variant": "hist-untiled"}
+        with span(SPAN_QUERY_KERNEL, phase="dispatch") as tags:
             if data.hist_narrow is not None:
                 # hist-resident store: one fused program off the i8/i16
                 # 2D-delta block — the [S, C, B] f32 temp never exists.
@@ -1105,7 +1146,6 @@ class QueryEngine:
                 from ..ops import rangefns
                 from .exec import _gather_rows_padded, _segment_partial
                 dd, first_d, bad = data.hist_narrow
-                Gp = _pow2(G)
                 corr = None
                 if len(bad):
                     bad_gids = gids[bad].copy()
@@ -1132,10 +1172,11 @@ class QueryEngine:
                     # or the XLA twin per query.fused_kernels), keyed as a
                     # distinct kernel variant in the plan cache
                     out = fusedresident.fused_hist_quantile_resident(
-                        q, np.asarray(data.bucket_les, np.float64), dd,
-                        first_d, data.n, gids, Gp, out_eval, window, fn,
-                        base_ts, interval_ms, corr=corr)
+                        q, les, dd, first_d, data.n, gids, Gp, out_eval,
+                        window, fn, base_ts, interval_ms, corr=corr)
                     path = f"fused-hist-narrow[{fusedresident.tag()}]"
+                    ktags.update(kernel=fusedresident.tag(),
+                                 variant=f"hist-{dd.dtype}")
                     ctx.stats.add("fused_kernels")
                     fusedresident.count_served("hist_quantile")
                 else:
@@ -1143,21 +1184,36 @@ class QueryEngine:
                     # XLA composition (bit-parity guaranteed by PR 1 rules)
                     fusedresident.count_fallback("hist_quantile")
                     out = gridfns.fused_hist_quantile_grid_narrow(
-                        q, np.asarray(data.bucket_les, np.float64), dd,
-                        first_d, data.n, gids, Gp, out_eval, window, fn,
-                        base_ts, interval_ms, stale_ms=ctx.stale_ms,
-                        corr=corr)
+                        q, les, dd, first_d, data.n, gids, Gp, out_eval,
+                        window, fn, base_ts, interval_ms,
+                        stale_ms=ctx.stale_ms, corr=corr)
+            elif (fn in fusedresident.HIST_FUSED_FNS
+                    and str(data.val.dtype) == "float32"
+                    and fusedresident.raw_hist_fusable(
+                        R, data.val.shape[1], len(out_eval), len(les),
+                        max(Gp, 8))):
+                # raw f32 residency (the shipped default): the same shape
+                # streamed over row tiles of the block itself, its own
+                # kernel variant — no [S, C, B]-sized temporary exists
+                out, ktags = fusedresident.fused_hist_quantile_raw(
+                    q, les, data.val, data.n, gids, Gp, out_eval, window,
+                    fn, base_ts, interval_ms)
+                ktags.update(steps=T, groups=G)
+                path = f"fused-hist[{fusedresident.tag()}]"
+                ctx.stats.add("fused_kernels")
+                fusedresident.count_served("hist_quantile")
             else:
+                # outside the tiled tier's gate (f64 stores, odd row
+                # counts, more groups than its accumulators hold, the
+                # other grid fns): ONE XLA program over the whole block,
+                # with [S, C, B]-sized temporaries — the parity reference
+                if fn in fusedresident.HIST_FUSED_FNS:
+                    fusedresident.count_fallback("hist_quantile")
                 out = gridfns.fused_hist_quantile_grid(
-                    q, np.asarray(data.bucket_les, np.float64), data.val,
-                    data.n, gids, _pow2(G), out_eval, window, fn,
-                    base_ts, interval_ms, stale_ms=ctx.stale_ms)
-        self._set_path(ctx, path)
-        ctx.stats.merge(pctx.stats)             # committed: fused serves
-        vals = np.asarray(out)[:G, :T]
-        m = ResultMatrix(out_ts, vals, list(uniq))
-        check_sample_limit(m.num_series, T, self.config.sample_limit)
-        return QueryResult(m)
+                    q, les, data.val, data.n, gids, Gp, out_eval, window,
+                    fn, base_ts, interval_ms, stale_ms=ctx.stale_ms)
+            tags.update(ktags)
+        return out, path, uniq, G, T
 
     # -- mesh dispatch (ref: queryengine2/QueryEngine.scala:59-67 — the
     # planner routes every query through per-shard dispatchers; here the

@@ -125,9 +125,10 @@ TRACE_SPEC: dict[str, str] = {
     SPAN_QUERY_EXECUTE: "ExecPlan execution (mesh, fused, or scatter-gather "
                         "path; tags: path).",
     SPAN_QUERY_LEAF: "One data-reading leaf under its shard lock; on the "
-                     "mesh route one span under every shard's lock (tags: "
-                     "shard, or shard=all route=mesh; lock_wait_ms = what "
-                     "the thread waited for shard locks inside it).",
+                     "mesh route one span under every shard's lock, on the "
+                     "fused-hist route the engine's own (tags: shard, or "
+                     "shard=all route=mesh; lock_wait_ms = what the thread "
+                     "waited for shard locks inside it).",
     SPAN_QUERY_SELECT: "Index select + array capture of one leaf; per shard "
                        "on the mesh route (tags: shard, series).",
     SPAN_QUERY_GROUPIDS: "Group ids of the selected series for a "
@@ -140,7 +141,9 @@ TRACE_SPEC: dict[str, str] = {
     SPAN_QUERY_KERNEL: "Host side of one fused kernel: phase=dispatch is "
                        "the call under the shard lock, phase=fetch the "
                        "blocking fetch of its result outside it (dispatch "
-                       "tags: kernel, rows, c0, cols, steps, groups).",
+                       "tags: kernel, rows, c0, cols, steps, groups; the "
+                       "fused-hist route adds buckets and variant = "
+                       "hist-raw | hist-int8 | hist-int16 | hist-untiled).",
     SPAN_QUERY_REDUCE: "Cross-shard reduce merge of child partials.",
     SPAN_QUERY_DISPATCH: "One cross-node /exec POST (tags: endpoint, "
                          "shards).",

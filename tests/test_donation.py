@@ -120,13 +120,20 @@ def test_staged_row_commit_donates_through_the_shard():
     np.testing.assert_array_equal(r[1], np.arange(8, dtype=np.float32))
 
 
-@pytest.mark.parametrize("nbuckets", [0, 4])
-def test_dense_append_matches_the_scatter_and_donates(monkeypatch, nbuckets):
+@pytest.mark.parametrize("nbuckets, layout", [(0, False), (4, False),
+                                              (4, True)])
+def test_dense_append_matches_the_scatter_and_donates(monkeypatch, nbuckets,
+                                                      layout):
     """Large stores flush through per-row selects (chunkstore
     DENSE_APPEND_BYTES: the one-program scatter needs a store-sized temp on
     the TPU). Same batches through both paths — ragged starts, two samples
     per row in one batch, repeated (dropped) samples — must leave identical
-    stores, and the dense programs must donate too."""
+    stores, and the dense programs must donate too. ``layout``: a
+    prom-histogram store (``sum``, ``count`` beside the bucket block),
+    whose flat rows are split a column before either route."""
+    from filodb_tpu.core.schemas import PROM_HISTOGRAM
+    lay = PROM_HISTOGRAM.col_layout(nbuckets) if layout else None
+    width = nbuckets + 2 if layout else nbuckets
     from filodb_tpu.core import chunkstore
     rng = np.random.default_rng(3)
     batches = []
@@ -137,16 +144,17 @@ def test_dense_append_matches_the_scatter_and_donates(monkeypatch, nbuckets):
                              np.full(7, BASE + (2 * step + 1) * IV)])
         if step:
             pid[0], ts[0] = batches[-1][0][0], batches[-1][1][0]   # a repeat
-        shape = (len(pid), nbuckets) if nbuckets else (len(pid),)
+        shape = (len(pid), width) if width else (len(pid),)
         batches.append((pid, ts.astype(np.int64),
                         rng.integers(0, 1000, shape).astype(np.float32)))
 
     def run(dense: bool):
         monkeypatch.setattr(chunkstore, "DENSE_APPEND_BYTES",
                             0 if dense else 1 << 60)
-        st = SeriesStore(64, 16, nbuckets=nbuckets)
+        st = SeriesStore(64, 16, nbuckets=nbuckets, layout=lay,
+                         default_col="h")
         for pid, ts, v in batches:
-            old = (st.ts, st.val, st.n)
+            old = (st.ts, st.val, st.n, *st.extra.values())
             st.append(pid, ts, v)
             assert all(h.is_deleted() for h in old)
         return st
@@ -154,6 +162,11 @@ def test_dense_append_matches_the_scatter_and_donates(monkeypatch, nbuckets):
     a, b = run(False), run(True)
     np.testing.assert_array_equal(np.asarray(a.ts), np.asarray(b.ts))
     np.testing.assert_array_equal(np.asarray(a.val), np.asarray(b.val))
+    assert sorted(a.extra) == sorted(b.extra) == (
+        ["count", "sum"] if layout else [])
+    for nm in a.extra:
+        np.testing.assert_array_equal(np.asarray(a.extra[nm]),
+                                      np.asarray(b.extra[nm]))
     np.testing.assert_array_equal(np.asarray(a.n), np.asarray(b.n))
     np.testing.assert_array_equal(a.n_host, b.n_host)
     assert a.stats.out_of_order_dropped == b.stats.out_of_order_dropped >= 4

@@ -412,6 +412,9 @@ def test_fused_hist_quantile_route_and_parity(hist_engine):
     start, end, step = BASE + 600_000, BASE + 900_000, 60_000
     q = "histogram_quantile(0.9, sum(rate(req_latency[2m])))"
     r1 = eng.query_range(q, start, end, step)
+    # 6 buckets are no whole sublane tile: outside the tiled raw tier's
+    # gate (fusedresident.raw_hist_fusable), so the untiled one-program
+    # composition serves — the bare route name
     assert r1.exec_path == "fused-hist"
     # grouping by an absent label still routes fused and must equal the
     # global sum (one group)

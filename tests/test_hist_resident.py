@@ -148,7 +148,8 @@ def test_hist_mixed_rows_take_the_pool_bit_exact(frac):
 def test_hist_query_parity_resident_vs_f32(mixed):
     """quantile-of-sum-of-rate (the fused path) and every hist grid function
     answer identically whether the store is raw-f32 or hist-resident —
-    bit-exactly for integer data; pool rows recompute through the general
+    bit-exactly for integer data (but where each store's own tiled tier
+    serves: to an f32 ulp); pool rows recompute through the general
     kernels (different f32 summation order, so the aggregate rounds)."""
     ms_a, _ = _build("off", mixed)
     ms_b, sh_b = _build("all", mixed)
@@ -164,17 +165,25 @@ def test_hist_query_parity_resident_vs_f32(mixed):
               'histogram_quantile(0.9, sum(rate(h{host="x1"}[2m])))'):
         ra = ea.query_range(q, start, end, step)
         rb = eb.query_range(q, start, end, step)
-        # the resident engine reports the fused-resident variant it served
-        # with ("fused-hist-narrow[pallas|xla]"); routes otherwise match
+        # each engine reports the fused variant it served with: the raw
+        # store "fused-hist[pallas|xla]" (the tiled raw tier), the resident
+        # one "fused-hist-narrow[...]"; routes otherwise match
         assert (rb.exec_path == ra.exec_path
-                or (ra.exec_path == "fused-hist"
-                    and rb.exec_path.startswith("fused-hist-narrow["))), \
+                or (ra.exec_path.startswith("fused-hist[")
+                    and rb.exec_path
+                    == ra.exec_path.replace("fused-hist[",
+                                            "fused-hist-narrow["))), \
             (q, ra.exec_path, rb.exec_path)
         a, b = np.asarray(ra.matrix.values), np.asarray(rb.matrix.values)
         assert a.shape == b.shape, q
         if mixed:
             np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6,
                                        equal_nan=True)
+        elif ra.exec_path.startswith("fused-hist["):
+            # two tiled tiers, two folds: the raw tier sums bucket steps in
+            # compensated pairs, the narrow tier cumulative buckets in plain
+            # f32 — they agree to an f32 ulp or two of the answer
+            np.testing.assert_allclose(a, b, rtol=1e-6, equal_nan=True)
         else:
             np.testing.assert_array_equal(a, b)
     assert sh_b.store.is_narrow_resident    # read-only queries don't rehydrate

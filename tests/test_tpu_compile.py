@@ -114,6 +114,67 @@ def test_hist_kernel_compiles_for_v5e_without_copying_the_store(one_chip,
     assert mem.temp_size_in_bytes < (64 << 20), mem
 
 
+@pytest.mark.parametrize("variant, c0, Ck", [
+    ("pallas", 0, 768),        # a 2 h range: every column
+    ("pallas", 512, 256),      # the last 15 min: two 128-column blocks
+    ("xla", 0, 768),           # the twin from the same tiling plan
+])
+def test_raw_hist_kernel_compiles_for_v5e_with_no_store_sized_temp(
+        one_chip, variant, c0, Ck):
+    """histdev_raw_32k: 2^15 series x 768 cells x 64 buckets of raw f32,
+    6.44 GB resident. The served map phase (fusedresident.raw_hist_map_body:
+    casts, the [S, C, B] -> [S, B, C] relabelling, the kernel) must hold no
+    [S, C, B]-sized temporary — the untiled composition holds four — nor a
+    copy of the active columns: its temp stays under 64 MiB."""
+    Sh, C, Tp, B, G = 1 << 15, 768, 128, 64, 8
+    assert fusedresident.raw_hist_fusable(Sh, C, 64, B, G)
+    Sb = fusedresident.raw_hist_rows_per_tile(Sh)
+    body = fusedresident.raw_hist_map_body(variant, "rate", WINDOW, IV, Sh,
+                                           Sb, C, Tp, B, G, c0, Ck)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    args = [sds((Sh, C, B), f32), sds((Sh,), i32), sds((Sh,), i32),
+            sds((Ck, Tp), f32), sds((Ck, Tp), f32),
+            sds((1, Tp), i32), sds((1, Tp), i32), sds((1, Tp), i32)]
+    with jax.enable_x64(False):
+        compiled = jax.jit(body).lower(*args).compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == (variant == "pallas")
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > Sh * C * B * 4
+    assert mem.temp_size_in_bytes < (64 << 20), mem
+
+
+def test_raw_hist_finish_compiles_for_v5e_inside_a_querys_patience(one_chip):
+    """The raw tier's f64 finish at histdev_raw_32k's shapes. The TPU
+    emulates f64, and its compiler took 108 s over this finish when the
+    buckets were cumulated by ``cumsum`` (under a second by shifted adds):
+    a cold server answered its first query with 504. The bound is generous
+    to the machine and far under the scan's time; no loop may come back
+    either (a contraction ran as five, 140 device events a query)."""
+    import time
+    G, T, Tp, B = 8, 64, 128, 64
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    acc = sds((G, B, Tp), f32)
+    t0 = time.perf_counter()
+    compiled = jax.jit(fusedresident.raw_hist_finish(G, T, B)).lower(
+        sds((), jnp.float64), sds((B,), jnp.float64), acc, acc, acc).compile()
+    assert time.perf_counter() - t0 < 45.0
+    assert " while(" not in compiled.as_text()
+
+
+def test_dense_flush_of_a_histogram_block_runs_in_place(one_chip):
+    """The flush of histdev_raw_32k's 6.44 GB bucket block: the per-row
+    select (chunkstore._dense_set) aliases its donated block and holds no
+    temp, where the one-program scatter asks for 6 GB beside it."""
+    from filodb_tpu.core import chunkstore
+    Sh, C, B = 1 << 15, 768, 64
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    compiled = chunkstore._dense_set.lower(
+        sds((Sh, C, B), f32), sds((Sh,), i32), sds((Sh, B), f32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= Sh * C * B * 4
+    assert mem.temp_size_in_bytes < (64 << 20), mem
+
+
 def test_mesh_fused_program_compiles_for_four_chips(topo):
     """One pjit ``dist_fused`` program on the described 2x2: four shards of
     2^18 x 768 f32, one per device, explicit NamedShardings both ways, the

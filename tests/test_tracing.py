@@ -381,6 +381,77 @@ def test_http_query_is_one_trace_rooted_at_the_request(served):
     assert leaf.tags["lock_wait_ms"] == 0
 
 
+def test_fused_hist_route_is_a_leaf_with_its_parts_and_a_fetch():
+    """The fused-hist route (query/engine.py ``_try_fused_hist``) records
+    what the ExecPlan leaf records: ``query.exec.leaf`` (tag
+    ``lock_wait_ms``) > select, group ids, kernel dispatch (the tiled raw
+    hist kernel's tags), and the kernel's fetch beside the leaf, after it
+    and outside the lock — the sums the benchmark's means are read from."""
+    import numpy as np
+
+    from filodb_tpu.config import Config
+    from filodb_tpu.core.record import RecordBuilder
+    from filodb_tpu.core.schemas import PROM_HISTOGRAM
+    from filodb_tpu.ops import fusedresident
+    from filodb_tpu.standalone import FiloServer
+    srv = FiloServer(Config({
+        "num_shards": 1, "http": {"port": 0}, "dataset": "hists",
+        "schema": "prom-histogram",
+        "store": {"max_series_per_shard": 32, "samples_per_series": 128,
+                  "flush_batch_size": 10**9}})).start()
+    try:
+        les = np.array([1., 2., 4., 8., 16., 32., 64., np.inf])
+        b = RecordBuilder(PROM_HISTOGRAM, bucket_les=les)
+        for t in range(N_SAMPLES):
+            for i in range(N_SERIES):
+                b.add({"_metric_": "h", "host": f"h{i}", "g": f"g{i % 4}"},
+                      BASE + t * 10_000,
+                      np.cumsum(np.arange(8) + i + 1.0) * (t + 1))
+        srv.memstore.ingest("hists", 0, b.build())
+        srv.memstore.flush_all()
+
+        def get(shift_ms):
+            q = urllib.parse.urlencode({
+                "query": "histogram_quantile(0.9, sum by (g)(rate(h[2m])))",
+                "start": (BASE + 300_000 + shift_ms) / 1000,
+                "end": (BASE + 800_000 + shift_ms) / 1000, "step": 10})
+            url = (f"http://127.0.0.1:{srv.http.port}/promql/hists/api/v1/"
+                   f"query_range?{q}")
+            with urllib.request.urlopen(url, timeout=60) as r:
+                return json.load(r)
+        get(0)                              # compiles; not the one we read
+        _trace_of_last_query()
+        tracer.drain()
+        body = get(1_000)
+        path = f"fused-hist[{fusedresident.tag()}]"
+        assert body["status"] == "success" \
+            and body["stats"]["exec_path"] == path
+        members = _trace_of_last_query()
+    finally:
+        srv.shutdown()
+    by = {}
+    for s in members:
+        by.setdefault(s.name, []).append(s)
+    assert by[SPAN_QUERY][0].tags["exec_path"] == path
+    (leaf,), (sel,), (gid,) = (by[SPAN_QUERY_LEAF], by[SPAN_QUERY_SELECT],
+                               by[SPAN_QUERY_GROUPIDS])
+    kern = {k.tags["phase"]: k for k in by[SPAN_QUERY_KERNEL]}
+    assert sorted(kern) == ["dispatch", "fetch"]
+    disp, fetch = kern["dispatch"], kern["fetch"]
+    assert sel.parent_id == gid.parent_id == disp.parent_id == leaf.span_id
+    assert fetch.parent_id == leaf.parent_id
+    assert fetch.start_ns >= leaf.start_ns + (leaf.duration_us - 2) * 1e3
+    assert leaf.tags == {"shard": 0, "lock_wait_ms": 0}
+    assert sel.tags["series"] == N_SERIES
+    assert gid.tags == {"keys": N_SERIES, "groups": 4, "route": "walk"}
+    assert disp.tags == {
+        "phase": "dispatch", "kernel": fusedresident.tag(), "rows": 32,
+        "c0": 0, "cols": 128, "steps": 51, "groups": 4, "buckets": 8,
+        "variant": "hist-raw"}
+    inner = sel.duration_us + gid.duration_us + disp.duration_us
+    assert inner <= leaf.duration_us + 3
+
+
 def test_global_aggregate_opens_no_groupids_span(served):
     _srv, get = served
     tracer.drain()
