@@ -47,7 +47,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..utils.metrics import (FILODB_QUERY_FUSED_FALLBACK,
+from ..utils.metrics import (FILODB_QUERY_FUSED_FALL_TILES,
+                             FILODB_QUERY_FUSED_FALLBACK,
                              FILODB_QUERY_FUSED_SERVED, registry)
 from . import decodereg, fusedgrid, gridfns
 
@@ -109,6 +110,15 @@ def scalar_shape_of(fn: str) -> str | None:
 def count_served(shape: str) -> None:
     registry.counter(FILODB_QUERY_FUSED_SERVED,
                      {"shape": shape, "mode": _mode}).increment()
+
+
+def count_fall_tiles(falls) -> int:
+    """Fetch the raw hist tier's [1] count of tiles that ran the correction
+    matmul (fused_hist_quantile_raw), add it to the registry, return it."""
+    k = int(np.asarray(falls)[0])
+    registry.counter(FILODB_QUERY_FUSED_FALL_TILES,
+                     {"mode": _mode}).increment(k)
+    return k
 
 
 def count_fallback(shape: str) -> None:
@@ -535,8 +545,8 @@ def fused_hist_quantile_resident(q: float, les, dd, first_d, n, gids,
 def raw_hist_rows_per_tile(S: int) -> int:
     """Series per grid step of the raw hist kernel: one series' [B, C] f32
     frame is 192 KiB at 64 x 768, so 16 of them (3 MiB, double-buffered)
-    with their masked copy and increments as scratch stay under 16 MiB, and
-    the tile's 16 x 64 = 1024 rows fill the MXU's streaming side."""
+    with their masked copy as scratch stay under 16 MiB, and the tile's
+    16 x 64 = 1024 rows fill the MXU's streaming side."""
     return 16 if S % 16 == 0 else 8
 
 
@@ -548,12 +558,13 @@ def raw_hist_fusable(S: int, C: int, T: int, B: int, num_groups: int) -> bool:
 
 
 def dot_exact01(x, w):
-    """``x [M, K] f32 @ w [K, N]`` for a 0/1 ``w`` held in bf16, exact to
-    f32: ``x`` splits into three bf16 pieces (8 mantissa bits each, the
-    rest taken off in f32 without rounding), each piece times a 0/1 weight
-    is exact and the MXU accumulates in f32. HIGHEST would split BOTH sides
-    and run six passes; the three that multiply the weight's (zero) low
-    pieces add nothing. Integers below 2^24 come out exact in any order."""
+    """``x [M, K] f32 @ w [K, N]`` for a ``w`` of -1, 0 and 1 held in bf16,
+    exact to f32: ``x`` splits into three bf16 pieces (8 mantissa bits
+    each, the rest taken off in f32 without rounding), each piece times
+    such a weight is exact and the MXU accumulates in f32. HIGHEST would
+    split BOTH sides and run six passes; the three that multiply the
+    weight's (zero) low pieces add nothing. Integers below 2^24 come out
+    exact in any order."""
     f32, bf16 = jnp.float32, jnp.bfloat16
     hi = x.astype(bf16)
     r = x - hi.astype(f32)
@@ -568,25 +579,105 @@ def dot_exact01(x, w):
     return dot(hi) + dot(mid) + dot(lo)
 
 
-def raw_hist_series_inc(fn: str, c0: int, x, n, roll):
+def raw_hist_weights(C: int, out_ts: np.ndarray, window_ms: int,
+                     base_ts: int, interval_ms: int):
+    """Host operands of the raw tier, from fusedgrid.host_operands' edges
+    and in the kernel's order: ``(last, w, band, used, lo, hi, rel, c0,
+    Ca)``.
+
+    ``w [Ca, N]``, entries -1/0/1, carries BOTH of a tile's products
+    through one matmul of the values. A step's window delta is a
+    telescoped sum: over cells ``(f, h]``, ``f = max(lo, 0)``, ``h`` =
+    ``hi`` clipped to the store's last column, ``sum(v[c] - v[c - 1]) =
+    v[h] - v[f]``; so columns ``0..T-1`` hold ``onehot(h) - onehot(f)`` (a
+    zero column where the window is empty, ``h <= f``) and columns
+    ``N/2..N/2+T-1`` the first sample's ``onehot(f)``, gridfns.
+    onehot_matrix's. ``N = roundup(2T, 128)``: up to 64 steps both halves
+    share the 128 columns ONE band took.
+
+    What the telescoped sum leaves out — the counter clip's drops, and a
+    row that ends inside a window — is raw_hist_corr's, summed over the
+    same cells by ``band [Ca, Tp]``, gridfns.band_matrix's open band;
+    ``used [1, Ca]`` marks the cells some window sums (cell 0 has no
+    predecessor and is never one) and ``last [1]`` is the last of them: a
+    fall elsewhere, or a row that ends past it, needs no correction. Rows
+    are sliced to the active columns ``[c0, c0 + Ca)``; edges padded as
+    fusedgrid.pad_edges."""
+    T = len(out_ts)
+    Tp, N = _roundup(max(T, 1), 128), _roundup(max(2 * T, 1), 128)
+    lo, hi = gridfns.grid_edges(out_ts, window_ms, base_ts, interval_ms)
+    lo_p, hi_p, rel_p = fusedgrid.pad_edges(lo, hi, out_ts - base_ts,
+                                            window_ms, Tp)
+    f, h = np.maximum(lo, 0), np.minimum(hi, C - 1)
+    first = gridfns.onehot_matrix(C, f, np.float32)
+    w = np.zeros((C, N), np.float32)
+    w[:, :T] = np.where(h > f, gridfns.onehot_matrix(C, h, np.float32)
+                        - first, 0.0)
+    w[:, N // 2:N // 2 + T] = first
+    band = np.zeros((C, Tp), np.float32)
+    band[:, :T] = gridfns.band_matrix(C, lo, hi, True, np.float32)
+    band[0] = 0.0
+    c0, Ca = fusedgrid.active_columns(C, lo, hi)
+    w, band = w[c0:c0 + Ca], band[c0:c0 + Ca]
+    used = band.any(axis=1)
+    last = np.array([c0 + np.flatnonzero(used).max(initial=-1)], np.int32)
+    return (last, w, band, used.astype(np.int32).reshape(1, Ca),
+            lo_p, hi_p, rel_p, c0, Ca)
+
+
+@functools.lru_cache(maxsize=32)
+def _raw_hist_device_operands(C: int, out_ts_key: bytes, window_ms: int,
+                              base_ts: int, interval_ms: int):
+    """raw_hist_weights on the device, cached per query shape as
+    fusedgrid._device_operands; the weights go up as bf16 (dot_exact01),
+    rounded on the host: no program is compiled for it."""
+    out_ts = np.frombuffer(out_ts_key, np.int64)
+    last, w, band, *rest, c0, Ca = raw_hist_weights(
+        C, out_ts, window_ms, base_ts, interval_ms)
+    return (jnp.asarray(last),
+            *(jnp.asarray(a.astype(jnp.bfloat16)) for a in (w, band)),
+            *(jnp.asarray(a) for a in rest), c0, Ca)
+
+
+def raw_hist_values(c0: int, x, n):
     """One series' raw cumulative buckets over the active columns ``x
     [B, Ca]`` (buckets on sublanes, cells on lanes, ``c0`` the first
-    column's cell) and its valid count ``n`` -> ``(v, inc)``: the values
-    with absent cells zeroed, and the per-cell increments with the counter
-    clip — a reset cell adds 0 — for ``rate`` / ``increase``. The masks are
-    fusedgrid.tile_contrib's: a cell has a predecessor when it is valid and
-    not cell 0, and the roll's wrapped column is never read."""
-    B, Ca = x.shape
-    lcol = jax.lax.broadcasted_iota(jnp.int32, (B, Ca), 1)
-    col = lcol + c0
-    valid = col < n
-    v = jnp.where(valid, x, 0.0)
+    column's cell) and its valid count ``n`` -> the values with absent
+    cells zeroed, whose differences the packed weight telescopes."""
+    col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) + c0
+    return jnp.where(col < n, x, 0.0)
+
+
+def raw_hist_drops(v, roll):
+    """raw_hist_values' ``v [M, Ca]`` -> per cell how far it lies below
+    its predecessor: above 0 where a counter fell (and at the cell after a
+    row's last sample). What every tile is tested with; what a fall is
+    worth is raw_hist_corr's, where one was seen."""
+    return roll(v) - v
+
+
+def raw_hist_corr(fn: str, c0: int, v, n, used, roll):
+    """Per cell, what the function's own increment — the counter clip for
+    ``rate`` / ``increase``: a reset cell adds 0; nothing past the row's
+    last sample — holds beyond the plain difference of raw_hist_values'
+    ``v`` that the packed weight telescopes: the drop at a reset, and the
+    last sample's value at cell ``n``. Zero wherever no window sums the
+    cell (raw_hist_weights' ``used [1, Ca]``); the roll's wrapped column
+    is never a used one."""
+    col = jax.lax.broadcasted_iota(jnp.int32, v.shape, 1) + c0
     raw = v - roll(v)
     inc = jnp.maximum(raw, 0.0) if fn != "delta" else raw
-    mask = valid & (col > 0)
-    if c0:
-        mask &= lcol > 0
-    return v, jnp.where(mask, inc, 0.0)
+    return jnp.where(used != 0, jnp.where(col < n, inc, 0.0) - raw, 0.0)
+
+
+def unpack_halves(d, Tp: int, roll):
+    """The packed product ``d [M, N]`` -> ``(delta, f_v)``, both
+    ``[M, Tp]`` with step t on lane t: the second half comes down by N/2
+    lanes. Lanes past the steps hold the other half's numbers; their
+    ``hi`` is -1 and hist_extrapolate masks them."""
+    half = d.shape[1] // 2
+    f_v = d[:, half:] if half == Tp else roll(d, half)
+    return d[:, :Tp], f_v[:, :Tp]
 
 
 def bucket_steps(contrib, roll):
@@ -615,17 +706,26 @@ def kahan_add(total, comp, x):
     return t, (t - total) - y
 
 
+def _sublane_max(a):
+    """``[M, Ca]`` -> ``[8, Ca]``: the maximum over whole sublane tiles, all
+    elementwise (M a multiple of B, B % 8 == 0: raw_hist_fusable)."""
+    return functools.reduce(jnp.maximum,
+                            [a[i:i + 8] for i in range(0, a.shape[0], 8)])
+
+
 def _raw_hist_kernel_body(fn: str, window_ms: int, interval_ms: int, Sb: int,
-                          per: int, G: int, c0: int, n_ref, gid_ref, val_ref,
-                          band_ref, ohlo_ref, lo_ref, hi_ref, rel_ref,
-                          sum_ref, comp_ref, cnt_ref, v_scr, inc_scr, d_scr,
-                          f_scr):
+                          per: int, G: int, c0: int, n_ref, gid_ref, last_ref,
+                          val_ref, w_ref, band_ref, used_ref, lo_ref, hi_ref,
+                          rel_ref, sum_ref, comp_ref, cnt_ref, falls_ref,
+                          x_scr, d_scr, f_scr):
     """One grid step = ``Sb`` series in three passes over the VMEM tile:
-    mask and difference each series (its count is an SMEM scalar), ONE pair
-    of band matmuls over all ``Sb * B`` bucket rows, then extrapolate each
-    series and fold its bucket steps into its group's [B, Tp] accumulator
-    (a compensated pair), in series order. SMEM blocks as in
-    _hist_kernel_body."""
+    mask each series (its count is an SMEM scalar), ONE packed matmul over
+    all ``Sb * B`` bucket rows, then extrapolate each series and fold its
+    bucket steps into its group's [B, Tp] accumulator (a compensated
+    pair), in series order. Only a tile in which a used cell fell, or a
+    row ends under a window, turns its values into raw_hist_corr's and
+    takes a second matmul, and counts itself in ``falls_ref``. SMEM blocks
+    as in _hist_kernel_body."""
     base = (pl.program_id(0) % per) * Sb
     B, Ca = val_ref.shape[1], val_ref.shape[2]
     Tp = band_ref.shape[1]
@@ -635,20 +735,46 @@ def _raw_hist_kernel_body(fn: str, window_ms: int, interval_ms: int, Sb: int,
         sum_ref[:] = jnp.zeros_like(sum_ref)
         comp_ref[:] = jnp.zeros_like(comp_ref)
         cnt_ref[:] = jnp.zeros_like(cnt_ref)
+        falls_ref[0] = 0
 
-    def prepare(s, carry):
-        v, inc = raw_hist_series_inc(
-            fn, c0, val_ref[s], n_ref[base + s],
-            roll=lambda a: pltpu.roll(a, jnp.int32(1), 1))
-        v_scr[s] = v
-        inc_scr[s] = inc
-        return carry
+    def roll1(a):
+        return pltpu.roll(a, jnp.int32(1), 1)
 
-    jax.lax.fori_loop(0, Sb, prepare, 0)
-    d_scr[:] = dot_exact01(inc_scr[:].reshape(Sb * B, Ca),
-                           band_ref[:]).reshape(Sb, B, Tp)
-    f_scr[:] = dot_exact01(v_scr[:].reshape(Sb * B, Ca),
-                           ohlo_ref[:]).reshape(Sb, B, Tp)
+    def prepare(s, ends):
+        n = n_ref[base + s]
+        x_scr[s] = raw_hist_values(c0, val_ref[s], n)
+        return ends | ((n > c0) & (n <= last_ref[0])).astype(jnp.int32)
+
+    def tile():
+        return x_scr[:].reshape(Sb * B, Ca)
+
+    ends = jax.lax.fori_loop(0, Sb, prepare, jnp.int32(0))
+    x = tile()
+    delta, f_v = unpack_halves(
+        dot_exact01(x, w_ref[:]), Tp,
+        lambda a, k: pltpu.roll(a, jnp.int32(k), 1))
+    d_scr[:] = delta.reshape(Sb, B, Tp)
+    f_scr[:] = f_v.reshape(Sb, B, Tp)
+    if fn == "delta":                     # no clip: nothing falls
+        fell = ends > 0
+    else:
+        # beside the matmul, not in the loop before it: the rolls then run
+        # while the MXU does (4 ms of a 16 ms query over 768 columns
+        # otherwise, on the v5e)
+        drops = _sublane_max(raw_hist_drops(x, roll1))
+        fell = (ends > 0) | (jnp.max(
+            jnp.where(used_ref[:] != 0, drops, 0.0)) > 0.0)
+
+    @pl.when(fell)
+    def _():
+        def correct(s, carry):
+            x_scr[s] = raw_hist_corr(fn, c0, x_scr[s], n_ref[base + s],
+                                     used_ref[:], roll1)
+            return carry
+
+        jax.lax.fori_loop(0, Sb, correct, 0)
+        d_scr[:] += dot_exact01(tile(), band_ref[:]).reshape(Sb, B, Tp)
+        falls_ref[0] += 1
 
     def fold(s, carry):
         n = n_ref[base + s]
@@ -669,18 +795,19 @@ def _raw_hist_kernel_body(fn: str, window_ms: int, interval_ms: int, Sb: int,
 
 
 def build_raw_hist_pallas(fn: str, window_ms: int, interval_ms: int, S: int,
-                          Sb: int, C: int, Tp: int, B: int, G: int,
+                          Sb: int, C: int, Tp: int, N: int, B: int, G: int,
                           interpret: bool, c0: int, Ca: int):
     """The raw (traceable) map-phase pallas_call of the raw hist tier: grid
     over [Sb] series tiles of the f32 block, three [G, B, Tp] accumulators
     (bucket-step sums, their compensation, series counts) in VMEM across
-    the sequential grid. Operands: n and gids [S] i32 (SMEM),
-    the block as [S, B, C] (the resident [S, C, 64] block's own HBM layout,
-    see build_hist_pallas), bf16 0/1 bands [Ca, Tp], edges [1, Tp].
-    ``(c0, Ca)`` is the active column range (fusedgrid.active_columns): a
-    sub-range query streams and multiplies only its own columns — unlike
-    the narrow tier, whose frames telescope from cell 0. Cached by its
-    caller's plan-cache entry."""
+    the sequential grid, and the count of tiles that took the correction
+    matmul, [1] i32 in SMEM. Operands: n and gids [S] i32 and ``last [1]``
+    (SMEM), the block as [S, B, C] (the resident [S, C, 64] block's own HBM
+    layout, see build_hist_pallas), raw_hist_weights' bf16 ``w [Ca, N]``
+    and ``band [Ca, Tp]``, ``used [1, Ca]``, edges [1, Tp]. ``(c0, Ca)`` is the active
+    column range (fusedgrid.active_columns): a sub-range query streams and
+    multiplies only its own columns — unlike the narrow tier, whose frames
+    telescope from cell 0. Cached by its caller's plan-cache entry."""
     sblk = 1024 if S % 1024 == 0 else S
     per = sblk // Sb
     body = functools.partial(_raw_hist_kernel_body, fn, window_ms,
@@ -691,32 +818,34 @@ def build_raw_hist_pallas(fn: str, window_ms: int, interval_ms: int, S: int,
                               memory_space=pltpu.VMEM)
     scalars = pl.BlockSpec((sblk,), lambda i: (i // per,),
                            memory_space=pltpu.SMEM)
+    count = pl.BlockSpec((1,), lambda i: (0,), memory_space=pltpu.SMEM)
     kcol = c0 // Ca                     # active_columns: c0 % Ca == 0
     in_specs = [
-        scalars, scalars,                                       # n, gid
+        scalars, scalars, count,                                # n, gid, last
         pl.BlockSpec((Sb, B, Ca), lambda i: (i, 0, kcol),
                      memory_space=pltpu.VMEM),                  # the block
-        const((Ca, Tp)), const((Ca, Tp)),                       # bands
+        const((Ca, N)), const((Ca, Tp)), const((1, Ca)),        # w, band, used
         const((1, Tp)), const((1, Tp)), const((1, Tp)),         # lo, hi, rel
     ]
     Cp, Bp = _pad(Ca, 128), _pad(B, 8)
     tile = Sb * Bp * Cp * 4
     footprint = (2 * tile                                       # the block
-                 + 2 * tile + 2 * Sb * Bp * Tp * 4              # scratch
-                 + 2 * 2 * _pad(Ca, 16) * Tp * 2                # bands
+                 + tile + 2 * Sb * Bp * Tp * 4                  # scratch
+                 + 2 * _pad(Ca, 16) * (N + Tp) * 2              # w, band
                  + 2 * 3 * G * Bp * Tp * 4)                     # accumulators
-    # + the matmuls' working set: three bf16 pieces and the f32 remainder
-    # of one [Sb * B, Ca] operand, and a dozen [B, Tp] planes of the fold
-    footprint += 3 * tile + 12 * Bp * Tp * 4
+    # + a matmul's working set: three bf16 pieces and the f32 remainder of
+    # the [Sb * B, Ca] operand, its [Sb * B, N] product, and a dozen
+    # [B, Tp] planes of the fold
+    footprint += 3 * tile + Sb * Bp * N * 4 + 12 * Bp * Tp * 4
     return pl.pallas_call(
         body,
         grid=(S // Sb,),
         in_specs=in_specs,
-        out_specs=(acc, acc, acc),
-        out_shape=tuple(jax.ShapeDtypeStruct((G, B, Tp), jnp.float32)
-                        for _ in range(3)),
+        out_specs=(acc, acc, acc, count),
+        out_shape=(*(jax.ShapeDtypeStruct((G, B, Tp), jnp.float32)
+                     for _ in range(3)),
+                   jax.ShapeDtypeStruct((1,), jnp.int32)),
         scratch_shapes=[pltpu.VMEM((Sb, B, Ca), jnp.float32),
-                        pltpu.VMEM((Sb, B, Ca), jnp.float32),
                         pltpu.VMEM((Sb, B, Tp), jnp.float32),
                         pltpu.VMEM((Sb, B, Tp), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
@@ -728,22 +857,31 @@ def build_raw_hist_pallas(fn: str, window_ms: int, interval_ms: int, S: int,
 
 
 def build_raw_hist_xla_tiles(fn: str, window_ms: int, interval_ms: int,
-                             S: int, Sb: int, C: int, Tp: int, B: int, G: int,
-                             c0: int, Ca: int):
+                             S: int, Sb: int, C: int, Tp: int, N: int,
+                             B: int, G: int, c0: int, Ca: int):
     """XLA twin of :func:`build_raw_hist_pallas` from the same tiling plan:
     a loop over the same [Sb, B, Ca] tiles (sliced out of the resident
     block one at a time, never the active columns of the whole store),
-    through the same raw_hist_series_inc, dot_exact01 and hist_extrapolate,
-    folding series by series with the same compensated adds. Unlike the
-    other tiers' twins the two are not bit-equal: the compensation keeps
-    the last bit of every contribution, where the compilers differ (a
-    multiply contracted with the subtraction after it, or not) — they
+    through the same raw_hist_values, dot_exact01, unpack_halves and
+    hist_extrapolate, raw_hist_corr's matmul under a ``cond`` on the same
+    test, folding series by series with the same compensated adds. Unlike
+    the other tiers' twins the two are not bit-equal: the compensation
+    keeps the last bit of every contribution, where the compilers differ
+    (a multiply contracted with the subtraction after it, or not) — they
     agree to a few f32 ulps of the answer."""
     f32 = jnp.float32
 
-    def call(n, gids, blk, band, ohlo, lo, hi, rel):
-        inc_of = jax.vmap(lambda x, n_s: raw_hist_series_inc(
-            fn, c0, x, n_s, roll=lambda a: jnp.roll(a, 1, axis=1)))
+    def call(n, gids, last, blk, w, band, used, lo, hi, rel):
+        def roll1(a):
+            return jnp.roll(a, 1, axis=1)
+
+        values_of = jax.vmap(lambda x, n_s: raw_hist_values(c0, x, n_s))
+        corr_of = jax.vmap(lambda v, n_s: raw_hist_corr(fn, c0, v, n_s, used,
+                                                        roll1))
+
+        def matmul(x, wt):
+            return dot_exact01(x.reshape(Sb * B, Ca), wt)
+
         def extrap_one(d, f, n_s):
             contrib, okb = hist_extrapolate(fn, window_ms, interval_ms, d, f,
                                             n_s, lo, hi, rel)
@@ -752,13 +890,22 @@ def build_raw_hist_xla_tiles(fn: str, window_ms: int, interval_ms: int,
         extrap = jax.vmap(extrap_one)
 
         def tile(i, carry):
+            *acc, falls = carry
             n_t = jax.lax.dynamic_slice(n, (i * Sb,), (Sb,))
             g_t = jax.lax.dynamic_slice(gids, (i * Sb,), (Sb,))
             x_t = jax.lax.dynamic_slice(blk, (i * Sb, 0, c0), (Sb, B, Ca))
-            v, inc = inc_of(x_t, n_t)
-            d = dot_exact01(inc.reshape(Sb * B, Ca), band).reshape(Sb, B, Tp)
-            f = dot_exact01(v.reshape(Sb * B, Ca), ohlo).reshape(Sb, B, Tp)
-            contrib, okb = extrap(d, f, n_t)
+            v = values_of(x_t, n_t)
+            fell = jnp.any((n_t > c0) & (n_t <= last[0]))
+            if fn != "delta":
+                drops = raw_hist_drops(v.reshape(Sb * B, Ca), roll1)
+                fell |= jnp.any((used != 0) & (drops > 0.0))
+            d, f = unpack_halves(matmul(v, w), Tp,
+                                 lambda a, k: jnp.roll(a, k, axis=1))
+            d = jax.lax.cond(
+                fell, lambda d: d + matmul(corr_of(v, n_t), band),
+                lambda d: d, d)
+            contrib, okb = extrap(d.reshape(Sb, B, Tp), f.reshape(Sb, B, Tp),
+                                  n_t)
             live = (n_t > 0) & (g_t >= 0) & (g_t < G)
             gi = jnp.clip(g_t, 0, G - 1)
 
@@ -769,9 +916,11 @@ def build_raw_hist_xla_tiles(fn: str, window_ms: int, interval_ms: int,
                         acc[1].at[g_s].set(jnp.where(live_s, comp,
                                                      acc[1][g_s])),
                         acc[2].at[g_s].add(jnp.where(live_s, k_s, 0.0))), None
-            return jax.lax.scan(fold, carry, (gi, live, contrib, okb))[0]
+            acc = jax.lax.scan(fold, tuple(acc), (gi, live, contrib, okb))[0]
+            return (*acc, falls + fell.astype(jnp.int32))
 
-        init = tuple(jnp.zeros((G, B, Tp), f32) for _ in range(3))
+        init = (*(jnp.zeros((G, B, Tp), f32) for _ in range(3)),
+                jnp.zeros((1,), jnp.int32))
         return jax.lax.fori_loop(0, S // Sb, tile, init)
 
     return call
@@ -779,25 +928,24 @@ def build_raw_hist_xla_tiles(fn: str, window_ms: int, interval_ms: int,
 
 def raw_hist_map_body(variant: str, fn: str, window_ms: int,
                       interval_ms: int, S: int, Sb: int, C: int, Tp: int,
-                      B: int, G: int, c0: int, Ca: int):
+                      N: int, B: int, G: int, c0: int, Ca: int):
     """The traceable map phase of the raw tier as it is served: ``variant``
     is fusedgrid.kernel_tag's name ("xla" | "pallas" | "pallas-interpret"),
     and dtype casts and the relabelling ride the one dispatch."""
     if variant == "xla":
         call = build_raw_hist_xla_tiles(fn, window_ms, interval_ms, S, Sb, C,
-                                        Tp, B, G, c0, Ca)
+                                        Tp, N, B, G, c0, Ca)
     else:
         call = build_raw_hist_pallas(fn, window_ms, interval_ms, S, Sb, C,
-                                     Tp, B, G, variant != "pallas", c0, Ca)
+                                     Tp, N, B, G, variant != "pallas", c0,
+                                     Ca)
 
-    def wrapped(val, n, gids, band, ohlo, lo, hi, rel):
+    def wrapped(val, n, gids, last, w, band, used, lo, hi, rel):
         # [S, C, B] -> [S, B, C]: a relabelling of the resident block's own
-        # layout on the TPU (build_hist_pallas); the 0/1 bands go in as
-        # bf16 (dot_exact01)
-        return call(n.astype(jnp.int32), gids.astype(jnp.int32),
+        # layout on the TPU (build_hist_pallas)
+        return call(n.astype(jnp.int32), gids.astype(jnp.int32), last,
                     val.astype(jnp.float32).transpose(0, 2, 1),
-                    band.astype(jnp.bfloat16), ohlo.astype(jnp.bfloat16),
-                    lo, hi, rel)
+                    w, band, used, lo, hi, rel)
     return wrapped
 
 
@@ -831,38 +979,40 @@ def fused_hist_quantile_raw(q: float, les, val, n, gids, num_groups: int,
     """histogram_quantile(q, sum by(...)(fn(m[w]))) over a RAW f32
     ``[S, C, B]`` block, map phase per the active mode: no [S, C, B]-sized
     temporary exists. Operands as fused_hist_quantile_resident's, with the
-    block itself in place of the 2D-delta state; the band and edge operands
-    are the scalar tier's own (fusedgrid's cache). Returns ``(out, tags)``:
-    the [G, T] device array, NOT fetched — the caller dispatches under the
-    shard lock and fetches outside it — and the dispatch's shape as the
-    ``query.exec.kernel`` span's tags."""
+    block itself in place of the 2D-delta state and raw_hist_weights'
+    packed weight in place of the two bands. Returns ``(out, fall_tiles,
+    tags)``, both device arrays NOT fetched — the caller dispatches under
+    the shard lock and fetches outside it: the [G, T] answer and the [1]
+    count of tiles that ran the correction matmul — and the dispatch's
+    shape as the ``query.exec.kernel`` span's tags (``packed``: the one
+    weight is narrower than two bands)."""
     assert fn in HIST_FUSED_FNS
     S, C, B = val.shape
     T = len(out_ts)
     G = _roundup(max(num_groups, 8), 8)
     assert raw_hist_fusable(S, C, T, B, G), (S, C, T, B, G)
-    Tp = _roundup(max(T, 1), 128)
     Sb = raw_hist_rows_per_tile(S)
     variant = variant or _mode
     assert variant in ("xla", "pallas")
     variant = fusedgrid.kernel_tag(variant)
 
-    band, ohlo, lo_d, hi_d, rel_d, c0, Ca = fusedgrid._device_operands(
-        C, Tp, np.ascontiguousarray(np.asarray(out_ts, np.int64)).tobytes(),
-        int(window_ms), int(base_ts), int(interval_ms), "rate", False)
+    *ops, c0, Ca = _raw_hist_device_operands(
+        C, np.ascontiguousarray(np.asarray(out_ts, np.int64)).tobytes(),
+        int(window_ms), int(base_ts), int(interval_ms))
+    Tp, N = ops[2].shape[1], ops[1].shape[1]          # band's, w's
     # a kernel variant of its own in the plan cache, as _hist_map_program
     from ..query.plancache import plan_cache
-    key = (variant, fn, int(window_ms), int(interval_ms), S, Sb, C, Tp, B, G,
-           c0, Ca)
+    key = (variant, fn, int(window_ms), int(interval_ms), S, Sb, C, Tp, N, B,
+           G, c0, Ca)
     prog = plan_cache.program("fusedres-hist-raw", key,
                               lambda: raw_hist_map_body(*key))
     with jax.enable_x64(False):       # as fused_hist_quantile_resident
-        psum, pcomp, pcnt = prog(val, jnp.asarray(n), jnp.asarray(gids),
-                                 band, ohlo, lo_d, hi_d, rel_d)
+        psum, pcomp, pcnt, falls = prog(val, jnp.asarray(n),
+                                        jnp.asarray(gids), *ops)
     fin = plan_cache.program("fusedres-hist-raw-finish",
                              (G, T, Tp, B, int(les.shape[0])),
                              lambda: raw_hist_finish(G, T, B))
     out = fin(jnp.float64(q), jnp.asarray(les), psum, pcomp, pcnt)
-    return out, {"kernel": variant, "rows": S, "c0": c0, "cols": Ca,
-                 "steps": T, "groups": num_groups, "buckets": B,
-                 "variant": "hist-raw"}
+    return out, falls, {"kernel": variant, "rows": S, "c0": c0, "cols": Ca,
+                        "steps": T, "groups": num_groups, "buckets": B,
+                        "variant": "hist-raw", "packed": int(N < 2 * Tp)}

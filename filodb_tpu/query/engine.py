@@ -1083,15 +1083,17 @@ class QueryEngine:
                 ltags["lock_wait_ms"] = (lock_wait_ns() - waited) / 1e6
         if got is None:
             return None
-        out, path, uniq, G, T = got
+        out, falls, path, uniq, G, T = got
         self._set_path(ctx, path)
         ctx.stats.merge(pctx.stats)             # committed: fused serves
         if out is None:
             return QueryResult(ResultMatrix(
                 out_ts, np.zeros((0, len(out_ts))), []))
         # the blocking fetch, outside the lock (the in-process leaf's rule)
-        with span(SPAN_QUERY_KERNEL, phase="fetch"):
+        with span(SPAN_QUERY_KERNEL, phase="fetch") as ftags:
             vals = np.asarray(out)[:G, :T]
+            if falls is not None:
+                ftags["fall_tiles"] = fusedresident.count_fall_tiles(falls)
         m = ResultMatrix(out_ts, vals, list(uniq))
         check_sample_limit(m.num_series, T, self.config.sample_limit)
         return QueryResult(m)
@@ -1103,9 +1105,11 @@ class QueryEngine:
         shape — the tiled kernel over the 2D-delta state
         (``fused-hist-narrow[...]``), the tiled kernel over the raw f32
         block (``fused-hist[...]``), or the untiled composition (bare
-        ``fused-hist``) for what neither gate takes. Returns ``(out, path,
-        group keys, G, T)`` — ``out`` the [G, T] device array, not fetched,
-        None for an empty selection — or None: general path."""
+        ``fused-hist``) for what neither gate takes. Returns ``(out,
+        fall_tiles, path, group keys, G, T)`` — ``out`` the [G, T] device
+        array, not fetched, None for an empty selection; ``fall_tiles`` the
+        raw tier's count of correction matmuls, on the device too, None
+        from the other programs — or None: general path."""
         from ..ops import fusedresident, gridfns
         from .exec import (SeriesSelection, _group_ids_for, _pad_steps,
                            _pow2)
@@ -1126,11 +1130,11 @@ class QueryEngine:
         gids, uniq, G = _group_ids_for(data.keys, data.rows, R,
                                        agg.by, agg.without)
         if not uniq:
-            return None, "fused-hist", uniq, G, T
+            return None, None, "fused-hist", uniq, G, T
         base_ts, interval_ms = data.grid
         les = np.asarray(data.bucket_les, np.float64)
         Gp = _pow2(G)
-        path = "fused-hist"
+        path, falls = "fused-hist", None
         # what the dispatch span says of the program it covers; a tiled
         # kernel adds its backend and column range
         ktags = {"kernel": "xla", "rows": R, "cols": data.val.shape[1],
@@ -1195,7 +1199,7 @@ class QueryEngine:
                 # raw f32 residency (the shipped default): the same shape
                 # streamed over row tiles of the block itself, its own
                 # kernel variant — no [S, C, B]-sized temporary exists
-                out, ktags = fusedresident.fused_hist_quantile_raw(
+                out, falls, ktags = fusedresident.fused_hist_quantile_raw(
                     q, les, data.val, data.n, gids, Gp, out_eval, window,
                     fn, base_ts, interval_ms)
                 ktags.update(steps=T, groups=G)
@@ -1213,7 +1217,7 @@ class QueryEngine:
                     q, les, data.val, data.n, gids, Gp, out_eval, window,
                     fn, base_ts, interval_ms, stale_ms=ctx.stale_ms)
             tags.update(ktags)
-        return out, path, uniq, G, T
+        return out, falls, path, uniq, G, T
 
     # -- mesh dispatch (ref: queryengine2/QueryEngine.scala:59-67 — the
     # planner routes every query through per-shard dispatchers; here the

@@ -63,6 +63,7 @@ FILODB_QUERY_ADMISSION_OVERSIZED = "filodb_query_admission_oversized"
 FILODB_QUERY_ADMISSION_COST = "filodb_query_admission_cost"
 FILODB_QUERY_FUSED_SERVED = "filodb_query_fused_served"
 FILODB_QUERY_FUSED_FALLBACK = "filodb_query_fused_fallback"
+FILODB_QUERY_FUSED_FALL_TILES = "filodb_query_fused_fall_tiles"
 FILODB_QUERY_MESH_SERVED = "filodb_query_mesh_served"
 FILODB_QUERY_MESH_FALLBACK = "filodb_query_mesh_fallback"
 FILODB_QUERY_NEGATIVE_CACHE_HITS = "filodb_query_negative_cache_hits"
@@ -228,6 +229,12 @@ METRICS_SPEC: dict[str, tuple[str, str]] = {
         "counter", "Queries that matched a fused shape but fell back to "
                    "the composed two-step path (shape gate, group cap, "
                    "off-grid store), tagged by shape."),
+    FILODB_QUERY_FUSED_FALL_TILES: (
+        "counter", "Row tiles of the raw hist kernel that ran its "
+                   "correction matmul — a counter reset or a series' last "
+                   "sample under a query window — summed over the queries "
+                   "it served, tagged by backend mode; 0 for counters that "
+                   "only grow: each query then costs one matmul a tile."),
     FILODB_QUERY_MESH_SERVED: (
         "counter", "Queries served by a mesh dist_* collective, tagged by "
                    "route (fused / fused-narrow / twostep / sketch / topk) "

@@ -143,7 +143,11 @@ TRACE_SPEC: dict[str, str] = {
                        "blocking fetch of its result outside it (dispatch "
                        "tags: kernel, rows, c0, cols, steps, groups; the "
                        "fused-hist route adds buckets and variant = "
-                       "hist-raw | hist-int8 | hist-int16 | hist-untiled).",
+                       "hist-raw | hist-int8 | hist-int16 | hist-untiled; "
+                       "hist-raw adds packed = 1 where one weight narrower "
+                       "than two bands carried both of a tile's products, "
+                       "and on the fetch span fall_tiles = the tiles that "
+                       "ran the correction matmul).",
     SPAN_QUERY_REDUCE: "Cross-shard reduce merge of child partials.",
     SPAN_QUERY_DISPATCH: "One cross-node /exec POST (tags: endpoint, "
                          "shards).",

@@ -27,6 +27,9 @@ Tolerances, each with its reason:
 - tiled against the untiled composition: the same rtol 2e-4 — the
   composition is the LESS exact side (it folds cumulative buckets in f32:
   its own error against f64 reaches 0.3 of the tolerance at a p99).
+- the packed weight against the sum of increments it replaces: equal, on
+  integers below 2^24 (a telescoped sum of integers, plus integer
+  corrections where the increments are clipped or the row ends).
 """
 
 import dataclasses
@@ -88,29 +91,43 @@ SHAPES = {"1024x64x8": (1024, 64, 8), "2048x128x64": (2048, 128, 64)}
 def block(request):
     S, C, B = SHAPES[request.param]
     v, n = _block(S, C, B, 29)
-    les = datagen.bucket_les(B)
-    # steps off the grid, the first windows reaching before cell 0
-    out_ts = BASE + 2 * IV + 3_000 + np.arange(24) * ((C - 4) * IV // 24)
-    return v, jnp.asarray(v, jnp.float32), n, les, out_ts
+    return v, jnp.asarray(v, jnp.float32), n, datagen.bucket_les(B)
 
 
-@pytest.mark.parametrize("G", [1, 8])
-@pytest.mark.parametrize("q", [0.5, 0.9, 0.99])
-@pytest.mark.parametrize("fn", ["rate", "increase", "delta"])
-def test_tiled_raw_kernel_against_composition_and_reference(block, fn, q, G):
-    v64, v32, n, les, out_ts = block
+def _steps(C, T):
+    """``T`` steps off the grid, the first windows reaching before cell 0,
+    the last ones past the short rows' ends."""
+    return BASE + 2 * IV + 3_000 + np.arange(T) * ((C - 4) * IV // T)
+
+
+FNS = ["rate", "increase", "delta"]
+
+
+# 24 steps: the weight's halves share 128 columns; 100: two bands wide;
+# 160: three of four, the halves meeting inside a lane tile
+@pytest.mark.parametrize("fn, q, G, T", [
+    (fn, q, G, 24) for fn in FNS for q in (0.5, 0.9, 0.99) for G in (1, 8)
+] + [(fn, 0.9, 8, 100) for fn in FNS] + [("rate", 0.9, 8, 160)])
+def test_tiled_raw_kernel_against_composition_and_reference(block, fn, q, G,
+                                                            T):
+    v64, v32, n, les = block
     S, C, B = v64.shape
+    out_ts = _steps(C, T)
     assert fusedresident.raw_hist_fusable(S, C, len(out_ts), B, 8)
     gids = (np.arange(S) % G).astype(np.int32)
     window = 120_000
     outs = {}
     for variant in ("xla", "pallas"):
-        out, tags = fusedresident.fused_hist_quantile_raw(
+        out, _falls, tags = fusedresident.fused_hist_quantile_raw(
             q, les, v32, n, gids, 8, out_ts, window, fn, BASE, IV,
             variant=variant)
         assert tags["kernel"] == fusedgrid.kernel_tag(variant)
         assert (tags["rows"], tags["buckets"]) == (S, B)
-        outs[variant] = np.asarray(out)[:G]
+        assert tags["packed"] == (T != 100)
+        outs[variant] = np.asarray(out)[:G], int(np.asarray(_falls)[0])
+    # the block's one fall, and rows that end under a window in every tile
+    assert outs["xla"][1] == outs["pallas"][1] == S // 16
+    outs = {k: o[0] for k, o in outs.items()}
     np.testing.assert_allclose(outs["xla"], outs["pallas"], rtol=1e-6)
     want = ref.group_quantile(
         q, les, ref.bucket_rates(fn, v64, np.arange(C), n, out_ts, window,
@@ -123,23 +140,139 @@ def test_tiled_raw_kernel_against_composition_and_reference(block, fn, q, G):
     assert err_ratio(comp, want) <= 1.0
 
 
-def test_a_sub_range_query_streams_only_its_columns():
+@pytest.mark.parametrize("short", [None, 236])
+def test_a_sub_range_query_streams_only_its_columns(short):
     """The raw tier slices columns (the narrow tier cannot: its frames
     telescope from cell 0): the last 10 windows of a 256-cell store read one
-    128-column block, and answer as the whole store does."""
+    128-column block, and answer as the whole store does — also where rows
+    end at cell ``short``, inside the block and under its windows: the
+    packed weight reads their zeroed cells, and the correction puts the
+    last sample back."""
     S, C, B = 64, 256, 8
     v, n = _block(S, C, B, 5)
+    if short:
+        n[1::5] = short
+        v[1::5, short:] = 0.0
     les = datagen.bucket_les(B)
     out_ts = BASE + (C - 40) * IV + 1_000 + np.arange(10) * 30_000
     gids = (np.arange(S) % 8).astype(np.int32)
     v32 = jnp.asarray(v, jnp.float32)
-    out, tags = fusedresident.fused_hist_quantile_raw(
+    out, falls, tags = fusedresident.fused_hist_quantile_raw(
         0.9, les, v32, n, gids, 8, out_ts, 60_000, "rate", BASE, IV)
     assert (tags["c0"], tags["cols"]) == (128, 128)
+    # the block's own short rows end at cell 128, 1 and 0: none in a window
+    assert int(np.asarray(falls)[0]) == (S // 16 if short else 0)
     want = ref.group_quantile(
         0.9, les, ref.bucket_rates("rate", v, np.arange(C), n, out_ts,
                                    60_000, IV), gids, 8)
     assert err_ratio(np.asarray(out), want) <= 0.25
+
+
+def _counters(S, C, B, seed, falls):
+    """Monotone integer buckets ``[S, C, B]``, every row full, and a reset
+    to zero at ``(series, cell)`` for each of ``falls``."""
+    rng = np.random.default_rng(seed)
+    v = np.cumsum(np.cumsum(rng.integers(0, 32, (S, C, B)), axis=1), axis=2)
+    for s_, c_ in falls:
+        v[s_, c_:] -= v[s_, c_]
+    return v.astype(np.float64), np.full(S, C, np.int32)
+
+
+@pytest.mark.parametrize("variant", ["xla", "pallas"])
+@pytest.mark.parametrize("falls, want_tiles", [
+    ([], 0),                                      # counters that only grow
+    ([(3, 40), (20, 90), (37, 41), (63, 100)], 4),  # a reset in every tile
+    ([(3, 40), (5, 60)], 1),                      # two in one tile
+    ([(20, 8), (40, 120)], 0),                    # under no window
+], ids=["none", "every", "one", "unread"])
+def test_fall_tiles_counts_the_tiles_that_took_the_correction(
+        falls, want_tiles, variant):
+    """64 series = 4 tiles of 16; windows of 6 cells at steps of 5 cover
+    cells 25..110 of 128. A tile runs the second matmul only where one of
+    its series falls in a cell some window sums; the answer is the
+    reference's either way."""
+    S, C, B = 64, 128, 8
+    v, n = _counters(S, C, B, 11, falls)
+    les = datagen.bucket_les(B)
+    out_ts = BASE + 30 * IV + 2_000 + np.arange(17) * 50_000
+    gids = (np.arange(S) % 8).astype(np.int32)
+    out, got, _tags = fusedresident.fused_hist_quantile_raw(
+        0.9, les, jnp.asarray(v, jnp.float32), n, gids, 8, out_ts, 60_000,
+        "rate", BASE, IV, variant=variant)
+    assert int(np.asarray(got)[0]) == want_tiles
+    want = ref.group_quantile(
+        0.9, les, ref.bucket_rates("rate", v, np.arange(C), n, out_ts,
+                                   60_000, IV), gids, 8)
+    assert err_ratio(np.asarray(out), want) <= 0.25
+
+
+@pytest.mark.parametrize("c0_from", [0, 150])
+@pytest.mark.parametrize("T", [24, 100])
+@pytest.mark.parametrize("fn", FNS)
+def test_the_packed_weight_telescopes_the_sum_of_increments(fn, T, c0_from):
+    """What one matmul of the values gives — ``v[hi] - v[lo]`` and the first
+    sample, side by side — plus the correction's matmul equals, to the
+    integer, what the two matmuls it replaces gave: the sum of clipped
+    increments over the open band (``_grid_hist_kernel``'s) and the values
+    at the first-sample one-hot. On the block with a fall, short and empty
+    rows; from cell 0 with windows before it, and on a column sub-range."""
+    S, C, B = 32, 256, 8
+    v, n = _block(S, C, B, 3)
+    n[4] = 200                                   # ends under a window
+    v[4, 200:] = 0.0
+    out_ts = BASE + c0_from * IV + 3_000 + np.arange(T) * (
+        (C - 4 - c0_from) * IV // T)
+    last, w, band, used, _lo, _hi, _rel, c0, Ca = \
+        fusedresident.raw_hist_weights(C, out_ts, 120_000, BASE, IV)
+    Tp, N = band.shape[1], w.shape[1]
+    assert (c0 > 0) == (c0_from > 0) and w.shape[0] == band.shape[0] == Ca
+    assert N == (128 if T <= 64 else 256) and Tp == 128
+    assert set(np.unique(w)) <= {-1.0, 0.0, 1.0}
+    assert (np.abs(w[:, :N // 2]).sum(0) <= 2).all() \
+        and (w[:, :N // 2].sum(0) == 0).all() \
+        and (w[:, N // 2:N // 2 + T].sum(0) == 1).all() \
+        and not w[:, T:N // 2].any() and not w[:, N // 2 + T:].any()
+    # the two products of before, in f64 on the whole store
+    lo, hi = gridfns.grid_edges(out_ts, 120_000, BASE, IV)
+    valid = np.arange(C)[None, :, None] < n[:, None, None]
+    vz = np.where(valid, v, 0.0)
+    raw = np.diff(vz, axis=1, prepend=vz[:, :1])
+    inc = np.where(valid, raw if fn == "delta" else np.maximum(raw, 0), 0.0)
+    want_d = np.einsum("scb,ct->sbt", inc,
+                       gridfns.band_matrix(C, lo, hi, True, np.float64))
+    want_f = np.einsum("scb,ct->sbt", vz, gridfns.onehot_matrix(
+        C, np.maximum(lo, 0), np.float64))
+    assert last[0] == min(hi.max(), C - 1) and used[0, last[0] - c0] \
+        and not used[0, last[0] - c0 + 1:].any()
+
+    def roll1(a):
+        return jnp.roll(a, 1, axis=1)
+    # the tile as it arrives: absent cells hold whatever was there before
+    x = jnp.asarray(np.where(valid, v, 7.0)[:, c0:c0 + Ca].transpose(0, 2, 1),
+                    jnp.float32)
+    vs = jnp.stack([fusedresident.raw_hist_values(c0, x[s_], int(n[s_]))
+                    for s_ in range(S)])
+    corr = jnp.stack([fusedresident.raw_hist_corr(
+        fn, c0, vs[s_], int(n[s_]), jnp.asarray(used), roll1)
+        for s_ in range(S)]).reshape(S * B, Ca)
+    # every cell that needs a correction shows as a drop (the pass each
+    # tile takes looks for no more), or is the cell after a row's end
+    drops = np.asarray(jnp.stack([fusedresident.raw_hist_drops(vs[s_], roll1)
+                                  for s_ in range(S)])) > 0
+    ends = np.arange(c0, c0 + Ca)[None, None, :] == n[:, None, None]
+    need = np.asarray(corr).reshape(S, B, Ca) != 0
+    assert need.any() and not (need & ~(drops | ends)).any()
+    vs = vs.reshape(S * B, Ca)
+    both = np.asarray(fusedresident.dot_exact01(
+        vs, jnp.asarray(w, jnp.bfloat16))).reshape(S, B, N)
+    fix = np.asarray(fusedresident.dot_exact01(
+        corr, jnp.asarray(band, jnp.bfloat16))).reshape(S, B, Tp)
+    np.testing.assert_array_equal(both[:, :, :T] + fix[:, :, :T], want_d)
+    np.testing.assert_array_equal(both[:, :, N // 2:N // 2 + T], want_f)
+    delta, f_v = fusedresident.unpack_halves(
+        jnp.asarray(both[0]), Tp, lambda a, k: jnp.roll(a, k, axis=1))
+    np.testing.assert_array_equal(np.asarray(delta)[:, :T], both[0, :, :T])
+    np.testing.assert_array_equal(np.asarray(f_v)[:, :T], want_f[0])
 
 
 @pytest.mark.parametrize("S, C, T, B, G, ok", [
@@ -157,11 +290,13 @@ def test_the_raw_tiers_gate(S, C, T, B, G, ok):
 def test_dot_exact01_is_exact_where_one_bf16_pass_is_not():
     rng = np.random.default_rng(1)
     x = rng.integers(0, 2**19, (64, 256)).astype(np.float32)
-    w = (rng.random((256, 128)) < 0.1).astype(np.float32)
+    w = ((rng.random((256, 128)) < 0.1).astype(np.float32)
+         - (rng.random((256, 128)) < 0.1))        # -1, 0 and 1
+    assert set(np.unique(w)) == {-1.0, 0.0, 1.0}
     want = x.astype(np.float64) @ w.astype(np.float64)
     got = np.asarray(fusedresident.dot_exact01(
         jnp.asarray(x), jnp.asarray(w, jnp.bfloat16)))
-    keep = want < 2**24                      # the sum itself must fit f32
+    keep = np.abs(want) < 2**24              # the sum itself must fit f32
     assert keep.mean() > 0.9
     np.testing.assert_array_equal(got[keep], want[keep])
     one_pass = np.asarray(jnp.dot(
@@ -342,6 +477,38 @@ def test_served_and_fallback_are_counted_on_the_raw_route(served):
     assert body["stats"]["exec_path"] == "fused-hist"
     s2, f2 = read()
     assert (s2 - s1, f2 - f1) == (0, 1)
+
+
+def test_windows_past_the_rows_end_are_served_through_the_correction(served):
+    """A range that ends after the newest scrape: every row's last sample
+    lies under a window, which the packed weight cannot know (it reads the
+    row's zeroed cell at ``hi``), so all 256 / 16 tiles take the correction
+    matmul — counted in /metrics — and the answer is the reference's."""
+    srv, _sh, get = served
+    url = f"http://127.0.0.1:{srv.http.port}/metrics"
+
+    def fall_tiles():
+        with urllib.request.urlopen(url, timeout=30) as r:
+            return _counter(r.read().decode(),
+                            "filodb_query_fused_fall_tiles_total",
+                            mode="pallas")
+    spec = {"q": 0.9, "fn": "rate", "window_s": 60, "by": ["g"]}
+    promql = "histogram_quantile(0.9, sum by (g)(rate(h[1m])))"
+    step = 15_000
+    for past_ms, want_tiles in ((-5_011, 0), (40_007, N_SERIES // 16)):
+        end = hist.scrape_ms(HEAD, DEPLOY) + past_ms
+        start = end - 120_000
+        before = fall_tiles()
+        body = get(promql, start, end, step)
+        assert body["stats"]["exec_path"] == \
+            f"fused-hist[{fusedresident.tag()}]"
+        assert fall_tiles() - before == want_tiles
+        out_ts = np.arange(start, end + 1, step)
+        got = _rows(body, out_ts, step)
+        want = hist.evaluate(SEED, np.arange(N_SERIES), spec, out_ts, DEPLOY,
+                             HEAD)
+        assert set(got) == set(want) and len(want) == 8
+        assert max(err_ratio(got[k], want[k]) for k in want) <= 1.0
 
 
 def test_one_bf16_pass_misses_the_tolerance():

@@ -114,26 +114,33 @@ def test_hist_kernel_compiles_for_v5e_without_copying_the_store(one_chip,
     assert mem.temp_size_in_bytes < (64 << 20), mem
 
 
-@pytest.mark.parametrize("variant, c0, Ck", [
-    ("pallas", 0, 768),        # a 2 h range: every column
-    ("pallas", 512, 256),      # the last 15 min: two 128-column blocks
-    ("xla", 0, 768),           # the twin from the same tiling plan
+@pytest.mark.parametrize("variant, c0, Ck, T", [
+    ("pallas", 0, 768, 64),    # a 2 h range: every column
+    ("pallas", 512, 256, 64),  # the last 15 min: two 128-column blocks
+    ("pallas", 0, 768, 128),   # past 64 steps: the weight is two bands wide
+    ("pallas", 0, 768, 160),   # ... and its halves meet inside a lane tile
+    ("xla", 0, 768, 64),       # the twin from the same tiling plan
 ])
 def test_raw_hist_kernel_compiles_for_v5e_with_no_store_sized_temp(
-        one_chip, variant, c0, Ck):
+        one_chip, variant, c0, Ck, T):
     """histdev_raw_32k: 2^15 series x 768 cells x 64 buckets of raw f32,
     6.44 GB resident. The served map phase (fusedresident.raw_hist_map_body:
-    casts, the [S, C, B] -> [S, B, C] relabelling, the kernel) must hold no
-    [S, C, B]-sized temporary — the untiled composition holds four — nor a
-    copy of the active columns: its temp stays under 64 MiB."""
-    Sh, C, Tp, B, G = 1 << 15, 768, 128, 64, 8
-    assert fusedresident.raw_hist_fusable(Sh, C, 64, B, G)
+    the cast, the [S, C, B] -> [S, B, C] relabelling, the kernel — one
+    packed matmul a tile, the correction's under a ``when``, the count of
+    those in SMEM) must hold no [S, C, B]-sized temporary — the untiled
+    composition holds four — nor a copy of the active columns: its temp
+    stays under 64 MiB (Mosaic holds the kernel itself to the scoped-VMEM
+    limit build_raw_hist_pallas states, at most VMEM_CAP)."""
+    Sh, C, B, G = 1 << 15, 768, 64, 8
+    Tp, N = -(-T // 128) * 128, -(-2 * T // 128) * 128
+    assert fusedresident.raw_hist_fusable(Sh, C, T, B, G)
     Sb = fusedresident.raw_hist_rows_per_tile(Sh)
     body = fusedresident.raw_hist_map_body(variant, "rate", WINDOW, IV, Sh,
-                                           Sb, C, Tp, B, G, c0, Ck)
+                                           Sb, C, Tp, N, B, G, c0, Ck)
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
     args = [sds((Sh, C, B), f32), sds((Sh,), i32), sds((Sh,), i32),
-            sds((Ck, Tp), f32), sds((Ck, Tp), f32),
+            sds((1,), i32), sds((Ck, N), jnp.bfloat16),
+            sds((Ck, Tp), jnp.bfloat16), sds((1, Ck), i32),
             sds((1, Tp), i32), sds((1, Tp), i32), sds((1, Tp), i32)]
     with jax.enable_x64(False):
         compiled = jax.jit(body).lower(*args).compile()

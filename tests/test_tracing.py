@@ -381,12 +381,19 @@ def test_http_query_is_one_trace_rooted_at_the_request(served):
     assert leaf.tags["lock_wait_ms"] == 0
 
 
-def test_fused_hist_route_is_a_leaf_with_its_parts_and_a_fetch():
+@pytest.mark.parametrize("end_s, fall_tiles", [
+    (800, 0),     # every window inside the rows' 90 samples
+    (950, 1),     # the last ones past them: the one tile with rows in it
+])
+def test_fused_hist_route_is_a_leaf_with_its_parts_and_a_fetch(end_s,
+                                                               fall_tiles):
     """The fused-hist route (query/engine.py ``_try_fused_hist``) records
     what the ExecPlan leaf records: ``query.exec.leaf`` (tag
     ``lock_wait_ms``) > select, group ids, kernel dispatch (the tiled raw
-    hist kernel's tags), and the kernel's fetch beside the leaf, after it
-    and outside the lock — the sums the benchmark's means are read from."""
+    hist kernel's tags, ``packed`` among them), and the kernel's fetch
+    beside the leaf, after it and outside the lock (tag ``fall_tiles``: the
+    tiles that ran the correction matmul) — the sums the benchmark's means
+    are read from."""
     import numpy as np
 
     from filodb_tpu.config import Config
@@ -413,8 +420,8 @@ def test_fused_hist_route_is_a_leaf_with_its_parts_and_a_fetch():
         def get(shift_ms):
             q = urllib.parse.urlencode({
                 "query": "histogram_quantile(0.9, sum by (g)(rate(h[2m])))",
-                "start": (BASE + 300_000 + shift_ms) / 1000,
-                "end": (BASE + 800_000 + shift_ms) / 1000, "step": 10})
+                "start": (BASE + (end_s - 500) * 1000 + shift_ms) / 1000,
+                "end": (BASE + end_s * 1000 + shift_ms) / 1000, "step": 10})
             url = (f"http://127.0.0.1:{srv.http.port}/promql/hists/api/v1/"
                    f"query_range?{q}")
             with urllib.request.urlopen(url, timeout=60) as r:
@@ -447,7 +454,8 @@ def test_fused_hist_route_is_a_leaf_with_its_parts_and_a_fetch():
     assert disp.tags == {
         "phase": "dispatch", "kernel": fusedresident.tag(), "rows": 32,
         "c0": 0, "cols": 128, "steps": 51, "groups": 4, "buckets": 8,
-        "variant": "hist-raw"}
+        "variant": "hist-raw", "packed": 1}
+    assert fetch.tags == {"phase": "fetch", "fall_tiles": fall_tiles}
     inner = sel.duration_us + gid.duration_us + disp.duration_us
     assert inner <= leaf.duration_us + 3
 
