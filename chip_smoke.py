@@ -48,8 +48,7 @@ RTOL, ATOL = 2e-4, 1e-4          # the tier-1 fused-vs-reference bound
 # the name ops/fusedgrid.kernel_tag gives a Mosaic-COMPILED kernel; the
 # interpreted form reads "pallas-interpret" and fails every route check
 EXPECT_TAG = "pallas"
-# a multi-device TPU mesh resolves query.mesh_programs=auto to one pjit
-# program per route (parallel/distributed.resolved_mesh_mode)
+# the mesh serves one pjit program per route (parallel/distributed.py)
 MESH_PREFIX = "mesh[pjit]-"
 
 _t0 = time.perf_counter()

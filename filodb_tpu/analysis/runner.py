@@ -93,7 +93,8 @@ class AnalysisReport:
         if self.corpus_stats:
             cs = self.corpus_stats
             lines.append(
-                f"  corpus: {cs.get('modules', 0)} modules, "
+                f"  corpus: {cs.get('modules', 0)} modules "
+                f"({cs.get('files_parsed', 0)} parse(s)), "
                 f"{cs.get('index_builds', 0)} index build(s) "
                 f"({cs.get('index_build_s', 0.0)}s), "
                 f"{cs.get('cfg_builds', 0)} CFG build(s) / "
@@ -223,7 +224,8 @@ def run_analysis(root: Path | str, paths: list[str] | None = None,
     ``baseline_path="auto"`` uses <root>/filolint_baseline.json when present.
     ``shared_corpus=False`` runs each rule family against its own freshly
     parsed corpus + index (the pre-sharing cost model, kept for the tier-1
-    timing assertion; findings are identical). Returns an AnalysisReport
+    counting assertion — ``corpus_stats["files_parsed"]`` is every file once
+    against once per family; findings are identical). Returns an AnalysisReport
     with findings split into new / inline-suppressed / baselined."""
     t_start = time.perf_counter()
     root = Path(root)
@@ -258,11 +260,14 @@ def run_analysis(root: Path | str, paths: list[str] | None = None,
             report.timings[type(c).__name__] = time.perf_counter() - t0
         findings += _finalize(checkers, corpus.modules, corpus=corpus,
                               timings=report.timings)
-        report.corpus_stats = corpus.stats()
+        report.corpus_stats = {**corpus.stats(),
+                               "files_parsed": len(corpus.modules)}
     else:
         # legacy per-family cost model: every family pays its own parse of
         # the whole file set AND its own PackageIndex/CFG builds
         n_families = len(_default_checkers(wire_spec, full_scope))
+        total = report.corpus_stats = {"files_parsed": 0, "index_builds": 0,
+                                       "cfg_builds": 0, "cfg_hits": 0}
         for i in range(n_families):
             c = _default_checkers(wire_spec, full_scope)[i]
             t0 = time.perf_counter()
@@ -272,6 +277,11 @@ def run_analysis(root: Path | str, paths: list[str] | None = None,
             for rel, tree in corpus.modules.items():
                 findings += c.check_module(rel, tree)
             findings += _finalize([c], corpus.modules, corpus=corpus)
+            cs = corpus.stats()
+            total["modules"] = cs["modules"]
+            total["files_parsed"] += cs["modules"]
+            for k in ("index_builds", "cfg_builds", "cfg_hits"):
+                total[k] += cs[k]
             report.timings[type(c).__name__] = \
                 report.timings.get(type(c).__name__, 0.0) + \
                 (time.perf_counter() - t0)

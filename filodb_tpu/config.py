@@ -68,10 +68,6 @@ CONFIG_SPEC: dict[str, tuple[str, Any, str]] = {
         "Max fraction of live rows allowed in the raw cohort pool before "
         "a store declines compressed residency (and counts a "
         "residency-fallback)."),
-    "store.narrow_mirror": (
-        "bool", False,
-        "Keep an i16 mirror ALONGSIDE raw f32 (bandwidth, not capacity); "
-        "ignored when compressed_residency is active."),
     "index.persist": (
         "bool", True,
         "Persist the part-key index as columnar time-bucket frames "
@@ -166,18 +162,6 @@ CONFIG_SPEC: dict[str, tuple[str, Any, str]] = {
         "xla = one XLA-fused program per shape (lax.scan over the same "
         "row tiles), pallas = single-pass Pallas kernels (interpret-mode "
         "on CPU, compiled Mosaic on TPU)."),
-    "query.mesh_programs": (
-        "str", "auto",
-        "Mesh dist_* program mode (parallel/distributed.py): pjit = one "
-        "global-view sharded executable per padded query shape, explicit "
-        "NamedSharding in/out boundaries plus operand donation; shard_map "
-        "= the plain jitted per-device path; auto = pjit on a multi-device "
-        "non-CPU backend, shard_map fallback on single-device/CPU CI."),
-    "query.mesh_donation": (
-        "bool", True,
-        "Donate the per-query group-id globals to pjit-mode mesh programs "
-        "so XLA reuses their buffers in place (TPU/GPU only; the CPU "
-        "backend lacks buffer donation and the flag is ignored there)."),
     "query.max_concurrent_cost": (
         "int|null", None,
         "Aggregate estimated query cost (series x steps x window-steps) "
@@ -510,7 +494,6 @@ class Config:
             dtype=s["dtype"],
             compressed_residency=s.get("compressed_residency", "off"),
             narrow_cohort_gate=float(s.get("narrow_cohort_gate", 0.25)),
-            narrow_mirror=bool(s.get("narrow_mirror", False)),
         )
 
     def query_config(self):

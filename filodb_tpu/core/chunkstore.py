@@ -432,10 +432,6 @@ class SeriesStore:
         # throttle() after releasing the lock.
         self._appends_since_sync = 0
         self.max_inflight = 8
-        # lazily-built u16 quantized mirror of the default value column
-        # (ops/narrow.py); the query leaf consults it when enabled
-        from ..ops.narrow import NarrowMirror
-        self.narrow = NarrowMirror()
         # narrow-RESIDENT state (StoreConfig.narrow_resident /
         # compressed_residency): (kind, ops, pool, pp, slot, ok_host) where
         # kind names the decode variant (ops/decodereg.py: "quant16" |

@@ -140,14 +140,6 @@ class StoreConfig:
     groups_per_shard: int = 16
     retention_ms: int = 3 * 3600 * 1000
     dtype: str = "float32"
-    # maintain an i16 quantized mirror of f32 value columns (ops/narrow.py):
-    # halves the HBM bytes the fused query path streams (bit-exact for
-    # integer-valued series; raw-f32 fallback per row otherwise). OFF by
-    # default: on this TPU generation the fused kernel is MXU-bound (band
-    # matmuls), so fewer HBM bytes measured ~1.5ms/query SLOWER at 1M
-    # series — enable on deployments where the value stream, not the MXU,
-    # is the measured bottleneck
-    narrow_mirror: bool = False
     # narrow-RESIDENT: after each flush the f32 value block compresses to
     # i16 (q, vmin, scale) + a raw-f32 cohort pool for non-quantizable rows
     # and the f32 array is FREED — ~2x value-retention per HBM byte. Appends
@@ -946,12 +938,6 @@ class TimeSeriesShard:
         t0 = time.perf_counter_ns()
         self.store.throttle()   # the one wait of the write path for the device
         tags["throttle_ms"] = (time.perf_counter_ns() - t0) / 1e6
-        if self.config.narrow_mirror and residency == "off":
-            # flush-time rebuild, outside the lock: the build streams the
-            # whole store and fetches the ok flags — queries only CONSULT.
-            # (Pointless alongside compressed residency — the i16 state IS
-            # the store there, and refresh would read the freed f32 block.)
-            self.store.narrow.refresh(self.store)
         if self.sink is None and self._pending_offset >= 0:
             # without a durable sink, device residency is the only watermark
             with self.lock:
@@ -976,7 +962,7 @@ class TimeSeriesShard:
         """Build the compressed-resident state without the shard lock, then
         swap under it iff nothing mutated meanwhile (a racing append donates
         the very buffers the build streams — detected and retried next
-        flush; ref: the NarrowMirror outside-the-lock rule). ``mode`` gates
+        flush). ``mode`` gates
         which store shapes compress (histograms only under "all")."""
         st = self.store
         if st is None:

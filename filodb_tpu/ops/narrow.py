@@ -1,12 +1,12 @@
-"""Narrow (compressed) on-device value mirror for the fused query path.
+"""Narrow (compressed) on-device value forms for the fused query path.
 
 Reference role: the read hot path of the reference decompresses NibblePack/
 delta-encoded chunks ON ACCESS (memory/.../format/NibblePack.scala:12-37,
 format/vectors/DoubleVector.scala, doc/compression.md) — bytes-per-sample is
 its main lever against memory bandwidth. The TPU analog here: a u16
-quantized mirror of the f32 store, built in ONE device pass and decoded in
-VMEM inside the fused Pallas kernel, halving the HBM bytes the north-star
-query streams.
+quantized form of the f32 store (and the integer-delta forms below), built
+in ONE device pass and decoded in VMEM inside the fused Pallas kernel,
+halving the HBM bytes the north-star query streams.
 
 Losslessness contract: per row, scale is the largest power of two with
 (vmax - vmin) / scale < 65536; a row is marked ``ok`` only when EVERY valid
@@ -16,10 +16,10 @@ timings) qualify; arbitrary continuous floats do not and take the raw-f32
 path — rows that fail are excluded from the narrow kernel (n forced to 0)
 and folded in via the general kernels, exactly like minority grid cohorts.
 
-The mirror is rebuilt lazily per store mutation epoch: serving workloads
-flush every few seconds but answer many queries per second, so one extra
-streaming pass per flush buys half the bytes on every query between
-flushes.
+The resident form is rebuilt at flush time (core/memstore.py
+``_compress_resident_two_phase``): serving workloads flush every few seconds
+but answer many queries per second, so one extra streaming pass per flush
+buys half the bytes on every query between flushes.
 """
 
 from __future__ import annotations
@@ -205,38 +205,3 @@ def cast_narrow_delta_i8(dv16):
     """i16 -> i8 narrowing when every ok row fits 8 bits; donates (frees) the
     i16 intermediate — flush-path encode never holds both widths."""
     return _cast_delta_i8_call()(dv16)
-
-
-class NarrowMirror:
-    """Narrow mirror of a SeriesStore's value column, refreshed at FLUSH
-    time (outside the shard lock — the build streams the whole store and
-    fetches the per-row ok flags, which must never block queries/ingest
-    waiting on the lock) and only CONSULTED by the query leaf."""
-
-    def __init__(self):
-        self._epoch = -1
-        self._data = None
-
-    @staticmethod
-    def _store_epoch(store) -> int:
-        return (store.stats.samples_appended
-                + store.stats.compactions * 1_000_003)
-
-    def refresh(self, store) -> None:
-        """(Re)build if the store mutated since the last build. Call OUTSIDE
-        the shard lock (flush-time); one streaming pass + one host fetch."""
-        if store.dtype != jnp.float32 or store.val.ndim != 2:
-            return
-        epoch = self._store_epoch(store)
-        if self._data is None or self._epoch != epoch:
-            q, vmin, scale, ok = build_narrow(store.val, store.n)
-            import numpy as np
-            self._data = (q, vmin, scale, np.asarray(ok))
-            self._epoch = epoch
-
-    def get(self, store):
-        """(q, vmin, scale, ok_host) when a CURRENT mirror exists, else None
-        — never builds (query leaves run under the shard lock)."""
-        if self._data is None or self._epoch != self._store_epoch(store):
-            return None
-        return self._data

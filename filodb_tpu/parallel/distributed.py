@@ -12,21 +12,14 @@ reduce -> cross-shard fold lower as a single program, so XLA overlaps decode
 compute against the reduce collectives. The collective *is* the
 scatter-gather: no serialization, no per-shard dispatch.
 
-Two execution modes (config ``query.mesh_programs``):
-
-  * ``pjit``      — the per-shard body (PR 9's fused tiling plan / the
-                    two-step kernels, unchanged) wraps in ``shard_map`` and
-                    jits with EXPLICIT ``in_shardings``/``out_shardings``
-                    (``NamedSharding`` per operand) plus donation of the
-                    per-query group-id globals. Declaring both sides is
-                    mandatory: implicit propagation would silently re-gather
-                    sharded store operands (filolint
-                    ``mesh-sharding-undeclared`` enforces this statically).
-  * ``shard_map`` — the plain jitted ``shard_map`` path (no declared
-                    boundary shardings); the fallback for single-device CPU
-                    CI, per the jax_graft fallback pattern (SNIPPETS.md [2]).
-  * ``auto``      — ``pjit`` on a multi-device non-CPU backend, else
-                    ``shard_map``.
+One program form: the per-shard body (the fused tiling plan / the two-step
+kernels) wraps in ``shard_map`` and jits with EXPLICIT
+``in_shardings``/``out_shardings`` (``NamedSharding`` per operand) plus
+donation of the per-query group-id globals where the backend honors it.
+Declaring both sides is mandatory: implicit propagation would silently
+re-gather sharded store operands (filolint ``mesh-sharding-undeclared``
+enforces this statically). The virtual CPU mesh of the test suite compiles
+and runs the same form the chip serves.
 
 Reduction schedule: float partial sums do NOT psum — psum's fold order is
 implementation-defined and may reassociate per shape, and an in-program f32
@@ -44,12 +37,12 @@ crosses the collective, so single-chip and multi-chip execution share semantics.
 
 Deliberately NOT lowered here: count_values — its partial state is keyed by
 rendered value strings (no fixed-size device layout to all_gather), and the
-host merge it rides measures at 1.1% of total query time at bench scale
-(bench_suite `count_values`, BENCH_SUITE_r07.json), so a hashed-value-bucket
-device layout would optimize a rounding error. Cross-HOST peers (shards owned
-by other OS processes) take the HTTP data plane instead: query/wire.py ships
-per-peer batched envelopes and co-located reduces (see query/planner.py
-_collapse_remote) — the collectives below cover co-resident shards only.
+host merge it rides carries only [distinct values] rows across shards, so a
+hashed-value-bucket device layout has nothing measured to win. Cross-HOST
+peers (shards owned by other OS processes) take the HTTP data plane instead:
+query/wire.py ships per-peer batched envelopes and co-located reduces (see
+query/planner.py _collapse_remote) — the collectives below cover co-resident
+shards only.
 """
 
 from __future__ import annotations
@@ -71,59 +64,17 @@ def make_mesh(devices=None, axis: str = "shard") -> Mesh:
     return Mesh(np.asarray(devices), (axis,))
 
 
-# ---------------------------------------------------------------------------
-# mesh-program mode (config: query.mesh_programs / query.mesh_donation) —
-# the same module-level dial pattern as ops/fusedresident.set_mode
-# ---------------------------------------------------------------------------
-
-MESH_MODES = ("auto", "pjit", "shard_map")
-_mesh_mode = "auto"
-_mesh_donation = True
-
-
-def mesh_mode() -> str:
-    """The configured mesh-program mode ("auto" | "pjit" | "shard_map")."""
-    return _mesh_mode
-
-
-def set_mesh_mode(m: str) -> None:
-    """Select the mesh-program mode (config: ``query.mesh_programs``)."""
-    global _mesh_mode
-    if m not in MESH_MODES:
-        raise ValueError(f"query.mesh_programs must be one of {MESH_MODES}, "
-                         f"got {m!r}")
-    _mesh_mode = m
-
-
-def set_mesh_donation(flag: bool) -> None:
-    """Enable/disable operand donation (config: ``query.mesh_donation``)."""
-    global _mesh_donation
-    _mesh_donation = bool(flag)
-
-
-def resolved_mesh_mode(mesh: Mesh | None = None) -> str:
-    """The mode a dispatch will actually use: ``auto`` resolves to ``pjit``
-    on a multi-device non-CPU backend and falls back to ``shard_map`` on
-    single-device / CPU CI (the SNIPPETS.md fallback rule)."""
-    if _mesh_mode != "auto":
-        return _mesh_mode
-    ndev = mesh.devices.size if mesh is not None else len(jax.devices())
-    return "pjit" if ndev > 1 and jax.default_backend() != "cpu" \
-        else "shard_map"
-
-
 def _donate_argnums(donate: tuple) -> tuple:
     """Donation is declared only where XLA can honor it: the CPU backend
     lacks buffer donation (jax warns and ignores it), so CI keeps clean
     logs while TPU/GPU runs reuse the per-query group-id buffers."""
-    if not _mesh_donation or jax.default_backend() == "cpu":
+    if jax.default_backend() == "cpu":
         return ()
     return donate
 
 
-def count_mesh_served(route: str, mode: str) -> None:
-    registry.counter(FILODB_QUERY_MESH_SERVED,
-                     {"route": route, "mode": mode}).increment()
+def count_mesh_served(route: str) -> None:
+    registry.counter(FILODB_QUERY_MESH_SERVED, {"route": route}).increment()
 
 
 def count_mesh_fallback(reason: str) -> None:
@@ -138,12 +89,11 @@ def _is_pspec(x) -> bool:
 
 
 def _sharded_jit(mesh: Mesh, in_specs, out_specs, donate: tuple = ()):
-    """The pjit-mode jit applicator: every ``PartitionSpec`` leaf in the
-    operand trees becomes an explicit ``NamedSharding`` on ``mesh`` and BOTH
-    ``in_shardings`` and ``out_shardings`` are declared (the jax_graft
-    pattern — SNIPPETS.md [2]/[3]: pjit requires both or falls back to
-    shard_map; an implicit side would silently re-gather sharded store
-    operands through host memory)."""
+    """The mesh programs' jit applicator: every ``PartitionSpec`` leaf in
+    the operand trees becomes an explicit ``NamedSharding`` on ``mesh`` and
+    BOTH ``in_shardings`` and ``out_shardings`` are declared (the jax_graft
+    pattern — SNIPPETS.md [2]/[3]; an implicit side would silently
+    re-gather sharded store operands through host memory)."""
     def to_shardings(tree):
         return jax.tree.map(lambda s: NamedSharding(mesh, s), tree,
                             is_leaf=_is_pspec)
@@ -259,7 +209,7 @@ class DistributedStore:
     def global_gids(self, group_ids_per_shard):
         """Per-slot global [NDEV, S] gid arrays, device_put to each shard's
         device (caller passes one [S] array per shard, shard order). Built
-        fresh per dispatch, so pjit-mode programs may DONATE them."""
+        fresh per dispatch, so the mesh programs may DONATE them."""
         out = []
         for j in range(self.slots):
             arrs = []
@@ -299,32 +249,24 @@ def _stack_parts(slot_parts):
 
 
 def _dist_program(kernel: str, statics: tuple, slot_shapes: tuple, build,
-                  mesh: Mesh, in_specs=None, out_specs=None,
-                  donate: tuple = ()):
+                  mesh: Mesh, in_specs, out_specs, donate: tuple = ()):
     """Mesh twin of the in-process kernel routing: every ``dist_*``
     collective below is a per-key program in the SAME process-global
     compiled-plan cache (query/plancache.py), keyed on its statics plus the
-    global-array slot shapes plus the mesh axes AND the resolved mode — a
-    pjit program never aliases a shard_map one, and neither aliases the
+    global-array slot shapes plus the mesh axes — it never aliases the
     per-shard in-process entries (distinct kernel names). A dashboard's
     first mesh query compiles here, every repeat (and every warmup-covered
     shape) hits.
 
-    In ``pjit`` mode the entry jits with the explicit boundary shardings
-    (and donation) from ``_sharded_jit`` — both spec trees are REQUIRED, the
-    runtime twin of filolint's ``mesh-sharding-undeclared`` rule."""
+    The entry jits with the explicit boundary shardings (and donation) from
+    ``_sharded_jit`` — both spec trees are REQUIRED parameters, the runtime
+    twin of filolint's ``mesh-sharding-undeclared`` rule."""
     from ..query.plancache import plan_cache
-    mode = resolved_mesh_mode(mesh)
-    wrap = None
-    if mode == "pjit":
-        if in_specs is None or out_specs is None:
-            raise ValueError(
-                f"{kernel}: pjit mode requires both in_specs and out_specs "
-                "(implicit propagation would re-gather sharded operands)")
-        wrap = _sharded_jit(mesh, in_specs, out_specs, donate)
     key = statics + slot_shapes + ("mesh", mesh.axis_names,
-                                   mesh.devices.size, mode)
-    return plan_cache.program(kernel, key, build, wrap=wrap)
+                                   mesh.devices.size)
+    return plan_cache.program(
+        kernel, key, build,
+        wrap=_sharded_jit(mesh, in_specs, out_specs, donate))
 
 
 def _tvn_shapes(slot_tvn) -> tuple:
@@ -722,15 +664,13 @@ class MeshQueryExecutor:
     grid-aligned to one common (base, interval) with a single uniform start
     cohort, and the shapes fit the fused kernel's VMEM gate, the per-shard
     map phase runs the single-pass fused Pallas kernel; otherwise the
-    general two-step kernels. ``last_path`` records the route taken,
-    ``last_mode`` the resolved mesh-program mode (pjit / shard_map) and
+    general two-step kernels. ``last_path`` records the route taken and
     ``last_block`` the fused kernel's column block ``(c0, columns)``, None
     on every other route."""
 
     def __init__(self, dstore: DistributedStore):
         self.dstore = dstore
         self.last_path: str | None = None
-        self.last_mode: str = resolved_mesh_mode(dstore.mesh)
         self.last_block: tuple[int, int] | None = None
 
     def _fused_grid(self):
@@ -756,7 +696,6 @@ class MeshQueryExecutor:
         slot_gids = tuple(self.dstore.global_gids(group_ids_per_shard))
         G = _pow2(num_groups)
         S, C, T = self.dstore.S, self.dstore.C, len(out_ts)
-        self.last_mode = resolved_mesh_mode(self.dstore.mesh)
         from ..ops import fusedresident
         variant = fusedresident.tag()
         grid = (self._fused_grid()
@@ -833,7 +772,6 @@ class MeshQueryExecutor:
         the in-process SketchPartial merge)."""
         slot_tvn = tuple(self.dstore.arrays())
         slot_gids = tuple(self.dstore.global_gids(group_ids_per_shard))
-        self.last_mode = resolved_mesh_mode(self.dstore.mesh)
         from ..query.exec import _pad_steps
         out_eval, T = _pad_steps(np.asarray(out_ts, np.int64))
         # pow2-bucket the group count: a churning by() cardinality must not
@@ -861,7 +799,6 @@ class MeshQueryExecutor:
         the caller maps (shard, row) back to series keys."""
         slot_tvn = tuple(self.dstore.arrays())
         slot_gids = tuple(self.dstore.global_gids(group_ids_per_shard))
-        self.last_mode = resolved_mesh_mode(self.dstore.mesh)
         from ..query.exec import _pad_steps
         out_eval, T = _pad_steps(np.asarray(out_ts, np.int64))
         Gp = _pow2(num_groups)    # compile-space bucketing, as aggregate()
@@ -893,8 +830,8 @@ def warm_mesh_shape(fn: str, op: str, S: int, C: int, steps: int,
     (``query.warmup_shapes`` entries with ``mesh: true`` — plancache.warmup
     calls this). Warms the general two-step program always and the fused
     program (the ACTIVE ``query.fused_kernels`` variant) when the shape
-    qualifies — under the RESOLVED mesh mode, so the warmed executable is
-    the serving executable. ``residency`` names a decode variant
+    qualifies, so the warmed executable is the serving executable.
+    ``residency`` names a decode variant
     (ops/decodereg.py) to warm the narrow-streaming program for in addition
     to the raw one — the first dashboard hit on a compressed-resident fleet
     then compiles nothing."""
@@ -921,7 +858,7 @@ def warm_mesh_shape(fn: str, op: str, S: int, C: int, steps: int,
     ts = gput((S, C), jnp.int64)
 
     def gids():
-        # gid globals are donated in pjit mode: build a fresh one per call
+        # gid globals are donated: build a fresh one per call
         return gput((S,), jnp.int32)
 
     dist_aggregate(((ts, val, n),), (gids(),), jnp.asarray(out_eval),

@@ -54,10 +54,7 @@ MESH_OPS = frozenset({"sum", "avg", "count", "group", "stddev", "stdvar",
 # candidate blocks (parallel/distributed.dist_topk), quantile psums sketch
 # counts. count_values stays on the host merge: its partial state is keyed
 # by rendered value STRINGS — there is no fixed-size device layout to
-# gather, and only [distinct values] rows cross shards anyway. Measured, not
-# asserted: the host merge is 1.1% of total query time at 8192 series x 8
-# shards (bench_suite `count_values`, BENCH_SUITE_r07.json) — far under the
-# 5% bar that would justify a hashed-bucket device layout.
+# gather, and only [distinct values] rows cross shards anyway.
 MESH_ORDER_OPS = frozenset({"topk", "bottomk", "quantile"})
 # device-side per-group loops in dist_topk compile per group: cap G like the
 # in-process order-stat map does (exec.AggregateMapReduce.ORDER_STAT_MAX_GROUPS)
@@ -1403,7 +1400,7 @@ class QueryEngine:
                                     G, args=(a0, a1), fetch=False)
             # the program that ran and, for a fused one, its column block:
             # what ties a device event to this query
-            kern["kernel"] = f"{ex.last_mode}-{ex.last_path}"
+            kern["kernel"] = f"pjit-{ex.last_path}"
             if ex.last_block is not None:
                 kern["c0"], kern["cols"] = ex.last_block
             if ctx is not None:     # committed: the mesh path serves this
@@ -1412,13 +1409,10 @@ class QueryEngine:
                     # stats symmetry with the in-process fused route
                     # (exec.py): cluster stats equal the single-node oracle
                     ctx.stats.add("fused_kernels")
-        # pjit-mode programs carry the mode in the exec path so dashboards
-        # (and the parity tests) can tell WHICH executable served; the
-        # shard_map fallback keeps the historical bare "mesh-" tag
-        tag = (f"mesh[pjit]-{ex.last_path}" if ex.last_mode == "pjit"
-               else f"mesh-{ex.last_path}")
-        self._set_path(ctx, tag)
-        distributed.count_mesh_served(ex.last_path, ex.last_mode)
+        # the strings the ledger, chip_smoke.MESH_PREFIX and the benchmark's
+        # expected routes read
+        self._set_path(ctx, f"mesh[pjit]-{ex.last_path}")
+        distributed.count_mesh_served(ex.last_path)
         if op in ("topk", "bottomk"):
             m = self._present_mesh_topk(lazy, shards, epochs, out_ts,
                                         list(uniq))

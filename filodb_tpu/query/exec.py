@@ -1401,14 +1401,8 @@ class SelectRawPartitionsExec(ExecPlan):
         if (grid is not None and col is None and les is None
                 and (store.S % 512 == 0 or store.S <= 512)
                 and val.ndim == 2):
-            # narrow-resident state first (the narrow form IS the store),
-            # then the optional mirror (an extra quant16 copy alongside f32)
+            # narrow-resident state (the narrow form IS the store)
             nd = store.narrow_operands()
-            if nd is None and shard.config.narrow_mirror:
-                md = store.narrow.get(store)
-                if md is not None:
-                    q, vmin, scale, ok_host = md
-                    nd = ("quant16", (q, vmin, scale), ok_host)
             if nd is not None:
                 kind, nops, ok_host = nd
                 bad = pids[~ok_host[pids]].astype(np.int32)

@@ -86,7 +86,7 @@ class CompiledPlanCache:
         ``in_shardings``/``out_shardings`` + donation, built where the mesh
         is known — parallel/distributed.py) so the global-view executable
         still rides this cache's hit/trace/span accounting. The CALLER must
-        key such entries distinctly (mode/mesh in ``key``): the cache
+        key such entries distinctly (the mesh in ``key``): the cache
         cannot see that two builds wrap differently."""
         import jax
         full = (kernel, *key)
@@ -190,9 +190,8 @@ def warmup(shapes: list) -> dict:
     ACTIVE ``query.fused_kernels`` mode will serve (pallas or the XLA
     twin) — set_mode runs before warmup at server startup exactly so the
     warmed program is the serving program. ``mesh`` (True warms the mesh
-    ``dist_*`` programs for the shape too, under the RESOLVED
-    ``query.mesh_programs`` mode — ``series`` then means rows PER SHARD;
-    no-op on a single-device process). Returns
+    ``dist_*`` programs for the shape too — ``series`` then means rows PER
+    SHARD; no-op on a single-device process). Returns
     ``{"programs": <new traces>, "ms": <wall>}``.
     """
     import numpy as np

@@ -1276,11 +1276,6 @@ class FiloServer:
         # warmup thread starts, so warmed programs are the ones that serve
         from .ops import fusedresident
         fusedresident.set_mode(str(cfg["query.fused_kernels"]))
-        # mesh-program mode next, same reasoning: the warmup below may
-        # pre-trace mesh dist_* programs and they must be the serving ones
-        from .parallel import distributed
-        distributed.set_mesh_mode(str(cfg["query.mesh_programs"]))
-        distributed.set_mesh_donation(bool(cfg["query.mesh_donation"]))
         # serving fast path: bound the process-global compiled-plan cache
         # and pre-trace the configured hot shapes in the background — the
         # server accepts traffic immediately; warmed dashboards simply stop

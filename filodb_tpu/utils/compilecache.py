@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
 One rule, applied by every entry point before its first compile
-(``FiloServer.start``, ``cli serve``, ``bench.py``, ``chip_smoke.py``): if
+(``FiloServer.start``, ``cli serve``, ``chip_smoke.py``): if
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and nothing is set
 in code; otherwise the cache is ``<checkout>/.jax_cache`` — a fixed,
 git-ignored path, because the path is part of the cache key and a directory

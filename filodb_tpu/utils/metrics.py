@@ -237,9 +237,7 @@ METRICS_SPEC: dict[str, tuple[str, str]] = {
                    "only grow: each query then costs one matmul a tile."),
     FILODB_QUERY_MESH_SERVED: (
         "counter", "Queries served by a mesh dist_* collective, tagged by "
-                   "route (fused / fused-narrow / twostep / sketch / topk) "
-                   "and resolved program mode (query.mesh_programs: pjit / "
-                   "shard_map)."),
+                   "route (fused / fused-narrow / twostep / sketch / topk)."),
     FILODB_QUERY_MESH_FALLBACK: (
         "counter", "Mesh-eligible queries that fell back to the host "
                    "scatter-gather path after eligibility, tagged by reason "

@@ -151,7 +151,7 @@ def test_engine_routes_promql_through_mesh():
     start, end, step = START + 300_000, START + 500_000, 20_000
 
     r = eng.query_range("sum(rate(m[5m]))", start, end, step)
-    assert r.exec_path == "mesh-fused", r.exec_path
+    assert r.exec_path == "mesh[pjit]-fused", r.exec_path
     want = local.query_range("sum(rate(m[5m]))", start, end, step)
     (_k, _t, got), = list(r.matrix.iter_series())
     (_k, _t, exp), = list(want.matrix.iter_series())
@@ -159,7 +159,7 @@ def test_engine_routes_promql_through_mesh():
 
     # grouped aggregate: keys + values must match the local path per group
     r = eng.query_range("sum by (grp) (rate(m[5m]))", start, end, step)
-    assert r.exec_path == "mesh-fused"
+    assert r.exec_path == "mesh[pjit]-fused"
     want = local.query_range("sum by (grp) (rate(m[5m]))", start, end, step)
     got = {k: v for k, _t, v in r.matrix.iter_series()}
     exp = {k: v for k, _t, v in want.matrix.iter_series()}
@@ -170,7 +170,7 @@ def test_engine_routes_promql_through_mesh():
     # filtered selection: non-matching rows must not leak into the sum
     q = 'sum(rate(m{grp="g1"}[5m]))'
     r = eng.query_range(q, start, end, step)
-    assert r.exec_path.startswith("mesh-")
+    assert r.exec_path.startswith("mesh[pjit]-")
     want = local.query_range(q, start, end, step)
     (_k, _t, got), = list(r.matrix.iter_series())
     (_k, _t, exp), = list(want.matrix.iter_series())
@@ -178,7 +178,7 @@ def test_engine_routes_promql_through_mesh():
 
     # min/max ride the twostep mesh path (pmin/pmax collectives)
     r = eng.query_range("max(rate(m[5m]))", start, end, step)
-    assert r.exec_path == "mesh-twostep"
+    assert r.exec_path == "mesh[pjit]-twostep"
     want = local.query_range("max(rate(m[5m]))", start, end, step)
     (_k, _t, got), = list(r.matrix.iter_series())
     (_k, _t, exp), = list(want.matrix.iter_series())
@@ -186,7 +186,7 @@ def test_engine_routes_promql_through_mesh():
 
     # instant query through the same dispatch
     ri = eng.query_instant("sum(rate(m[5m]))", end)
-    assert ri.exec_path == "mesh-fused"
+    assert ri.exec_path == "mesh[pjit]-fused"
     wi = local.query_instant("sum(rate(m[5m]))", end)
     (_k, _t, got), = list(ri.matrix.iter_series())
     (_k, _t, exp), = list(wi.matrix.iter_series())
@@ -240,10 +240,10 @@ def test_engine_mesh_topk_and_quantile():
     local = QueryEngine(ms, "prometheus")
     start, end, step = START + 300_000, START + 500_000, 20_000
 
-    for q, route in (("topk(3, rate(m[5m]))", "mesh-topk"),
-                     ("bottomk(2, rate(m[5m]))", "mesh-topk"),
-                     ("topk(2, rate(m[5m])) by (grp)", "mesh-topk"),
-                     ('topk(2, rate(m{grp="g1"}[5m]))', "mesh-topk")):
+    for q, route in (("topk(3, rate(m[5m]))", "mesh[pjit]-topk"),
+                     ("bottomk(2, rate(m[5m]))", "mesh[pjit]-topk"),
+                     ("topk(2, rate(m[5m])) by (grp)", "mesh[pjit]-topk"),
+                     ('topk(2, rate(m{grp="g1"}[5m]))', "mesh[pjit]-topk")):
         r = eng.query_range(q, start, end, step)
         assert r.exec_path == route, (q, r.exec_path)
         want = local.query_range(q, start, end, step)
@@ -262,7 +262,7 @@ def test_engine_mesh_topk_and_quantile():
     for q in ("quantile(0.5, rate(m[5m]))",
               "quantile(0.9, rate(m[5m])) by (grp)"):
         r = eng.query_range(q, start, end, step)
-        assert r.exec_path == "mesh-sketch", (q, r.exec_path)
+        assert r.exec_path == "mesh[pjit]-sketch", (q, r.exec_path)
         want = local.query_range(q, start, end, step)
         got = {k: v for k, _t, v in r.matrix.iter_series()}
         exp = {k: v for k, _t, v in want.matrix.iter_series()}
@@ -301,7 +301,7 @@ def test_mesh_two_shards_per_device():
               "max(rate(m[5m]))", "topk(3, rate(m[5m]))",
               "quantile(0.5, rate(m[5m]))"):
         r = eng.query_range(q, start, end, step)
-        assert r.exec_path.startswith("mesh-"), (q, r.exec_path)
+        assert r.exec_path.startswith("mesh[pjit]-"), (q, r.exec_path)
         want = local.query_range(q, start, end, step)
         got = {k: v for k, _t, v in r.matrix.iter_series()}
         exp = {k: v for k, _t, v in want.matrix.iter_series()}
@@ -313,7 +313,7 @@ def test_mesh_two_shards_per_device():
 
 # -- PR 16: composed two-step reduce is bit-stable across step buckets --------
 #
-# PR 13's fold-order caveat (documented in bench_suite.bench_dashboard_soak):
+# PR 13's fold-order caveat:
 # the composed path's [G,R]x[R,T] segment reduce could differ in the last
 # ulp across padded-T step buckets — XLA was free to reassociate the matmul
 # fold per output shape. Closed by (a) the row-order stable segment reduce

@@ -327,7 +327,7 @@ def test_mesh_accepts_narrow_resident_stores(q):
     eh = QueryEngine(ms, "prometheus", mapper)          # host path oracle
     start, end, step = START + 300_000, START + 800_000, 30_000
     rm = em.query_range(q, start, end, step)
-    assert rm.exec_path.startswith("mesh-"), rm.exec_path
+    assert rm.exec_path.startswith("mesh[pjit]-"), rm.exec_path
     rh = eh.query_range(q, start, end, step)
     a = {k: v for k, _t, v in rh.matrix.iter_series()}
     b = {k: v for k, _t, v in rm.matrix.iter_series()}
@@ -353,7 +353,7 @@ def test_mesh_narrow_fused_streams_i16():
     em = QueryEngine(ms, "prometheus", mapper, mesh=make_mesh())
     rn = em.query_range("sum(rate(m[2m]))", START + 300_000,
                         START + 800_000, 30_000)
-    assert rn.exec_path == "mesh-fused-narrow", rn.exec_path
+    assert rn.exec_path == "mesh[pjit]-fused-narrow", rn.exec_path
     assert counts["v"] == 0
     for st, orig in origs:
         st.value_block = orig

@@ -2,8 +2,8 @@
 trigram pre-filter extraction, bitmap algebra, top-k popcount parity (incl.
 a mixed local+peer fixture), and the parse-time regex 422 edge
 (ref analogs: PartKeyLuceneIndexSpec + PartKeyIndexBenchmark — the 1M-series
-bar lives in scripts/bench_suite.py `partkey_index` and the slow scale test
-below; tier-1 proves correctness at 64k)."""
+scale lives in the slow test below and in the benchmark's 2^20-series cells;
+tier-1 proves correctness at 64k)."""
 
 import numpy as np
 import pytest

@@ -83,7 +83,7 @@ def test_second_identical_query_compiles_nothing_on_mesh():
     start, end, step = BASE + 300_000, BASE + 500_000, 20_000
     for q in ("sum(rate(m[5m]))", "max(rate(m[5m]))"):
         r1 = eng.query_range(q, start, end, step)
-        assert r1.exec_path.startswith("mesh-"), r1.exec_path
+        assert r1.exec_path.startswith("mesh[pjit]-"), r1.exec_path
         tracer.drain()
         t0 = plan_cache.traces
         r2 = eng.query_range(q, start, end, step)
@@ -115,53 +115,48 @@ def _mesh_store(dataset="meshiso"):
     return mesh, ms
 
 
-def test_mesh_programs_never_alias_per_shard_or_other_mode_entries():
-    """ISSUE 16 key audit: the mesh dist_* programs are keyed on (padded
-    shape, mesh axes, resolved mode) — a pjit-mode program must neither
-    reuse nor overwrite the shard_map-mode entry for the same query shape
-    (nor any per-shard in-process entry), and each mode's second identical
-    query still traces 0."""
-    from filodb_tpu.parallel import distributed
+def test_mesh_program_never_aliases_a_per_shard_entry():
+    """Key audit: a mesh dist_* program is keyed on (padded shape, mesh
+    axes) under a kernel name of its own — it must neither reuse nor
+    overwrite a per-shard in-process entry of the same query shape, a
+    query shape owns exactly ONE mesh entry, the second identical query
+    traces 0, and the answer equals the host scatter-gather's bit for
+    bit."""
     mesh, ms = _mesh_store()
     eng = QueryEngine(ms, "meshiso", mesh=mesh)
+    host = QueryEngine(ms, "meshiso")
+    plan_cache.clear()      # a cold process: both paths compile their own
     start, end, step = BASE + 300_000, BASE + 500_000, 20_000
     q = 'sum(rate(m[5m]))'
-    try:
-        distributed.set_mesh_mode("shard_map")
-        r_sm = eng.query_range(q, start, end, step)
-        assert r_sm.exec_path.startswith("mesh-"), r_sm.exec_path
-        size_sm, t_sm = len(plan_cache), plan_cache.traces
-        # switching mode must COMPILE A DISTINCT PROGRAM (no aliasing): the
-        # cache grows and real traces happen for the same query shape
-        distributed.set_mesh_mode("pjit")
-        r_pj = eng.query_range(q, start, end, step)
-        assert r_pj.exec_path.startswith("mesh[pjit]-"), r_pj.exec_path
-        assert len(plan_cache) > size_sm, \
-            "pjit-mode program must be a NEW cache entry, not an alias"
-        assert plan_cache.traces > t_sm
-        # identical pjit query: warm, traces nothing
-        t0 = plan_cache.traces
-        r_pj2 = eng.query_range(q, start, end, step)
-        assert plan_cache.traces == t0
-        # flipping BACK must hit the original shard_map entry (it was never
-        # overwritten) — still zero traces
-        distributed.set_mesh_mode("shard_map")
-        r_sm2 = eng.query_range(q, start, end, step)
-        assert plan_cache.traces == t0, \
-            "shard_map entry must survive the pjit compile untouched"
-        # and all four answers are bit-identical (the ordered-fold contract)
-        for r in (r_pj, r_pj2, r_sm2):
-            assert (np.asarray(r.matrix.values).tolist()
-                    == np.asarray(r_sm.matrix.values).tolist())
-    finally:
-        distributed.set_mesh_mode("auto")
+    r_host = host.query_range(q, start, end, step)
+    assert not r_host.exec_path.startswith("mesh"), r_host.exec_path
+    per_shard = set(plan_cache.keys())
+    t_host = plan_cache.traces
+    r_mesh = eng.query_range(q, start, end, step)
+    assert r_mesh.exec_path.startswith("mesh[pjit]-"), r_mesh.exec_path
+    added = set(plan_cache.keys()) - per_shard
+    assert plan_cache.traces > t_host, \
+        "the mesh program must compile: no per-shard entry serves it"
+    assert [k[0] for k in added if k[0].startswith("dist-")] \
+        == ["dist-fused"], added
+    assert per_shard <= set(plan_cache.keys()), \
+        "per-shard entries must survive the mesh compile untouched"
+    # identical mesh query: warm, traces nothing, adds nothing
+    t0, size = plan_cache.traces, len(plan_cache)
+    r_mesh2 = eng.query_range(q, start, end, step)
+    assert plan_cache.traces == t0 and len(plan_cache) == size
+    # and the host path re-hits its own entries at zero traces
+    r_host2 = host.query_range(q, start, end, step)
+    assert plan_cache.traces == t0
+    for r in (r_mesh, r_mesh2, r_host2):
+        assert (np.asarray(r.matrix.values).tolist()
+                == np.asarray(r_host.matrix.values).tolist())
 
 
 def test_warmup_covers_mesh_variants():
     """query.warmup_shapes with ``mesh: true`` pre-traces the mesh dist_*
-    programs under the RESOLVED query.mesh_programs mode: the first real
-    mesh query of the warmed shape compiles nothing — in BOTH modes."""
-    from filodb_tpu.parallel import distributed
+    programs: the first real mesh query of the warmed shape compiles
+    nothing."""
     mesh, ms = _mesh_store("meshwarm")
     eng = QueryEngine(ms, "meshwarm", mesh=mesh)
     start, end, step = BASE + 300_000, BASE + 500_000, 20_000
@@ -169,19 +164,14 @@ def test_warmup_covers_mesh_variants():
     spec = {"fn": "rate", "op": "sum", "series": 16, "samples": 64,
             "steps": steps, "step_ms": step, "window_ms": 300_000,
             "interval_ms": IV, "groups": 1, "mesh": True}
-    try:
-        for mode, tag in (("shard_map", "mesh-"), ("pjit", "mesh[pjit]-")):
-            distributed.set_mesh_mode(mode)
-            warmup([spec])
-            tracer.drain()
-            t0 = plan_cache.traces
-            r = eng.query_range('sum(rate(m[5m]))', start, end, step)
-            assert r.exec_path.startswith(tag), r.exec_path
-            assert plan_cache.traces == t0, \
-                f"warmed {mode} mesh shape must not compile at serve time"
-            assert _compile_spans() == []
-    finally:
-        distributed.set_mesh_mode("auto")
+    warmup([spec])
+    tracer.drain()
+    t0 = plan_cache.traces
+    r = eng.query_range('sum(rate(m[5m]))', start, end, step)
+    assert r.exec_path.startswith("mesh[pjit]-"), r.exec_path
+    assert plan_cache.traces == t0, \
+        "warmed mesh shape must not compile at serve time"
+    assert _compile_spans() == []
 
 
 def test_warmup_pretraces_the_dashboard_shape():

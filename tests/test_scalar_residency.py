@@ -296,7 +296,6 @@ def test_mixed_residency_mesh_serves_with_parity():
     kinds differ) cannot stream one narrow program — narrow_arrays() must
     return None and the fused route streams transient f32 decodes, still
     bit-equal to a no-mesh oracle."""
-    from filodb_tpu.parallel import distributed
     from filodb_tpu.parallel.distributed import make_mesh
 
     mesh = make_mesh()
@@ -330,17 +329,13 @@ def test_mixed_residency_mesh_serves_with_parity():
     em = QueryEngine(ms_mesh, "mixmesh", mesh=mesh)
     eo = QueryEngine(ms_host, "mixmesh")
     start, end, step = START + 300_000, START + 800_000, 30_000
-    distributed.set_mesh_mode("pjit")
-    try:
-        for q in ("sum(rate(m[2m]))", "sum by (grp) (rate(m[2m]))"):
-            rm = em.query_range(q, start, end, step)
-            assert rm.exec_path == "mesh[pjit]-fused", rm.exec_path
-            np.testing.assert_array_equal(
-                np.asarray(rm.matrix.values),
-                np.asarray(eo.query_range(q, start, end, step).matrix.values),
-                err_msg=q)
-    finally:
-        distributed.set_mesh_mode("auto")
+    for q in ("sum(rate(m[2m]))", "sum by (grp) (rate(m[2m]))"):
+        rm = em.query_range(q, start, end, step)
+        assert rm.exec_path == "mesh[pjit]-fused", rm.exec_path
+        np.testing.assert_array_equal(
+            np.asarray(rm.matrix.values),
+            np.asarray(eo.query_range(q, start, end, step).matrix.values),
+            err_msg=q)
 
 
 # -- warmup coverage ----------------------------------------------------------

@@ -861,17 +861,20 @@ def test_shared_corpus_matches_and_beats_per_family():
     """PR 18 satellite: all rule families run over ONE parsed corpus with
     one PackageIndex and memoized CFGs. The legacy per-family mode (each
     family re-parses and re-indexes) must produce fingerprint-identical
-    findings — and measurably slower, or the sharing rotted away."""
+    findings — from measurably more work, or the sharing rotted away.
+    Counted, not timed: under six xdist workers a wall clock says how
+    loaded the sandbox is, not what the runner did."""
+    from filodb_tpu.analysis.runner import _default_checkers
     shared = run_analysis(REPO, shared_corpus=True)
     legacy = run_analysis(REPO, shared_corpus=False)
     fps = sorted(f.fingerprint for f in shared.all_findings)
     assert fps == sorted(f.fingerprint for f in legacy.all_findings)
+    n, families = shared.files_analyzed, len(_default_checkers(None, True))
+    assert families > 1
     assert shared.corpus_stats["index_builds"] == 1
-    # the tier-1 latency guard: a full-repo run stays interactive
-    assert shared.wall_s < 10.0, f"full-repo filolint run {shared.wall_s:.2f}s"
-    assert shared.wall_s < legacy.wall_s, (
-        f"shared corpus ({shared.wall_s:.2f}s) must beat per-family "
-        f"parsing ({legacy.wall_s:.2f}s)")
+    assert shared.corpus_stats["files_parsed"] == n
+    assert legacy.corpus_stats["files_parsed"] == n * families
+    assert legacy.corpus_stats["index_builds"] > 1
 
 
 def test_sarif_artifact_is_current():

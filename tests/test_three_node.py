@@ -264,7 +264,6 @@ def test_mesh_bit_parity_grid_vs_host_loop_and_oracle(residency):
     twostep, topk, sketch), pjit mesh == 3-node host loop == single-node
     oracle, EXACT equality, exec path tagged mesh[pjit]-*."""
     from filodb_tpu.core.memstore import StoreConfig
-    from filodb_tpu.parallel import distributed
     from filodb_tpu.parallel.distributed import make_mesh
 
     def cfg():
@@ -319,7 +318,6 @@ def test_mesh_bit_parity_grid_vs_host_loop_and_oracle(residency):
     start, end, step = START + 300_000, START + 800_000, 30_000
     queries = MESH_PARITY_QUERIES[residency]
     tags = set()
-    distributed.set_mesh_mode("pjit")
     try:
         for q in queries:
             rm = mesh_eng.query_range(q, start, end, step)
@@ -332,7 +330,6 @@ def test_mesh_bit_parity_grid_vs_host_loop_and_oracle(residency):
             assert got_loop == want, f"host loop diverged from oracle: {q!r}"
             assert got_mesh == want, f"mesh diverged from oracle: {q!r}"
     finally:
-        distributed.set_mesh_mode("auto")
         for srv in servers.values():
             srv.stop()
     if residency != "f64":
@@ -353,7 +350,6 @@ def test_mesh_engine_i8_hist_residency_host_merges_with_parity():
     from filodb_tpu.core.memstore import StoreConfig
     from filodb_tpu.core.record import RecordBuilder
     from filodb_tpu.core.schemas import PROM_HISTOGRAM
-    from filodb_tpu.parallel import distributed
     from filodb_tpu.parallel.distributed import make_mesh
 
     B = 8
@@ -389,13 +385,9 @@ def test_mesh_engine_i8_hist_residency_host_merges_with_parity():
     em = QueryEngine(ms_mesh, DATASET, ShardMapper(NSHARDS), mesh=mesh)
     eo = QueryEngine(ms_host, DATASET, ShardMapper(NSHARDS))
     start, end, step = START + 300_000, START + 800_000, 30_000
-    distributed.set_mesh_mode("pjit")
-    try:
-        for q in ('histogram_quantile(0.9, sum(rate(h[2m])))',
-                  'sum(rate(h[2m]))'):
-            rm = em.query_range(q, start, end, step)
-            assert not rm.exec_path.startswith("mesh"), (q, rm.exec_path)
-            assert _as_comparable(rm) \
-                == _as_comparable(eo.query_range(q, start, end, step)), q
-    finally:
-        distributed.set_mesh_mode("auto")
+    for q in ('histogram_quantile(0.9, sum(rate(h[2m])))',
+              'sum(rate(h[2m]))'):
+        rm = em.query_range(q, start, end, step)
+        assert not rm.exec_path.startswith("mesh"), (q, rm.exec_path)
+        assert _as_comparable(rm) \
+            == _as_comparable(eo.query_range(q, start, end, step)), q
