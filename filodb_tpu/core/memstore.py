@@ -105,6 +105,7 @@ from .filters import Filter
 from .partkey_index import PartKeyIndex
 from .record import RecordContainer
 from .schemas import Schema, Schemas, part_key_bytes, part_key_of
+from .selection import SelectionMemo, ShardSelection
 from .store import (INDEX_FLAG_UNPARSEABLE, INDEX_GENESIS_BUCKET,
                     INDEX_RETIRE_BUCKET, INDEX_TOMBSTONE_BUCKET,
                     ChunkSetRecord, ChunkSink, encode_index_bucket,
@@ -274,6 +275,9 @@ class TimeSeriesShard:
         # reconstructs labels on demand, so query leaves cache the key object
         # (built once per series lifetime, dropped on purge)
         self._rv_keys: dict[int, object] = {}
+        # what the wide selectors and their groupings came to, kept while
+        # the index says the same (core/selection.py has the rule)
+        self._selections = SelectionMemo(self)
         self.eviction_policy = eviction_policy or CapacityEvictionPolicy()
         # guards the donating device append vs concurrent query dispatch: the
         # scatter invalidates (donates) the old store buffers, so query leaves
@@ -1602,6 +1606,19 @@ class TimeSeriesShard:
             from ..query.rangevector import RangeVectorKey
             k = self._rv_keys[pid] = RangeVectorKey.of(self.index.labels_of(pid))
         return k
+
+    def selection(self, filters: list[Filter], start: int, end: int,
+                  keep_over: int) -> tuple[ShardSelection, str]:
+        """The query leaves' select: the series ``filters`` match in
+        [start, end] and how they came — ``hit`` / ``miss`` of the selection
+        memo, or ``bypass`` for what it does not keep (a selection of at most
+        ``keep_over`` series, a time mask that bites, a recovering shard).
+        Staged rows land first, as before every select: the memo holds what
+        labels decide, never samples. The arrays handed out are shared and
+        read-only."""
+        self.flush()
+        with self.lock:
+            return self._selections.select(filters, start, end, keep_over)
 
     def part_ids_from_filters(self, filters: list[Filter], start: int, end: int,
                               limit: int | None = None) -> np.ndarray:

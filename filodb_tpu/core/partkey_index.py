@@ -169,6 +169,17 @@ class PartKeyIndex:
     def __len__(self) -> int:
         return len(self._off)
 
+    @property
+    def epoch(self) -> int:
+        """Bumps on any postings mutation: what a cached filter result, here
+        or in the shard's selection memo, is validated against."""
+        return self._epoch
+
+    def all_live_through(self, end_time: int) -> bool:
+        """True when no entry has ever ended and none starts after
+        ``end_time``: the per-entry time filter is then the identity."""
+        return self._num_ended == 0 and self._max_start <= end_time
+
     def _intern_name(self, name: str) -> int:
         nid = self._name_id.get(name)
         if nid is None:
@@ -594,8 +605,7 @@ class PartKeyIndex:
             if len(self._filter_cache) > 512:
                 self._filter_cache.clear()
             self._filter_cache[ckey] = (self._epoch, result)
-        if len(result) and not (self._num_ended == 0
-                                and self._max_start <= end_time):
+        if len(result) and not self.all_live_through(end_time):
             starts = self._start.view()[result]
             ends = self._end.view()[result]
             result = result[(starts <= end_time) & (ends >= start_time)]

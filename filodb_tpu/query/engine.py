@@ -1108,7 +1108,7 @@ class QueryEngine:
         raw tier's count of correction matmuls, on the device too, None
         from the other programs — or None: general path."""
         from ..ops import fusedresident, gridfns
-        from .exec import (SeriesSelection, _group_ids_for, _pad_steps,
+        from .exec import (SeriesSelection, _grouping_for, _pad_steps,
                            _pow2)
         fn = inner.function
         q = float(plan.function_args[0])
@@ -1124,8 +1124,8 @@ class QueryEngine:
                 abs(int(out_ts[-1]) - data.grid[0])) + window >= 2**31):
             return None
         R = data.val.shape[0]
-        gids, uniq, G = _group_ids_for(data.keys, data.rows, R,
-                                       agg.by, agg.without)
+        gids, uniq, G, gids_dev = _grouping_for(data.keys, data.rows, R,
+                                                agg.by, agg.without)
         if not uniq:
             return None, None, "fused-hist", uniq, G, T
         base_ts, interval_ms = data.grid
@@ -1196,9 +1196,12 @@ class QueryEngine:
                 # raw f32 residency (the shipped default): the same shape
                 # streamed over row tiles of the block itself, its own
                 # kernel variant — no [S, C, B]-sized temporary exists
+                # the selection memo's device copy of the group ids, where
+                # it keeps one: no upload a query
                 out, falls, ktags = fusedresident.fused_hist_quantile_raw(
-                    q, les, data.val, data.n, gids, Gp, out_eval, window,
-                    fn, base_ts, interval_ms)
+                    q, les, data.val, data.n,
+                    gids if gids_dev is None else gids_dev, Gp, out_eval,
+                    window, fn, base_ts, interval_ms)
                 ktags.update(steps=T, groups=G)
                 path = f"fused-hist[{fusedresident.tag()}]"
                 ctx.stats.add("fused_kernels")
@@ -1293,6 +1296,7 @@ class QueryEngine:
                            dtype=np.int64)
         if len(out_ts) == 0:
             return None
+        from .exec import GATHER_THRESHOLD    # read at call time, as the leaf does
         filters = list(raw.filters)
         from_ms = raw.range_selector.from_ms
         to_ms = raw.range_selector.to_ms
@@ -1319,7 +1323,9 @@ class QueryEngine:
             for sh in shards:    # path actually serves (a later fallback to
                 # the host path must not double-count its own leaf counts)
                 with span(SPAN_QUERY_SELECT, shard=sh.shard_num) as sel:
-                    pids = sh.part_ids_from_filters(filters, from_ms, to_ms)
+                    picked, sel["memo"] = sh.selection(
+                        filters, from_ms, to_ms, GATHER_THRESHOLD)
+                    pids = picked.pids
                     sel["series"] = len(pids)
                     paging = sh.needs_paging(pids, from_ms)
                 if paging:
@@ -1334,18 +1340,18 @@ class QueryEngine:
                         uniq.setdefault(RangeVectorKey(()), 0)
                     else:
                         # the shard's own groups from its label columns
-                        # (vid pools are per shard), then G keys — not the
+                        # (vid pools are per shard; once per index state:
+                        # the selection memo), then G keys — not the
                         # series — mapped onto the shared numbering
                         with span(SPAN_QUERY_GROUPIDS, keys=len(pids),
                                   route="index") as tags:
-                            local, groups = sh.index.group_ids(
-                                pids, plan.by, plan.without)
+                            local, tags["memo"] = picked.grouping(
+                                plan.by, plan.without)
                             shared = np.fromiter(
-                                (uniq.setdefault(RangeVectorKey(gk),
-                                                 len(uniq))
-                                 for gk in groups),
-                                np.int32, count=len(groups))
-                            g[pids] = shared[local]
+                                (uniq.setdefault(gk, len(uniq))
+                                 for gk in local.keys),
+                                np.int32, count=len(local.keys))
+                            g[pids] = shared[local.gids]
                             tags["groups"] = len(uniq)
                         count_groupids("index")
                 gids_list.append(g)

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.filters import Filter
+from ..core.selection import ShardSelection
 from ..ops import aggregators, binop, instantfns, rangefns
 from ..utils.diagnostics import lock_wait_ns
 from ..utils.metrics import FILODB_GROUPIDS, registry
@@ -476,40 +477,45 @@ class LazyKeys:
     Releases of unrelated partitions do not invalidate the selection."""
 
     def __init__(self, shard, pids):
+        """``pids``: the shard's ``ShardSelection`` (its arrays are shared
+        with every query the selection memo serves it to), or bare part
+        ids."""
         self._shard = shard
-        self._pids = pids
-        self._epochs = shard.slot_epoch[pids].copy()
+        self._sel = (pids if isinstance(pids, ShardSelection)
+                     else ShardSelection(shard, pids))
+        self.pids = self._sel.pids
+        self._sel.snapshot()
 
     def _check(self):
-        if (self._shard.slot_epoch[self._pids] != self._epochs).any():
+        if self._sel.released():
             raise QueryError("selection invalidated by concurrent partition "
                              "release (eviction/purge); retry the query")
 
     def __len__(self):
-        return len(self._pids)
+        return len(self.pids)
 
     def __getitem__(self, i):
         with self._shard.lock:   # label arena mutates during release
             self._check()
             if isinstance(i, slice):
-                return [self._shard.rv_key_of(int(p)) for p in self._pids[i]]
-            return self._shard.rv_key_of(int(self._pids[i]))
+                return [self._shard.rv_key_of(int(p)) for p in self.pids[i]]
+            return self._shard.rv_key_of(int(self.pids[i]))
 
     def __iter__(self):
         with self._shard.lock:
             self._check()
-            keys = [self._shard.rv_key_of(int(p)) for p in self._pids]
+            keys = [self._shard.rv_key_of(int(p)) for p in self.pids]
         return iter(keys)
 
-    def group_ids(self, by, without):
-        """(group id per selected series, the G group keys) straight from
-        the index's label columns (PartKeyIndex.group_ids): no key of a
-        selected series is materialized. Same guard as reading the keys."""
+    def grouping(self, by, without):
+        """(the selection's ``Grouping`` for ``by``/``without``, ``hit`` |
+        ``miss`` | ``bypass`` of the selection memo): group ids and the G
+        group keys straight from the index's label columns
+        (PartKeyIndex.group_ids), computed once per index state — no key of
+        a selected series is materialized. Same guard as reading the keys."""
         with self._shard.lock:
             self._check()
-            gids, groups = self._shard.index.group_ids(self._pids, by,
-                                                       without)
-        return gids, [RangeVectorKey(g) for g in groups]
+            return self._sel.grouping(by, without)
 
 
 def count_groupids(route: str) -> None:
@@ -522,14 +528,22 @@ def _group_ids_for(keys, rows, R, by, without):
     values are all-NaN / zero-count. The source follows the input: a
     selection that is still pids (``LazyKeys``) groups by the index's label
     columns, materialized keys (narrow or paged selections, matrices out of
-    joins and functions) by a walk over them."""
+    joins and functions) by a walk over them. The array may be one the
+    selection memo shares between queries: read-only, copy before a write."""
+    return _grouping_for(keys, rows, R, by, without)[:3]
+
+
+def _grouping_for(keys, rows, R, by, without):
+    """``_group_ids_for`` and, fourth, the device copy of ``gids`` where the
+    selection memo keeps one (None: the caller uploads)."""
     if len(keys) and not by and not without:
         # global aggregation: one group, keys never materialized
-        return np.zeros(R, np.int32), [RangeVectorKey(())], 1
+        return np.zeros(R, np.int32), [RangeVectorKey(())], 1, None
     route = "index" if isinstance(keys, LazyKeys) else "walk"
     with span(SPAN_QUERY_GROUPIDS, keys=len(keys), route=route) as tags:
         if route == "index":
-            gid_of_key, uniq = keys.group_ids(by, without)
+            grouping, tags["memo"] = keys.grouping(by, without)
+            gid_of_key, uniq = grouping.gids, list(grouping.keys)
         else:
             seen: dict[RangeVectorKey, int] = {}
             gid_of_key = np.fromiter(
@@ -539,14 +553,17 @@ def _group_ids_for(keys, rows, R, by, without):
             uniq = list(seen)
         G = tags["groups"] = max(len(uniq), 1)
     count_groupids(route)
+    dev = None
     if not len(keys):
         gids = np.zeros(R, np.int32)
     elif rows is None:
         gids = gid_of_key
+    elif route == "index" and rows is keys.pids:
+        gids, dev = grouping.dense(rows, R)
     else:
         gids = np.zeros(R, np.int32)
         gids[rows] = gid_of_key
-    return gids, uniq, G
+    return gids, uniq, G, dev
 
 
 def group_keys_of(keys, by, without):
@@ -612,7 +629,8 @@ class AggregateMapReduce(Transformer):
         from ..ops import fusedgrid, fusedresident
         sel = data.sel
         R = sel.val.shape[0]
-        gids, uniq, G = _group_ids_for(sel.keys, sel.rows, R, self.by, self.without)
+        gids, uniq, G, gids_dev = _grouping_for(sel.keys, sel.rows, R,
+                                                self.by, self.without)
         Gp = _pow2(G)
         if Gp > fusedgrid.MAX_GROUPS:
             fusedresident.count_fallback(
@@ -636,7 +654,7 @@ class AggregateMapReduce(Transformer):
             n_eff = n_eff.at[jnp.asarray(np.asarray(minority))].set(0)
         if G == 1 and not self.by and not self.without:
             gids_dev = fusedgrid.zero_gids(R)   # cached: no per-query upload
-        else:
+        elif gids_dev is None:      # else the selection memo's device copy
             gids_dev = jnp.asarray(gids)
         # fetch=False: the leaf holds the shard lock through this dispatch —
         # the blocking host fetch happens at present/merge time, outside it.
@@ -1298,19 +1316,21 @@ class SelectRawPartitionsExec(ExecPlan):
 
     def do_execute(self, ctx) -> SeriesSelection:
         with span(SPAN_QUERY_SELECT, shard=self.shard) as tags:
-            sel = self._select(ctx)
+            sel = self._select(ctx, tags)
             tags["series"] = len(sel.pids if isinstance(sel, _WideODP)
                                  else sel.keys)
             return sel
 
-    def _select(self, ctx):
+    def _select(self, ctx, tags: dict):
         """Index select + array capture (the snapshot the chain runs on)."""
         shard, col = self._shard_of(ctx)
         if shard.store is None:   # histogram shard with no data yet
             z = jnp.zeros((8, 8), jnp.float32)
             return SeriesSelection(jnp.full((8, 8), 1 << 62, jnp.int64), z,
                                    jnp.zeros(8, jnp.int32), [], None, None)
-        pids = shard.part_ids_from_filters(list(self.filters), self.start_ms, self.end_ms)
+        picked, tags["memo"] = shard.selection(
+            list(self.filters), self.start_ms, self.end_ms, GATHER_THRESHOLD)
+        pids = picked.pids      # shared and read-only on a memo hit
         ctx.stats.add("series_matched", len(pids))
         store = shard.store
         # bucket boundaries ride only when the SELECTED column is the
@@ -1333,7 +1353,7 @@ class SelectRawPartitionsExec(ExecPlan):
         if len(pids) > GATHER_THRESHOLD:
             # wide selection: defer key materialization (global aggregates
             # never read them; per-series outputs pay the cost on iteration)
-            keys = LazyKeys(shard, pids)
+            keys = LazyKeys(shard, picked)
         else:
             keys = [shard.rv_key_of(int(p)) for p in pids]
         ts, val, n = store.arrays(col)
@@ -1389,7 +1409,7 @@ class SelectRawPartitionsExec(ExecPlan):
         # wide selection: no gather — disable non-selected rows via n = 0
         # (store.S is the PHYSICAL padded row count; the full-selection test
         # is against the logical series count)
-        if len(pids) == total:
+        if picked.is_all:
             n_eff = n
         else:
             mask = np.zeros(store.S, bool)
@@ -1423,7 +1443,7 @@ class SelectRawPartitionsExec(ExecPlan):
         ctx.stats.add("blocks_narrow"
                       if (narrow is not None or hist_narrow is not None)
                       else "blocks_raw")
-        return SeriesSelection(ts, val, n_eff, keys, pids.astype(np.int32), grid, les,
+        return SeriesSelection(ts, val, n_eff, keys, pids, grid, les,
                                g_min, narrow, hist_narrow)
 
 

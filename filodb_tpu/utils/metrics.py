@@ -48,6 +48,7 @@ FILODB_SHARD_LOCK_WAIT_SECONDS = "filodb_shard_lock_wait_seconds"
 FILODB_SHARD_LOCK_HOLD_SECONDS = "filodb_shard_lock_hold_seconds"
 FILODB_LOCK_HOLD_MS = "filodb_lock_hold_ms"
 FILODB_GROUPIDS = "filodb_groupids"
+FILODB_SELECTION_MEMO = "filodb_selection_memo"
 FILODB_QUERY_LATENCY_MS = "filodb_query_latency_ms"
 FILODB_QUERY_SLOW = "filodb_query_slow"
 FILODB_QUERY_COMPILE_CACHE_HITS = "filodb_query_compile_cache_hits"
@@ -175,6 +176,13 @@ METRICS_SPEC: dict[str, tuple[str, str]] = {
                    "tagged by route: index = gathers over the part-key "
                    "index's label columns (a selection still held as pids), "
                    "walk = one Python step a materialized series key."),
+    FILODB_SELECTION_MEMO: (
+        "counter", "Reads of a shard's selection memo (core/selection.py) "
+                   "by part (select = a selector's part ids and slot "
+                   "epochs, groupids = a by/without's group ids, keys and "
+                   "device array) and outcome: hit, miss (built and kept), "
+                   "bypass (not kept; reason = narrow, time_mask or "
+                   "recovering)."),
     FILODB_QUERY_LATENCY_MS: (
         "histogram", "End-to-end PromQL latency per dataset; the /metrics "
                      "rendering carries the last query's trace id as an "

@@ -130,14 +130,16 @@ TRACE_SPEC: dict[str, str] = {
                      "shard=all route=mesh; lock_wait_ms = what the thread "
                      "waited for shard locks inside it).",
     SPAN_QUERY_SELECT: "Index select + array capture of one leaf; per shard "
-                       "on the mesh route (tags: shard, series).",
+                       "on the mesh route (tags: shard, series, memo = hit "
+                       "| miss | bypass of the shard's selection memo).",
     SPAN_QUERY_GROUPIDS: "Group ids of the selected series for a "
                          "by/without aggregation: from the index's label "
                          "columns where the selection is still pids "
                          "(route=index), else one Python step a "
                          "materialized key (route=walk); a global "
                          "aggregate opens none (tags: keys, groups, "
-                         "route).",
+                         "route; on route=index memo = hit | miss | bypass: "
+                         "whether the selection memo had the grouping).",
     SPAN_QUERY_KERNEL: "Host side of one fused kernel: phase=dispatch is "
                        "the call under the shard lock, phase=fetch the "
                        "blocking fetch of its result outside it (dispatch "

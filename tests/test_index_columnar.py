@@ -385,6 +385,7 @@ def test_group_ids_for_a_million_pids_build_exactly_g_keys(monkeypatch):
         lock = threading.RLock()
         index = idx
         slot_epoch = np.zeros(n, np.uint32)
+        _release_epoch = 0
 
         def rv_key_of(self, pid):
             raise AssertionError("a series key was materialized")

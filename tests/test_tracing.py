@@ -426,6 +426,7 @@ def test_fused_hist_route_is_a_leaf_with_its_parts_and_a_fetch(end_s,
                    f"query_range?{q}")
             with urllib.request.urlopen(url, timeout=60) as r:
                 return json.load(r)
+        tracer.drain()                      # no earlier test's request
         get(0)                              # compiles; not the one we read
         _trace_of_last_query()
         tracer.drain()
@@ -495,7 +496,9 @@ def test_groupids_route_follows_the_selection(served, monkeypatch):
     wide = get(shift_ms=1_000)
     span_w, = [s for s in _trace_of_last_query()
                if s.name == SPAN_QUERY_GROUPIDS]
-    assert span_w.tags == {"keys": N_SERIES, "groups": 4, "route": "index"}
+    # the selector and the grouping were asked before: the memo's arrays
+    assert span_w.tags == {"keys": N_SERIES, "groups": 4, "route": "index",
+                           "memo": "hit"}
     mid = _groupids_counts(srv)
     assert (mid["index"], mid["walk"]) == (before["index"] + 1,
                                            before["walk"])
@@ -519,10 +522,16 @@ def test_spans_are_in_the_profiler_trace_on_its_clock(served, tmp_path):
     start and end on the trace's own clock."""
     import jax
     _srv, get = served
+    tracer.drain()
     get()                                   # compile outside the trace
+    _trace_of_last_query()                  # ... and close its spans there
+    tracer.drain()
     jax.profiler.start_trace(str(tmp_path))
     try:
         get(shift_ms=1_000)
+        # the request's span, and the annotation around it, close after the
+        # client has read the answer: a trace stopped before that lacks it
+        _trace_of_last_query()
     finally:
         jax.profiler.stop_trace()
     path = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
