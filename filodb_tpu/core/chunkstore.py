@@ -23,6 +23,7 @@ fits raw, and raw arrays keep the query path a pure gather/reduce. Compression
 from __future__ import annotations
 
 import functools
+import logging
 from dataclasses import dataclass
 
 import jax
@@ -31,7 +32,30 @@ import numpy as np
 
 from ..utils import diagnostics
 
+log = logging.getLogger(__name__)
+
 TS_PAD = np.int64(1) << np.int64(62)   # sentinel > any real timestamp
+
+# How the store keeps time. Column k of a row is the row's k-th sample. While
+# every series is scraped on one common grid (sample k at ``first + k *
+# interval``, every first stamp on the grid) the s64 block ``ts`` holds the
+# stamps and the shard is in its GRID form. The first stamp that is off that
+# grid — a target with its own scrape phase, a scrape stamped late — turns the
+# scalar store into its LINE form, once: a row's stamps are then a line
+# (``line0[row] + k * interval``, ``line0`` the row's first stamp) plus a
+# narrow signed residual per cell, ``res[S, C]`` on the device beside the
+# values, and the s64 block is dropped (it is derivable: ``ts_block``,
+# ``DeferredTs``). A sample's cell is ``round((ts - line0) / interval)``, so
+# a late scrape never shares a cell with its successor. A row whose sample
+# does not fit its line — a residual beyond RES_MAX, a skipped cell, a
+# changed interval — is DEMOTED as a row: its exact stamps move to a host
+# pool, it joins the minority set (``line_info().minority``) and is answered
+# by the general kernels over gathered rows, as a churned row is. The shard
+# stays on the fused path (ref: upstream's delta-delta timestamp vectors, a
+# line plus narrow residuals — doc/compression.md).
+RES_DTYPE = np.int8
+RES_MAX = 127
+DEMOTE_REASONS = ("residual", "gap", "interval")
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
@@ -184,6 +208,19 @@ def _derive_ts_impl(first, n, interval, C):
 _derive_ts = jax.jit(_derive_ts_impl, static_argnums=(3,))
 
 
+@functools.partial(jax.jit, static_argnums=(4,))
+def _derive_line_ts(line0, n, interval, res, C):
+    """The i64 stamps of a line-form store (or of gathered rows of one):
+    ``line0[r] + k * interval + res[r, k]`` for k < n[r], TS_PAD beyond."""
+    ts = _derive_ts_impl(line0, n, interval, C)
+    return jnp.where(ts == TS_PAD, TS_PAD, ts + res.astype(jnp.int64))
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _zero_counts(n, pids):
+    return n.at[pids].set(0, mode="drop")
+
+
 def _verify_ts_block_impl(ts, first, n, interval, C):
     """Fused derive-and-compare reduction over a ROW BLOCK — a whole-store
     comparison at 1M x 768 materializes multi-GB i64 hi/lo split temps and
@@ -334,7 +371,8 @@ class DeferredDecodeHist(_Deferred):
 
 
 class DeferredTs(_Deferred):
-    """Lazy i64 view of an elided (grid-derived) timestamp block."""
+    """Lazy i64 view of a timestamp block the store does not hold: elided
+    on a grid (compressed residency) or kept as line + residual."""
 
     dtype = np.dtype(np.int64)
 
@@ -344,12 +382,25 @@ class DeferredTs(_Deferred):
     def gather_rows(self, rid):
         """[P, C] i64 of the given rows only (row-wise derivation)."""
         st = self._store
+        if self._arr is None and st.res is not None:
+            return st._line_ts(rid)
         if self._arr is None and st._ts_elided:
             first_g = jnp.take(jnp.asarray(st.first_ts), rid)
             n_g = jnp.take(st.n, rid)
             return _derive_ts_rows(first_g, n_g, jnp.int64(st.grid_interval),
                                    st.C)
         return jnp.take(self.materialize(), rid, axis=0)
+
+
+@dataclass(frozen=True)
+class LineInfo:
+    """What the fused tier reads of a line-form store (``line_info``)."""
+    base_ts: int            # the majority rows' lines start within
+    interval_ms: int        # [base_ts, base_ts + interval_ms + RES_MAX]
+    start: object           # device i32 [S]: line0 - base_ts (clipped)
+    res: object             # device int8 [S, C]: stamp - line
+    off_mask: np.ndarray    # bool [S]: live rows the line kernel must skip
+    minority: np.ndarray    # int32: their row ids, ascending
 
 
 @dataclass
@@ -415,6 +466,26 @@ class SeriesStore:
         self.grid_base: int | None = None
         self.grid_interval: int | None = None
         self.grid_ok = True
+        # the LINE form (see the text at RES_DTYPE): the residual block, the
+        # stamp each row's line gives column 0, the rows demoted from their
+        # line with their exact stamps in a host pool, and how many rows
+        # each reason demoted. A layout (histogram, multi-column) store has
+        # no line form: its first off-grid stamp clears grid_ok for the
+        # shard, as before
+        self.res = None
+        self.line0 = np.full(S, -1, np.int64)
+        self.off_line = np.zeros(S, bool)
+        self._pool_ts = np.zeros((0, capacity), np.int64)
+        self._pool_slot = np.full(S, -1, np.int32)
+        self._pool_next = 0     # pool slots in use lie below this ...
+        self._pool_free: list[int] = []    # ... but for those frees let go
+        # what queries off the fused path read of a line store, kept until
+        # the next mutation instead of built by each of them: the pool on
+        # the device, and the s64 block derived from line + residual
+        self._pool_dev = None
+        self._line_block = None
+        self.demoted = dict.fromkeys(DEMOTE_REASONS, 0)
+        self.demoted_last_append = 0
         # start-cohort summary cache: recomputing per-row offsets per QUERY is
         # an O(S) host pass; starts only change on new series/compact/free
         self._cohorts = None
@@ -465,6 +536,7 @@ class SeriesStore:
         if self.owner_lock is not None:
             diagnostics.assert_owned(self.owner_lock, what)
         self.detective.record(what)
+        self._pool_dev = self._line_block = None
 
     # -- narrow-resident lifecycle ------------------------------------------
     #
@@ -697,7 +769,16 @@ class SeriesStore:
         return self.val
 
     def ts_block(self):
-        """i64 timestamp block: resident, or a TRANSIENT grid derivation."""
+        """i64 timestamp block: resident, or a TRANSIENT derivation (from
+        the grid, or from line + residual with the demoted rows' exact
+        stamps laid over it)."""
+        if self.res is not None:
+            # one derivation per state of the store, not one per query: the
+            # next mutation lets it go (``_pre_donate``)
+            kept = self._line_block
+            if kept is None or kept[0] is not self.res or kept[1] is not self.n:
+                kept = self._line_block = (self.res, self.n, self._line_ts())
+            return kept[2]
         if not self._ts_elided:
             return self.ts
         return _derive_ts(jnp.asarray(self.first_ts), self.n,
@@ -744,6 +825,8 @@ class SeriesStore:
         12B/sample f32 store to ~1-2B/sample (delta8 / quant16)."""
         t = 0 if self._ts_elided or self.ts is None \
             else self.ts.size * self.ts.dtype.itemsize
+        if self.res is not None:
+            t = self.res.size * self.res.dtype.itemsize
         return t + self.resident_value_bytes()
 
     # -- ingest -------------------------------------------------------------
@@ -802,9 +885,12 @@ class SeriesStore:
         uniq, first_pos = np.unique(r, return_index=True)
         newly = uniq[self.n_host[uniq] == 0]
         self.first_ts[newly] = t[first_pos[self.n_host[uniq] == 0]]
+        self.line0[newly] = self.first_ts[newly]
         if len(newly):
             self._cohorts = None   # new starts can change the cohort summary
-        self._track_grid(r, t, uniq, first_pos)
+        # line form: the stamp block written below is the residual block
+        res = self._track_stamps(r, t, cols, uniq, first_pos)
+        stamps = t if res is None else res
         np.maximum.at(self.last_ts, r, t)
         counts = np.bincount(r, minlength=self.S).astype(np.int32)
         self.n_host += counts
@@ -813,7 +899,7 @@ class SeriesStore:
         v = np.asarray(v)
         rp = np.full(P, self.S, np.int32); rp[:m] = r
         cp = np.zeros(P, np.int32); cp[:m] = cols
-        tp = np.zeros(P, np.int64); tp[:m] = t
+        tp = np.zeros(P, stamps.dtype); tp[:m] = stamps
         # split the flat [m, W] ingest row by the schema layout: default
         # column (scalar or histogram span) + named scalar columns
         dv, ev = v, {}
@@ -823,13 +909,14 @@ class SeriesStore:
                 dv = colv
             else:
                 ev[nm] = colv
+        block = self._stamp_block
         if (int(occ.max()) < DENSE_APPEND_MAX_K
-                and self.ts.nbytes + self.val.nbytes >= DENSE_APPEND_BYTES):
-            self._append_dense(r, cols, t, dv, ev, occ, counts)
+                and block.nbytes + self.val.nbytes >= DENSE_APPEND_BYTES):
+            self._append_dense(r, cols, stamps, dv, ev, occ, counts)
         elif self.layout is None:
             vp = np.zeros((P,) + v.shape[1:], v.dtype); vp[:m] = v
-            self.ts, self.val, self.n = _scatter_append(
-                self.ts, self.val, self.n,
+            self._stamp_block, self.val, self.n = _scatter_append(
+                block, self.val, self.n,
                 jnp.asarray(rp), jnp.asarray(cp), jnp.asarray(tp),
                 jnp.asarray(vp).astype(self.dtype), jnp.asarray(counts))
         else:
@@ -849,7 +936,9 @@ class SeriesStore:
     def _append_dense(self, r, cols, t, v, extra, occ, counts) -> None:
         """The flush of a large store (see DENSE_APPEND_BYTES): one donated
         per-row select per block and per in-batch occurrence, instead of
-        one scatter over all blocks. ``v`` is the default column's values
+        one scatter over all blocks. ``t`` is what the stamp block takes
+        (s64 stamps, or the line form's residuals, written with the values
+        by the same select). ``v`` is the default column's values
         ([m] or, for a histogram column, [m, B]); ``extra`` the named
         scalar columns' of a layout store, each block through the same
         select."""
@@ -863,7 +952,8 @@ class SeriesStore:
             rk = r[sel]
             col = np.full(self.S, -1, np.int32); col[rk] = cols[sel]
             col_d = jnp.asarray(col)
-            self.ts = _dense_set(self.ts, col_d, rows_of(t, sel, rk))
+            self._stamp_block = _dense_set(self._stamp_block, col_d,
+                                           rows_of(t, sel, rk))
             self.val = _dense_set(self.val, col_d, rows_of(v, sel, rk))
             for nm, a in extra.items():
                 self.extra[nm] = _dense_set(self.extra[nm], col_d,
@@ -891,50 +981,167 @@ class SeriesStore:
                 continue    # donated by a racing append: retry on the new n
         self._appends_since_sync = 0
 
-    def _track_grid(self, r, t, uniq, first_pos) -> None:
-        """Maintain the shard scrape-grid invariant on each append batch:
-        common (base, interval), per-series contiguity, uniform start."""
-        if not self.grid_ok:
-            return
-        if self.grid_base is None:
-            self.grid_base = int(t[0])
-        if self.grid_interval is None:
-            same = np.concatenate([[False], np.diff(r) == 0])
-            if same.any():
-                i = int(np.argmax(same))
-                self.grid_interval = int(t[i] - t[i - 1])
-            else:
-                existing = self.n_host[r] > 0
-                if existing.any():
-                    i = int(np.argmax(existing))
-                    self.grid_interval = int(t[i] - self.last_ts[r[i]])
-            if self.grid_interval is not None and self.grid_interval <= 0:
-                self.grid_ok = False
-            if self.grid_interval is None:
-                return
-            # interval just established: starts recorded before it was known
-            # (earlier batches) must land on the grid too, else their offsets
-            # in grid_offsets() would silently misalign
-            live = self.n_host > 0
-            if self.grid_ok and live.any():
-                starts = self.first_ts[live]
-                if (((starts - self.grid_base) % self.grid_interval) != 0).any():
-                    self.grid_ok = False
-                    return
-        iv = self.grid_interval
-        ok = ((t - self.grid_base) % iv == 0).all()
-        # contiguity within the batch
+    # -- how the store keeps time (see the text at RES_DTYPE) ----------------
+
+    @property
+    def _stamp_block(self):
+        """The device block a flush writes stamps into: the s64 block, or
+        the line form's residuals."""
+        return self.ts if self.res is None else self.res
+
+    @_stamp_block.setter
+    def _stamp_block(self, block) -> None:
+        if self.res is None:
+            self.ts = block
+        else:
+            self.res = block
+
+    @property
+    def stamp_form(self) -> str:
+        return "grid" if self.res is None else "line"
+
+    def rows_off_line(self) -> int:
+        """Live rows the line kernel skips now (0 in the grid form): what
+        the last ``line_info`` found, or, before any query has asked, the
+        demoted rows alone. Reads host state only: the metrics page asks
+        without the shard lock."""
+        summary = self._cohorts
+        if summary is not None and summary[0] == "line":
+            return len(summary[1][-1])
+        return int(self.off_line.sum())
+
+    def _interval_of(self, r, t, uniq, first_pos):
+        """The shard's scrape interval, from the first batch that holds a
+        second sample of any series: the median step (a late scrape among
+        them must not set it)."""
         same = np.concatenate([[False], np.diff(r) == 0])
-        if ok and same.any():
-            ok = (np.diff(t)[same[1:]] == iv).all()
-        # contiguity vs stored tail for series with history
-        if ok:
-            existing = self.n_host[uniq] > 0
-            if existing.any():
-                heads = t[first_pos[existing]]
-                ok = (heads == self.last_ts[uniq[existing]] + iv).all()
-        if not ok:
-            self.grid_ok = False
+        existing = self.n_host[uniq] > 0
+        d = np.concatenate([np.diff(t)[same[1:]],
+                            t[first_pos[existing]]
+                            - self.last_ts[uniq[existing]]])
+        if not len(d):
+            return None
+        return int(np.partition(d, len(d) // 2)[len(d) // 2])
+
+    def _track_stamps(self, r, t, cols, uniq, first_pos):
+        """Hold each append batch against the rows' lines. In the grid form
+        (every stamp ON its line, every line's phase the shard's) nothing
+        changes hands and None is returned: the s64 block takes the stamps.
+        The first sample off that turns a scalar store to its line form,
+        once (a layout store: ``grid_ok`` off for the shard, as ever); from
+        then on the batch's residuals (int8 [m]) are returned for the
+        residual block, and a sample that does not fit demotes its row."""
+        self.demoted_last_append = 0
+        if self.res is None and not self.grid_ok:
+            return None
+        if self.grid_base is None:
+            self.grid_base = int(t.min())
+        iv = self.grid_interval
+        phases = uniq
+        if iv is None:
+            iv = self._interval_of(r, t, uniq, first_pos)
+            if iv is None:
+                return None
+            if iv <= 0:
+                self.grid_ok = False
+                return None
+            self.grid_interval = iv
+            # starts recorded before the interval was known
+            phases = np.union1d(uniq, np.flatnonzero(self.n_host > 0))
+        off = t - (self.line0[r] + cols.astype(np.int64) * iv)
+        if self.res is None:
+            if (not off.any() and not ((self.line0[phases] - self.grid_base)
+                                       % iv).any()):
+                return None
+            if self.nbuckets or self.layout is not None:
+                self.grid_ok = False
+                return None
+            self._to_line()
+        shift = (off + iv // 2) // iv          # cells past the row's next one
+        fits = (shift == 0) & (np.abs(off) <= RES_MAX)
+        bad = ~fits & ~self.off_line[r]
+        if bad.any():
+            rows, first = np.unique(r[bad], return_index=True)
+            sh = shift[bad][first]
+            near = np.abs((off - shift * iv)[bad][first]) <= RES_MAX
+            # on the line in another cell: a gap; in its own cell and too
+            # far from the line for the width: the residual; neither: the
+            # row's interval is not the shard's any more
+            reason = np.where(near, "gap", np.where(sh == 0, "residual",
+                                                    "interval"))
+            self._demote(rows, reason)
+        out = self.off_line[r]
+        if out.any():
+            self._pool_ts[self._pool_slot[r[out]], cols[out]] = t[out]
+            self._pool_dev = None
+        return np.where(out, 0, off).astype(RES_DTYPE)
+
+    def _to_line(self) -> None:
+        """Grid form -> line form, once: every stamp so far is ON its row's
+        line (the grid invariant), so the residual block starts as zeros
+        and the s64 block is dropped."""
+        dev = next(iter(self.n.devices()))
+        self.res = jax.device_put(jnp.zeros((self.S, self.C), RES_DTYPE), dev)
+        self.ts = None
+        self.grid_ok = False
+        self._cohorts = None
+        log.info("store keeps stamps as line + residual from here on "
+                 "(%d rows live, interval %d ms, residual %s)",
+                 int((self.n_host > 0).sum()), self.grid_interval,
+                 np.dtype(RES_DTYPE).name)
+
+    def _demote(self, rows: np.ndarray, reasons: np.ndarray) -> None:
+        """Take ``rows`` off their lines: their exact stamps so far (line +
+        residual) go to the host pool, where every later stamp of theirs
+        is written too."""
+        # slots that freed rows let go first, new ones past them
+        again = [self._pool_free.pop()
+                 for _ in range(min(len(rows), len(self._pool_free)))]
+        fresh = len(rows) - len(again)
+        have, used = len(self._pool_ts), self._pool_next
+        self._pool_next += fresh
+        if used + fresh > have:
+            grown = np.full((max(2 * have, used + fresh, 8), self.C),
+                            TS_PAD, np.int64)
+            grown[:have] = self._pool_ts
+            self._pool_ts = grown
+        self._pool_slot[rows] = again + list(range(used, used + fresh))
+        n = self.n_host[rows]
+        past = np.asarray(jnp.take(self.res, jnp.asarray(rows), axis=0),
+                          np.int64)
+        k = np.arange(self.C, dtype=np.int64)[None, :]
+        stamps = self.line0[rows, None] + k * self.grid_interval + past
+        self._pool_ts[self._pool_slot[rows]] = np.where(k < n[:, None],
+                                                        stamps, TS_PAD)
+        self.off_line[rows] = True
+        self._cohorts = self._pool_dev = None
+        for why in DEMOTE_REASONS:
+            self.demoted[why] += int((reasons == why).sum())
+        self.demoted_last_append += len(rows)
+        log.info("%d row(s) demoted from their line (%s)", len(rows),
+                 ", ".join(sorted(set(reasons.tolist()))))
+
+    def _line_ts(self, rid=None):
+        """i64 stamps of a line-form store from line + residual, the
+        demoted rows' exact stamps laid over them: the whole block [S, C],
+        or the rows ``rid`` (a device id vector) [P, C]."""
+        if rid is None:
+            line0, slot, n, res = self.line0, self._pool_slot, self.n, self.res
+        else:
+            rows = np.asarray(rid)
+            line0, slot = self.line0[rows], self._pool_slot[rows]
+            n, res = jnp.take(self.n, rid), jnp.take(self.res, rid, axis=0)
+        ts = _derive_line_ts(jnp.asarray(line0), n,
+                             jnp.int64(self.grid_interval), res, self.C)
+        out = np.flatnonzero(slot >= 0)
+        if len(out):
+            if self._pool_dev is None:      # one upload per state
+                self._pool_dev = jax.device_put(
+                    self._pool_ts[:self._pool_next],
+                    next(iter(self.res.devices())))
+            ts = ts.at[jnp.asarray(out)].set(
+                jnp.take(self._pool_dev, jnp.asarray(slot[out]), axis=0))
+        return ts
 
     def grid_info(self):
         """(base_ts, interval_ms) when the shard stays on a common scrape grid
@@ -978,22 +1185,85 @@ class SeriesStore:
                     self._cohorts = ("mixed", offs)
         return self._cohorts
 
+    def line_info(self) -> LineInfo | None:
+        """The line form's operands for the fused tier, None in the grid
+        form. Cached like ``grid_cohorts`` (new series, compaction, frees
+        and demotions invalidate it; an append that demotes no row does
+        not). The majority are the live rows on their line whose line
+        starts within one interval (+ RES_MAX) of ``base_ts``, so that a
+        window's first and last cell differ between any two of them by at
+        most one; demoted rows and rows that start elsewhere (churn) are
+        the minority, answered by the general kernels."""
+        if self.res is None or not self.grid_interval:
+            return None
+        if self._cohorts is None or self._cohorts[0] != "line":
+            iv = int(self.grid_interval)
+            live = self.n_host > 0
+            on = live & ~self.off_line
+            a = self.line0 - int(self.grid_base)
+            a_q = 0
+            if on.any():
+                rel = a - a[on].min()
+                cells, cnts = np.unique(rel[on] // iv, return_counts=True)
+                a_q = int(a[on].min()) + int(cells[np.argmax(cnts)]) * iv
+            rel = a - a_q
+            major = on & (rel >= 0) & (rel <= iv + RES_MAX)
+            off = live & ~major
+            start = jax.device_put(
+                jnp.asarray(np.clip(rel, -1, iv + RES_MAX + 1), jnp.int32),
+                next(iter(self.res.devices())))
+            self._cohorts = ("line", (int(self.grid_base) + a_q, iv, start,
+                                      off,
+                                      np.flatnonzero(off).astype(np.int32)))
+        base, iv, start, off, minority = self._cohorts[1]
+        # the block as it is NOW: every flush donates and replaces it
+        return LineInfo(base, iv, start, self.res, off, minority)
+
     def compact(self, cutoff_ts: int) -> None:
         """Evict samples older than ``cutoff_ts`` (amortized; ref: block reclaim
         by time bucket, BlockManager.scala markBucketedBlocksReclaimable)."""
         self._rehydrate()      # the shift gathers the raw f32 block
         self._pre_donate("SeriesStore.compact")
+        line = self.res is not None
+        old_n = self.n_host
         if self.extra:
             self.ts, self.val, self.extra, self.n = _compact_multi(
                 self.ts, self.val, self.extra, self.n, jnp.int64(cutoff_ts))
         else:
-            self.ts, self.val, self.n = _compact(self.ts, self.val, self.n,
-                                                 jnp.int64(cutoff_ts))
+            # line form: the shift runs over a transient derivation
+            new_ts, self.val, self.n = _compact(
+                self._line_ts() if line else self.ts_block(), self.val, self.n,
+                jnp.int64(cutoff_ts))
+            if not line:
+                self.ts = new_ts
         self.n_host = np.array(self.n)  # fresh writable host copy
-        new_first = np.array(self.ts[:, 0])
-        self.first_ts = np.where(self.n_host > 0, new_first, -1)
+        if line:
+            self._recompact_line(new_ts, old_n - self.n_host)
+        else:
+            new_first = np.array(self.ts[:, 0])
+            self.first_ts = np.where(self.n_host > 0, new_first, -1)
+            self.line0 = self.first_ts.copy()
         self._cohorts = None
         self.stats.compactions += 1
+
+    def _recompact_line(self, new_ts, dropped: np.ndarray) -> None:
+        """After a compaction of a line-form store: every row's line moves
+        on by the cells it dropped, its residuals shift with the samples,
+        and a demoted row's pool row is read anew."""
+        live = self.n_host > 0
+        self.line0 = np.where(
+            live, self.line0 + dropped.astype(np.int64) * self.grid_interval,
+            -1)
+        self.first_ts = np.where(live, np.array(new_ts[:, 0]), -1)
+        line = _derive_ts(jnp.asarray(self.line0), self.n,
+                          jnp.int64(self.grid_interval), self.C)
+        fit = jnp.asarray(live & ~self.off_line)[:, None] & (new_ts != TS_PAD)
+        self.res = jnp.where(fit, new_ts - line, 0).astype(RES_DTYPE)
+        out = np.flatnonzero(self._pool_slot >= 0)
+        if len(out):
+            self._pool_ts[self._pool_slot[out]] = np.asarray(
+                jnp.take(new_ts, jnp.asarray(out), axis=0))
+            self._pool_dev = None
 
     def free_rows(self, part_ids: np.ndarray) -> None:
         """Release the rows of purged partitions so their slots can be reused
@@ -1011,9 +1281,17 @@ class SeriesStore:
         # padded entries use row S -> dropped by the out-of-bounds scatter mode
         pp = np.full(P, self.S, np.int32)
         pp[:m] = np.asarray(part_ids, np.int32)
-        self.ts, self.n = _free_rows(self.ts, self.n, jnp.asarray(pp))
+        if self.res is None:
+            self.ts, self.n = _free_rows(self.ts, self.n, jnp.asarray(pp))
+        else:       # n = 0 masks the row's residuals; its pool row is let go
+            self.n = _zero_counts(self.n, jnp.asarray(pp))
+            self.off_line[part_ids] = False
+            held = self._pool_slot[part_ids]
+            self._pool_free.extend(held[held >= 0].tolist())
+            self._pool_slot[part_ids] = -1
         self.n_host[part_ids] = 0
         self.first_ts[part_ids] = -1
+        self.line0[part_ids] = -1
         self.last_ts[part_ids] = -(1 << 62)
         self._cohorts = None
 
@@ -1025,7 +1303,7 @@ class SeriesStore:
         schema's default column). Compressed-resident stores return deferred
         views (the grid/fused paths plan from shape metadata and never
         materialize; general paths decode transients at exec._dval)."""
-        ts = DeferredTs(self) if self._ts_elided else self.ts
+        ts = DeferredTs(self) if self.ts is None else self.ts
         return ts, self.column_array(column), self.n
 
     def column_array(self, column: str | None = None):

@@ -931,6 +931,8 @@ class TimeSeriesShard:
         with self.lock:
             staged = bool(self._staged)
             written = self._flush_staged_locked() if staged else 0
+            if staged:
+                tags["demoted"] = self.store.demoted_last_append
         residency = self.config.residency_mode()
         if not staged:
             # nothing new — but a purge/compact since the last flush may have

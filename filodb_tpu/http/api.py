@@ -238,7 +238,10 @@ class FiloHttpServer:
                                      FILODB_SHARD_LOCK_HOLD_SECONDS,
                                      FILODB_SHARD_LOCK_LONG_HOLDS,
                                      FILODB_SHARD_LOCK_WAIT_SECONDS,
-                                     FILODB_SHARD_NUM_SERIES, registry)
+                                     FILODB_SHARD_NUM_SERIES,
+                                     FILODB_STORE_ROWS_DEMOTED,
+                                     FILODB_STORE_ROWS_OFF_LINE,
+                                     FILODB_STORE_STAMP_FORM, registry)
         # snapshot: a downsample serving refresh adds family engines
         # concurrently (standalone ds_serve_loop)
         for ds, e in list(self.engines.items()):
@@ -250,6 +253,17 @@ class FiloHttpServer:
                     registry.gauge(f"filodb_shard_{k}", tags).update(float(v))
                 registry.gauge(FILODB_SHARD_NUM_SERIES, tags).update(
                     float(s.num_series))
+                st = s.store
+                if st is not None:
+                    shard = {"shard": str(s.shard_num)}
+                    registry.gauge(FILODB_STORE_STAMP_FORM, shard).update(
+                        float(st.stamp_form == "line"))
+                    registry.gauge(FILODB_STORE_ROWS_OFF_LINE, shard).update(
+                        float(st.rows_off_line()))
+                    for why, rows in st.demoted.items():
+                        c = registry.counter(FILODB_STORE_ROWS_DEMOTED,
+                                             {**shard, "reason": why})
+                        c.increment(rows - c.value)
                 if hasattr(s.lock, "contentions"):   # TimedRLock diagnostics
                     registry.gauge(FILODB_SHARD_LOCK_CONTENTIONS, tags) \
                         .update(float(s.lock.contentions))

@@ -69,8 +69,200 @@ def _roundup(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
+# The line form's six edge cells a step, in the order of their one-hot blocks
+# in ``ohe [Ca, 6 Tp]``: the two cells below the sure range [lo, hi], the
+# two above it (a row holds each or not, by its own stamps), then the sure
+# range's first and last cell (whose residuals the durations need). The
+# window functions read the first four only.
+EDGE_SLOTS = 6
+EDGE_SLOTS_WINDOW = 4
+_NEVER = 1 << 30        # an edge bound no start + residual reaches
+
+
+def line_spread(interval_ms: int) -> tuple[int, int]:
+    """(dmin, dmax) of ``start + residual`` over a line store's majority
+    rows (core/chunkstore.py ``line_info``): what ``grid_edges`` needs to
+    say which cells every row holds in a window."""
+    from ..core.chunkstore import RES_MAX
+    return -RES_MAX, interval_ms + 2 * RES_MAX
+
+
+# A row's start rides in the high bits of its sample count: every [S] -> [S,
+# 1] operand of the kernel is a lane-padded 512 MB relayout a query at 2^20
+# rows (PERF.md §5), and a count needs 11 bits (C <= MAX_CAPACITY = 1024)
+_COUNT_BITS = 11
+
+
+def pack_start(n, start):
+    """i32 [S]: ``n`` (0..1024) below, ``start`` (-1..2^20) above."""
+    return n.astype(jnp.int32) | (start.astype(jnp.int32) << _COUNT_BITS)
+
+
+def unpack_start(packed):
+    return packed & ((1 << _COUNT_BITS) - 1), packed >> _COUNT_BITS
+
+
+def line_fusable(window_ms: int, interval_ms: int) -> bool:
+    """Can the line kernel answer? Its two edge cells a side must be apart
+    (a window of at least three intervals), stamps must rise along a row
+    whatever the residuals (an interval well above their width), and a
+    start must fit beside the row's count (:func:`pack_start`)."""
+    from ..core.chunkstore import RES_MAX
+    return (interval_ms >= 8 * RES_MAX
+            and window_ms >= 3 * interval_ms + 6 * RES_MAX
+            and interval_ms + 2 * RES_MAX < 1 << (31 - _COUNT_BITS))
+
+
+def dot_exact01(x, w):
+    """``x [M, K] f32 @ w [K, N]`` for a ``w`` of -1, 0 and 1 held in bf16,
+    exact to f32: ``x`` splits into three bf16 pieces (8 mantissa bits
+    each, the rest taken off in f32 without rounding), each piece times
+    such a weight is exact and the MXU accumulates in f32. HIGHEST would
+    split BOTH sides and run six passes; the three that multiply the
+    weight's (zero) low pieces add nothing. Integers below 2^24 come out
+    exact in any order."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    hi = x.astype(bf16)
+    r = x - hi.astype(f32)
+    mid = r.astype(bf16)
+    lo = (r - mid.astype(f32)).astype(bf16)
+
+    def dot(a):
+        # DEFAULT, spelled out: one pass a piece (and the package-wide
+        # "highest" would ask Mosaic for an fp32 contraction of bf16)
+        return jnp.dot(a, w, precision=jax.lax.Precision.DEFAULT,
+                       preferred_element_type=f32)
+    return dot(hi) + dot(mid) + dot(lo)
+
+
+def _line_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
+                  v, n, band, ohe, lo, hi, rel, roll, start, res, eb):
+    """:func:`tile_contrib` on a line store: window membership and the
+    extrapolation's durations from each row's TRUE stamps, ``start[s] + c
+    * interval + res[s, c]`` (relative to the selection's base).
+
+    ``[lo, hi]`` are the cells EVERY row holds in the window
+    (gridfns.grid_edges with the line's spread): the band matmul sums
+    them, as on the grid. The two cells below ``lo`` and the two above
+    ``hi`` are in the window for some rows and not for others: their
+    values and residuals are picked by one-hot products (``ohe``; the
+    residuals are small integers, exact in ONE bf16 pass) and each row
+    decides them by comparing ``start + residual`` with the step's bound
+    for that cell (``eb``, small integers: differences of stamps, never
+    stamps). Stamps rise along a row, so the cells a row holds stay one
+    contiguous run ``[f_idx, l_idx]``."""
+    f32, i32 = jnp.float32, jnp.int32
+    Sb, Ca = v.shape
+    Tp = lo.shape[1]
+    window = fn in FUSED_WINDOW_FNS
+    lcol = jax.lax.broadcasted_iota(i32, (Sb, Ca), 1)
+    col = lcol + c0
+    valid = col < n
+    v = jnp.where(valid, v, 0.0)
+    rf = jnp.where(valid, res.astype(i32).astype(f32), 0.0)
+
+    slots = EDGE_SLOTS_WINDOW if window else EDGE_SLOTS
+    w = ohe[:, :slots * Tp]
+    rp = jnp.dot(rf.astype(jnp.bfloat16), w,
+                 precision=jax.lax.Precision.DEFAULT,
+                 preferred_element_type=f32)                  # [Sb, slots Tp]
+
+    def pick(x, j):
+        return x[:, j * Tp:(j + 1) * Tp]
+
+    a = start.astype(f32)                                     # [Sb, 1]
+    ebf = eb.astype(f32)
+    # a2 < a1 < lo <= hi < b1 < b2; a cell the row does not have is out.
+    # Each low cell is decided on its own: a row that ENDED in a2 (n == lo
+    # - 1) has no a1 and may still hold a2 in the window. A row's cells
+    # are a prefix, so above the sure range b2 needs b1
+    def has(cell):
+        return (cell >= 0) & (cell < n)
+
+    m_a2 = has(lo - 2) & (a + pick(rp, 0) >= ebf[0:1])
+    m_a1 = has(lo - 1) & (a + pick(rp, 1) >= ebf[1:2])
+    m_b1 = has(hi + 1) & (a + pick(rp, 2) <= ebf[2:3])
+    m_b2 = m_b1 & (hi + 2 < n) & (a + pick(rp, 3) <= ebf[3:4])
+
+    f_sure = jnp.maximum(lo, 0)                               # [1, Tp]
+    l_sure = jnp.minimum(hi, n - 1)                           # [Sb, Tp]
+    f_idx = jnp.where(m_a2, lo - 2, jnp.where(m_a1, lo - 1, f_sure))
+    l_idx = l_sure + m_b1.astype(i32) + m_b2.astype(i32)
+    cnt = jnp.maximum(l_idx - f_idx + 1, 0)
+    cnt_f = cnt.astype(f32)
+
+    if window:
+        ok = cnt >= 1
+        if fn == "count_over_time":
+            return jnp.where(ok, cnt_f, 0.0), ok.astype(f32)
+        vp = dot_exact01(v, w)
+        s = jnp.dot(v, band, preferred_element_type=f32)      # closed band
+        for j, m in enumerate((m_a2, m_a1, m_b1, m_b2)):
+            s = s + jnp.where(m, pick(vp, j), 0.0)
+        if fn == "avg_over_time":
+            s = s / cnt_f
+        return jnp.where(ok, s, 0.0), ok.astype(f32)
+
+    is_counter = fn != "delta"
+
+    def step(x):              # one increment, counter-corrected like inc
+        return jnp.maximum(x, 0.0) if is_counter else x
+
+    vp = dot_exact01(v, w)
+    v_a2, v_a1, v_b1, v_b2, v_lo, v_hi = (pick(vp, j) for j in range(6))
+    raw = v - roll(v)
+    mask = valid & (col > 0)
+    if c0:
+        mask &= lcol > 0
+    inc = jnp.where(mask, step(raw), 0.0)
+    delta = jnp.dot(inc, band, preferred_element_type=f32)    # (lo, hi]
+    delta = (delta
+             + jnp.where(m_a1 & (lo < n), step(v_lo - v_a1), 0.0)
+             + jnp.where(m_a2 & m_a1, step(v_a1 - v_a2), 0.0)
+             + jnp.where(m_b1 & (hi >= 0), step(v_b1 - v_hi), 0.0)
+             + jnp.where(m_b2, step(v_b2 - v_b1), 0.0))
+    f_v = jnp.where(m_a2, v_a2, jnp.where(m_a1, v_a1, v_lo))
+    # the residual of the row's own last cell, where it ends under the window
+    r_end = jnp.sum(jnp.where(col == n - 1, rf, 0.0), axis=1, keepdims=True)
+    r_f = jnp.where(m_a2, pick(rp, 0), jnp.where(m_a1, pick(rp, 1),
+                                                 pick(rp, 4)))
+    r_l = jnp.where(m_b2, pick(rp, 3), jnp.where(
+        m_b1, pick(rp, 2), jnp.where(hi <= n - 1, pick(rp, 5), r_end)))
+    # stamps relative to the base, in integers until they are differences
+    t_f = f_idx * interval_ms + start + r_f.astype(i32)
+    t_l = l_idx * interval_ms + start + r_l.astype(i32)
+    dur_start = (t_f - (rel - window_ms)).astype(f32) / 1000.0
+    dur_end = (rel - t_l).astype(f32) / 1000.0
+    sampled = (t_l - t_f).astype(f32) / 1000.0
+    return _extrapolate(fn, window_ms, delta, f_v, dur_start, dur_end,
+                        sampled, cnt, cnt_f)
+
+
+def _extrapolate(fn, window_ms, delta, f_v, dur_start, dur_end, sampled,
+                 cnt, cnt_f):
+    """Prometheus' extrapolatedRate from a window's delta, first value and
+    three durations (seconds): ``(contrib, okf)`` of :func:`tile_contrib`."""
+    f32 = jnp.float32
+    avg_dur = sampled / (cnt_f - 1.0)
+    if fn != "delta":
+        safe = jnp.where(delta > 0, delta, 1.0)
+        dur_zero = jnp.where(delta > 0, sampled * (f_v / safe), jnp.inf)
+        dur_start = jnp.where((delta > 0) & (f_v >= 0) & (dur_zero < dur_start),
+                              dur_zero, dur_start)
+    thresh = avg_dur * 1.1
+    extrap = sampled
+    extrap = extrap + jnp.where(dur_start < thresh, dur_start, avg_dur / 2)
+    extrap = extrap + jnp.where(dur_end < thresh, dur_end, avg_dur / 2)
+    scaled = delta * (extrap / sampled)
+    if fn == "rate":
+        scaled = scaled * (1000.0 / window_ms)
+
+    ok = cnt >= 2
+    return jnp.where(ok, scaled, 0.0), ok.astype(f32)
+
+
 def tile_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
-                 v, n, band, ohlo, lo, hi, rel, roll):
+                 v, n, band, ohlo, lo, hi, rel, roll, line=None):
     """Shared per-tile window math of the fused tier: decoded values
     ``v [Sb, Ca]`` -> ``(contrib [Sb, Tp]`` with absent cells zeroed,
     ``okf [Sb, Tp]`` presence as f32). ONE definition per tiling plan for
@@ -80,7 +272,13 @@ def tile_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     ``roll`` abstracts the backend's shift primitive (pltpu.roll in-kernel,
     jnp.roll in the scan); the wrapped column's garbage is masked either
     way. ``band`` is the OPEN band for the rate family and the CLOSED band
-    for the window-aggregation fns (host_operands builds the right one)."""
+    for the window-aggregation fns (host_operands builds the right one).
+    ``line = (start, res, eb)`` is a line store's tile (see
+    :func:`_line_contrib`; ``ohlo`` is then the six-block ``ohe``); None is
+    the grid, where column c IS cell c of every row."""
+    if line is not None:
+        return _line_contrib(fn, window_ms, interval_ms, c0, v, n, band,
+                             ohlo, lo, hi, rel, roll, *line)
     f32 = jnp.float32
     Sb, Ca = v.shape
     lcol = jax.lax.broadcasted_iota(jnp.int32, (Sb, Ca), 1)
@@ -127,22 +325,8 @@ def tile_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     dur_start = (f_rel - (relf - window_ms)) / 1000.0
     dur_end = (relf - l_rel) / 1000.0
     sampled = (l_rel - f_rel) / 1000.0
-    avg_dur = sampled / (cnt_f - 1.0)
-    if is_counter:
-        safe = jnp.where(delta > 0, delta, 1.0)
-        dur_zero = jnp.where(delta > 0, sampled * (f_v / safe), jnp.inf)
-        dur_start = jnp.where((delta > 0) & (f_v >= 0) & (dur_zero < dur_start),
-                              dur_zero, dur_start)
-    thresh = avg_dur * 1.1
-    extrap = sampled
-    extrap = extrap + jnp.where(dur_start < thresh, dur_start, avg_dur / 2)
-    extrap = extrap + jnp.where(dur_end < thresh, dur_end, avg_dur / 2)
-    scaled = delta * (extrap / sampled)
-    if fn == "rate":
-        scaled = scaled * (1000.0 / window_ms)
-
-    ok = cnt >= 2
-    return jnp.where(ok, scaled, 0.0), ok.astype(f32)
+    return _extrapolate(fn, window_ms, delta, f_v, dur_start, dur_end,
+                        sampled, cnt, cnt_f)
 
 
 # back-compat alias: the quant16 decode now lives in the shared decode-
@@ -152,7 +336,7 @@ decode_narrow_tile = decodereg.decode_quant16
 
 def _kernel_body(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
                  Sb: int, Ca: int, Tp: int, G: int, residency: str, c0: int,
-                 *refs):
+                 line: bool, *refs):
     """``Ca`` is the streamed column width and ``c0`` its global offset into
     the store: a sub-range query streams (and matmuls) only its active
     columns (see active_columns); full-range queries have c0=0, Ca=C.
@@ -162,20 +346,28 @@ def _kernel_body(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
     R = var.row_operands
     val_ref = refs[0]
     rowrefs = refs[1:1 + R]
-    (n_ref, gid_ref, band_ref, ohlo_ref,
-     lo_ref, hi_ref, rel_ref, sum_ref, cnt_ref, *maybe_sumsq) = refs[1 + R:]
+    rest = refs[1 + R:]
+    n_ref, gid_ref = rest[:2]
+    n, tile = n_ref[:], None                                  # [Sb, 1] i32
+    if line:        # res [Sb, Ca] int8 ... eb [8, Tp] i32; start rides in n
+        (res_ref, band_ref, ohlo_ref, lo_ref, hi_ref, rel_ref,
+         eb_ref, sum_ref, cnt_ref, *maybe_sumsq) = rest[2:]
+        n, start = unpack_start(n)
+        tile = (start, res_ref[:], eb_ref[:])
+    else:
+        (band_ref, ohlo_ref, lo_ref, hi_ref, rel_ref,
+         sum_ref, cnt_ref, *maybe_sumsq) = rest[2:]
     i = pl.program_id(0)
     f32 = jnp.float32
 
     # decode in VMEM: the registered pallas twin of the residency variant
     v = var.pallas(val_ref[:], *(r[:] for r in rowrefs))      # [Sb, Ca]
-    n = n_ref[:]                                              # [Sb, 1] i32
     # i32 shift: x64 mode would lower an i64 operand, which
     # tpu.dynamic_rotate rejects
     contrib, okf = tile_contrib(
         fn, window_ms, interval_ms, c0, v, n, band_ref[:], ohlo_ref[:],
         lo_ref[:], hi_ref[:], rel_ref[:],
-        roll=lambda x: pltpu.roll(x, jnp.int32(1), 1))
+        roll=lambda x: pltpu.roll(x, jnp.int32(1), 1), line=tile)
 
     # per-group fold on the MXU: [G, Sb] one-hot x [Sb, Tp]
     gid = gid_ref[:]                                          # [Sb, 1] i32
@@ -203,7 +395,8 @@ def _kernel_body(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
 @functools.lru_cache(maxsize=64)
 def build_pallas(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
                  S: int, Sb: int, C: int, Tp: int, G: int, interpret: bool,
-                 residency: str = "raw", c0: int = 0, Ck: int = 0):
+                 residency: str = "raw", c0: int = 0, Ck: int = 0,
+                 line: bool = False):
     """The raw (traceable) fused-kernel pallas_call — also invoked inside
     ``shard_map`` by the mesh executor (parallel/distributed.py), where each
     shard runs this same map phase on its resident block and the partial
@@ -218,15 +411,20 @@ def build_pallas(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
     column ``c0`` and spans only ``Ca`` columns — HBM bytes and MXU MACs
     scale with the query's range, not the store's retention — and the band
     operands arrive pre-sliced to [Ca, Tp]. full_columns variants (the
-    delta cumsum telescopes from cell 0) require c0=0."""
+    delta cumsum telescopes from cell 0) require c0=0. ``line``: the store
+    keeps stamps as line + residual — the kernel takes each row's start
+    packed above its count (:func:`pack_start`), the residual block beside
+    the values, ``ohe`` in place of ``ohlo`` and the edge bounds ``eb`` last
+    (:func:`_line_contrib`)."""
     var = decodereg.variant(residency)
     assert not var.full_columns or c0 == 0, (residency, c0)
+    assert not line or residency == "raw", residency
     n_out = 3 if needs_sumsq else 2
     Ca = Ck if Ck else C
     out_shape = tuple(jax.ShapeDtypeStruct((G, Tp), jnp.float32)
                       for _ in range(n_out))
     body = functools.partial(_kernel_body, fn, needs_sumsq, window_ms,
-                             interval_ms, Sb, Ca, Tp, G, residency, c0)
+                             interval_ms, Sb, Ca, Tp, G, residency, c0, line)
     acc_spec = pl.BlockSpec((G, Tp), lambda i: (0, 0), memory_space=pltpu.VMEM)
     const = functools.partial(pl.BlockSpec, index_map=lambda i: (0, 0),
                               memory_space=pltpu.VMEM)
@@ -236,11 +434,15 @@ def build_pallas(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
     in_specs = [pl.BlockSpec((Sb, Ca), lambda i: (i, kcol),
                              memory_space=pltpu.VMEM)]
     in_specs += [row((Sb, 1))] * var.row_operands   # vmin/scale or anchor
+    in_specs += [row((Sb, 1)), row((Sb, 1))]
+    if line:
+        in_specs += [in_specs[0]]                   # the residual tile
     in_specs += [
-        row((Sb, 1)), row((Sb, 1)),
-        const((Ca, Tp)), const((Ca, Tp)),
+        const((Ca, Tp)), const((Ca, (EDGE_SLOTS if line else 1) * Tp)),
         const((1, Tp)), const((1, Tp)), const((1, Tp)),
     ]
+    if line:
+        in_specs += [const((8, Tp))]
     # scoped VMEM, stated from the footprint instead of the 16 MiB default:
     # the value tile and both bands double-buffered, the accumulators, and
     # the f32 working set of tile_contrib (decoded tile, shifted copy,
@@ -251,6 +453,9 @@ def build_pallas(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
                       + 2 * Ca * Tp * 4)
                  + 2 * n_out * G * Tp * 4
                  + 4 * Sb * Ca * 4 + 12 * Sb * Tp * 4)
+    if line:        # residual tile, ohe, the picked planes, bf16 pieces
+        footprint += (2 * (Sb * Ca + EDGE_SLOTS * Ca * Tp * 2)
+                      + 2 * EDGE_SLOTS * Sb * Tp * 4 + 3 * Sb * Ca * 4)
     return pl.pallas_call(
         body,
         grid=(S // Sb,),
@@ -296,7 +501,8 @@ def active_columns(C: int, lo: np.ndarray, hi: np.ndarray) -> tuple[int, int]:
 
 def build_xla_tiles(fn: str, needs_sumsq: bool, window_ms: int,
                     interval_ms: int, S: int, Sb: int, C: int, Tp: int,
-                    G: int, residency: str = "raw", c0: int = 0, Ck: int = 0):
+                    G: int, residency: str = "raw", c0: int = 0, Ck: int = 0,
+                    line: bool = False):
     """XLA-fused twin of :func:`build_pallas`, built from the SAME tiling
     plan: one ``lax.scan`` walks the identical [Sb, Ca] row tiles through
     the identical :func:`tile_contrib` math and accumulates the same [G, Tp]
@@ -317,12 +523,16 @@ def build_xla_tiles(fn: str, needs_sumsq: bool, window_ms: int,
     roll = lambda x: jnp.roll(x, 1, axis=1)  # noqa: E731 — tile-local wrap,
     # masked in tile_contrib exactly like pltpu.roll's
 
-    def fold(carry, xs, band, ohlo, lo, hi, rel):
+    def fold(carry, xs, band, ohlo, lo, hi, rel, *eb):
         blk_t, *rest = xs
         v = var.xla(blk_t, *rest[:R])
-        n_t, g_t = rest[R], rest[R + 1]
+        n_t, g_t, tile = rest[R], rest[R + 1], None
+        if line:
+            n_t, start_t = unpack_start(n_t)
+            tile = (start_t, rest[R + 2], eb[0])
         contrib, okf = tile_contrib(fn, window_ms, interval_ms, c0,
-                                    v, n_t, band, ohlo, lo, hi, rel, roll)
+                                    v, n_t, band, ohlo, lo, hi, rel, roll,
+                                    line=tile)
         gcol = jax.lax.broadcasted_iota(jnp.int32, (Sb, G), 1)
         oh = (gcol == g_t).astype(f32)
         out = (carry[0] + jax.lax.dot_general(oh, contrib, dn,
@@ -334,28 +544,33 @@ def build_xla_tiles(fn: str, needs_sumsq: bool, window_ms: int,
                 oh, contrib * contrib, dn, preferred_element_type=f32),)
         return out, None
 
-    def run_tiles(tiles, band, ohlo, lo, hi, rel):
+    def run_tiles(tiles, *ops):
         init = tuple(jnp.zeros((G, Tp), f32)
                      for _ in range(3 if needs_sumsq else 2))
         outs, _ = jax.lax.scan(
-            lambda c, xs: fold(c, xs, band, ohlo, lo, hi, rel), init, tiles)
+            lambda c, xs: fold(c, xs, *ops), init, tiles)
         return outs
 
     def call(blk, *rest):
-        # rest: R per-row decode operands, n2, g2, then the 5 band/edge ops;
-        # active columns sliced like the pallas block index map
+        # rest: R per-row decode operands, n2, g2, (a line store's
+        # residual block,) then the band/edge ops; active columns sliced
+        # like the pallas block index map
         rows, n2, g2 = rest[:R], rest[R], rest[R + 1]
         tiles = ((blk[:, c0:c0 + Ca].reshape(nt, Sb, Ca),)
                  + tuple(r.reshape(nt, Sb, 1) for r in rows)
                  + (n2.reshape(nt, Sb, 1), g2.reshape(nt, Sb, 1)))
-        return run_tiles(tiles, *rest[R + 2:])
+        k = R + 2
+        if line:
+            tiles += (rest[k][:, c0:c0 + Ca].reshape(nt, Sb, Ca),)
+            k += 1
+        return run_tiles(tiles, *rest[k:])
     return call
 
 
 def _build_call(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
                 S: int, Sb: int, C: int, Tp: int, G: int,
                 residency: str = "raw", c0: int = 0, Ck: int = 0,
-                variant: str = "pallas"):
+                variant: str = "pallas", line: bool = False):
     """The compiled fused program via the explicit plan cache (query/
     plancache.py) — its key IS this signature: fn/op statics, the padded
     [S, C, Tp, G] shape buckets, the ``residency`` decode variant
@@ -369,11 +584,11 @@ def _build_call(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
     def build():
         if variant == "xla":
             call = build_xla_tiles(fn, needs_sumsq, window_ms, interval_ms,
-                                   S, Sb, C, Tp, G, residency, c0, Ck)
+                                   S, Sb, C, Tp, G, residency, c0, Ck, line)
         else:
             call = build_pallas(fn, needs_sumsq, window_ms, interval_ms,
                                 S, Sb, C, Tp, G, variant != "pallas",
-                                residency, c0, Ck)
+                                residency, c0, Ck, line)
 
         # one dispatch per query: dtype casts and [S] -> [S, 1] reshapes live
         # inside the jit — every extra dispatch is a host round trip of its
@@ -386,6 +601,11 @@ def _build_call(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
                             n.astype(jnp.int32).reshape(S, 1),
                             gids.astype(jnp.int32).reshape(S, 1),
                             *rest[R + 2:])
+        elif line:
+            def wrapped(val, n, gids, start, res, *ops):
+                return call(val.astype(jnp.float32),
+                            pack_start(n, start).reshape(S, 1),
+                            gids.astype(jnp.int32).reshape(S, 1), res, *ops)
         else:
             def wrapped(val, n, gids, *ops):
                 return call(val.astype(jnp.float32),
@@ -393,10 +613,11 @@ def _build_call(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
                             gids.astype(jnp.int32).reshape(S, 1), *ops)
         return wrapped
 
-    return plan_cache.program(
-        "fused-grid",
-        (fn, needs_sumsq, window_ms, interval_ms, S, Sb, C, Tp, G,
-         residency, c0, Ck, variant), build)
+    # a grid program's key is what it was before there was a line form
+    key = (fn, needs_sumsq, window_ms, interval_ms, S, Sb, C, Tp, G,
+           residency, c0, Ck, variant)
+    return plan_cache.program("fused-grid", key + (("line",) if line else ()),
+                              build)
 
 
 def pad_edges(lo: np.ndarray, hi: np.ndarray, rel: np.ndarray,
@@ -416,7 +637,7 @@ def pad_edges(lo: np.ndarray, hi: np.ndarray, rel: np.ndarray,
 
 def host_operands(C: int, Tp: int, out_ts: np.ndarray, window_ms: int,
                   base_ts: int, interval_ms: int, fn_kind: str = "rate",
-                  full_cols: bool = False):
+                  full_cols: bool = False, line: bool = False):
     """Band/one-hot/edge operands as host arrays + active column range:
     (band, ohlo, lo[1,Tp], hi[1,Tp], rel[1,Tp], c0, Ck) — shared by the
     single-chip upload cache below and the mesh path (which replicates them
@@ -427,33 +648,61 @@ def host_operands(C: int, Tp: int, out_ts: np.ndarray, window_ms: int,
     increment matmul needs, "window" the CLOSED band of the *_over_time
     fns (tile_contrib consumes whichever matches its fn). ``full_cols``
     bypasses active-column slicing — required by full_columns decode
-    variants whose per-tile decode telescopes from cell 0."""
+    variants whose per-tile decode telescopes from cell 0. ``line``: the
+    operands of a line store (:func:`_line_contrib`) — ``[lo, hi]`` are the
+    cells every row holds, ``ohe [C, 6 Tp]`` bf16 replaces ``ohlo`` (one-hot
+    blocks of the cells lo-2, lo-1, hi+1, hi+2, max(lo, 0), hi), and ``eb
+    [8, Tp]`` i32 follows ``rel``: per step the least ``start + residual``
+    that puts cell lo-2 (row 0) or lo-1 (row 1) in the window and the
+    most that puts hi+1 (row 2) or hi+2 (row 3) in it."""
     T = len(out_ts)
-    lo, hi = gridfns.grid_edges(out_ts, window_ms, base_ts, interval_ms)
+    lo, hi = gridfns.grid_edges(out_ts, window_ms, base_ts, interval_ms,
+                                line_spread(interval_ms) if line else (0, 0))
     rel = out_ts - base_ts
     lo_p, hi_p, rel_p = pad_edges(lo, hi, rel, window_ms, Tp)
     band = np.zeros((C, Tp), np.float32)
     band[:, :T] = gridfns.band_matrix(C, lo, hi, fn_kind == "rate",
                                       np.float32)
-    ohlo = np.zeros((C, Tp), np.float32)
-    ohlo[:, :T] = gridfns.onehot_matrix(C, np.maximum(lo, 0), np.float32)
-    c0, Ca = (0, C) if full_cols else active_columns(C, lo, hi)
+    if not line:
+        ohlo = np.zeros((C, Tp), np.float32)
+        ohlo[:, :T] = gridfns.onehot_matrix(C, np.maximum(lo, 0), np.float32)
+        c0, Ca = (0, C) if full_cols else active_columns(C, lo, hi)
+        if Ca < C:
+            band = np.ascontiguousarray(band[c0:c0 + Ca])
+            ohlo = np.ascontiguousarray(ohlo[c0:c0 + Ca])
+        return (band, ohlo, lo_p, hi_p, rel_p, c0, Ca)
+    import ml_dtypes
+    cells = (lo - 2, lo - 1, hi + 1, hi + 2, np.maximum(lo, 0), hi)
+    ohe = np.zeros((C, EDGE_SLOTS * Tp), ml_dtypes.bfloat16)
+    steps = np.arange(T)
+    for j, cell in enumerate(cells):
+        real = (cell >= 0) & (cell < C)
+        ohe[cell[real], j * Tp + steps[real]] = 1
+    eb = np.zeros((8, Tp), np.int64)
+    eb[:2], eb[2:4] = _NEVER, -_NEVER
+    for j, cell in enumerate(cells[:2]):          # start + res >= this
+        eb[j, :T] = np.where(cell >= 0, rel - window_ms - cell * interval_ms,
+                             _NEVER)
+    for j, cell in enumerate(cells[2:4], 2):      # start + res <= this
+        eb[j, :T] = np.where(cell >= 0, rel - cell * interval_ms, -_NEVER)
+    eb = np.clip(eb, -_NEVER, _NEVER).astype(np.int32)
+    c0, Ca = active_columns(C, lo - 2, hi + 2)
     if Ca < C:
         band = np.ascontiguousarray(band[c0:c0 + Ca])
-        ohlo = np.ascontiguousarray(ohlo[c0:c0 + Ca])
-    return (band, ohlo, lo_p, hi_p, rel_p, c0, Ca)
+        ohe = np.ascontiguousarray(ohe[c0:c0 + Ca])
+    return (band, ohe, lo_p, hi_p, rel_p, eb, c0, Ca)
 
 
 @functools.lru_cache(maxsize=32)
 def _device_operands(C: int, Tp: int, out_ts_key: bytes, window_ms: int,
                      base_ts: int, interval_ms: int, fn_kind: str = "rate",
-                     full_cols: bool = False):
+                     full_cols: bool = False, line: bool = False):
     """Band/one-hot/edge operands on device, cached per query shape — the
     upload matters: repeated host->device transfers of the [C, Tp] bands per
     row-batch are megabytes per query that never change."""
     out_ts = np.frombuffer(out_ts_key, np.int64)
     *arrs, c0, Ck = host_operands(C, Tp, out_ts, window_ms, base_ts,
-                                  interval_ms, fn_kind, full_cols)
+                                  interval_ms, fn_kind, full_cols, line)
     return tuple(jnp.asarray(a) for a in arrs) + (c0, Ck)
 
 
@@ -507,7 +756,7 @@ class PaddedPartials:
 def fused_grid_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
                          out_ts: np.ndarray, window_ms: int,
                          base_ts: int, interval_ms: int, fetch: bool = True,
-                         narrow=None, variant: str = "pallas"):
+                         narrow=None, variant: str = "pallas", line=None):
     """One-pass ``op(fn(metric[window]))`` partials over a grid-aligned block.
 
     val [S, C] f32 (S a multiple of 512 or a power of two), n [S] i32 valid
@@ -520,7 +769,11 @@ def fused_grid_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
     names the decode variant ("quant16" | "delta16" | "delta8") and
     ``operands = (block, *row_operands)`` its device arrays — 1/4 to 1/2 the
     HBM bytes; the caller must already have zeroed ``n`` for rows whose
-    narrow encoding is not bit-exact.
+    narrow encoding is not bit-exact. ``line = (start, res)`` says that
+    ``val`` is a line store's block: device i32 [S] row starts relative to
+    ``base_ts`` and the int8 [S, C] residual block (core/chunkstore.py
+    ``line_info``); the caller checked :func:`line_fusable` and zeroed
+    ``n`` for the rows off their line.
     """
     assert fn in FUSED_FNS | FUSED_WINDOW_FNS and op in FUSED_OPS
     if narrow is not None:
@@ -535,15 +788,16 @@ def fused_grid_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
     Sb = 512 if S % 512 == 0 else (S if S <= 512 else None)
     G = _roundup(max(num_groups, 8), 8)
 
-    band, ohlo, lo_d, hi_d, rel_d, c0, Ck = _device_operands(
+    *ops, c0, Ck = _device_operands(
         C, Tp, np.ascontiguousarray(np.asarray(out_ts, np.int64)).tobytes(),
         int(window_ms), int(base_ts), int(interval_ms),
         "window" if fn in FUSED_WINDOW_FNS else "rate",
-        decodereg.variant(kind).full_columns)
+        decodereg.variant(kind).full_columns, line is not None)
 
     needs_sumsq = op in ("stddev", "stdvar")
     call = _build_call(fn, needs_sumsq, int(window_ms), int(interval_ms),
-                       S, Sb, C, Tp, G, kind, c0, Ck, kernel_tag(variant))
+                       S, Sb, C, Tp, G, kind, c0, Ck, kernel_tag(variant),
+                       line is not None)
     # the framework runs with x64 on (int64 timestamps); Mosaic rejects the
     # i64 scalars x64 tracing injects (grid index maps, roll shifts), and the
     # kernel itself is pure f32/i32 — so trace the call with x64 off.
@@ -551,13 +805,14 @@ def fused_grid_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
     # the bytes the kernel streams from inside (rows x cols from c0 on)
     with span(SPAN_QUERY_KERNEL, phase="dispatch",
               kernel=kernel_tag(variant), rows=S, c0=c0, cols=Ck, steps=T,
-              groups=num_groups), jax.enable_x64(False):
+              groups=num_groups, stamps="grid" if line is None else "line"), \
+            jax.enable_x64(False):
         if nops is not None:
-            outs = call(*nops, jnp.asarray(n), jnp.asarray(gids),
-                        band, ohlo, lo_d, hi_d, rel_d)
+            outs = call(*nops, jnp.asarray(n), jnp.asarray(gids), *ops)
+        elif line is not None:
+            outs = call(val, jnp.asarray(n), jnp.asarray(gids), *line, *ops)
         else:
-            outs = call(val, jnp.asarray(n), jnp.asarray(gids),
-                        band, ohlo, lo_d, hi_d, rel_d)
+            outs = call(val, jnp.asarray(n), jnp.asarray(gids), *ops)
     # partial state is tiny ([G, Tp]): ONE host fetch finishes the query — the
     # slice/present/combine chain as device ops would cost a round-trip each
     padded = PaddedPartials(outs, op, num_groups, T)

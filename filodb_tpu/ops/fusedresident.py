@@ -51,6 +51,7 @@ from ..utils.metrics import (FILODB_QUERY_FUSED_FALL_TILES,
                              FILODB_QUERY_FUSED_FALLBACK,
                              FILODB_QUERY_FUSED_SERVED, registry)
 from . import decodereg, fusedgrid, gridfns
+from .fusedgrid import dot_exact01
 
 MODES = ("off", "xla", "pallas")
 
@@ -134,7 +135,8 @@ def count_fallback(shape: str) -> None:
 
 def scalar_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
                      out_ts: np.ndarray, window_ms: int, base_ts: int,
-                     interval_ms: int, fetch: bool = True, narrow=None):
+                     interval_ms: int, fetch: bool = True, narrow=None,
+                     line=None):
     """Mode-routed one-pass ``op(fn(metric[w]))`` partials (see
     fusedgrid.fused_grid_aggregate for operand contracts;
     ``narrow=(kind, operands)`` streams a registered narrow block —
@@ -143,7 +145,7 @@ def scalar_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
     assert _mode != "off"
     out = fusedgrid.fused_grid_aggregate(
         op, fn, val, n, gids, num_groups, out_ts, window_ms, base_ts,
-        interval_ms, fetch=fetch, narrow=narrow, variant=_mode)
+        interval_ms, fetch=fetch, narrow=narrow, variant=_mode, line=line)
     count_served(scalar_shape_of(fn) or "rate_sum")
     return out
 
@@ -555,28 +557,6 @@ def raw_hist_fusable(S: int, C: int, T: int, B: int, num_groups: int) -> bool:
     sublane tiles of buckets (the tile is reshaped [Sb, B, Ca] ->
     [Sb * B, Ca] for its matmuls, which is a relabelling only then)."""
     return hist_fusable(S, C, T, B, num_groups) and B % 8 == 0
-
-
-def dot_exact01(x, w):
-    """``x [M, K] f32 @ w [K, N]`` for a ``w`` of -1, 0 and 1 held in bf16,
-    exact to f32: ``x`` splits into three bf16 pieces (8 mantissa bits
-    each, the rest taken off in f32 without rounding), each piece times
-    such a weight is exact and the MXU accumulates in f32. HIGHEST would
-    split BOTH sides and run six passes; the three that multiply the
-    weight's (zero) low pieces add nothing. Integers below 2^24 come out
-    exact in any order."""
-    f32, bf16 = jnp.float32, jnp.bfloat16
-    hi = x.astype(bf16)
-    r = x - hi.astype(f32)
-    mid = r.astype(bf16)
-    lo = (r - mid.astype(f32)).astype(bf16)
-
-    def dot(a):
-        # DEFAULT, spelled out: one pass a piece (and the package-wide
-        # "highest" would ask Mosaic for an fp32 contraction of bf16)
-        return jnp.dot(a, w, precision=jax.lax.Precision.DEFAULT,
-                       preferred_element_type=f32)
-    return dot(hi) + dot(mid) + dot(lo)
 
 
 def raw_hist_weights(C: int, out_ts: np.ndarray, window_ms: int,

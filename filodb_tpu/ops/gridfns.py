@@ -35,12 +35,24 @@ GRID_FNS = {"rate", "increase", "delta", "sum_over_time", "count_over_time",
             "avg_over_time", "last_sample", "last_over_time"}
 
 
-def grid_edges(out_ts: np.ndarray, window_ms: int, base_ts: int, interval_ms: int):
-    """Host-side closed-form window edges in grid cells: cells with timestamps
-    in [t - window, t] are [lo_t, hi_t] inclusive (empty when hi < lo)."""
-    lo = np.ceil((out_ts - window_ms - base_ts) / interval_ms).astype(np.int64)
-    hi = np.floor((out_ts - base_ts) / interval_ms).astype(np.int64)
-    return lo, hi
+def grid_edges(out_ts: np.ndarray, window_ms: int, base_ts: int,
+               interval_ms: int, spread: tuple[int, int] = (0, 0)):
+    """Where a window lies in a row — THE one place that says so. Cell c of
+    a row holds a stamp ``base_ts + c * interval_ms + d``; on a grid ``d``
+    is 0 for every row, on a line store it is the row's start plus the
+    cell's residual, somewhere in ``spread = (dmin, dmax)``. Returned are
+    the cells that lie in [t - window, t] for EVERY such d: [lo_t, hi_t]
+    inclusive (empty when hi < lo). With the default spread that is the
+    exact closed form of the grid; with a wider one the cells just outside
+    ([lo - k, lo) and (hi, hi + k], k = ceil of the spread in intervals)
+    are in the window for some rows and not for others, and the caller
+    decides them row by row from the true stamps."""
+    dmin, dmax = spread
+    num_lo = np.asarray(out_ts, np.int64) - window_ms - base_ts - dmin
+    num_hi = np.asarray(out_ts, np.int64) - base_ts - dmax
+    lo = -((-num_lo) // interval_ms)            # ceil, in integers
+    hi = num_hi // interval_ms                  # floor
+    return lo.astype(np.int64), hi.astype(np.int64)
 
 
 def band_matrix(C: int, lo: np.ndarray, hi: np.ndarray, open_left: bool,

@@ -163,13 +163,18 @@ def _periodic(fn, ts, val, n, out_ts, window_ms, arg0, arg1, w_cap, acc):
         r = var if fn == "stdvar_over_time" else jnp.sqrt(var)
         return jnp.where(cnt_i >= 1, r, NAN)
 
-    if fn in ("last_over_time", "last_sample"):
+    if fn in ("last_over_time", "last_sample", "last_sample_age"):
         l_v = W.take(fval, right - 1)
         l_t = W.take(ts, right - 1)
         # last_sample additionally enforces staleness: arg0 = stale_ms
-        if fn == "last_sample":
-            fresh = (out_ts[None, :] - l_t) <= arg0
-            return jnp.where((cnt_i >= 1) & fresh, l_v, NAN)
+        if fn != "last_over_time":
+            age = out_ts[None, :] - l_t
+            if fn == "last_sample_age":
+                # how long before the step the instant selector's sample
+                # was stamped (ms; PromQL timestamp() is the step less
+                # this): a difference of stamps, small, never a stamp
+                l_v = age.astype(acc)
+            return jnp.where((cnt_i >= 1) & (age <= arg0), l_v, NAN)
         return jnp.where(cnt_i >= 1, l_v, NAN)
 
     if fn in ("changes", "resets"):

@@ -103,6 +103,12 @@ class SeriesSelection:
     # ``bad_rows`` (store rows in the cohort pool) recompute via row-wise
     # decode through the general kernels. Wide selections only.
     hist_narrow: tuple | None = None
+    # the store keeps stamps as line + residual (core/chunkstore.py
+    # ``LineInfo``): ``grid`` is None and the fused scalar tier reads the
+    # line — ``base_ts``/``interval_ms`` in the grid's place, each row's
+    # start and the residual block beside ``val``; the rows off their line
+    # are in ``grid_minority``. Wide selections only.
+    line: object | None = None
 
 
 @dataclass
@@ -196,10 +202,18 @@ class FusedWindowData:
 
     def materialize(self) -> MatrixView:
         from ..ops import gridfns
-        base_ts, interval_ms = self.sel.grid
         # same T-bucketing as PSM.apply: this fallback otherwise re-opens the
         # per-dashboard-shape compile cost on the hot f32 path
         out_eval, T = _pad_steps(self.out_ts)
+        if self.sel.grid is None:
+            # a line store with no aggregate over the window function to
+            # fuse with: the general kernels, from the stamps themselves
+            vals = rangefns.periodic_samples(
+                _dval(self.sel.ts), _dval(self.sel.val), self.sel.n,
+                out_eval, self.window, self.fn)
+            return MatrixView(self.out_ts, vals[:, :T], self.sel.keys,
+                              self.sel.rows)
+        base_ts, interval_ms = self.sel.grid
         vals = gridfns.periodic_samples_grid(
             _dval(self.sel.val), self.sel.n, out_eval, self.window, self.fn,
             base_ts, interval_ms, stale_ms=self.stale_ms)
@@ -269,7 +283,7 @@ class PeriodicSamplesMapper(Transformer):
         out_eval, T = _pad_steps(out_ts)
         Tpad = len(out_eval)
         fn = self.function or "last_sample"
-        if fn == "last_sample":
+        if fn in ("last_sample", "last_sample_age"):
             window = ctx.stale_ms
             args = (float(ctx.stale_ms),)
         else:
@@ -317,13 +331,11 @@ class PeriodicSamplesMapper(Transformer):
             if Tpad != T:
                 vals = vals[:, :T]
             return MatrixView(out_ts, vals, data.keys, data.rows, data.bucket_les)
+        if data.line is not None and self._line_fusable(data, fn, window,
+                                                        out_ts):
+            return FusedWindowData(data, out_ts, window, fn, ctx.stale_ms)
         if grid_usable and fn in gridfns.GRID_FNS:
-            from ..ops import fusedgrid, fusedresident
-            S, C = data.val.shape
-            if (fusedresident.mode() != "off"
-                    and fusedresident.scalar_shape_of(fn) is not None
-                    and data.val.dtype == jnp.float32
-                    and fusedgrid.fusable(S, C, len(out_ts), 1)):
+            if self._fusable(data, fn, out_ts):
                 # defer: a following AggregateMapReduce can fuse the window
                 # function with the aggregation in one single-pass program
                 # (Pallas or the XLA-fused twin per query.fused_kernels)
@@ -343,6 +355,28 @@ class PeriodicSamplesMapper(Transformer):
         if Tpad != T:
             vals = vals[:, :T]
         return MatrixView(out_ts, vals, data.keys, data.rows)
+
+
+    @staticmethod
+    def _fusable(data, fn, out_ts) -> bool:
+        """May a following aggregate fuse with this window function?"""
+        from ..ops import fusedgrid, fusedresident
+        S, C = data.val.shape
+        return (fusedresident.mode() != "off"
+                and fusedresident.scalar_shape_of(fn) is not None
+                and data.val.dtype == jnp.float32
+                and fusedgrid.fusable(S, C, len(out_ts), 1))
+
+    @classmethod
+    def _line_fusable(cls, data, fn, window, out_ts) -> bool:
+        """... over a line store's selection: the grid's conditions (the
+        relative range fits i32 among them) and the line's own."""
+        from ..ops import fusedgrid
+        base = data.line.base_ts
+        return (cls._fusable(data, fn, out_ts)
+                and fusedgrid.line_fusable(window, data.line.interval_ms)
+                and max(abs(int(out_ts[0]) - base),
+                        abs(int(out_ts[-1]) - base)) + window < 2**31)
 
 
 @dataclass
@@ -636,7 +670,12 @@ class AggregateMapReduce(Transformer):
             fusedresident.count_fallback(
                 fusedresident.scalar_shape_of(data.fn) or "rate_sum")
             return None
-        base_ts, interval_ms = sel.grid
+        line = None
+        if sel.grid is not None:
+            base_ts, interval_ms = sel.grid
+        else:
+            base_ts, interval_ms = sel.line.base_ts, sel.line.interval_ms
+            line = (sel.line.start, sel.line.res)
         n_eff = sel.n
         minority = sel.grid_minority
         narrow = None
@@ -667,7 +706,7 @@ class AggregateMapReduce(Transformer):
             sel.val if narrow is not None else _dval(sel.val),
             n_eff, gids_dev, Gp,
             data.out_ts, data.window, base_ts, interval_ms, fetch=False,
-            narrow=narrow)
+            narrow=narrow, line=line)
         ctx.stats.add("fused_kernels")
         ctx.kernels.add((narrow[0] if narrow is not None else "raw",
                          fusedresident.tag()))
@@ -1100,9 +1139,13 @@ class MiscellaneousFunctionMapper(Transformer):
         import re
         m = _as_matrix(data)
         if self.function == "timestamp":
+            # of an instant selector: each sample's own stamp (the leaf
+            # evaluated its age, ``str_args == ("age",)``); of anything
+            # else, a derived value's stamp is the step
             vals = np.asarray(m.values)
+            age = vals if self.str_args == ("age",) else 0
             out = np.where(np.isnan(vals), np.nan,
-                           (m.out_ts[None, :] / 1000.0))
+                           (m.out_ts[None, :] - age) / 1000.0)
             return ResultMatrix(m.out_ts, out,
                                 [k.without(("_metric_",)) for k in m.keys])
         if self.function == "label_replace":
@@ -1395,6 +1438,21 @@ class SelectRawPartitionsExec(ExecPlan):
                         grid = (base + o_maj * iv, iv)
                         if m:
                             minority_sel = mins
+        line = None
+        if grid is None and col is None and les is None:
+            # stamps kept as line + residual: the fused tier reads the
+            # line; the rows off it are this selection's minority, past
+            # the gate the general path answers as on any off-grid shard
+            line = store.line_info()
+            if line is not None and len(line.minority):
+                mins = line.off_mask[pids]
+                m = int(mins.sum())
+                if m > 0.25 * int((store.n_host[pids] > 0).sum()):
+                    line = None
+                elif m:
+                    minority_sel = mins
+        tags["demoted"] = (int(minority_sel.sum())
+                           if minority_sel is not None else 0)
         if len(pids) <= GATHER_THRESHOLD and len(pids) < 0.5 * max(total, 1):
             # narrow selection: gather rows once, padded to a power of two
             ctx.stats.add("blocks_raw")
@@ -1443,8 +1501,12 @@ class SelectRawPartitionsExec(ExecPlan):
         ctx.stats.add("blocks_narrow"
                       if (narrow is not None or hist_narrow is not None)
                       else "blocks_raw")
+        from ..core.chunkstore import _Deferred
+        if line is not None and (val.ndim != 2 or narrow is not None
+                                 or isinstance(val, _Deferred)):
+            line = None
         return SeriesSelection(ts, val, n_eff, keys, pids, grid, les,
-                               g_min, narrow, hist_narrow)
+                               g_min, narrow, hist_narrow, line)
 
 
 def _execute_children(children, ctx):

@@ -199,9 +199,16 @@ class QueryPlanner:
                 InstantVectorFunctionMapper(p.function, p.function_args)]
             return child
         if isinstance(p, L.ApplyMiscellaneousFunction):
+            args = p.string_args
+            if p.function == "timestamp" and isinstance(p.vectors,
+                                                        L.PeriodicSeries):
+                # timestamp(selector): the stamps the samples came with
+                args = ("age",)
             child = self._walk(p.vectors)
             child.transformers = child.transformers + [
-                MiscellaneousFunctionMapper(p.function, p.string_args)]
+                MiscellaneousFunctionMapper(p.function, args)]
+            if args == ("age",):
+                self._selector_ages(child)
             return child
         if isinstance(p, L.ApplySortFunction):
             child = self._walk(p.vectors)
@@ -321,6 +328,18 @@ class QueryPlanner:
             return 0.0        # scalar literals / time() / chunk-meta probes
 
         return walk(plan)
+
+    @staticmethod
+    def _selector_ages(plan: ExecPlan) -> None:
+        """Have the instant selector under ``plan`` evaluate each sample's
+        age at the step instead of its value (``timestamp()`` needs it)."""
+        import dataclasses
+        for node in [plan, *getattr(plan, "children", ())]:
+            inner = getattr(node, "inner", node)     # a peer-owned leaf
+            inner.transformers = [
+                dataclasses.replace(t, function="last_sample_age")
+                if isinstance(t, PeriodicSamplesMapper) and t.function is None
+                else t for t in inner.transformers]
 
     def _walk_shard_children(self, p) -> list[ExecPlan]:
         if isinstance(p, L.PeriodicSeries):

@@ -88,6 +88,9 @@ FILODB_RETENTION_ODP_ROWS = "filodb_retention_odp_rows"
 FILODB_RETENTION_REPLICA_FAILOVER = "filodb_retention_replica_failover"
 FILODB_RETENTION_AGED_OUT_ROWS = "filodb_retention_aged_out_rows"
 FILODB_STORE_RESIDENCY_FALLBACK = "filodb_store_residency_fallback"
+FILODB_STORE_STAMP_FORM = "filodb_store_stamp_form"
+FILODB_STORE_ROWS_DEMOTED = "filodb_store_rows_demoted"
+FILODB_STORE_ROWS_OFF_LINE = "filodb_store_rows_off_line"
 FILODB_RULES_EVALUATIONS = "filodb_rules_evaluations"
 FILODB_RULES_EVAL_FAILURES = "filodb_rules_eval_failures"
 FILODB_RULES_EVAL_LATENCY_MS = "filodb_rules_eval_latency_ms"
@@ -322,6 +325,20 @@ METRICS_SPEC: dict[str, tuple[str, str]] = {
                    "ok-contract (cohort gate breached), tagged "
                    "reason=resets|non-integer|range — distinguishes "
                    "\"compressed\" from \"tried and fell back to raw\"."),
+    FILODB_STORE_STAMP_FORM: (
+        "gauge", "How a shard's store keeps time: 0 = grid (every stamp on "
+                 "one common scrape grid, the s64 block resident), 1 = line "
+                 "(a line a row plus a narrow residual a cell; turned on "
+                 "once, by the first stamp off the grid)."),
+    FILODB_STORE_ROWS_DEMOTED: (
+        "counter", "Rows of a line-form store demoted from their line, "
+                   "tagged reason=residual|gap|interval (a stamp too far "
+                   "from the line for the residual's width, a skipped "
+                   "cell, a row on no cell of the line); a demoted row is "
+                   "answered by the general kernels."),
+    FILODB_STORE_ROWS_OFF_LINE: (
+        "gauge", "Live rows of a shard's store that the line kernel skips "
+                 "now: demoted rows and rows that start in another cell."),
     FILODB_RULES_EVALUATIONS: (
         "counter", "Rule evaluations completed, tagged group= and rule= "
                    "(one per rule per scheduler tick)."),

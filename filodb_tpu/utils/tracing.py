@@ -131,7 +131,9 @@ TRACE_SPEC: dict[str, str] = {
                      "waited for shard locks inside it).",
     SPAN_QUERY_SELECT: "Index select + array capture of one leaf; per shard "
                        "on the mesh route (tags: shard, series, memo = hit "
-                       "| miss | bypass of the shard's selection memo).",
+                       "| miss | bypass of the shard's selection memo, "
+                       "demoted = selected rows the fused kernel skips and "
+                       "the general kernels answer).",
     SPAN_QUERY_GROUPIDS: "Group ids of the selected series for a "
                          "by/without aggregation: from the index's label "
                          "columns where the selection is still pids "
@@ -143,7 +145,8 @@ TRACE_SPEC: dict[str, str] = {
     SPAN_QUERY_KERNEL: "Host side of one fused kernel: phase=dispatch is "
                        "the call under the shard lock, phase=fetch the "
                        "blocking fetch of its result outside it (dispatch "
-                       "tags: kernel, rows, c0, cols, steps, groups; the "
+                       "tags: kernel, rows, c0, cols, steps, groups, stamps "
+                       "= grid | line, how the store keeps time; the "
                        "fused-hist route adds buckets and variant = "
                        "hist-raw | hist-int8 | hist-int16 | hist-untiled; "
                        "hist-raw adds packed = 1 where one weight narrower "
@@ -182,7 +185,8 @@ TRACE_SPEC: dict[str, str] = {
                        "device scatter, backpressure, residency upkeep; an "
                        "idle flush opens none (tags: shard, rows, "
                        "lock_wait_ms, throttle_ms = the wait for the "
-                       "device).",
+                       "device, demoted = rows this flush took off their "
+                       "line).",
     SPAN_QUERY_RETENTION: "Downsample-aware routing of one query: the "
                           "resolution decision and its routed/stitched "
                           "leg queries hang under it (tags: dataset, "

@@ -154,13 +154,20 @@ def test_dense_append_matches_the_scatter_and_donates(monkeypatch, nbuckets,
         st = SeriesStore(64, 16, nbuckets=nbuckets, layout=lay,
                          default_col="h")
         for pid, ts, v in batches:
-            old = (st.ts, st.val, st.n, *st.extra.values())
+            old = (st.val, st.n, *st.extra.values())
+            stamps = st._stamp_block
             st.append(pid, ts, v)
             assert all(h.is_deleted() for h in old)
+            # rows that skip a cell turn the scalar store to its line form:
+            # the s64 block is dropped whole there, not donated
+            assert stamps.is_deleted() or (st.ts is None
+                                           and stamps is not st.res)
         return st
 
     a, b = run(False), run(True)
-    np.testing.assert_array_equal(np.asarray(a.ts), np.asarray(b.ts))
+    assert a.stamp_form == b.stamp_form == ("grid" if nbuckets else "line")
+    np.testing.assert_array_equal(np.asarray(a.ts_block()),
+                                  np.asarray(b.ts_block()))
     np.testing.assert_array_equal(np.asarray(a.val), np.asarray(b.val))
     assert sorted(a.extra) == sorted(b.extra) == (
         ["count", "sum"] if layout else [])

@@ -263,9 +263,9 @@ def test_the_packed_weight_telescopes_the_sum_of_increments(fn, T, c0_from):
     need = np.asarray(corr).reshape(S, B, Ca) != 0
     assert need.any() and not (need & ~(drops | ends)).any()
     vs = vs.reshape(S * B, Ca)
-    both = np.asarray(fusedresident.dot_exact01(
+    both = np.asarray(fusedgrid.dot_exact01(
         vs, jnp.asarray(w, jnp.bfloat16))).reshape(S, B, N)
-    fix = np.asarray(fusedresident.dot_exact01(
+    fix = np.asarray(fusedgrid.dot_exact01(
         corr, jnp.asarray(band, jnp.bfloat16))).reshape(S, B, Tp)
     np.testing.assert_array_equal(both[:, :, :T] + fix[:, :, :T], want_d)
     np.testing.assert_array_equal(both[:, :, N // 2:N // 2 + T], want_f)
@@ -294,7 +294,7 @@ def test_dot_exact01_is_exact_where_one_bf16_pass_is_not():
          - (rng.random((256, 128)) < 0.1))        # -1, 0 and 1
     assert set(np.unique(w)) == {-1.0, 0.0, 1.0}
     want = x.astype(np.float64) @ w.astype(np.float64)
-    got = np.asarray(fusedresident.dot_exact01(
+    got = np.asarray(fusedgrid.dot_exact01(
         jnp.asarray(x), jnp.asarray(w, jnp.bfloat16)))
     keep = np.abs(want) < 2**24              # the sum itself must fit f32
     assert keep.mean() > 0.9

@@ -89,6 +89,78 @@ def test_scalar_kernel_compiles_for_v5e(one_chip, fn, sumsq, C, Tp, G,
     _compile(call, _scalar_args(one_chip, C, Tp, residency))
 
 
+def _line_args(sh, C, Tp):
+    """A line store's operands (fusedgrid._line_contrib): each row's start
+    packed above its count, the int8 residual block beside the values,
+    ``ohe`` for ``ohlo``, the edge bounds last."""
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)  # noqa: E731
+    return [sds((S, C), f32), sds((S, 1), i32), sds((S, 1), i32),
+            sds((S, C), jnp.int8),
+            sds((C, Tp), f32), sds((C, fusedgrid.EDGE_SLOTS * Tp), jnp.bfloat16),
+            sds((1, Tp), i32), sds((1, Tp), i32), sds((1, Tp), i32),
+            sds((8, Tp), i32)]
+
+
+@pytest.mark.parametrize("fn,sumsq,Tp,G", [
+    ("rate", False, 128, 8),             # sum(rate), sum by (g)
+    ("avg_over_time", False, 128, 8),
+    ("sum_over_time", True, 128, 8),     # stddev: sumsq plane
+    ("count_over_time", False, 128, 8),
+    ("delta", True, 512, 64),            # every cap at once
+])
+def test_line_kernel_compiles_for_v5e_with_no_block_sized_temp(one_chip, fn,
+                                                               sumsq, Tp, G):
+    """promdev_prom_1m's kernel at 2^20 x 768: values and int8 residuals
+    stream in row tiles straight from their blocks, no operand is s64, and
+    the only temporaries are the grid kernel's own: the lane-padded
+    relayouts of its two [S, 1] operands, 512 MB each (PERF.md §5; a row's
+    start rides in its count, so the line adds no third) — nothing that
+    grows with the columns."""
+    C = 768
+    call = fusedgrid.build_pallas(fn, sumsq, WINDOW, IV, S, 512, C, Tp, G,
+                                  False, "raw", 0, 0, True)
+    args = _line_args(one_chip, C, Tp)
+    assert not any(a.dtype == jnp.int64 for a in args)
+    compiled = _compile(call, args)
+    assert "s64[" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 * S * 128 * 4 + (64 << 20), mem
+    grid = _compile(fusedgrid.build_pallas(fn, sumsq, WINDOW, IV, S, 512, C,
+                                           Tp, G, False, "raw", 0, 0),
+                    _scalar_args(one_chip, C, Tp, "raw"))
+    assert (mem.temp_size_in_bytes
+            <= grid.memory_analysis().temp_size_in_bytes + (8 << 20)), mem
+
+
+def test_line_kernels_xla_twin_compiles_for_v5e(one_chip):
+    """The twin scans the same tiles through the same tile math; its temp
+    is the tiles' relayout at most, never an s64 plane."""
+    C, Tp, G = 768, 128, 8
+    call = fusedgrid.build_xla_tiles("rate", False, WINDOW, IV, S, 512, C, Tp,
+                                     G, "raw", 0, 0, True)
+    with jax.enable_x64(False):
+        compiled = jax.jit(call).lower(*_line_args(one_chip, C, Tp)).compile()
+    assert "s64[" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < S * C * 5, mem
+
+
+def test_dense_flush_of_residuals_runs_in_place(one_chip):
+    """The flush of a line store at 2^20 x 768: the per-row select writes
+    the int8 residuals and the f32 values in place, each in a program of
+    its own; no s64 block (and none of its two u32 planes) exists."""
+    from filodb_tpu.core import chunkstore
+    C = 768
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    for dt in (jnp.int8, f32):
+        compiled = chunkstore._dense_set.lower(
+            sds((S, C), dt), sds((S,), i32), sds((S,), dt)).compile()
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= S * C * jnp.dtype(dt).itemsize
+        assert mem.temp_size_in_bytes < (64 << 20), mem
+        assert "s64[" not in compiled.as_text()
+
+
 @pytest.mark.parametrize("dd_dtype", [jnp.int8, jnp.int16])
 def test_hist_kernel_compiles_for_v5e_without_copying_the_store(one_chip,
                                                                 dd_dtype):
