@@ -22,4 +22,6 @@ _jax.config.update("jax_enable_x64", True)
 # f32. XLA's default on the TPU runs an f32 dot as ONE bf16 pass — 8 bits of
 # mantissa. First seen on the chip (PR 22): a raw selector read back 92160 for a
 # stored 92181. The CPU backend is exact either way, so no CPU test could show it.
+# The fused tiers spell their passes out (ops/fusedgrid.dot_exact01) and do not
+# lean on this; the composed paths do.
 _jax.config.update("jax_default_matmul_precision", "highest")

@@ -69,14 +69,26 @@ def _roundup(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-# The line form's six edge cells a step, in the order of their one-hot blocks
-# in ``ohe [Ca, 6 Tp]``: the two cells below the sure range [lo, hi], the
-# two above it (a row holds each or not, by its own stamps), then the sure
-# range's first and last cell (whose residuals the durations need). The
-# window functions read the first four only.
+# The line form's six edge cells a step, in the order of their one-hot slots
+# in ``ohe``: the two cells below the sure range [lo, hi], the two above it
+# (a row holds each or not, by its own stamps), then the sure range's first
+# and last cell (whose residuals the durations need). The window functions
+# read the first four only. A slot is a block of Tp lanes, ``ohe [Ca, 6
+# Tp]`` — or, where the steps fit half a block (:func:`slots_per_block`),
+# half of one: slots 2p and 2p + 1 share block p of ``ohe [Ca, 3 x 128]``,
+# step t on lane t and on lane 64 + t, and the window functions' CLOSED band
+# rides as slot 4 beside their four (raw_hist_weights packs its halves so).
 EDGE_SLOTS = 6
 EDGE_SLOTS_WINDOW = 4
+BAND_SLOT = 4           # the packed window form's band
 _NEVER = 1 << 30        # an edge bound no start + residual reaches
+
+
+def slots_per_block(T: int) -> int:
+    """Edge slots a 128-lane block of a line program over ``T`` steps: the
+    MXU's passes are counted in blocks, and up to 64 steps use half of
+    one."""
+    return 2 if T <= 64 else 1
 
 
 def line_spread(interval_ms: int) -> tuple[int, int]:
@@ -113,14 +125,15 @@ def line_fusable(window_ms: int, interval_ms: int) -> bool:
             and interval_ms + 2 * RES_MAX < 1 << (31 - _COUNT_BITS))
 
 
-def dot_exact01(x, w):
+def dot_exact01(x, w, dims=None):
     """``x [M, K] f32 @ w [K, N]`` for a ``w`` of -1, 0 and 1 held in bf16,
     exact to f32: ``x`` splits into three bf16 pieces (8 mantissa bits
     each, the rest taken off in f32 without rounding), each piece times
     such a weight is exact and the MXU accumulates in f32. HIGHEST would
     split BOTH sides and run six passes; the three that multiply the
     weight's (zero) low pieces add nothing. Integers below 2^24 come out
-    exact in any order."""
+    exact in any order. ``dims``: dot_general's dimension numbers of ``(w,
+    x)``, the weight on the LEFT — what :func:`group_fold` contracts."""
     f32, bf16 = jnp.float32, jnp.bfloat16
     hi = x.astype(bf16)
     r = x - hi.astype(f32)
@@ -130,9 +143,32 @@ def dot_exact01(x, w):
     def dot(a):
         # DEFAULT, spelled out: one pass a piece (and the package-wide
         # "highest" would ask Mosaic for an fp32 contraction of bf16)
-        return jnp.dot(a, w, precision=jax.lax.Precision.DEFAULT,
-                       preferred_element_type=f32)
+        if dims is None:
+            return jnp.dot(a, w, precision=jax.lax.Precision.DEFAULT,
+                           preferred_element_type=f32)
+        return jax.lax.dot_general(w, a, dims,
+                                   precision=jax.lax.Precision.DEFAULT,
+                                   preferred_element_type=f32)
     return dot(hi) + dot(mid) + dot(lo)
+
+
+def group_fold(gid, G: int, contrib, okf, needs_sumsq: bool):
+    """A tile's per-group partial state on the MXU: the one-hot ``oh [Sb,
+    G]`` of ``gid [Sb, 1]`` (bf16) against ``contrib`` / ``okf`` / the
+    squares ``[Sb, Tp]``, contracting the rows -> ``(sum, count[, sumsq])``,
+    each ``[G, Tp]``. The f32 sides go through :func:`dot_exact01`; ``okf``
+    is 0/1 itself and exact in ONE pass."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    gcol = jax.lax.broadcasted_iota(jnp.int32, (gid.shape[0], G), 1)
+    oh = (gcol == gid).astype(f32).astype(bf16)
+    dn = (((0,), (0,)), ((), ()))
+    out = (dot_exact01(contrib, oh, dn),
+           jax.lax.dot_general(oh, okf.astype(bf16), dn,
+                               precision=jax.lax.Precision.DEFAULT,
+                               preferred_element_type=f32))
+    if needs_sumsq:
+        out += (dot_exact01(contrib * contrib, oh, dn),)
+    return out
 
 
 def _line_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
@@ -150,11 +186,18 @@ def _line_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     decides them by comparing ``start + residual`` with the step's bound
     for that cell (``eb``, small integers: differences of stamps, never
     stamps). Stamps rise along a row, so the cells a row holds stay one
-    contiguous run ``[f_idx, l_idx]``."""
+    contiguous run ``[f_idx, l_idx]``.
+
+    ``ohe`` says by its width how its slots lie (see ``EDGE_SLOTS``): a
+    block each, or two a block. A packed slot's plane comes out with the
+    other half's numbers in lanes 64 on; no step lives there (``hi`` is -1
+    and ``eb`` never met), so they are masked like any padded step."""
     f32, i32 = jnp.float32, jnp.int32
     Sb, Ca = v.shape
     Tp = lo.shape[1]
     window = fn in FUSED_WINDOW_FNS
+    per = EDGE_SLOTS * Tp // ohe.shape[1]         # edge slots a block
+    packed = per == 2
     lcol = jax.lax.broadcasted_iota(i32, (Sb, Ca), 1)
     col = lcol + c0
     valid = col < n
@@ -162,13 +205,16 @@ def _line_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     rf = jnp.where(valid, res.astype(i32).astype(f32), 0.0)
 
     slots = EDGE_SLOTS_WINDOW if window else EDGE_SLOTS
-    w = ohe[:, :slots * Tp]
-    rp = jnp.dot(rf.astype(jnp.bfloat16), w,
+    # the window fns' values go through every block they have: the picks
+    # and, packed, the band with them
+    w = ohe if packed else ohe[:, :slots * Tp]
+    rp = jnp.dot(rf.astype(jnp.bfloat16), w[:, :slots // per * Tp],
                  precision=jax.lax.Precision.DEFAULT,
-                 preferred_element_type=f32)                  # [Sb, slots Tp]
+                 preferred_element_type=f32)              # [Sb, slots/per Tp]
 
-    def pick(x, j):
-        return x[:, j * Tp:(j + 1) * Tp]
+    def pick(x, j):       # slot j's plane, step t on lane t
+        blk = x[:, j // per * Tp:(j // per + 1) * Tp]
+        return roll(blk, Tp // 2) if j % per else blk
 
     a = start.astype(f32)                                     # [Sb, 1]
     ebf = eb.astype(f32)
@@ -196,7 +242,7 @@ def _line_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
         if fn == "count_over_time":
             return jnp.where(ok, cnt_f, 0.0), ok.astype(f32)
         vp = dot_exact01(v, w)
-        s = jnp.dot(v, band, preferred_element_type=f32)      # closed band
+        s = pick(vp, BAND_SLOT) if packed else dot_exact01(v, band)
         for j, m in enumerate((m_a2, m_a1, m_b1, m_b2)):
             s = s + jnp.where(m, pick(vp, j), 0.0)
         if fn == "avg_over_time":
@@ -210,12 +256,12 @@ def _line_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
 
     vp = dot_exact01(v, w)
     v_a2, v_a1, v_b1, v_b2, v_lo, v_hi = (pick(vp, j) for j in range(6))
-    raw = v - roll(v)
+    raw = v - roll(v, 1)
     mask = valid & (col > 0)
     if c0:
         mask &= lcol > 0
     inc = jnp.where(mask, step(raw), 0.0)
-    delta = jnp.dot(inc, band, preferred_element_type=f32)    # (lo, hi]
+    delta = dot_exact01(inc, band)                            # (lo, hi]
     delta = (delta
              + jnp.where(m_a1 & (lo < n), step(v_lo - v_a1), 0.0)
              + jnp.where(m_a2 & m_a1, step(v_a1 - v_a2), 0.0)
@@ -269,13 +315,15 @@ def tile_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     BOTH backends: the Pallas kernel body reads its VMEM refs and calls
     this; the XLA-fused twin (ops/fusedresident.py) scans the same row
     tiles through it — variant parity is by construction, not discipline.
-    ``roll`` abstracts the backend's shift primitive (pltpu.roll in-kernel,
+    ``roll(x, k)`` abstracts the backend's lane shift (pltpu.roll in-kernel,
     jnp.roll in the scan); the wrapped column's garbage is masked either
     way. ``band`` is the OPEN band for the rate family and the CLOSED band
     for the window-aggregation fns (host_operands builds the right one).
-    ``line = (start, res, eb)`` is a line store's tile (see
-    :func:`_line_contrib`; ``ohlo`` is then the six-block ``ohe``); None is
-    the grid, where column c IS cell c of every row."""
+    ``band`` and ``ohlo`` are 0/1 in bf16 and every product with them is
+    :func:`dot_exact01`'s: three MXU passes, exact, whatever the default
+    matmul precision. ``line = (start, res, eb)`` is a line store's tile
+    (see :func:`_line_contrib`; ``ohlo`` is then ``ohe``); None is the
+    grid, where column c IS cell c of every row."""
     if line is not None:
         return _line_contrib(fn, window_ms, interval_ms, c0, v, n, band,
                              ohlo, lo, hi, rel, roll, *line)
@@ -296,7 +344,7 @@ def tile_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
         ok = cnt >= 1
         if fn == "count_over_time":
             return jnp.where(ok, cnt_f, 0.0), ok.astype(f32)
-        s = jnp.dot(v, band, preferred_element_type=f32)      # closed band
+        s = dot_exact01(v, band)                              # closed band
         if fn == "avg_over_time":
             s = s / cnt_f
         return jnp.where(ok, s, 0.0), ok.astype(f32)
@@ -308,7 +356,7 @@ def tile_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     # column 0 wraps to the slice's LAST column — its increment is garbage but
     # never consumed (band rows at/below the first window edge are zero);
     # zero it anyway so no value-dependent surprise can leak
-    prev = roll(v)
+    prev = roll(v, 1)
     raw = v - prev
     inc = jnp.maximum(raw, 0.0) if is_counter else raw
     mask = valid & (col > 0)
@@ -316,8 +364,8 @@ def tile_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
         mask &= lcol > 0
     inc = jnp.where(mask, inc, 0.0)
 
-    delta = jnp.dot(inc, band, preferred_element_type=f32)    # [Sb, Tp]
-    f_v = jnp.dot(v, ohlo, preferred_element_type=f32)
+    delta = dot_exact01(inc, band)                            # [Sb, Tp]
+    f_v = dot_exact01(v, ohlo)
 
     relf = rel.astype(f32)                                    # [1, Tp]
     f_rel = (f_idx * interval_ms).astype(f32)
@@ -336,7 +384,7 @@ decode_narrow_tile = decodereg.decode_quant16
 
 def _kernel_body(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
                  Sb: int, Ca: int, Tp: int, G: int, residency: str, c0: int,
-                 line: bool, *refs):
+                 line: int, *refs):
     """``Ca`` is the streamed column width and ``c0`` its global offset into
     the store: a sub-range query streams (and matmuls) only its active
     columns (see active_columns); full-range queries have c0=0, Ca=C.
@@ -358,7 +406,6 @@ def _kernel_body(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
         (band_ref, ohlo_ref, lo_ref, hi_ref, rel_ref,
          sum_ref, cnt_ref, *maybe_sumsq) = rest[2:]
     i = pl.program_id(0)
-    f32 = jnp.float32
 
     # decode in VMEM: the registered pallas twin of the residency variant
     v = var.pallas(val_ref[:], *(r[:] for r in rowrefs))      # [Sb, Ca]
@@ -367,36 +414,25 @@ def _kernel_body(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
     contrib, okf = tile_contrib(
         fn, window_ms, interval_ms, c0, v, n, band_ref[:], ohlo_ref[:],
         lo_ref[:], hi_ref[:], rel_ref[:],
-        roll=lambda x: pltpu.roll(x, jnp.int32(1), 1), line=tile)
-
-    # per-group fold on the MXU: [G, Sb] one-hot x [Sb, Tp]
-    gid = gid_ref[:]                                          # [Sb, 1] i32
-    gcol = jax.lax.broadcasted_iota(jnp.int32, (Sb, G), 1)
-    oh = (gcol == gid).astype(f32)                            # [Sb, G]
-    dn = (((0,), (0,)), ((), ()))
-    psum = jax.lax.dot_general(oh, contrib, dn, preferred_element_type=f32)
-    pcnt = jax.lax.dot_general(oh, okf, dn, preferred_element_type=f32)
+        roll=lambda x, k: pltpu.roll(x, jnp.int32(k), 1), line=tile)
+    accs = (sum_ref, cnt_ref, *maybe_sumsq)
 
     @pl.when(i == 0)
     def _():
-        sum_ref[:] = jnp.zeros_like(sum_ref)
-        cnt_ref[:] = jnp.zeros_like(cnt_ref)
-        if needs_sumsq:
-            maybe_sumsq[0][:] = jnp.zeros_like(maybe_sumsq[0])
+        for acc in accs:
+            acc[:] = jnp.zeros_like(acc)
 
-    sum_ref[:] += psum
-    cnt_ref[:] += pcnt
-    if needs_sumsq:
-        psq = jax.lax.dot_general(oh, contrib * contrib, dn,
-                                  preferred_element_type=f32)
-        maybe_sumsq[0][:] += psq
+    # per-group fold on the MXU: [G, Sb] one-hot x [Sb, Tp]
+    for acc, part in zip(accs, group_fold(gid_ref[:], G, contrib, okf,
+                                          needs_sumsq)):
+        acc[:] += part
 
 
 @functools.lru_cache(maxsize=64)
 def build_pallas(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
                  S: int, Sb: int, C: int, Tp: int, G: int, interpret: bool,
                  residency: str = "raw", c0: int = 0, Ck: int = 0,
-                 line: bool = False):
+                 line: int = 0):
     """The raw (traceable) fused-kernel pallas_call — also invoked inside
     ``shard_map`` by the mesh executor (parallel/distributed.py), where each
     shard runs this same map phase on its resident block and the partial
@@ -410,11 +446,13 @@ def build_pallas(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
     it covers less than the full store, the kernel's value block starts at
     column ``c0`` and spans only ``Ca`` columns — HBM bytes and MXU MACs
     scale with the query's range, not the store's retention — and the band
-    operands arrive pre-sliced to [Ca, Tp]. full_columns variants (the
-    delta cumsum telescopes from cell 0) require c0=0. ``line``: the store
-    keeps stamps as line + residual — the kernel takes each row's start
-    packed above its count (:func:`pack_start`), the residual block beside
-    the values, ``ohe`` in place of ``ohlo`` and the edge bounds ``eb`` last
+    operands arrive pre-sliced to [Ca, Tp], 0/1 in bf16. full_columns
+    variants (the delta cumsum telescopes from cell 0) require c0=0.
+    ``line``: 0 on a grid store; where the store keeps stamps as line +
+    residual, the edge slots a block of ``ohe`` (:func:`slots_per_block`) —
+    the kernel then takes each row's start packed above its count
+    (:func:`pack_start`), the residual block beside the values, ``ohe`` in
+    place of ``ohlo`` and the edge bounds ``eb`` last
     (:func:`_line_contrib`)."""
     var = decodereg.variant(residency)
     assert not var.full_columns or c0 == 0, (residency, c0)
@@ -437,25 +475,26 @@ def build_pallas(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
     in_specs += [row((Sb, 1)), row((Sb, 1))]
     if line:
         in_specs += [in_specs[0]]                   # the residual tile
+    We = EDGE_SLOTS // line * Tp if line else Tp    # ohe's (ohlo's) lanes
     in_specs += [
-        const((Ca, Tp)), const((Ca, (EDGE_SLOTS if line else 1) * Tp)),
+        const((Ca, Tp)), const((Ca, We)),
         const((1, Tp)), const((1, Tp)), const((1, Tp)),
     ]
     if line:
         in_specs += [const((8, Tp))]
     # scoped VMEM, stated from the footprint instead of the 16 MiB default:
-    # the value tile and both bands double-buffered, the accumulators, and
-    # the f32 working set of tile_contrib (decoded tile, shifted copy,
-    # increments; a dozen [Sb, Tp] planes). At the caps (C=1024, Tp=512,
-    # G=64) with exact f32 contractions the default runs out ("Ran out of
-    # memory in memory space vmem", compiled for v5e)
+    # the value tile and both bf16 bands double-buffered, the accumulators,
+    # and the working set of tile_contrib (decoded tile, shifted copy,
+    # increments, and of v and the increments each the f32 remainder and
+    # three bf16 pieces; a dozen [Sb, Tp] planes). At the caps (C=1024,
+    # Tp=512, G=64) the default runs out ("Ran out of memory in memory
+    # space vmem", compiled for v5e)
     footprint = (2 * (Sb * Ca * jnp.dtype(var.block_dtype).itemsize
-                      + 2 * Ca * Tp * 4)
+                      + Ca * (Tp + We) * 2)
                  + 2 * n_out * G * Tp * 4
-                 + 4 * Sb * Ca * 4 + 12 * Sb * Tp * 4)
-    if line:        # residual tile, ohe, the picked planes, bf16 pieces
-        footprint += (2 * (Sb * Ca + EDGE_SLOTS * Ca * Tp * 2)
-                      + 2 * EDGE_SLOTS * Sb * Tp * 4 + 3 * Sb * Ca * 4)
+                 + 9 * Sb * Ca * 4 + 12 * Sb * Tp * 4)
+    if line:        # residual tile and its bf16 copy, the picked planes
+        footprint += 2 * Sb * Ca + Sb * Ca * 4 + 4 * Sb * We * 4
     return pl.pallas_call(
         body,
         grid=(S // Sb,),
@@ -502,7 +541,7 @@ def active_columns(C: int, lo: np.ndarray, hi: np.ndarray) -> tuple[int, int]:
 def build_xla_tiles(fn: str, needs_sumsq: bool, window_ms: int,
                     interval_ms: int, S: int, Sb: int, C: int, Tp: int,
                     G: int, residency: str = "raw", c0: int = 0, Ck: int = 0,
-                    line: bool = False):
+                    line: int = 0):
     """XLA-fused twin of :func:`build_pallas`, built from the SAME tiling
     plan: one ``lax.scan`` walks the identical [Sb, Ca] row tiles through
     the identical :func:`tile_contrib` math and accumulates the same [G, Tp]
@@ -519,9 +558,8 @@ def build_xla_tiles(fn: str, needs_sumsq: bool, window_ms: int,
     R = var.row_operands
     Ca = Ck if Ck else C
     nt = S // Sb
-    dn = (((0,), (0,)), ((), ()))
-    roll = lambda x: jnp.roll(x, 1, axis=1)  # noqa: E731 — tile-local wrap,
-    # masked in tile_contrib exactly like pltpu.roll's
+    roll = lambda x, k: jnp.roll(x, k, axis=1)  # noqa: E731 — tile-local
+    # wrap, masked in tile_contrib exactly like pltpu.roll's
 
     def fold(carry, xs, band, ohlo, lo, hi, rel, *eb):
         blk_t, *rest = xs
@@ -533,16 +571,8 @@ def build_xla_tiles(fn: str, needs_sumsq: bool, window_ms: int,
         contrib, okf = tile_contrib(fn, window_ms, interval_ms, c0,
                                     v, n_t, band, ohlo, lo, hi, rel, roll,
                                     line=tile)
-        gcol = jax.lax.broadcasted_iota(jnp.int32, (Sb, G), 1)
-        oh = (gcol == g_t).astype(f32)
-        out = (carry[0] + jax.lax.dot_general(oh, contrib, dn,
-                                              preferred_element_type=f32),
-               carry[1] + jax.lax.dot_general(oh, okf, dn,
-                                              preferred_element_type=f32))
-        if needs_sumsq:
-            out += (carry[2] + jax.lax.dot_general(
-                oh, contrib * contrib, dn, preferred_element_type=f32),)
-        return out, None
+        parts = group_fold(g_t, G, contrib, okf, needs_sumsq)
+        return tuple(c + p for c, p in zip(carry, parts)), None
 
     def run_tiles(tiles, *ops):
         init = tuple(jnp.zeros((G, Tp), f32)
@@ -570,14 +600,15 @@ def build_xla_tiles(fn: str, needs_sumsq: bool, window_ms: int,
 def _build_call(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
                 S: int, Sb: int, C: int, Tp: int, G: int,
                 residency: str = "raw", c0: int = 0, Ck: int = 0,
-                variant: str = "pallas", line: bool = False):
+                variant: str = "pallas", line: int = 0):
     """The compiled fused program via the explicit plan cache (query/
     plancache.py) — its key IS this signature: fn/op statics, the padded
     [S, C, Tp, G] shape buckets, the ``residency`` decode variant
     ("raw" | "quant16" | "delta16" | "delta8"), and the backend ``variant``
     as :func:`kernel_tag` names it ("pallas" | "pallas-interpret" | "xla")
     — every (residency, backend) pair is a distinct program and caches as a
-    distinct kernel variant."""
+    distinct kernel variant. ``line`` as :func:`build_pallas` has it: Tp is
+    128 for 1..128 steps, so a packed line program is told apart here."""
     from ..query.plancache import plan_cache
     R = decodereg.variant(residency).row_operands
 
@@ -613,11 +644,13 @@ def _build_call(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
                             gids.astype(jnp.int32).reshape(S, 1), *ops)
         return wrapped
 
-    # a grid program's key is what it was before there was a line form
+    # a grid program's key is what it was before there was a line form, and
+    # an unpacked line program's what it was before there was a packed one
     key = (fn, needs_sumsq, window_ms, interval_ms, S, Sb, C, Tp, G,
            residency, c0, Ck, variant)
-    return plan_cache.program("fused-grid", key + (("line",) if line else ()),
-                              build)
+    if line:
+        key += ("line",) if line == 1 else ("line", line)
+    return plan_cache.program("fused-grid", key, build)
 
 
 def pad_edges(lo: np.ndarray, hi: np.ndarray, rel: np.ndarray,
@@ -641,43 +674,52 @@ def host_operands(C: int, Tp: int, out_ts: np.ndarray, window_ms: int,
     """Band/one-hot/edge operands as host arrays + active column range:
     (band, ohlo, lo[1,Tp], hi[1,Tp], rel[1,Tp], c0, Ck) — shared by the
     single-chip upload cache below and the mesh path (which replicates them
-    across shard devices). For a sub-range query the band/ohlo rows are
-    sliced to the active [c0, c0+Ck) columns (the tiled kernel streams
-    only those store tiles); full-range queries keep [C, Tp] operands.
+    across shard devices). ``band`` and ``ohlo`` are 0/1 and go up as bf16,
+    rounded here (:func:`dot_exact01` multiplies them in three passes). For
+    a sub-range query the band/ohlo rows are sliced to the active [c0,
+    c0+Ck) columns (the tiled kernel streams only those store tiles);
+    full-range queries keep [C, Tp] operands.
     ``fn_kind`` picks the band form: "rate" builds the OPEN band the
     increment matmul needs, "window" the CLOSED band of the *_over_time
     fns (tile_contrib consumes whichever matches its fn). ``full_cols``
     bypasses active-column slicing — required by full_columns decode
     variants whose per-tile decode telescopes from cell 0. ``line``: the
     operands of a line store (:func:`_line_contrib`) — ``[lo, hi]`` are the
-    cells every row holds, ``ohe [C, 6 Tp]`` bf16 replaces ``ohlo`` (one-hot
-    blocks of the cells lo-2, lo-1, hi+1, hi+2, max(lo, 0), hi), and ``eb
-    [8, Tp]`` i32 follows ``rel``: per step the least ``start + residual``
-    that puts cell lo-2 (row 0) or lo-1 (row 1) in the window and the
-    most that puts hi+1 (row 2) or hi+2 (row 3) in it."""
+    cells every row holds, ``ohe`` replaces ``ohlo`` (one-hot slots of the
+    cells lo-2, lo-1, hi+1, hi+2, max(lo, 0), hi: ``[C, 6 Tp]``, or two
+    slots a block, ``[C, 3 x 128]``, where :func:`slots_per_block` says
+    so, the window fns' band then in slot 4), and ``eb [8, Tp]`` i32
+    follows ``rel``: per step the least ``start + residual`` that puts cell
+    lo-2 (row 0) or lo-1 (row 1) in the window and the most that puts hi+1
+    (row 2) or hi+2 (row 3) in it."""
+    import ml_dtypes
+    bf16 = ml_dtypes.bfloat16
     T = len(out_ts)
     lo, hi = gridfns.grid_edges(out_ts, window_ms, base_ts, interval_ms,
                                 line_spread(interval_ms) if line else (0, 0))
     rel = out_ts - base_ts
     lo_p, hi_p, rel_p = pad_edges(lo, hi, rel, window_ms, Tp)
-    band = np.zeros((C, Tp), np.float32)
+    band = np.zeros((C, Tp), bf16)
     band[:, :T] = gridfns.band_matrix(C, lo, hi, fn_kind == "rate",
                                       np.float32)
     if not line:
-        ohlo = np.zeros((C, Tp), np.float32)
+        ohlo = np.zeros((C, Tp), bf16)
         ohlo[:, :T] = gridfns.onehot_matrix(C, np.maximum(lo, 0), np.float32)
         c0, Ca = (0, C) if full_cols else active_columns(C, lo, hi)
         if Ca < C:
             band = np.ascontiguousarray(band[c0:c0 + Ca])
             ohlo = np.ascontiguousarray(ohlo[c0:c0 + Ca])
         return (band, ohlo, lo_p, hi_p, rel_p, c0, Ca)
-    import ml_dtypes
     cells = (lo - 2, lo - 1, hi + 1, hi + 2, np.maximum(lo, 0), hi)
-    ohe = np.zeros((C, EDGE_SLOTS * Tp), ml_dtypes.bfloat16)
+    slot = Tp // slots_per_block(T)       # lanes from one slot to the next
+    ohe = np.zeros((C, EDGE_SLOTS * slot), bf16)
     steps = np.arange(T)
+    if slot < Tp and fn_kind == "window":
+        cells = cells[:EDGE_SLOTS_WINDOW]
+        ohe[:, BAND_SLOT * slot:BAND_SLOT * slot + T] = band[:, :T]
     for j, cell in enumerate(cells):
         real = (cell >= 0) & (cell < C)
-        ohe[cell[real], j * Tp + steps[real]] = 1
+        ohe[cell[real], j * slot + steps[real]] = 1
     eb = np.zeros((8, Tp), np.int64)
     eb[:2], eb[2:4] = _NEVER, -_NEVER
     for j, cell in enumerate(cells[:2]):          # start + res >= this
@@ -787,6 +829,7 @@ def fused_grid_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
     Tp = _roundup(max(T, 1), 128)
     Sb = 512 if S % 512 == 0 else (S if S <= 512 else None)
     G = _roundup(max(num_groups, 8), 8)
+    per = slots_per_block(T) if line is not None else 0
 
     *ops, c0, Ck = _device_operands(
         C, Tp, np.ascontiguousarray(np.asarray(out_ts, np.int64)).tobytes(),
@@ -796,16 +839,18 @@ def fused_grid_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
 
     needs_sumsq = op in ("stddev", "stdvar")
     call = _build_call(fn, needs_sumsq, int(window_ms), int(interval_ms),
-                       S, Sb, C, Tp, G, kind, c0, Ck, kernel_tag(variant),
-                       line is not None)
+                       S, Sb, C, Tp, G, kind, c0, Ck, kernel_tag(variant), per)
     # the framework runs with x64 on (int64 timestamps); Mosaic rejects the
     # i64 scalars x64 tracing injects (grid index maps, roll shifts), and the
     # kernel itself is pure f32/i32 — so trace the call with x64 off.
     # The span's tags are what ties a device event to its query and gives
-    # the bytes the kernel streams from inside (rows x cols from c0 on)
+    # the bytes the kernel streams from inside (rows x cols from c0 on);
+    # ``packed``: edge slots a block of a line program (1 | 2)
+    tags = {"stamps": "grid"} if line is None else {"stamps": "line",
+                                                    "packed": per}
     with span(SPAN_QUERY_KERNEL, phase="dispatch",
               kernel=kernel_tag(variant), rows=S, c0=c0, cols=Ck, steps=T,
-              groups=num_groups, stamps="grid" if line is None else "line"), \
+              groups=num_groups, **tags), \
             jax.enable_x64(False):
         if nops is not None:
             outs = call(*nops, jnp.asarray(n), jnp.asarray(gids), *ops)

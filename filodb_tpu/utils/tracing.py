@@ -146,7 +146,9 @@ TRACE_SPEC: dict[str, str] = {
                        "the call under the shard lock, phase=fetch the "
                        "blocking fetch of its result outside it (dispatch "
                        "tags: kernel, rows, c0, cols, steps, groups, stamps "
-                       "= grid | line, how the store keeps time; the "
+                       "= grid | line, how the store keeps time, and on "
+                       "line packed = 1 | 2, the edge slots a 128-lane "
+                       "block of the kernel's one-hot operand; the "
                        "fused-hist route adds buckets and variant = "
                        "hist-raw | hist-int8 | hist-int16 | hist-untiled; "
                        "hist-raw adds packed = 1 where one weight narrower "

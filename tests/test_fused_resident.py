@@ -97,10 +97,15 @@ def test_scalar_grid_all_modes_exact_vs_oracle():
             if m != "off":
                 # the fused map phase actually served (per-query stats)
                 assert r.stats.fused_kernels >= 1, (q, m)
-        # both backends exactly equal the composed-path oracle: same tile
-        # math, same fold contraction — parity by construction
-        np.testing.assert_array_equal(res["xla"], res["off"], err_msg=q)
-        np.testing.assert_array_equal(res["pallas"], res["off"], err_msg=q)
+        # the backends equal each other exactly: same tile math, same fold
+        # contraction — parity by construction. The composed-path oracle
+        # sums the same exact products in another order (its contractions
+        # are the default's six passes, the fused tier's its own three):
+        # a few f32 ulps of a sum (a stddev's cancellation makes them some
+        # 1e-6 of the answer; the deployments state 2e-4)
+        np.testing.assert_array_equal(res["xla"], res["pallas"], err_msg=q)
+        np.testing.assert_allclose(res["pallas"], res["off"], rtol=1e-5,
+                                   atol=0, err_msg=q)
 
 
 def test_scalar_off_mode_disables_the_fused_tier():

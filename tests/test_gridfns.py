@@ -351,3 +351,149 @@ def test_grid_operand_cache_bound_and_hits():
     for i in range(40):
         gridfns.grid_operands(64, out_ts + i, 60_000, "rate", 1_000_000, 10_000)
     assert gridfns._grid_operands_cached.cache_info().currsize <= 32
+
+
+# -- the fused scalar tier's products: three bf16 passes, spelled out ----------
+
+def _hard_values(rows=64, cols=256, seed=9):
+    """f32 [rows, cols] the split has to get right: non-integers of every
+    sign, magnitudes past 2^24, and values ON and one f32 ulp beside the
+    points where bf16 rounds up or down (a bf16 keeps 8 significant bits:
+    halfway between two of them is an odd multiple of 2^-8 times a power
+    of two)."""
+    rng = np.random.default_rng(seed)
+    x = rng.normal(0, 1, (rows, cols)) * 10.0 ** rng.integers(-3, 9, (rows, cols))
+    x[:, ::7] = rng.integers(1 << 24, 1 << 30, x[:, ::7].shape) + 0.5
+    half = (2 * rng.integers(64, 128, x[:, 3::7].shape) + 1) * 2.0 ** -8
+    x[:, 3::7] = half * 2.0 ** rng.integers(-6, 20, half.shape)
+    x = x.astype(np.float32)
+    x[:, 4::14] = np.nextafter(x[:, 3::14][:, :x[:, 4::14].shape[1]],
+                               np.float32(np.inf))
+    x[:, 5::14] = np.nextafter(x[:, 3::14][:, :x[:, 5::14].shape[1]],
+                               np.float32(-np.inf))
+    x[1::2] *= -1
+    assert (np.abs(x) > 1 << 24).any() and (x != np.round(x)).any()
+    return x
+
+
+def _band_and_pick(cols=256, T=40, Tp=128):
+    lo = np.arange(T) * 5 + 3
+    hi = lo + 30
+    band = np.zeros((cols, Tp), np.float32)
+    band[:, :T] = gridfns.band_matrix(cols, lo, hi, False, np.float32)
+    pick = np.zeros((cols, Tp), np.float32)
+    pick[:, :T] = gridfns.onehot_matrix(cols, lo, np.float32)
+    return band, pick
+
+
+def _fold_values(rows, Tp):
+    x = _hard_values(rows, Tp, seed=4)
+    x[np.abs(x) > 1e15] = 7.25                      # squares stay in f32
+    return x
+
+
+def _close_to_f64(got, x, w):
+    """Every product exact, the sum rounded as an f32 sum is: within a few
+    ulps of the terms' absolute sum — and nowhere near what ONE bf16 pass
+    gives."""
+    want = x.astype(np.float64) @ w.astype(np.float64)
+    room = 2.0 ** -21 * (np.abs(x).astype(np.float64) @ np.abs(w))
+    assert (np.abs(got - want) <= room).all()
+    return want
+
+
+def test_a_pick_through_three_passes_is_the_value_itself():
+    import jax.numpy as jnp
+    from filodb_tpu.ops import fusedgrid
+    x = _hard_values()
+    _band, pick = _band_and_pick()
+    got = np.asarray(fusedgrid.dot_exact01(jnp.asarray(x),
+                                           jnp.asarray(pick, jnp.bfloat16)))
+    lo = np.arange(40) * 5 + 3
+    np.testing.assert_array_equal(got[:, :40], x[:, lo])
+    assert not got[:, 40:].any()
+    one = np.asarray(jnp.dot(jnp.asarray(x, jnp.bfloat16),
+                             jnp.asarray(pick, jnp.bfloat16),
+                             preferred_element_type=jnp.float32))
+    assert (one[:, :40] != x[:, lo]).mean() > 0.5
+
+
+def test_a_band_sum_through_three_passes_is_an_f32_sum_of_exact_terms():
+    import jax
+    import jax.numpy as jnp
+    from filodb_tpu.ops import fusedgrid
+    x = _hard_values()
+    band, _pick = _band_and_pick()
+    got = np.asarray(fusedgrid.dot_exact01(jnp.asarray(x),
+                                           jnp.asarray(band, jnp.bfloat16)))
+    want = _close_to_f64(got, x, band)
+    # what the package-wide default gave is no closer
+    six = np.asarray(jnp.dot(jnp.asarray(x), jnp.asarray(band),
+                             precision=jax.lax.Precision.HIGHEST))
+    assert np.abs(got - want).max() <= 2 * np.abs(six - want).max()
+    one = np.asarray(jnp.dot(jnp.asarray(x, jnp.bfloat16),
+                             jnp.asarray(band, jnp.bfloat16),
+                             preferred_element_type=jnp.float32))
+    room = 2.0 ** -21 * (np.abs(x).astype(np.float64) @ band)
+    assert (np.abs(one - want) > room)[:, :40].mean() > 0.9
+
+
+@pytest.mark.parametrize("sumsq", (False, True))
+def test_the_group_fold_through_three_passes(sumsq):
+    import jax.numpy as jnp
+    from filodb_tpu.ops import fusedgrid
+    rows, Tp, G = 64, 128, 8
+    contrib = _fold_values(rows, Tp)
+    okf = (np.random.default_rng(1).random((rows, Tp)) < 0.7).astype(np.float32)
+    gid = (np.arange(rows) * 5 % 6).astype(np.int32)  # groups 6, 7 empty
+    parts = fusedgrid.group_fold(jnp.asarray(gid)[:, None], G,
+                                 jnp.asarray(contrib), jnp.asarray(okf), sumsq)
+    assert len(parts) == 2 + sumsq
+    oh = (gid[:, None] == np.arange(G)[None, :]).astype(np.float32)
+    _close_to_f64(np.asarray(parts[0]).T, contrib.T, oh)
+    np.testing.assert_array_equal(np.asarray(parts[1]), oh.T @ okf)
+    if sumsq:
+        _close_to_f64(np.asarray(parts[2]).T, (contrib * contrib).T, oh)
+    assert not np.asarray(parts[0])[6:].any()
+
+
+def test_a_nan_and_an_inf_poison_the_cells_they_poisoned_and_no_others():
+    """Under the default's six passes a NaN or an inf in a row made every
+    product of that row NaN (0 x inf): the whole row of a band sum or a
+    pick, and in the fold every group's cell of a poisoned step. The three
+    spelled-out passes poison those and leave every other cell as it is."""
+    import jax
+    import jax.numpy as jnp
+    from filodb_tpu.ops import fusedgrid
+    x = _hard_values()
+    clean = x.copy()
+    x[5, 17], x[9, 200], x[11, 0] = np.nan, np.inf, -np.inf
+    band, pick = _band_and_pick()
+    for w in (band, pick):
+        got = np.asarray(fusedgrid.dot_exact01(
+            jnp.asarray(x), jnp.asarray(w, jnp.bfloat16)))
+        six = np.asarray(jnp.dot(jnp.asarray(x), jnp.asarray(w),
+                                 precision=jax.lax.Precision.HIGHEST))
+        with np.errstate(invalid="ignore"):
+            f64 = x.astype(np.float64) @ w.astype(np.float64)
+        bad = np.zeros(got.shape, bool)
+        bad[[5, 9, 11]] = True
+        for other in (six, f64):
+            np.testing.assert_array_equal(np.isfinite(other), ~bad)
+        np.testing.assert_array_equal(np.isfinite(got), ~bad)
+        was = np.asarray(fusedgrid.dot_exact01(
+            jnp.asarray(clean), jnp.asarray(w, jnp.bfloat16)))
+        np.testing.assert_array_equal(got[~bad], was[~bad])
+    rows, Tp, G = 64, 128, 8
+    contrib = _fold_values(rows, Tp)
+    contrib[3, 10], contrib[20, 90] = np.nan, np.inf
+    okf = np.ones((rows, Tp), np.float32)
+    gid = (np.arange(rows) % G).astype(np.int32)
+    s, c, q = (np.asarray(p) for p in fusedgrid.group_fold(
+        jnp.asarray(gid)[:, None], G, jnp.asarray(contrib), jnp.asarray(okf),
+        True))
+    bad = np.zeros((G, Tp), bool)
+    bad[:, [10, 90]] = True
+    np.testing.assert_array_equal(np.isfinite(s), ~bad)
+    np.testing.assert_array_equal(np.isfinite(q), ~bad)
+    assert np.isfinite(c).all()

@@ -233,6 +233,120 @@ def test_a_row_that_ended_keeps_its_last_sample_in_the_window(backend, fn):
         assert err(present(agg, parts), aggregate(agg, want, gids, G)) < 1.0
 
 
+# -- C: two edge slots a 128-lane block (up to 64 steps) against one ----------
+
+STEP_COUNTS = (1, 61, 64, 65, 128)       # packed, packed, packed; one a block
+
+
+@functools.lru_cache(maxsize=None)
+def ended_steps(T):
+    """The first ``T`` of 128 steps over the ended store, the same for every
+    ``T``: first the eighteen that put a window's low edge on an ended row's
+    last stamp, 1 ms before and 1 ms after (rows that end at lo - 2, at
+    lo - 1, inside the window and before it, by their phases), then steps
+    of a stride that is no multiple of the interval."""
+    t, _v, n, _st, _ = the_ended_store()
+    lasts = np.array([t[r, n[r] - 1] for r in ENDED_ROWS])
+    edge = (lasts[:, None] + WINDOW + np.array([-1, 0, 1])[None, :]).ravel()
+    steps = np.concatenate([edge, BASE + 400_007 + 4_673 * np.arange(110)])
+    assert len(np.unique(steps)) == 128
+    return np.sort(steps[:T])
+
+
+@functools.lru_cache(maxsize=None)
+def ended_parts(backend, fn, T, grouped):
+    _t, _v, _n, st, _ = the_ended_store()
+    info = st.line_info()
+    gids = (np.arange(S) % G if grouped else np.zeros(S)).astype(np.int32)
+    parts = fusedgrid.fused_grid_aggregate(
+        "stddev", fn, st.val, st.n, jnp.asarray(gids), G if grouped else 1,
+        ended_steps(T), WINDOW, info.base_ts, info.interval_ms,
+        variant=backend, line=(info.start, info.res))
+    return gids, {k: np.asarray(a) for k, a in parts.items()}
+
+
+@functools.lru_cache(maxsize=None)
+def ended_want(fn):
+    t, v, n, _st, _ = the_ended_store()
+    return ended_steps(128), np.array([eval_range_fn(
+        fn, t[s, :n[s]], v[s, :n[s]], ended_steps(128), WINDOW)
+        for s in range(S)])
+
+
+@pytest.mark.parametrize("T", STEP_COUNTS)
+@pytest.mark.parametrize("fn", FNS)
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_the_line_kernel_at_every_slot_layout_matches_the_reference(
+        backend, fn, T):
+    steps, want = ended_want(fn)
+    want = want[:, np.searchsorted(steps, ended_steps(T))]
+    for grouped in (False, True):
+        gids, parts = ended_parts(backend, fn, T, grouped)
+        assert parts["sum"].shape == (G if grouped else 1, T)
+        for agg in AGGS:
+            assert err(present(agg, parts),
+                       aggregate(agg, want, gids, G if grouped else 1)) < 1.0
+
+
+@pytest.mark.parametrize("fn", FNS)
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_two_slots_a_block_answer_what_one_slot_a_block_answers(backend, fn):
+    """The same steps through both layouts (61 and 64 steps packed, the
+    first of 65 and of 128 not): every product is exact either way and the
+    algebra after it is the same, so the partial state is equal to the
+    bit."""
+    for grouped in (False, True):
+        for packed, plain in ((61, 65), (64, 128), (1, 128)):
+            assert fusedgrid.slots_per_block(packed) == 2
+            assert fusedgrid.slots_per_block(plain) == 1
+            at = np.searchsorted(ended_steps(plain), ended_steps(packed))
+            _, a = ended_parts(backend, fn, packed, grouped)
+            _, b = ended_parts(backend, fn, plain, grouped)
+            for k in ("sum", "count", "sumsq"):
+                np.testing.assert_array_equal(a[k], b[k][:, at])
+
+
+def test_the_slot_layout_is_part_of_a_line_programs_key_and_tag():
+    """Tp is 128 for 61 steps and for 100: the plan key and the dispatch
+    span say which layout a line program has; a grid program's key has
+    neither word and its span no ``packed``."""
+    from filodb_tpu.query.plancache import plan_cache
+    _t, _v, _n, st, _ = the_ended_store()
+    info = st.line_info()
+    plan_cache.clear()
+    tracer.drain()
+    for T, line in ((61, True), (100, True), (61, False)):
+        fusedgrid.fused_grid_aggregate(
+            "sum", "rate", st.val, st.n, jnp.zeros(S, jnp.int32), 1,
+            ended_steps(128)[:T], WINDOW, info.base_ts, info.interval_ms,
+            variant="xla", line=(info.start, info.res) if line else None)
+    keys = [k for k in plan_cache._entries if k[0] == "fused-grid"]
+    assert [k[14:] for k in keys] == [("line", 2), ("line",), ()]
+    assert len({k[:14] for k in keys}) == 1
+    spans = [s.tags for s in tracer.drain() if s.name == SPAN_QUERY_KERNEL
+             and s.tags.get("phase") == "dispatch"]
+    assert [(s["stamps"], s.get("packed")) for s in spans] == [
+        ("line", 2), ("line", 1), ("grid", None)]
+    # the operands: six slots in three blocks, or in six
+    for T, width in ((61, 3 * 128), (64, 3 * 128), (65, 6 * 128)):
+        for kind in ("rate", "window"):
+            band, ohe, lo, hi, rel, eb, c0, ca = fusedgrid.host_operands(
+                C, 128, ended_steps(128)[:T], WINDOW, info.base_ts, IV, kind,
+                line=True)
+            assert band.shape == (ca, 128) and ohe.shape == (ca, width)
+            assert band.dtype == ohe.dtype == jnp.bfloat16
+            assert eb.shape == (8, 128) and lo.shape == (1, 128)
+            ones = np.asarray(ohe, np.float32).sum(0)
+            if width == 3 * 128 and kind == "window":
+                # four one-hot slots, then the closed band in the fifth
+                assert (ones[:4 * 64] <= 1).all() and not ones[5 * 64:].any()
+                np.testing.assert_array_equal(
+                    np.asarray(ohe, np.float32)[:, 256:256 + T],
+                    np.asarray(band, np.float32)[:, :T])
+            else:
+                assert (ones <= 1).all() and ones.sum() > 4 * T
+
+
 def test_the_ended_rows_meet_the_case_they_are_there_for():
     """Of the chosen steps some hold, of some row, exactly its LAST sample
     in cell lo - 2 (the row has no cell lo - 1): the case in which a2 has
@@ -293,7 +407,9 @@ def test_dropping_the_residual_misses_the_tolerance(fn):
 
 def test_a_zero_residual_one_phase_store_is_todays_store():
     """Every stamp on one grid: no residual block, the s64 block resident,
-    today's operands byte for byte and today's program key."""
+    today's operands — their shapes and every entry, the 0/1 matrices held
+    in bf16 since the kernel spells its passes out — and today's program
+    key."""
     t = BASE + np.arange(K)[None, :] * IV + np.zeros((S, 1), np.int64)
     st = fed(t, stream()[1])
     assert st.stamp_form == "grid" and st.res is None and st.grid_ok
@@ -313,9 +429,11 @@ def test_a_zero_residual_one_phase_store_is_todays_store():
     c0, ca = fusedgrid.active_columns(C, lo, hi)
     want = (band[c0:c0 + ca], ohlo[c0:c0 + ca], lo_p, hi_p, rel_p, c0, ca)
     assert len(got) == len(want) == 7
-    for g, w in zip(got, want):
+    for i, (g, w) in enumerate(zip(got, want)):
         if isinstance(w, np.ndarray):
-            assert g.dtype == w.dtype and g.tobytes() == w.tobytes()
+            assert g.dtype == (jnp.bfloat16 if i < 2 else w.dtype)
+            assert g.shape == w.shape
+            assert g.astype(w.dtype).tobytes() == w.tobytes()
         else:
             assert g == w
     from filodb_tpu.query.plancache import plan_cache
@@ -324,7 +442,7 @@ def test_a_zero_residual_one_phase_store_is_todays_store():
                                    jnp.zeros(S, jnp.int32), 1, out_ts, WINDOW,
                                    BASE, IV, variant="xla")
     keys = [k for k in plan_cache._entries if k[0] == "fused-grid"]
-    assert len(keys) == 1 and "line" not in keys[0][1]
+    assert len(keys) == 1 and "line" not in keys[0]
 
 
 def test_a_layout_store_keeps_todays_behaviour():

@@ -28,7 +28,7 @@ from filodb_tpu.parallel import distributed
 
 S = 1 << 20
 WINDOW, IV = 300_000, 10_000
-f32, i32 = jnp.float32, jnp.int32
+f32, i32, bf16 = jnp.float32, jnp.int32, jnp.bfloat16
 
 
 @pytest.fixture(scope="module")
@@ -69,7 +69,7 @@ def _scalar_args(sh, C, Tp, residency):
     return ([sds((S, C), var.block_dtype)]
             + [sds((S, 1), f32)] * var.row_operands
             + [sds((S, 1), i32), sds((S, 1), i32),
-               sds((C, Tp), f32), sds((C, Tp), f32),
+               sds((C, Tp), bf16), sds((C, Tp), bf16),   # band, ohlo: 0/1
                sds((1, Tp), i32), sds((1, Tp), i32), sds((1, Tp), i32)])
 
 
@@ -89,27 +89,36 @@ def test_scalar_kernel_compiles_for_v5e(one_chip, fn, sumsq, C, Tp, G,
     _compile(call, _scalar_args(one_chip, C, Tp, residency))
 
 
-def _line_args(sh, C, Tp):
+def _line_args(sh, C, Tp, per=1):
     """A line store's operands (fusedgrid._line_contrib): each row's start
     packed above its count, the int8 residual block beside the values,
-    ``ohe`` for ``ohlo``, the edge bounds last."""
+    ``ohe`` for ``ohlo`` with ``per`` edge slots a block, the edge bounds
+    last."""
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)  # noqa: E731
     return [sds((S, C), f32), sds((S, 1), i32), sds((S, 1), i32),
             sds((S, C), jnp.int8),
-            sds((C, Tp), f32), sds((C, fusedgrid.EDGE_SLOTS * Tp), jnp.bfloat16),
+            sds((C, Tp), bf16), sds((C, fusedgrid.EDGE_SLOTS // per * Tp), bf16),
             sds((1, Tp), i32), sds((1, Tp), i32), sds((1, Tp), i32),
             sds((8, Tp), i32)]
 
 
-@pytest.mark.parametrize("fn,sumsq,Tp,G", [
-    ("rate", False, 128, 8),             # sum(rate), sum by (g)
-    ("avg_over_time", False, 128, 8),
-    ("sum_over_time", True, 128, 8),     # stddev: sumsq plane
-    ("count_over_time", False, 128, 8),
-    ("delta", True, 512, 64),            # every cap at once
+@pytest.mark.parametrize("fn,sumsq,Tp,G,per", [
+    ("rate", False, 128, 8, 1),          # sum(rate), sum by (g)
+    ("avg_over_time", False, 128, 8, 1),
+    ("sum_over_time", True, 128, 8, 1),  # stddev: sumsq plane
+    ("count_over_time", False, 128, 8, 1),
+    ("delta", True, 512, 64, 1),         # every cap at once
+    # up to 64 steps two edge slots share a block: the 64-lane roll that
+    # brings a half down, the window fns' band beside their picks
+    ("rate", False, 128, 8, 2),
+    ("rate", False, 128, 64, 2),
+    ("sum_over_time", True, 128, 8, 2),
+    ("sum_over_time", True, 128, 64, 2),
+    ("count_over_time", False, 128, 8, 2),
 ])
 def test_line_kernel_compiles_for_v5e_with_no_block_sized_temp(one_chip, fn,
-                                                               sumsq, Tp, G):
+                                                               sumsq, Tp, G,
+                                                               per):
     """promdev_prom_1m's kernel at 2^20 x 768: values and int8 residuals
     stream in row tiles straight from their blocks, no operand is s64, and
     the only temporaries are the grid kernel's own: the lane-padded
@@ -118,8 +127,8 @@ def test_line_kernel_compiles_for_v5e_with_no_block_sized_temp(one_chip, fn,
     grows with the columns."""
     C = 768
     call = fusedgrid.build_pallas(fn, sumsq, WINDOW, IV, S, 512, C, Tp, G,
-                                  False, "raw", 0, 0, True)
-    args = _line_args(one_chip, C, Tp)
+                                  False, "raw", 0, 0, per)
+    args = _line_args(one_chip, C, Tp, per)
     assert not any(a.dtype == jnp.int64 for a in args)
     compiled = _compile(call, args)
     assert "s64[" not in compiled.as_text()
@@ -132,14 +141,16 @@ def test_line_kernel_compiles_for_v5e_with_no_block_sized_temp(one_chip, fn,
             <= grid.memory_analysis().temp_size_in_bytes + (8 << 20)), mem
 
 
-def test_line_kernels_xla_twin_compiles_for_v5e(one_chip):
+@pytest.mark.parametrize("per", [1, 2])
+def test_line_kernels_xla_twin_compiles_for_v5e(one_chip, per):
     """The twin scans the same tiles through the same tile math; its temp
     is the tiles' relayout at most, never an s64 plane."""
     C, Tp, G = 768, 128, 8
     call = fusedgrid.build_xla_tiles("rate", False, WINDOW, IV, S, 512, C, Tp,
-                                     G, "raw", 0, 0, True)
+                                     G, "raw", 0, 0, per)
     with jax.enable_x64(False):
-        compiled = jax.jit(call).lower(*_line_args(one_chip, C, Tp)).compile()
+        compiled = jax.jit(call).lower(
+            *_line_args(one_chip, C, Tp, per)).compile()
     assert "s64[" not in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < S * C * 5, mem
@@ -273,7 +284,7 @@ def test_mesh_fused_program_compiles_for_four_chips(topo):
     args = ((sds((nd, per, C), f32, sharding=sh),),
             (sds((nd, per), i32, sharding=sh),),
             (sds((nd, per), i32, sharding=sh),),
-            sds((C, Tp), f32, sharding=rep), sds((C, Tp), f32, sharding=rep),
+            sds((C, Tp), bf16, sharding=rep), sds((C, Tp), bf16, sharding=rep),
             sds((1, Tp), i32, sharding=rep), sds((1, Tp), i32, sharding=rep),
             sds((1, Tp), i32, sharding=rep))
     with jax.enable_x64(False):
