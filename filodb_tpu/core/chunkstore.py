@@ -36,25 +36,50 @@ log = logging.getLogger(__name__)
 
 TS_PAD = np.int64(1) << np.int64(62)   # sentinel > any real timestamp
 
-# How the store keeps time. Column k of a row is the row's k-th sample. While
-# every series is scraped on one common grid (sample k at ``first + k *
-# interval``, every first stamp on the grid) the s64 block ``ts`` holds the
-# stamps and the shard is in its GRID form. The first stamp that is off that
-# grid — a target with its own scrape phase, a scrape stamped late — turns the
-# scalar store into its LINE form, once: a row's stamps are then a line
-# (``line0[row] + k * interval``, ``line0`` the row's first stamp) plus a
-# narrow signed residual per cell, ``res[S, C]`` on the device beside the
-# values, and the s64 block is dropped (it is derivable: ``ts_block``,
-# ``DeferredTs``). A sample's cell is ``round((ts - line0) / interval)``, so
-# a late scrape never shares a cell with its successor. A row whose sample
-# does not fit its line — a residual beyond RES_MAX, a skipped cell, a
-# changed interval — is DEMOTED as a row: its exact stamps move to a host
-# pool, it joins the minority set (``line_info().minority``) and is answered
-# by the general kernels over gathered rows, as a churned row is. The shard
-# stays on the fused path (ref: upstream's delta-delta timestamp vectors, a
-# line plus narrow residuals — doc/compression.md).
+# How the store keeps time. While every series is scraped on one common grid
+# (sample k at ``first + k * interval``, every first stamp on the grid, no
+# scrape missed) column k of a row is the row's k-th sample, the s64 block
+# ``ts`` holds the stamps and the shard is in its GRID form. The first stamp
+# that is off that grid — a target with its own scrape phase, a scrape
+# stamped late — or the first missed scrape turns the scalar store into its
+# LINE form, once: column c is then CELL c of the row's line (``line0[row] +
+# c * interval``, ``line0`` the row's first stamp) whether or not a sample
+# sits in it, with a narrow signed residual per cell, ``res[S, C]`` on the
+# device beside the values, and the s64 block is dropped (it is derivable:
+# ``ts_block``, ``DeferredTs``). A sample's cell is ``round((ts - line0) /
+# interval)``, so a late scrape never shares a cell with its successor. A
+# cell WITHOUT a sample is a HOLE and is kept as one: its residual reads
+# ``RES_HOLE`` (residuals lie in [-RES_MAX, RES_MAX], so the mark costs no
+# byte). Holes come from Prometheus's staleness markers (a row whose value
+# is ``STALE_NAN``: the scrape failed) and from cells a row skipped, runs of
+# up to HOLE_RUN_MAX cells of the two together (``tail_holes[row]`` is the
+# run a row ends in, so the bound holds across batches); ``n[row]`` counts
+# the cells a row USES, holes among them, and ``holes_host[row]`` the
+# holes. A hole's VALUE cell, which no function reads as a value, says
+# which of the two it is: a marker's holds its own stamp less the cell's
+# line stamp (what the residual would have held), a skipped cell's
+# ``HOLE_SKIPPED``. No function counts, sums or extrapolates to a hole. An
+# instant selector whose newest row at or before a step is a MARKER (by
+# the marker's own stamp) returns nothing, Prometheus's rule; a skipped
+# cell is nothing at all, and the sample before it is served for as long
+# as the lookback says. A row whose sample
+# does not fit its line — a residual beyond RES_MAX, a run of holes past
+# the bound, a changed interval — is DEMOTED as a row: its exact stamps
+# move to a host pool (a hole there is ``TS_PAD`` + its stamp), it joins the
+# minority set (``line_info().minority``) and is answered by the general
+# kernels over gathered rows, as a churned row is. The shard stays on the
+# fused path (ref: upstream's delta-delta timestamp vectors, a line plus
+# narrow residuals — doc/compression.md; Prometheus scrape/scrape.go for
+# the markers). A layout (histogram, multi-column) store has no line form:
+# a marker there is a NaN sample and a skipped cell clears ``grid_ok``.
 RES_DTYPE = np.int8
 RES_MAX = 127
+RES_HOLE = -128         # the residual of a cell that holds no sample
+HOLE_RUN_MAX = 3        # holes a row may hold in a run and stay on its line
+HOLE_SKIPPED = 256.0    # the value cell of a hole that no marker came for
+# Prometheus's value.StaleNaN: this bit pattern and no other NaN
+STALE_NAN_BITS = np.uint64(0x7FF0000000000002)
+STALE_NAN = np.array([STALE_NAN_BITS]).view(np.float64)[0]
 DEMOTE_REASONS = ("residual", "gap", "interval")
 
 
@@ -211,9 +236,47 @@ _derive_ts = jax.jit(_derive_ts_impl, static_argnums=(3,))
 @functools.partial(jax.jit, static_argnums=(4,))
 def _derive_line_ts(line0, n, interval, res, C):
     """The i64 stamps of a line-form store (or of gathered rows of one):
-    ``line0[r] + k * interval + res[r, k]`` for k < n[r], TS_PAD beyond."""
+    ``line0[r] + k * interval + res[r, k]`` for k < n[r], TS_PAD beyond. A
+    hole stays in its cell, told from a sample by being past TS_PAD: TS_PAD
+    + the stamp its line gives the cell (a marker's, to the residual)."""
     ts = _derive_ts_impl(line0, n, interval, C)
-    return jnp.where(ts == TS_PAD, TS_PAD, ts + res.astype(jnp.int64))
+    hole = res == RES_HOLE
+    return jnp.where(ts == TS_PAD, TS_PAD,
+                     ts + jnp.where(hole, TS_PAD, res.astype(jnp.int64)))
+
+
+@jax.jit
+def close_holes(ts, val, n):
+    """Rows whose used cells hold holes (stamps past TS_PAD, see
+    ``_derive_line_ts``) -> ``(ts, val, n)`` with every row's samples a
+    sorted prefix, as the general kernels (ops/windows.py) assume: a stable
+    partition along the row, ``n`` the samples left."""
+    col = jax.lax.broadcasted_iota(jnp.int32, ts.shape, 1)
+    real = (ts < TS_PAD) & (col < n[:, None])
+    order = jnp.argsort(~real, axis=1, stable=True)
+    m = real.sum(axis=1).astype(n.dtype)
+    ts = jnp.where(col < m[:, None], jnp.take_along_axis(ts, order, axis=1),
+                   TS_PAD)
+    return ts, jnp.take_along_axis(val, order, axis=1), m
+
+
+@functools.partial(jax.jit, donate_argnums=(1, 2))
+def _compact_line(ts, val, n, cutoff):
+    """``_compact`` over a line store's derived stamps: a row's cells shift
+    left by the cells before its first SAMPLE at or after ``cutoff``, holes
+    moving with their cells (a row never starts in a hole)."""
+    S, C = ts.shape
+    keep = (ts < TS_PAD) & (ts >= cutoff)
+    k = jnp.where(keep.any(axis=1), jnp.argmax(keep, axis=1), n)
+    idx = jnp.arange(C)[None, :] + k[:, None]
+    new_n = jnp.maximum(n - k.astype(n.dtype), 0)
+    valid = idx < n[:, None]
+    idx = jnp.where(idx < C, idx, C - 1)
+    new_ts = jnp.where(valid, jnp.take_along_axis(ts, idx, axis=1), TS_PAD)
+    new_val = jnp.where(valid, jnp.take_along_axis(val, idx, axis=1), 0)
+    return new_ts, new_val, new_n
+
+
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -401,6 +464,7 @@ class LineInfo:
     res: object             # device int8 [S, C]: stamp - line
     off_mask: np.ndarray    # bool [S]: live rows the line kernel must skip
     minority: np.ndarray    # int32: their row ids, ascending
+    holes: bool = False     # some used cell of the store holds no sample
 
 
 @dataclass
@@ -410,6 +474,8 @@ class SeriesStoreStats:
     capacity_dropped: int = 0
     compactions: int = 0
     frees: int = 0
+    stale_markers: int = 0      # rows that carried STALE_NAN (counted in
+                                # samples_appended too: they were ingested)
 
 
 class SeriesStore:
@@ -486,6 +552,14 @@ class SeriesStore:
         self._line_block = None
         self.demoted = dict.fromkeys(DEMOTE_REASONS, 0)
         self.demoted_last_append = 0
+        # holes a row holds among its n_host cells, and their sum (kept as a
+        # running one: every query reads it under the shard lock)
+        self.holes_host = np.zeros(S, np.int32)
+        self.hole_cells = 0
+        self.holes_last_append = 0
+        self._closed = None     # closed_arrays(), until the next mutation
+        self._phases_due = False
+        self.tail_holes = np.zeros(S, np.int32)     # the run a row ends in
         # start-cohort summary cache: recomputing per-row offsets per QUERY is
         # an O(S) host pass; starts only change on new series/compact/free
         self._cohorts = None
@@ -536,7 +610,7 @@ class SeriesStore:
         if self.owner_lock is not None:
             diagnostics.assert_owned(self.owner_lock, what)
         self.detective.record(what)
-        self._pool_dev = self._line_block = None
+        self._pool_dev = self._line_block = self._closed = None
 
     # -- narrow-resident lifecycle ------------------------------------------
     #
@@ -866,7 +940,23 @@ class SeriesStore:
                 keep[mask] = kk
             self.stats.out_of_order_dropped += int((~keep).sum())
             r, t, v = r[keep], t[keep], v[keep]
-        # running occurrence index within the (filtered) sorted batch -> dense cols
+        # a staleness marker (the scrape failed) is a row, not a sample: it
+        # is counted as ingested and leaves a hole in its cell. One that
+        # comes before its series' first sample has no line to sit on
+        stale = self._markers(v)
+        if stale is not None:
+            real = np.cumsum(~stale) - ~stale
+            first = np.concatenate([[0], np.nonzero(np.diff(r))[0] + 1])
+            before = real - np.repeat(
+                real[first], np.diff(np.concatenate([first, [len(r)]])))
+            lead = stale & (self.n_host[r] == 0) & (before == 0)
+            self.stats.stale_markers += int(stale.sum())
+            if lead.any():
+                r, t, v, stale = r[~lead], t[~lead], v[~lead], stale[~lead]
+            if not len(r):
+                return 0
+        # running occurrence index within the (filtered) sorted batch -> the
+        # rows' next columns
         boundaries = np.concatenate([[0], np.nonzero(np.diff(r))[0] + 1])
         occ = np.arange(len(r)) - np.repeat(
             boundaries, np.diff(np.concatenate([boundaries, [len(r)]])))
@@ -876,24 +966,83 @@ class SeriesStore:
             self.stats.capacity_dropped += int(over.sum())
             r, t, v, cols = r[~over], t[~over], v[~over], cols[~over]
             occ = occ[~over]
+            stale = None if stale is None else stale[~over]
         m = len(r)
         if m == 0:
             return 0
         self._rehydrate()      # mutations write the raw f32 block
         self._pre_donate("SeriesStore.append")
         # host bookkeeping
-        uniq, first_pos = np.unique(r, return_index=True)
+        # (the batch is sorted by row: its rows are its runs)
+        first_pos = np.flatnonzero(np.concatenate([[True], r[1:] != r[:-1]]))
+        uniq = r[first_pos]
+        # a row's column is its next one or, on its line, its CELL (see the
+        # text at RES_DTYPE): a skipped cell stays behind as a hole
+        cols, skipped, gap = self._cells(r, t, cols, uniq, first_pos, stale)
         newly = uniq[self.n_host[uniq] == 0]
         self.first_ts[newly] = t[first_pos[self.n_host[uniq] == 0]]
         self.line0[newly] = self.first_ts[newly]
         if len(newly):
             self._cohorts = None   # new starts can change the cohort summary
         # line form: the stamp block written below is the residual block
-        res = self._track_stamps(r, t, cols, uniq, first_pos)
+        # (markers and runs past the bound are named only where there are
+        # any: a batch without them is held as it ever was)
+        marks = {k: a for k, a in (("stale", stale), ("gap", gap))
+                 if a is not None}
+        res = self._track_stamps(r, t, cols, uniq, first_pos, **marks)
         stamps = t if res is None else res
+        if res is not None:
+            over = cols >= self.C       # a cell past the row's capacity
+            if over.any():
+                self.stats.capacity_dropped += int(over.sum())
+                r, t, v, cols = r[~over], t[~over], v[~over], cols[~over]
+                occ, stamps = occ[~over], stamps[~over]
+                stale = None if stale is None else stale[~over]
+                m = len(r)
+                if m == 0:
+                    return 0
+            if stale is not None:
+                # a marker's value cell: its stamp less the cell's line
+                # stamp (a demoted row's pool holds the stamp itself)
+                v = np.where(stale, np.where(
+                    self.off_line[r], 0,
+                    t - (self.line0[r] + cols.astype(np.int64)
+                         * self.grid_interval)), v)
+            if skipped:
+                # the cells a row skipped are written as holes: entries of
+                # their own, after the batch's (``occ`` tells a row's apart)
+                r, t, v, cols, occ, stamps, stale = self._with_skipped(
+                    r, t, v, cols, occ, stamps, stale)
+        ingested, m = m, len(r)
         np.maximum.at(self.last_ts, r, t)
-        counts = np.bincount(r, minlength=self.S).astype(np.int32)
-        self.n_host += counts
+        # a row's columns rise along the sorted batch: its last one counts
+        if m != ingested:       # entries for skipped cells joined the runs
+            first_pos = np.flatnonzero(
+                np.concatenate([[True], r[1:] != r[:-1]]))
+        last = np.concatenate([first_pos[1:], [m]]) - 1
+        moved = (cols[last] + 1 - self.n_host[uniq]).astype(np.int32)
+        counts = np.zeros(self.S, np.int32)
+        counts[uniq] = moved
+        self.holes_last_append = 0
+        if res is not None:
+            # the cells each row moved on by, less the samples it was given
+            given = np.diff(np.concatenate([first_pos, [m]])) \
+                if stale is None else np.add.reduceat(~stale, first_pos)
+            holes = moved - given.astype(np.int32)
+            self.holes_host[uniq] += holes
+            self.holes_last_append = int(holes.sum())
+            self.hole_cells += self.holes_last_append
+            # the run of holes each row ends in now: the entries after its
+            # last sample of the batch, or all of them on top of the old run
+            if stale is None:
+                self.tail_holes[uniq] = 0
+            else:
+                at = np.maximum.reduceat(
+                    np.where(stale, -1, np.arange(m)), first_pos)
+                self.tail_holes[uniq] = np.where(
+                    at >= 0, last - at,
+                    self.tail_holes[uniq] + last - first_pos + 1)
+        self.n_host[uniq] += moved
         # pad to bucketed size; padded rows use row index S => dropped by scatter
         P = _pad_size(m)
         v = np.asarray(v)
@@ -929,9 +1078,41 @@ class SeriesStore:
                 self.ts, self.val, self.extra, self.n,
                 jnp.asarray(rp), jnp.asarray(cp), jnp.asarray(tp),
                 jnp.asarray(vp).astype(self.dtype), evp, jnp.asarray(counts))
-        self.stats.samples_appended += m
+        self.stats.samples_appended += ingested
         self._appends_since_sync += 1
-        return m
+        return ingested
+
+    def _next_cols(self, r, cols):
+        """The column each entry of a sorted batch takes if its row skips
+        nothing: one past its row's entry before it, or the row's next."""
+        nxt = self.n_host[r].astype(cols.dtype)
+        again = np.flatnonzero(r[1:] == r[:-1]) + 1     # few, or none
+        nxt[again] = cols[again - 1] + 1
+        return nxt
+
+    def _with_skipped(self, r, t, v, cols, occ, res, stale):
+        """The batch with one more entry for every cell a row skipped on
+        its line (sorted by row still): residual RES_HOLE, value
+        HOLE_SKIPPED, and no sample (``stale`` true)."""
+        nxt = self._next_cols(r, cols)
+        skip = np.where(self.off_line[r], 0, cols - nxt)
+        at = np.flatnonzero(skip)
+        rows = np.repeat(r[at], skip[at])
+        cells = np.concatenate([np.arange(nxt[i], cols[i]) for i in at])
+        top = int(occ.max()) + 1
+        k = np.concatenate([np.arange(skip[i]) for i in at]) + top
+        stale = np.zeros(len(r), bool) if stale is None else stale
+        order = np.lexsort((np.concatenate([cols, cells]),
+                            np.concatenate([r, rows])))
+
+        def both(a, b):
+            return np.concatenate([a, b])[order]
+        z = len(rows)
+        return (both(r, rows), both(t, np.repeat(t[at], skip[at])),
+                both(v, np.full((z,) + v.shape[1:], HOLE_SKIPPED, v.dtype)),
+                both(cols, cells), both(occ, k),
+                both(res, np.full(z, RES_HOLE, res.dtype)),
+                both(stale, np.ones(z, bool)))
 
     def _append_dense(self, r, cols, t, v, extra, occ, counts) -> None:
         """The flush of a large store (see DENSE_APPEND_BYTES): one donated
@@ -1023,14 +1204,106 @@ class SeriesStore:
             return None
         return int(np.partition(d, len(d) // 2)[len(d) // 2])
 
-    def _track_stamps(self, r, t, cols, uniq, first_pos):
-        """Hold each append batch against the rows' lines. In the grid form
-        (every stamp ON its line, every line's phase the shard's) nothing
+    def _markers(self, v):
+        """bool [m]: the batch's staleness markers, None where it has none
+        (or the store is none that keeps them as holes)."""
+        if self.nbuckets or self.layout is not None or v.ndim != 1 \
+                or v.dtype != np.float64:
+            return None
+        stale = v.view(np.uint64) == STALE_NAN_BITS
+        return stale if stale.any() else None
+
+    def _cells(self, r, t, dense, uniq, first_pos, stale):
+        """(columns, whether a row skips a cell, the entries of rows whose
+        holes run past the bound or None) of a sorted batch: a scalar
+        store holds a row that is on its line by CELLS, so a stamp that
+        lies some cells past the row's next one goes to its own cell and
+        leaves holes behind, as long as the RUN of holes it closes — the
+        cells it skipped, the markers (``stale``) before it in the batch,
+        the run the row ended in — stays within HOLE_RUN_MAX; a marker
+        that would lengthen a run past it is held to the same. Every other
+        sample — a layout store's, a demoted row's, one that fits no cell
+        of its row or closes too long a run (``_track_stamps`` then
+        demotes the row), any before the interval is known — goes to its
+        row's next column, ``dense``. Sets the interval when this batch is
+        the first to show it."""
+        if self.nbuckets or self.layout is not None or (
+                self.res is None and not self.grid_ok):
+            return dense, False, None
+        iv = self.grid_interval
+        if iv is None:
+            iv = self._interval_of(r, t, uniq, first_pos)
+            if iv is None or iv <= 0:       # _track_stamps reads the same,
+                return dense, False, None   # and says
+            self.grid_interval = iv
+            self._phases_due = True     # starts recorded before it was known
+        l0 = self.line0[r]
+        # the scrape every row was waiting for, each in its next cell:
+        # nothing to place (the stream's steady state, one multiply a row)
+        if len(uniq) == len(r) and dense.all() and (
+                np.abs(t - (l0 + dense * iv)) <= RES_MAX).all() and (
+                stale is None
+                or (self.tail_holes[r[stale]] < HOLE_RUN_MAX).all()):
+            return dense, False, None
+        fresh = np.flatnonzero(self.n_host[uniq] == 0)
+        if len(fresh):              # rows this batch starts: its first stamp
+            runs = np.diff(np.concatenate([first_pos, [len(r)]]))
+            began = np.zeros(len(uniq), bool)
+            began[fresh] = True
+            l0 = np.where(np.repeat(began, runs),
+                          np.repeat(t[first_pos], runs), l0)
+        cell = (t - l0 + iv // 2) // iv
+        shift = cell - self._next_cols(r, cell)
+        long = self._hole_runs(r, shift, stale, first_pos) > HOLE_RUN_MAX
+        misfit = ((shift < 0) | long
+                  | (np.abs(t - (l0 + cell * iv)) > RES_MAX))
+        off = self.off_line[r]
+        gap = None
+        if misfit.any():            # one misfit: the whole row's batch
+            rows = np.zeros(self.S, bool)
+            rows[r[misfit]] = True
+            off = off | rows[r]
+            if long.any():
+                rows[:] = False
+                rows[r[long]] = True
+                gap = rows[r]
+        cols = np.where(off, dense, cell) if off.any() else cell
+        return cols, bool(((shift > 0) & ~off).any()), gap
+
+    def _hole_runs(self, r, shift, stale, first_pos):
+        """int [m]: the run of holes each entry of a sorted batch closes
+        (a sample: the cells it skipped and the holes before them) or
+        lengthens (a marker: itself too), counted back to its row's last
+        sample — in the batch, or before it (``tail_holes``)."""
+        tail = self.tail_holes[r]
+        if stale is None and len(first_pos) == len(r):
+            return tail + shift             # one sample a row
+        m = len(r)
+        stale = np.zeros(m, bool) if stale is None else stale
+        h = shift + stale                   # holes an entry adds
+        upto = np.cumsum(h)
+        at = np.arange(m)
+        # the row's last sample before the entry, where the batch has one
+        prev = np.maximum.accumulate(np.where(stale, -1, at))
+        prev = np.concatenate([[-1], prev[:-1]])
+        first = np.repeat(first_pos,
+                          np.diff(np.concatenate([first_pos, [m]])))
+        since = np.where(prev >= first, upto[prev],
+                         (upto - h)[first] - tail)
+        return upto - since
+
+    def _track_stamps(self, r, t, cols, uniq, first_pos, stale=None,
+                      gap=None):
+        """Hold each append batch, laid into the columns ``cols``, against
+        the rows' lines. In the grid form (every stamp ON its line, every
+        line's phase the shard's, no cell skipped, no marker) nothing
         changes hands and None is returned: the s64 block takes the stamps.
-        The first sample off that turns a scalar store to its line form,
+        The first batch off that turns a scalar store to its line form,
         once (a layout store: ``grid_ok`` off for the shard, as ever); from
-        then on the batch's residuals (int8 [m]) are returned for the
-        residual block, and a sample that does not fit demotes its row."""
+        then on the batch's residuals (int8 [m], ``RES_HOLE`` for a
+        staleness marker, ``stale``) are returned for the residual block,
+        and a sample that does not fit its column demotes its row, as does
+        a run of holes past the bound (``gap``, from ``_cells``)."""
         self.demoted_last_append = 0
         if self.res is None and not self.grid_ok:
             return None
@@ -1046,35 +1319,46 @@ class SeriesStore:
                 self.grid_ok = False
                 return None
             self.grid_interval = iv
-            # starts recorded before the interval was known
+            self._phases_due = True
+        if self._phases_due:            # starts recorded before it was known
             phases = np.union1d(uniq, np.flatnonzero(self.n_host > 0))
+            self._phases_due = False
         off = t - (self.line0[r] + cols.astype(np.int64) * iv)
         if self.res is None:
-            if (not off.any() and not ((self.line0[phases] - self.grid_base)
-                                       % iv).any()):
+            if (not off.any() and stale is None
+                    and (cols == self._next_cols(r, cols)).all()
+                    and not ((self.line0[phases] - self.grid_base)
+                             % iv).any()):
                 return None
             if self.nbuckets or self.layout is not None:
                 self.grid_ok = False
                 return None
             self._to_line()
-        shift = (off + iv // 2) // iv          # cells past the row's next one
+        shift = (off + iv // 2) // iv          # cells off the given column
         fits = (shift == 0) & (np.abs(off) <= RES_MAX)
+        if gap is not None:
+            fits &= ~gap
         bad = ~fits & ~self.off_line[r]
         if bad.any():
             rows, first = np.unique(r[bad], return_index=True)
             sh = shift[bad][first]
             near = np.abs((off - shift * iv)[bad][first]) <= RES_MAX
-            # on the line in another cell: a gap; in its own cell and too
-            # far from the line for the width: the residual; neither: the
+            # on the line, in another cell or in its own: a gap (a run of
+            # holes past what a line keeps); in its own cell and too far
+            # from the line for the width: the residual; neither: the
             # row's interval is not the shard's any more
             reason = np.where(near, "gap", np.where(sh == 0, "residual",
                                                     "interval"))
             self._demote(rows, reason)
         out = self.off_line[r]
+        hole = np.zeros(len(r), bool) if stale is None else stale
         if out.any():
-            self._pool_ts[self._pool_slot[r[out]], cols[out]] = t[out]
+            # a marker keeps its stamp in the pool, past TS_PAD
+            self._pool_ts[self._pool_slot[r[out]], cols[out]] = \
+                t[out] + np.where(hole[out], TS_PAD, 0)
             self._pool_dev = None
-        return np.where(out, 0, off).astype(RES_DTYPE)
+        return np.where(hole, RES_HOLE, np.where(out, 0, off)).astype(
+            RES_DTYPE)
 
     def _to_line(self) -> None:
         """Grid form -> line form, once: every stamp so far is ON its row's
@@ -1110,7 +1394,8 @@ class SeriesStore:
         past = np.asarray(jnp.take(self.res, jnp.asarray(rows), axis=0),
                           np.int64)
         k = np.arange(self.C, dtype=np.int64)[None, :]
-        stamps = self.line0[rows, None] + k * self.grid_interval + past
+        stamps = (self.line0[rows, None] + k * self.grid_interval
+                  + np.where(past == RES_HOLE, TS_PAD, past))
         self._pool_ts[self._pool_slot[rows]] = np.where(k < n[:, None],
                                                         stamps, TS_PAD)
         self.off_line[rows] = True
@@ -1217,7 +1502,8 @@ class SeriesStore:
                                       np.flatnonzero(off).astype(np.int32)))
         base, iv, start, off, minority = self._cohorts[1]
         # the block as it is NOW: every flush donates and replaces it
-        return LineInfo(base, iv, start, self.res, off, minority)
+        return LineInfo(base, iv, start, self.res, off, minority,
+                        self.hole_cells > 0)
 
     def compact(self, cutoff_ts: int) -> None:
         """Evict samples older than ``cutoff_ts`` (amortized; ref: block reclaim
@@ -1231,7 +1517,7 @@ class SeriesStore:
                 self.ts, self.val, self.extra, self.n, jnp.int64(cutoff_ts))
         else:
             # line form: the shift runs over a transient derivation
-            new_ts, self.val, self.n = _compact(
+            new_ts, self.val, self.n = (_compact_line if line else _compact)(
                 self._line_ts() if line else self.ts_block(), self.val, self.n,
                 jnp.int64(cutoff_ts))
             if not line:
@@ -1257,8 +1543,15 @@ class SeriesStore:
         self.first_ts = np.where(live, np.array(new_ts[:, 0]), -1)
         line = _derive_ts(jnp.asarray(self.line0), self.n,
                           jnp.int64(self.grid_interval), self.C)
-        fit = jnp.asarray(live & ~self.off_line)[:, None] & (new_ts != TS_PAD)
-        self.res = jnp.where(fit, new_ts - line, 0).astype(RES_DTYPE)
+        fit = jnp.asarray(live & ~self.off_line)[:, None] & (new_ts < TS_PAD)
+        self.res = jnp.where(fit, new_ts - line, jnp.where(
+            new_ts > TS_PAD, RES_HOLE, 0)).astype(RES_DTYPE)
+        real = new_ts < TS_PAD
+        self.holes_host = self.n_host - np.asarray(real.sum(axis=1), np.int32)
+        self.hole_cells = int(self.holes_host.sum())
+        k = jnp.arange(1, self.C + 1, dtype=jnp.int32)[None, :]
+        self.tail_holes = self.n_host - np.asarray(
+            jnp.where(real, k, 0).max(axis=1), np.int32)
         out = np.flatnonzero(self._pool_slot >= 0)
         if len(out):
             self._pool_ts[self._pool_slot[out]] = np.asarray(
@@ -1289,7 +1582,10 @@ class SeriesStore:
             held = self._pool_slot[part_ids]
             self._pool_free.extend(held[held >= 0].tolist())
             self._pool_slot[part_ids] = -1
+        self.hole_cells -= int(self.holes_host[part_ids].sum())
         self.n_host[part_ids] = 0
+        self.holes_host[part_ids] = 0
+        self.tail_holes[part_ids] = 0
         self.first_ts[part_ids] = -1
         self.line0[part_ids] = -1
         self.last_ts[part_ids] = -(1 << 62)
@@ -1320,18 +1616,40 @@ class SeriesStore:
             return self.extra[column]
         raise KeyError(f"unknown value column {column!r}")
 
+    @property
+    def samples_host(self) -> np.ndarray:
+        """int32 [S]: the samples each row holds — its used cells less its
+        holes; what ``closed_arrays`` / ``snapshot_arrays`` rows are cut
+        to."""
+        return self.n_host - self.holes_host if self.hole_cells \
+            else self.n_host
+
+    def closed_arrays(self, column: str | None = None):
+        """(ts, val, n) blocks with every row's samples a sorted prefix,
+        for readers that know no holes (the mesh's general programs, the
+        per-series loops): the store's own blocks while no cell is a hole,
+        else one partition per state of the store (``close_holes``)."""
+        v = self.column_array(column)
+        if isinstance(v, _Deferred):
+            v = v.materialize()
+        if not self.hole_cells:
+            return self.ts_block(), v, self.n
+        kept = self._closed
+        if kept is None or kept[0] != column or kept[1] is not self.res:
+            kept = self._closed = (column, self.res,
+                                   close_holes(self.ts_block(), v, self.n))
+        return kept[2]
+
     def snapshot_arrays(self, column: str | None = None):
         """(ts, val) blocks materialized ONCE for per-series slicing loops —
         callers iterating many pids must use this instead of per-pid
         series_snapshot (which would re-decode a compressed-resident store's
-        full block per series)."""
-        v = self.column_array(column)
-        if isinstance(v, _Deferred):
-            v = v.materialize()
-        return self.ts_block(), v
+        full block per series). Row ``p`` holds ``samples_host[p]``
+        samples."""
+        return self.closed_arrays(column)[:2]
 
     def series_snapshot(self, part_id: int, column: str | None = None):
         """Host copy of one series (tests/debug; loops use snapshot_arrays)."""
-        cnt = int(self.n_host[part_id])
+        cnt = int(self.samples_host[part_id])
         t, v = self.snapshot_arrays(column)
         return (np.asarray(t[part_id, :cnt]), np.asarray(v[part_id, :cnt]))

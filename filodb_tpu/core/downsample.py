@@ -128,8 +128,9 @@ class InlineDownsampler:
         # one block materialization for the whole scan (a compressed-resident
         # store must not decode its full block once per pid)
         tsrc, vsrc = st.snapshot_arrays()
+        samples = st.samples_host
         for pid in range(st.S):
-            cnt = int(st.n_host[pid])
+            cnt = int(samples[pid])
             if cnt == 0:
                 continue
             t = np.asarray(tsrc[pid, :cnt])

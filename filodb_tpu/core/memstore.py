@@ -933,6 +933,7 @@ class TimeSeriesShard:
             written = self._flush_staged_locked() if staged else 0
             if staged:
                 tags["demoted"] = self.store.demoted_last_append
+                tags["holes"] = self.store.holes_last_append
         residency = self.config.residency_mode()
         if not staged:
             # nothing new — but a purge/compact since the last flush may have
@@ -1558,6 +1559,8 @@ class TimeSeriesShard:
             cnt = int(self.store.n_host[p])
             hot_t = np.asarray(ts_host[i, :cnt])
             hot_v = np.asarray(val_host[i, :cnt])
+            if self.store.hole_cells:       # a hole is no sample
+                hot_t, hot_v = hot_t[hot_t < TS_PAD], hot_v[hot_t < TS_PAD]
             boundary = hot_t[0] if len(hot_t) else (1 << 62)
             if cold_ts[p]:
                 ct = np.concatenate(cold_ts[p])

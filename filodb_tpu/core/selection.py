@@ -89,7 +89,7 @@ class ShardSelection:
     the shard does not keep (it then lives as long as its query)."""
 
     __slots__ = ("shard", "pids", "is_all", "stamp", "_release", "_epochs",
-                 "_groupings")
+                 "_groupings", "_cells")
 
     def __init__(self, shard, pids: np.ndarray, stamp=None):
         self.shard = shard
@@ -100,6 +100,19 @@ class ShardSelection:
         self._release = shard._release_epoch
         self._epochs = None
         self._groupings: OrderedDict = OrderedDict()
+        self._cells = None      # (store state, hole cells, used cells)
+
+    def cells(self, store) -> tuple[int, int]:
+        """(hole cells, used cells) of the selected rows, from the counts
+        the store keeps on the host a row: one pass per state of the store
+        (a flush moves both), not one per query."""
+        state = store.mutation_epoch()
+        kept = self._cells
+        if kept is None or kept[0] != state:
+            rows = slice(None) if self.is_all else self.pids
+            kept = self._cells = (state, int(store.holes_host[rows].sum()),
+                                  int(store.n_host[rows].sum()))
+        return kept[1], kept[2]
 
     def snapshot(self) -> None:
         """Capture the slot epochs of the selected series (once; a kept

@@ -238,7 +238,9 @@ class FiloHttpServer:
                                      FILODB_SHARD_LOCK_HOLD_SECONDS,
                                      FILODB_SHARD_LOCK_LONG_HOLDS,
                                      FILODB_SHARD_LOCK_WAIT_SECONDS,
+                                     FILODB_INGEST_STALE_MARKERS,
                                      FILODB_SHARD_NUM_SERIES,
+                                     FILODB_STORE_HOLE_CELLS,
                                      FILODB_STORE_ROWS_DEMOTED,
                                      FILODB_STORE_ROWS_OFF_LINE,
                                      FILODB_STORE_STAMP_FORM, registry)
@@ -264,6 +266,10 @@ class FiloHttpServer:
                         c = registry.counter(FILODB_STORE_ROWS_DEMOTED,
                                              {**shard, "reason": why})
                         c.increment(rows - c.value)
+                    registry.gauge(FILODB_STORE_HOLE_CELLS, shard).update(
+                        float(st.hole_cells))
+                    c = registry.counter(FILODB_INGEST_STALE_MARKERS, shard)
+                    c.increment(st.stats.stale_markers - c.value)
                 if hasattr(s.lock, "contentions"):   # TimedRLock diagnostics
                     registry.gauge(FILODB_SHARD_LOCK_CONTENTIONS, tags) \
                         .update(float(s.lock.contentions))

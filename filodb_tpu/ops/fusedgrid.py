@@ -284,6 +284,195 @@ def _line_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
                         sampled, cnt, cnt_f)
 
 
+def _hole_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
+                  v, n, band, ohe, lo, hi, rel, roll, start, res, eb):
+    """:func:`_line_contrib` on a line store that has HOLES: a cell of a
+    row's line may hold no sample (core/chunkstore.py, the text at
+    ``RES_DTYPE``: its residual reads ``RES_HOLE``), in runs of up to
+    ``HOLE_RUN_MAX``. A hole is no sample: the count is a sum of validity,
+    an increment runs from a sample to the NEXT sample, and a window's
+    first and last samples are the first and last that exist in it.
+
+    Operands as :func:`_line_contrib` has them, but for the rate family's
+    ``band``: the cells ``[lo, hi - 1]`` (an increment is laid in its
+    EARLIER sample's cell, so the band sums the pairs that start in the
+    sure range; the one that leaves it over a hole at ``hi`` is taken out
+    again, by picks). The samples a window can hold beside the sure
+    range's are, as there, the cells lo-2, lo-1, hi+1 and hi+2, each
+    decided by its own stamp and now by whether it holds a sample; the
+    first sample at or after ``lo`` and the last at or before ``hi`` are
+    read from FILLED planes — a hole takes the next (the previous)
+    sample's value, residual and distance, two shifts by one and two cells
+    reaching over a run of three — picked at ``lo`` and ``hi``. A product
+    still has a 0/1 operand: values in three bf16 passes, residuals,
+    distances and validity in one."""
+    from ..core.chunkstore import HOLE_RUN_MAX, RES_HOLE
+    assert HOLE_RUN_MAX == 3, "two shifts reach over a run of three"
+    f32, i32, bf16 = jnp.float32, jnp.int32, jnp.bfloat16
+    Sb, Ca = v.shape
+    Tp = lo.shape[1]
+    window = fn in FUSED_WINDOW_FNS
+    per = EDGE_SLOTS * Tp // ohe.shape[1]         # edge slots a block
+    packed = per == 2
+    lcol = jax.lax.broadcasted_iota(i32, (Sb, Ca), 1)
+    col = lcol + c0
+    ri = res.astype(i32)
+    valid = (col < n) & (ri != RES_HOLE)
+    v = jnp.where(valid, v, 0.0)
+    okf = valid.astype(f32).astype(bf16)
+
+    def dot1(x, w):           # one pass: small integers against 0/1
+        return jnp.dot(x, w, precision=jax.lax.Precision.DEFAULT,
+                       preferred_element_type=f32)
+
+    def pick(x, j):           # slot j's plane, step t on lane t
+        blk = x[:, j // per * Tp:(j // per + 1) * Tp]
+        return roll(blk, Tp // 2) if j % per else blk
+
+    # the four edge cells' residuals, RES_HOLE where the cell has no sample
+    rh = jnp.where(valid, ri, RES_HOLE).astype(f32).astype(bf16)
+    rp = dot1(rh, ohe[:, :4 // per * Tp])
+    a = start.astype(f32)                                     # [Sb, 1]
+    ebf = eb.astype(f32)
+
+    def holds(cell, j):       # the cell exists, is the row's, has a sample
+        return (cell >= 0) & (cell < n) & (pick(rp, j) != RES_HOLE)
+
+    m_a2 = holds(lo - 2, 0) & (a + pick(rp, 0) >= ebf[0:1])
+    m_a1 = holds(lo - 1, 1) & (a + pick(rp, 1) >= ebf[1:2])
+    m_b1 = holds(hi + 1, 2) & (a + pick(rp, 2) <= ebf[2:3])
+    m_b2 = holds(hi + 2, 3) & (a + pick(rp, 3) <= ebf[3:4])
+    edges = (m_a2.astype(f32) + m_a1.astype(f32) + m_b1.astype(f32)
+             + m_b2.astype(f32))
+
+    if window:
+        # the closed band counts the sure range's samples
+        cnt_f = dot1(okf, band) + edges
+        ok = cnt_f >= 1.0
+        if fn == "count_over_time":
+            return jnp.where(ok, cnt_f, 0.0), ok.astype(f32)
+        w = ohe if packed else ohe[:, :EDGE_SLOTS_WINDOW * Tp]
+        vp = dot_exact01(v, w)
+        s = pick(vp, BAND_SLOT) if packed else dot_exact01(v, band)
+        for j, m in enumerate((m_a2, m_a1, m_b1, m_b2)):
+            s = s + jnp.where(m, pick(vp, j), 0.0)
+        if fn == "avg_over_time":
+            s = s / cnt_f
+        return jnp.where(ok, s, 0.0), ok.astype(f32)
+
+    # filled planes. ``meta``: a sample's residual + 128 (1..255), 0 for
+    # none, and 256 a cell of distance once filled from a neighbour
+    step1, step2 = 256, 512
+    meta = jnp.where(valid, ri + 128, 0)
+
+    def fill(m0, x0, back: bool):
+        """(meta, values) with every hole taking its next (``back``) or
+        previous sample's, up to three cells away; 0 where there is none.
+        The shift's wrapped columns take nothing."""
+        def shifted(x, k):
+            if back:
+                return roll(x, Ca - k), lcol < Ca - k
+            return roll(x, k), lcol >= k
+        out_m, out_x = m0, x0
+        for k, d in ((1, step1), (2, step2)):
+            sm, inside = shifted(out_m, k)
+            sx, _ = shifted(out_x, k)
+            take = (out_m == 0) & inside & (sm > 0)
+            out_x = jnp.where(take, sx, out_x)
+            out_m = jnp.where(take, sm + d, out_m)
+        return out_m, out_x
+
+    # the NEXT sample after each cell (strictly), then with the cell's own
+    nxt_m0, inside = roll(meta, Ca - 1), lcol < Ca - 1
+    nxt_m0 = jnp.where(inside, nxt_m0, 0)
+    nxt_m, nxt_v = fill(nxt_m0, jnp.where(inside, roll(v, Ca - 1), 0.0), True)
+    mb = jnp.where(valid, meta, jnp.where(nxt_m > 0, nxt_m + step1, 0))
+    vb = jnp.where(valid, v, nxt_v)
+    mf, vf = fill(meta, v, False)
+
+    is_counter = fn != "delta"
+
+    def step(x):              # one increment, counter-corrected like inc
+        return jnp.maximum(x, 0.0) if is_counter else x
+
+    # a pair's increment in its EARLIER sample's cell
+    g = jnp.where(valid & (nxt_m > 0), step(nxt_v - v), 0.0)
+    mid_pairs = dot_exact01(g, band)                          # [lo, hi - 1]
+    mid_cnt = dot1(okf, band)
+
+    def parts(m):     # (distance in cells, residual: RES_HOLE for none)
+        m = m.astype(f32)
+        k = jnp.floor(m * (1.0 / step1))
+        return k.astype(bf16), (m - k * step1 - 128.0).astype(bf16)
+
+    if packed:        # lo and hi share block 2: hi's half comes down
+        blk_lo = blk_hi = ohe[:, 2 * Tp:3 * Tp]
+        vbp = dot_exact01(vb, ohe)
+
+        def down(x):
+            return roll(x, Tp // 2)
+    else:
+        blk_lo, blk_hi = ohe[:, 4 * Tp:5 * Tp], ohe[:, 5 * Tp:]
+        vbp = dot_exact01(vb, ohe[:, :5 * Tp])
+
+        def down(x):
+            return x
+    (kb, rb), (kf, rf) = parts(mb), parts(mf)
+    kl, rl = dot1(kb, blk_lo), dot1(rb, blk_lo)
+    kh, rhi = down(dot1(kf, blk_hi)), down(dot1(rf, blk_hi))
+    v_hi = down(dot_exact01(vf, blk_hi))
+    v_a2, v_a1, v_b1, v_b2, v_lo = (pick(vbp, j) for j in range(5))
+
+    f_sure = jnp.maximum(lo, 0)                               # [1, Tp]
+    some = hi >= f_sure                 # the sure range holds a cell
+    # hi itself holds a sample: the band [lo, hi - 1] leaves it out
+    at_hi = some & (hi < n) & (rhi != RES_HOLE) & (kh == 0.0)
+    cnt_f = mid_cnt + at_hi.astype(f32) + edges
+    mid = mid_cnt + at_hi.astype(f32) >= 1.0
+    cnt = cnt_f.astype(i32)
+
+    # the row's own last sample (the tile's: a window's cells lie in it)
+    last = jnp.max(jnp.where(valid, col, -1), axis=1, keepdims=True)
+    r_end = jnp.sum(jnp.where(col == last, jnp.where(valid, ri, 0), 0),
+                    axis=1, keepdims=True)
+    reach = rhi != RES_HOLE             # a sample within three cells of hi
+    # the pair that leaves the sure range's last sample H: inside the band
+    # when hi is a hole (H < hi) and H has a next sample, and in the window
+    # only if that sample is (b1, else b2: vb at hi + 1 is its value)
+    over = mid & (kh > 0.0) & reach & (hi < last)
+    leave = step(v_b1 - v_hi)
+    nin = m_b1 | m_b2
+    v_next = jnp.where(m_b1, v_b1, v_b2)
+    pin = m_a1 | m_a2
+    v_prev = jnp.where(m_a1, v_a1, v_a2)
+    delta = (mid_pairs
+             - jnp.where(over, leave, 0.0)
+             + jnp.where(mid & nin, step(v_next - v_hi), 0.0)
+             + jnp.where(mid & pin, step(v_lo - v_prev), 0.0)
+             + jnp.where(m_a2 & m_a1, step(v_a1 - v_a2), 0.0)
+             + jnp.where(m_b1 & m_b2, step(v_b2 - v_b1), 0.0)
+             + jnp.where(~mid & pin & nin, step(v_next - v_prev), 0.0))
+    f_v = jnp.where(m_a2, v_a2, jnp.where(m_a1, v_a1, v_lo))
+
+    # stamps relative to the base, in integers until they are differences.
+    # A row that ended under the window: its own last sample's cell and
+    # residual, where the fill from hi does not reach it
+    c_f = jnp.where(m_a2, lo - 2, jnp.where(m_a1, lo - 1,
+                                            f_sure + kl.astype(i32)))
+    r_f = jnp.where(m_a2, pick(rp, 0), jnp.where(m_a1, pick(rp, 1), rl))
+    c_l = jnp.where(m_b2, hi + 2, jnp.where(m_b1, hi + 1, jnp.where(
+        reach, hi - kh.astype(i32), last)))
+    r_l = jnp.where(m_b2, pick(rp, 3), jnp.where(m_b1, pick(rp, 2), jnp.where(
+        reach, rhi, r_end.astype(f32))))
+    t_f = c_f * interval_ms + start + r_f.astype(i32)
+    t_l = c_l * interval_ms + start + r_l.astype(i32)
+    dur_start = (t_f - (rel - window_ms)).astype(f32) / 1000.0
+    dur_end = (rel - t_l).astype(f32) / 1000.0
+    sampled = (t_l - t_f).astype(f32) / 1000.0
+    return _extrapolate(fn, window_ms, delta, f_v, dur_start, dur_end,
+                        sampled, cnt, cnt_f)
+
+
 def _extrapolate(fn, window_ms, delta, f_v, dur_start, dur_end, sampled,
                  cnt, cnt_f):
     """Prometheus' extrapolatedRate from a window's delta, first value and
@@ -308,7 +497,8 @@ def _extrapolate(fn, window_ms, delta, f_v, dur_start, dur_end, sampled,
 
 
 def tile_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
-                 v, n, band, ohlo, lo, hi, rel, roll, line=None):
+                 v, n, band, ohlo, lo, hi, rel, roll, line=None,
+                 holes: bool = False):
     """Shared per-tile window math of the fused tier: decoded values
     ``v [Sb, Ca]`` -> ``(contrib [Sb, Tp]`` with absent cells zeroed,
     ``okf [Sb, Tp]`` presence as f32). ONE definition per tiling plan for
@@ -323,10 +513,12 @@ def tile_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     :func:`dot_exact01`'s: three MXU passes, exact, whatever the default
     matmul precision. ``line = (start, res, eb)`` is a line store's tile
     (see :func:`_line_contrib`; ``ohlo`` is then ``ohe``); None is the
-    grid, where column c IS cell c of every row."""
+    grid, where column c IS cell c of every row. ``holes``: the line store
+    has cells without a sample (:func:`_hole_contrib`)."""
     if line is not None:
-        return _line_contrib(fn, window_ms, interval_ms, c0, v, n, band,
-                             ohlo, lo, hi, rel, roll, *line)
+        contrib = _hole_contrib if holes else _line_contrib
+        return contrib(fn, window_ms, interval_ms, c0, v, n, band,
+                       ohlo, lo, hi, rel, roll, *line)
     f32 = jnp.float32
     Sb, Ca = v.shape
     lcol = jax.lax.broadcasted_iota(jnp.int32, (Sb, Ca), 1)
@@ -384,7 +576,7 @@ decode_narrow_tile = decodereg.decode_quant16
 
 def _kernel_body(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
                  Sb: int, Ca: int, Tp: int, G: int, residency: str, c0: int,
-                 line: int, *refs):
+                 line: int, holes: bool, *refs):
     """``Ca`` is the streamed column width and ``c0`` its global offset into
     the store: a sub-range query streams (and matmuls) only its active
     columns (see active_columns); full-range queries have c0=0, Ca=C.
@@ -414,7 +606,8 @@ def _kernel_body(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
     contrib, okf = tile_contrib(
         fn, window_ms, interval_ms, c0, v, n, band_ref[:], ohlo_ref[:],
         lo_ref[:], hi_ref[:], rel_ref[:],
-        roll=lambda x, k: pltpu.roll(x, jnp.int32(k), 1), line=tile)
+        roll=lambda x, k: pltpu.roll(x, jnp.int32(k), 1), line=tile,
+        holes=holes)
     accs = (sum_ref, cnt_ref, *maybe_sumsq)
 
     @pl.when(i == 0)
@@ -432,7 +625,7 @@ def _kernel_body(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
 def build_pallas(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
                  S: int, Sb: int, C: int, Tp: int, G: int, interpret: bool,
                  residency: str = "raw", c0: int = 0, Ck: int = 0,
-                 line: int = 0):
+                 line: int = 0, holes: bool = False):
     """The raw (traceable) fused-kernel pallas_call — also invoked inside
     ``shard_map`` by the mesh executor (parallel/distributed.py), where each
     shard runs this same map phase on its resident block and the partial
@@ -453,7 +646,8 @@ def build_pallas(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
     the kernel then takes each row's start packed above its count
     (:func:`pack_start`), the residual block beside the values, ``ohe`` in
     place of ``ohlo`` and the edge bounds ``eb`` last
-    (:func:`_line_contrib`)."""
+    (:func:`_line_contrib`). ``holes``: the line store has cells without a
+    sample; the same operands, read by :func:`_hole_contrib`."""
     var = decodereg.variant(residency)
     assert not var.full_columns or c0 == 0, (residency, c0)
     assert not line or residency == "raw", residency
@@ -462,7 +656,8 @@ def build_pallas(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
     out_shape = tuple(jax.ShapeDtypeStruct((G, Tp), jnp.float32)
                       for _ in range(n_out))
     body = functools.partial(_kernel_body, fn, needs_sumsq, window_ms,
-                             interval_ms, Sb, Ca, Tp, G, residency, c0, line)
+                             interval_ms, Sb, Ca, Tp, G, residency, c0, line,
+                             holes)
     acc_spec = pl.BlockSpec((G, Tp), lambda i: (0, 0), memory_space=pltpu.VMEM)
     const = functools.partial(pl.BlockSpec, index_map=lambda i: (0, 0),
                               memory_space=pltpu.VMEM)
@@ -495,6 +690,8 @@ def build_pallas(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
                  + 9 * Sb * Ca * 4 + 12 * Sb * Tp * 4)
     if line:        # residual tile and its bf16 copy, the picked planes
         footprint += 2 * Sb * Ca + Sb * Ca * 4 + 4 * Sb * We * 4
+    if holes:       # the filled planes and their pieces
+        footprint += 14 * Sb * Ca * 4
     return pl.pallas_call(
         body,
         grid=(S // Sb,),
@@ -541,7 +738,7 @@ def active_columns(C: int, lo: np.ndarray, hi: np.ndarray) -> tuple[int, int]:
 def build_xla_tiles(fn: str, needs_sumsq: bool, window_ms: int,
                     interval_ms: int, S: int, Sb: int, C: int, Tp: int,
                     G: int, residency: str = "raw", c0: int = 0, Ck: int = 0,
-                    line: int = 0):
+                    line: int = 0, holes: bool = False):
     """XLA-fused twin of :func:`build_pallas`, built from the SAME tiling
     plan: one ``lax.scan`` walks the identical [Sb, Ca] row tiles through
     the identical :func:`tile_contrib` math and accumulates the same [G, Tp]
@@ -570,7 +767,7 @@ def build_xla_tiles(fn: str, needs_sumsq: bool, window_ms: int,
             tile = (start_t, rest[R + 2], eb[0])
         contrib, okf = tile_contrib(fn, window_ms, interval_ms, c0,
                                     v, n_t, band, ohlo, lo, hi, rel, roll,
-                                    line=tile)
+                                    line=tile, holes=holes)
         parts = group_fold(g_t, G, contrib, okf, needs_sumsq)
         return tuple(c + p for c, p in zip(carry, parts)), None
 
@@ -600,7 +797,7 @@ def build_xla_tiles(fn: str, needs_sumsq: bool, window_ms: int,
 def _build_call(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
                 S: int, Sb: int, C: int, Tp: int, G: int,
                 residency: str = "raw", c0: int = 0, Ck: int = 0,
-                variant: str = "pallas", line: int = 0):
+                variant: str = "pallas", line: int = 0, holes: bool = False):
     """The compiled fused program via the explicit plan cache (query/
     plancache.py) — its key IS this signature: fn/op statics, the padded
     [S, C, Tp, G] shape buckets, the ``residency`` decode variant
@@ -608,18 +805,20 @@ def _build_call(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
     as :func:`kernel_tag` names it ("pallas" | "pallas-interpret" | "xla")
     — every (residency, backend) pair is a distinct program and caches as a
     distinct kernel variant. ``line`` as :func:`build_pallas` has it: Tp is
-    128 for 1..128 steps, so a packed line program is told apart here."""
+    128 for 1..128 steps, so a packed line program is told apart here; so
+    is the mode that reads around ``holes``."""
     from ..query.plancache import plan_cache
     R = decodereg.variant(residency).row_operands
 
     def build():
         if variant == "xla":
             call = build_xla_tiles(fn, needs_sumsq, window_ms, interval_ms,
-                                   S, Sb, C, Tp, G, residency, c0, Ck, line)
+                                   S, Sb, C, Tp, G, residency, c0, Ck, line,
+                                   holes)
         else:
             call = build_pallas(fn, needs_sumsq, window_ms, interval_ms,
                                 S, Sb, C, Tp, G, variant != "pallas",
-                                residency, c0, Ck, line)
+                                residency, c0, Ck, line, holes)
 
         # one dispatch per query: dtype casts and [S] -> [S, 1] reshapes live
         # inside the jit — every extra dispatch is a host round trip of its
@@ -650,6 +849,8 @@ def _build_call(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
            residency, c0, Ck, variant)
     if line:
         key += ("line",) if line == 1 else ("line", line)
+    if holes:
+        key += ("holes",)
     return plan_cache.program("fused-grid", key, build)
 
 
@@ -670,7 +871,8 @@ def pad_edges(lo: np.ndarray, hi: np.ndarray, rel: np.ndarray,
 
 def host_operands(C: int, Tp: int, out_ts: np.ndarray, window_ms: int,
                   base_ts: int, interval_ms: int, fn_kind: str = "rate",
-                  full_cols: bool = False, line: bool = False):
+                  full_cols: bool = False, line: bool = False,
+                  holes: bool = False):
     """Band/one-hot/edge operands as host arrays + active column range:
     (band, ohlo, lo[1,Tp], hi[1,Tp], rel[1,Tp], c0, Ck) — shared by the
     single-chip upload cache below and the mesh path (which replicates them
@@ -691,7 +893,10 @@ def host_operands(C: int, Tp: int, out_ts: np.ndarray, window_ms: int,
     so, the window fns' band then in slot 4), and ``eb [8, Tp]`` i32
     follows ``rel``: per step the least ``start + residual`` that puts cell
     lo-2 (row 0) or lo-1 (row 1) in the window and the most that puts hi+1
-    (row 2) or hi+2 (row 3) in it."""
+    (row 2) or hi+2 (row 3) in it. ``holes``: the same operands for
+    :func:`_hole_contrib`, but for the rate family's band, the cells ``[lo,
+    hi - 1]``, and the active columns, three cells a side (a filled plane
+    reaches over a run of holes)."""
     import ml_dtypes
     bf16 = ml_dtypes.bfloat16
     T = len(out_ts)
@@ -700,8 +905,11 @@ def host_operands(C: int, Tp: int, out_ts: np.ndarray, window_ms: int,
     rel = out_ts - base_ts
     lo_p, hi_p, rel_p = pad_edges(lo, hi, rel, window_ms, Tp)
     band = np.zeros((C, Tp), bf16)
-    band[:, :T] = gridfns.band_matrix(C, lo, hi, fn_kind == "rate",
-                                      np.float32)
+    if holes and fn_kind == "rate":
+        band[:, :T] = gridfns.band_matrix(C, lo, hi - 1, False, np.float32)
+    else:
+        band[:, :T] = gridfns.band_matrix(C, lo, hi, fn_kind == "rate",
+                                          np.float32)
     if not line:
         ohlo = np.zeros((C, Tp), bf16)
         ohlo[:, :T] = gridfns.onehot_matrix(C, np.maximum(lo, 0), np.float32)
@@ -728,7 +936,8 @@ def host_operands(C: int, Tp: int, out_ts: np.ndarray, window_ms: int,
     for j, cell in enumerate(cells[2:4], 2):      # start + res <= this
         eb[j, :T] = np.where(cell >= 0, rel - cell * interval_ms, -_NEVER)
     eb = np.clip(eb, -_NEVER, _NEVER).astype(np.int32)
-    c0, Ca = active_columns(C, lo - 2, hi + 2)
+    reach = 3 if holes else 2
+    c0, Ca = active_columns(C, lo - reach, hi + reach)
     if Ca < C:
         band = np.ascontiguousarray(band[c0:c0 + Ca])
         ohe = np.ascontiguousarray(ohe[c0:c0 + Ca])
@@ -738,13 +947,14 @@ def host_operands(C: int, Tp: int, out_ts: np.ndarray, window_ms: int,
 @functools.lru_cache(maxsize=32)
 def _device_operands(C: int, Tp: int, out_ts_key: bytes, window_ms: int,
                      base_ts: int, interval_ms: int, fn_kind: str = "rate",
-                     full_cols: bool = False, line: bool = False):
+                     full_cols: bool = False, line: bool = False,
+                     holes: bool = False):
     """Band/one-hot/edge operands on device, cached per query shape — the
     upload matters: repeated host->device transfers of the [C, Tp] bands per
     row-batch are megabytes per query that never change."""
     out_ts = np.frombuffer(out_ts_key, np.int64)
     *arrs, c0, Ck = host_operands(C, Tp, out_ts, window_ms, base_ts,
-                                  interval_ms, fn_kind, full_cols, line)
+                                  interval_ms, fn_kind, full_cols, line, holes)
     return tuple(jnp.asarray(a) for a in arrs) + (c0, Ck)
 
 
@@ -798,7 +1008,8 @@ class PaddedPartials:
 def fused_grid_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
                          out_ts: np.ndarray, window_ms: int,
                          base_ts: int, interval_ms: int, fetch: bool = True,
-                         narrow=None, variant: str = "pallas", line=None):
+                         narrow=None, variant: str = "pallas", line=None,
+                         holes: bool = False):
     """One-pass ``op(fn(metric[window]))`` partials over a grid-aligned block.
 
     val [S, C] f32 (S a multiple of 512 or a power of two), n [S] i32 valid
@@ -815,7 +1026,9 @@ def fused_grid_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
     ``val`` is a line store's block: device i32 [S] row starts relative to
     ``base_ts`` and the int8 [S, C] residual block (core/chunkstore.py
     ``line_info``); the caller checked :func:`line_fusable` and zeroed
-    ``n`` for the rows off their line.
+    ``n`` for the rows off their line. ``holes`` says that the line store
+    has HOLES (``line_info().holes``): the program that reads around them
+    runs, and no other store's.
     """
     assert fn in FUSED_FNS | FUSED_WINDOW_FNS and op in FUSED_OPS
     if narrow is not None:
@@ -830,24 +1043,27 @@ def fused_grid_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
     Sb = 512 if S % 512 == 0 else (S if S <= 512 else None)
     G = _roundup(max(num_groups, 8), 8)
     per = slots_per_block(T) if line is not None else 0
+    holes = line is not None and bool(holes)
 
     *ops, c0, Ck = _device_operands(
         C, Tp, np.ascontiguousarray(np.asarray(out_ts, np.int64)).tobytes(),
         int(window_ms), int(base_ts), int(interval_ms),
         "window" if fn in FUSED_WINDOW_FNS else "rate",
-        decodereg.variant(kind).full_columns, line is not None)
+        decodereg.variant(kind).full_columns, line is not None, holes)
 
     needs_sumsq = op in ("stddev", "stdvar")
     call = _build_call(fn, needs_sumsq, int(window_ms), int(interval_ms),
-                       S, Sb, C, Tp, G, kind, c0, Ck, kernel_tag(variant), per)
+                       S, Sb, C, Tp, G, kind, c0, Ck, kernel_tag(variant), per,
+                       holes)
     # the framework runs with x64 on (int64 timestamps); Mosaic rejects the
     # i64 scalars x64 tracing injects (grid index maps, roll shifts), and the
     # kernel itself is pure f32/i32 — so trace the call with x64 off.
     # The span's tags are what ties a device event to its query and gives
     # the bytes the kernel streams from inside (rows x cols from c0 on);
     # ``packed``: edge slots a block of a line program (1 | 2)
-    tags = {"stamps": "grid"} if line is None else {"stamps": "line",
-                                                    "packed": per}
+    # ``holes``: which mode of the line program ran (0 | 1)
+    tags = {"stamps": "grid"} if line is None else {
+        "stamps": "line", "packed": per, "holes": int(holes)}
     with span(SPAN_QUERY_KERNEL, phase="dispatch",
               kernel=kernel_tag(variant), rows=S, c0=c0, cols=Ck, steps=T,
               groups=num_groups, **tags), \

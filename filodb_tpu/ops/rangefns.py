@@ -87,8 +87,36 @@ def _linreg_sums(ctx):
     return cnt, slope, intercept
 
 
-def _periodic(fn, ts, val, n, out_ts, window_ms, arg0, arg1, w_cap, acc):
-    """Core dispatch; ``fn`` and ``w_cap`` are static."""
+def _open_holes(fn, ts, val, n):
+    """Rows of a line store that has HOLES (core/chunkstore.py, the text at
+    ``RES_DTYPE``: a used cell without a sample, its stamp past TS_PAD) ->
+    rows the kernels below can read. A hole is no sample, so a range
+    function reads the rows with the holes taken out (``close_holes``).
+    The instant selector reads them IN PLACE. A staleness marker is a
+    sample without a value at the marker's OWN stamp (its value cell holds
+    that stamp less the one in ``ts``): the newest row at or before a step
+    being a marker, the step has no sample. A cell the row skipped is
+    nothing: it repeats the cell before it, so the sample (or the marker)
+    held there is served on, as far as the lookback says."""
+    from ..core.chunkstore import HOLE_RUN_MAX, HOLE_SKIPPED, close_holes
+    if fn not in ("last_sample", "last_sample_age"):
+        return close_holes(ts, val, n)
+    hole = ts > W.TS_PAD
+    skipped = hole & (val == HOLE_SKIPPED)
+    marker = hole & ~skipped
+    ts = jnp.where(marker, ts - W.TS_PAD + val.astype(ts.dtype), ts)
+    val = jnp.where(marker, jnp.nan, val)
+    for _ in range(HOLE_RUN_MAX):       # a row never starts in a hole
+        ts = jnp.where(skipped, jnp.roll(ts, 1, axis=1), ts)
+        val = jnp.where(skipped, jnp.roll(val, 1, axis=1), val)
+    return ts, val, n
+
+
+def _periodic(fn, ts, val, n, out_ts, window_ms, arg0, arg1, w_cap, acc,
+              holes=False):
+    """Core dispatch; ``fn``, ``w_cap`` and ``holes`` are static."""
+    if holes:
+        ts, val, n = _open_holes(fn, ts, val, n)
     valid = W.valid_mask(ts, n)
     left, right = W.window_edges(ts, out_ts, window_ms)
     cnt_i = right - left
@@ -173,7 +201,7 @@ def _periodic(fn, ts, val, n, out_ts, window_ms, arg0, arg1, w_cap, acc):
                 # how long before the step the instant selector's sample
                 # was stamped (ms; PromQL timestamp() is the step less
                 # this): a difference of stamps, small, never a stamp
-                l_v = age.astype(acc)
+                l_v = jnp.where(jnp.isnan(l_v), NAN, age.astype(acc))
             return jnp.where((cnt_i >= 1) & (age <= arg0), l_v, NAN)
         return jnp.where(cnt_i >= 1, l_v, NAN)
 
@@ -240,7 +268,8 @@ def _periodic(fn, ts, val, n, out_ts, window_ms, arg0, arg1, w_cap, acc):
     raise ValueError(f"unknown range function {fn}")  # pragma: no cover
 
 
-def _kernel(fn: str, w_cap: int, acc_name: str, shape_key: tuple):
+def _kernel(fn: str, w_cap: int, acc_name: str, shape_key: tuple,
+            holes: bool = False):
     """The per-shape compiled program via the explicit plan cache (query/
     plancache.py): the key carries the padded row/step buckets the exec
     layer already stabilizes, so repeated dashboard shapes hit a cached
@@ -249,8 +278,10 @@ def _kernel(fn: str, w_cap: int, acc_name: str, shape_key: tuple):
     from ..query.plancache import plan_cache
     acc = jnp.dtype(acc_name)
     return plan_cache.program(
-        "periodic", (fn, w_cap, acc_name) + shape_key,
-        lambda: functools.partial(_periodic, fn, w_cap=w_cap, acc=acc))
+        "periodic",
+        (fn, w_cap, acc_name) + shape_key + (("holes",) if holes else ()),
+        lambda: functools.partial(_periodic, fn, w_cap=w_cap, acc=acc,
+                                  holes=holes))
 
 
 HIST_FNS = {"rate", "increase", "delta", "sum_over_time", "last_sample",
@@ -292,16 +323,17 @@ def periodic_samples_hist(ts, val, n, out_ts, window_ms, fn: str,
 
 def periodic_samples(ts, val, n, out_ts, window_ms, fn: str,
                      arg0: float = 0.0, arg1: float = 0.0, w_cap: int = 256,
-                     accum: str = "float64"):
+                     accum: str = "float64", holes: bool = False):
     """Evaluate range function ``fn`` for every series row at every output step.
 
     ts/val/n: store arrays (already gathered to the selected rows) — see windows.py.
     out_ts: int64 [T] output step timestamps. window_ms: range window (for
     ``last_sample`` pass the staleness lookback as both window and arg0).
-    Returns float64 [P, T] with NaN for undefined points.
+    Returns float64 [P, T] with NaN for undefined points. ``holes``: the
+    rows come from a line store with holes in place (:func:`_open_holes`).
     """
     S, C = val.shape
-    k = _kernel(fn, w_cap, accum, (S, C, len(out_ts), str(val.dtype)))
+    k = _kernel(fn, w_cap, accum, (S, C, len(out_ts), str(val.dtype)), holes)
     return k(ts, val, n, jnp.asarray(out_ts),
              jnp.int64(window_ms), jnp.float64(arg0),
              jnp.float64(arg1))

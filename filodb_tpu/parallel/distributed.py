@@ -148,16 +148,18 @@ class DistributedStore:
         shards contribute TRANSIENT decodes (ts_block/value_block run on the
         shard's own device, so placement is unchanged) — the general
         collectives read the same f32/i64 view either way; the fused route
-        streams the compressed state instead via :meth:`narrow_arrays`."""
+        streams the compressed state instead via :meth:`narrow_arrays`. A
+        line store's holes are taken out (``closed_arrays``): these
+        programs read every row's samples as a sorted prefix."""
         out = []
         for j in range(self.slots):
-            ss = self._slot(j)
+            closed = [s.store.closed_arrays() for s in self._slot(j)]
             out.append((
-                self._global([s.store.ts_block() for s in ss],
+                self._global([c[0] for c in closed],
                              (self.S, self.C), jnp.int64),
-                self._global([s.store.value_block() for s in ss],
+                self._global([c[1] for c in closed],
                              (self.S, self.C), None),
-                self._global([s.store.n for s in ss], (self.S,), jnp.int32)))
+                self._global([c[2] for c in closed], (self.S,), jnp.int32)))
         return out
 
     def value_arrays(self):

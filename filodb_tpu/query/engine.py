@@ -1654,7 +1654,7 @@ class QueryEngine:
                     # one block materialization for the whole selection — a
                     # compressed-resident store must not decode per series
                     tsrc, vsrc = shard.store.snapshot_arrays()
-                    nh = shard.store.n_host
+                    nh = shard.store.samples_host
                     rows = [(np.asarray(tsrc[int(p), :nh[int(p)]]),
                              np.asarray(vsrc[int(p), :nh[int(p)]]))
                             for p in pids]

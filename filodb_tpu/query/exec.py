@@ -109,6 +109,10 @@ class SeriesSelection:
     # start and the residual block beside ``val``; the rows off their line
     # are in ``grid_minority``. Wide selections only.
     line: object | None = None
+    # some used cell of the store holds no sample (a missed scrape: its
+    # stamp in ``ts`` lies past TS_PAD, core/chunkstore.py): the general
+    # kernels are told (ops/rangefns.py ``_open_holes``)
+    holes: bool = False
 
 
 @dataclass
@@ -210,7 +214,7 @@ class FusedWindowData:
             # fuse with: the general kernels, from the stamps themselves
             vals = rangefns.periodic_samples(
                 _dval(self.sel.ts), _dval(self.sel.val), self.sel.n,
-                out_eval, self.window, self.fn)
+                out_eval, self.window, self.fn, holes=self.sel.holes)
             return MatrixView(self.out_ts, vals[:, :T], self.sel.keys,
                               self.sel.rows)
         base_ts, interval_ms = self.sel.grid
@@ -242,7 +246,8 @@ def _correct_minority_cohort(data, vals, out_ts, window, fn, a0, a1,
                                               out_ts, window, fn, a0)
     else:
         corr = rangefns.periodic_samples(sub_ts, sub_val, sub_n,
-                                         out_ts, window, fn, a0, a1)
+                                         out_ts, window, fn, a0, a1,
+                                         holes=data.holes)
     return vals.at[jnp.asarray(rows)].set(corr[:M].astype(vals.dtype))
 
 
@@ -351,7 +356,7 @@ class PeriodicSamplesMapper(Transformer):
         else:
             vals = rangefns.periodic_samples(_dval(data.ts), _dval(data.val),
                                              data.n, out_eval, window, fn,
-                                             a0, a1)
+                                             a0, a1, holes=data.holes)
         if Tpad != T:
             vals = vals[:, :T]
         return MatrixView(out_ts, vals, data.keys, data.rows)
@@ -706,7 +711,8 @@ class AggregateMapReduce(Transformer):
             sel.val if narrow is not None else _dval(sel.val),
             n_eff, gids_dev, Gp,
             data.out_ts, data.window, base_ts, interval_ms, fetch=False,
-            narrow=narrow, line=line)
+            narrow=narrow, line=line,
+            holes=line is not None and sel.line.holes)
         ctx.stats.add("fused_kernels")
         ctx.kernels.add((narrow[0] if narrow is not None else "raw",
                          fusedresident.tag()))
@@ -715,7 +721,8 @@ class AggregateMapReduce(Transformer):
             sub_ts, sub_val, sub_n, P = _gather_rows_padded(sel.ts, sel.val,
                                                             sel.n, rows)
             corr = rangefns.periodic_samples(sub_ts, sub_val, sub_n,
-                                             data.out_ts, data.window, data.fn)
+                                             data.out_ts, data.window, data.fn,
+                                             holes=sel.holes)
             mgids = np.zeros(P, np.int32)
             mgids[:len(rows)] = gids[rows]
             mparts = _segment_partial(self.operator, corr, jnp.asarray(mgids), Gp)
@@ -1453,6 +1460,9 @@ class SelectRawPartitionsExec(ExecPlan):
                     minority_sel = mins
         tags["demoted"] = (int(minority_sel.sum())
                            if minority_sel is not None else 0)
+        holes = store.res is not None and store.hole_cells > 0
+        if store.res is not None:
+            tags["hole_cells"], tags["used_cells"] = picked.cells(store)
         if len(pids) <= GATHER_THRESHOLD and len(pids) < 0.5 * max(total, 1):
             # narrow selection: gather rows once, padded to a power of two
             ctx.stats.add("blocks_raw")
@@ -1463,7 +1473,7 @@ class SelectRawPartitionsExec(ExecPlan):
             g_min = (np.nonzero(minority_sel)[0].astype(np.int32)
                      if minority_sel is not None else None)
             return SeriesSelection(sel_ts, sel_val, sel_n, keys, sel_rows, grid, les,
-                                   g_min)
+                                   g_min, holes=holes)
         # wide selection: no gather — disable non-selected rows via n = 0
         # (store.S is the PHYSICAL padded row count; the full-selection test
         # is against the logical series count)
@@ -1506,7 +1516,7 @@ class SelectRawPartitionsExec(ExecPlan):
                                  or isinstance(val, _Deferred)):
             line = None
         return SeriesSelection(ts, val, n_eff, keys, pids, grid, les,
-                               g_min, narrow, hist_narrow, line)
+                               g_min, narrow, hist_narrow, line, holes)
 
 
 def _execute_children(children, ctx):
@@ -1963,7 +1973,7 @@ class SelectChunkInfosExec(ExecPlan):
             for p in pids:
                 p = int(p)
                 labels = dict(shard.index.labels_of(p))
-                n = int(st.n_host[p])
+                n = int(st.samples_host[p])
                 per_sample = 8 + (vcol_itemsize
                                   * max(st.nbuckets, 1))
                 labels.update({

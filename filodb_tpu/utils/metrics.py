@@ -91,6 +91,8 @@ FILODB_STORE_RESIDENCY_FALLBACK = "filodb_store_residency_fallback"
 FILODB_STORE_STAMP_FORM = "filodb_store_stamp_form"
 FILODB_STORE_ROWS_DEMOTED = "filodb_store_rows_demoted"
 FILODB_STORE_ROWS_OFF_LINE = "filodb_store_rows_off_line"
+FILODB_STORE_HOLE_CELLS = "filodb_store_hole_cells"
+FILODB_INGEST_STALE_MARKERS = "filodb_ingest_stale_markers"
 FILODB_RULES_EVALUATIONS = "filodb_rules_evaluations"
 FILODB_RULES_EVAL_FAILURES = "filodb_rules_eval_failures"
 FILODB_RULES_EVAL_LATENCY_MS = "filodb_rules_eval_latency_ms"
@@ -333,12 +335,23 @@ METRICS_SPEC: dict[str, tuple[str, str]] = {
     FILODB_STORE_ROWS_DEMOTED: (
         "counter", "Rows of a line-form store demoted from their line, "
                    "tagged reason=residual|gap|interval (a stamp too far "
-                   "from the line for the residual's width, a skipped "
-                   "cell, a row on no cell of the line); a demoted row is "
+                   "from the line for the residual's width, a run of "
+                   "holes, staleness markers and skipped cells together, "
+                   "past the bound a line keeps, a "
+                   "row on no cell of the line); a demoted row is "
                    "answered by the general kernels."),
     FILODB_STORE_ROWS_OFF_LINE: (
         "gauge", "Live rows of a shard's store that the line kernel skips "
                  "now: demoted rows and rows that start in another cell."),
+    FILODB_STORE_HOLE_CELLS: (
+        "gauge", "Cells of a shard's line-form store that hold no sample "
+                 "among the cells its rows use: missed scrapes, kept as "
+                 "holes (a staleness marker or a skipped cell); no "
+                 "function reads one."),
+    FILODB_INGEST_STALE_MARKERS: (
+        "counter", "Rows ingested that carried Prometheus's staleness "
+                   "marker (value.StaleNaN: the scrape failed), per shard; "
+                   "a scalar store writes each as a hole."),
     FILODB_RULES_EVALUATIONS: (
         "counter", "Rule evaluations completed, tagged group= and rule= "
                    "(one per rule per scheduler tick)."),

@@ -133,7 +133,10 @@ TRACE_SPEC: dict[str, str] = {
                        "on the mesh route (tags: shard, series, memo = hit "
                        "| miss | bypass of the shard's selection memo, "
                        "demoted = selected rows the fused kernel skips and "
-                       "the general kernels answer).",
+                       "the general kernels answer; on a line store "
+                       "hole_cells = the selected rows' cells without a "
+                       "sample and used_cells = all the cells they use, "
+                       "from the host's counts, kept with the selection).",
     SPAN_QUERY_GROUPIDS: "Group ids of the selected series for a "
                          "by/without aggregation: from the index's label "
                          "columns where the selection is still pids "
@@ -148,7 +151,9 @@ TRACE_SPEC: dict[str, str] = {
                        "tags: kernel, rows, c0, cols, steps, groups, stamps "
                        "= grid | line, how the store keeps time, and on "
                        "line packed = 1 | 2, the edge slots a 128-lane "
-                       "block of the kernel's one-hot operand; the "
+                       "block of the kernel's one-hot operand, and holes "
+                       "= 0 | 1, whether the mode that reads around cells "
+                       "without a sample ran; the "
                        "fused-hist route adds buckets and variant = "
                        "hist-raw | hist-int8 | hist-int16 | hist-untiled; "
                        "hist-raw adds packed = 1 where one weight narrower "
@@ -188,7 +193,8 @@ TRACE_SPEC: dict[str, str] = {
                        "idle flush opens none (tags: shard, rows, "
                        "lock_wait_ms, throttle_ms = the wait for the "
                        "device, demoted = rows this flush took off their "
-                       "line).",
+                       "line, holes = cells it left without a sample: "
+                       "staleness markers and skipped cells).",
     SPAN_QUERY_RETENTION: "Downsample-aware routing of one query: the "
                           "resolution decision and its routed/stitched "
                           "leg queries hang under it (tags: dataset, "

@@ -24,7 +24,7 @@ import numpy as np
 import pytest
 
 from filodb_tpu.core import chunkstore
-from filodb_tpu.core.chunkstore import RES_MAX, SeriesStore
+from filodb_tpu.core.chunkstore import HOLE_RUN_MAX, RES_MAX, SeriesStore
 from filodb_tpu.core.memstore import StoreConfig, TimeSeriesMemStore
 from filodb_tpu.core.record import RecordBuilder
 from filodb_tpu.core.schemas import GAUGE
@@ -469,10 +469,11 @@ REASONS = {"gap": 20, "residual": 21, "interval": 22}
 
 
 def demoting_stream():
-    """Scrape 50 on: row 20 skips a cell, row 21 is 300 ms late once, row
-    22 goes on at a 16 s interval."""
+    """Scrape 50 on: row 20 skips four cells (one more than a line keeps
+    as holes), row 21 is 300 ms late once, row 22 goes on at a 16 s
+    interval."""
     t, v = stream(seed=3, reset_row=None)
-    t[20, 50:] += IV
+    t[20, 50:] += (HOLE_RUN_MAX + 1) * IV
     t[21, 50] += 300
     t[22, 50:] += 6_000 * np.arange(1, K - 49)
     return t, v
@@ -612,7 +613,7 @@ def test_past_the_gate_the_general_path_answers():
     """More than a quarter of the selection off its line: no fused kernel,
     the general kernels over the derived stamps, still the reference."""
     t, v = stream(seed=5, reset_row=None)
-    t[::3, 30:] += IV                      # a third of the rows skip a cell
+    t[::3, 30:] += (HOLE_RUN_MAX + 1) * IV  # a third of the rows: a gap
     ms, shard, eng = mk_engine()
     ingest(shard, t, v, range(K))
     assert shard.store.demoted["gap"] == len(range(0, S, 3))

@@ -141,6 +141,41 @@ def test_line_kernel_compiles_for_v5e_with_no_block_sized_temp(one_chip, fn,
             <= grid.memory_analysis().temp_size_in_bytes + (8 << 20)), mem
 
 
+@pytest.mark.parametrize("fn,sumsq,Tp,G,per", [
+    ("rate", False, 128, 8, 2),          # packed: adhoc_prom_miss's cards
+    ("rate", False, 128, 64, 2),
+    ("sum_over_time", True, 128, 8, 2),
+    ("avg_over_time", False, 128, 8, 2),
+    ("count_over_time", False, 128, 8, 2),
+    ("rate", False, 128, 8, 1),          # one slot a block
+    ("sum_over_time", True, 128, 8, 1),
+    ("delta", True, 512, 64, 1),         # every cap at once
+])
+def test_hole_mode_of_the_line_kernel_compiles_for_v5e(one_chip, fn, sumsq,
+                                                       Tp, G, per):
+    """promdev_prom_miss_1m's kernel at 2^20 x 768 (fusedgrid._hole_contrib):
+    the same operands as the line kernel's, the filled planes live in VMEM
+    a tile at a time — no s64, and no temporary the line kernel has not."""
+    C = 768
+    call = fusedgrid.build_pallas(fn, sumsq, WINDOW, IV, S, 512, C, Tp, G,
+                                  False, "raw", 0, 0, per, True)
+    compiled = _compile(call, _line_args(one_chip, C, Tp, per))
+    assert "s64[" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 * S * 128 * 4 + (64 << 20), mem
+
+
+def test_hole_modes_xla_twin_compiles_for_v5e(one_chip):
+    C, Tp, G = 768, 128, 8
+    call = fusedgrid.build_xla_tiles("rate", False, WINDOW, IV, S, 512, C, Tp,
+                                     G, "raw", 0, 0, 2, True)
+    with jax.enable_x64(False):
+        compiled = jax.jit(call).lower(
+            *_line_args(one_chip, C, Tp, 2)).compile()
+    assert "s64[" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < S * C * 5
+
+
 @pytest.mark.parametrize("per", [1, 2])
 def test_line_kernels_xla_twin_compiles_for_v5e(one_chip, per):
     """The twin scans the same tiles through the same tile math; its temp

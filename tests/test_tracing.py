@@ -620,3 +620,53 @@ def test_gc_hook_records_full_collections_and_leaves_with_the_server(served):
     tracer.drain()
     gc.collect()
     assert not [s for s in tracer.snapshot() if s.name == SPAN_RUNTIME_GC]
+
+
+@pytest.mark.parametrize("missed", (0, 3))
+def test_missed_scrapes_show_in_the_flush_the_select_and_the_dispatch(
+        served, missed):
+    """PR 35's three tags, over HTTP: a flush says how many cells it left
+    without a sample, the select span how many of the selected rows' used
+    cells are holes, the dispatch span which mode of the line program ran.
+    A store without holes (``missed`` 0) runs the mode it ran before and
+    says so; a grid store's spans carry none of them."""
+    from filodb_tpu.core.chunkstore import STALE_NAN
+    from filodb_tpu.core.record import RecordBuilder
+    from filodb_tpu.core.schemas import GAUGE
+    from filodb_tpu.utils.tracing import SPAN_INGEST_FLUSH
+    srv, get = served
+    tracer.drain()                          # no other test's request
+    get()
+    grid = _trace_of_last_query()
+    (sel,) = [s for s in grid if s.name == SPAN_QUERY_SELECT]
+    (disp,) = [s for s in grid if s.name == SPAN_QUERY_KERNEL
+               and s.tags["phase"] == "dispatch"]
+    assert disp.tags["stamps"] == "grid" and "holes" not in disp.tags
+    assert "hole_cells" not in sel.tags and "used_cells" not in sel.tags
+    tracer.drain()
+    # scrapes 90..95, 7 ms late (the form turns); of scrape 92 ``missed``
+    # series carry a staleness marker instead of a sample
+    b = RecordBuilder(GAUGE)
+    for t in range(N_SAMPLES, N_SAMPLES + 6):
+        for i in range(N_SERIES):
+            stale = t == N_SAMPLES + 2 and i < missed
+            b.add({"_metric_": "m", "host": f"h{i}", "g": f"g{i % 4}"},
+                  BASE + t * 10_000 + (0 if stale else 7),
+                  STALE_NAN if stale else float(i + 3 * t))
+    srv.memstore.ingest("prometheus", 0, b.build())
+    srv.memstore.flush_all()
+    flushes = [s for s in tracer.drain() if s.name == SPAN_INGEST_FLUSH]
+    assert sum(s.tags["holes"] for s in flushes) == missed
+    assert sum(s.tags["rows"] for s in flushes) == 6 * N_SERIES
+    body = get(shift_ms=150_001)         # off the cached grid
+    assert body["stats"]["exec_path"].startswith("local-fused[")
+    line = _trace_of_last_query()
+    (sel,) = [s for s in line if s.name == SPAN_QUERY_SELECT]
+    (disp,) = [s for s in line if s.name == SPAN_QUERY_KERNEL
+               and s.tags["phase"] == "dispatch"]
+    assert (sel.tags["hole_cells"], sel.tags["used_cells"]) == (
+        missed, N_SERIES * (N_SAMPLES + 6))
+    assert (disp.tags["stamps"], disp.tags["holes"]) == (
+        "line", int(missed > 0))
+    store = srv.memstore.shards_of("prometheus")[0].store
+    assert store.hole_cells == missed == store.stats.stale_markers
