@@ -46,8 +46,10 @@ class DecodeVariant:
     ``pallas``/``xla`` map (block, *row_operands) -> decoded f32 values;
     the kernel body calls ``pallas`` on materialized VMEM refs, the scan
     twin calls ``xla`` on its per-tile slices. ``row_operands`` counts the
-    per-row f32 side arrays ([S] -> [Sb, 1] tiles) the decode consumes
-    beyond the block itself."""
+    per-row f32 side arrays the decode consumes beyond the block itself
+    (``[S]`` in the store; a ``[Sb, 1]`` column of the tile by the time
+    the decode sees them, turned inside the fused program:
+    ops/fusedgrid.py ``lane_major``)."""
 
     name: str
     pallas: Callable

@@ -99,9 +99,9 @@ def line_spread(interval_ms: int) -> tuple[int, int]:
     return -RES_MAX, interval_ms + 2 * RES_MAX
 
 
-# A row's start rides in the high bits of its sample count: every [S] -> [S,
-# 1] operand of the kernel is a lane-padded 512 MB relayout a query at 2^20
-# rows (PERF.md §5), and a count needs 11 bits (C <= MAX_CAPACITY = 1024)
+# A row's start rides in the high bits of its sample count: one per-row
+# operand (one [1, Sb] block a tile, one turn to a column in the kernel)
+# instead of two, and a count needs 11 bits (C <= MAX_CAPACITY = 1024)
 _COUNT_BITS = 11
 
 
@@ -125,15 +125,15 @@ def line_fusable(window_ms: int, interval_ms: int) -> bool:
             and interval_ms + 2 * RES_MAX < 1 << (31 - _COUNT_BITS))
 
 
-def dot_exact01(x, w, dims=None):
+def dot_exact01(x, w, left: bool = False):
     """``x [M, K] f32 @ w [K, N]`` for a ``w`` of -1, 0 and 1 held in bf16,
     exact to f32: ``x`` splits into three bf16 pieces (8 mantissa bits
     each, the rest taken off in f32 without rounding), each piece times
     such a weight is exact and the MXU accumulates in f32. HIGHEST would
     split BOTH sides and run six passes; the three that multiply the
     weight's (zero) low pieces add nothing. Integers below 2^24 come out
-    exact in any order. ``dims``: dot_general's dimension numbers of ``(w,
-    x)``, the weight on the LEFT — what :func:`group_fold` contracts."""
+    exact in any order. ``left``: the weight stands on the LEFT, ``w [M, K]
+    @ x [K, N]`` — what :func:`group_fold` multiplies."""
     f32, bf16 = jnp.float32, jnp.bfloat16
     hi = x.astype(bf16)
     r = x - hi.astype(f32)
@@ -143,31 +143,46 @@ def dot_exact01(x, w, dims=None):
     def dot(a):
         # DEFAULT, spelled out: one pass a piece (and the package-wide
         # "highest" would ask Mosaic for an fp32 contraction of bf16)
-        if dims is None:
-            return jnp.dot(a, w, precision=jax.lax.Precision.DEFAULT,
-                           preferred_element_type=f32)
-        return jax.lax.dot_general(w, a, dims,
-                                   precision=jax.lax.Precision.DEFAULT,
-                                   preferred_element_type=f32)
+        return jnp.dot(*((w, a) if left else (a, w)),
+                       precision=jax.lax.Precision.DEFAULT,
+                       preferred_element_type=f32)
     return dot(hi) + dot(mid) + dot(lo)
 
 
+def lane_major(x, Sb: int):
+    """A per-row operand ``[S]`` as the fused program takes it: ``[S / Sb,
+    1, Sb]``, tile i's rows along the lanes of block i. The reshape moves no
+    byte; an ``[S, 1]`` column is tiled (8, 128) on the chip, 512 MB written
+    and read back a query at 2^20 rows."""
+    return x.reshape(-1, 1, Sb)
+
+
+def _column(row):
+    """A tile's ``[1, Sb]`` block of a per-row operand down the sublanes,
+    ``[Sb, 1]``: ``Sb`` elements inside the tile, never ``S``. Spelled as
+    a reshape on both backends: of the forms Mosaic takes (this, ``row.T``,
+    a sublane broadcast to 8 or 128 rows and a 32-bit transpose) it is the
+    cheapest on the v5e by 0.9-2.6 ms a query at 2^20 rows (PERF.md §6,
+    PR 38)."""
+    return row.reshape(row.shape[1], 1)
+
+
 def group_fold(gid, G: int, contrib, okf, needs_sumsq: bool):
-    """A tile's per-group partial state on the MXU: the one-hot ``oh [Sb,
-    G]`` of ``gid [Sb, 1]`` (bf16) against ``contrib`` / ``okf`` / the
-    squares ``[Sb, Tp]``, contracting the rows -> ``(sum, count[, sumsq])``,
-    each ``[G, Tp]``. The f32 sides go through :func:`dot_exact01`; ``okf``
-    is 0/1 itself and exact in ONE pass."""
+    """A tile's per-group partial state on the MXU: the one-hot of ``gid
+    [1, Sb]`` built transposed, ``oh_t [G, Sb]`` (bf16; the row broadcast
+    down the sublanes), times ``contrib`` / ``okf`` / the squares ``[Sb,
+    Tp]`` -> ``(sum, count[, sumsq])``, each ``[G, Tp]``. The f32 sides go
+    through :func:`dot_exact01`; ``okf`` is 0/1 itself and exact in ONE
+    pass."""
     f32, bf16 = jnp.float32, jnp.bfloat16
-    gcol = jax.lax.broadcasted_iota(jnp.int32, (gid.shape[0], G), 1)
-    oh = (gcol == gid).astype(f32).astype(bf16)
-    dn = (((0,), (0,)), ((), ()))
-    out = (dot_exact01(contrib, oh, dn),
-           jax.lax.dot_general(oh, okf.astype(bf16), dn,
-                               precision=jax.lax.Precision.DEFAULT,
-                               preferred_element_type=f32))
+    grow = jax.lax.broadcasted_iota(jnp.int32, (G, gid.shape[1]), 0)
+    oh_t = (grow == gid).astype(f32).astype(bf16)
+    out = (dot_exact01(contrib, oh_t, left=True),
+           jnp.dot(oh_t, okf.astype(bf16),
+                   precision=jax.lax.Precision.DEFAULT,
+                   preferred_element_type=f32))
     if needs_sumsq:
-        out += (dot_exact01(contrib * contrib, oh, dn),)
+        out += (dot_exact01(contrib * contrib, oh_t, left=True),)
     return out
 
 
@@ -587,8 +602,10 @@ def _kernel_body(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
     val_ref = refs[0]
     rowrefs = refs[1:1 + R]
     rest = refs[1 + R:]
+    # the per-row operands arrive lane-major, [1, Sb] (lane_major): the
+    # tile math wants them down the sublanes, the fold takes gid as it is
     n_ref, gid_ref = rest[:2]
-    n, tile = n_ref[:], None                                  # [Sb, 1] i32
+    n, tile = _column(n_ref[:]), None                         # [Sb, 1] i32
     if line:        # res [Sb, Ca] int8 ... eb [8, Tp] i32; start rides in n
         (res_ref, band_ref, ohlo_ref, lo_ref, hi_ref, rel_ref,
          eb_ref, sum_ref, cnt_ref, *maybe_sumsq) = rest[2:]
@@ -600,7 +617,7 @@ def _kernel_body(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
     i = pl.program_id(0)
 
     # decode in VMEM: the registered pallas twin of the residency variant
-    v = var.pallas(val_ref[:], *(r[:] for r in rowrefs))      # [Sb, Ca]
+    v = var.pallas(val_ref[:], *(_column(r[:]) for r in rowrefs))  # [Sb, Ca]
     # i32 shift: x64 mode would lower an i64 operand, which
     # tpu.dynamic_rotate rejects
     contrib, okf = tile_contrib(
@@ -633,7 +650,9 @@ def build_pallas(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
     the identical map phase runs on every data node). ``residency`` names
     the decode variant (ops/decodereg.py): the value operand is that
     variant's narrow block plus its per-row operands (quant16: vmin/scale;
-    delta16/delta8: anchor), decoded to f32 in VMEM per tile.
+    delta16/delta8: anchor), decoded to f32 in VMEM per tile. Every per-row
+    operand — those, then ``n`` and ``gids`` — is lane-major, ``[S / Sb, 1,
+    Sb]`` (:func:`lane_major`), one ``[1, Sb]`` block a grid step.
 
     ``(c0, Ca)`` describe the active column range (see active_columns): when
     it covers less than the full store, the kernel's value block starts at
@@ -661,13 +680,14 @@ def build_pallas(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
     acc_spec = pl.BlockSpec((G, Tp), lambda i: (0, 0), memory_space=pltpu.VMEM)
     const = functools.partial(pl.BlockSpec, index_map=lambda i: (0, 0),
                               memory_space=pltpu.VMEM)
-    row = lambda shape: pl.BlockSpec(shape, lambda i: (i, 0),  # noqa: E731
-                                     memory_space=pltpu.VMEM)
+    # a per-row operand, [S / Sb, 1, Sb] (lane_major): tile i's [1, Sb]
+    row = pl.BlockSpec((None, 1, Sb), lambda i: (i, 0, 0),
+                       memory_space=pltpu.VMEM)
     kcol = c0 // Ca                       # active_columns guarantees c0 % Ca == 0
     in_specs = [pl.BlockSpec((Sb, Ca), lambda i: (i, kcol),
                              memory_space=pltpu.VMEM)]
-    in_specs += [row((Sb, 1))] * var.row_operands   # vmin/scale or anchor
-    in_specs += [row((Sb, 1)), row((Sb, 1))]
+    in_specs += [row] * var.row_operands            # vmin/scale or anchor
+    in_specs += [row, row]                          # n (with start), gids
     if line:
         in_specs += [in_specs[0]]                   # the residual tile
     We = EDGE_SLOTS // line * Tp if line else Tp    # ohe's (ohlo's) lanes
@@ -760,8 +780,9 @@ def build_xla_tiles(fn: str, needs_sumsq: bool, window_ms: int,
 
     def fold(carry, xs, band, ohlo, lo, hi, rel, *eb):
         blk_t, *rest = xs
-        v = var.xla(blk_t, *rest[:R])
-        n_t, g_t, tile = rest[R], rest[R + 1], None
+        rows_t = [_column(r) for r in rest[:R + 1]]
+        v = var.xla(blk_t, *rows_t[:R])
+        n_t, g_t, tile = rows_t[R], rest[R + 1], None
         if line:
             n_t, start_t = unpack_start(n_t)
             tile = (start_t, rest[R + 2], eb[0])
@@ -779,14 +800,12 @@ def build_xla_tiles(fn: str, needs_sumsq: bool, window_ms: int,
         return outs
 
     def call(blk, *rest):
-        # rest: R per-row decode operands, n2, g2, (a line store's
-        # residual block,) then the band/edge ops; active columns sliced
-        # like the pallas block index map
-        rows, n2, g2 = rest[:R], rest[R], rest[R + 1]
-        tiles = ((blk[:, c0:c0 + Ca].reshape(nt, Sb, Ca),)
-                 + tuple(r.reshape(nt, Sb, 1) for r in rows)
-                 + (n2.reshape(nt, Sb, 1), g2.reshape(nt, Sb, 1)))
+        # rest: R per-row decode operands, n and gids, each [nt, 1, Sb]
+        # (lane_major) and scanned as it is, (a line store's residual
+        # block,) then the band/edge ops; active columns sliced like the
+        # pallas block index map
         k = R + 2
+        tiles = (blk[:, c0:c0 + Ca].reshape(nt, Sb, Ca),) + rest[:k]
         if line:
             tiles += (rest[k][:, c0:c0 + Ca].reshape(nt, Sb, Ca),)
             k += 1
@@ -794,64 +813,67 @@ def build_xla_tiles(fn: str, needs_sumsq: bool, window_ms: int,
     return call
 
 
-def _build_call(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
-                S: int, Sb: int, C: int, Tp: int, G: int,
-                residency: str = "raw", c0: int = 0, Ck: int = 0,
-                variant: str = "pallas", line: int = 0, holes: bool = False):
-    """The compiled fused program via the explicit plan cache (query/
-    plancache.py) — its key IS this signature: fn/op statics, the padded
-    [S, C, Tp, G] shape buckets, the ``residency`` decode variant
-    ("raw" | "quant16" | "delta16" | "delta8"), and the backend ``variant``
-    as :func:`kernel_tag` names it ("pallas" | "pallas-interpret" | "xla")
-    — every (residency, backend) pair is a distinct program and caches as a
+def fused_program(fn: str, needs_sumsq: bool, window_ms: int,
+                  interval_ms: int, S: int, Sb: int, C: int, Tp: int, G: int,
+                  residency: str = "raw", c0: int = 0, Ck: int = 0,
+                  variant: str = "pallas", line: int = 0,
+                  holes: bool = False):
+    """The whole fused program of one query as a traceable function of the
+    store's own arrays — ``n``, ``gids``, a line store's ``start`` and the
+    narrow variants' per-row operands all ``[S]``: the casts, the pack of
+    start above count and the ``[S] -> [S / Sb, 1, Sb]`` reshapes
+    (:func:`lane_major`, no byte moves) live inside the one jit, so a query
+    is one dispatch and no relayout. ``residency`` .. ``holes`` as
+    :func:`build_pallas` and :func:`_build_call` have them."""
+    R = decodereg.variant(residency).row_operands
+    if variant == "xla":
+        call = build_xla_tiles(fn, needs_sumsq, window_ms, interval_ms,
+                               S, Sb, C, Tp, G, residency, c0, Ck, line,
+                               holes)
+    else:
+        call = build_pallas(fn, needs_sumsq, window_ms, interval_ms,
+                            S, Sb, C, Tp, G, variant != "pallas",
+                            residency, c0, Ck, line, holes)
+
+    def rows(n, gids):
+        return (lane_major(n.astype(jnp.int32), Sb),
+                lane_major(gids.astype(jnp.int32), Sb))
+
+    if residency != "raw":
+        def wrapped(blk, *rest):
+            return call(blk, *(lane_major(r, Sb) for r in rest[:R]),
+                        *rows(rest[R], rest[R + 1]), *rest[R + 2:])
+    elif line:
+        def wrapped(val, n, gids, start, res, *ops):
+            return call(val.astype(jnp.float32),
+                        *rows(pack_start(n, start), gids), res, *ops)
+    else:
+        def wrapped(val, n, gids, *ops):
+            return call(val.astype(jnp.float32), *rows(n, gids), *ops)
+    return wrapped
+
+
+def _build_call(*statics):
+    """The compiled fused program via the explicit plan cache
+    (query/plancache.py). ``statics`` are :func:`fused_program`'s fifteen
+    arguments in its order, and the key IS them: fn/op statics, the padded
+    [S, C, Tp, G] shape buckets, the ``residency`` decode variant ("raw" |
+    "quant16" | "delta16" | "delta8"), and the backend ``variant`` as
+    :func:`kernel_tag` names it ("pallas" | "pallas-interpret" | "xla") —
+    every (residency, backend) pair is a distinct program and caches as a
     distinct kernel variant. ``line`` as :func:`build_pallas` has it: Tp is
     128 for 1..128 steps, so a packed line program is told apart here; so
     is the mode that reads around ``holes``."""
     from ..query.plancache import plan_cache
-    R = decodereg.variant(residency).row_operands
-
-    def build():
-        if variant == "xla":
-            call = build_xla_tiles(fn, needs_sumsq, window_ms, interval_ms,
-                                   S, Sb, C, Tp, G, residency, c0, Ck, line,
-                                   holes)
-        else:
-            call = build_pallas(fn, needs_sumsq, window_ms, interval_ms,
-                                S, Sb, C, Tp, G, variant != "pallas",
-                                residency, c0, Ck, line, holes)
-
-        # one dispatch per query: dtype casts and [S] -> [S, 1] reshapes live
-        # inside the jit — every extra dispatch is a host round trip of its
-        # own beside the kernel's
-        if residency != "raw":
-            def wrapped(blk, *rest):
-                rows = tuple(r.reshape(S, 1) for r in rest[:R])
-                n, gids = rest[R], rest[R + 1]
-                return call(blk, *rows,
-                            n.astype(jnp.int32).reshape(S, 1),
-                            gids.astype(jnp.int32).reshape(S, 1),
-                            *rest[R + 2:])
-        elif line:
-            def wrapped(val, n, gids, start, res, *ops):
-                return call(val.astype(jnp.float32),
-                            pack_start(n, start).reshape(S, 1),
-                            gids.astype(jnp.int32).reshape(S, 1), res, *ops)
-        else:
-            def wrapped(val, n, gids, *ops):
-                return call(val.astype(jnp.float32),
-                            n.astype(jnp.int32).reshape(S, 1),
-                            gids.astype(jnp.int32).reshape(S, 1), *ops)
-        return wrapped
-
     # a grid program's key is what it was before there was a line form, and
     # an unpacked line program's what it was before there was a packed one
-    key = (fn, needs_sumsq, window_ms, interval_ms, S, Sb, C, Tp, G,
-           residency, c0, Ck, variant)
+    *key, line, holes = statics
     if line:
         key += ("line",) if line == 1 else ("line", line)
     if holes:
         key += ("holes",)
-    return plan_cache.program("fused-grid", key, build)
+    return plan_cache.program("fused-grid", tuple(key),
+                              lambda: fused_program(*statics))
 
 
 def pad_edges(lo: np.ndarray, hi: np.ndarray, rel: np.ndarray,

@@ -538,8 +538,8 @@ def _dist_fused_aggregate_impl(fn: str, op: str, num_groups: int, mesh: Mesh,
         slot_parts = []
         for val, n, gids in zip(slot_vals, slot_ns, slot_gids):
             o = call(val[0].astype(jnp.float32),
-                     n[0].astype(jnp.int32).reshape(S, 1),
-                     gids[0].astype(jnp.int32).reshape(S, 1),
+                     fusedgrid.lane_major(n[0].astype(jnp.int32), Sb),
+                     fusedgrid.lane_major(gids[0].astype(jnp.int32), Sb),
                      band, ohlo, lo, hi, rel)
             slot_parts.append(_fused_parts(op, o))
         return _stack_parts(slot_parts)
@@ -598,9 +598,9 @@ def _dist_fused_narrow_impl(fn: str, op: str, num_groups: int, mesh: Mesh,
         slot_parts = []
         for blk, rows, n, gids in zip(slot_blocks, slot_rows, slot_ns,
                                       slot_gids):
-            o = call(blk[0], *(r[0].reshape(S, 1) for r in rows),
-                     n[0].astype(jnp.int32).reshape(S, 1),
-                     gids[0].astype(jnp.int32).reshape(S, 1),
+            o = call(blk[0], *(fusedgrid.lane_major(r[0], Sb) for r in rows),
+                     fusedgrid.lane_major(n[0].astype(jnp.int32), Sb),
+                     fusedgrid.lane_major(gids[0].astype(jnp.int32), Sb),
                      band, ohlo, lo, hi, rel)
             slot_parts.append(_fused_parts(op, o))
         return _stack_parts(slot_parts)

@@ -446,7 +446,7 @@ def test_the_group_fold_through_three_passes(sumsq):
     contrib = _fold_values(rows, Tp)
     okf = (np.random.default_rng(1).random((rows, Tp)) < 0.7).astype(np.float32)
     gid = (np.arange(rows) * 5 % 6).astype(np.int32)  # groups 6, 7 empty
-    parts = fusedgrid.group_fold(jnp.asarray(gid)[:, None], G,
+    parts = fusedgrid.group_fold(jnp.asarray(gid)[None, :], G,
                                  jnp.asarray(contrib), jnp.asarray(okf), sumsq)
     assert len(parts) == 2 + sumsq
     oh = (gid[:, None] == np.arange(G)[None, :]).astype(np.float32)
@@ -455,6 +455,89 @@ def test_the_group_fold_through_three_passes(sumsq):
     if sumsq:
         _close_to_f64(np.asarray(parts[2]).T, (contrib * contrib).T, oh)
     assert not np.asarray(parts[0])[6:].any()
+
+
+def _fold_from_a_column(gid, G, contrib, okf, needs_sumsq):
+    """The group fold as it stood while the kernel took ``gid [Sb, 1]`` (PR
+    34): the one-hot ``[Sb, G]``, contracted over dimension 0 of both
+    sides. Kept here as what :func:`fusedgrid.group_fold` from a ``[1, Sb]``
+    row has to equal to the bit."""
+    import jax
+    import jax.numpy as jnp
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    oh = (jax.lax.broadcasted_iota(jnp.int32, (gid.shape[0], G), 1)
+          == gid).astype(f32).astype(bf16)
+    dn = (((0,), (0,)), ((), ()))
+
+    def dot(a):
+        return jax.lax.dot_general(oh, a, dn,
+                                   precision=jax.lax.Precision.DEFAULT,
+                                   preferred_element_type=f32)
+
+    def exact(x):
+        hi = x.astype(bf16)
+        r = x - hi.astype(f32)
+        mid = r.astype(bf16)
+        return dot(hi) + dot(mid) + dot((r - mid.astype(f32)).astype(bf16))
+
+    out = (exact(contrib), dot(okf.astype(bf16)))
+    return out + ((exact(contrib * contrib),) if needs_sumsq else ())
+
+
+@pytest.mark.parametrize("values", ("exact", "hard", "nan-inf"))
+@pytest.mark.parametrize("rows", (8, 512))
+@pytest.mark.parametrize("G", (8, 64))
+def test_the_fold_from_a_row_is_the_fold_from_a_column(G, rows, values):
+    """Sums, counts and squares of the two folds, bit for bit wherever the
+    ORDER of an f32 sum cannot show: counts always; sums and squares of
+    halves (every partial sum exact); NaN and inf in the same cells; a group
+    with no row (the last) all zeros in both. Hard values (magnitudes past
+    2^24, bf16's rounding edges) are the same exact products summed — on
+    the MXU in the same order; XLA:CPU's gemm takes a transposed left side
+    in another, so here they meet within the room of an f32 sum. That the
+    chip's bits are the parent's is for ``scripts/fused_bits.py`` to say,
+    run on both trees in one chip call (PERF.md §6, PR 38)."""
+    import jax.numpy as jnp
+    from filodb_tpu.ops import fusedgrid
+    Tp = 128
+    rng = np.random.default_rng(G + rows)
+    if values == "exact":
+        contrib = (rng.integers(-100, 101, (rows, Tp)) / 2).astype(np.float32)
+    else:
+        contrib = _fold_values(rows, Tp)
+    if values == "nan-inf":
+        contrib[3, 10], contrib[rows - 1, 90], contrib[5, 0] = \
+            np.nan, np.inf, -np.inf
+    okf = (rng.random((rows, Tp)) < 0.7).astype(np.float32)
+    gid = (np.arange(rows) * 5 % (G - 1)).astype(np.int32)
+    got = fusedgrid.group_fold(jnp.asarray(gid)[None, :], G,
+                               jnp.asarray(contrib), jnp.asarray(okf), True)
+    was = _fold_from_a_column(jnp.asarray(gid)[:, None], G,
+                              jnp.asarray(contrib), jnp.asarray(okf), True)
+    (s, c, q), (s0, c0, q0) = ([np.asarray(p) for p in ps]
+                               for ps in (got, was))
+    assert s.shape == c.shape == q.shape == (G, Tp)
+    np.testing.assert_array_equal(c, c0)
+    assert not c[G - 1].any() and np.isfinite(c).all()
+    bad = np.zeros((G, Tp), bool)
+    if values == "nan-inf":
+        bad[:, [0, 10, 90]] = True
+    oh = (gid[:, None] == np.arange(G)[None, :]).astype(np.float64)
+    with np.errstate(invalid="ignore", over="ignore"):
+        sq = contrib * contrib          # an f32 square, as the fold's
+    for a, b, x in ((s, s0, contrib), (q, q0, sq)):
+        np.testing.assert_array_equal(np.isfinite(a), ~bad)
+        np.testing.assert_array_equal(np.isfinite(b), ~bad)
+        if values == "exact" or rows == 8:
+            np.testing.assert_array_equal(a.view(np.uint32),
+                                          b.view(np.uint32))
+        else:
+            room = 2.0 ** -21 * (oh.T @ np.abs(np.where(np.isfinite(x), x, 0)
+                                               ).astype(np.float64))
+            assert (np.abs(a.astype(np.float64) - b)[~bad]
+                    <= room[~bad]).all()
+        if values != "nan-inf":
+            assert not a[G - 1].any()
 
 
 def test_a_nan_and_an_inf_poison_the_cells_they_poisoned_and_no_others():
@@ -490,7 +573,7 @@ def test_a_nan_and_an_inf_poison_the_cells_they_poisoned_and_no_others():
     okf = np.ones((rows, Tp), np.float32)
     gid = (np.arange(rows) % G).astype(np.int32)
     s, c, q = (np.asarray(p) for p in fusedgrid.group_fold(
-        jnp.asarray(gid)[:, None], G, jnp.asarray(contrib), jnp.asarray(okf),
+        jnp.asarray(gid)[None, :], G, jnp.asarray(contrib), jnp.asarray(okf),
         True))
     bad = np.zeros((G, Tp), bool)
     bad[:, [10, 90]] = True

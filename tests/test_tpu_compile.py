@@ -6,7 +6,10 @@ mode — what every other test runs — cannot show what Mosaic refuses: this fi
 found ``cumsum`` unimplemented in the Pallas TPU lowering (delta8/delta16
 decode, the hist kernel), the hist kernel's [Sb, C, 64] tile transposing the
 whole store into a 2x lane-padded copy per query, and a rank-1 SMEM block that
-must match XLA's 1024-wide tiling. Each case compiles one kernel at the shapes
+must match XLA's 1024-wide tiling; and it holds what a whole program may keep
+beside its kernel (the scalar program turned two [S] operands to lane-padded
+[S, 1] columns a query, 1 GB of temporaries, until its kernel took them
+lane-major). Each case compiles one kernel or program at the shapes
 ``chip_smoke.py`` serves (2^20 series, capacity 768/1024, 8..64 groups) with
 ``interpret=False`` passed directly — code that asks ``jax.default_backend()``
 sees the CPU here — under ``enable_x64(False)`` like the call sites.
@@ -15,6 +18,8 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process may hold libtpu, every xdist worker imports every test file,
 and all of these tests live in this one file so one worker gets them all.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +31,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
 from filodb_tpu.ops import decodereg, fusedgrid, fusedresident
 from filodb_tpu.parallel import distributed
 
-S = 1 << 20
+S, SB = 1 << 20, 512
+ROWS = (S // SB, 1, SB)     # a per-row operand, lane-major (fusedgrid.lane_major)
 WINDOW, IV = 300_000, 10_000
 f32, i32, bf16 = jnp.float32, jnp.int32, jnp.bfloat16
 
@@ -67,8 +73,8 @@ def _scalar_args(sh, C, Tp, residency):
     var = decodereg.variant(residency)
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)  # noqa: E731
     return ([sds((S, C), var.block_dtype)]
-            + [sds((S, 1), f32)] * var.row_operands
-            + [sds((S, 1), i32), sds((S, 1), i32),
+            + [sds(ROWS, f32)] * var.row_operands
+            + [sds(ROWS, i32), sds(ROWS, i32),
                sds((C, Tp), bf16), sds((C, Tp), bf16),   # band, ohlo: 0/1
                sds((1, Tp), i32), sds((1, Tp), i32), sds((1, Tp), i32)])
 
@@ -95,7 +101,7 @@ def _line_args(sh, C, Tp, per=1):
     ``ohe`` for ``ohlo`` with ``per`` edge slots a block, the edge bounds
     last."""
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)  # noqa: E731
-    return [sds((S, C), f32), sds((S, 1), i32), sds((S, 1), i32),
+    return [sds((S, C), f32), sds(ROWS, i32), sds(ROWS, i32),
             sds((S, C), jnp.int8),
             sds((C, Tp), bf16), sds((C, fusedgrid.EDGE_SLOTS // per * Tp), bf16),
             sds((1, Tp), i32), sds((1, Tp), i32), sds((1, Tp), i32),
@@ -121,10 +127,10 @@ def test_line_kernel_compiles_for_v5e_with_no_block_sized_temp(one_chip, fn,
                                                                per):
     """promdev_prom_1m's kernel at 2^20 x 768: values and int8 residuals
     stream in row tiles straight from their blocks, no operand is s64, and
-    the only temporaries are the grid kernel's own: the lane-padded
-    relayouts of its two [S, 1] operands, 512 MB each (PERF.md §5; a row's
-    start rides in its count, so the line adds no third) — nothing that
-    grows with the columns."""
+    there is no temporary to speak of: the per-row operands (a row's start
+    packed above its count, the group ids) arrive lane-major, a [1, Sb]
+    block a tile, and turn to a column inside the tile (PERF.md §5) —
+    nothing that grows with the rows or the columns."""
     C = 768
     call = fusedgrid.build_pallas(fn, sumsq, WINDOW, IV, S, 512, C, Tp, G,
                                   False, "raw", 0, 0, per)
@@ -133,7 +139,7 @@ def test_line_kernel_compiles_for_v5e_with_no_block_sized_temp(one_chip, fn,
     compiled = _compile(call, args)
     assert "s64[" not in compiled.as_text()
     mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < 2 * S * 128 * 4 + (64 << 20), mem
+    assert mem.temp_size_in_bytes < (64 << 20), mem
     grid = _compile(fusedgrid.build_pallas(fn, sumsq, WINDOW, IV, S, 512, C,
                                            Tp, G, False, "raw", 0, 0),
                     _scalar_args(one_chip, C, Tp, "raw"))
@@ -162,7 +168,7 @@ def test_hole_mode_of_the_line_kernel_compiles_for_v5e(one_chip, fn, sumsq,
     compiled = _compile(call, _line_args(one_chip, C, Tp, per))
     assert "s64[" not in compiled.as_text()
     mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < 2 * S * 128 * 4 + (64 << 20), mem
+    assert mem.temp_size_in_bytes < (64 << 20), mem
 
 
 def test_hole_modes_xla_twin_compiles_for_v5e(one_chip):
@@ -189,6 +195,70 @@ def test_line_kernels_xla_twin_compiles_for_v5e(one_chip, per):
     assert "s64[" not in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < S * C * 5, mem
+
+
+def _store_args(sh, rows, C, Tp, residency="raw", per=0):
+    """What a QUERY hands the whole fused program
+    (fusedgrid.fused_program): the store's own arrays, every per-row
+    operand ``[S]`` — the casts, the pack and the reshapes are the
+    program's."""
+    var = decodereg.variant(residency)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)  # noqa: E731
+    args = ([sds((rows, C), var.block_dtype)]
+            + [sds((rows,), f32)] * var.row_operands
+            + [sds((rows,), i32), sds((rows,), i32)])
+    if per:
+        args += [sds((rows,), i32), sds((rows, C), jnp.int8)]
+    We = fusedgrid.EDGE_SLOTS // per * Tp if per else Tp
+    args += [sds((C, Tp), bf16), sds((C, We), bf16),
+             sds((1, Tp), i32), sds((1, Tp), i32), sds((1, Tp), i32)]
+    return args + ([sds((8, Tp), i32)] if per else [])
+
+
+def _no_column_and_no_temp(compiled, rows):
+    """No per-row operand became a column (an ``[S, 1]`` array is tiled (8,
+    128) on the chip: a 512 MB relayout a query at 2^20 rows, read back a
+    [Sb, 1] block a tile), and the program holds no temporary to speak
+    of."""
+    text = compiled.as_text()
+    assert not re.search(r"\b[sf]32\[%d,1\]" % rows, text), \
+        re.findall(r".*[sf]32\[%d,1\].*" % rows, text)[:3]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < (64 << 20), mem
+
+
+@pytest.mark.parametrize("fn,sumsq,G,residency,per,holes", [
+    ("rate", False, 8, "raw", 0, False),            # grid: adhoc_cold's
+    ("avg_over_time", False, 8, "raw", 0, False),
+    ("sum_over_time", True, 8, "raw", 0, False),
+    ("rate", False, 8, "raw", 2, False),            # line, packed: adhoc_prom's
+    ("sum_over_time", True, 64, "raw", 2, False),
+    ("rate", False, 8, "raw", 1, False),            # line, one slot a block
+    ("rate", False, 8, "raw", 2, True),             # holes: adhoc_prom_miss's
+    ("sum_over_time", True, 8, "raw", 2, True),
+    ("rate", False, 8, "delta8", 0, False),         # f32 row operands turn too
+    ("rate", False, 8, "quant16", 0, False),
+])
+def test_the_whole_fused_program_holds_no_column_and_no_temp(
+        one_chip, fn, sumsq, G, residency, per, holes):
+    """The program a query runs, from the store's ``[S]`` arrays (not the
+    kernel from ready-made operands), at 2^20 x 768: the two ``copy
+    s32[1048576,1]`` that every fused query ran before its Pallas call,
+    and the 1 GB of temporaries they were, are gone."""
+    C, Tp = 768, 128
+    prog = fusedgrid.fused_program(fn, sumsq, WINDOW, IV, S, SB, C, Tp, G,
+                                   residency, 0, 0, "pallas", per, holes)
+    compiled = _compile(prog, _store_args(one_chip, S, C, Tp, residency, per))
+    assert "s64[" not in compiled.as_text()
+    _no_column_and_no_temp(compiled, S)
+
+
+@pytest.mark.parametrize("rows", [8, 64, 512])
+def test_a_one_tile_store_compiles_for_v5e_too(one_chip, rows):
+    """Sb = S: the [1, S] row turns to a column at any S % 8 == 0."""
+    prog = fusedgrid.fused_program("rate", False, WINDOW, IV, rows, rows,
+                                   768, 128, 8, "raw", 0, 0, "pallas", 2)
+    _compile(prog, _store_args(one_chip, rows, 768, 128, "raw", 2))
 
 
 def test_dense_flush_of_residuals_runs_in_place(one_chip):
@@ -328,3 +398,6 @@ def test_mesh_fused_program_compiles_for_four_chips(topo):
     # each device holds its own shard and nothing of the others'
     assert compiled.memory_analysis().argument_size_in_bytes \
         < 1.05 * per * C * 4 + (8 << 20)
+    # ... and turns no per-row operand of it to a column (256 MB of
+    # temporaries a device, two copies a slot a query, before)
+    _no_column_and_no_temp(compiled, per)
