@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import logging
 import threading
-import time
 from collections import deque
 from dataclasses import dataclass, field
 
@@ -110,7 +109,8 @@ from .store import (INDEX_FLAG_UNPARSEABLE, INDEX_GENESIS_BUCKET,
                     INDEX_RETIRE_BUCKET, INDEX_TOMBSTONE_BUCKET,
                     ChunkSetRecord, ChunkSink, encode_index_bucket,
                     labels_from_blob)
-from ..utils.diagnostics import TimedRLock, assert_owned, lock_wait_ns
+from ..utils.diagnostics import (TimedRLock, assert_owned, lock_hold_ns,
+                                 lock_wait_ns)
 from ..utils.metrics import (FILODB_INDEX_PERSISTED_BUCKETS,
                              FILODB_INDEX_RECOVER_MS,
                              FILODB_RETENTION_AGED_OUT_ROWS,
@@ -921,10 +921,11 @@ class TimeSeriesShard:
             # opens no span (rows staged by another thread just now land
             # untraced)
             return self._flush({})
-        waited = lock_wait_ns()
+        waited, held = lock_wait_ns(), lock_hold_ns()
         with span(SPAN_INGEST_FLUSH, shard=self.shard_num) as tags:
             tags["rows"] = written = self._flush(tags)
             tags["lock_wait_ms"] = (lock_wait_ns() - waited) / 1e6
+            tags["lock_hold_ms"] = (lock_hold_ns() - held) / 1e6
         return written
 
     def _flush(self, tags: dict) -> int:
@@ -942,9 +943,7 @@ class TimeSeriesShard:
             if residency != "off":
                 self._compress_resident_two_phase(residency)
             return 0
-        t0 = time.perf_counter_ns()
         self.store.throttle()   # the one wait of the write path for the device
-        tags["throttle_ms"] = (time.perf_counter_ns() - t0) / 1e6
         if self.sink is None and self._pending_offset >= 0:
             # without a durable sink, device residency is the only watermark
             with self.lock:
@@ -1681,6 +1680,10 @@ class TimeSeriesMemStore:
 
     def shards_of(self, dataset: str) -> list[TimeSeriesShard]:
         return [s for (d, _), s in sorted(self._shards.items()) if d == dataset]
+
+    def shards(self) -> list[TimeSeriesShard]:
+        """Every shard of every dataset (a snapshot: set-up may add one)."""
+        return list(self._shards.values())
 
     def ingest(self, dataset: str, shard: int, container: RecordContainer,
                offset: int = -1) -> None:

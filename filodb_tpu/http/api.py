@@ -234,9 +234,9 @@ class FiloHttpServer:
         TimeSeriesShardStats Kamon counters, TimeSeriesShard.scala:36-97)."""
         from dataclasses import asdict
 
-        from ..utils.metrics import (FILODB_SHARD_LOCK_CONTENTIONS,
+        from ..utils.diagnostics import inflight
+        from ..utils.metrics import (FILODB_DEVICE_INFLIGHT_PROGRAMS,
                                      FILODB_SHARD_LOCK_HOLD_SECONDS,
-                                     FILODB_SHARD_LOCK_LONG_HOLDS,
                                      FILODB_SHARD_LOCK_WAIT_SECONDS,
                                      FILODB_INGEST_STALE_MARKERS,
                                      FILODB_SHARD_NUM_SERIES,
@@ -244,6 +244,8 @@ class FiloHttpServer:
                                      FILODB_STORE_ROWS_DEMOTED,
                                      FILODB_STORE_ROWS_OFF_LINE,
                                      FILODB_STORE_STAMP_FORM, registry)
+        registry.gauge(FILODB_DEVICE_INFLIGHT_PROGRAMS).update(
+            float(inflight.count))
         # snapshot: a downsample serving refresh adds family engines
         # concurrently (standalone ds_serve_loop)
         for ds, e in list(self.engines.items()):
@@ -270,11 +272,7 @@ class FiloHttpServer:
                         float(st.hole_cells))
                     c = registry.counter(FILODB_INGEST_STALE_MARKERS, shard)
                     c.increment(st.stats.stale_markers - c.value)
-                if hasattr(s.lock, "contentions"):   # TimedRLock diagnostics
-                    registry.gauge(FILODB_SHARD_LOCK_CONTENTIONS, tags) \
-                        .update(float(s.lock.contentions))
-                    registry.gauge(FILODB_SHARD_LOCK_LONG_HOLDS, tags) \
-                        .update(float(s.lock.long_holds))
+                if hasattr(s.lock, "hold_s"):        # TimedRLock diagnostics
                     registry.gauge(FILODB_SHARD_LOCK_WAIT_SECONDS, tags) \
                         .update(s.lock.wait_s)
                     registry.gauge(FILODB_SHARD_LOCK_HOLD_SECONDS, tags) \

@@ -35,6 +35,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils.diagnostics import inflight
 from ..utils.tracing import SPAN_QUERY_KERNEL, span
 from . import decodereg, gridfns
 
@@ -1004,15 +1005,20 @@ class PaddedPartials:
     stall every ingest/query thread for the whole streaming pass. resolve()
     runs at present/merge time, outside the lock."""
 
-    def __init__(self, outs, op: str, num_groups: int, T: int):
+    def __init__(self, outs, op: str, num_groups: int, T: int, ticket):
         self._outs = outs
         self._op = op
         self._ng = num_groups
         self._T = T
+        # the dispatch's place in the in-flight count (diagnostics
+        # .inflight): given back by the fetch, or with this bundle if it is
+        # dropped unfetched
+        self._ticket = ticket
 
     def parts_of(self, outs) -> dict:
         """Partial dict from ALREADY-FETCHED outputs (callers batching many
         bundles into one device_get use this instead of resolve())."""
+        self._ticket.fetched()
         s, c = outs[0][:self._ng, :self._T], outs[1][:self._ng, :self._T]
         if self._op in ("count", "group"):
             return {"count": c}
@@ -1086,7 +1092,8 @@ def fused_grid_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
     # ``holes``: which mode of the line program ran (0 | 1)
     tags = {"stamps": "grid"} if line is None else {
         "stamps": "line", "packed": per, "holes": int(holes)}
-    with span(SPAN_QUERY_KERNEL, phase="dispatch",
+    ticket = inflight.dispatched()
+    with span(SPAN_QUERY_KERNEL, phase="dispatch", ahead=ticket.ahead,
               kernel=kernel_tag(variant), rows=S, c0=c0, cols=Ck, steps=T,
               groups=num_groups, **tags), \
             jax.enable_x64(False):
@@ -1098,7 +1105,7 @@ def fused_grid_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
             outs = call(val, jnp.asarray(n), jnp.asarray(gids), *ops)
     # partial state is tiny ([G, Tp]): ONE host fetch finishes the query — the
     # slice/present/combine chain as device ops would cost a round-trip each
-    padded = PaddedPartials(outs, op, num_groups, T)
+    padded = PaddedPartials(outs, op, num_groups, T, ticket)
     return padded.resolve() if fetch else padded
 
 

@@ -32,7 +32,7 @@ from ..utils.metrics import (FILODB_QUERY_LATENCY_MS,
                              FILODB_QUERY_RESULT_CACHE_MISSES,
                              FILODB_QUERY_SLOW, registry)
 from ..promql import parser as promql
-from ..utils.diagnostics import lock_wait_ns
+from ..utils.diagnostics import inflight, lock_hold_ns, lock_wait_ns
 from ..utils.tracing import (SPAN_QUERY, SPAN_QUERY_ADMIT,
                              SPAN_QUERY_EXECUTE, SPAN_QUERY_FRAGMENT,
                              SPAN_QUERY_GROUPIDS, SPAN_QUERY_KERNEL,
@@ -556,7 +556,7 @@ class QueryEngine:
         ctx = self._ctx()
         t0 = time.perf_counter_ns()
         err: BaseException | None = None
-        waited = lock_wait_ns()
+        waited, held = lock_wait_ns(), lock_hold_ns()
         start_ms, end_ms, step_ms = range_key or (instant_ms, instant_ms, 0)
         with span(SPAN_QUERY, dataset=self.dataset, promql=promql_text[:200],
                   start_ms=start_ms, end_ms=end_ms, step_ms=step_ms,
@@ -636,9 +636,11 @@ class QueryEngine:
                 qtags["exec_path"] = ctx.exec_path
                 qtags["status"] = ("ok" if err is None
                                    else type(err).__name__)
-                # every wait of this thread for a shard lock: the leaf's
-                # (its own tag) and the epoch probe's before it
+                # every wait of this thread for a shard lock, and every
+                # hold it released: the leaf's (its own tags) and the epoch
+                # probe's before it
                 qtags["lock_wait_ms"] = (lock_wait_ns() - waited) / 1e6
+                qtags["lock_hold_ms"] = (lock_hold_ns() - held) / 1e6
                 self._note_query_done(promql_text, ctx,
                                       (time.perf_counter_ns() - t0) / 1e6,
                                       tctx, err)
@@ -1065,7 +1067,7 @@ class QueryEngine:
         # — commit the probe's stats only when the fused route serves (the
         # same only-when-committed rule as the mesh path)
         pctx = _dc_replace(ctx, stats=QueryStats())
-        waited = lock_wait_ns()
+        waited, held = lock_wait_ns(), lock_hold_ns()
         # the route's one leaf: lock wait (a tag), select, group ids and
         # the kernel's dispatch inside it, as SelectRawPartitionsExec's
         with span(SPAN_QUERY_LEAF, shard=sh.shard_num) as ltags:
@@ -1078,9 +1080,10 @@ class QueryEngine:
                         sh, leaf, pctx, ctx, plan, agg, inner, out_ts)
             finally:
                 ltags["lock_wait_ms"] = (lock_wait_ns() - waited) / 1e6
+                ltags["lock_hold_ms"] = (lock_hold_ns() - held) / 1e6
         if got is None:
             return None
-        out, falls, path, uniq, G, T = got
+        out, falls, path, uniq, G, T, ticket = got
         self._set_path(ctx, path)
         ctx.stats.merge(pctx.stats)             # committed: fused serves
         if out is None:
@@ -1089,6 +1092,7 @@ class QueryEngine:
         # the blocking fetch, outside the lock (the in-process leaf's rule)
         with span(SPAN_QUERY_KERNEL, phase="fetch") as ftags:
             vals = np.asarray(out)[:G, :T]
+            ticket.fetched()
             if falls is not None:
                 ftags["fall_tiles"] = fusedresident.count_fall_tiles(falls)
         m = ResultMatrix(out_ts, vals, list(uniq))
@@ -1106,7 +1110,9 @@ class QueryEngine:
         fall_tiles, path, group keys, G, T)`` — ``out`` the [G, T] device
         array, not fetched, None for an empty selection; ``fall_tiles`` the
         raw tier's count of correction matmuls, on the device too, None
-        from the other programs — or None: general path."""
+        from the other programs — with, last, the dispatch's handle in the
+        in-flight count, for the fetch to give back — or None: general
+        path."""
         from ..ops import fusedresident, gridfns
         from .exec import (SeriesSelection, _grouping_for, _pad_steps,
                            _pow2)
@@ -1127,7 +1133,7 @@ class QueryEngine:
         gids, uniq, G, gids_dev = _grouping_for(data.keys, data.rows, R,
                                                 agg.by, agg.without)
         if not uniq:
-            return None, None, "fused-hist", uniq, G, T
+            return None, None, "fused-hist", uniq, G, T, None
         base_ts, interval_ms = data.grid
         les = np.asarray(data.bucket_les, np.float64)
         Gp = _pow2(G)
@@ -1137,7 +1143,9 @@ class QueryEngine:
         ktags = {"kernel": "xla", "rows": R, "cols": data.val.shape[1],
                  "steps": T, "groups": G, "buckets": len(les),
                  "variant": "hist-untiled"}
-        with span(SPAN_QUERY_KERNEL, phase="dispatch") as tags:
+        ticket = inflight.dispatched()
+        with span(SPAN_QUERY_KERNEL, phase="dispatch",
+                  ahead=ticket.ahead) as tags:
             if data.hist_narrow is not None:
                 # hist-resident store: one fused program off the i8/i16
                 # 2D-delta block — the [S, C, B] f32 temp never exists.
@@ -1217,7 +1225,7 @@ class QueryEngine:
                     q, les, data.val, data.n, gids, Gp, out_eval, window,
                     fn, base_ts, interval_ms, stale_ms=ctx.stale_ms)
             tags.update(ktags)
-        return out, falls, path, uniq, G, T
+        return out, falls, path, uniq, G, T, ticket
 
     # -- mesh dispatch (ref: queryengine2/QueryEngine.scala:59-67 — the
     # planner routes every query through per-shard dispatchers; here the
@@ -1308,11 +1316,18 @@ class QueryEngine:
         # in-process leaf) — and a flush's compress_commit landing between
         # an unlocked eligibility check and dispatch would swap the raw
         # blocks for compressed state mid-plan (the 500s VERDICT flagged)
-        waited = lock_wait_ns()
+        waited, held = lock_wait_ns(), lock_hold_ns()
         with contextlib.ExitStack() as stack:
             # the mesh route's one leaf: every shard's lock, taken in order
             leaf = stack.enter_context(span(SPAN_QUERY_LEAF, shard="all",
-                                            route="mesh"))
+                                            route="mesh", locks=len(shards)))
+
+            def note_hold():
+                # as the stack unwinds: after the locks' release (a hold is
+                # counted there), before the leaf span closes
+                leaf["lock_hold_ms"] = (lock_hold_ns() - held) / 1e6
+
+            stack.callback(note_hold)
             for sh in shards:
                 stack.enter_context(sh.lock)
             leaf["lock_wait_ms"] = (lock_wait_ns() - waited) / 1e6
@@ -1377,9 +1392,13 @@ class QueryEngine:
             # and compiles here — step-count bucketing inside the executor
             # bounds that compile space exactly like the in-process path.
             # The span closes with the stack, just before the locks release
+            # a return without a fetch below (a cap) drops the handle, and
+            # the count with it
+            ticket = inflight.dispatched()
             kern = stack.enter_context(span(
-                SPAN_QUERY_KERNEL, phase="dispatch", steps=len(out_ts),
-                rows=sum(sh.store.S for sh in shards), groups=G))
+                SPAN_QUERY_KERNEL, phase="dispatch", ahead=ticket.ahead,
+                steps=len(out_ts), rows=sum(sh.store.S for sh in shards),
+                groups=G))
             if op == "quantile":
                 # same safety gates as the in-process order-stat map: group
                 # cap + dense-sketch memory cap (every device allocates the
@@ -1420,17 +1439,18 @@ class QueryEngine:
         self._set_path(ctx, f"mesh[pjit]-{ex.last_path}")
         distributed.count_mesh_served(ex.last_path)
         if op in ("topk", "bottomk"):
-            m = self._present_mesh_topk(lazy, shards, epochs, out_ts,
+            m = self._present_mesh_topk(lazy, ticket, shards, epochs, out_ts,
                                         list(uniq))
         else:
             with span(SPAN_QUERY_KERNEL, phase="fetch"):
                 vals = lazy.resolve()
+                ticket.fetched()
             m = ResultMatrix(out_ts, vals, list(uniq))
         from .exec import check_sample_limit
         check_sample_limit(m.num_series, len(out_ts), self.config.sample_limit)
         return QueryResult(m)
 
-    def _present_mesh_topk(self, lazy, shards, epochs, out_ts,
+    def _present_mesh_topk(self, lazy, ticket, shards, epochs, out_ts,
                            group_keys) -> ResultMatrix:
         """Map the mesh topk's (shard, row) winners back to series keys and
         present them Prometheus-style (union of selected series, values at
@@ -1440,6 +1460,7 @@ class QueryEngine:
         from .exec import QueryError, TopKPartial, _present_topk
         with span(SPAN_QUERY_KERNEL, phase="fetch"):
             vals, shard_ids, rows, ok = lazy.resolve()
+            ticket.fetched()
         G, k, T = vals.shape
         flat_ok = ok.ravel()
         pairs = (shard_ids.ravel()[flat_ok].astype(np.int64) << 32) \

@@ -30,7 +30,7 @@ import numpy as np
 from ..core.filters import Filter
 from ..core.selection import ShardSelection
 from ..ops import aggregators, binop, instantfns, rangefns
-from ..utils.diagnostics import lock_wait_ns
+from ..utils.diagnostics import lock_hold_ns, lock_wait_ns
 from ..utils.metrics import FILODB_GROUPIDS, registry
 from ..utils.tracing import (SPAN_QUERY_GROUPIDS, SPAN_QUERY_KERNEL,
                              SPAN_QUERY_LEAF, SPAN_QUERY_ODP,
@@ -1245,14 +1245,16 @@ class SelectRawPartitionsExec(ExecPlan):
         return _shard_of_ctx(ctx, self.shard, self.column)
 
     def execute(self, ctx: QueryContext):
-        waited = lock_wait_ns()
+        waited, held = lock_wait_ns(), lock_hold_ns()
         with span(SPAN_QUERY_LEAF, shard=self.shard) as tags:
             try:
                 return self._execute_leaf(ctx)
             finally:
                 # a waiting thread is not what the host was doing: the wait
-                # for the shard lock is a tag of the leaf, not a span
+                # for the shard lock is a tag of the leaf, not a span; the
+                # hold beside it is the lock's time this leaf took
                 tags["lock_wait_ms"] = (lock_wait_ns() - waited) / 1e6
+                tags["lock_hold_ms"] = (lock_hold_ns() - held) / 1e6
 
     def _execute_leaf(self, ctx: QueryContext):
         # hold the shard lock across array capture AND the transformer chain's
@@ -1708,6 +1710,7 @@ def _merge_partials(op: str, partials: list[AggPartial]) -> AggPartial:
     # same fetch — calling their resolve() here would round-trip per shard
     raw = [p.parts for p in partials]
     with span(SPAN_QUERY_KERNEL, phase="fetch"):
+        # parts_of() below takes each bundle out of the in-flight count
         fetched = jax.device_get([r._outs if hasattr(r, "parts_of") else r
                                   for r in raw])
     resolved = [r.parts_of(f) if hasattr(r, "parts_of") else f

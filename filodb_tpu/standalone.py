@@ -25,7 +25,7 @@ from .parallel.cluster import ShardManager, ShardStatus
 from .parallel.shardmapper import ShardMapper
 from .query.engine import QueryEngine
 from .query.rangevector import QueryError
-from .utils.diagnostics import lock_wait_ns
+from .utils.diagnostics import lock_hold_ns, lock_wait_ns
 from .utils.metrics import (FILODB_INGEST_DECODE_ERRORS,
                             FILODB_INGESTED_ROWS, ShardHealthStats, registry)
 from .utils.tracing import SPAN_INGEST_CONSUME, span, tracer
@@ -208,7 +208,7 @@ class IngestionConsumer(threading.Thread):
                         # the scatter leg of the ingest path, tagged with
                         # how much it moved
                         n_rows = 0
-                        waited = lock_wait_ns()
+                        waited, held = lock_wait_ns(), lock_hold_ns()
                         try:
                             with span(SPAN_INGEST_CONSUME,
                                       dataset=self.dataset,
@@ -224,6 +224,8 @@ class IngestionConsumer(threading.Thread):
                                 tags["rows"] = n_rows
                                 tags["lock_wait_ms"] = (
                                     lock_wait_ns() - waited) / 1e6
+                                tags["lock_hold_ms"] = (
+                                    lock_hold_ns() - held) / 1e6
                         finally:
                             if isinstance(src, _DecodeAhead):
                                 src.close()
@@ -300,6 +302,7 @@ class FiloServer:
         self._endpoints_at = 0.0
         self._zipkin = None
         self._gc_hooked = False
+        self._beating = False
 
     def _start_shard(self, dataset: str, shard_num: int) -> None:
         """Bring up one owned shard: store + (optionally) its bus consumer
@@ -1270,6 +1273,11 @@ class FiloServer:
             # server lives (shutdown() removes the hook)
             tracer.install_gc_hook()
             self._gc_hooked = True
+        if tracer.enabled and not self._beating:
+            # the interpreter's wake-up and the shard locks' utilisation as
+            # runtime.beat spans, one a second (shutdown() stops it)
+            tracer.start_heartbeat(self._shard_locks)
+            self._beating = True
         from .query.engine import slow_query_log
         slow_query_log.resize(int(cfg["query.slow_log_size"]))
         # fused compressed-resident kernel tier: pick the backend BEFORE the
@@ -1373,6 +1381,13 @@ class FiloServer:
         if self._gc_hooked:
             tracer.remove_gc_hook()
             self._gc_hooked = False
+        if self._beating:
+            tracer.stop_heartbeat(self._shard_locks)
+            self._beating = False
+
+    def _shard_locks(self) -> list:
+        """This server's shard locks, for the tracer's heartbeat."""
+        return [s.lock for s in self.memstore.shards()]
 
 
 def _pow2(n: int) -> int:

@@ -168,6 +168,70 @@ def lock_wait_ns() -> int:
     return getattr(_tls, "wait_ns", 0)
 
 
+def lock_hold_ns() -> int:
+    """Nanoseconds the CALLING thread has HELD shard locks (``order_class``
+    "shard") since it started: first-depth holds only, each counted when it
+    is released — the holder's twin of :func:`lock_wait_ns`. A span tags
+    the holds that ended inside it as the difference of two readings; a
+    hold released inside a nested span is in the inner and the outer
+    span's tag alike."""
+    return int(getattr(_tls, "hold_s", 0.0) * 1e9)
+
+
+class Dispatched:
+    """One counted dispatch (see :class:`InflightPrograms`): ``ahead`` is
+    the count as it entered. ``fetched()`` takes it out of the count, once;
+    a handle dropped unfetched (an error between dispatch and fetch) takes
+    it out as it is collected."""
+
+    __slots__ = ("_owner", "_key", "ahead")
+
+    def __init__(self, owner: "InflightPrograms", key: int, ahead: int):
+        self._owner, self._key, self.ahead = owner, key, ahead
+
+    def fetched(self) -> None:
+        self._owner._open.pop(self._key, None)      # atomic; idempotent
+
+    __del__ = fetched
+
+
+class InflightPrograms:
+    """The device queue as the host sees it: fused programs that were
+    dispatched and whose result no one has fetched yet, process-wide. A
+    dispatch site takes ``dispatched()`` BEFORE it hands the program to the
+    device — ``ahead`` of the handle it gets is what the device runs before
+    this program — and keeps the handle with the program's result; the
+    fetch site calls ``fetched()`` on it. The flush's programs are not
+    counted: no one fetches them."""
+
+    def __init__(self):
+        self._lock = threading.Lock()       # serializes count-then-insert
+        # serial -> perf_counter_ns at dispatch; insertion order = age
+        self._open: dict[int, int] = {}
+        self._serial = 0
+
+    def dispatched(self) -> Dispatched:
+        now = time.perf_counter_ns()
+        with self._lock:
+            self._serial = key = self._serial + 1
+            ahead = len(self._open)
+            self._open[key] = now
+        return Dispatched(self, key, ahead)
+
+    @property
+    def count(self) -> int:
+        return len(self._open)
+
+    def oldest_age_s(self) -> float | None:
+        """Seconds since the oldest unfetched dispatch; None with none."""
+        stamps = list(self._open.values())  # one C call: atomic
+        return ((time.perf_counter_ns() - stamps[0]) / 1e9 if stamps
+                else None)
+
+
+inflight = InflightPrograms()
+
+
 class DiagnosticsError(AssertionError):
     """A violated concurrency invariant (only raised when diagnostics on)."""
 
@@ -239,7 +303,9 @@ class TimedRLock:
     the long-hold stack capture only happens when on. ``wait_s`` totals the
     time threads blocked in contended acquires (the uncontended path reads
     no clock for it) and ``hold_s`` the first-depth holds, so
-    ``rate(hold_s)`` is the lock's utilisation without FILODB_LOCK_DEBUG.
+    ``rate(hold_s)`` is the lock's utilisation without FILODB_LOCK_DEBUG;
+    ``holder`` names the thread of the current (or last) first-depth hold
+    (the acquire stores its id; the name is looked up when asked for).
 
     ``order_class`` names the lock's class in the global acquisition order
     (LOCK_ORDER). Under FILODB_LOCK_DEBUG=1 every acquisition checks the
@@ -263,10 +329,10 @@ class TimedRLock:
         self.wait_s = 0.0
         self.hold_s = 0.0
         self._acquired_at = 0.0
+        self._holder = 0                # thread id of the first-depth holder
         self._depth = 0
         self._registered = False        # in the hold watchdog's held set
         self._warned_hold = 0.0         # _acquired_at already flagged
-        self._hold_hist = None          # lazy filodb_lock_hold_ms handle
         # serializes the contention/long-hold counter RMWs: contentions is
         # bumped precisely when the main lock is NOT held, so `+= 1` there
         # races every other contending thread (found by filolint's
@@ -320,12 +386,20 @@ class TimedRLock:
         self._depth += 1
         if self._depth == 1:
             self._acquired_at = time.monotonic()
+            self._holder = threading.get_ident()
             if debug:
                 _watchdog.register(self)
                 self._registered = True
         if debug:
             _held_locks().append(self)
         return True
+
+    @property
+    def holder(self) -> str:
+        for t in threading.enumerate():
+            if t.ident == self._holder:
+                return t.name
+        return f"thread-{self._holder}" if self._holder else ""
 
     def _watchdog_check(self, now: float) -> None:
         """Called by the hold watchdog's scan thread. Reads are racy by
@@ -348,20 +422,13 @@ class TimedRLock:
         if self._depth == 1:
             held = time.monotonic() - self._acquired_at
             self.hold_s += held         # serialized by the lock itself
+            if self.order_class == "shard":
+                # the holder's own total (lock_hold_ns): shard locks only,
+                # so a group-flush or sink lock around one counts nothing
+                _tls.hold_s = getattr(_tls, "hold_s", 0.0) + held
             if self._registered:
                 _watchdog.unregister(self)
                 self._registered = False
-            if lock_debug:
-                hist = self._hold_hist
-                if hist is None:
-                    # deferred import: metrics is a leaf module but the
-                    # lock is constructed on paths that must not pay for
-                    # registry wiring unless debug is on
-                    from .metrics import FILODB_LOCK_HOLD_MS, registry
-                    hist = self._hold_hist = registry.histogram(
-                        FILODB_LOCK_HOLD_MS,
-                        {"class": self.order_class or "other"})
-                hist.record(held * 1000.0)
             if held > HOLD_WARN_S and self._warned_hold != self._acquired_at:
                 with self._stats_lock:
                     self.long_holds += 1

@@ -42,11 +42,8 @@ FILODB_PEER_EXEC_LATENCY_MS = "filodb_peer_exec_latency_ms"
 FILODB_PEER_BREAKER_OPEN = "filodb_peer_breaker_open"
 FILODB_SHARD_STATUS = "filodb_shard_status"
 FILODB_SHARD_NUM_SERIES = "filodb_shard_num_series"
-FILODB_SHARD_LOCK_CONTENTIONS = "filodb_shard_lock_contentions"
-FILODB_SHARD_LOCK_LONG_HOLDS = "filodb_shard_lock_long_holds"
 FILODB_SHARD_LOCK_WAIT_SECONDS = "filodb_shard_lock_wait_seconds"
 FILODB_SHARD_LOCK_HOLD_SECONDS = "filodb_shard_lock_hold_seconds"
-FILODB_LOCK_HOLD_MS = "filodb_lock_hold_ms"
 FILODB_GROUPIDS = "filodb_groupids"
 FILODB_SELECTION_MEMO = "filodb_selection_memo"
 FILODB_QUERY_LATENCY_MS = "filodb_query_latency_ms"
@@ -82,7 +79,9 @@ FILODB_QUERY_FRAGMENT_CACHE_BYTES = "filodb_query_fragment_cache_bytes"
 FILODB_QUERY_WINDOWS_WIDENED = "filodb_query_windows_widened"
 FILODB_QUERY_SUBSCRIBE_INCREMENTS = "filodb_query_subscribe_increments"
 FILODB_INGEST_PUBLISH_LATENCY_MS = "filodb_ingest_publish_latency_ms"
-FILODB_TRACE_SPANS = "filodb_trace_spans"
+FILODB_DEVICE_INFLIGHT_PROGRAMS = "filodb_device_inflight_programs"
+FILODB_RUNTIME_WAKEUP_SECONDS = "filodb_runtime_wakeup_seconds"
+FILODB_RUNTIME_STALLS = "filodb_runtime_stalls"
 FILODB_RETENTION_ROUTED_QUERIES = "filodb_retention_routed_queries"
 FILODB_RETENTION_ODP_ROWS = "filodb_retention_odp_rows"
 FILODB_RETENTION_REPLICA_FAILOVER = "filodb_retention_replica_failover"
@@ -161,21 +160,12 @@ METRICS_SPEC: dict[str, tuple[str, str]] = {
                  "(Active/Assigned/Recovery/Down/Unassigned)."),
     FILODB_SHARD_NUM_SERIES: (
         "gauge", "Live series per shard."),
-    FILODB_SHARD_LOCK_CONTENTIONS: (
-        "gauge", "TimedRLock contention count per shard (diagnostics)."),
-    FILODB_SHARD_LOCK_LONG_HOLDS: (
-        "gauge", "TimedRLock long-hold count per shard (diagnostics)."),
     FILODB_SHARD_LOCK_WAIT_SECONDS: (
         "gauge", "Seconds threads have blocked in contended acquires of the "
                  "shard lock, total since start."),
     FILODB_SHARD_LOCK_HOLD_SECONDS: (
         "gauge", "Seconds the shard lock has been held, total since start; "
                  "its rate is the lock's utilisation."),
-    FILODB_LOCK_HOLD_MS: (
-        "histogram", "TimedRLock hold time per lock class, recorded under "
-                     "FILODB_LOCK_DEBUG=1 — the runtime twin of filolint's "
-                     "live-block-under-lock rule; soak runs alert on "
-                     "hold-time regressions the static pass cannot see."),
     FILODB_GROUPIDS: (
         "counter", "by/without group-id computations under a shard lock, "
                    "tagged by route: index = gathers over the part-key "
@@ -301,9 +291,23 @@ METRICS_SPEC: dict[str, tuple[str, str]] = {
         "histogram", "BrokerBus pipelined publish-group round trip per "
                      "partition, exemplar-tagged with the publish trace "
                      "id."),
-    FILODB_TRACE_SPANS: (
-        "counter", "Spans recorded into the tracer ring buffer (sampled-in "
-                   "only; sampled-out spans cost no clock reads)."),
+    FILODB_DEVICE_INFLIGHT_PROGRAMS: (
+        "gauge", "Fused query programs dispatched to the device whose "
+                 "result no thread has fetched yet, process-wide, as of the "
+                 "scrape (the flush's programs are not counted: no one "
+                 "fetches them). A dispatch span's tag ahead is the same "
+                 "count as its program entered."),
+    FILODB_RUNTIME_WAKEUP_SECONDS: (
+        "histogram", "How late the tracer's heartbeat thread came out of "
+                     "each 20 ms sleep: what a thread pays to get the "
+                     "interpreter back, as a worker coming out of a device "
+                     "fetch does (the GIL-pressure gauge; recorded while a "
+                     "server runs with trace.enabled)."),
+    FILODB_RUNTIME_STALLS: (
+        "counter", "Heartbeat wake-ups more than 1 s late: the whole "
+                   "process stood still. Each logs one warning naming the "
+                   "shard lock held, its holder, the oldest unfetched "
+                   "dispatch and whether a full collection overlapped."),
     FILODB_RETENTION_ROUTED_QUERIES: (
         "counter", "Queries the retention router served from a downsample "
                    "family (tagged dataset + resolution; stitched raw+ds "
@@ -509,12 +513,12 @@ class MetricsRegistry:
         self._metrics: dict[tuple, object] = {}
         self._lock = threading.Lock()
 
-    def _get(self, cls, name: str, tags: dict | None):
+    def _get(self, cls, name: str, tags: dict | None, *args):
         key = (name, tuple(sorted((tags or {}).items())))
         with self._lock:
             m = self._metrics.get(key)
             if m is None:
-                m = self._metrics[key] = cls()
+                m = self._metrics[key] = cls(*args)
             return m
 
     def counter(self, name: str, tags: dict | None = None) -> Counter:
@@ -523,8 +527,11 @@ class MetricsRegistry:
     def gauge(self, name: str, tags: dict | None = None) -> Gauge:
         return self._get(Gauge, name, tags)
 
-    def histogram(self, name: str, tags: dict | None = None) -> Histogram:
-        return self._get(Histogram, name, tags)
+    def histogram(self, name: str, tags: dict | None = None,
+                  bounds=Histogram.DEFAULT_BOUNDS) -> Histogram:
+        """``bounds`` count when the series is first made (a unit other
+        than milliseconds brings its own)."""
+        return self._get(Histogram, name, tags, bounds)
 
     def expose_prometheus(self) -> str:
         """Prometheus text format 0.0.4."""

@@ -27,8 +27,8 @@ so any ``jax.profiler`` trace of the process holds the program's spans in
 its host planes, on the trace's own clock, beside the device operations.
 An annotation outside a profiler session costs a fraction of a
 microsecond; a sampled-out or disabled span enters none. Intervals handed
-to ``record()`` after the fact (a queue wait, a garbage collection) cannot
-enter one and live in the ring only.
+to ``record()`` after the fact (a queue wait, a garbage collection, the
+heartbeat's ``runtime.beat``) cannot enter one and live in the ring only.
 
 Sampling: the decision is made once at the trace ROOT (``sample_rate``) and
 rides the context, so either every participating node records a trace or
@@ -52,7 +52,9 @@ from dataclasses import dataclass, field
 
 from jax.profiler import TraceAnnotation
 
-from .metrics import FILODB_SWALLOWED_ERRORS, FILODB_TRACE_SPANS, registry
+from . import diagnostics
+from .metrics import (FILODB_RUNTIME_STALLS, FILODB_RUNTIME_WAKEUP_SECONDS,
+                      FILODB_SWALLOWED_ERRORS, registry)
 
 log = logging.getLogger("filodb_tpu.trace")
 
@@ -103,6 +105,7 @@ SPAN_CLUSTER_LEAD = "cluster.epoch.lead"
 SPAN_CLUSTER_REJOIN = "cluster.rejoin"
 SPAN_CLUSTER_REBALANCE = "cluster.rebalance"
 SPAN_RUNTIME_GC = "runtime.gc"
+SPAN_RUNTIME_BEAT = "runtime.beat"
 
 TRACE_SPEC: dict[str, str] = {
     SPAN_HTTP_REQUEST: "Root span of one HTTP query_range/query request on "
@@ -117,8 +120,11 @@ TRACE_SPEC: dict[str, str] = {
                       "the fact; tags: priority).",
     SPAN_QUERY: "One PromQL query; the root unless an http.request opened "
                 "first (tags: dataset, promql, start_ms, end_ms, step_ms, "
-                "tenant; on close exec_path, status and lock_wait_ms = "
-                "every wait of its thread for a shard lock).",
+                "tenant; on close exec_path, status, lock_wait_ms = "
+                "every wait of its thread for a shard lock, and "
+                "lock_hold_ms = every hold of a shard lock its thread "
+                "released inside it: the epoch probe's and the leaf's, so "
+                "a leaf's hold is in the leaf's tag and in this one).",
     SPAN_QUERY_PARSE: "PromQL text -> LogicalPlan.",
     SPAN_QUERY_PLAN: "LogicalPlan -> ExecPlan materialization + remote "
                      "collapse.",
@@ -128,7 +134,10 @@ TRACE_SPEC: dict[str, str] = {
                      "mesh route one span under every shard's lock, on the "
                      "fused-hist route the engine's own (tags: shard, or "
                      "shard=all route=mesh; lock_wait_ms = what the thread "
-                     "waited for shard locks inside it).",
+                     "waited for shard locks inside it, lock_hold_ms = what "
+                     "it held them for, counted as each is released; on the "
+                     "mesh route that is the sum over the locks it took and "
+                     "locks = how many).",
     SPAN_QUERY_SELECT: "Index select + array capture of one leaf; per shard "
                        "on the mesh route (tags: shard, series, memo = hit "
                        "| miss | bypass of the shard's selection memo, "
@@ -148,7 +157,12 @@ TRACE_SPEC: dict[str, str] = {
     SPAN_QUERY_KERNEL: "Host side of one fused kernel: phase=dispatch is "
                        "the call under the shard lock, phase=fetch the "
                        "blocking fetch of its result outside it (dispatch "
-                       "tags: kernel, rows, c0, cols, steps, groups, stamps "
+                       "tags: ahead = fused query programs dispatched before "
+                       "this one whose result no thread had fetched yet as "
+                       "it entered, process-wide: what the device runs "
+                       "first (the flush's programs are not in it: no one "
+                       "fetches them); kernel, rows, c0, cols, steps, "
+                       "groups, stamps "
                        "= grid | line, how the store keeps time, and on "
                        "line packed = 1 | 2, the edge slots a 128-lane "
                        "block of the kernel's one-hot operand, and holes "
@@ -187,12 +201,15 @@ TRACE_SPEC: dict[str, str] = {
                           "append (tags: partition, broker).",
     SPAN_INGEST_CONSUME: "One consumer drain: bus containers scattered "
                          "into the shard store (tags: dataset, shard, rows, "
-                         "lock_wait_ms).",
+                         "lock_wait_ms, lock_hold_ms = the shard lock's "
+                         "holds its thread released inside it, those of the "
+                         "flushes nested in it too).",
     SPAN_INGEST_FLUSH: "One shard flush that had staged rows to land: "
                        "device scatter, backpressure, residency upkeep; an "
                        "idle flush opens none (tags: shard, rows, "
-                       "lock_wait_ms, throttle_ms = the wait for the "
-                       "device, demoted = rows this flush took off their "
+                       "lock_wait_ms, lock_hold_ms = the shard lock's holds "
+                       "it released, also in the tag of a consume or query "
+                       "span around it, demoted = rows this flush took off their "
                        "line, holes = cells it left without a sample: "
                        "staleness markers and skipped cells).",
     SPAN_QUERY_RETENTION: "Downsample-aware routing of one query: the "
@@ -226,6 +243,24 @@ TRACE_SPEC: dict[str, str] = {
     SPAN_RUNTIME_GC: "One full (generation 2) garbage collection of the "
                      "server process, every thread stopped (recorded after "
                      "the fact by the server's gc hook; tags: collected).",
+    SPAN_RUNTIME_BEAT: "One second of the tracer's heartbeat, a thread that "
+                       "sleeps 20 ms at a time while a server runs and "
+                       "takes, as it wakes, how late it is: what a thread "
+                       "pays to get the interpreter back. The interval is "
+                       "that second's WORST wake-up, due -> woke, not the "
+                       "second (recorded after the fact; tags: ticks, "
+                       "late_ms = sum over the second's wake-ups, period_ms "
+                       "= the time the counts cover, inflight = fused "
+                       "programs dispatched and not fetched as it closed, "
+                       "lock and lock_hold_ms = the busiest shard lock's "
+                       "name and the growth of its hold_s over the period: "
+                       "the LOCK's side, each hold once. A wake-up more "
+                       "than 1 s late adds stall = 1 and what the thread "
+                       "saw as it came back: held_lock, holder, held_ms = "
+                       "the shard lock held longest, the thread that holds "
+                       "it and since when; oldest_dispatch_ms = the age of "
+                       "the oldest unfetched program; gc = 1 where a full "
+                       "collection overlapped).",
 }
 
 
@@ -292,10 +327,12 @@ class Tracer:
         self._seq = 0
         self._gc_users = 0
         self._gc_t0: int | None = None
+        self._gc_last: tuple[int, int] | None = None    # (start, end)
+        self._beat_probes: list = []
+        self._beat: _Heartbeat | None = None
         self.log_spans = False
         self.enabled = True
         self.sample_rate = 1.0
-        self._span_counter = registry.counter(FILODB_TRACE_SPANS)
 
     # -- context ------------------------------------------------------------
 
@@ -391,7 +428,6 @@ class Tracer:
             self._seq += 1
             rec.seq = self._seq
             self.spans.append(rec)
-        self._span_counter.increment()
         if self.log_spans:
             log.info("span %s %.1fms %s", rec.name, rec.duration_us / 1000,
                      rec.tags)
@@ -402,10 +438,10 @@ class Tracer:
         """Record a FINISHED interval (``time.perf_counter_ns`` readings)
         under the calling thread's current context, by span()'s sampling
         and ``enabled`` rules: for the waits no ``with`` block on one thread
-        can bracket (the scheduler queue, a garbage collection). Takes no
-        lock and touches no metric — the gc hook calls it from wherever a
-        collection happened to start — so the record reaches the ring and
-        the span counter with the next span, snapshot or drain."""
+        can bracket (the scheduler queue, a garbage collection, the
+        heartbeat's second). Takes no lock — the gc hook calls it from
+        wherever a collection happened to start — so the record reaches the
+        ring with the next span, snapshot or drain."""
         stack = self._stack()
         if not stack and not self.enabled:
             return
@@ -425,7 +461,7 @@ class Tracer:
                           (t1_ns - t0_ns) // 1000, tags, 0, t0_ns)
 
     def _sync_handoff(self) -> None:
-        """Move what record() handed over into the ring and the counter.
+        """Move what record() handed over into the ring.
         The deque is lock-free on both sides (appends and pops are atomic)."""
         late = []
         try:
@@ -439,7 +475,6 @@ class Tracer:
                 self._seq += 1
                 r.seq = self._seq
                 self.spans.append(r)
-        self._span_counter.increment(len(late))
         if self.log_spans:
             for r in late:
                 log.info("span %s %.1fms %s", r.name, r.duration_us / 1000,
@@ -474,8 +509,39 @@ class Tracer:
             self._gc_t0 = time.perf_counter_ns()
         elif self._gc_t0 is not None:
             t0, self._gc_t0 = self._gc_t0, None
-            self.record(SPAN_RUNTIME_GC, t0, time.perf_counter_ns(),
+            self._gc_last = (t0, time.perf_counter_ns())
+            self.record(SPAN_RUNTIME_GC, *self._gc_last,
                         collected=info.get("collected", 0))
+
+    def gc_overlapped(self, t0_ns: int, t1_ns: int) -> bool:
+        """Whether a full collection ran (or still runs) inside the
+        interval: the one in progress or the last finished one."""
+        last = self._gc_last
+        return (self._gc_t0 is not None
+                or (last is not None and last[0] < t1_ns and last[1] > t0_ns))
+
+    # -- heartbeat ----------------------------------------------------------
+
+    def start_heartbeat(self, shard_locks) -> None:
+        """``runtime.beat`` spans from here on: one heartbeat thread for the
+        process, shared by every server that asked, as the gc hook is
+        (FiloServer.start / shutdown pair it with :meth:`stop_heartbeat`).
+        ``shard_locks`` is the server's callable returning its shards'
+        ``TimedRLock``s; the beat reads them once a second."""
+        with self._lock:
+            self._beat_probes.append(shard_locks)
+            if self._beat is None:
+                self._beat = _Heartbeat(self).start()
+
+    def stop_heartbeat(self, shard_locks) -> None:
+        with self._lock:
+            if shard_locks in self._beat_probes:
+                self._beat_probes.remove(shard_locks)
+            beat = None
+            if not self._beat_probes:
+                beat, self._beat = self._beat, None
+        if beat is not None:
+            beat.stop()
 
     # -- assembly / export --------------------------------------------------
 
@@ -601,6 +667,126 @@ class _OpenSpan:
                 trace_id, span_id, parent_id, self._name, self._t0, t1, t1,
                 self._tags))
         return False
+
+
+class _Heartbeat:
+    """The interpreter's wake-up, sampled: a daemon thread that sleeps
+    ``PERIOD_NS`` at a time and, each time it wakes, takes ``late = woke -
+    due``. A thread coming out of a sleep needs the GIL back exactly as a
+    worker coming out of a device fetch does, so ``late`` is what a fetch
+    pays, 50 times a second, without touching the fetch. Once a second it
+    hands ONE ``runtime.beat`` span to ``Tracer.record()``; a wake-up more
+    than ``STALL_NS`` late also logs one warning and counts one stall.
+    All state below belongs to the beat thread (``tick`` is its body, and
+    takes its clock readings as arguments: a test hands it its own)."""
+
+    PERIOD_NS = 20_000_000
+    SPAN_NS = 1_000_000_000
+    STALL_NS = 1_000_000_000
+    # wake-ups run from tens of microseconds to a stall's seconds
+    WAKEUP_BOUNDS_S = (0.0001, 0.0002, 0.0005, 0.001, 0.002, 0.005, 0.01,
+                       0.02, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
+
+    def __init__(self, tracer_: "Tracer"):
+        self.tracer = tracer_
+        self._halt = threading.Event()
+        self._thread: threading.Thread | None = None
+        self._wakeups = registry.histogram(
+            FILODB_RUNTIME_WAKEUP_SECONDS, bounds=self.WAKEUP_BOUNDS_S)
+        self._stalls = registry.counter(FILODB_RUNTIME_STALLS)
+        self._new_period(time.perf_counter_ns(), self._locks())
+
+    def start(self) -> "_Heartbeat":
+        self._thread = threading.Thread(target=self._run, daemon=True,
+                                        name="trace-heartbeat")
+        self._thread.start()
+        return self
+
+    def stop(self) -> None:
+        self._halt.set()
+        if self._thread is not None:
+            self._thread.join(timeout=3)
+            self._thread = None
+
+    def _run(self) -> None:
+        while True:
+            due = time.perf_counter_ns() + self.PERIOD_NS
+            if self._halt.wait(self.PERIOD_NS / 1e9):
+                return
+            try:
+                self.tick(due, time.perf_counter_ns())
+            except Exception:  # noqa: BLE001 — a probe that raises (a
+                # shard map changing under it) must not end the heartbeat
+                # for the life of the process; counted, logged, next tick
+                registry.counter(FILODB_SWALLOWED_ERRORS,
+                                 {"site": "trace-heartbeat"}).increment()
+                log.warning("heartbeat tick failed", exc_info=True)
+
+    def _locks(self) -> list:
+        return [lk for probe in list(self.tracer._beat_probes)
+                for lk in probe()]
+
+    def _new_period(self, now_ns: int, locks: list) -> None:
+        """Counts to zero, the locks' totals as they stand."""
+        self._t0 = now_ns
+        self._ticks = 0
+        self._late_ns = 0
+        self._worst = (0, 0)                # (due, woke) of the latest
+        self._held = {id(lk): lk.hold_s for lk in locks}
+
+    def tick(self, due_ns: int, woke_ns: int) -> None:
+        """One wake-up, due at ``due_ns`` and come at ``woke_ns``."""
+        if not self.tracer.enabled:
+            self._new_period(woke_ns, [])   # off: no count, no span
+            return
+        late = max(0, woke_ns - due_ns)
+        self._wakeups.record(late / 1e9)
+        self._ticks += 1
+        self._late_ns += late
+        if late >= self._worst[1] - self._worst[0]:
+            self._worst = (due_ns, woke_ns)
+        stall = self._stalled(due_ns, woke_ns) if late >= self.STALL_NS \
+            else {}
+        if not stall and woke_ns - self._t0 < self.SPAN_NS:
+            return
+        tags = {"ticks": self._ticks, "late_ms": self._late_ns / 1e6,
+                "period_ms": (woke_ns - self._t0) / 1e6,
+                "inflight": diagnostics.inflight.count}
+        locks = self._locks()
+        if locks:
+            # the lock's own total: every hold once, whoever held it
+            growth = [(lk.hold_s - self._held.get(id(lk), lk.hold_s), lk)
+                      for lk in locks]
+            held_s, busiest = max(growth, key=lambda g: g[0])
+            tags.update(lock=busiest.name, lock_hold_ms=held_s * 1e3)
+        self.tracer.record(SPAN_RUNTIME_BEAT, *self._worst, **tags, **stall)
+        self._new_period(woke_ns, locks)
+
+    def _stalled(self, due_ns: int, woke_ns: int) -> dict:
+        """A wake-up a second and more late: what this thread sees as it
+        comes back, as the beat's tags and ONE warning (so an untraced
+        run's log says what stood still). Racy reads of the locks, by
+        design: the worst a torn one costs is a wrong name."""
+        held = [lk for lk in self._locks() if lk._depth > 0]
+        lk = min(held, key=lambda k: k._acquired_at) if held else None
+        age_s = diagnostics.inflight.oldest_age_s()
+        in_gc = self.tracer.gc_overlapped(due_ns, woke_ns)
+        tags = {"stall": 1, "gc": int(in_gc)}
+        what = since = "none"
+        if lk is not None:
+            held_ms = (time.monotonic() - lk._acquired_at) * 1e3
+            tags.update(held_lock=lk.name, holder=lk.holder, held_ms=held_ms)
+            what = f"{lk.name} by {lk.holder} for {held_ms:.0f} ms"
+        if age_s is not None:
+            tags["oldest_dispatch_ms"] = age_s * 1e3
+            since = f"{age_s * 1e3:.0f} ms"
+        self._stalls.increment()
+        log.warning(
+            "stall: the heartbeat woke %.0f ms late; shard lock held: %s; "
+            "oldest unfetched dispatch: %s (%d in flight); full collection "
+            "inside it: %s", (woke_ns - due_ns) / 1e6, what, since,
+            diagnostics.inflight.count, "yes" if in_gc else "no")
+        return tags
 
 
 class ZipkinReporter:

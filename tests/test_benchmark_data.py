@@ -7,7 +7,9 @@ and ``prom``'s — the stamp law, the true-stamp reference, the fill of a
 line store, probes, reader, control and files (``test_prom_data.py``) —
 and ``prom_miss``'s: the miss law, the reference over the samples that
 exist, the fill of a hole store, probes, reader, control and files
-(``test_prom_miss_data.py``), every case under a name of its own.
+(``test_prom_miss_data.py``), and the cases of the five readers of what a
+worker waits for (``test_wait_layers.py``, PR 39), every case under a name
+of its own.
 They run in seconds on the CPU, and what they pin is the yardstick: tier-1
 collects them here, under their own names, so that the floor counts them.
 """
@@ -16,19 +18,25 @@ import pytest
 
 for _mod in ("benchmark.tests.test_data", "benchmark.tests.test_hist_data",
              "benchmark.tests.test_prom_data",
-             "benchmark.tests.test_prom_miss_data"):
+             "benchmark.tests.test_prom_miss_data",
+             "benchmark.tests.test_wait_layers"):
     pytest.register_assert_rewrite(_mod)
 
 from benchmark.tests.test_data import *        # noqa: E402,F401,F403
 from benchmark.tests.test_hist_data import *   # noqa: E402,F401,F403
 from benchmark.tests.test_prom_data import *   # noqa: E402,F401,F403
 from benchmark.tests.test_prom_miss_data import *   # noqa: E402,F401,F403
+from benchmark.tests.test_wait_layers import *      # noqa: E402,F401,F403
 
 
-# Two cases of those files that a star import alone does not give tier-1:
+# Cases of those files that a star import alone does not give tier-1:
+
+import json                                                 # noqa: E402
+import os                                                   # noqa: E402
 
 from benchmark.tests import test_hist_data as _hist_cases   # noqa: E402
 from benchmark.tests import test_prom_data as _prom_cases   # noqa: E402
+from benchmark.tests import test_prom_miss_data as _miss_cases  # noqa: E402
 
 # ``hist``'s fill case bears the name of ``prom``'s, which the later import
 # shadows: collected here under a name of its own
@@ -44,7 +52,116 @@ test_hist_fill_leaves_the_store_the_write_path_would = \
     "hole_cells_pct, as ISSUE 35 asks, and may edit no file the benchmark "
     "has. A `benchmark` PR has to make that case test membership, not the "
     "tail (ROADMAP.md queue 2 item 0 (12)); the rest of what it says of "
-    "promdev_prom_1m is held by test_prom_miss_configuration_and_cell_"
-    "are_as_named"))
+    "promdev_prom_1m is held by test_the_prom_cells_are_as_named_whatever_"
+    "follows_them"))
 def test_the_configuration_and_the_cell_are_as_named():
     _prom_cases.test_the_configuration_and_the_cell_are_as_named()
+
+
+@pytest.mark.xfail(strict=True, reason=(
+    "benchmark/tests/test_prom_miss_data.py pins per_layer's last two names "
+    "to demoted_rows_pct and hole_cells_pct; PR 39 appended its five "
+    "readers' entries, as ISSUE 39 asks, and may edit no file the benchmark "
+    "has. A `benchmark` PR has to make that case test membership, not the "
+    "tail (ROADMAP.md queue 2 item 0 (12)); everything else it says of "
+    "promdev_prom_miss_1m and promdev_prom_1m is held by "
+    "test_the_prom_cells_are_as_named_whatever_follows_them"))
+def test_prom_miss_configuration_and_cell_are_as_named():
+    _miss_cases.test_prom_miss_configuration_and_cell_are_as_named()
+
+
+def test_the_prom_cells_are_as_named_whatever_follows_them():
+    """What the two pinned cases above say of ``promdev_prom_1m`` x
+    ``adhoc`` and ``promdev_prom_miss_1m`` x ``adhoc``, by membership and
+    order, not by the tail: entries appended after them change nothing."""
+    ROOT, BENCH = _miss_cases.ROOT, _miss_cases.BENCH
+    traffic, BASE, IV = _miss_cases.traffic, _miss_cases.BASE, _miss_cases.IV
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    confs = {c["name"]: c for c in bench["configs"]}
+    cells = {w["name"]: w for w in bench["workloads"]}
+    conf, cell = confs["promdev_prom_miss_1m"], cells["adhoc_prom_miss"]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "promdev_prom_miss_1m", "adhoc", 1)
+    for entries, first, then in (
+            (bench["configs"], confs["promdev_prom_1m"], conf),
+            (bench["workloads"], cells["adhoc_prom"], cell)):
+        assert entries.index(first) < entries.index(then)
+    with open(os.path.join(ROOT, conf["file"])) as f:
+        d = json.load(f)
+    with open(os.path.join(ROOT, confs["promdev_prom_1m"]["file"])) as f:
+        prom = json.load(f)
+    assert d["source"] == conf["source"] and len(d["source"]) <= 200
+    assert "scrape.go" in d["source"] and "StaleNaN" in d["source"] \
+        and "timeseries-dev-source.conf" in d["source"]
+    assert d["source"] != prom["source"]
+    assert d["reduced"] == conf["reduced"] == [] and d["architecture"] is None
+    for key in ("server", "series", "metric", "labels", "scrape_interval_ms",
+                "fill_columns", "containers_per_scrape"):
+        assert d[key] == prom[key], key
+    assert d["data"] == "prom_miss" and "GB" in conf["why"]
+    stated = dict(d["guarantees"])
+    assert stated.pop("holes").startswith("a missed scrape is not a sample")
+    assert stated == prom["guarantees"]
+    assumed = dict(d["assumed"])
+    for key in ("stream", "markers", "hole_runs"):
+        assert key in assumed
+    assert "0 mod 128" in assumed["stream"] and "k = 0" in assumed["stream"]
+    assert "departure" in assumed["markers"]
+    for key in ("stamp_law", "samples_per_series", "targets", "values",
+                "scrape_ms", "fill_columns"):
+        assert assumed[key] == prom["assumed"][key], key
+    metrics = {m["name"]: m for m in bench["per_layer"] + bench["end_to_end"]}
+    for name in ("query_p50_ms", "kernel_roofline_pct", "leaf_ms",
+                 "demoted_rows_pct"):
+        lists = metrics[name]["workloads"]
+        assert lists.index("adhoc_prom") + 1 == lists.index(
+            "adhoc_prom_miss"), name
+    assert metrics["demoted_rows_pct"]["workloads"] == [
+        "adhoc_prom", "adhoc_prom_miss"]
+    assert metrics["hole_cells_pct"] == {
+        "name": "hole_cells_pct", "unit": "%", "better": "lower",
+        "source": "program_span", "layer": "fused kernel",
+        "moves": "query_rate", "workloads": ["adhoc_prom_miss"]}
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names.index("demoted_rows_pct") + 1 == names.index(
+        "hole_cells_pct")
+    # promdev_prom_1m's own, as its file's case has them
+    pc, pw = confs["promdev_prom_1m"], cells["adhoc_prom"]
+    assert (pw["config"], pw["traffic"], pw["chips"]) == (
+        "promdev_prom_1m", "adhoc", 1)
+    assert prom["source"] == pc["source"] and prom["data"] == "prom"
+    assert prom["reduced"] == pc["reduced"] == []
+    assert "no missed scrape" in prom["assumed"]["stream"]
+    with open(os.path.join(BENCH, "configs", "promdev_raw_1m.json")) as f:
+        raw = json.load(f)
+    assert len(prom["source"]) <= 200 and "scrape.go" in prom["source"] \
+        and "2 ms" in prom["source"] \
+        and "timeseries-dev-source.conf" in prom["source"]
+    assert prom["architecture"] is None
+    for key in ("server", "series", "metric", "labels", "scrape_interval_ms",
+                "fill_columns", "containers_per_scrape"):
+        assert prom[key] == raw[key], key
+    stated = dict(prom["guarantees"])
+    assert stated.pop("stamps") == ("a sample is stored under the stamp it "
+                                    "came with; a raw selector returns that "
+                                    "stamp")
+    assert stated == raw["guarantees"]
+    assert {"stamp_law", "samples_per_series", "targets", "stream", "values",
+            "scrape_ms"} <= set(prom["assumed"])
+    assert {k: v for k, v in metrics["demoted_rows_pct"].items()
+            if k != "workloads"} == {
+        "name": "demoted_rows_pct", "unit": "%", "better": "lower",
+        "source": "program_span", "layer": "fused kernel",
+        "moves": "query_rate"}
+    mix = traffic.load("adhoc")
+    assert mix["expect_routes"] == ["fused"]
+    gen = traffic.Generator(mix, 5, BASE + 720 * IV)
+    assert all(r.end_ms <= BASE + 720 * IV for r in gen.warmup())
+    for f in ("data/prom_miss/__init__.py", "data/prom_miss/datagen.py",
+              "data/prom_miss/fill.py", "data/prom_miss/reference.py",
+              "data/prom/__init__.py", "layers/hole_cells_pct.py",
+              "layers/demoted_rows_pct.py", "control_holes.py",
+              "control_stamps.py", "configs/promdev_prom_miss_1m.json",
+              "configs/promdev_prom_1m.json"):
+        assert os.path.isfile(os.path.join(BENCH, f)), f

@@ -169,20 +169,104 @@ def test_lock_hold_watchdog_flags_wedged_holder(monkeypatch):
         diagnostics.enable_lock_debug(was)
 
 
-def test_lock_hold_histogram_records():
-    """Under FILODB_LOCK_DEBUG=1 every first-depth release lands one
-    observation in filodb_lock_hold_ms tagged with the lock class."""
-    from filodb_tpu.utils.metrics import FILODB_LOCK_HOLD_MS, registry
+def test_lock_hold_ns_counts_first_depth_holds_per_thread_at_release():
+    """The holder's twin of ``lock_wait_ns``: what THIS thread held shard
+    locks for, added when a first-depth hold is released. A re-entry's
+    release adds nothing, another thread's hold is its own, and a lock of
+    another class (a group-flush lock around a shard lock) counts nothing."""
+    lock = diagnostics.TimedRLock("t", order_class="shard")
+    before = diagnostics.lock_hold_ns()
+    with lock:
+        assert lock.holder == threading.current_thread().name
+        with lock:
+            time.sleep(0.02)
+        assert diagnostics.lock_hold_ns() == before     # inner release
+        time.sleep(0.02)
+        assert diagnostics.lock_hold_ns() == before     # still held
+    mine = diagnostics.lock_hold_ns() - before
+    assert mine >= 40_000_000
+    assert abs(mine / 1e9 - lock.hold_s) < 1e-6         # the one hold, once
 
-    was = diagnostics.lock_debug
-    diagnostics.enable_lock_debug(True)
+    got = {}
+
+    def other():
+        t0 = diagnostics.lock_hold_ns()
+        with lock:
+            got["holder"] = lock.holder
+            time.sleep(0.05)
+        got["ns"] = diagnostics.lock_hold_ns() - t0
+
+    th = threading.Thread(target=other, name="the-other")
+    th.start()
+    th.join(5)
+    assert not th.is_alive()
+    assert got["holder"] == "the-other" and got["ns"] >= 50_000_000
+    assert diagnostics.lock_hold_ns() - before == mine  # not this thread's
+    assert abs((mine + got["ns"]) / 1e9 - lock.hold_s) < 1e-6
+
+    outer = diagnostics.TimedRLock("g", order_class="group_flush")
+    with outer:
+        time.sleep(0.01)
+    assert outer.hold_s >= 0.01
+    assert diagnostics.lock_hold_ns() - before == mine
+
+
+def test_inflight_counts_a_dispatch_until_it_is_fetched_or_dropped():
+    """``ahead`` is the count as a dispatch entered; a fetch gives its place
+    back, once; a handle dropped unfetched (an error between dispatch and
+    fetch) gives it back as it is collected; the oldest one's age is kept."""
+    q = diagnostics.InflightPrograms()
+    assert q.count == 0 and q.oldest_age_s() is None
+    a = q.dispatched()
+    time.sleep(0.01)
+    b = q.dispatched()
+    assert (a.ahead, b.ahead, q.count) == (0, 1, 2)
+    assert q.oldest_age_s() >= 0.01
+    a.fetched()
+    a.fetched()                                         # idempotent
+    assert q.count == 1 and q.oldest_age_s() < 0.01 + 1.0
+    c = q.dispatched()
+    assert c.ahead == 1
+    del b                                               # dropped unfetched
+    assert q.count == 1
+    c.fetched()
+    assert q.count == 0 and q.oldest_age_s() is None
+
+
+def test_inflight_count_loses_no_update_under_contending_threads():
+    """More threads than cores, a short switch interval: every dispatch is
+    counted once and given back once, and no ``ahead`` passes the number of
+    other threads."""
+    import os
+    import sys
+    q = diagnostics.InflightPrograms()
+    n_threads, rounds = 2 * (os.cpu_count() or 4), 400
+    worst, errors = [], []
+
+    def worker():
+        top = 0
+        try:
+            for i in range(rounds):
+                h = q.dispatched()
+                top = max(top, h.ahead)
+                if i % 7:
+                    h.fetched()
+                else:
+                    del h                               # the dropped handle
+        except Exception as e:  # noqa: BLE001 — reported below
+            errors.append(e)
+        worst.append(top)
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
     try:
-        h = registry.histogram(FILODB_LOCK_HOLD_MS, {"class": "sink"})
-        before = h.count
-        lk = diagnostics.TimedRLock("hist-test", order_class="sink")
-        with lk:
-            with lk:        # reentrant acquire must not double-record
-                pass
-        assert h.count == before + 1
+        threads = [threading.Thread(target=worker) for _ in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(30)
     finally:
-        diagnostics.enable_lock_debug(was)
+        sys.setswitchinterval(was)
+    assert not errors and not any(t.is_alive() for t in threads)
+    assert q.count == 0 and q._serial == n_threads * rounds
+    assert len(worst) == n_threads and max(worst) <= n_threads - 1

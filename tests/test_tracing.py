@@ -1,7 +1,8 @@
 """Tracer mechanics (no device): monotonic starts and durations, nested and
 cross-thread parentage, root-decided sampling, ring bounds, Zipkin shape,
 intervals recorded after the fact; and the served query's spans through an
-in-process server: one root, the profiler's clock, lock wait, the gc hook."""
+in-process server: one root, the profiler's clock, lock wait and hold, the
+device queue ahead of a dispatch, the gc hook, the heartbeat."""
 
 import gc
 import glob
@@ -13,11 +14,14 @@ import urllib.request
 
 import pytest
 
+from filodb_tpu.utils import diagnostics
 from filodb_tpu.utils.tracing import (SPAN_HTTP_RENDER, SPAN_HTTP_REQUEST,
+                                      SPAN_INGEST_CONSUME, SPAN_INGEST_FLUSH,
                                       SPAN_QUERY, SPAN_QUERY_GROUPIDS,
                                       SPAN_QUERY_KERNEL, SPAN_QUERY_LEAF,
                                       SPAN_QUERY_QUEUE, SPAN_QUERY_SELECT,
-                                      SPAN_RUNTIME_GC, Tracer, tracer)
+                                      SPAN_RUNTIME_BEAT, SPAN_RUNTIME_GC,
+                                      Tracer, _Heartbeat, tracer)
 
 
 @pytest.fixture()
@@ -379,6 +383,9 @@ def test_http_query_is_one_trace_rooted_at_the_request(served):
         + by[SPAN_QUERY_GROUPIDS].duration_us + disp.duration_us
     assert inner <= leaf.duration_us + 3
     assert leaf.tags["lock_wait_ms"] == 0
+    # nothing was in flight before this query's one program, and its fetch
+    # gave the place back
+    assert disp.tags["ahead"] == 0 and diagnostics.inflight.count == 0
 
 
 @pytest.mark.parametrize("end_s, fall_tiles", [
@@ -388,9 +395,10 @@ def test_http_query_is_one_trace_rooted_at_the_request(served):
 def test_fused_hist_route_is_a_leaf_with_its_parts_and_a_fetch(end_s,
                                                                fall_tiles):
     """The fused-hist route (query/engine.py ``_try_fused_hist``) records
-    what the ExecPlan leaf records: ``query.exec.leaf`` (tag
-    ``lock_wait_ms``) > select, group ids, kernel dispatch (the tiled raw
-    hist kernel's tags, ``packed`` among them), and the kernel's fetch
+    what the ExecPlan leaf records: ``query.exec.leaf`` (tags
+    ``lock_wait_ms``, ``lock_hold_ms``) > select, group ids, kernel
+    dispatch (``ahead``, the tiled raw hist kernel's tags, ``packed``
+    among them), and the kernel's fetch
     beside the leaf, after it and outside the lock (tag ``fall_tiles``: the
     tiles that ran the correction matmul) — the sums the benchmark's means
     are read from."""
@@ -449,11 +457,14 @@ def test_fused_hist_route_is_a_leaf_with_its_parts_and_a_fetch(end_s,
     assert sel.parent_id == gid.parent_id == disp.parent_id == leaf.span_id
     assert fetch.parent_id == leaf.parent_id
     assert fetch.start_ns >= leaf.start_ns + (leaf.duration_us - 2) * 1e3
+    assert 0 < leaf.tags.pop("lock_hold_ms") <= leaf.duration_us / 1e3
     assert leaf.tags == {"shard": 0, "lock_wait_ms": 0}
     assert sel.tags["series"] == N_SERIES
     assert gid.tags == {"keys": N_SERIES, "groups": 4, "route": "walk"}
+    assert diagnostics.inflight.count == 0          # the fetch gave it back
     assert disp.tags == {
-        "phase": "dispatch", "kernel": fusedresident.tag(), "rows": 32,
+        "phase": "dispatch", "ahead": 0, "kernel": fusedresident.tag(),
+        "rows": 32,
         "c0": 0, "cols": 128, "steps": 51, "groups": 4, "buckets": 8,
         "variant": "hist-raw", "packed": 1}
     assert fetch.tags == {"phase": "fetch", "fall_tiles": fall_tiles}
@@ -605,6 +616,150 @@ def test_leaf_tags_the_wait_for_a_held_shard_lock(served):
         assert float(line.rsplit(" ", 1)[1]) > 0, line
 
 
+def test_every_span_that_tags_a_lock_wait_tags_the_hold(served):
+    """``lock_hold_ms`` sits wherever ``lock_wait_ms`` does: what the span's
+    thread held shard locks for, counted as each hold is released. A hold
+    released inside a nested span is in both tags: the leaf's is in its
+    query's, which holds the epoch probe's beside it."""
+    from filodb_tpu.core.record import RecordBuilder
+    from filodb_tpu.core.schemas import GAUGE
+    srv, get = served
+    shard = srv.memstore.shards_of("prometheus")[0]
+    get()                                   # compiled
+    _trace_of_last_query()
+    tracer.drain()
+    held0 = shard.lock.hold_s
+    get(shift_ms=1_000)
+    by = {s.name: s for s in _trace_of_last_query()}
+    leaf, query = by[SPAN_QUERY_LEAF].tags, by[SPAN_QUERY].tags
+    assert 0 < leaf["lock_hold_ms"] <= query["lock_hold_ms"]
+    assert leaf["lock_hold_ms"] <= by[SPAN_QUERY_LEAF].duration_us / 1e3
+    assert query["lock_hold_ms"] <= by[SPAN_QUERY].duration_us / 1e3
+    # the lock's own total grew by what the query's thread says it held
+    assert abs((shard.lock.hold_s - held0) * 1e3
+               - query["lock_hold_ms"]) < 1e-3
+    b = RecordBuilder(GAUGE)
+    for i in range(N_SERIES):
+        b.add({"_metric_": "m", "host": f"h{i}", "g": f"g{i % 4}"},
+              BASE + N_SAMPLES * 10_000, 1.0)
+    srv.memstore.ingest("prometheus", 0, b.build())
+    srv.memstore.flush_all()
+    spans = tracer.snapshot()
+    (flush,) = [s for s in spans if s.name == SPAN_INGEST_FLUSH]
+    assert 0 < flush.tags["lock_hold_ms"] <= flush.duration_us / 1e3
+    tagged = [s for s in spans if "lock_wait_ms" in s.tags]
+    assert {s.name for s in tagged} == {SPAN_QUERY, SPAN_QUERY_LEAF,
+                                        SPAN_INGEST_FLUSH}
+    assert all("lock_hold_ms" in s.tags for s in tagged)
+
+
+def test_consume_span_tags_the_holds_of_the_flushes_inside_it(tmp_path):
+    """The consumer's drain holds the shard lock to stage rows and, through
+    the flush nested in it, to land them: its ``lock_hold_ms`` has both."""
+    from filodb_tpu.config import Config
+    from filodb_tpu.core.record import RecordBuilder
+    from filodb_tpu.core.schemas import GAUGE
+    from filodb_tpu.ingest.bus import FileBus
+    from filodb_tpu.standalone import FiloServer
+    srv = FiloServer(Config({
+        "num_shards": 1, "http": {"port": 0}, "bus_dir": str(tmp_path),
+        "store": {"max_series_per_shard": 32, "samples_per_series": 128,
+                  "flush_batch_size": 8}})).start()
+    try:
+        tracer.drain()
+        b = RecordBuilder(GAUGE)
+        for i in range(N_SERIES):
+            b.add({"_metric_": "m", "host": f"h{i}"}, BASE, float(i))
+        FileBus(str(tmp_path / "shard0.log")).publish(b.build())
+        deadline = time.monotonic() + 10
+        while time.monotonic() < deadline:
+            spans = tracer.snapshot()
+            drains = [s for s in spans if s.name == SPAN_INGEST_CONSUME]
+            if drains:
+                break
+            time.sleep(0.05)
+    finally:
+        srv.shutdown()
+    (drain,) = drains
+    assert drain.tags["rows"] == N_SERIES
+    inside = [s for s in spans if s.name == SPAN_INGEST_FLUSH
+              and s.parent_id == drain.span_id]
+    assert inside, [s.name for s in spans]
+    assert 0 < sum(s.tags["lock_hold_ms"] for s in inside) \
+        <= drain.tags["lock_hold_ms"] <= drain.duration_us / 1e3
+
+
+def test_mesh_leaf_tags_the_sum_over_its_locks_and_how_many():
+    """The mesh route's one leaf takes every shard's lock: ``lock_hold_ms``
+    is the sum over them, ``locks`` how many, so that a reader can give a
+    per-lock figure; the dispatch is counted in flight until its fetch."""
+    from filodb_tpu.query.engine import QueryEngine
+
+    from .test_distributed import START, build_f32_store
+    mesh, ms, shards = build_f32_store()
+    eng = QueryEngine(ms, "prometheus", mesh=mesh)
+    before = [sh.lock.hold_s for sh in shards]
+    tracer.drain()
+    r = eng.query_range("sum(rate(m[5m]))", START + 300_000, START + 500_000,
+                        20_000)
+    assert r.exec_path == "mesh[pjit]-fused"
+    by = {}
+    for s in tracer.snapshot():
+        by.setdefault(s.name, []).append(s)
+    (leaf,), (query,) = by[SPAN_QUERY_LEAF], by[SPAN_QUERY]
+    assert leaf.tags["route"] == "mesh" and leaf.tags["locks"] == len(shards)
+    grown = sum(sh.lock.hold_s - b for sh, b in zip(shards, before)) * 1e3
+    assert 0 < leaf.tags["lock_hold_ms"] <= query.tags["lock_hold_ms"]
+    assert abs(query.tags["lock_hold_ms"] - grown) < 1e-3
+    # every lock is held for about the leaf's length, so their sum is
+    # about ``locks`` times one lock's share
+    assert leaf.tags["lock_hold_ms"] / leaf.tags["locks"] \
+        <= leaf.duration_us / 1e3
+    (disp,) = [k for k in by[SPAN_QUERY_KERNEL]
+               if k.tags["phase"] == "dispatch"]
+    assert disp.tags["ahead"] == 0 and diagnostics.inflight.count == 0
+
+
+def test_a_dispatch_is_in_flight_until_fetched_batched_or_dropped():
+    """The in-flight count across the fused tier's handles: ``ahead`` of the
+    second of two unfetched dispatches is 1; a ``resolve()`` gives a place
+    back, so does the batched fetch of a cross-shard merge (``parts_of``),
+    so does a handle dropped unfetched."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from filodb_tpu.ops import fusedgrid
+    from filodb_tpu.query.exec import AggPartial, _merge_partials
+    S, C, iv = 8, 128, 10_000
+    val = jnp.asarray(np.cumsum(np.ones((S, C), np.float32), axis=1))
+    n = jnp.full(S, C, jnp.int32)
+    gids = jnp.zeros(S, jnp.int32)
+    out_ts = np.arange(BASE + 300_000, BASE + 800_001, iv, dtype=np.int64)
+
+    def dispatch():
+        return fusedgrid.fused_grid_aggregate(
+            "sum", "rate", val, n, gids, 1, out_ts, 120_000, BASE, iv,
+            fetch=False)
+
+    assert diagnostics.inflight.count == 0
+    tracer.drain()
+    a, b = dispatch(), dispatch()
+    aheads = [s.tags["ahead"] for s in tracer.snapshot()
+              if s.name == SPAN_QUERY_KERNEL]
+    assert aheads == [0, 1] and diagnostics.inflight.count == 2
+    want = a.resolve()["sum"]
+    assert diagnostics.inflight.count == 1
+    c = dispatch()
+    merged = _merge_partials("sum", [
+        AggPartial("sum", out_ts, p, ["k"], 1, None) for p in (b, c)])
+    assert diagnostics.inflight.count == 0              # the batched fetch
+    np.testing.assert_allclose(merged.parts["sum"][:1], 2 * want)
+    d = dispatch()
+    assert diagnostics.inflight.count == 1
+    del d                                               # never fetched
+    assert diagnostics.inflight.count == 0
+
+
 def test_gc_hook_records_full_collections_and_leaves_with_the_server(served):
     srv, _get = served
     assert tracer._on_gc in gc.callbacks
@@ -620,6 +775,213 @@ def test_gc_hook_records_full_collections_and_leaves_with_the_server(served):
     tracer.drain()
     gc.collect()
     assert not [s for s in tracer.snapshot() if s.name == SPAN_RUNTIME_GC]
+
+
+# -- the heartbeat ------------------------------------------------------------
+
+def _beat_threads():
+    return [t for t in threading.enumerate() if t.name == "trace-heartbeat"]
+
+
+def _beats(spans):
+    return [s for s in spans if s.name == SPAN_RUNTIME_BEAT]
+
+
+def test_heartbeat_is_shared_like_the_gc_hook_and_beats_once_a_second():
+    """Two servers, one heartbeat thread, gone after the last shutdown; one
+    ``runtime.beat`` a second of ~50 wake-ups, whose interval is the worst
+    wake-up (not the second) and whose lock tags are the LOCK's side: a
+    hold by a thread that opens no span is in them."""
+    from filodb_tpu.config import Config
+    from filodb_tpu.standalone import FiloServer
+
+    def server():
+        return FiloServer(Config({
+            "num_shards": 1, "http": {"port": 0},
+            "store": {"max_series_per_shard": 32, "samples_per_series": 128,
+                      "flush_batch_size": 10**9}})).start()
+    assert not _beat_threads()
+    a = server()
+    try:
+        b = server()
+        try:
+            assert len(_beat_threads()) == 1
+            tracer.drain()
+            lock = a.memstore.shards_of("prometheus")[0].lock
+            with lock:                      # no span around this hold
+                time.sleep(0.3)
+            deadline = time.monotonic() + 4     # two beats: ~2 s
+            while time.monotonic() < deadline:
+                beats = _beats(tracer.snapshot())
+                if len(beats) >= 2 and sum(
+                        s.tags["lock_hold_ms"] for s in beats) >= 290:
+                    break
+                time.sleep(0.05)
+        finally:
+            b.shutdown()
+        assert len(_beat_threads()) == 1    # the first server's still
+    finally:
+        a.shutdown()
+    assert not _beat_threads()
+    assert 2 <= len(beats) <= 3, beats
+    for s in beats:
+        t = s.tags
+        assert 20 <= t["ticks"] <= 50, t    # fewer under a loaded host
+        assert 1000 <= t["period_ms"] < 1500 and t["inflight"] == 0
+        assert 0 <= t["late_ms"] <= t["period_ms"] - 20 * t["ticks"] + 1
+        assert s.duration_us / 1e3 <= t["late_ms"] + 1e-3   # the worst one
+        assert "stall" not in t and t["lock"] == "shard-0-lock"
+    assert 290 <= sum(s.tags["lock_hold_ms"] for s in beats) <= 600
+    assert max(s.tags["lock_hold_ms"] for s in beats) >= 150
+
+
+def test_no_heartbeat_and_no_beat_with_tracing_off(tr):
+    """``trace.enabled: false`` starts no heartbeat thread; a heartbeat
+    whose tracer is switched off under it counts and records nothing."""
+    from filodb_tpu.config import Config
+    from filodb_tpu.standalone import FiloServer
+    was = tracer.enabled
+    try:
+        srv = FiloServer(Config({
+            "num_shards": 1, "http": {"port": 0}, "trace": {"enabled": False},
+            "store": {"max_series_per_shard": 32, "samples_per_series": 128,
+                      "flush_batch_size": 10**9}})).start()
+        try:
+            assert not tracer.enabled and not _beat_threads()
+        finally:
+            srv.shutdown()
+    finally:
+        tracer.enabled = was
+    tr.enabled = False
+    beat = _Heartbeat(tr)
+    for k in range(1, 120):                 # 2.4 s on a clock of its own
+        beat.tick(beat._t0 + k * 20_000_000, beat._t0 + k * 20_000_000 + 10)
+    assert not tr.snapshot() and beat._ticks == 0
+
+
+def test_beat_is_the_seconds_worst_wakeup_on_a_stubbed_clock(tr):
+    """``tick`` takes its clock readings as arguments. 45 wake-ups 0.1 ms
+    late and one 30 ms late make one beat: ``late_ms`` their sum, the
+    interval the worst one alone (due -> woke), so that an idle gap it names
+    is one in which the interpreter really was not to be had."""
+    lock = diagnostics.TimedRLock("shard-7-lock", order_class="shard")
+    quiet = diagnostics.TimedRLock("shard-8-lock", order_class="shard")
+    tr._beat_probes.append(lambda: [lock, quiet])
+    beat = _Heartbeat(tr)
+    t0, due, worst = beat._t0, beat._t0, None
+    with lock:
+        pass                                # held a moment: the busiest
+    for k in range(45):
+        due += 20_100_000
+        late = 30_000_000 if k == 20 else 100_000
+        if k == 20:
+            worst = due
+        beat.tick(due, due + late)
+        due += late - 100_000
+    assert not tr.snapshot()                # 45 x 20.1 ms + 29.9 < a second
+    beat.tick(t0 + 1_000_000_000, t0 + 1_000_100_000)
+    (rec,) = tr.snapshot()
+    assert rec.name == SPAN_RUNTIME_BEAT and rec.parent_id is None
+    assert (rec.start_ns, rec.duration_us) == (worst, 30_000)
+    t = rec.tags
+    assert t["ticks"] == 46 and abs(t["late_ms"] - (45 * 0.1 + 30)) < 1e-6
+    assert t["period_ms"] == 1000.1 and t["inflight"] == 0
+    assert t["lock"] == "shard-7-lock"
+    assert abs(t["lock_hold_ms"] - lock.hold_s * 1e3) < 1e-9
+    assert "stall" not in t
+    # the next period starts from the locks' totals as they stand
+    beat.tick(t0 + 2_000_100_000, t0 + 2_000_100_000)
+    assert tr.snapshot()[-1].tags["lock_hold_ms"] == 0
+
+
+def test_heartbeat_reads_a_late_wakeup_while_a_python_loop_holds_the_gil(tr):
+    """A thread coming out of its sleep needs the GIL back as a worker
+    coming out of a device fetch does: with a pure-Python loop holding it
+    for a 20 ms switch interval at a time, the mean wake-up is milliseconds
+    (it is tens of microseconds in a quiet process)."""
+    import sys
+    stop = threading.Event()
+
+    def spin():
+        x = 0
+        while not stop.is_set():
+            x += 1
+
+    probe = list
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(0.02)
+    busy = threading.Thread(target=spin)
+    try:
+        busy.start()
+        tr.start_heartbeat(probe)
+        deadline = time.monotonic() + 5
+        while not _beats(tr.snapshot()) and time.monotonic() < deadline:
+            time.sleep(0.05)
+    finally:
+        sys.setswitchinterval(was)
+        stop.set()
+        busy.join(5)
+        tr.stop_heartbeat(probe)
+    assert not busy.is_alive() and not _beat_threads()
+    t = _beats(tr.snapshot())[0].tags
+    assert t["late_ms"] / t["ticks"] >= 2.0, t
+    assert "lock" not in t                  # no server, no shard lock
+
+
+def test_a_wakeup_over_a_second_late_is_a_stall_with_what_stood(tr, caplog):
+    """A stubbed wake-up 1.5 s late: the beat carries ``stall`` = 1, the
+    shard lock that is held, its holder and since when, the oldest
+    unfetched dispatch's age, whether a full collection overlapped; ONE
+    warning says the same and ``filodb_runtime_stalls_total`` counts it."""
+    import logging
+
+    from filodb_tpu.utils.metrics import FILODB_RUNTIME_STALLS, registry
+    lock = diagnostics.TimedRLock("shard-3-lock", order_class="shard")
+    free = diagnostics.TimedRLock("shard-4-lock", order_class="shard")
+    tr._beat_probes.append(lambda: [free, lock])
+    beat = _Heartbeat(tr)
+    stalls = registry.counter(FILODB_RUNTIME_STALLS)
+    n0 = stalls.value
+    held, release = threading.Event(), threading.Event()
+
+    def holder():
+        with lock:
+            held.set()
+            release.wait(5)
+
+    th = threading.Thread(target=holder, name="flush-of-shard-3")
+    th.start()
+    ticket = diagnostics.inflight.dispatched()
+    try:
+        assert held.wait(5)
+        time.sleep(0.02)
+        due = beat._t0 + 20_000_000
+        with caplog.at_level(logging.WARNING, logger="filodb_tpu.trace"):
+            beat.tick(due, due + 1_500_000_000)
+            # a full collection inside the next late wake-up
+            tr._gc_last = (due + 1_600_000_000, due + 1_900_000_000)
+            beat.tick(due + 1_520_000_000, due + 3_000_000_000)
+    finally:
+        ticket.fetched()
+        release.set()
+        th.join(5)
+    assert not th.is_alive()
+    first, second = _beats(tr.snapshot())
+    t = first.tags
+    assert (first.start_ns, first.duration_us) == (due, 1_500_000)
+    assert t["stall"] == 1 and t["gc"] == 0 and t["ticks"] == 1
+    assert (t["held_lock"], t["holder"]) == ("shard-3-lock",
+                                             "flush-of-shard-3")
+    assert 20 <= t["held_ms"] < 5000 and 20 <= t["oldest_dispatch_ms"] < 5000
+    assert t["inflight"] >= 1 and t["late_ms"] == 1500.0
+    assert second.tags["stall"] == 1 and second.tags["gc"] == 1
+    warned = [r for r in caplog.records if r.name == "filodb_tpu.trace"]
+    assert len(warned) == 2 and stalls.value == n0 + 2
+    text = warned[0].getMessage()
+    for part in ("1500 ms late", "shard-3-lock by flush-of-shard-3",
+                 "oldest unfetched dispatch", "full collection inside it: no"):
+        assert part in text, text
+    assert "full collection inside it: yes" in warned[1].getMessage()
 
 
 @pytest.mark.parametrize("missed", (0, 3))
