@@ -36,6 +36,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..utils.diagnostics import inflight
+from ..utils.metrics import FILODB_QUERY_FUSED_FALL_TILES, registry
 from ..utils.tracing import SPAN_QUERY_KERNEL, span
 from . import decodereg, gridfns
 
@@ -187,8 +188,88 @@ def group_fold(gid, G: int, contrib, okf, needs_sumsq: bool):
     return out
 
 
+def counts_falls(fn: str, line) -> bool:
+    """Does the program telescope its sure range's delta, and so return
+    its fallen tiles beside its partial state (:func:`tile_fell`)? The
+    rate family on a line store."""
+    return bool(line) and fn in FUSED_FNS
+
+
+def count_fall_tiles(falls, kernel: str, mode: str) -> int:
+    """A fetched ``[1]`` count of the tiles that fell (``kernel``: "line",
+    this module's rate programs on a line store; "hist", the raw hist
+    tier's correction matmul), added to the registry and returned."""
+    k = int(np.asarray(falls)[0])
+    registry.counter(FILODB_QUERY_FUSED_FALL_TILES,
+                     {"kernel": kernel, "mode": mode}).increment(k)
+    return k
+
+
+def sublane_max(a):
+    """``[M, Ca]`` -> ``[8, Ca]``: the maximum over whole sublane tiles, all
+    elementwise (M a multiple of 8)."""
+    return functools.reduce(jnp.maximum,
+                            [a[i:i + 8] for i in range(0, a.shape[0], 8)])
+
+
+def tile_fell(drops, c0: int, first, last, ends):
+    """Must a rate tile of the line form sum its increments cell by cell?
+    One scalar. The sure range's delta is the difference of the range's
+    last and first sample (Prometheus's own ``last - first``) unless,
+    among the cells some window sums, a counter fell — ``drops [Sb, Ca]``
+    is above 0 where a sample lies below the one before it (None: the
+    function does not clip), and the cells that count are those from the
+    least of ``first [1, Tp]`` to the most of ``last [1, Tp]`` over the
+    steps whose range holds one, the tile's first column never (its
+    neighbour is the roll's wrap) — or a row ends under a window (``ends
+    [Sb, Tp]``: the picked last value is then not the row's last sample).
+    ``drops`` comes of the MASKED values, plainly: it also reads above 0
+    in the cell after a row's last sample, which is a row that ends under
+    a window if a window sums that cell. A fall reported where no window
+    sums it costs the tile the band product and no answer. A difference,
+    an elementwise sublane maximum and two reduces: it rides beside the
+    picks' matmul (fusedresident.raw_hist_drops)."""
+    f32, i32 = jnp.float32, jnp.int32
+    fell = jnp.max(jnp.where(ends, 1.0, 0.0)) > 0.0
+    if drops is None:
+        return fell
+    some = last >= first
+    c_lo = jnp.min(jnp.where(some, first, _NEVER))
+    c_hi = jnp.max(jnp.where(some, last, -1))
+    d8 = sublane_max(drops)
+    col = jax.lax.broadcasted_iota(i32, d8.shape, 1) + c0
+    used = (col >= c_lo) & (col <= c_hi) & (col > c0)
+    return fell | (jnp.max(jnp.where(used, d8, f32(0.0))) > 0.0)
+
+
+def fallen_fold(need, fold, zeros):
+    """A line rate tile's partial state by the form the tile needs, for
+    both backends: ``fold(form) -> (parts, fell)`` is the whole tile — its
+    operands read, :func:`tile_contrib` in that form, :func:`group_fold` —
+    and ``need`` says that the tile BEFORE this one fell. Then the tile
+    runs in the band form at once; else telescoped, and again in the band
+    form if it fell (the telescoped answer is dropped). Where counters
+    fall tile after tile the program so costs what it cost before the
+    delta was telescoped, the test beside it; where none falls, the
+    telescoped tile alone, ONE basic block with no branch inside it (a
+    branch between a tile's matmuls and its ``[Sb, Tp]`` algebra costs
+    what telescoping saves: the two no longer overlap). Returns ``(parts,
+    ran_band, fell)``: the state to add, whether the band form ran (what
+    ``fall_tiles`` counts) and the next tile's ``need``."""
+    def telescoped():
+        parts, fell = fold("tel")
+        return tuple(jnp.where(fell, 0.0, p) for p in parts), fell
+
+    first, ran_band = jax.lax.cond(need, lambda: (zeros, jnp.bool_(True)),
+                                   telescoped)
+    second, fell = jax.lax.cond(ran_band, lambda: fold("band"),
+                                lambda: (zeros, jnp.bool_(False)))
+    return tuple(a + b for a, b in zip(first, second)), ran_band, fell
+
+
 def _line_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
-                  v, n, band, ohe, lo, hi, rel, roll, start, res, eb):
+                  v, n, band, ohe, lo, hi, rel, roll, start, res, eb,
+                  form: str = "band"):
     """:func:`tile_contrib` on a line store: window membership and the
     extrapolation's durations from each row's TRUE stamps, ``start[s] + c
     * interval + res[s, c]`` (relative to the selection's base).
@@ -207,7 +288,18 @@ def _line_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     ``ohe`` says by its width how its slots lie (see ``EDGE_SLOTS``): a
     block each, or two a block. A packed slot's plane comes out with the
     other half's numbers in lanes 64 on; no step lives there (``hi`` is -1
-    and ``eb`` never met), so they are masked like any padded step."""
+    and ``eb`` never met), so they are masked like any padded step.
+
+    The rate family has two ``form``s of one tile and returns a third
+    value, a scalar: whether the tile FELL (:func:`tile_fell`). "band" is
+    the tile as it always was: the sure range's delta a band product over
+    the increments. "tel" TELESCOPES it, ``v[hi] - v[max(lo, 0)]``, both
+    picked anyway — no increment plane, no split of it, no band product,
+    and nothing of a row that ends under the window (its last cell's
+    residual is a reduce along the row) — and is right wherever the tile
+    did not fall; where it did, the caller runs "band" over the same tile
+    and drops this answer. The window functions have one form and return
+    two values."""
     f32, i32 = jnp.float32, jnp.int32
     Sb, Ca = v.shape
     Tp = lo.shape[1]
@@ -272,20 +364,32 @@ def _line_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
 
     vp = dot_exact01(v, w)
     v_a2, v_a1, v_b1, v_b2, v_lo, v_hi = (pick(vp, j) for j in range(6))
-    raw = v - roll(v, 1)
-    mask = valid & (col > 0)
-    if c0:
-        mask &= lcol > 0
-    inc = jnp.where(mask, step(raw), 0.0)
-    delta = dot_exact01(inc, band)                            # (lo, hi]
+    prev = roll(v, 1)
+    # after the picks' matmul, so that the test runs while the MXU does. A
+    # row that ends under the window, in a cell the window may hold: its
+    # v_hi is the masked 0, and its last stamp is its own cell's
+    fell = tile_fell(prev - v if is_counter else None, c0, f_sure + 1, hi,
+                     (n > jnp.maximum(lo - 2, 0)) & (n <= hi))
+    if form == "tel":
+        # a padded step has hi = -1, and an empty range would pick garbage
+        delta = jnp.where(hi > f_sure, v_hi - v_lo, 0.0)
+        r_end = 0.0           # no row of an unfallen tile ends under hi
+    else:
+        mask = valid & (col > 0)
+        if c0:
+            mask &= lcol > 0
+        inc = jnp.where(mask, step(v - prev), 0.0)
+        delta = dot_exact01(inc, band)                        # (lo, hi]
+        # the residual of the row's own last cell, where it ends under the
+        # window
+        r_end = jnp.sum(jnp.where(col == n - 1, rf, 0.0), axis=1,
+                        keepdims=True)
     delta = (delta
              + jnp.where(m_a1 & (lo < n), step(v_lo - v_a1), 0.0)
              + jnp.where(m_a2 & m_a1, step(v_a1 - v_a2), 0.0)
              + jnp.where(m_b1 & (hi >= 0), step(v_b1 - v_hi), 0.0)
              + jnp.where(m_b2, step(v_b2 - v_b1), 0.0))
     f_v = jnp.where(m_a2, v_a2, jnp.where(m_a1, v_a1, v_lo))
-    # the residual of the row's own last cell, where it ends under the window
-    r_end = jnp.sum(jnp.where(col == n - 1, rf, 0.0), axis=1, keepdims=True)
     r_f = jnp.where(m_a2, pick(rp, 0), jnp.where(m_a1, pick(rp, 1),
                                                  pick(rp, 4)))
     r_l = jnp.where(m_b2, pick(rp, 3), jnp.where(
@@ -297,11 +401,12 @@ def _line_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     dur_end = (rel - t_l).astype(f32) / 1000.0
     sampled = (t_l - t_f).astype(f32) / 1000.0
     return _extrapolate(fn, window_ms, delta, f_v, dur_start, dur_end,
-                        sampled, cnt, cnt_f)
+                        sampled, cnt, cnt_f) + (fell,)
 
 
 def _hole_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
-                  v, n, band, ohe, lo, hi, rel, roll, start, res, eb):
+                  v, n, band, ohe, lo, hi, rel, roll, start, res, eb,
+                  form: str = "band"):
     """:func:`_line_contrib` on a line store that has HOLES: a cell of a
     row's line may hold no sample (core/chunkstore.py, the text at
     ``RES_DTYPE``: its residual reads ``RES_HOLE``), in runs of up to
@@ -321,7 +426,15 @@ def _hole_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     sample's value, residual and distance, two shifts by one and two cells
     reaching over a run of three — picked at ``lo`` and ``hi``. A product
     still has a 0/1 operand: values in three bf16 passes, residuals,
-    distances and validity in one."""
+    distances and validity in one.
+
+    The two ``form``s of :func:`_line_contrib`: "tel" takes the sure
+    range's delta as its last sample's value less its first's (the two
+    filled planes picked at ``hi`` and ``lo``), with no pair plane, no
+    band product over it and nothing of a row's own last sample (two
+    reduces along the row); the tile FELL where a pair that starts in
+    some sure range fell, or a row's last sample lies beyond the fills'
+    reach before some ``hi``."""
     from ..core.chunkstore import HOLE_RUN_MAX, RES_HOLE
     assert HOLE_RUN_MAX == 3, "two shifts reach over a run of three"
     f32, i32, bf16 = jnp.float32, jnp.int32, jnp.bfloat16
@@ -411,10 +524,7 @@ def _hole_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     def step(x):              # one increment, counter-corrected like inc
         return jnp.maximum(x, 0.0) if is_counter else x
 
-    # a pair's increment in its EARLIER sample's cell
-    g = jnp.where(valid & (nxt_m > 0), step(nxt_v - v), 0.0)
-    mid_pairs = dot_exact01(g, band)                          # [lo, hi - 1]
-    mid_cnt = dot1(okf, band)
+    mid_cnt = dot1(okf, band)                                 # [lo, hi - 1]
 
     def parts(m):     # (distance in cells, residual: RES_HOLE for none)
         m = m.astype(f32)
@@ -447,22 +557,37 @@ def _hole_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     mid = mid_cnt + at_hi.astype(f32) >= 1.0
     cnt = cnt_f.astype(i32)
 
-    # the row's own last sample (the tile's: a window's cells lie in it)
-    last = jnp.max(jnp.where(valid, col, -1), axis=1, keepdims=True)
-    r_end = jnp.sum(jnp.where(col == last, jnp.where(valid, ri, 0), 0),
-                    axis=1, keepdims=True)
     reach = rhi != RES_HOLE             # a sample within three cells of hi
-    # the pair that leaves the sure range's last sample H: inside the band
-    # when hi is a hole (H < hi) and H has a next sample, and in the window
-    # only if that sample is (b1, else b2: vb at hi + 1 is its value)
-    over = mid & (kh > 0.0) & reach & (hi < last)
-    leave = step(v_b1 - v_hi)
     nin = m_b1 | m_b2
     v_next = jnp.where(m_b1, v_b1, v_b2)
     pin = m_a1 | m_a2
     v_prev = jnp.where(m_a1, v_a1, v_a2)
-    delta = (mid_pairs
-             - jnp.where(over, leave, 0.0)
+    # after the picks' matmuls, so that the test runs while the MXU does. A
+    # sample less its next one's value (0 for none: a row's last sample
+    # reads as a fall, where a window sums the pair it would start); and a
+    # row whose last sample the fill from hi does not reach, while the
+    # window holds a sample of it that would be its last
+    fell = tile_fell(v - nxt_v if is_counter else None, c0, f_sure, hi - 1,
+                     ~reach & (mid | (pin & ~nin)))
+    if form == "tel":
+        delta_mid = jnp.where(mid, v_hi - v_lo, 0.0)
+        last = r_end = 0      # no row of an unfallen tile ends out of reach
+    else:
+        # a pair's increment in its EARLIER sample's cell, cells [lo, hi -
+        # 1]; the row's own last sample (the tile's: a window's cells lie
+        # in it)
+        g = jnp.where(valid & (nxt_m > 0), step(nxt_v - v), 0.0)
+        last = jnp.max(jnp.where(valid, col, -1), axis=1, keepdims=True)
+        r_end = jnp.sum(jnp.where(col == last, jnp.where(valid, ri, 0), 0),
+                        axis=1, keepdims=True).astype(f32)
+        # the pair that leaves the sure range's last sample H: inside the
+        # band when hi is a hole (H < hi) and H has a next sample, and in
+        # the window only if that sample is (b1, else b2: vb at hi + 1 is
+        # its value)
+        over = mid & (kh > 0.0) & reach & (hi < last)
+        delta_mid = dot_exact01(g, band) - jnp.where(
+            over, step(v_b1 - v_hi), 0.0)
+    delta = (delta_mid
              + jnp.where(mid & nin, step(v_next - v_hi), 0.0)
              + jnp.where(mid & pin, step(v_lo - v_prev), 0.0)
              + jnp.where(m_a2 & m_a1, step(v_a1 - v_a2), 0.0)
@@ -479,14 +604,14 @@ def _hole_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     c_l = jnp.where(m_b2, hi + 2, jnp.where(m_b1, hi + 1, jnp.where(
         reach, hi - kh.astype(i32), last)))
     r_l = jnp.where(m_b2, pick(rp, 3), jnp.where(m_b1, pick(rp, 2), jnp.where(
-        reach, rhi, r_end.astype(f32))))
+        reach, rhi, r_end)))
     t_f = c_f * interval_ms + start + r_f.astype(i32)
     t_l = c_l * interval_ms + start + r_l.astype(i32)
     dur_start = (t_f - (rel - window_ms)).astype(f32) / 1000.0
     dur_end = (rel - t_l).astype(f32) / 1000.0
     sampled = (t_l - t_f).astype(f32) / 1000.0
     return _extrapolate(fn, window_ms, delta, f_v, dur_start, dur_end,
-                        sampled, cnt, cnt_f)
+                        sampled, cnt, cnt_f) + (fell,)
 
 
 def _extrapolate(fn, window_ms, delta, f_v, dur_start, dur_end, sampled,
@@ -514,7 +639,7 @@ def _extrapolate(fn, window_ms, delta, f_v, dur_start, dur_end, sampled,
 
 def tile_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
                  v, n, band, ohlo, lo, hi, rel, roll, line=None,
-                 holes: bool = False):
+                 holes: bool = False, form: str = "band"):
     """Shared per-tile window math of the fused tier: decoded values
     ``v [Sb, Ca]`` -> ``(contrib [Sb, Tp]`` with absent cells zeroed,
     ``okf [Sb, Tp]`` presence as f32). ONE definition per tiling plan for
@@ -530,11 +655,14 @@ def tile_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     matmul precision. ``line = (start, res, eb)`` is a line store's tile
     (see :func:`_line_contrib`; ``ohlo`` is then ``ohe``); None is the
     grid, where column c IS cell c of every row. ``holes``: the line store
-    has cells without a sample (:func:`_hole_contrib`)."""
+    has cells without a sample (:func:`_hole_contrib`). The rate family
+    on a line store (:func:`counts_falls`) has two ``form``s of a tile,
+    "tel" and "band", and returns a third value, a scalar: whether the
+    tile fell (:func:`tile_fell`; :func:`fallen_fold` runs the two)."""
     if line is not None:
         contrib = _hole_contrib if holes else _line_contrib
         return contrib(fn, window_ms, interval_ms, c0, v, n, band,
-                       ohlo, lo, hi, rel, roll, *line)
+                       ohlo, lo, hi, rel, roll, *line, form=form)
     f32 = jnp.float32
     Sb, Ca = v.shape
     lcol = jax.lax.broadcasted_iota(jnp.int32, (Sb, Ca), 1)
@@ -606,36 +734,64 @@ def _kernel_body(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
     # the per-row operands arrive lane-major, [1, Sb] (lane_major): the
     # tile math wants them down the sublanes, the fold takes gid as it is
     n_ref, gid_ref = rest[:2]
-    n, tile = _column(n_ref[:]), None                         # [Sb, 1] i32
     if line:        # res [Sb, Ca] int8 ... eb [8, Tp] i32; start rides in n
         (res_ref, band_ref, ohlo_ref, lo_ref, hi_ref, rel_ref,
-         eb_ref, sum_ref, cnt_ref, *maybe_sumsq) = rest[2:]
-        n, start = unpack_start(n)
-        tile = (start, res_ref[:], eb_ref[:])
+         eb_ref, *outs) = rest[2:]
     else:
-        (band_ref, ohlo_ref, lo_ref, hi_ref, rel_ref,
-         sum_ref, cnt_ref, *maybe_sumsq) = rest[2:]
+        band_ref, ohlo_ref, lo_ref, hi_ref, rel_ref, *outs = rest[2:]
+    falls = counts_falls(fn, line)
+    # a line rate program's last output: the tiles that ran the band form,
+    # [1] i32 in SMEM; and whether the tile before this one fell, a scratch
+    *accs, falls_ref, need_ref = outs if falls else (*outs, None, None)
+
     i = pl.program_id(0)
 
-    # decode in VMEM: the registered pallas twin of the residency variant
-    v = var.pallas(val_ref[:], *(_column(r[:]) for r in rowrefs))  # [Sb, Ca]
-    # i32 shift: x64 mode would lower an i64 operand, which
-    # tpu.dynamic_rotate rejects
-    contrib, okf = tile_contrib(
-        fn, window_ms, interval_ms, c0, v, n, band_ref[:], ohlo_ref[:],
-        lo_ref[:], hi_ref[:], rel_ref[:],
-        roll=lambda x, k: pltpu.roll(x, jnp.int32(k), 1), line=tile,
-        holes=holes)
-    accs = (sum_ref, cnt_ref, *maybe_sumsq)
+    def tile(form="band"):
+        """The tile, every ref read here: inside the branch that runs it."""
+        n, on_line = _column(n_ref[:]), None                  # [Sb, 1] i32
+        if line:
+            n, start = unpack_start(n)
+            on_line = (start, res_ref[:], eb_ref[:])
+        # decode in VMEM: the registered pallas twin of the residency
+        # variant
+        v = var.pallas(val_ref[:], *(_column(r[:]) for r in rowrefs))
+        # i32 shift: x64 mode would lower an i64 operand, which
+        # tpu.dynamic_rotate rejects
+        return tile_contrib(
+            fn, window_ms, interval_ms, c0, v, n, band_ref[:], ohlo_ref[:],
+            lo_ref[:], hi_ref[:], rel_ref[:],
+            roll=lambda x, k: pltpu.roll(x, jnp.int32(k), 1), line=on_line,
+            holes=holes, form=form)
 
-    @pl.when(i == 0)
-    def _():
-        for acc in accs:
-            acc[:] = jnp.zeros_like(acc)
+    def fold(contrib, okf):
+        # per-group fold on the MXU: [G, Sb] one-hot x [Sb, Tp]
+        return group_fold(gid_ref[:], G, contrib, okf, needs_sumsq)
 
-    # per-group fold on the MXU: [G, Sb] one-hot x [Sb, Tp]
-    for acc, part in zip(accs, group_fold(gid_ref[:], G, contrib, okf,
-                                          needs_sumsq)):
+    def start_at_zero():
+        @pl.when(i == 0)
+        def _():
+            for acc in accs:
+                acc[:] = jnp.zeros_like(acc)
+            if falls:
+                falls_ref[0] = 0
+                need_ref[0] = 0
+
+    if falls:
+        start_at_zero()
+
+        def whole(form):
+            contrib, okf, fell = tile(form)
+            return fold(contrib, okf), fell
+
+        parts, ran_band, fell = fallen_fold(
+            need_ref[0] != 0, whole, tuple(jnp.zeros_like(a) for a in accs))
+        falls_ref[0] += ran_band.astype(jnp.int32)
+        need_ref[0] = fell.astype(jnp.int32)
+    else:           # as it was: the tile, the first step's zeros, the fold
+        contrib, okf = tile()
+        start_at_zero()
+        parts = fold(contrib, okf)
+    for acc, part in zip(accs, parts):
         acc[:] += part
 
 
@@ -667,7 +823,10 @@ def build_pallas(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
     (:func:`pack_start`), the residual block beside the values, ``ohe`` in
     place of ``ohlo`` and the edge bounds ``eb`` last
     (:func:`_line_contrib`). ``holes``: the line store has cells without a
-    sample; the same operands, read by :func:`_hole_contrib`."""
+    sample; the same operands, read by :func:`_hole_contrib`. A line
+    program of the rate family (:func:`counts_falls`) returns one output
+    more, last: the tiles that ran the band form (:func:`fallen_fold`),
+    ``[1]`` i32 in SMEM."""
     var = decodereg.variant(residency)
     assert not var.full_columns or c0 == 0, (residency, c0)
     assert not line or residency == "raw", residency
@@ -675,10 +834,19 @@ def build_pallas(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
     Ca = Ck if Ck else C
     out_shape = tuple(jax.ShapeDtypeStruct((G, Tp), jnp.float32)
                       for _ in range(n_out))
+    out_specs = tuple(pl.BlockSpec((G, Tp), lambda i: (0, 0),
+                                   memory_space=pltpu.VMEM)
+                      for _ in range(n_out))
+    if counts_falls(fn, line):
+        out_shape += (jax.ShapeDtypeStruct((1,), jnp.int32),)
+        out_specs += (pl.BlockSpec((1,), lambda i: (0,),
+                                   memory_space=pltpu.SMEM),)
+        scratch = [pltpu.SMEM((1,), jnp.int32)]     # did the last tile fall
+    else:
+        scratch = []
     body = functools.partial(_kernel_body, fn, needs_sumsq, window_ms,
                              interval_ms, Sb, Ca, Tp, G, residency, c0, line,
                              holes)
-    acc_spec = pl.BlockSpec((G, Tp), lambda i: (0, 0), memory_space=pltpu.VMEM)
     const = functools.partial(pl.BlockSpec, index_map=lambda i: (0, 0),
                               memory_space=pltpu.VMEM)
     # a per-row operand, [S / Sb, 1, Sb] (lane_major): tile i's [1, Sb]
@@ -717,8 +885,9 @@ def build_pallas(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
         body,
         grid=(S // Sb,),
         in_specs=in_specs,
-        out_specs=tuple(acc_spec for _ in range(n_out)),
+        out_specs=out_specs,
         out_shape=out_shape,
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=min(VMEM_CAP, max(32 << 20, 2 * footprint))),
@@ -779,26 +948,42 @@ def build_xla_tiles(fn: str, needs_sumsq: bool, window_ms: int,
     roll = lambda x, k: jnp.roll(x, k, axis=1)  # noqa: E731 — tile-local
     # wrap, masked in tile_contrib exactly like pltpu.roll's
 
+    falls = counts_falls(fn, line)
+
     def fold(carry, xs, band, ohlo, lo, hi, rel, *eb):
         blk_t, *rest = xs
         rows_t = [_column(r) for r in rest[:R + 1]]
-        v = var.xla(blk_t, *rows_t[:R])
         n_t, g_t, tile = rows_t[R], rest[R + 1], None
         if line:
             n_t, start_t = unpack_start(n_t)
             tile = (start_t, rest[R + 2], eb[0])
-        contrib, okf = tile_contrib(fn, window_ms, interval_ms, c0,
-                                    v, n_t, band, ohlo, lo, hi, rel, roll,
-                                    line=tile, holes=holes)
-        parts = group_fold(g_t, G, contrib, okf, needs_sumsq)
-        return tuple(c + p for c, p in zip(carry, parts)), None
+
+        def one(form="band"):
+            v = var.xla(blk_t, *rows_t[:R])
+            contrib, okf, *fell = tile_contrib(
+                fn, window_ms, interval_ms, c0, v, n_t, band, ohlo, lo, hi,
+                rel, roll, line=tile, holes=holes, form=form)
+            return group_fold(g_t, G, contrib, okf, needs_sumsq), *fell
+
+        if not falls:
+            parts, = one()
+            return tuple(c + p for c, p in zip(carry, parts)), None
+        # the tiles that ran the band form and whether the last one fell
+        # ride last, as the Pallas program's SMEM output and scratch
+        *acc, count, need = carry
+        parts, ran_band, fell = fallen_fold(
+            need, one, tuple(jnp.zeros_like(a) for a in acc))
+        return (*(c + p for c, p in zip(acc, parts)),
+                count + ran_band.astype(jnp.int32), fell), None
 
     def run_tiles(tiles, *ops):
         init = tuple(jnp.zeros((G, Tp), f32)
                      for _ in range(3 if needs_sumsq else 2))
+        if falls:
+            init += (jnp.zeros((1,), jnp.int32), jnp.bool_(False))
         outs, _ = jax.lax.scan(
             lambda c, xs: fold(c, xs, *ops), init, tiles)
-        return outs
+        return outs[:-1] if falls else outs
 
     def call(blk, *rest):
         # rest: R per-row decode operands, n and gids, each [nt, 1, Sb]
@@ -1005,7 +1190,8 @@ class PaddedPartials:
     stall every ingest/query thread for the whole streaming pass. resolve()
     runs at present/merge time, outside the lock."""
 
-    def __init__(self, outs, op: str, num_groups: int, T: int, ticket):
+    def __init__(self, outs, op: str, num_groups: int, T: int, ticket,
+                 tiles: int | None = None, variant: str = "pallas"):
         self._outs = outs
         self._op = op
         self._ng = num_groups
@@ -1014,11 +1200,21 @@ class PaddedPartials:
         # .inflight): given back by the fetch, or with this bundle if it is
         # dropped unfetched
         self._ticket = ticket
+        # a program that counts its fallen tiles (counts_falls): its grid
+        # steps, and the count is its LAST output; None for every other
+        self._tiles, self._variant = tiles, variant
+        self.fall_tags: dict = {}
 
     def parts_of(self, outs) -> dict:
         """Partial dict from ALREADY-FETCHED outputs (callers batching many
-        bundles into one device_get use this instead of resolve())."""
+        bundles into one device_get use this instead of resolve()). A line
+        rate program's fallen tiles are counted in /metrics here, and left
+        in ``fall_tags`` (``fall_tiles``, ``tiles``) for the fetch span."""
         self._ticket.fetched()
+        if self._tiles is not None:
+            *outs, falls = outs
+            self.fall_tags = {"tiles": self._tiles, "fall_tiles":
+                              count_fall_tiles(falls, "line", self._variant)}
         s, c = outs[0][:self._ng, :self._T], outs[1][:self._ng, :self._T]
         if self._op in ("count", "group"):
             return {"count": c}
@@ -1028,9 +1224,10 @@ class PaddedPartials:
         return parts
 
     def resolve(self) -> dict:
-        with span(SPAN_QUERY_KERNEL, phase="fetch"):
-            outs = jax.device_get(self._outs)
-        return self.parts_of(outs)
+        with span(SPAN_QUERY_KERNEL, phase="fetch") as tags:
+            parts = self.parts_of(jax.device_get(self._outs))
+            tags.update(self.fall_tags)
+        return parts
 
 
 def fused_grid_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
@@ -1105,7 +1302,9 @@ def fused_grid_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
             outs = call(val, jnp.asarray(n), jnp.asarray(gids), *ops)
     # partial state is tiny ([G, Tp]): ONE host fetch finishes the query — the
     # slice/present/combine chain as device ops would cost a round-trip each
-    padded = PaddedPartials(outs, op, num_groups, T, ticket)
+    padded = PaddedPartials(outs, op, num_groups, T, ticket,
+                            S // Sb if counts_falls(fn, per) else None,
+                            variant)
     return padded.resolve() if fetch else padded
 
 

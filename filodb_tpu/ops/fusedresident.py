@@ -47,8 +47,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..utils.metrics import (FILODB_QUERY_FUSED_FALL_TILES,
-                             FILODB_QUERY_FUSED_FALLBACK,
+from ..utils.metrics import (FILODB_QUERY_FUSED_FALLBACK,
                              FILODB_QUERY_FUSED_SERVED, registry)
 from . import decodereg, fusedgrid, gridfns
 from .fusedgrid import dot_exact01
@@ -116,10 +115,7 @@ def count_served(shape: str) -> None:
 def count_fall_tiles(falls) -> int:
     """Fetch the raw hist tier's [1] count of tiles that ran the correction
     matmul (fused_hist_quantile_raw), add it to the registry, return it."""
-    k = int(np.asarray(falls)[0])
-    registry.counter(FILODB_QUERY_FUSED_FALL_TILES,
-                     {"mode": _mode}).increment(k)
-    return k
+    return fusedgrid.count_fall_tiles(falls, "hist", _mode)
 
 
 def count_fallback(shape: str) -> None:
@@ -687,13 +683,6 @@ def kahan_add(total, comp, x):
     return t, (t - total) - y
 
 
-def _sublane_max(a):
-    """``[M, Ca]`` -> ``[8, Ca]``: the maximum over whole sublane tiles, all
-    elementwise (M a multiple of B, B % 8 == 0: raw_hist_fusable)."""
-    return functools.reduce(jnp.maximum,
-                            [a[i:i + 8] for i in range(0, a.shape[0], 8)])
-
-
 def _raw_hist_kernel_body(fn: str, window_ms: int, interval_ms: int, Sb: int,
                           per: int, G: int, c0: int, n_ref, gid_ref, last_ref,
                           val_ref, w_ref, band_ref, used_ref, lo_ref, hi_ref,
@@ -742,7 +731,7 @@ def _raw_hist_kernel_body(fn: str, window_ms: int, interval_ms: int, Sb: int,
         # beside the matmul, not in the loop before it: the rolls then run
         # while the MXU does (4 ms of a 16 ms query over 768 columns
         # otherwise, on the v5e)
-        drops = _sublane_max(raw_hist_drops(x, roll1))
+        drops = fusedgrid.sublane_max(raw_hist_drops(x, roll1))
         fell = (ends > 0) | (jnp.max(
             jnp.where(used_ref[:] != 0, drops, 0.0)) > 0.0)
 

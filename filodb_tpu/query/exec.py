@@ -1709,12 +1709,16 @@ def _merge_partials(op: str, partials: list[AggPartial]) -> AggPartial:
     # device bundles (PaddedPartials) contribute their raw outputs to the
     # same fetch — calling their resolve() here would round-trip per shard
     raw = [p.parts for p in partials]
-    with span(SPAN_QUERY_KERNEL, phase="fetch"):
-        # parts_of() below takes each bundle out of the in-flight count
+    with span(SPAN_QUERY_KERNEL, phase="fetch") as ftags:
         fetched = jax.device_get([r._outs if hasattr(r, "parts_of") else r
                                   for r in raw])
-    resolved = [r.parts_of(f) if hasattr(r, "parts_of") else f
-                for r, f in zip(raw, fetched)]
+        # parts_of() takes each bundle out of the in-flight count; the line
+        # rate programs among them say how many of their tiles fell
+        resolved = [r.parts_of(f) if hasattr(r, "parts_of") else f
+                    for r, f in zip(raw, fetched)]
+        for r in raw:
+            for k, n in getattr(r, "fall_tags", {}).items():
+                ftags[k] = ftags.get(k, 0) + n
     merged: dict[str, object] = {}
     for p, rparts in zip(partials, resolved):
         # scatter this shard's groups into the global group space

@@ -233,11 +233,15 @@ METRICS_SPEC: dict[str, tuple[str, str]] = {
                    "the composed two-step path (shape gate, group cap, "
                    "off-grid store), tagged by shape."),
     FILODB_QUERY_FUSED_FALL_TILES: (
-        "counter", "Row tiles of the raw hist kernel that ran its "
-                   "correction matmul — a counter reset or a series' last "
-                   "sample under a query window — summed over the queries "
-                   "it served, tagged by backend mode; 0 for counters that "
-                   "only grow: each query then costs one matmul a tile."),
+        "counter", "Row tiles of a fused kernel that telescopes a "
+                   "window's delta and fell back to summing increments — "
+                   "a counter reset or a series' last sample under a query "
+                   "window — summed over the queries it served, tagged by "
+                   "kernel (hist: the raw hist kernel's correction matmul; "
+                   "line: the scalar kernel's band product on a line "
+                   "store) and backend mode; 0 for counters that only "
+                   "grow: each query then costs the fewest matmuls a "
+                   "tile."),
     FILODB_QUERY_MESH_SERVED: (
         "counter", "Queries served by a mesh dist_* collective, tagged by "
                    "route (fused / fused-narrow / twostep / sketch / topk)."),

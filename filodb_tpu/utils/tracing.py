@@ -173,7 +173,14 @@ TRACE_SPEC: dict[str, str] = {
                        "hist-raw adds packed = 1 where one weight narrower "
                        "than two bands carried both of a tile's products, "
                        "and on the fetch span fall_tiles = the tiles that "
-                       "ran the correction matmul).",
+                       "ran the correction matmul; the fetch span of a "
+                       "rate program on a line store carries fall_tiles = "
+                       "the row tiles that took the band product over "
+                       "their increments, a counter having fallen under "
+                       "some window or a row ending under one, and tiles "
+                       "= the row tiles of the dispatch: the others took "
+                       "a window's delta as the difference of its last "
+                       "and first sample).",
     SPAN_QUERY_REDUCE: "Cross-shard reduce merge of child partials.",
     SPAN_QUERY_DISPATCH: "One cross-node /exec POST (tags: endpoint, "
                          "shards).",

@@ -8,14 +8,22 @@ and compare the last line's checksum (PR 38: parent and change,
 
 Usage:
     python scripts/fused_bits.py [--root <tree>] [--tag <name>] [--rows N]
+                                 [--full]
 
 ``--root``: the tree whose ``filodb_tpu`` is run (default: this one), e.g.
 a ``git archive`` of the parent commit. Nine shapes at ``rows`` x 768 (2^20
 by default: a chip's store; 1024 fits the CPU's interpret mode), 61 steps:
 grid, line and hole stores x rate / ``avg_over_time`` by (g) / the squares x
 15 m and 2 h cards. Each line: ms a dispatch (24 pipelined, best of three;
-a tree before PR 38 has its two ``[S] -> [S, 1]`` relayouts inside) and a
-checksum of the partial state; the last: one checksum over all nine.
+a tree before PR 38 has its two ``[S] -> [S, 1]`` relayouts inside), a
+checksum of the partial state and, for a line or hole rate shape on a tree
+that telescopes its delta (PR 40), the tiles that fell of the tiles it has;
+the last: one checksum over all nine. One row in 97 ends 40 cells early, so
+by default EVERY tile of a rate shape holds a row that ends under a window
+and runs the band form: the parent's work plus the test. ``--full`` fills
+every row (no tile falls but where the hole shape's random holes run past
+the fills' reach): the telescoped tile's own time, and a checksum of its
+own.
 """
 
 from __future__ import annotations
@@ -36,6 +44,7 @@ def main(argv=None) -> int:
         os.path.dirname(os.path.abspath(__file__))))
     ap.add_argument("--tag", default="tree")
     ap.add_argument("--rows", type=int, default=1 << 20)
+    ap.add_argument("--full", action="store_true")
     a = ap.parse_args(argv)
     sys.path.insert(0, os.path.abspath(a.root))
     import jax
@@ -64,7 +73,8 @@ def main(argv=None) -> int:
                        for i in range(3))
     del parts
     rows = jnp.arange(S, dtype=jnp.int32)
-    n = jnp.full((S,), C, jnp.int32) - (rows % 97 == 0) * 40
+    n = jnp.full((S,), C, jnp.int32) - (rows % 97 == 0) * (0 if a.full
+                                                            else 40)
     gids8 = (rows * 7) % 8
     start = jax.random.randint(jax.random.PRNGKey(9), (S,), 0, IV
                                ).astype(jnp.int32)
@@ -80,7 +90,9 @@ def main(argv=None) -> int:
             return fusedgrid.fused_grid_aggregate(
                 op, fn, val, n, gids, G, out_ts, WINDOW, BASE, IV,
                 fetch=False, line=line, holes=holes)
-        r = go().resolve()
+        first = go()
+        r = first.resolve()
+        falls = getattr(first, "fall_tags", {})     # a tree before PR 40: none
         bits = b"".join(np.asarray(r[k]).tobytes() for k in sorted(r))
         every.update(bits)
         best = float("inf")
@@ -91,7 +103,9 @@ def main(argv=None) -> int:
             best = min(best, (time.perf_counter() - t0) / K * 1e3)
         times.append(best)
         print(a.tag, name, round(best, 3), "ms",
-              hashlib.sha256(bits).hexdigest()[:12], flush=True)
+              hashlib.sha256(bits).hexdigest()[:12],
+              *([f"fell {falls['fall_tiles']}/{falls['tiles']}"]
+                if falls else []), flush=True)
 
     for card, step in (("15m", 15_000), ("2h", 120_000)):
         bench(f"grid rate {card}", "sum", "rate", zero, 1, step)

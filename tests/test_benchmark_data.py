@@ -8,8 +8,8 @@ line store, probes, reader, control and files (``test_prom_data.py``) —
 and ``prom_miss``'s: the miss law, the reference over the samples that
 exist, the fill of a hole store, probes, reader, control and files
 (``test_prom_miss_data.py``), and the cases of the five readers of what a
-worker waits for (``test_wait_layers.py``, PR 39), every case under a name
-of its own.
+worker waits for (``test_wait_layers.py``, PR 39) and of ``fall_tiles_pct``'s
+reader (``test_fall_layer.py``, PR 40), every case under a name of its own.
 They run in seconds on the CPU, and what they pin is the yardstick: tier-1
 collects them here, under their own names, so that the floor counts them.
 """
@@ -19,7 +19,8 @@ import pytest
 for _mod in ("benchmark.tests.test_data", "benchmark.tests.test_hist_data",
              "benchmark.tests.test_prom_data",
              "benchmark.tests.test_prom_miss_data",
-             "benchmark.tests.test_wait_layers"):
+             "benchmark.tests.test_wait_layers",
+             "benchmark.tests.test_fall_layer"):
     pytest.register_assert_rewrite(_mod)
 
 from benchmark.tests.test_data import *        # noqa: E402,F401,F403
@@ -27,6 +28,7 @@ from benchmark.tests.test_hist_data import *   # noqa: E402,F401,F403
 from benchmark.tests.test_prom_data import *   # noqa: E402,F401,F403
 from benchmark.tests.test_prom_miss_data import *   # noqa: E402,F401,F403
 from benchmark.tests.test_wait_layers import *      # noqa: E402,F401,F403
+from benchmark.tests.test_fall_layer import *       # noqa: E402,F401,F403
 
 
 # Cases of those files that a star import alone does not give tier-1:

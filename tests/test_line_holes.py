@@ -40,8 +40,10 @@ from filodb_tpu.utils.tracing import (SPAN_INGEST_FLUSH, SPAN_QUERY_KERNEL,
 
 from .prom_reference import eval_range_fn
 from .test_fused_resident import fused_mode
-from .test_line_stamps import (AGGS, BACKENDS, BASE, FNS, IV, WINDOW,
-                               aggregate, err, present)
+from .test_line_stamps import (AGGS, BACKENDS, BASE, FNS, IV, RATE_FNS,
+                               RESET_ROW, ROWS_PLACED, TARGET, TC, TILE_STEPS,
+                               TK, TS, WANT_FALLS, WINDOW, aggregate,
+                               band_form, err, present, same_bits, tile_steps)
 
 # where a hole sits, relative to a target step's sure range [lo, hi] (the
 # cells EVERY row holds in that window) and its four open edge cells
@@ -320,6 +322,226 @@ def test_a_store_without_holes_builds_todays_programs():
         for x, y in zip(a[1:6], b[1:6]):
             if x.shape == y.shape:
                 assert x.tobytes() == y.tobytes()
+
+
+# -- the telescoped delta of the hole mode ------------------------------------
+#
+# tests/test_line_stamps.py section D, on a line that has holes: the sure
+# range's delta is its LAST sample's value less its FIRST's (the two filled
+# planes picked at hi and lo), and the band product over the pairs runs in
+# a tile where a pair that starts in some sure range fell, or a row's last
+# sample lies beyond the fills' reach before some hi. Arrays by hand, three
+# tiles of 512 rows; a hole's value cell holds a number no function reads.
+
+HG = 4
+
+
+@functools.lru_cache(maxsize=None)
+def hole_stream(kind, rows=TS):
+    """(start, res with RES_HOLE marks, val, n, there [rows, TK]): one cell
+    in sixteen a hole, runs capped at the bound, never the first cell;
+    ``kind`` as test_line_stamps.tile_stream has it."""
+    rng = np.random.default_rng(len(kind) + rows + 1)
+    start = rng.integers(0, IV, rows).astype(np.int32)
+    start[0] = 0
+    res = np.zeros((rows, TC), np.int8)
+    res[:, :TK] = np.where(rng.random((rows, TK)) < 0.25,
+                           rng.integers(-60, 61, (rows, TK)), 0)
+    res[:, 0] = 0
+    hole = rng.random((rows, TK)) < 1 / 16
+    hole[:, 0] = False
+    for k in range(HOLE_RUN_MAX, TK):
+        hole[:, k] &= ~hole[:, k - HOLE_RUN_MAX:k].all(axis=1)
+    inc = rng.integers(0, 100, (rows, TK)).astype(np.float64)
+    if kind == "walk":
+        inc -= 50
+    if kind == "fraction":
+        inc = inc * 1.0009765625 + rng.random((rows, TK))
+    v = np.zeros((rows, TC))
+    v[:, :TK] = np.cumsum(inc, axis=1) + rng.integers(0, 1000, rows)[:, None]
+    n = np.full(rows, TK, np.int32)
+    if kind == "reset":
+        c = TK // 2
+        hole[RESET_ROW, c - 1:c + 1] = False
+        v[RESET_ROW, c:TK] -= v[RESET_ROW, c] - 3
+    if kind == "ends":
+        n[100] = 60
+        hole[100, 59] = False
+    res[:, :TK][hole] = RES_HOLE
+    v[:, :TK][hole] = 7.0
+    return start, res, v.astype(np.float32), n, ~hole
+
+
+def hole_reference(fn, kind, rows, out_ts, v=None, there=None):
+    start, res, val, n, th = hole_stream(kind, rows)
+    v = val if v is None else v
+    th = th if there is None else there
+    t = (BASE + start[:, None].astype(np.int64) + np.arange(TK)[None, :] * IV
+         + np.where(th, res[:, :TK], 0))
+    out = []
+    for s_ in range(rows):
+        m = th[s_] & (np.arange(TK) < n[s_])
+        out.append(eval_range_fn(fn, t[s_][m], v[s_, :TK][m].astype(
+            np.float64), out_ts, WINDOW))
+    return np.array(out)
+
+
+def run_holes(backend, fn, kind, out_ts, grouped, rows=TS, v=None, res=None):
+    """(partial state, the fetch's fall tags) of one hole-mode dispatch."""
+    start, res0, val, n, _ = hole_stream(kind, rows)
+    gids = (np.arange(rows) % HG if grouped else np.zeros(rows)).astype(
+        np.int32)
+    p = fusedgrid.fused_grid_aggregate(
+        "stddev", fn, jnp.asarray(val if v is None else v), jnp.asarray(n),
+        jnp.asarray(gids), HG if grouped else 1, np.asarray(out_ts, np.int64),
+        WINDOW, BASE, IV, fetch=False, variant=backend,
+        line=(jnp.asarray(start), jnp.asarray(res0 if res is None else res)),
+        holes=True)
+    parts = {k: np.asarray(a) for k, a in p.resolve().items()}
+    return parts, dict(p.fall_tags)
+
+
+@functools.lru_cache(maxsize=None)
+def both_hole_forms(backend, fn, kind, layout, grouped):
+    out_ts = tile_steps(TILE_STEPS[layout])
+    got, falls = run_holes(backend, fn, kind, out_ts, grouped)
+    with band_form():
+        want, all_fell = run_holes(backend, fn, kind, out_ts, grouped)
+    assert all_fell == {"fall_tiles": TS // 512, "tiles": TS // 512}
+    return got, falls, want
+
+
+@pytest.mark.parametrize("grouped", (False, True), ids=("global", "by"))
+@pytest.mark.parametrize("layout", TILE_STEPS)
+@pytest.mark.parametrize("fn", RATE_FNS)
+@pytest.mark.parametrize("kind", WANT_FALLS)
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_the_hole_modes_telescoped_delta_answers_the_band_form_to_the_bit(
+        backend, kind, fn, layout, grouped):
+    """As on a line without holes: a stream without a fall reports none;
+    one reset costs its own tile and the next the band form; a row whose
+    last sample lies beyond the fills' reach before some hi falls for
+    ``delta`` too; values that go down make every counter tile fall and no
+    ``delta`` tile."""
+    got, falls, want = both_hole_forms(backend, fn, kind, layout, grouped)
+    same_bits(got, want)
+    assert falls == {"tiles": TS // 512,
+                     "fall_tiles": WANT_FALLS[kind][fn == "delta"]}
+
+
+@pytest.mark.parametrize("fn", RATE_FNS)
+@pytest.mark.parametrize("kind", ("clean", "reset"))
+def test_the_hole_modes_telescoped_backends_agree_to_the_bit(kind, fn):
+    for layout in TILE_STEPS:
+        same_bits(both_hole_forms("pallas", fn, kind, layout, True)[0],
+                  both_hole_forms("xla", fn, kind, layout, True)[0])
+
+
+# a reset of row 5 at a cell named from the target step's sure range, and
+# the holes beside it: (cell off lo or hi, from hi?, holes off the same,
+# falls). A pair lies in its EARLIER sample's cell and the band sums the
+# cells [lo, hi - 1]: the pair INTO lo and the pair that leaves hi are the
+# edges', decided by picks — over a hole too
+PLACED_H = {"lo": (0, 0, (), 0), "lo+1": (1, 0, (), 1),
+            "interior": (12, 0, (), 1), "hi": (0, 1, (), 1),
+            "hi+1": (1, 1, (), 0),
+            "lo+1 over a hole at lo": (1, 0, (0,), 0),
+            "hi over a hole at hi-1": (0, 1, (-1,), 1),
+            "interior over a run of three": (12, 0, (9, 10, 11), 1)}
+
+
+def placed_holes(place):
+    off, from_hi, holes, _ = PLACED_H[place]
+    lo, hi = gridfns.grid_edges(np.array([TARGET]), WINDOW, BASE, IV,
+                                fusedgrid.line_spread(IV))
+    lo, hi = int(lo[0]), int(hi[0])
+    at = hi if from_hi else lo
+    _start, res, val, _n, there = hole_stream("clean", ROWS_PLACED)
+    res, v, there = res.copy(), val.copy(), there.copy()
+    near = slice(lo - 5, hi + 6)
+    # row 5 holds every cell around the window but the placed holes
+    res[5, near] = np.where(res[5, near] == RES_HOLE, 0, res[5, near])
+    there[5, near] = True
+    v[5, :TK] = np.cumsum(np.full(TK, 17.0)) + 100
+    for h in holes:
+        res[5, at + h], there[5, at + h], v[5, at + h] = RES_HOLE, False, 7.0
+    c = at + off
+    v[5, c:TK] = np.where(there[5, c:], v[5, c:TK] - (v[5, c] - 3), 7.0)
+    return res, v, there
+
+
+@pytest.mark.parametrize("T", (1, 65), ids=("packed", "unpacked"))
+@pytest.mark.parametrize("fn", RATE_FNS)
+@pytest.mark.parametrize("place", PLACED_H)
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_a_reset_beside_holes_falls_where_a_window_sums_its_pair(
+        backend, place, fn, T):
+    res, v, there = placed_holes(place)
+    out_ts = np.full(T, TARGET)
+    want = hole_reference(fn, "clean", ROWS_PLACED, out_ts, v, there)
+    for grouped in (False, True):
+        parts, falls = run_holes(backend, fn, "clean", out_ts, grouped,
+                                 ROWS_PLACED, v, res)
+        gids = np.arange(ROWS_PLACED) % HG if grouped else np.zeros(
+            ROWS_PLACED, int)
+        for agg in ("sum", "avg", "count"):
+            assert err(present(agg, parts), aggregate(
+                agg, want, gids, HG if grouped else 1)) < 1.0
+        assert falls == {"tiles": 1, "fall_tiles":
+                         PLACED_H[place][3] if fn != "delta" else 0}
+
+
+@pytest.mark.parametrize("fn", RATE_FNS)
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_fractions_telescope_inside_the_tolerance_around_holes(backend, fn):
+    rows = ROWS_PLACED
+    for layout, T in TILE_STEPS.items():
+        out_ts = tile_steps(T)
+        want = hole_reference(fn, "fraction", rows, out_ts)
+        for grouped in (False, True):
+            parts, falls = run_holes(backend, fn, "fraction", out_ts, grouped,
+                                     rows)
+            gids = np.arange(rows) % HG if grouped else np.zeros(rows, int)
+            for agg in ("sum", "avg", "count"):
+                assert err(present(agg, parts), aggregate(
+                    agg, want, gids, HG if grouped else 1)) < 1.0
+            assert falls == {"tiles": 1, "fall_tiles": 0}
+
+
+@pytest.mark.parametrize("fn", RATE_FNS)
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_an_empty_sure_range_adds_nothing_in_the_hole_mode(backend, fn):
+    """The stream's very start: no sure cell, or one; and the padded
+    lanes."""
+    rows = ROWS_PLACED
+    out_ts = BASE + np.array([5_000, 9_999, 15_000, 20_050, 31_000, 305_000])
+    want = hole_reference(fn, "clean", rows, out_ts)
+    parts, falls = run_holes(backend, fn, "clean", out_ts, True, rows)
+    gids = np.arange(rows) % HG
+    for agg in ("sum", "avg", "count"):
+        assert err(present(agg, parts), aggregate(agg, want, gids, HG)) < 1.0
+    assert falls == {"tiles": 1, "fall_tiles": 0}
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_the_places_stream_reports_its_one_reset(backend):
+    """The stream of the places has ONE counter reset (row 3) and rows that
+    end in holes under a window: its rate programs report a fallen tile,
+    its window programs no count."""
+    size, layout = "1024x128", "packed"
+    st = the_store(size)
+    info = st.line_info()
+    out_ts = the_stream(size)[6][layout]
+    tracer.drain()
+    for fn in ("rate", "sum_over_time"):
+        fusedgrid.fused_grid_aggregate(
+            "sum", fn, st.val, st.n, jnp.zeros(st.S, jnp.int32), 1, out_ts,
+            WINDOW, info.base_ts, info.interval_ms, variant=backend,
+            line=(info.start, info.res), holes=True)
+    fetches = [s.tags for s in tracer.drain() if s.name == SPAN_QUERY_KERNEL
+               and s.tags.get("phase") == "fetch"]
+    assert fetches[0]["tiles"] == 2 and fetches[0]["fall_tiles"] >= 1
+    assert fetches[1] == {"phase": "fetch"}
 
 
 # -- the store ----------------------------------------------------------------

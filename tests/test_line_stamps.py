@@ -347,6 +347,268 @@ def test_the_slot_layout_is_part_of_a_line_programs_key_and_tag():
                 assert (ones <= 1).all() and ones.sum() > 4 * T
 
 
+# -- D: the telescoped delta, and the tiles that fall back to the band ---------
+#
+# A rate tile takes its sure range's delta as v[hi] - v[max(lo, 0)] and runs
+# the band product over the increments only where tile_fell says it must: a
+# counter fell among the cells some window sums, or a row ends under a
+# window. The line's arrays are built by hand here (three tiles of 512 rows;
+# a start a row, residuals in the int8 width): the band form — every tile
+# falls, what every tile computed before — is the same program with the test
+# answering True, and the reference says whether either is right.
+
+RATE_FNS = ("rate", "increase", "delta")
+TS, TC, TK = 1536, 128, 100             # three tiles of 512 rows
+TILE_STEPS = {"packed": 61, "unpacked": 100}
+RESET_ROW = 700                         # in tile 1, of 0..2
+
+
+@functools.lru_cache(maxsize=None)
+def tile_stream(kind, rows=TS):
+    """(start [rows] i32, res [rows, TC] i8, val [rows, TC] f32, n [rows]):
+    ``clean`` integer counters that only grow, every row full; ``reset``:
+    row RESET_ROW falls once; ``ends``: row 100 stops at scrape 60;
+    ``walk``: integers that go up and down (no counter: ``delta``'s diet);
+    ``fraction``: counters that grow by fractions no f32 sum holds
+    exactly."""
+    rng = np.random.default_rng(len(kind) + rows)
+    start = rng.integers(0, IV, rows).astype(np.int32)
+    start[0] = 0
+    res = np.zeros((rows, TC), np.int8)
+    res[:, :TK] = np.where(rng.random((rows, TK)) < 0.25,
+                           rng.integers(-60, 61, (rows, TK)), 0)
+    res[:, 0] = 0
+    inc = rng.integers(0, 100, (rows, TK)).astype(np.float64)
+    if kind == "walk":
+        inc -= 50
+    if kind == "fraction":
+        inc = inc * 1.0009765625 + rng.random((rows, TK))
+    v = np.zeros((rows, TC))
+    v[:, :TK] = np.cumsum(inc, axis=1) + rng.integers(0, 1000, rows)[:, None]
+    n = np.full(rows, TK, np.int32)
+    if kind == "reset":
+        v[RESET_ROW, TK // 2:TK] -= v[RESET_ROW, TK // 2] - 3
+    if kind == "ends":
+        n[100] = 60
+    return start, res, v.astype(np.float32), n
+
+
+def tile_stamps(kind, rows=TS):
+    start, res, _v, _n = tile_stream(kind, rows)
+    return (BASE + start[:, None].astype(np.int64)
+            + np.arange(TK)[None, :] * IV + res[:, :TK])
+
+
+def tile_steps(T):
+    return BASE + 400_007 + (TK * IV - 420_000) // T * np.arange(T)
+
+
+def run_line(backend, fn, kind, out_ts, grouped, rows=TS, v=None):
+    """(partial state, the fetch's fall tags) of one dispatch."""
+    start, res, val, n = tile_stream(kind, rows)
+    gids = (np.arange(rows) % G if grouped else np.zeros(rows)).astype(
+        np.int32)
+    p = fusedgrid.fused_grid_aggregate(
+        "stddev", fn, jnp.asarray(val if v is None else v), jnp.asarray(n),
+        jnp.asarray(gids), G if grouped else 1, np.asarray(out_ts, np.int64),
+        WINDOW, BASE, IV, fetch=False, variant=backend,
+        line=(jnp.asarray(start), jnp.asarray(res)))
+    parts = {k: np.asarray(a) for k, a in p.resolve().items()}
+    return parts, dict(p.fall_tags)
+
+
+class band_form:
+    """Every rate tile takes the band product, as every tile did before the
+    delta was telescoped: the test answers True while this is open (and no
+    program built under it outlives it)."""
+
+    @staticmethod
+    def _forget():
+        from filodb_tpu.query.plancache import plan_cache
+        fusedgrid.build_pallas.cache_clear()
+        plan_cache.clear()
+
+    def __enter__(self):
+        self._real = fusedgrid.tile_fell
+        fusedgrid.tile_fell = lambda *a: jnp.bool_(True)
+        self._forget()
+
+    def __exit__(self, *exc):
+        fusedgrid.tile_fell = self._real
+        self._forget()
+
+
+@functools.lru_cache(maxsize=None)
+def both_forms(backend, fn, kind, layout, grouped):
+    """(telescoped parts, its fall tags, the band form's parts)."""
+    out_ts = tile_steps(TILE_STEPS[layout])
+    got, falls = run_line(backend, fn, kind, out_ts, grouped)
+    with band_form():
+        want, all_fell = run_line(backend, fn, kind, out_ts, grouped)
+    assert all_fell == {"fall_tiles": TS // 512, "tiles": TS // 512}
+    return got, falls, want
+
+
+def same_bits(a, b):
+    for k in ("sum", "count", "sumsq"):
+        np.testing.assert_array_equal(a[k], b[k])
+
+
+# the tiles that run the band form, by stream and function (counters,
+# delta): the tile that fell and the one after it, which runs the band form
+# at once because its neighbour fell (fusedgrid.fallen_fold) and, clean
+# itself, hands the next tile back to the telescoped form
+WANT_FALLS = {"clean": (0, 0), "reset": (2, 0), "ends": (2, 2),
+              "walk": (TS // 512, 0)}
+
+
+@pytest.mark.parametrize("grouped", (False, True), ids=("global", "by"))
+@pytest.mark.parametrize("layout", TILE_STEPS)
+@pytest.mark.parametrize("fn", RATE_FNS)
+@pytest.mark.parametrize("kind", WANT_FALLS)
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_the_telescoped_delta_answers_the_band_form_to_the_bit(
+        backend, kind, fn, layout, grouped):
+    """On integer counters both are exact: a stream without a fall reports
+    no fallen tile; one reset among clean tiles costs its own tile and the
+    next the band form, the third none; a row that ends under a window
+    falls for ``delta`` too; values that go down make every counter tile
+    fall and no ``delta`` tile."""
+    got, falls, want = both_forms(backend, fn, kind, layout, grouped)
+    same_bits(got, want)
+    assert falls == {"tiles": TS // 512,
+                     "fall_tiles": WANT_FALLS[kind][fn == "delta"]}
+
+
+@pytest.mark.parametrize("fn", RATE_FNS)
+@pytest.mark.parametrize("kind", ("clean", "reset"))
+def test_the_telescoped_backends_agree_to_the_bit(kind, fn):
+    for layout in TILE_STEPS:
+        a = both_forms("pallas", fn, kind, layout, True)[0]
+        b = both_forms("xla", fn, kind, layout, True)[0]
+        same_bits(a, b)
+
+
+# one target step, asked alone (two slots a block) or 65 times over (one a
+# block: the cells some window sums are then exactly its (lo, hi]), with a
+# reset of row 5 at a cell named from the step's sure range [lo, hi]. An
+# increment lies in its LATER sample's cell: the cells lo - 1, lo (the pair
+# lo - 1 -> lo is the edge's, decided by picks) and hi + 1, hi + 2 need no
+# fall; lo + 1, the interior and hi do
+PLACED = {"lo-1": (-1, 0, 0), "lo": (0, 0, 0), "lo+1": (1, 0, 1),
+          "interior": (12, 0, 1), "hi": (0, 1, 1), "hi+1": (1, 1, 0),
+          "hi+2": (2, 1, 0)}
+TARGET = BASE + 600_000 + 4_321
+ROWS_PLACED = 64
+
+
+def placed_values(place):
+    off, from_hi, _ = PLACED[place]
+    lo, hi = gridfns.grid_edges(np.array([TARGET]), WINDOW, BASE, IV,
+                                fusedgrid.line_spread(IV))
+    c = int(hi[0] if from_hi else lo[0]) + off
+    v = tile_stream("clean", ROWS_PLACED)[2].copy()
+    v[5, c:TK] -= v[5, c] - 3
+    assert v[5, c] < v[5, c - 1]
+    return v
+
+
+@pytest.mark.parametrize("T", (1, 65), ids=("packed", "unpacked"))
+@pytest.mark.parametrize("fn", RATE_FNS)
+@pytest.mark.parametrize("place", PLACED)
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_a_reset_falls_where_a_window_sums_it_and_nowhere_else(
+        backend, place, fn, T):
+    v = placed_values(place)
+    out_ts = np.full(T, TARGET)
+    t = tile_stamps("clean", ROWS_PLACED)
+    want = np.array([eval_range_fn(fn, t[s], v[s, :TK].astype(np.float64),
+                                   out_ts, WINDOW)
+                     for s in range(ROWS_PLACED)])
+    for grouped in (False, True):
+        parts, falls = run_line(backend, fn, "clean", out_ts, grouped,
+                                ROWS_PLACED, v)
+        gids = np.arange(ROWS_PLACED) % G if grouped else np.zeros(
+            ROWS_PLACED, int)
+        for agg in AGGS:
+            assert err(present(agg, parts),
+                       aggregate(agg, want, gids, G if grouped else 1)) < 1.0
+        assert falls == {"tiles": 1, "fall_tiles":
+                         PLACED[place][2] if fn != "delta" else 0}
+
+
+@pytest.mark.parametrize("fn", RATE_FNS)
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_fractions_telescope_inside_the_tolerance(backend, fn):
+    """Values no f32 sum holds exactly: the difference of two samples and
+    the sum of the increments between them are each within rounding of
+    the reference, which is Prometheus's ``last - first``."""
+    rows = ROWS_PLACED
+    t = tile_stamps("fraction", rows)
+    v = tile_stream("fraction", rows)[2]
+    for layout, T in TILE_STEPS.items():
+        out_ts = tile_steps(T)
+        want = np.array([eval_range_fn(fn, t[s], v[s, :TK].astype(np.float64),
+                                       out_ts, WINDOW) for s in range(rows)])
+        for grouped in (False, True):
+            parts, falls = run_line(backend, fn, "fraction", out_ts, grouped,
+                                    rows)
+            gids = np.arange(rows) % G if grouped else np.zeros(rows, int)
+            for agg in AGGS:
+                assert err(present(agg, parts), aggregate(
+                    agg, want, gids, G if grouped else 1)) < 1.0
+            assert falls == {"tiles": 1, "fall_tiles": 0}
+
+
+@pytest.mark.parametrize("fn", RATE_FNS)
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_an_empty_sure_range_and_the_padded_steps_add_nothing(backend, fn):
+    """Steps at the stream's very start: no cell is sure (hi < lo: what the
+    one-hots of lo and hi pick there is garbage) or one is (hi = lo: the
+    range holds no increment), the window's samples are edge cells by each
+    row's own stamps. And the lanes past the steps are no step at all."""
+    rows = ROWS_PLACED
+    out_ts = BASE + np.array([5_000, 9_999, 15_000, 20_050, 31_000, 305_000])
+    lo, hi = gridfns.grid_edges(out_ts, WINDOW, BASE, IV,
+                                fusedgrid.line_spread(IV))
+    assert (hi < np.maximum(lo, 0)).any() and (hi == np.maximum(lo, 0)).any()
+    t = tile_stamps("clean", rows)
+    v = tile_stream("clean", rows)[2]
+    want = np.array([eval_range_fn(fn, t[s], v[s, :TK].astype(np.float64),
+                                   out_ts, WINDOW) for s in range(rows)])
+    parts, falls = run_line(backend, fn, "clean", out_ts, True, rows)
+    gids = np.arange(rows) % G
+    for agg in AGGS:
+        assert err(present(agg, parts), aggregate(agg, want, gids, G)) < 1.0
+    assert falls == {"tiles": 1, "fall_tiles": 0}
+    assert parts["sum"].shape == (G, len(out_ts))
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_the_suites_own_streams_report_their_falls(backend):
+    """The one-reset stream of section B falls (one tile holds it all); so
+    do the rows of section C that end under a window; the window functions
+    and a grid program return no count."""
+    tracer.drain()
+    t, v, st = the_store()
+    info = st.line_info()
+    out_ts = steps_on_the_edges(t, *GRIDS["15s"])
+    for fn, store, line in (("rate", st, True), ("avg_over_time", st, True),
+                            ("rate", the_ended_store()[3], True),
+                            ("rate", st, False)):
+        i = store.line_info()
+        fusedgrid.fused_grid_aggregate(
+            "sum", fn, store.val, store.n, jnp.zeros(S, jnp.int32), 1, out_ts,
+            WINDOW, i.base_ts, i.interval_ms, variant=backend,
+            line=(i.start, i.res) if line else None)
+    fetches = [s.tags for s in tracer.drain() if s.name == SPAN_QUERY_KERNEL
+               and s.tags.get("phase") == "fetch"]
+    assert fetches == [{"phase": "fetch", "tiles": 1, "fall_tiles": 1},
+                       {"phase": "fetch"},
+                       {"phase": "fetch", "tiles": 1, "fall_tiles": 1},
+                       {"phase": "fetch"}]
+
+
 def test_the_ended_rows_meet_the_case_they_are_there_for():
     """Of the chosen steps some hold, of some row, exactly its LAST sample
     in cell lo - 2 (the row has no cell lo - 1): the case in which a2 has
@@ -815,6 +1077,15 @@ def test_the_served_path_on_a_line_store(monkeypatch):
     flush = [s for s in spans if s.name == SPAN_INGEST_FLUSH]
     assert sum(s.tags["demoted"] for s in flush) == 3
     lines = text.splitlines()
+    # the rate program's fetch says how many of its tiles ran the band form
+    # (no counter of this stream falls under the range, no row ends under
+    # it: none), and /metrics counts them apart from the hist kernel's
+    fetch, = (s.tags for s in spans if s.name == SPAN_QUERY_KERNEL
+              and s.tags.get("phase") == "fetch")
+    assert fetch == {"phase": "fetch", "tiles": 1, "fall_tiles": 0}
+    assert sum(ln.startswith('filodb_query_fused_fall_tiles_total{'
+                             'kernel="line",mode="pallas"} ')
+               for ln in lines) == 1
     assert 'filodb_store_stamp_form{shard="0"} 1' in lines
     assert 'filodb_store_rows_off_line{shard="0"} 3' in lines
     for why in chunkstore.DEMOTE_REASONS:

@@ -253,6 +253,45 @@ def test_the_whole_fused_program_holds_no_column_and_no_temp(
     _no_column_and_no_temp(compiled, S)
 
 
+@pytest.mark.parametrize("fn,per,holes,dots", [
+    ("rate", 2, False, (8, 11)),         # adhoc_prom's: 12 and 15 passes
+    ("increase", 1, False, (8, 11)),
+    ("delta", 2, False, (8, 11)),
+    ("rate", 2, True, (16, 19)),         # adhoc_prom_miss's: 19 and 22
+    ("rate", 1, True, (16, 19)),
+    ("delta", 2, True, (16, 19)),
+])
+def test_a_line_rate_program_compiles_with_both_forms_of_its_tile(
+        one_chip, fn, per, holes, dots):
+    """The telescoped tile and the band form are both in the program, each
+    under its branch (fusedgrid.fallen_fold), the band form three products
+    the dearer (a product over ``ohe``'s blocks is one ``dot_general``); the
+    count of tiles that ran it is the last output, ``[1]`` i32 in SMEM. And
+    the whole still holds no ``copy``, no ``[S, 1]`` array and no
+    temporary."""
+    C, Tp, G = 768, 128, 8
+    prog = fusedgrid.fused_program(fn, False, WINDOW, IV, S, SB, C, Tp, G,
+                                   "raw", 0, 0, "pallas", per, holes)
+    args = _store_args(one_chip, S, C, Tp, "raw", per)
+    with jax.enable_x64(False):
+        jaxpr = jax.make_jaxpr(prog)(*args)
+    call, = (e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call")
+    branches = [[str(b).count("dot_general") for b in e.params["branches"]]
+                for e in call.params["jaxpr"].eqns
+                if e.primitive.name == "cond"]
+    # (program_id == 0; the tile before fell: nothing | telescoped; this
+    # one fell: nothing | band), branches listed false first
+    assert branches == [[0, 0], [dots[0], 0], [0, dots[1]]]
+    outs = call.params["grid_mapping"].block_mappings_output
+    assert [str(o.transformed_block_aval) for o in outs] == [
+        "Ref<vmem>{float32[8,128]}"] * 2 + ["Ref<smem>{int32[1]}"]
+    compiled = _compile(prog, args)
+    text = compiled.as_text()
+    assert "s64[" not in text and not re.search(r"\bcopy\(", text)
+    _no_column_and_no_temp(compiled, S)
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
 @pytest.mark.parametrize("rows", [8, 64, 512])
 def test_a_one_tile_store_compiles_for_v5e_too(one_chip, rows):
     """Sb = S: the [1, S] row turns to a column at any S % 8 == 0."""
