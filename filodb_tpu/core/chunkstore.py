@@ -363,6 +363,20 @@ def _decode_delta_rows(dv, anchor, pool, pool_slot, rid):
 _derive_ts_rows = _derive_ts
 
 
+@functools.partial(jax.jit, static_argnums=(3,))
+def _gather_grid(val, n, picked, C):
+    """(ts, val, n) of the rows ``picked[0]`` of a grid-form store, their
+    stamps derived (``SeriesStore.grid_row_gather``): ``picked`` is int64
+    ``[3, P]`` — store rows, each row's first stamp (-1: a pad row, or one
+    without a sample), the interval — one upload for all a gather needs
+    from the host."""
+    rid = picked[0].astype(jnp.int32)
+    first = picked[1]
+    n_g = jnp.where(first >= 0, jnp.take(n, rid), 0).astype(jnp.int32)
+    return (_derive_ts_impl(first, n_g, picked[2, 0], C),
+            jnp.take(val, rid, axis=0), n_g)
+
+
 class _Deferred:
     """Base for lazy views of elided store blocks: shape metadata for
     planning; ``materialize()`` reconstructs. General query paths funnel
@@ -1443,6 +1457,33 @@ class SeriesStore:
         if not (self.n_host > 0).any():
             return None
         return int(self.grid_base), int(self.grid_interval)
+
+    def grid_row_gather(self):
+        """``(rows, live, val, n) -> (ts, val, n)`` of a few gathered rows of
+        a store in its GRID form (the caller has seen ``grid_info()``), None
+        where the stamps are not a resident s64 block. There a row's stamps
+        are ``first_ts[row] + k * interval`` for k < n[row] — the grid
+        invariant, what ``compress_prepare`` verifies before it elides the
+        block — so the gather derives them from the host's ``first_ts`` and
+        the block is no operand of its program: on the TPU a program that
+        takes the s64 ``[S, C]`` block splits ALL of it into two u32 planes
+        (2^20 x 768: 6.4 GB read and 3 GB of temporaries) to hand back
+        eight rows. ONE program and one upload a gather (``_gather_grid``):
+        a leaf holds the shard lock through every dispatch it makes.
+        ``rows``: the pow2-padded row ids on the host, the first ``live``
+        real; the pad rows come back with n = 0 and TS_PAD all along."""
+        if self.ts is None or self.res is not None:
+            return None
+        first_ts, iv, C = self.first_ts, int(self.grid_interval), self.C
+
+        def gather(rows: np.ndarray, live: int, val, n):
+            picked = np.full((3, len(rows)), -1, np.int64)
+            picked[0] = rows
+            picked[1, :live] = first_ts[rows[:live]]
+            picked[2] = iv
+            return _gather_grid(val, n, jnp.asarray(picked), C)
+
+        return gather
 
     def grid_offsets(self, rows: np.ndarray) -> np.ndarray:
         """Start cell of each given row (its first sample's grid cell index

@@ -20,6 +20,7 @@ class Filter:
 
 @dataclass(frozen=True)
 class Equals(Filter):
+    KIND = "eq"      # the select span\'s ``matchers`` tag
     value: str
 
     def matches(self, value: str) -> bool:
@@ -28,6 +29,7 @@ class Equals(Filter):
 
 @dataclass(frozen=True)
 class NotEquals(Filter):
+    KIND = "ne"      # the select span\'s ``matchers`` tag
     value: str
 
     def matches(self, value: str) -> bool:
@@ -36,6 +38,7 @@ class NotEquals(Filter):
 
 @dataclass(frozen=True)
 class In(Filter):
+    KIND = "in"      # the select span\'s ``matchers`` tag
     values: tuple[str, ...]
 
     def matches(self, value: str) -> bool:
@@ -44,6 +47,7 @@ class In(Filter):
 
 @dataclass(frozen=True)
 class EqualsRegex(Filter):
+    KIND = "re"      # the select span\'s ``matchers`` tag
     pattern: str
 
     def matches(self, value: str) -> bool:
@@ -52,6 +56,7 @@ class EqualsRegex(Filter):
 
 @dataclass(frozen=True)
 class NotEqualsRegex(Filter):
+    KIND = "nre"      # the select span\'s ``matchers`` tag
     pattern: str
 
     def matches(self, value: str) -> bool:

@@ -157,6 +157,9 @@ class PartKeyIndex:
         # intersection, so changing query windows still hit
         self._epoch = 0
         self._filter_cache: dict[tuple, tuple[int, np.ndarray]] = {}
+        # filter sets that had to be resolved (the cache above missed): the
+        # leaf's select span tags ``resolve`` from its growth
+        self.filter_misses = 0
         # registration hot path: raw pair bytes (b"name\x01value") -> its
         # (nid, vid) identity, so the bulk add does ONE dict probe per label
         # pair instead of two nested gets + string decodes. (nid, vid) stays
@@ -601,6 +604,7 @@ class PartKeyIndex:
         if hit is not None and hit[0] == self._epoch:
             result = hit[1]
         else:
+            self.filter_misses += 1
             result = self._eval_filters(filters)
             if len(self._filter_cache) > 512:
                 self._filter_cache.clear()

@@ -88,8 +88,13 @@ class RecordContainer:
         return self.part_keys, self.set_hashes
 
     def to_bytes(self) -> bytes:
-        blob = json.dumps(list(self.label_sets),
-                          separators=(",", ":")).encode()
+        # a lazy label list keeps its own encoding: a producer re-sends a
+        # batch container every scrape with new stamps and values, and a
+        # decoded one is re-sent as it came (``json.dumps`` of 125,000
+        # eleven-label sets holds the interpreter a quarter of a second)
+        encoded = getattr(self.label_sets, "json_blob", None)
+        blob = encoded() if encoded is not None else _labels_json(
+            self.label_sets)
         n = len(self.ts)
         parts = [
             _HDR.pack(_MAGIC, 3, self.schema.schema_id, n, len(blob)),
@@ -149,7 +154,7 @@ class RecordContainer:
         part_hash = np.frombuffer(buf, "<u8", n, off); off += 8 * n
         shard_hash = np.frombuffer(buf, "<u4", n, off); off += 4 * n
         part_idx = np.frombuffer(buf, "<i4", n, off); off += 4 * n
-        label_sets = json.loads(buf[off : off + blob_len]); off += blob_len
+        blob = buf[off : off + blob_len]; off += blob_len
         part_keys = set_hashes = None
         if ver >= 2:
             (nk,) = struct.unpack_from("<I", buf, off); off += 4
@@ -158,8 +163,50 @@ class RecordContainer:
             part_keys = []
             for ln in lens.tolist():
                 part_keys.append(buf[off:off + ln]); off += ln
+        # a v2+ frame says how many label sets it holds: the dicts are built
+        # when someone reads one (a series not seen before), not per scrape
+        label_sets = (_LazyJsonLabels(blob, len(part_keys))
+                      if part_keys is not None else json.loads(blob))
         return cls(schema, ts, values, part_hash, shard_hash, part_idx,
                    label_sets, bucket_les, part_keys, set_hashes)
+
+
+def _labels_json(label_sets) -> bytes:
+    return json.dumps(list(label_sets), separators=(",", ":")).encode()
+
+
+class _LazyJsonLabels:
+    """Label dicts of a decoded container, parsed on first access: the
+    ingest of series the shard already knows resolves them by key bytes and
+    hash (the frame's trailer) and reads only ``len()``, so the consumer of
+    a 125,000-row scrape never builds its dicts — one ``json.loads`` of
+    them holds the interpreter for a quarter of a second a container, every
+    scrape (PERF.md, PR 41)."""
+
+    __slots__ = ("_blob", "_n", "_real")
+
+    def __init__(self, blob: bytes, n: int):
+        self._blob, self._n, self._real = blob, n, None
+
+    def _mat(self) -> list:
+        if self._real is None:
+            self._real = json.loads(self._blob)
+        return self._real
+
+    def json_blob(self) -> bytes:
+        return bytes(self._blob)
+
+    def __len__(self) -> int:
+        return self._n
+
+    def __getitem__(self, i):
+        return self._mat()[i]
+
+    def __iter__(self):
+        return iter(self._mat())
+
+    def __eq__(self, other):
+        return list(self) == list(other)
 
 
 class _LazyBatchLabels:
@@ -169,13 +216,21 @@ class _LazyBatchLabels:
     reference's ingest never materializes label maps either — BinaryRecords
     carry the key bytes and Lucene docs build from those)."""
 
-    __slots__ = ("fixed", "vary", "cols", "_real")
+    __slots__ = ("fixed", "vary", "cols", "_real", "_blob")
 
     def __init__(self, fixed: dict, vary: list, cols: list):
         self.fixed = fixed
         self.vary = vary
         self.cols = cols
         self._real = None
+        self._blob = None
+
+    def json_blob(self) -> bytes:
+        """The wire encoding of the label sets, made once: the labels of a
+        batch container never change, its stamps and values do."""
+        if self._blob is None:
+            self._blob = _labels_json(self)
+        return self._blob
 
     def _mat(self) -> list:
         if self._real is None:

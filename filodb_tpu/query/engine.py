@@ -464,8 +464,14 @@ class QueryEngine:
     def _note_fused(self, ctx: QueryContext, base: str) -> None:
         """Name the fused-tier programs a local plan's leaves ran in the
         exec path (see QueryContext.kernels); a plan that ran none keeps
-        the bare ``base``."""
+        the bare ``base``, or reads ``<base>-gather`` where all its leaves
+        gathered narrow selections (QueryContext.leaf_routes)."""
         if not ctx.kernels:
+            if ctx.leaf_routes == {"gather"}:
+                # every local leaf gathered a narrow selection's rows and
+                # the general kernels answered: told apart from a wide
+                # selection's composed path, which stays the bare ``base``
+                self._set_path(ctx, f"{base}-gather")
             return
         kinds = sorted({k for k, _ in ctx.kernels} - {"raw"})
         tags = sorted({t for _, t in ctx.kernels})

@@ -31,10 +31,12 @@ from ..core.filters import Filter
 from ..core.selection import ShardSelection
 from ..ops import aggregators, binop, instantfns, rangefns
 from ..utils.diagnostics import lock_hold_ns, lock_wait_ns
-from ..utils.metrics import FILODB_GROUPIDS, registry
-from ..utils.tracing import (SPAN_QUERY_GROUPIDS, SPAN_QUERY_KERNEL,
-                             SPAN_QUERY_LEAF, SPAN_QUERY_ODP,
-                             SPAN_QUERY_REDUCE, SPAN_QUERY_SELECT, span)
+from ..utils.metrics import (FILODB_GROUPIDS, FILODB_INDEX_RESOLVE,
+                             FILODB_QUERY_LEAF, registry)
+from ..utils.tracing import (SPAN_QUERY_GATHER, SPAN_QUERY_GROUPIDS,
+                             SPAN_QUERY_KERNEL, SPAN_QUERY_LEAF,
+                             SPAN_QUERY_ODP, SPAN_QUERY_REDUCE,
+                             SPAN_QUERY_SELECT, span)
 from .rangevector import (QueryError, QueryResult, QueryStats,
                           RangeVectorKey, ResultMatrix, fmt_value)
 
@@ -63,6 +65,11 @@ class QueryContext:
     # and the composed two-step path read differently. Shared by reference
     # across dataclasses.replace copies, like ``stats``
     kernels: set = field(default_factory=set)
+    # how this query's local leaves took their rows ("gather" | "wide" |
+    # "paged", ``count_leaf``; a remote leaf adds "remote"): a plan whose
+    # leaves ALL gathered a narrow selection and ran no fused program reads
+    # "local-gather". Shared by reference like ``kernels``
+    leaf_routes: set = field(default_factory=set)
 
 
 @dataclass
@@ -147,17 +154,22 @@ def _dval(arr):
     return arr.materialize() if isinstance(arr, _Deferred) else arr
 
 
-def _gather_rows_padded(ts, val, n, rows: np.ndarray):
+def _gather_rows_padded(ts, val, n, rows: np.ndarray, grid_gather=None):
     """Gather the given array rows padded to a pow2 row count (kernel-shape
     stability). Pad rows are fully disabled: n = 0 AND timestamps forced to
     the pad sentinel — the general kernels derive windows from timestamps, so
     a pad row aliasing row 0's real data would otherwise produce phantom
-    (non-NaN) outputs that aggregation counts as present."""
+    (non-NaN) outputs that aggregation counts as present. ``grid_gather``
+    (``SeriesStore.grid_row_gather``): the rows taken by the store's own
+    one program, their stamps derived from the grid, where ``ts`` is a
+    grid-form store's resident s64 block — which is then no operand."""
     from ..core.chunkstore import TS_PAD, _Deferred
     M = len(rows)
     P = _pow2(M)
     pad = np.zeros(P, np.int32)
     pad[:M] = rows
+    if grid_gather is not None and not isinstance(val, _Deferred):
+        return grid_gather(pad, M, val, n) + (P,)
     rid = jnp.asarray(pad)
     real = jnp.arange(P) < M
     n_g = jnp.where(real, jnp.take(n, rid), 0)
@@ -559,6 +571,18 @@ class LazyKeys:
 
 def count_groupids(route: str) -> None:
     registry.counter(FILODB_GROUPIDS, {"route": route}).increment()
+
+
+def count_leaf(ctx, tags: dict, route: str) -> None:
+    """How a leaf took its rows, on its select span, in ``/metrics`` and in
+    the query's ``leaf_routes``: ``gather`` = a narrow selection (at most
+    GATHER_THRESHOLD series and under half the index: keys materialized,
+    rows gathered to a power of two, none for an empty selection), ``wide``
+    = the store's own blocks with ``n`` zeroed outside the selection,
+    ``paged`` = merged with cold chunks from the sink."""
+    tags["route"] = route
+    ctx.leaf_routes.add(route)
+    registry.counter(FILODB_QUERY_LEAF, {"route": route}).increment()
 
 
 def _group_ids_for(keys, rows, R, by, without):
@@ -1380,8 +1404,16 @@ class SelectRawPartitionsExec(ExecPlan):
             z = jnp.zeros((8, 8), jnp.float32)
             return SeriesSelection(jnp.full((8, 8), 1 << 62, jnp.int64), z,
                                    jnp.zeros(8, jnp.int32), [], None, None)
+        # the leaf holds the shard lock: nobody else moves the index's
+        # count of filter sets it had to resolve (its cache missed)
+        resolved = shard.index.filter_misses
         picked, tags["memo"] = shard.selection(
             list(self.filters), self.start_ms, self.end_ms, GATHER_THRESHOLD)
+        tags["matchers"] = "+".join(sorted(f.KIND for f in self.filters))
+        tags["resolve"] = ("miss" if shard.index.filter_misses != resolved
+                           else "hit")
+        registry.counter(FILODB_INDEX_RESOLVE,
+                         {"outcome": tags["resolve"]}).increment()
         pids = picked.pids      # shared and read-only on a memo hit
         ctx.stats.add("series_matched", len(pids))
         store = shard.store
@@ -1396,6 +1428,7 @@ class SelectRawPartitionsExec(ExecPlan):
         # on-demand paging: query reaches behind resident data -> merge cold
         # chunks from the sink (ref: OnDemandPagingShard.scanPartitions)
         if les is None and shard.needs_paging(pids, self.start_ms):
+            count_leaf(ctx, tags, "paged")
             if len(pids) > ODP_BATCH:
                 return _WideODP(pids)
             ctx.stats.add("rows_paged_in", len(pids))
@@ -1411,6 +1444,7 @@ class SelectRawPartitionsExec(ExecPlan):
         ts, val, n = store.arrays(col)
         total = len(shard.index)
         grid = store.grid_info()
+        on_grid = grid is not None      # the STORE's form, whatever the cohorts
         if len(pids) == 0:
             # synthetic pad selection (the store-None branch's shape):
             # slicing a compressed-resident store's deferred view here would
@@ -1419,6 +1453,7 @@ class SelectRawPartitionsExec(ExecPlan):
             # the same empty result the real slice would.
             vshape = ((8, 8, store.nbuckets)
                       if getattr(val, "ndim", 2) == 3 else (8, 8))
+            count_leaf(ctx, tags, "gather")     # of no row
             return SeriesSelection(
                 jnp.full((8, 8), 1 << 62, jnp.int64),
                 jnp.zeros(vshape, store.dtype), jnp.zeros(8, jnp.int32),
@@ -1468,7 +1503,17 @@ class SelectRawPartitionsExec(ExecPlan):
         if len(pids) <= GATHER_THRESHOLD and len(pids) < 0.5 * max(total, 1):
             # narrow selection: gather rows once, padded to a power of two
             ctx.stats.add("blocks_raw")
-            sel_ts, sel_val, sel_n, P = _gather_rows_padded(ts, val, n, pids)
+            count_leaf(ctx, tags, "gather")
+            with span(SPAN_QUERY_GATHER, shard=self.shard,
+                      rows=len(pids)) as gtags:
+                sel_ts, sel_val, sel_n, P = _gather_rows_padded(
+                    ts, val, n, pids,
+                    store.grid_row_gather() if on_grid and ts is store.ts
+                    else None)
+                gtags["padded"] = P
+                gtags["bytes"] = len(pids) * (
+                    int(np.prod(val.shape[1:])) * np.dtype(val.dtype).itemsize
+                    + ts.shape[1] * 8)
             # P > len(pids): arrays carry pad rows beyond the keys — expose the
             # identity row map so downstream compaction/group-scatter skips them
             sel_rows = None if P == len(pids) else np.arange(len(pids), dtype=np.int32)
@@ -1479,6 +1524,7 @@ class SelectRawPartitionsExec(ExecPlan):
         # wide selection: no gather — disable non-selected rows via n = 0
         # (store.S is the PHYSICAL padded row count; the full-selection test
         # is against the logical series count)
+        count_leaf(ctx, tags, "wide")
         if picked.is_all:
             n_eff = n
         else:

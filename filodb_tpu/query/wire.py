@@ -674,6 +674,9 @@ class RemoteLeafExec(ExecPlan):
     IS_REMOTE = True             # non-leaf parents fan these out in threads
 
     def execute(self, ctx):
+        # what the peer's leaves did does not cross the wire: the caller's
+        # exec path must not read as all-gathered on its local leaves alone
+        getattr(ctx, "leaf_routes", set()).add("remote")
         ship, local = _split_wire_prefix(self.transformers)
         plan = replace(self.inner,
                        transformers=list(self.inner.transformers) + ship)
@@ -724,6 +727,7 @@ class RemoteBatchExec(ExecPlan):
     IS_BATCH = True              # parents splice the result list in place
 
     def execute(self, ctx):
+        getattr(ctx, "leaf_routes", set()).add("remote")    # as a lone leaf
         plans, locals_ = [], []
         for m in self.members:
             ship, local = _split_wire_prefix(m.transformers)

@@ -46,6 +46,8 @@ FILODB_SHARD_LOCK_WAIT_SECONDS = "filodb_shard_lock_wait_seconds"
 FILODB_SHARD_LOCK_HOLD_SECONDS = "filodb_shard_lock_hold_seconds"
 FILODB_GROUPIDS = "filodb_groupids"
 FILODB_SELECTION_MEMO = "filodb_selection_memo"
+FILODB_QUERY_LEAF = "filodb_query_leaf"
+FILODB_INDEX_RESOLVE = "filodb_index_resolve"
 FILODB_QUERY_LATENCY_MS = "filodb_query_latency_ms"
 FILODB_QUERY_SLOW = "filodb_query_slow"
 FILODB_QUERY_COMPILE_CACHE_HITS = "filodb_query_compile_cache_hits"
@@ -178,6 +180,17 @@ METRICS_SPEC: dict[str, tuple[str, str]] = {
                    "device array) and outcome: hit, miss (built and kept), "
                    "bypass (not kept; reason = narrow, time_mask or "
                    "recovering)."),
+    FILODB_QUERY_LEAF: (
+        "counter", "Data-reading leaves by how they took their rows: route "
+                   "= gather (a narrow selection: keys materialized, rows "
+                   "gathered to a power of two), wide (the store's own "
+                   "blocks, n zeroed outside the selection) or paged (cold "
+                   "chunks merged in from the sink)."),
+    FILODB_INDEX_RESOLVE: (
+        "counter", "Leaf selects by whether the index had to resolve the "
+                   "filter set: outcome = miss (matcher set algebra ran, "
+                   "regex value sets among it) or hit (the part ids came "
+                   "from the index's filter cache or the selection memo)."),
     FILODB_QUERY_LATENCY_MS: (
         "histogram", "End-to-end PromQL latency per dataset; the /metrics "
                      "rendering carries the last query's trace id as an "

@@ -78,6 +78,7 @@ SPAN_QUERY_PLAN = "query.plan"
 SPAN_QUERY_EXECUTE = "query.execute"
 SPAN_QUERY_LEAF = "query.exec.leaf"
 SPAN_QUERY_SELECT = "query.exec.select"
+SPAN_QUERY_GATHER = "query.exec.gather"
 SPAN_QUERY_GROUPIDS = "query.exec.groupids"
 SPAN_QUERY_KERNEL = "query.exec.kernel"
 SPAN_QUERY_REDUCE = "query.exec.reduce"
@@ -145,7 +146,22 @@ TRACE_SPEC: dict[str, str] = {
                        "the general kernels answer; on a line store "
                        "hole_cells = the selected rows' cells without a "
                        "sample and used_cells = all the cells they use, "
-                       "from the host's counts, kept with the selection).",
+                       "from the host's counts, kept with the selection; "
+                       "matchers = the filters' kinds, eq | ne | in | re | "
+                       "nre joined by +; resolve = miss where the index "
+                       "had to resolve the filter set, regex value sets "
+                       "among it, hit where its filter cache or the memo "
+                       "had the part ids; route = gather | wide | paged: "
+                       "how the leaf took its rows; the keys of a gather "
+                       "are materialized inside this span).",
+    SPAN_QUERY_GATHER: "The row gather of a narrow selection (at most "
+                       "GATHER_THRESHOLD series and under half the index), "
+                       "inside the select span: values, counts and stamps "
+                       "of the selected rows, padded to a power of two; the "
+                       "stamps of a grid-form store are derived from each "
+                       "row's first stamp, its s64 block is no operand "
+                       "(tags: shard, rows, padded, bytes = rows x a row's "
+                       "values and stamps, what the gather needs).",
     SPAN_QUERY_GROUPIDS: "Group ids of the selected series for a "
                          "by/without aggregation: from the index's label "
                          "columns where the selection is still pids "

@@ -9,7 +9,11 @@ and ``prom_miss``'s: the miss law, the reference over the samples that
 exist, the fill of a hole store, probes, reader, control and files
 (``test_prom_miss_data.py``), and the cases of the five readers of what a
 worker waits for (``test_wait_layers.py``, PR 39) and of ``fall_tiles_pct``'s
-reader (``test_fall_layer.py``, PR 40), every case under a name of its own.
+reader (``test_fall_layer.py``, PR 40), and ``tsbs_cpu``'s: the walk and the
+tags, the reference against its brute-force twin, the fill, the served path
+for the twelve text kinds, probes, the generated traffic file, the files
+(``test_tsbs_data.py``) with the four readers of what a narrow leaf does
+(``test_tsbs_layers.py``, PR 41), every case under a name of its own.
 They run in seconds on the CPU, and what they pin is the yardstick: tier-1
 collects them here, under their own names, so that the floor counts them.
 """
@@ -20,7 +24,9 @@ for _mod in ("benchmark.tests.test_data", "benchmark.tests.test_hist_data",
              "benchmark.tests.test_prom_data",
              "benchmark.tests.test_prom_miss_data",
              "benchmark.tests.test_wait_layers",
-             "benchmark.tests.test_fall_layer"):
+             "benchmark.tests.test_fall_layer",
+             "benchmark.tests.test_tsbs_data",
+             "benchmark.tests.test_tsbs_layers"):
     pytest.register_assert_rewrite(_mod)
 
 from benchmark.tests.test_data import *        # noqa: E402,F401,F403
@@ -29,6 +35,8 @@ from benchmark.tests.test_prom_data import *   # noqa: E402,F401,F403
 from benchmark.tests.test_prom_miss_data import *   # noqa: E402,F401,F403
 from benchmark.tests.test_wait_layers import *      # noqa: E402,F401,F403
 from benchmark.tests.test_fall_layer import *       # noqa: E402,F401,F403
+from benchmark.tests.test_tsbs_data import *        # noqa: E402,F401,F403
+from benchmark.tests.test_tsbs_layers import *      # noqa: E402,F401,F403
 
 
 # Cases of those files that a star import alone does not give tier-1:
@@ -39,6 +47,7 @@ import os                                                   # noqa: E402
 from benchmark.tests import test_hist_data as _hist_cases   # noqa: E402
 from benchmark.tests import test_prom_data as _prom_cases   # noqa: E402
 from benchmark.tests import test_prom_miss_data as _miss_cases  # noqa: E402
+from benchmark.tests import test_wait_layers as _wait_cases     # noqa: E402
 
 # ``hist``'s fill case bears the name of ``prom``'s, which the later import
 # shadows: collected here under a name of its own
@@ -70,6 +79,48 @@ def test_the_configuration_and_the_cell_are_as_named():
     "test_the_prom_cells_are_as_named_whatever_follows_them"))
 def test_prom_miss_configuration_and_cell_are_as_named():
     _miss_cases.test_prom_miss_configuration_and_cell_are_as_named()
+
+
+@pytest.mark.xfail(strict=True, reason=(
+    "benchmark/tests/test_wait_layers.py pins device_ahead_mean's entry to "
+    "exactly six keys, none of them `workloads`. PR 41's cell tsbs_single "
+    "reports query_p50_ms, which that metric moves, and runs no fused "
+    "program, so the reader finds nothing there: the entry gained the list "
+    "of the cells that do report it, as the contract asks, and PR 41 may "
+    "edit no file the benchmark has. A `benchmark` PR has to make that case "
+    "compare the six keys and leave `workloads` to its own (ROADMAP.md queue "
+    "2 item 0 (12)); everything else it says of the five entries is held by "
+    "test_the_five_wait_entries_are_as_named_whatever_cells_they_list"))
+def test_benchmark_json_lists_the_five_with_their_layers():     # noqa: F811
+    _wait_cases.test_benchmark_json_lists_the_five_with_their_layers()
+
+
+def test_the_five_wait_entries_are_as_named_whatever_cells_they_list():
+    """What the pinned case above says of PR 39's five entries, key by key,
+    with ``workloads`` — where an entry has one — held to the cells that
+    report the metric it moves and whose leaves run what it reads."""
+    with open(os.path.join(_miss_cases.ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    per_layer = {m["name"]: m for m in bench["per_layer"]}
+    want = {"lock_hold_pct": ("%", "leaf under the shard lock", "query_rate"),
+            "lock_hold_mean_ms": ("ms", "leaf under the shard lock",
+                                  "query_rate"),
+            "device_ahead_mean": ("programs", "fused kernel", "query_p50_ms"),
+            "wakeup_mean_ms": ("ms", "runtime", "query_rate"),
+            "stall_max_ms": ("ms", "runtime", "query_rate")}
+    assert tuple(want) == _wait_cases.WAIT_LAYERS
+    for name, (unit, layer, moves) in want.items():
+        entry = dict(per_layer[name])
+        cells = entry.pop("workloads", None)
+        assert entry == {"name": name, "unit": unit, "better": "lower",
+                         "source": "program_span", "layer": layer,
+                         "moves": moves}, name
+        assert os.path.isfile(os.path.join(_miss_cases.BENCH, "layers",
+                                           f"{name}.py"))
+        assert (cells is None) == (name != "device_ahead_mean"), name
+    p50 = next(m for m in bench["end_to_end"] if m["name"] == "query_p50_ms")
+    assert per_layer["device_ahead_mean"]["workloads"] == [
+        c for c in p50["workloads"] if c != "tsbs_single"]
 
 
 def test_the_prom_cells_are_as_named_whatever_follows_them():

@@ -440,3 +440,31 @@ def test_mesh_fused_program_compiles_for_four_chips(topo):
     # ... and turns no per-row operand of it to a column (256 MB of
     # temporaries a device, two copies a slot a query, before)
     _no_column_and_no_temp(compiled, per)
+
+
+def test_a_narrow_gather_of_a_grid_store_takes_no_part_of_the_stamp_block(
+        one_chip):
+    """Eight rows of 2^20 x 768 (PR 41). The TPU has no 64-bit lanes: a
+    program that takes the s64 stamp block as an operand splits ALL of it
+    into two u32 planes — 3 GB of temporaries to hand back 48 KB — so the
+    narrow leaf of a grid-form store derives its rows' stamps from their
+    first stamps and gathers the values alone, in one program
+    (``chunkstore._gather_grid``): no store-sized operand but the f32 block
+    and the counts, no temporary."""
+    from filodb_tpu.core import chunkstore
+    C, P8 = 768, 8
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    with jax.enable_x64(True):
+        i64 = jnp.int64
+        taken = jax.jit(lambda ts, rid: jnp.take(ts, rid, axis=0)).lower(
+            sds((S, C), i64), sds((P8,), i32)).compile()
+        gathered = chunkstore._gather_grid.lower(
+            sds((S, C), f32), sds((S,), i32), sds((3, P8), i64), C).compile()
+    was = taken.memory_analysis()
+    assert "X64Split" in taken.as_text()
+    assert was.temp_size_in_bytes >= S * C * 4          # a whole u32 plane
+    text, mem = gathered.as_text(), gathered.memory_analysis()
+    assert f"s64[{S},{C}]" not in text and f"u32[{S},{C}]" not in text
+    assert mem.temp_size_in_bytes < (1 << 20), mem
+    assert mem.argument_size_in_bytes < S * C * 4 + S * 4 + (1 << 16)
+    assert mem.output_size_in_bytes < P8 * C * (8 + 4) + (1 << 12)
