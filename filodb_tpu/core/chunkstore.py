@@ -1474,16 +1474,27 @@ class SeriesStore:
         real; the pad rows come back with n = 0 and TS_PAD all along."""
         if self.ts is None or self.res is not None:
             return None
-        first_ts, iv, C = self.first_ts, int(self.grid_interval), self.C
+        C = self.C
 
         def gather(rows: np.ndarray, live: int, val, n):
-            picked = np.full((3, len(rows)), -1, np.int64)
-            picked[0] = rows
-            picked[1, :live] = first_ts[rows[:live]]
-            picked[2] = iv
-            return _gather_grid(val, n, jnp.asarray(picked), C)
+            return _gather_grid(val, n,
+                                jnp.asarray(self.grid_row_picks(rows, live)),
+                                C)
 
         return gather
+
+    def grid_row_picks(self, rows: np.ndarray, live: int) -> np.ndarray:
+        """All that ``_gather_grid`` needs from the host, int64 ``[3, P]``:
+        the pow2-padded row ids ``rows``, each row's first stamp (-1 past
+        the first ``live``: a pad row) and the interval. A host array: a
+        leaf that composes the gather into its one program
+        (query/exec.py ``GatheredRows``) hands it over as that program's
+        argument."""
+        picked = np.full((3, len(rows)), -1, np.int64)
+        picked[0] = rows
+        picked[1, :live] = self.first_ts[rows[:live]]
+        picked[2] = int(self.grid_interval)
+        return picked
 
     def grid_offsets(self, rows: np.ndarray) -> np.ndarray:
         """Start cell of each given row (its first sample's grid cell index

@@ -610,13 +610,24 @@ def histogram_quantile_np(q, les, counts):
 def periodic_samples_grid(val, n, out_ts: np.ndarray, window_ms: int, fn: str,
                           base_ts: int, interval_ms: int, stale_ms: int = 300_000):
     """Grid-path periodic samples over a uniform-start shard: [S, T] output."""
-    C = val.shape[1]
-    dtype = np.float64 if val.dtype == jnp.float64 else np.float32
-    ops = grid_operands(C, out_ts, window_ms, fn, base_ts, interval_ms, dtype)
     k = _plan("grid",
               (fn,) + tuple(val.shape) + (len(out_ts), str(val.dtype)),
               lambda: functools.partial(_grid_kernel, fn))
-    return k(val, jnp.asarray(n, jnp.int32), ops["band"],
-             ops["band_open"], ops["onehot_lo"], ops["onehot_hi"],
-             ops["lo"], ops["hi"], ops["rel_out"], ops["window_ms"],
-             ops["interval_ms"], jnp.int32(min(stale_ms, 2**31 - 1)))
+    return k(val, jnp.asarray(n, jnp.int32),
+             *grid_kernel_operands(val.shape[1], val.dtype, out_ts,
+                                   window_ms, fn, base_ts, interval_ms,
+                                   stale_ms))
+
+
+def grid_kernel_operands(C: int, val_dtype, out_ts: np.ndarray,
+                         window_ms: int, fn: str, base_ts: int,
+                         interval_ms: int, stale_ms: int) -> tuple:
+    """``_grid_kernel``'s operands after ``(fn, val, n)``, in its order:
+    the cached device arrays of ``grid_operands`` and the staleness bound
+    as a HOST s32 (an argument of the call, no upload of its own)."""
+    dtype = np.float64 if val_dtype == jnp.float64 else np.float32
+    ops = grid_operands(C, out_ts, window_ms, fn, base_ts, interval_ms, dtype)
+    return (ops["band"], ops["band_open"], ops["onehot_lo"],
+            ops["onehot_hi"], ops["lo"], ops["hi"], ops["rel_out"],
+            ops["window_ms"], ops["interval_ms"],
+            np.int32(min(stale_ms, 2**31 - 1)))

@@ -20,6 +20,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import windows as W
 
@@ -276,12 +277,30 @@ def _kernel(fn: str, w_cap: int, acc_name: str, shape_key: tuple,
     executable and the cache's capacity bound actually bounds retained
     programs (functools.cache + jax's internal cache bounded neither)."""
     from ..query.plancache import plan_cache
-    acc = jnp.dtype(acc_name)
     return plan_cache.program(
         "periodic",
         (fn, w_cap, acc_name) + shape_key + (("holes",) if holes else ()),
-        lambda: functools.partial(_periodic, fn, w_cap=w_cap, acc=acc,
-                                  holes=holes))
+        lambda: periodic_body(fn, w_cap, acc_name, holes))
+
+
+def periodic_body(fn: str, w_cap: int = 256, accum: str = "float64",
+                  holes: bool = False):
+    """``_periodic`` with its statics bound: ``(ts, val, n, *operands)`` ->
+    ``[P, T]``, the traceable body of the ``periodic`` program and of a
+    gathered leaf's one program (query/exec.py ``_leaf_body``), which
+    composes it after its gather."""
+    return functools.partial(_periodic, fn, w_cap=w_cap,
+                             acc=jnp.dtype(accum), holes=holes)
+
+
+def periodic_operands(out_ts, window_ms, arg0: float = 0.0,
+                      arg1: float = 0.0) -> tuple:
+    """What the host knows of a ``periodic_body`` call, as HOST values of
+    the types the program was traced with (s64 steps, s64 window, two
+    f64): arguments of the one call, not eager uploads before it — each of
+    those is a dispatch, and a leaf dispatches under its shard's lock."""
+    return (np.asarray(out_ts, np.int64), np.int64(window_ms),
+            np.float64(arg0), np.float64(arg1))
 
 
 HIST_FNS = {"rate", "increase", "delta", "sum_over_time", "last_sample",
@@ -334,6 +353,4 @@ def periodic_samples(ts, val, n, out_ts, window_ms, fn: str,
     """
     S, C = val.shape
     k = _kernel(fn, w_cap, accum, (S, C, len(out_ts), str(val.dtype)), holes)
-    return k(ts, val, n, jnp.asarray(out_ts),
-             jnp.int64(window_ms), jnp.float64(arg0),
-             jnp.float64(arg1))
+    return k(ts, val, n, *periodic_operands(out_ts, window_ms, arg0, arg1))

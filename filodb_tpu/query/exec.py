@@ -32,7 +32,8 @@ from ..core.selection import ShardSelection
 from ..ops import aggregators, binop, instantfns, rangefns
 from ..utils.diagnostics import lock_hold_ns, lock_wait_ns
 from ..utils.metrics import (FILODB_GROUPIDS, FILODB_INDEX_RESOLVE,
-                             FILODB_QUERY_LEAF, registry)
+                             FILODB_QUERY_LEAF, FILODB_QUERY_LEAF_GATHER,
+                             registry)
 from ..utils.tracing import (SPAN_QUERY_GATHER, SPAN_QUERY_GROUPIDS,
                              SPAN_QUERY_KERNEL, SPAN_QUERY_LEAF,
                              SPAN_QUERY_ODP, SPAN_QUERY_REDUCE,
@@ -163,7 +164,7 @@ def _gather_rows_padded(ts, val, n, rows: np.ndarray, grid_gather=None):
     (``SeriesStore.grid_row_gather``): the rows taken by the store's own
     one program, their stamps derived from the grid, where ``ts`` is a
     grid-form store's resident s64 block — which is then no operand."""
-    from ..core.chunkstore import TS_PAD, _Deferred
+    from ..core.chunkstore import _Deferred
     M = len(rows)
     P = _pow2(M)
     pad = np.zeros(P, np.int32)
@@ -171,16 +172,122 @@ def _gather_rows_padded(ts, val, n, rows: np.ndarray, grid_gather=None):
     if grid_gather is not None and not isinstance(val, _Deferred):
         return grid_gather(pad, M, val, n) + (P,)
     rid = jnp.asarray(pad)
-    real = jnp.arange(P) < M
-    n_g = jnp.where(real, jnp.take(n, rid), 0)
+
     # deferred (compressed-resident) blocks gather row-wise — a minority fix
     # over a few rows must not materialize the full [S, C] block
-    ts_rows = (ts.gather_rows(rid) if isinstance(ts, _Deferred)
-               else jnp.take(ts, rid, axis=0))
-    val_rows = (val.gather_rows(rid) if isinstance(val, _Deferred)
-                else jnp.take(val, rid, axis=0))
-    ts_g = jnp.where(real[:, None], ts_rows, TS_PAD)
-    return ts_g, val_rows, n_g.astype(jnp.int32), P
+    def take(block):
+        return (block.gather_rows(rid) if isinstance(block, _Deferred)
+                else jnp.take(block, rid, axis=0))
+
+    ts_g, n_g = _disable_pad_rows(take(ts), jnp.take(n, rid), M)
+    return ts_g, take(val), n_g, P
+
+
+def _disable_pad_rows(ts_rows, n_rows, live):
+    """(ts, n) of gathered rows with those past the first ``live`` disabled:
+    n = 0 and TS_PAD all along (see ``_gather_rows_padded``)."""
+    from ..core.chunkstore import TS_PAD
+    real = jnp.arange(n_rows.shape[0]) < live
+    return (jnp.where(real[:, None], ts_rows, TS_PAD),
+            jnp.where(real, n_rows, 0).astype(jnp.int32))
+
+
+def _take_rows(ts, val, n, rid, live):
+    """``_gather_rows_padded`` of resident blocks as a traceable function of
+    its operands: the store's ``(ts, val, n)``, then what the host knows —
+    the pow2-padded row ids and how many of them are real."""
+    ts_g, n_g = _disable_pad_rows(jnp.take(ts, rid, axis=0),
+                                  jnp.take(n, rid), live)
+    return ts_g, jnp.take(val, rid, axis=0), n_g
+
+
+# device dispatches of a gathered leaf in its STEPWISE form, as its gather
+# span's ``programs`` says them (a tag, not a profiler: the form's own
+# constant): the gather's — an upload and the store's one program on a grid;
+# ids, mask, three takes, two selects and a cast off it — and the window
+# program after it, the least that follows
+STEPWISE_PROGRAMS = {True: 3, False: 10}
+
+
+def _joins_one_program(ts, val, les, minority_sel, on_grid: bool) -> bool:
+    """May a narrow selection's gather join the leaf's one program
+    (``GatheredRows``)? By what the selection can observe, no knob: scalar
+    rows of a RESIDENT value block — a compressed-resident one decodes row
+    by row on the host's say (``DeferredDecode.gather_rows``) — whose stamps
+    are the grid's (``on_grid``) or a resident s64 block — a line store's
+    are laid together from host and device state (``SeriesStore._line_ts``)
+    — all of one start cohort: a minority's rows are gathered again from the
+    gathered ones (``_correct_minority_cohort``)."""
+    from ..core.chunkstore import _Deferred
+    return (les is None and minority_sel is None
+            and not isinstance(val, _Deferred)
+            and (on_grid or not isinstance(ts, _Deferred)))
+
+
+def count_gather(tags: dict, programs: int) -> None:
+    """How a gathered leaf reached the device, on its gather span
+    (``programs``: 1 = gather, window function, step slice and the
+    aggregate's map phase dispatched as ONE program; more = the stepwise
+    form's dispatches) and in ``/metrics``."""
+    tags["programs"] = programs
+    registry.counter(FILODB_QUERY_LEAF_GATHER,
+                     {"form": "one" if programs == 1 else "steps"}).increment()
+
+
+@dataclass
+class GatheredRows:
+    """Leaf output of a NARROW selection whose rows are still in the store:
+    what ``SeriesSelection``'s second state carries, less the three gathered
+    device arrays. The gather has not run; it is composed into the ONE
+    program the leaf dispatches under its shard's lock (``GatheredWindow``):
+    every eager dispatch there is a release of the interpreter and a wait to
+    get it back while the shard's other clients queue behind the lock.
+
+    What the selection could observe decided this form (``_select``): a
+    scalar store on the gather route, its value block resident, its stamps
+    the grid's (``on_grid``: derived in the program from each row's first
+    stamp, ``SeriesStore.grid_row_picks``) or a resident s64 block, no row
+    of a minority cohort. Everything else is gathered in ``_select``, step
+    by step, as it was."""
+    store_ops: tuple          # (val, n) on a grid, else (ts, val, n): [S, ..]
+    host_ops: tuple           # (picked s64 [3, P],) | (row ids s32 [P], live)
+    on_grid: bool
+    shape: tuple              # (P, C) of the rows once gathered
+    dtype: object             # ... and their values' dtype
+    keys: list[RangeVectorKey]
+    rows: np.ndarray | None   # identity map [0..len(keys)) where P is padded
+    grid: tuple | None
+    span_tags: dict           # the gather span's: shard, rows, padded, bytes
+    _sel: SeriesSelection | None = None
+
+    # SeriesSelection's fields that are never set on this form
+    bucket_les = None
+    grid_minority = None
+    line = None
+    holes = False
+
+    def gathered(self) -> SeriesSelection:
+        """The rows gathered on their own, the stepwise form: for a
+        consumer that is no window function of this module (the fused
+        tier's kernel takes gathered rows; a raw selection leaving the
+        leaf)."""
+        if self._sel is None:
+            with span(SPAN_QUERY_GATHER, **self.span_tags) as tags:
+                count_gather(tags, STEPWISE_PROGRAMS[self.on_grid])
+                ts, val, n = self.gather_body()(
+                    *self.store_ops, *map(jnp.asarray, self.host_ops))
+            self._sel = SeriesSelection(ts, val, n, self.keys, self.rows,
+                                        self.grid)
+        return self._sel
+
+    def gather_body(self):
+        """``(*store_ops, *host_ops) -> (ts, val, n)``, traceable: the
+        bodies the stepwise gather runs."""
+        if not self.on_grid:
+            return _take_rows
+        from ..core.chunkstore import _gather_grid
+        C = self.shape[1]
+        return lambda val, n, picked: _gather_grid(val, n, picked, C)
 
 
 def check_sample_limit(num_series: int, steps: int, limit: int) -> None:
@@ -210,14 +317,22 @@ class FusedWindowData:
     fuses window evaluation + aggregation into one single-pass Pallas kernel
     (ops/fusedgrid.py) — the [S, T] rate matrix never hits HBM. Any other
     consumer materializes through the standard grid kernel first."""
-    sel: SeriesSelection
+    sel: "SeriesSelection | GatheredRows"
     out_ts: np.ndarray
     window: int
     fn: str
     stale_ms: int
 
+    def unfused(self) -> "GatheredWindow":
+        """Over rows not gathered yet, with no fused aggregate to run: the
+        grid kernel composed after their gather."""
+        return GatheredWindow(self.sel, self.out_ts, "grid", self.fn,
+                              self.window, (0.0, 0.0), self.stale_ms)
+
     def materialize(self) -> MatrixView:
         from ..ops import gridfns
+        if isinstance(self.sel, GatheredRows):
+            return self.unfused().materialize()
         # same T-bucketing as PSM.apply: this fallback otherwise re-opens the
         # per-dashboard-shape compile cost on the hot f32 path
         out_eval, T = _pad_steps(self.out_ts)
@@ -240,6 +355,135 @@ class FusedWindowData:
         if vals.shape[1] != T:
             vals = vals[:, :T]
         return MatrixView(self.out_ts, vals, self.sel.keys, self.sel.rows)
+
+
+@dataclass
+class GatheredWindow:
+    """Lazy PeriodicSamplesMapper output over ``GatheredRows``: neither the
+    gather nor the window function has run. ``AggregateMapReduce`` composes
+    its map phase onto them (``aggregate``), any other consumer asks for the
+    matrix (``materialize``); either way ONE program is dispatched, from the
+    plan cache, and what the host knows — the picked rows, the steps, the
+    window, the function's arguments, the group ids — goes in as host
+    values, the call's own arguments. Must not leave the shard lock
+    undispatched (``_execute_leaf``), like ``FusedWindowData``."""
+    sel: GatheredRows
+    out_ts: np.ndarray
+    kernel: str               # "grid" | "periodic": PSM.apply's own choice
+    fn: str
+    window: int
+    args: tuple               # (arg0, arg1) of a "periodic" function
+    stale_ms: int
+
+    def materialize(self) -> MatrixView:
+        return MatrixView(self.out_ts, self._dispatch(), self.sel.keys,
+                          self.sel.rows)
+
+    def aggregate(self, op: str, gids: np.ndarray, num_groups: int) -> dict:
+        """``_segment_partial`` of the matrix, in the same program."""
+        return self._dispatch(op, np.asarray(gids, np.int32), num_groups)
+
+    def _dispatch(self, op=None, gids=None, num_groups=0):
+        from ..ops import gridfns
+        from .plancache import plan_cache
+        sel = self.sel
+        out_eval, T = _pad_steps(self.out_ts)
+        if self.kernel == "grid":
+            win_ops = gridfns.grid_kernel_operands(
+                sel.shape[1], sel.dtype, out_eval, self.window, self.fn,
+                *sel.grid, self.stale_ms)
+        else:
+            win_ops = rangefns.periodic_operands(out_eval, self.window,
+                                                 *self.args)
+        spec, *packed = _pack_operands(
+            (*sel.host_ops, *win_ops) + (() if op is None else (gids,)))
+        statics = (self.kernel, self.fn, op, num_groups, T, spec,
+                   len(sel.host_ops))
+        prog = plan_cache.program(
+            "leaf",
+            statics + (sel.on_grid, sel.store_ops[-1].shape[0]) + sel.shape
+            + (str(sel.dtype),),
+            lambda: functools.partial(_leaf_body, sel.gather_body(),
+                                      *statics))
+        with span(SPAN_QUERY_GATHER, **sel.span_tags) as tags:
+            count_gather(tags, 1)
+            return prog(sel.store_ops, *packed)
+
+
+def _pack_operands(operands) -> tuple:
+    """``(spec, ints, floats, device)`` of a program's operands: what the
+    host knows laid into ONE s64 and at most one f64 host vector (None
+    where there is no float), the device arrays as they are, and ``spec``
+    (static, hashable) to take them apart again inside the program
+    (``_unpack_operands``). Every host argument of a jitted call is a
+    transfer of its own, and on the chip each costs the shard lock's holder
+    ~0.4 ms (the interpreter let go and won back: PERF.md §6 PR 42) — seven
+    small arguments were most of a narrow leaf's hold."""
+    spec, ints, floats, device = [], [], [], []
+    for x in operands:
+        if isinstance(x, jax.Array):
+            spec.append(("device", None, None))
+            device.append(x)
+            continue
+        x = np.asarray(x)
+        kind = "float" if x.dtype.kind == "f" else "int"
+        (floats if kind == "float" else ints).append(x.ravel())
+        spec.append((kind, x.shape, x.dtype.name))
+    return (tuple(spec), np.concatenate(ints).astype(np.int64),
+            np.concatenate(floats).astype(np.float64) if floats else None,
+            tuple(device))
+
+
+def _unpack_operands(spec, ints, floats, device) -> list:
+    """The operands ``_pack_operands`` took, in their order, shapes and
+    dtypes (every value exactly: s64 holds the integer types, f64 the
+    floats)."""
+    out, at = [], {"int": 0, "float": 0, "device": 0}
+    for kind, shape, dtype in spec:
+        if kind == "device":
+            out.append(device[at[kind]])
+            at[kind] += 1
+            continue
+        size = int(np.prod(shape, dtype=np.int64))
+        src = ints if kind == "int" else floats
+        out.append(src[at[kind]:at[kind] + size].reshape(shape).astype(dtype))
+        at[kind] += size
+    return out
+
+
+def _leaf_body(gather, kernel, fn, op, num_groups, T, spec, n_host,
+               store_ops, ints, floats, device):
+    """THE one program of a gathered leaf: the row gather, the window
+    function, the step-pad slice and the aggregate's map phase — the bodies
+    the stepwise form dispatches one by one, composed unchanged (so the
+    answers are that form's, bit for bit). Its operands: the store's blocks,
+    then the gather's ``n_host`` host operands, the window kernel's and, with
+    an aggregate, the group ids, packed (``_pack_operands``)."""
+    from ..ops import gridfns
+    ops = _unpack_operands(spec, ints, floats, device)
+    gids = None if op is None else ops.pop()
+    ts, val, n = gather(*store_ops, *ops[:n_host])
+    if kernel == "grid":
+        vals = gridfns._grid_kernel(fn, val, n, *ops[n_host:])
+    else:
+        vals = rangefns.periodic_body(fn)(ts, val, n, *ops[n_host:])
+    vals = vals[:, :T]
+    if op is None:
+        return vals
+
+    def reduce(vals, gids):
+        return aggregators.partial_aggregate(op, vals, gids,
+                                             num_groups=num_groups,
+                                             stable=True)
+
+    # The reduce as a computation of its own, as it is a program of its own
+    # in the stepwise form: fused with the window function's last
+    # arithmetic the compiler contracts other multiplies and adds into one
+    # fma (XLA:CPU drops an optimization barrier before it fuses), and
+    # quantile_over_time's interpolation comes out another last bit. A group
+    # id is never negative; the compiler cannot know, and no fusion crosses
+    # a conditional.
+    return jax.lax.cond(gids[0] >= 0, reduce, reduce, vals, gids)
 
 
 def _correct_minority_cohort(data, vals, out_ts, window, fn, a0, a1,
@@ -287,7 +531,8 @@ class PeriodicSamplesMapper(Transformer):
         return np.arange(self.start_ms, self.end_ms + 1, step, dtype=np.int64)
 
     def apply(self, data, ctx: QueryContext):
-        assert isinstance(data, SeriesSelection), "PSM must sit directly on a leaf"
+        assert isinstance(data, (SeriesSelection, GatheredRows)), \
+            "PSM must sit directly on a leaf"
         out_ts = self.out_ts(ctx)
         if len(out_ts) == 0:
             return MatrixView(out_ts, np.zeros((len(data.keys), 0)),
@@ -351,12 +596,19 @@ class PeriodicSamplesMapper(Transformer):
         if data.line is not None and self._line_fusable(data, fn, window,
                                                         out_ts):
             return FusedWindowData(data, out_ts, window, fn, ctx.stale_ms)
-        if grid_usable and fn in gridfns.GRID_FNS:
-            if self._fusable(data, fn, out_ts):
-                # defer: a following AggregateMapReduce can fuse the window
-                # function with the aggregation in one single-pass program
-                # (Pallas or the XLA-fused twin per query.fused_kernels)
-                return FusedWindowData(data, out_ts, window, fn, ctx.stale_ms)
+        on_grid_fn = grid_usable and fn in gridfns.GRID_FNS
+        if on_grid_fn and self._fusable(data, fn, out_ts):
+            # defer: a following AggregateMapReduce can fuse the window
+            # function with the aggregation in one single-pass program
+            # (Pallas or the XLA-fused twin per query.fused_kernels)
+            return FusedWindowData(data, out_ts, window, fn, ctx.stale_ms)
+        if isinstance(data, GatheredRows):
+            # rows not gathered yet: the kernel this method would run now,
+            # composed with their gather and whatever follows
+            return GatheredWindow(data, out_ts,
+                                  "grid" if on_grid_fn else "periodic", fn,
+                                  window, (a0, a1), ctx.stale_ms)
+        if on_grid_fn:
             base_ts, interval_ms = data.grid
             vals = gridfns.periodic_samples_grid(_dval(data.val), data.n,
                                                  out_eval, window,
@@ -378,10 +630,11 @@ class PeriodicSamplesMapper(Transformer):
     def _fusable(data, fn, out_ts) -> bool:
         """May a following aggregate fuse with this window function?"""
         from ..ops import fusedgrid, fusedresident
-        S, C = data.val.shape
+        block = data if isinstance(data, GatheredRows) else data.val
+        S, C = block.shape
         return (fusedresident.mode() != "off"
                 and fusedresident.scalar_shape_of(fn) is not None
-                and data.val.dtype == jnp.float32
+                and block.dtype == jnp.float32
                 and fusedgrid.fusable(S, C, len(out_ts), 1))
 
     @classmethod
@@ -665,7 +918,15 @@ class AggregateMapReduce(Transformer):
                 fused = self._apply_fused(data, ctx)
                 if fused is not None:
                     return fused
-            data = data.materialize()
+            data = (data.unfused() if isinstance(data.sel, GatheredRows)
+                    else data.materialize())
+        if isinstance(data, GatheredWindow):
+            P = data.sel.shape[0]
+            gids, uniq, G = _group_ids_for(data.sel.keys, data.sel.rows, P,
+                                           self.by, self.without)
+            return AggPartial(self.operator, data.out_ts,
+                              data.aggregate(self.operator, gids, _pow2(G)),
+                              list(uniq), G, None)
         if isinstance(data, MatrixView):
             m = data
         else:
@@ -691,7 +952,8 @@ class AggregateMapReduce(Transformer):
         caller falls back to the two-step path (segment_sum handles large G)."""
         from ..ops import fusedgrid, fusedresident
         sel = data.sel
-        R = sel.val.shape[0]
+        lazy = isinstance(sel, GatheredRows)
+        R = sel.shape[0] if lazy else sel.val.shape[0]
         gids, uniq, G, gids_dev = _grouping_for(sel.keys, sel.rows, R,
                                                 self.by, self.without)
         Gp = _pow2(G)
@@ -699,6 +961,9 @@ class AggregateMapReduce(Transformer):
             fusedresident.count_fallback(
                 fusedresident.scalar_shape_of(data.fn) or "rate_sum")
             return None
+        if lazy:
+            # the fused kernel takes gathered rows: the stepwise gather
+            sel = data.sel = sel.gathered()
         line = None
         if sel.grid is not None:
             base_ts, interval_ms = sel.grid
@@ -758,7 +1023,7 @@ class AggregateMapReduce(Transformer):
         state instead of shipping the full [P, T] matrix to the reduce node
         (ref: RowAggregator partial state incl. t-digest,
         AggrOverRangeVectors.scala:244-)."""
-        if isinstance(data, FusedWindowData):
+        if isinstance(data, (FusedWindowData, GatheredWindow)):
             data = data.materialize()
         if isinstance(data, MatrixView):
             m = data
@@ -1214,14 +1479,14 @@ def _go_to_py_template(s: str) -> str:
 def _as_matrix(data) -> ResultMatrix:
     if isinstance(data, ResultMatrix):
         return data
-    if isinstance(data, FusedWindowData):
+    if isinstance(data, (FusedWindowData, GatheredWindow)):
         return data.materialize().compact()
     if isinstance(data, MatrixView):
         return data.compact()
     if isinstance(data, (AggPartial, TopKPartial, SketchPartial,
                          CountValuesPartial)):
         raise QueryError("aggregate partial where matrix expected (missing presenter)")
-    if isinstance(data, SeriesSelection):
+    if isinstance(data, (SeriesSelection, GatheredRows)):
         raise QueryError("raw series where matrix expected (missing periodic mapper)")
     raise TypeError(type(data))
 
@@ -1299,10 +1564,12 @@ class SelectRawPartitionsExec(ExecPlan):
         try:
             with shard.lock:
                 result = super().execute(ctx)
-                if isinstance(result, FusedWindowData):
+                if isinstance(result, (FusedWindowData, GatheredWindow)):
                     # a lazy window view must not escape the lock: its kernel
                     # dispatch would race a concurrent ingest flush's donation
                     result = result.materialize()
+                elif isinstance(result, GatheredRows):
+                    result = result.gathered()      # nor rows not gathered
         except RuntimeError as e:
             # use-after-donation detective (ref: BlockDetective): name the
             # donation site instead of jax's opaque "Array has been deleted"
@@ -1500,23 +1767,37 @@ class SelectRawPartitionsExec(ExecPlan):
         holes = store.res is not None and store.hole_cells > 0
         if store.res is not None:
             tags["hole_cells"], tags["used_cells"] = picked.cells(store)
+        from ..core.chunkstore import _Deferred
         if len(pids) <= GATHER_THRESHOLD and len(pids) < 0.5 * max(total, 1):
             # narrow selection: gather rows once, padded to a power of two
             ctx.stats.add("blocks_raw")
             count_leaf(ctx, tags, "gather")
-            with span(SPAN_QUERY_GATHER, shard=self.shard,
-                      rows=len(pids)) as gtags:
-                sel_ts, sel_val, sel_n, P = _gather_rows_padded(
-                    ts, val, n, pids,
-                    store.grid_row_gather() if on_grid and ts is store.ts
-                    else None)
-                gtags["padded"] = P
-                gtags["bytes"] = len(pids) * (
-                    int(np.prod(val.shape[1:])) * np.dtype(val.dtype).itemsize
-                    + ts.shape[1] * 8)
+            M, P = len(pids), _pow2(len(pids))
+            gtags = dict(shard=self.shard, rows=M, padded=P, bytes=M * (
+                int(np.prod(val.shape[1:])) * np.dtype(val.dtype).itemsize
+                + ts.shape[1] * 8))
             # P > len(pids): arrays carry pad rows beyond the keys — expose the
             # identity row map so downstream compaction/group-scatter skips them
-            sel_rows = None if P == len(pids) else np.arange(len(pids), dtype=np.int32)
+            sel_rows = None if P == M else np.arange(M, dtype=np.int32)
+            grid_gather = (store.grid_row_gather() if on_grid
+                           and ts is store.ts
+                           and not isinstance(val, _Deferred) else None)
+            on_g = grid_gather is not None
+            if _joins_one_program(ts, val, les, minority_sel, on_g):
+                # the gather joins the window function's program; its span
+                # opens where that program is dispatched
+                pad = np.zeros(P, np.int32)
+                pad[:M] = pids
+                return GatheredRows(
+                    (val, n) if on_g else (ts, val, n),
+                    (store.grid_row_picks(pad, M),) if on_g
+                    else (pad, np.int32(M)),
+                    on_g, (P, val.shape[1]), val.dtype, keys, sel_rows, grid,
+                    gtags)
+            with span(SPAN_QUERY_GATHER, **gtags) as gtags:
+                count_gather(gtags, STEPWISE_PROGRAMS[on_g])
+                sel_ts, sel_val, sel_n, _ = _gather_rows_padded(
+                    ts, val, n, pids, grid_gather)
             g_min = (np.nonzero(minority_sel)[0].astype(np.int32)
                      if minority_sel is not None else None)
             return SeriesSelection(sel_ts, sel_val, sel_n, keys, sel_rows, grid, les,
@@ -1559,7 +1840,6 @@ class SelectRawPartitionsExec(ExecPlan):
         ctx.stats.add("blocks_narrow"
                       if (narrow is not None or hist_narrow is not None)
                       else "blocks_raw")
-        from ..core.chunkstore import _Deferred
         if line is not None and (val.ndim != 2 or narrow is not None
                                  or isinstance(val, _Deferred)):
             line = None

@@ -47,6 +47,7 @@ FILODB_SHARD_LOCK_HOLD_SECONDS = "filodb_shard_lock_hold_seconds"
 FILODB_GROUPIDS = "filodb_groupids"
 FILODB_SELECTION_MEMO = "filodb_selection_memo"
 FILODB_QUERY_LEAF = "filodb_query_leaf"
+FILODB_QUERY_LEAF_GATHER = "filodb_query_leaf_gather"
 FILODB_INDEX_RESOLVE = "filodb_index_resolve"
 FILODB_QUERY_LATENCY_MS = "filodb_query_latency_ms"
 FILODB_QUERY_SLOW = "filodb_query_slow"
@@ -186,6 +187,17 @@ METRICS_SPEC: dict[str, tuple[str, str]] = {
                    "gathered to a power of two), wide (the store's own "
                    "blocks, n zeroed outside the selection) or paged (cold "
                    "chunks merged in from the sink)."),
+    FILODB_QUERY_LEAF_GATHER: (
+        "counter", "Gathered leaves (route = gather, a row at least) by how "
+                   "they reached the device under the shard lock: form = "
+                   "one (the row gather, the window function, the step "
+                   "slice and the aggregate's map phase dispatched as ONE "
+                   "program, the host's scalars its arguments: a resident "
+                   "scalar block whose stamps are the grid's or a resident "
+                   "s64 block, one start cohort) or steps (gathered on its "
+                   "own, the kernels after it one dispatch each: a "
+                   "compressed-resident, line-form or histogram store, a "
+                   "churned cohort, rows a fused kernel takes)."),
     FILODB_INDEX_RESOLVE: (
         "counter", "Leaf selects by whether the index had to resolve the "
                    "filter set: outcome = miss (matcher set algebra ran, "

@@ -154,14 +154,23 @@ TRACE_SPEC: dict[str, str] = {
                        "had the part ids; route = gather | wide | paged: "
                        "how the leaf took its rows; the keys of a gather "
                        "are materialized inside this span).",
-    SPAN_QUERY_GATHER: "The row gather of a narrow selection (at most "
-                       "GATHER_THRESHOLD series and under half the index), "
-                       "inside the select span: values, counts and stamps "
-                       "of the selected rows, padded to a power of two; the "
-                       "stamps of a grid-form store are derived from each "
-                       "row's first stamp, its s64 block is no operand "
-                       "(tags: shard, rows, padded, bytes = rows x a row's "
-                       "values and stamps, what the gather needs).",
+    SPAN_QUERY_GATHER: "A narrow selection's rows reaching the device (at "
+                       "most GATHER_THRESHOLD series and under half the "
+                       "index). programs = 1: the dispatch of the leaf's "
+                       "ONE program, under the leaf span after the select "
+                       "span: row gather padded to a power of two, window "
+                       "function, step slice and the aggregate's map phase, "
+                       "with the picked rows, steps, window, arguments and "
+                       "group ids as host arguments of the call (packed: one "
+                       "s64 and one f64 vector). programs "
+                       "> 1: the stepwise form, the gather alone, inside the "
+                       "select span (or where a fused kernel asked for the "
+                       "rows), the count its form's dispatches at the "
+                       "least. The stamps of a grid-form store are derived "
+                       "from each row's first stamp, its s64 block is no "
+                       "operand (tags: shard, rows, padded, bytes = rows x "
+                       "a row's values and stamps, what the gather needs, "
+                       "programs).",
     SPAN_QUERY_GROUPIDS: "Group ids of the selected series for a "
                          "by/without aggregation: from the index's label "
                          "columns where the selection is still pids "

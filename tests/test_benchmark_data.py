@@ -13,7 +13,9 @@ reader (``test_fall_layer.py``, PR 40), and ``tsbs_cpu``'s: the walk and the
 tags, the reference against its brute-force twin, the fill, the served path
 for the twelve text kinds, probes, the generated traffic file, the files
 (``test_tsbs_data.py``) with the four readers of what a narrow leaf does
-(``test_tsbs_layers.py``, PR 41), every case under a name of its own.
+(``test_tsbs_layers.py``, PR 41) and the reader of how often a gathered
+leaf ran as one program (``test_gather_fused_layer.py``, PR 42), every case
+under a name of its own.
 They run in seconds on the CPU, and what they pin is the yardstick: tier-1
 collects them here, under their own names, so that the floor counts them.
 """
@@ -26,7 +28,8 @@ for _mod in ("benchmark.tests.test_data", "benchmark.tests.test_hist_data",
              "benchmark.tests.test_wait_layers",
              "benchmark.tests.test_fall_layer",
              "benchmark.tests.test_tsbs_data",
-             "benchmark.tests.test_tsbs_layers"):
+             "benchmark.tests.test_tsbs_layers",
+             "benchmark.tests.test_gather_fused_layer"):
     pytest.register_assert_rewrite(_mod)
 
 from benchmark.tests.test_data import *        # noqa: E402,F401,F403
@@ -37,6 +40,7 @@ from benchmark.tests.test_wait_layers import *      # noqa: E402,F401,F403
 from benchmark.tests.test_fall_layer import *       # noqa: E402,F401,F403
 from benchmark.tests.test_tsbs_data import *        # noqa: E402,F401,F403
 from benchmark.tests.test_tsbs_layers import *      # noqa: E402,F401,F403
+from benchmark.tests.test_gather_fused_layer import *   # noqa: E402,F401,F403
 
 
 # Cases of those files that a star import alone does not give tier-1:

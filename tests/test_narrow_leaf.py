@@ -23,8 +23,8 @@ from filodb_tpu.query import exec as qexec
 from filodb_tpu.query.engine import QueryEngine
 from filodb_tpu.utils.metrics import (FILODB_INDEX_RESOLVE,
                                       FILODB_QUERY_LEAF, registry)
-from filodb_tpu.utils.tracing import (SPAN_QUERY_GATHER, SPAN_QUERY_SELECT,
-                                      tracer)
+from filodb_tpu.utils.tracing import (SPAN_QUERY_GATHER, SPAN_QUERY_LEAF,
+                                      SPAN_QUERY_SELECT, tracer)
 
 BASE, IV = 1_700_000_000_000, 10_000
 HOSTS, FIELDS, SCRAPES = 24, 4, 40
@@ -211,13 +211,16 @@ def test_the_select_span_says_matchers_resolution_and_route_and_the_gather_its_r
         spans = tracer.drain()
         (sel,) = [s for s in spans if s.name == SPAN_QUERY_SELECT]
         (gat,) = [s for s in spans if s.name == SPAN_QUERY_GATHER]
-        assert gat.parent_id == sel.span_id
+        (leaf,) = [s for s in spans if s.name == SPAN_QUERY_LEAF]
+        # since PR 42 the gather is in the leaf's one program: its span is
+        # that program's dispatch, beside the select span, not inside it
+        assert gat.parent_id == leaf.span_id == sel.parent_id
         assert gat.tags == {"shard": 0, "rows": 3, "padded": 8,
-                            "bytes": 3 * sh.store.C * (4 + 8)}
+                            "bytes": 3 * sh.store.C * (4 + 8),
+                            "programs": 1}
         assert sel.tags["series"] == 3 and sel.tags["route"] == "gather"
         assert sel.tags["matchers"] == "eq+ne+re" and sel.tags["memo"] \
             == "bypass"
-        assert sel.duration_us >= gat.duration_us
         seen.append(sel.tags["resolve"])
     assert seen == ["miss", "hit"]      # the index's filter cache had it
     # a wide selection: no gather span

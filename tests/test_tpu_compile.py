@@ -468,3 +468,40 @@ def test_a_narrow_gather_of_a_grid_store_takes_no_part_of_the_stamp_block(
     assert mem.temp_size_in_bytes < (1 << 20), mem
     assert mem.argument_size_in_bytes < S * C * 4 + S * 4 + (1 << 16)
     assert mem.output_size_in_bytes < P8 * C * (8 + 4) + (1 << 12)
+
+
+@pytest.mark.parametrize("P", [1, 8])
+def test_a_gathered_leafs_one_program_compiles_for_v5e_with_no_block_temp(
+        one_chip, P):
+    """The one program of a narrow leaf (PR 42: ``exec._leaf_body``) at the
+    size ``tsbs_single`` runs it — 1 or 8 rows of 2^20 x 768, gather with
+    derived stamps, ``max_over_time`` over 64 padded steps, the slice to 61
+    and the ``max`` aggregate's map phase — takes the f32 block and the
+    counts as its only store-sized operands, the host's values as small
+    ones, and holds no temporary of a block's size."""
+    import functools
+
+    from filodb_tpu.core import chunkstore
+    from filodb_tpu.query import exec as qexec
+    C, T, Tpad = 768, 61, 64
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    with jax.enable_x64(True):
+        i64, f64 = jnp.int64, jnp.float64
+        # what GatheredWindow._dispatch packs: the picked rows, the step
+        # grid, the window, the function's two arguments, the group ids
+        spec, ints, floats, _dev = qexec._pack_operands((
+            np.zeros((3, P), np.int64), np.zeros(Tpad, np.int64),
+            np.int64(60_000), np.float64(0), np.float64(0),
+            np.zeros(P, np.int32)))
+        body = functools.partial(
+            qexec._leaf_body,
+            lambda val, n, picked: chunkstore._gather_grid(val, n, picked, C),
+            "periodic", "max_over_time", "max", 1, T, spec, 1)
+        compiled = jax.jit(body).lower(
+            (sds((S, C), f32), sds((S,), i32)), sds(ints.shape, i64),
+            sds(floats.shape, f64), ()).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert f"s64[{S},{C}]" not in text and f"u32[{S},{C}]" not in text
+    assert mem.temp_size_in_bytes < (64 << 20), mem
+    assert mem.argument_size_in_bytes < S * C * 4 + S * 4 + (1 << 16)
+    assert mem.output_size_in_bytes < (1 << 16)
