@@ -36,6 +36,12 @@ log = logging.getLogger(__name__)
 
 TS_PAD = np.int64(1) << np.int64(62)   # sentinel > any real timestamp
 
+
+class DecodeRefused(RuntimeError):
+    """A query asked for the whole f32 / s64 view of a store that is held
+    narrow, and the device has no room for it
+    (``SeriesStore.check_decode_budget``)."""
+
 # How the store keeps time. While every series is scraped on one common grid
 # (sample k at ``first + k * interval``, every first stamp on the grid, no
 # scrape missed) column k of a row is the row's k-th sample, the s64 block
@@ -192,6 +198,12 @@ def _free_rows(ts, n, pids):
     return ts, n
 
 
+def _pow2_rows(m: int) -> int:
+    """The next power of two at or over ``m``: the shapes of the pool's
+    few-row programs."""
+    return 1 << max(int(m) - 1, 0).bit_length()
+
+
 def _pad_size(m: int) -> int:
     """Bucket flush sizes to powers of two to bound jit recompilations."""
     size = 1024
@@ -284,6 +296,108 @@ def _zero_counts(n, pids):
     return n.at[pids].set(0, mode="drop")
 
 
+# -- the delta form mutated IN PLACE ------------------------------------------
+#
+# A scalar gauge store on a grid whose values are a delta block (``dv`` int8
+# or int16 ``[S, C]``, ``anchor`` f32 ``[S]``: v[c] = anchor + sum(dv[:c + 1]),
+# dv[:, 0] = 0) and whose stamps are elided is appended to, aged out and
+# freed AS IT IS: the f32 ``[S, C]`` block and the s64 one are never built
+# (at 2^20 x 4,608 they are 19 and 39 GB). The host keeps what an append has
+# to know of a row — its last value and the value every sample of it lies
+# within 2^23 of (``last_val``, ``ref_val``) — so a delta is one subtraction
+# on the host and the device writes a byte a sample. A sample that does not
+# fit (a delta that is no integer or past the width, a value 2^23 from the
+# row's reference) moves ITS ROW to the raw pool with the row's decoded
+# history, one row's decode; the row is exact there ever after.
+DELTA_LIMIT = {"delta8": 127, "delta16": 32767}
+PREFIX_MAX = float(1 << 23)
+# rows a blocked pass of the in-place form handles at a time: its
+# temporaries are a block's, at most 2^15 x C x (1 B shifted + 4 B index)
+BLOCK_ROWS = 1 << 15
+REHYDRATE_CAUSES = ("append", "compact", "free", "off_grid", "cohort_gate")
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _scatter_append_delta(dv, n, rows, cols, d, counts_add):
+    return dv.at[rows, cols].set(d, mode="drop"), n + counts_add
+
+
+def _delta_rows(dv, anchor, rid):
+    """f32 ``[P, C]`` of the rows ``rid`` of a delta block, no pool laid
+    over them."""
+    dvg = jnp.take(dv, rid, axis=0).astype(jnp.float32)
+    return jnp.take(anchor, rid)[:, None] + jnp.cumsum(dvg, axis=1)
+
+
+@functools.partial(jax.jit, donate_argnums=(2, 3), static_argnums=(5,))
+def _pool_admit(dv, anchor, pool, slot, picks, Rp):
+    """Rows ``picks[0]`` (pads: S) leave the delta form for the pool slots
+    ``picks[1]`` (pads: ``Rp``) with their decoded history; the pool grows
+    to ``Rp`` rows where it has fewer."""
+    rid = picks[0]
+    hist = _delta_rows(dv, anchor, jnp.minimum(rid, dv.shape[0] - 1))
+    if Rp > pool.shape[0]:
+        pool = jnp.concatenate(
+            [pool, jnp.zeros((Rp - pool.shape[0], pool.shape[1]),
+                             pool.dtype)])
+    return (pool.at[picks[1]].set(hist, mode="drop"),
+            slot.at[rid].set(picks[1], mode="drop"))
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _pool_write(pool, slots, cols, v):
+    return pool.at[slots, cols].set(v, mode="drop")
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _clear_slots(slot, pids):
+    return slot.at[pids].set(-1, mode="drop")
+
+
+def _shift_left(block, k, n):
+    """Each row of ``block`` moved left by ``k[row]`` cells, zeros past the
+    ``n[row] - k[row]`` it keeps."""
+    C = block.shape[1]
+    col = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1)
+    idx = col + k[:, None]
+    kept = jnp.take_along_axis(block, jnp.minimum(idx, C - 1), axis=1)
+    return jnp.where(idx < n[:, None], kept, 0)
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1, 2), static_argnums=(5,))
+def _compact_delta_block(dv, anchor, n, k, r0, rows):
+    """Rows ``r0 .. r0 + rows`` of a delta block aged out by ``k`` cells a
+    row: the anchor moves on to the first sample kept, the deltas after it
+    shift left (the one AT it becomes the row's zero)."""
+    C = dv.shape[1]
+    blk = jax.lax.dynamic_slice(dv, (r0, jnp.zeros((), r0.dtype)), (rows, C))
+    kb = jax.lax.dynamic_slice(k, (r0,), (rows,))
+    nb = jax.lax.dynamic_slice(n, (r0,), (rows,))
+    ab = jax.lax.dynamic_slice(anchor, (r0,), (rows,))
+    col = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1)
+    head = jnp.sum(jnp.where(col <= kb[:, None], blk, 0), axis=1,
+                   dtype=jnp.int32)
+    shifted = jnp.where(col > 0, _shift_left(blk, kb, nb), 0)
+    nb = nb - kb
+    ab = jnp.where(nb > 0, ab + head.astype(jnp.float32), 0.0)
+    return (jax.lax.dynamic_update_slice(dv, shifted,
+                                         (r0, jnp.zeros((), r0.dtype))),
+            jax.lax.dynamic_update_slice(anchor, ab, (r0,)),
+            jax.lax.dynamic_update_slice(n, nb, (r0,)))
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _compact_pool(pool, k, n):
+    return _shift_left(pool, k, n)
+
+
+@jax.jit
+def _row_last(dv, anchor):
+    """Each row's last value: a delta block holds zeros past a row's
+    count."""
+    return anchor + jnp.sum(dv, axis=1, dtype=jnp.int32).astype(jnp.float32)
+
+
 def _verify_ts_block_impl(ts, first, n, interval, C):
     """Fused derive-and-compare reduction over a ROW BLOCK — a whole-store
     comparison at 1M x 768 materializes multi-GB i64 hi/lo split temps and
@@ -348,15 +462,16 @@ def _decode_narrow_rows(q, vmin, scale, pool, pool_slot, rid):
     return jnp.where((slot >= 0)[:, None], pv, v)
 
 
-@jax.jit
-def _decode_delta_rows(dv, anchor, pool, pool_slot, rid):
-    """Row-wise delta16/delta8 decode with pool-value overlay — the delta
-    twin of :func:`_decode_narrow_rows`."""
-    dvg = jnp.take(dv, rid, axis=0).astype(jnp.float32)
-    v = jnp.take(anchor, rid)[:, None] + jnp.cumsum(dvg, axis=1)
+def _decode_delta_rows_impl(dv, anchor, pool, pool_slot, rid):
+    v = _delta_rows(dv, anchor, rid)
     slot = jnp.take(pool_slot, rid, mode="clip")
     pv = jnp.take(pool, jnp.maximum(slot, 0), axis=0, mode="clip")
     return jnp.where((slot >= 0)[:, None], pv, v)
+
+
+# row-wise delta16/delta8 decode with pool-value overlay — the delta twin of
+# _decode_narrow_rows
+_decode_delta_rows = jax.jit(_decode_delta_rows_impl)
 
 
 # row-wise derivation is the same rule applied to a gathered first/n pair
@@ -377,6 +492,18 @@ def _gather_grid(val, n, picked, C):
             jnp.take(val, rid, axis=0), n_g)
 
 
+@functools.partial(jax.jit, static_argnums=(6,))
+def _gather_grid_delta(dv, anchor, pool, slot, n, picked, C):
+    """``_gather_grid`` over a delta block: the picked rows decoded (the
+    bodies of ``_decode_delta_rows``, the pool laid over them) and their
+    stamps derived, one program."""
+    rid = picked[0].astype(jnp.int32)
+    first = picked[1]
+    n_g = jnp.where(first >= 0, jnp.take(n, rid), 0).astype(jnp.int32)
+    return (_derive_ts_impl(first, n_g, picked[2, 0], C),
+            _decode_delta_rows_impl(dv, anchor, pool, slot, rid), n_g)
+
+
 class _Deferred:
     """Base for lazy views of elided store blocks: shape metadata for
     planning; ``materialize()`` reconstructs. General query paths funnel
@@ -395,6 +522,8 @@ class _Deferred:
 
     def materialize(self):
         if self._arr is None:
+            self._store.check_decode_budget(
+                int(np.prod(self.shape)) * self.dtype.itemsize)
             self._arr = self._build()
         return self._arr
 
@@ -464,7 +593,7 @@ class DeferredTs(_Deferred):
         if self._arr is None and st._ts_elided:
             first_g = jnp.take(jnp.asarray(st.first_ts), rid)
             n_g = jnp.take(st.n, rid)
-            return _derive_ts_rows(first_g, n_g, jnp.int64(st.grid_interval),
+            return _derive_ts_rows(first_g, n_g, jnp.int64(st._interval()),
                                    st.C)
         return jnp.take(self.materialize(), rid, axis=0)
 
@@ -497,8 +626,13 @@ class SeriesStore:
 
     def __init__(self, max_series: int, capacity: int, dtype=jnp.float32,
                  device=None, nbuckets: int = 0, layout=None,
-                 default_col: str | None = None):
-        """``layout`` (from Schema.col_layout) declares multi-value-column
+                 default_col: str | None = None, born_narrow: bool = False):
+        """``born_narrow`` (compressed residency): a scalar f32 store starts
+        in its delta8 form with stamps elided and no raw block is ever
+        allocated (see the text at DELTA_LIMIT); any other shape of store
+        is born raw, as without it.
+
+        ``layout`` (from Schema.col_layout) declares multi-value-column
         storage: the schema's DEFAULT column lives in ``self.val`` and every
         other data column gets its own named [S, C] array in ``self.extra``
         — one ts/n pair serves all columns (ref: multi-column datasets,
@@ -520,8 +654,13 @@ class SeriesStore:
         dev = device or jax.local_devices()[0]
         S = self.S
         vshape = (S, capacity) if not nbuckets else (S, capacity, nbuckets)
-        self.ts = jax.device_put(jnp.full((S, capacity), TS_PAD, jnp.int64), dev)
-        self.val = jax.device_put(jnp.zeros(vshape, dtype), dev)
+        born_narrow = (born_narrow and not nbuckets and layout is None
+                       and dtype == jnp.float32)
+        self.ts = self.val = None
+        if not born_narrow:
+            self.ts = jax.device_put(
+                jnp.full((S, capacity), TS_PAD, jnp.int64), dev)
+            self.val = jax.device_put(jnp.zeros(vshape, dtype), dev)
         self.extra: dict[str, jax.Array] = {}
         if layout is not None:
             # default col = the schema's value_column (else the histogram
@@ -617,6 +756,29 @@ class SeriesStore:
         # (first_ts, n, grid_interval) on demand — the 8B/sample column is
         # redundant on a grid-contiguous store (compress_resident)
         self._ts_elided = False
+        # the delta form mutated in place (see the text at DELTA_LIMIT):
+        # what the host knows of each row, the pool's rows by slot, and
+        # the counts the flush span and /metrics read
+        self.last_val = self.ref_val = self.anchor_host = None
+        self._slot_host = np.full(S, -1, np.int32)
+        self._vpool_rows = np.full(1, S, np.int32)
+        self._vpool_next = 0
+        self._vpool_free: list[int] = []
+        self.pooled_last_append = 0
+        self.rehydrated = dict.fromkeys(REHYDRATE_CAUSES, 0)
+        self.decode_budget = None   # bytes; None: what the device has free
+        if born_narrow:
+            put = functools.partial(jax.device_put, device=dev)
+            self._narrow = (
+                "delta8", (put(jnp.zeros((S, capacity), jnp.int8)),
+                           put(jnp.zeros(S, jnp.float32))),
+                put(jnp.zeros((1, capacity), jnp.float32)),
+                put(jnp.asarray(self._vpool_rows)),
+                put(jnp.asarray(self._slot_host)), np.ones(S, bool))
+            self._ts_elided = True
+            self.last_val = np.zeros(S, np.float32)
+            self.ref_val = np.zeros(S, np.float32)
+            self.anchor_host = np.zeros(S, np.float32)
 
     def _pre_donate(self, what: str) -> None:
         """Every buffer-donating mutation funnels through here: assert the
@@ -803,6 +965,66 @@ class SeriesStore:
         if ts_ok and not self._ts_elided:
             self.ts = None     # the 8B/sample block's HBM released here
             self._ts_elided = True
+        if self._narrow is not None and self._narrow[0] in DELTA_LIMIT \
+                and self._ts_elided and self.res is None:
+            self._adopt_mirrors()
+
+    def _adopt_mirrors(self) -> None:
+        """A store that a rebuild turned into the delta form with elided
+        stamps is mutated in place from here on: what the host has to know
+        of each row, read off the new state once (two ``[S]`` fetches and
+        the pool)."""
+        kind, (dv, anchor), pool, pp, slot, ok = self._narrow
+        self._narrow = (kind, (dv, anchor), pool, pp, slot, np.array(ok))
+        self.anchor_host = np.array(anchor)
+        self.ref_val = self.anchor_host.copy()
+        last = np.array(_row_last(dv, anchor))
+        self._slot_host = np.array(slot)
+        self._vpool_rows = np.array(pp)
+        held = np.flatnonzero(self._vpool_rows < self.S)
+        self._vpool_next, self._vpool_free = len(self._vpool_rows), []
+        if len(held) != len(self._vpool_rows):
+            self._vpool_next = int(held.max(initial=-1)) + 1
+            self._vpool_free = [i for i in range(self._vpool_next)
+                                if self._vpool_rows[i] >= self.S]
+        if len(held):
+            rows = self._vpool_rows[held]
+            last[rows] = np.asarray(pool)[
+                held, np.maximum(self.n_host[rows] - 1, 0)]
+        self.last_val = last
+
+    @property
+    def _inplace(self) -> bool:
+        """Is this the delta form that appends, ages out and frees as it
+        is (see the text at DELTA_LIMIT)?"""
+        return (self._narrow is not None and self._narrow[0] in DELTA_LIMIT
+                and self._ts_elided and self.res is None
+                and self.last_val is not None)
+
+    def _interval(self) -> int:
+        """The grid's interval; 1 before a second sample of any row has
+        shown it (every row then derives its one stamp alike)."""
+        return int(self.grid_interval or 1)
+
+    def check_decode_budget(self, nbytes: int) -> None:
+        """Raise ``DecodeRefused`` where a transient of ``nbytes`` (the
+        f32 or s64 ``[S, C]`` view of a block this store holds narrow or
+        not at all) is more than the device has free — with its working
+        copy beside it, half of what is free. A query that asks for it
+        fails; the node does not."""
+        if not self.is_narrow_resident:
+            return
+        budget = self.decode_budget
+        if budget is None:
+            stats = next(iter(self.n.devices())).memory_stats() or {}
+            if "bytes_limit" not in stats:
+                return              # a backend that does not say (the CPU)
+            budget = (stats["bytes_limit"] - stats.get("bytes_in_use", 0)) // 2
+        if nbytes > budget:
+            raise DecodeRefused(
+                f"decoding {nbytes} bytes of a compressed-resident store "
+                f"({self.S} x {self.C}) exceeds the device's budget of "
+                f"{budget}: narrow the selection")
 
     @property
     def _val_compressed(self) -> bool:
@@ -823,11 +1045,15 @@ class SeriesStore:
         self.compress_commit(prep)
         return self._val_compressed or self._ts_elided
 
-    def _rehydrate(self) -> None:
+    def _rehydrate(self, cause: str = "append") -> None:
         """Restore the resident f32/i64 blocks (mutations write raw); the
-        next compress_resident() re-adopts the compressed state."""
+        next compress_resident() re-adopts the compressed state. Counted
+        by ``cause`` (REHYDRATE_CAUSES): the delta form on a grid never
+        comes here to be appended to, aged out or freed."""
         if not self._val_compressed and not self._ts_elided:
             return
+        self.rehydrated[cause] += 1
+        self.last_val = None        # the in-place form's mirrors go with it
         self._pre_donate("SeriesStore.rehydrate")
         if self._narrow is not None:
             kind, ops, pool, pp, _slot, _ok = self._narrow
@@ -840,7 +1066,7 @@ class SeriesStore:
             self._nhist = None
         if self._ts_elided:
             self.ts = _derive_ts(jnp.asarray(self.first_ts), self.n,
-                                 jnp.int64(self.grid_interval), self.C)
+                                 jnp.int64(self._interval()), self.C)
             self._ts_elided = False
 
     def value_block(self):
@@ -870,7 +1096,7 @@ class SeriesStore:
         if not self._ts_elided:
             return self.ts
         return _derive_ts(jnp.asarray(self.first_ts), self.n,
-                          jnp.int64(self.grid_interval), self.C)
+                          jnp.int64(self._interval()), self.C)
 
     def narrow_operands(self):
         """(kind, operands, ok_host) when narrow-resident, else None — the
@@ -916,6 +1142,17 @@ class SeriesStore:
         if self.res is not None:
             t = self.res.size * self.res.dtype.itemsize
         return t + self.resident_value_bytes()
+
+    def resident_bytes_per_sample(self) -> float:
+        """``resident_sample_bytes`` over the cells the store holds
+        (``S x C``, times the buckets of a histogram): 12 raw, ~1 in the
+        delta8 form with elided stamps."""
+        return self.resident_sample_bytes() / (
+            self.S * self.C * max(self.nbuckets, 1))
+
+    @property
+    def rehydrates(self) -> int:
+        return sum(self.rehydrated.values())
 
     # -- ingest -------------------------------------------------------------
 
@@ -984,7 +1221,9 @@ class SeriesStore:
         m = len(r)
         if m == 0:
             return 0
-        self._rehydrate()      # mutations write the raw f32 block
+        inplace = self._inplace
+        if not inplace:
+            self._rehydrate("append")   # mutations write the raw f32 block
         self._pre_donate("SeriesStore.append")
         # host bookkeeping
         # (the batch is sorted by row: its rows are its runs)
@@ -1004,6 +1243,10 @@ class SeriesStore:
         marks = {k: a for k, a in (("stale", stale), ("gap", gap))
                  if a is not None}
         res = self._track_stamps(r, t, cols, uniq, first_pos, **marks)
+        if inplace and not (self._inplace and self.grid_ok):
+            # the batch left the grid (``_to_line``): the store is raw now
+            self._rehydrate("off_grid")
+            inplace = False
         stamps = t if res is None else res
         if res is not None:
             over = cols >= self.C       # a cell past the row's capacity
@@ -1057,6 +1300,12 @@ class SeriesStore:
                     at >= 0, last - at,
                     self.tail_holes[uniq] + last - first_pos + 1)
         self.n_host[uniq] += moved
+        self.pooled_last_append = 0
+        if inplace:
+            self._append_delta(r, cols, v, occ, uniq, first_pos, last, counts)
+            self.stats.samples_appended += ingested
+            self._appends_since_sync += 1
+            return ingested
         # pad to bucketed size; padded rows use row index S => dropped by scatter
         P = _pad_size(m)
         v = np.asarray(v)
@@ -1095,6 +1344,129 @@ class SeriesStore:
         self.stats.samples_appended += ingested
         self._appends_since_sync += 1
         return ingested
+
+    def _append_delta(self, r, cols, v, occ, uniq, first_pos, last,
+                      counts) -> None:
+        """The device's part of an append to the delta form in place: each
+        entry's delta against the sample before it — the batch's, or the
+        row's last — taken on the host and written as it is, one narrow
+        cell a sample (the dense / scatter split is the raw store's, by
+        the block's bytes). A row with an entry that does not fit moves
+        to the pool first (``_pool_rows``); a pooled row's entries are
+        written there, raw."""
+        kind, (dv, anchor), pool, pp, slot, ok = self._narrow
+        vf = np.asarray(v, np.float32)      # what a raw store would hold
+        prev = np.empty_like(vf)
+        prev[1:] = vf[:-1]
+        prev[first_pos] = self.last_val[uniq]
+        start = cols == 0
+        if start.any():
+            rows0 = r[start]
+            self.ref_val[rows0] = self.anchor_host[rows0] = prev[start] = \
+                vf[start]
+            anchor = jax.device_put(self.anchor_host,
+                                    next(iter(dv.devices())))
+        v64 = vf.astype(np.float64)     # differences of f32s, exactly
+        d = v64 - prev
+        with np.errstate(invalid="ignore"):
+            fits = ((d == np.rint(d)) & (np.abs(d) <= DELTA_LIMIT[kind])
+                    & (np.abs(v64 - self.ref_val[r]) <= PREFIX_MAX))
+        self._narrow = (kind, (dv, anchor), pool, pp, slot, ok)
+        bad = ~fits & (self._slot_host[r] < 0)
+        if bad.any():
+            self._pool_rows(np.unique(r[bad]))
+            kind, (dv, anchor), pool, pp, slot, ok = self._narrow
+        out = self._slot_host[r] >= 0
+        if out.any():
+            z = int(out.sum())
+            P = _pow2_rows(z)
+            picks = np.zeros((2, P), np.int32)
+            picks[0] = pool.shape[0]        # pads: past the pool, dropped
+            picks[0, :z], picks[1, :z] = self._slot_host[r[out]], cols[out]
+            vp = np.zeros(P, np.float32)
+            vp[:z] = vf[out]
+            pool = _pool_write(pool, jnp.asarray(picks[0]),
+                               jnp.asarray(picks[1]), jnp.asarray(vp))
+            d = np.where(out, 0.0, d)
+        dq = d.astype(dv.dtype)
+        if (int(occ.max()) < DENSE_APPEND_MAX_K
+                and dv.nbytes >= DENSE_APPEND_BYTES):
+            for k in range(int(occ.max()) + 1):
+                sel = occ == k
+                rk = r[sel]
+                col = np.full(self.S, -1, np.int32)
+                col[rk] = cols[sel]
+                new = np.zeros(self.S, dq.dtype)
+                new[rk] = dq[sel]
+                dv = _dense_set(dv, jnp.asarray(col), jnp.asarray(new))
+            n = _add_counts(self.n, jnp.asarray(counts))
+        else:
+            m = len(r)
+            P = _pad_size(m)
+            rp = np.full(P, self.S, np.int32); rp[:m] = r
+            cp = np.zeros(P, np.int32); cp[:m] = cols
+            dp = np.zeros(P, dq.dtype); dp[:m] = dq
+            dv, n = _scatter_append_delta(
+                dv, self.n, jnp.asarray(rp), jnp.asarray(cp),
+                jnp.asarray(dp), jnp.asarray(counts))
+        self.n = n
+        self._narrow = (kind, (dv, anchor), pool, pp, slot, ok)
+        self.last_val[uniq] = vf[last]
+        if self.pooled_last_append:
+            self._hold_gate()
+
+    def _pool_rows(self, rows: np.ndarray) -> None:
+        """Take ``rows`` out of the delta form: their history so far,
+        decoded a row at a time (``_pool_admit``: ``len(rows) x C`` f32,
+        never the block), goes to pool slots, where their samples are
+        written raw from now on."""
+        kind, (dv, anchor), pool, pp, slot, ok = self._narrow
+        again = [self._vpool_free.pop()
+                 for _ in range(min(len(rows), len(self._vpool_free)))]
+        fresh = len(rows) - len(again)
+        slots = np.asarray(again + list(range(
+            self._vpool_next, self._vpool_next + fresh)), np.int32)
+        self._vpool_next += fresh
+        Rp = pool.shape[0]
+        while Rp < self._vpool_next:
+            Rp *= 2
+        B = _pow2_rows(len(rows))
+        picks = np.empty((2, B), np.int32)
+        picks[0], picks[1] = self.S, Rp
+        picks[0, :len(rows)], picks[1, :len(rows)] = rows, slots
+        pool, slot = _pool_admit(dv, anchor, pool, slot, jnp.asarray(picks),
+                                 Rp)
+        held = np.full(Rp, self.S, np.int32)
+        held[:len(self._vpool_rows)] = self._vpool_rows
+        held[slots] = rows
+        self._vpool_rows = held
+        self._slot_host[rows] = slots
+        ok[rows] = False
+        self._narrow = (kind, (dv, anchor), pool,
+                        jax.device_put(held, next(iter(dv.devices()))),
+                        slot, ok)
+        self.pooled_last_append += len(rows)
+        log.info("%d row(s) left the %s form for the raw pool (%d there)",
+                 len(rows), kind, int((self._slot_host >= 0).sum()))
+
+    def _hold_gate(self) -> None:
+        """More rows in the pool than the cohort gate allows: raw f32 is
+        the cheaper residency, as a rebuild would have found (``_bad_rows``)
+        — the store declines and the next flush tries the other forms. A
+        store too deep to be held raw at all keeps its form and its pool,
+        and says so."""
+        live = self.n_host > 0
+        pooled = int((live & (self._slot_host >= 0)).sum())
+        if pooled <= self.cohort_gate * max(int(live.sum()), 1):
+            return
+        try:
+            self.check_decode_budget(self.S * self.C * 12)
+        except DecodeRefused:
+            log.warning("%d of %d live rows are in the raw pool, past the "
+                        "cohort gate, and the store is too deep to be held "
+                        "raw: it stays narrow", pooled, int(live.sum()))
+            return
+        self._rehydrate("cohort_gate")
 
     def _next_cols(self, r, cols):
         """The column each entry of a sorted batch takes if its row skips
@@ -1472,16 +1844,42 @@ class SeriesStore:
         a leaf holds the shard lock through every dispatch it makes.
         ``rows``: the pow2-padded row ids on the host, the first ``live``
         real; the pad rows come back with n = 0 and TS_PAD all along."""
-        if self.ts is None or self.res is not None:
+        if self.grid_gather_operands(self.column_array()) is None:
             return None
-        C = self.C
 
         def gather(rows: np.ndarray, live: int, val, n):
-            return _gather_grid(val, n,
-                                jnp.asarray(self.grid_row_picks(rows, live)),
-                                C)
+            store_ops, body, _decode = self.grid_gather_operands(val)
+            return body(*store_ops[:-1], n,
+                        jnp.asarray(self.grid_row_picks(rows, live)))
 
         return gather
+
+    def grid_gather_operands(self, val):
+        """``(store_ops, body, decode)`` of a gather of a few rows of the
+        value block ``val`` (``column_array``'s) on the grid, None where
+        this store has none (a line store; a quant16 block; stamps neither
+        resident nor elided): ``body(*store_ops, picked) -> (ts, val, n)``,
+        traceable, ``store_ops`` the device blocks it reads, ``n`` last,
+        and ``decode`` what it does to the values on the way — ``raw``, or
+        ``delta8`` / ``delta16`` where the block is held in that form and
+        the picked rows are decoded inside the program
+        (``_gather_grid_delta``). The stamps are derived from the host's
+        ``first_ts`` either way."""
+        if self.res is not None or (self.ts is None and not self._ts_elided):
+            return None
+        C = self.C
+        if not isinstance(val, _Deferred):
+            return ((val, self.n),
+                    lambda val, n, picked: _gather_grid(val, n, picked, C),
+                    "raw")
+        if not isinstance(val, DeferredDecode) or val._arr is not None \
+                or self._narrow is None or self._narrow[0] not in DELTA_LIMIT:
+            return None
+        kind, ops, pool, _pp, slot, _ok = self._narrow
+        return ((*ops, pool, slot, self.n),
+                lambda dv, anchor, pool, slot, n, picked: _gather_grid_delta(
+                    dv, anchor, pool, slot, n, picked, C),
+                kind)
 
     def grid_row_picks(self, rows: np.ndarray, live: int) -> np.ndarray:
         """All that ``_gather_grid`` needs from the host, int64 ``[3, P]``:
@@ -1493,7 +1891,7 @@ class SeriesStore:
         picked = np.full((3, len(rows)), -1, np.int64)
         picked[0] = rows
         picked[1, :live] = self.first_ts[rows[:live]]
-        picked[2] = int(self.grid_interval)
+        picked[2] = self._interval()
         return picked
 
     def grid_offsets(self, rows: np.ndarray) -> np.ndarray:
@@ -1560,7 +1958,9 @@ class SeriesStore:
     def compact(self, cutoff_ts: int) -> None:
         """Evict samples older than ``cutoff_ts`` (amortized; ref: block reclaim
         by time bucket, BlockManager.scala markBucketedBlocksReclaimable)."""
-        self._rehydrate()      # the shift gathers the raw f32 block
+        if self._inplace:
+            return self._compact_delta(int(cutoff_ts))
+        self._rehydrate("compact")     # the shift gathers the raw f32 block
         self._pre_donate("SeriesStore.compact")
         line = self.res is not None
         old_n = self.n_host
@@ -1581,6 +1981,44 @@ class SeriesStore:
             new_first = np.array(self.ts[:, 0])
             self.first_ts = np.where(self.n_host > 0, new_first, -1)
             self.line0 = self.first_ts.copy()
+        self._cohorts = None
+        self.stats.compactions += 1
+
+    def _compact_delta(self, cutoff_ts: int) -> None:
+        """``compact`` of the delta form in place: how many cells a row
+        drops is the host's to say (its stamps are ``first_ts + k x
+        interval``), each row's anchor moves on to the first sample it
+        keeps and its deltas shift left — in blocks of ``BLOCK_ROWS``
+        rows, donated, so that the temporaries are a block's."""
+        self._pre_donate("SeriesStore.compact")
+        kind, (dv, anchor), pool, pp, slot, ok = self._narrow
+        iv = self._interval()
+        live = self.n_host > 0
+        k = np.where(live, np.clip(-(-(cutoff_ts - self.first_ts) // iv), 0,
+                                   self.n_host), 0).astype(np.int32)
+        dev = next(iter(dv.devices()))
+        k_d = jax.device_put(k, dev)
+        held = self._vpool_rows < self.S
+        if held.any():
+            rows = np.minimum(self._vpool_rows, self.S - 1)
+            pool = _compact_pool(
+                pool, jnp.asarray(np.where(held, k[rows], 0)),
+                jnp.asarray(np.where(held, self.n_host[rows], 0)))
+        S = self.S
+        rows = S if S <= BLOCK_ROWS else min(BLOCK_ROWS, S & -S)
+        n = self.n
+        for r0 in range(0, S, rows):
+            dv, anchor, n = _compact_delta_block(
+                dv, anchor, n, k_d, jax.device_put(np.int32(r0), dev), rows)
+        self.n = n
+        self._narrow = (kind, (dv, anchor), pool, pp, slot, ok)
+        self.n_host = self.n_host - k
+        kept = self.n_host > 0
+        self.first_ts = np.where(kept, self.first_ts + k.astype(np.int64) * iv,
+                                 -1)
+        self.line0 = self.first_ts.copy()
+        self.anchor_host = np.array(anchor)
+        self.last_val = np.where(kept, self.last_val, 0).astype(np.float32)
         self._cohorts = None
         self.stats.compactions += 1
 
@@ -1618,7 +2056,9 @@ class SeriesStore:
         donated in-place — no transient second copy of the [S, C] arrays."""
         if len(part_ids) == 0:
             return
-        self._rehydrate()      # the scatter resets the raw ts block
+        inplace = self._inplace
+        if not inplace:
+            self._rehydrate("free")    # the scatter resets the raw ts block
         self.stats.frees += 1
         self._pre_donate("SeriesStore.free_rows")
         m = len(part_ids)
@@ -1626,7 +2066,25 @@ class SeriesStore:
         # padded entries use row S -> dropped by the out-of-bounds scatter mode
         pp = np.full(P, self.S, np.int32)
         pp[:m] = np.asarray(part_ids, np.int32)
-        if self.res is None:
+        if inplace:
+            # n = 0 masks the row's deltas (its next sample starts it anew,
+            # at column 0); its pool slot is let go
+            kind, ops, pool, vp, slot, ok = self._narrow
+            self.n = _zero_counts(self.n, jnp.asarray(pp))
+            held = self._slot_host[part_ids]
+            held = held[held >= 0]
+            if len(held):
+                self._vpool_free.extend(held.tolist())
+                self._vpool_rows[held] = self.S
+                vp = jax.device_put(self._vpool_rows,
+                                    next(iter(self.n.devices())))
+                slot = _clear_slots(slot, jnp.asarray(pp))
+            self._slot_host[part_ids] = -1
+            ok[part_ids] = True
+            self._narrow = (kind, ops, pool, vp, slot, ok)
+            self.last_val[part_ids] = self.ref_val[part_ids] = \
+                self.anchor_host[part_ids] = 0
+        elif self.res is None:
             self.ts, self.n = _free_rows(self.ts, self.n, jnp.asarray(pp))
         else:       # n = 0 masks the row's residuals; its pool row is let go
             self.n = _zero_counts(self.n, jnp.asarray(pp))

@@ -303,6 +303,7 @@ class TimeSeriesShard:
         self._stage_pid: list[np.ndarray] = []
         self._stage_ts: list[np.ndarray] = []
         self._stage_val: list[np.ndarray] = []
+        self._rehydrates_seen = 0    # the store's count at the last flush span
         self._staged = 0
         # per-group ingest offset watermarks (ref: checkpoint per flush group)
         self.group_watermarks = np.full(config.groups_per_shard, -1, np.int64)
@@ -787,7 +788,8 @@ class TimeSeriesShard:
                             self.config.samples_per_series,
                             dtype=self._dtype, device=self._device,
                             nbuckets=nb, layout=layout,
-                            default_col=self.schema.value_column)
+                            default_col=self.schema.value_column,
+                            born_narrow=self.config.residency_mode() != "off")
         store.cohort_gate = self.config.narrow_cohort_gate
         return store
 
@@ -933,8 +935,12 @@ class TimeSeriesShard:
             staged = bool(self._staged)
             written = self._flush_staged_locked() if staged else 0
             if staged:
-                tags["demoted"] = self.store.demoted_last_append
-                tags["holes"] = self.store.holes_last_append
+                st = self.store
+                tags["demoted"] = st.demoted_last_append
+                tags["holes"] = st.holes_last_append
+                tags["pooled"] = st.pooled_last_append
+                # an append in place leaves the store as it found it
+                tags["form"] = "narrow" if st._inplace else "raw"
         residency = self.config.residency_mode()
         if not staged:
             # nothing new — but a purge/compact since the last flush may have
@@ -961,34 +967,41 @@ class TimeSeriesShard:
             # (compact rehydrates — compressing first would be discarded
             # work). Two-phase: the streaming build + host fetches run
             # OUTSIDE the shard lock; only the swap takes it.
-            self._compress_resident_two_phase(residency)
+            if self._compress_resident_two_phase(residency):
+                tags["form"] = "rebuilt"
+        st = self.store
+        tags["rehydrates"] = st.rehydrates - self._rehydrates_seen
+        self._rehydrates_seen = st.rehydrates
+        tags["sample_bytes"] = st.resident_bytes_per_sample()
         return written
 
-    def _compress_resident_two_phase(self, mode: str = "gauge") -> None:
+    def _compress_resident_two_phase(self, mode: str = "gauge") -> bool:
         """Build the compressed-resident state without the shard lock, then
         swap under it iff nothing mutated meanwhile (a racing append donates
         the very buffers the build streams — detected and retried next
         flush). ``mode`` gates
-        which store shapes compress (histograms only under "all")."""
+        which store shapes compress (histograms only under "all"). A store
+        that is narrow already — born so, or appended to in place — is
+        left alone. True where a rebuild was committed."""
         st = self.store
         if st is None:
-            return
+            return False
         if st.nbuckets and mode != "all":
-            return
+            return False
         epoch0 = st.mutation_epoch()
         # idempotence: fully compressed already, or nothing mutated since the
         # last (possibly declined) attempt — a declined 25%-gate store must
         # not re-run the full-store build on every empty flush tick
         if st._val_compressed and (st._ts_elided
                                    or st.grid_info() is None):
-            return
+            return False
         if getattr(self, "_last_compress_epoch", None) == epoch0:
-            return
+            return False
         self._last_compress_epoch = epoch0
         try:
             prep = st.compress_prepare(hist=mode == "all")
         except RuntimeError:
-            return                 # racing donation invalidated the build
+            return False           # racing donation invalidated the build
         if prep is None:
             if st.residency_decline is not None:
                 # the store WANTED compression and the data refused the
@@ -996,10 +1009,12 @@ class TimeSeriesShard:
                 # signal, not a silent raw-residency downgrade
                 registry.counter(FILODB_STORE_RESIDENCY_FALLBACK,
                                  {"reason": st.residency_decline}).increment()
-            return
+            return False
         with self.lock:
             if st.mutation_epoch() == epoch0:
                 st.compress_commit(prep)
+                return True
+        return False
 
     # -- persistence flush pipeline (ref: TimeSeriesShard.doFlushSteps :814) --
 

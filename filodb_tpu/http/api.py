@@ -241,6 +241,8 @@ class FiloHttpServer:
                                      FILODB_INGEST_STALE_MARKERS,
                                      FILODB_SHARD_NUM_SERIES,
                                      FILODB_STORE_HOLE_CELLS,
+                                     FILODB_STORE_REHYDRATE,
+                                     FILODB_STORE_RESIDENT_BYTES_PER_SAMPLE,
                                      FILODB_STORE_ROWS_DEMOTED,
                                      FILODB_STORE_ROWS_OFF_LINE,
                                      FILODB_STORE_STAMP_FORM, registry)
@@ -270,6 +272,13 @@ class FiloHttpServer:
                         c.increment(rows - c.value)
                     registry.gauge(FILODB_STORE_HOLE_CELLS, shard).update(
                         float(st.hole_cells))
+                    for why, times in st.rehydrated.items():
+                        c = registry.counter(FILODB_STORE_REHYDRATE,
+                                             {**shard, "cause": why})
+                        c.increment(times - c.value)
+                    registry.gauge(FILODB_STORE_RESIDENT_BYTES_PER_SAMPLE,
+                                   shard).update(
+                                       st.resident_bytes_per_sample())
                     c = registry.counter(FILODB_INGEST_STALE_MARKERS, shard)
                     c.increment(st.stats.stale_markers - c.value)
                 if hasattr(s.lock, "hold_s"):        # TimedRLock diagnostics

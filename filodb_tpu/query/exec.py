@@ -32,6 +32,7 @@ from ..core.selection import ShardSelection
 from ..ops import aggregators, binop, instantfns, rangefns
 from ..utils.diagnostics import lock_hold_ns, lock_wait_ns
 from ..utils.metrics import (FILODB_GROUPIDS, FILODB_INDEX_RESOLVE,
+                             FILODB_QUERY_REFUSED,
                              FILODB_QUERY_LEAF, FILODB_QUERY_LEAF_GATHER,
                              registry)
 from ..utils.tracing import (SPAN_QUERY_GATHER, SPAN_QUERY_GROUPIDS,
@@ -151,8 +152,16 @@ def _dval(arr):
     f32 decode / i64 grid derivation); real arrays pass through. The single
     choke point general query paths funnel through — the fused/grid paths
     plan from shape metadata and never call this."""
-    from ..core.chunkstore import _Deferred
-    return arr.materialize() if isinstance(arr, _Deferred) else arr
+    from ..core.chunkstore import DecodeRefused, _Deferred
+    if not isinstance(arr, _Deferred):
+        return arr
+    try:
+        return arr.materialize()
+    except DecodeRefused as e:
+        # a wide read of a store too deep to decode whole: the QUERY fails
+        registry.counter(FILODB_QUERY_REFUSED,
+                         {"reason": "decode_bytes"}).increment()
+        raise QueryError(str(e)) from None
 
 
 def _gather_rows_padded(ts, val, n, rows: np.ndarray, grid_gather=None):
@@ -162,14 +171,15 @@ def _gather_rows_padded(ts, val, n, rows: np.ndarray, grid_gather=None):
     a pad row aliasing row 0's real data would otherwise produce phantom
     (non-NaN) outputs that aggregation counts as present. ``grid_gather``
     (``SeriesStore.grid_row_gather``): the rows taken by the store's own
-    one program, their stamps derived from the grid, where ``ts`` is a
-    grid-form store's resident s64 block — which is then no operand."""
+    one program, their stamps derived from the grid (a delta block's rows
+    decoded in it), where the store has one for ``val`` — the s64 block is
+    then no operand."""
     from ..core.chunkstore import _Deferred
     M = len(rows)
     P = _pow2(M)
     pad = np.zeros(P, np.int32)
     pad[:M] = rows
-    if grid_gather is not None and not isinstance(val, _Deferred):
+    if grid_gather is not None:
         return grid_gather(pad, M, val, n) + (P,)
     rid = jnp.asarray(pad)
 
@@ -212,16 +222,19 @@ STEPWISE_PROGRAMS = {True: 3, False: 10}
 def _joins_one_program(ts, val, les, minority_sel, on_grid: bool) -> bool:
     """May a narrow selection's gather join the leaf's one program
     (``GatheredRows``)? By what the selection can observe, no knob: scalar
-    rows of a RESIDENT value block — a compressed-resident one decodes row
-    by row on the host's say (``DeferredDecode.gather_rows``) — whose stamps
-    are the grid's (``on_grid``) or a resident s64 block — a line store's
-    are laid together from host and device state (``SeriesStore._line_ts``)
-    — all of one start cohort: a minority's rows are gathered again from the
+    rows whose stamps are the grid's (``on_grid``: the store has a gather
+    for them, ``SeriesStore.grid_gather_operands`` — of a resident value
+    block, or of a delta block whose picked rows it decodes inside the
+    program) or a resident s64 block beside a resident value block — a
+    quant16 block decodes row by row on the host's say
+    (``DeferredDecode.gather_rows``), a line store's stamps are laid
+    together from host and device state (``SeriesStore._line_ts``) — all of
+    one start cohort: a minority's rows are gathered again from the
     gathered ones (``_correct_minority_cohort``)."""
     from ..core.chunkstore import _Deferred
     return (les is None and minority_sel is None
-            and not isinstance(val, _Deferred)
-            and (on_grid or not isinstance(ts, _Deferred)))
+            and (on_grid or not (isinstance(val, _Deferred)
+                                 or isinstance(ts, _Deferred))))
 
 
 def count_gather(tags: dict, programs: int) -> None:
@@ -249,9 +262,12 @@ class GatheredRows:
     stamp, ``SeriesStore.grid_row_picks``) or a resident s64 block, no row
     of a minority cohort. Everything else is gathered in ``_select``, step
     by step, as it was."""
-    store_ops: tuple          # (val, n) on a grid, else (ts, val, n): [S, ..]
+    store_ops: tuple          # on a grid (val, n) or a delta block's (dv,
+    #                           anchor, pool, slot, n), else (ts, val, n)
     host_ops: tuple           # (picked s64 [3, P],) | (row ids s32 [P], live)
     on_grid: bool
+    body: object              # the grid's gather (``grid_gather_operands``)
+    decode: str               # "raw" | "delta8" | "delta16": done in it
     shape: tuple              # (P, C) of the rows once gathered
     dtype: object             # ... and their values' dtype
     keys: list[RangeVectorKey]
@@ -283,11 +299,7 @@ class GatheredRows:
     def gather_body(self):
         """``(*store_ops, *host_ops) -> (ts, val, n)``, traceable: the
         bodies the stepwise gather runs."""
-        if not self.on_grid:
-            return _take_rows
-        from ..core.chunkstore import _gather_grid
-        C = self.shape[1]
-        return lambda val, n, picked: _gather_grid(val, n, picked, C)
+        return self.body if self.on_grid else _take_rows
 
 
 def check_sample_limit(num_series: int, steps: int, limit: int) -> None:
@@ -401,8 +413,8 @@ class GatheredWindow:
                    len(sel.host_ops))
         prog = plan_cache.program(
             "leaf",
-            statics + (sel.on_grid, sel.store_ops[-1].shape[0]) + sel.shape
-            + (str(sel.dtype),),
+            statics + (sel.on_grid, sel.decode) + sel.shape + tuple(
+                (o.shape, str(o.dtype)) for o in sel.store_ops),
             lambda: functools.partial(_leaf_body, sel.gather_body(),
                                       *statics))
         with span(SPAN_QUERY_GATHER, **sel.span_tags) as tags:
@@ -1773,31 +1785,39 @@ class SelectRawPartitionsExec(ExecPlan):
             ctx.stats.add("blocks_raw")
             count_leaf(ctx, tags, "gather")
             M, P = len(pids), _pow2(len(pids))
-            gtags = dict(shard=self.shard, rows=M, padded=P, bytes=M * (
-                int(np.prod(val.shape[1:])) * np.dtype(val.dtype).itemsize
-                + ts.shape[1] * 8))
+            # the grid's own gather of a few rows: stamps derived, a delta
+            # block's rows decoded in it (``decode`` says what it read)
+            gops = store.grid_gather_operands(val) if on_grid else None
+            on_g = gops is not None
+            decode = gops[2] if on_g else (
+                store.narrow_operands()[0] if store.narrow_operands()
+                and isinstance(val, _Deferred) and val.ndim == 2 else "raw")
+            width = {"raw": np.dtype(val.dtype).itemsize, "delta8": 1}.get(
+                decode, 2)
+            gtags = dict(shard=self.shard, rows=M, padded=P, decode=decode,
+                         bytes=M * (int(np.prod(val.shape[1:])) * width
+                                    + (0 if isinstance(ts, _Deferred)
+                                       else ts.shape[1] * 8)))
             # P > len(pids): arrays carry pad rows beyond the keys — expose the
             # identity row map so downstream compaction/group-scatter skips them
             sel_rows = None if P == M else np.arange(M, dtype=np.int32)
-            grid_gather = (store.grid_row_gather() if on_grid
-                           and ts is store.ts
-                           and not isinstance(val, _Deferred) else None)
-            on_g = grid_gather is not None
             if _joins_one_program(ts, val, les, minority_sel, on_g):
                 # the gather joins the window function's program; its span
                 # opens where that program is dispatched
                 pad = np.zeros(P, np.int32)
                 pad[:M] = pids
                 return GatheredRows(
-                    (val, n) if on_g else (ts, val, n),
+                    gops[0] if on_g else (ts, val, n),
                     (store.grid_row_picks(pad, M),) if on_g
                     else (pad, np.int32(M)),
-                    on_g, (P, val.shape[1]), val.dtype, keys, sel_rows, grid,
+                    on_g, gops[1] if on_g else None, decode,
+                    (P, val.shape[1]), val.dtype, keys, sel_rows, grid,
                     gtags)
             with span(SPAN_QUERY_GATHER, **gtags) as gtags:
                 count_gather(gtags, STEPWISE_PROGRAMS[on_g])
                 sel_ts, sel_val, sel_n, _ = _gather_rows_padded(
-                    ts, val, n, pids, grid_gather)
+                    ts, val, n, pids,
+                    store.grid_row_gather() if on_g else None)
             g_min = (np.nonzero(minority_sel)[0].astype(np.int32)
                      if minority_sel is not None else None)
             return SeriesSelection(sel_ts, sel_val, sel_n, keys, sel_rows, grid, les,

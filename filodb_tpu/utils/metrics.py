@@ -91,6 +91,10 @@ FILODB_RETENTION_REPLICA_FAILOVER = "filodb_retention_replica_failover"
 FILODB_RETENTION_AGED_OUT_ROWS = "filodb_retention_aged_out_rows"
 FILODB_STORE_RESIDENCY_FALLBACK = "filodb_store_residency_fallback"
 FILODB_STORE_STAMP_FORM = "filodb_store_stamp_form"
+FILODB_STORE_REHYDRATE = "filodb_store_rehydrate"
+FILODB_STORE_RESIDENT_BYTES_PER_SAMPLE = \
+    "filodb_store_resident_bytes_per_sample"
+FILODB_QUERY_REFUSED = "filodb_query_refused"
 FILODB_STORE_ROWS_DEMOTED = "filodb_store_rows_demoted"
 FILODB_STORE_ROWS_OFF_LINE = "filodb_store_rows_off_line"
 FILODB_STORE_HOLE_CELLS = "filodb_store_hole_cells"
@@ -360,6 +364,24 @@ METRICS_SPEC: dict[str, tuple[str, str]] = {
                    "ok-contract (cohort gate breached), tagged "
                    "reason=resets|non-integer|range — distinguishes "
                    "\"compressed\" from \"tried and fell back to raw\"."),
+    FILODB_STORE_REHYDRATE: (
+        "counter", "Times a compressed-resident store was decoded back to "
+                   "its raw f32 + s64 blocks, tagged cause=append|compact|"
+                   "free (a mutation of a form that cannot take it: "
+                   "quant16, delta16 off a grid, a histogram), off_grid "
+                   "(the delta form's first stamp off the scrape grid) or "
+                   "cohort_gate (more rows in the raw pool than "
+                   "store.narrow_cohort_gate allows). The delta form on a "
+                   "grid appends, ages out and frees as it is: 0 there."),
+    FILODB_STORE_RESIDENT_BYTES_PER_SAMPLE: (
+        "gauge", "Resident HBM bytes of a shard's sample state (values + "
+                 "stamps) over the cells it holds: 12 raw (f32 + s64), ~1 "
+                 "in the delta8 form with elided stamps."),
+    FILODB_QUERY_REFUSED: (
+        "counter", "Queries refused by name rather than run, tagged "
+                   "reason=decode_bytes: a wide selection whose decode of a "
+                   "compressed-resident store to f32 [S, C] is more than "
+                   "half the device's free memory."),
     FILODB_STORE_STAMP_FORM: (
         "gauge", "How a shard's store keeps time: 0 = grid (every stamp on "
                  "one common scrape grid, the s64 block resident), 1 = line "

@@ -168,9 +168,12 @@ TRACE_SPEC: dict[str, str] = {
                        "rows), the count its form's dispatches at the "
                        "least. The stamps of a grid-form store are derived "
                        "from each row's first stamp, its s64 block is no "
-                       "operand (tags: shard, rows, padded, bytes = rows x "
-                       "a row's values and stamps, what the gather needs, "
-                       "programs).",
+                       "operand; a delta block's picked rows are decoded "
+                       "inside the same program (tags: shard, rows, padded, "
+                       "bytes = rows x a row's values and stamps as the "
+                       "store holds them, what the gather needs, programs, "
+                       "decode = raw | delta8 | delta16 | quant16: the form "
+                       "the rows were read from).",
     SPAN_QUERY_GROUPIDS: "Group ids of the selected series for a "
                          "by/without aggregation: from the index's label "
                          "columns where the selection is still pids "
@@ -243,7 +246,15 @@ TRACE_SPEC: dict[str, str] = {
                        "it released, also in the tag of a consume or query "
                        "span around it, demoted = rows this flush took off their "
                        "line, holes = cells it left without a sample: "
-                       "staleness markers and skipped cells).",
+                       "staleness markers and skipped cells; form = narrow "
+                       "| raw | rebuilt: the append wrote the delta form in "
+                       "place, wrote raw blocks, or wrote raw blocks that "
+                       "this flush re-encoded whole; pooled = rows the "
+                       "append moved from the delta form to the raw pool; "
+                       "rehydrates = times the store was decoded back to "
+                       "raw since the flush before; sample_bytes = resident "
+                       "bytes a cell, values + stamps, as the flush "
+                       "left them).",
     SPAN_QUERY_RETENTION: "Downsample-aware routing of one query: the "
                           "resolution decision and its routed/stitched "
                           "leg queries hang under it (tags: dataset, "

@@ -14,7 +14,11 @@ tags, the reference against its brute-force twin, the fill, the served path
 for the twelve text kinds, probes, the generated traffic file, the files
 (``test_tsbs_data.py``) with the four readers of what a narrow leaf does
 (``test_tsbs_layers.py``, PR 41) and the reader of how often a gathered
-leaf ran as one program (``test_gather_fused_layer.py``, PR 42), every case
+leaf ran as one program (``test_gather_fused_layer.py``, PR 42), and
+``tsbs_cpu_d8``'s: the fill's deltas, the checks of a narrow store, the
+reference at 12 h against the twin, the served path over the 12 h mix,
+probes over the whole depth, the files, the three readers of the flush's
+form and the cell dry-added (``test_tsbs_d8_data.py``, PR 44), every case
 under a name of its own.
 They run in seconds on the CPU, and what they pin is the yardstick: tier-1
 collects them here, under their own names, so that the floor counts them.
@@ -29,7 +33,8 @@ for _mod in ("benchmark.tests.test_data", "benchmark.tests.test_hist_data",
              "benchmark.tests.test_fall_layer",
              "benchmark.tests.test_tsbs_data",
              "benchmark.tests.test_tsbs_layers",
-             "benchmark.tests.test_gather_fused_layer"):
+             "benchmark.tests.test_gather_fused_layer",
+             "benchmark.tests.test_tsbs_d8_data"):
     pytest.register_assert_rewrite(_mod)
 
 from benchmark.tests.test_data import *        # noqa: E402,F401,F403
@@ -41,6 +46,7 @@ from benchmark.tests.test_fall_layer import *       # noqa: E402,F401,F403
 from benchmark.tests.test_tsbs_data import *        # noqa: E402,F401,F403
 from benchmark.tests.test_tsbs_layers import *      # noqa: E402,F401,F403
 from benchmark.tests.test_gather_fused_layer import *   # noqa: E402,F401,F403
+from benchmark.tests.test_tsbs_d8_data import *         # noqa: E402,F401,F403
 
 
 # Cases of those files that a star import alone does not give tier-1:
@@ -52,6 +58,8 @@ from benchmark.tests import test_hist_data as _hist_cases   # noqa: E402
 from benchmark.tests import test_prom_data as _prom_cases   # noqa: E402
 from benchmark.tests import test_prom_miss_data as _miss_cases  # noqa: E402
 from benchmark.tests import test_wait_layers as _wait_cases     # noqa: E402
+from benchmark.tests import test_gather_fused_layer as _fused_cases  # noqa: E402
+from benchmark.tests import test_tsbs_data as _tsbs_cases       # noqa: E402
 
 # ``hist``'s fill case bears the name of ``prom``'s, which the later import
 # shadows: collected here under a name of its own
@@ -124,7 +132,7 @@ def test_the_five_wait_entries_are_as_named_whatever_cells_they_list():
         assert (cells is None) == (name != "device_ahead_mean"), name
     p50 = next(m for m in bench["end_to_end"] if m["name"] == "query_p50_ms")
     assert per_layer["device_ahead_mean"]["workloads"] == [
-        c for c in p50["workloads"] if c != "tsbs_single"]
+        c for c in p50["workloads"] if not c.startswith("tsbs_single")]
 
 
 def test_the_prom_cells_are_as_named_whatever_follows_them():
@@ -222,3 +230,74 @@ def test_the_prom_cells_are_as_named_whatever_follows_them():
               "control_stamps.py", "configs/promdev_prom_miss_1m.json",
               "configs/promdev_prom_1m.json"):
         assert os.path.isfile(os.path.join(BENCH, f)), f
+
+
+_PR44 = (
+    "{file} pins the `workloads` of {what} to [\"tsbs_single\"] alone; PR 44 "
+    "appended its cell tsbs_single_12h to those lists, as ISSUE 44 asks (the "
+    "cell runs the same narrow leaf and reports what they read), and may "
+    "edit no file the benchmark has. A `benchmark` PR has to make that case "
+    "test membership, not equality (ROADMAP.md queue 2 item 0 (12)); "
+    "everything else it says is held by {held}")
+
+
+@pytest.mark.xfail(strict=True, reason=_PR44.format(
+    file="benchmark/tests/test_tsbs_data.py",
+    what="gather_mean_ms, selected_series_mean, matcher_miss_pct and "
+         "leaf_device_ms",
+    held="test_the_tsbs_cells_are_as_named_whatever_follows_them"))
+def test_tsbs_configuration_cell_and_layers_are_as_named():     # noqa: F811
+    _tsbs_cases.test_tsbs_configuration_cell_and_layers_are_as_named()
+
+
+@pytest.mark.xfail(strict=True, reason=_PR44.format(
+    file="benchmark/tests/test_gather_fused_layer.py",
+    what="gather_fused_pct",
+    held="test_the_tsbs_cells_are_as_named_whatever_follows_them"))
+def test_the_entry_is_as_the_issue_names_it():                  # noqa: F811
+    _fused_cases.test_the_entry_is_as_the_issue_names_it()
+
+
+def test_the_tsbs_cells_are_as_named_whatever_follows_them():
+    """What the two pinned cases above say of PR 41's four entries and PR
+    42's one, key by key, with ``workloads`` held to the cells whose leaves
+    gather — ``tsbs_single`` first — and of ``tsbs_cpu_100k`` x
+    ``tsbs_single`` themselves, by membership."""
+    ROOT, BENCH = _tsbs_cases.ROOT, _tsbs_cases.BENCH
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    per_layer = {m["name"]: m for m in bench["per_layer"]}
+    gathering = [w["name"] for w in bench["workloads"]
+                 if w["traffic"].startswith("tsbs_single")]
+    assert gathering[0] == "tsbs_single"
+    for name, unit, better, source in (
+            ("gather_mean_ms", "ms", "lower", "program_span"),
+            ("selected_series_mean", "series", "lower", "program_span"),
+            ("matcher_miss_pct", "%", "lower", "program_span"),
+            ("leaf_device_ms", "ms", "lower", "device_trace"),
+            ("gather_fused_pct", "%", "higher", "program_span")):
+        assert per_layer[name] == {
+            "name": name, "unit": unit, "better": better, "source": source,
+            "layer": "leaf under the shard lock", "moves": "query_rate",
+            "workloads": gathering}, name
+        assert os.path.isfile(os.path.join(BENCH, "layers", f"{name}.py"))
+    confs = {c["name"]: c for c in bench["configs"]}
+    cells = {w["name"]: w for w in bench["workloads"]}
+    conf, cell = confs["tsbs_cpu_100k"], cells["tsbs_single"]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "tsbs_cpu_100k", "tsbs_single", 1)
+    assert "9.66 GB" in cell["why"] and "8 rows of 2^20" in cell["why"]
+    with open(os.path.join(ROOT, conf["file"])) as f:
+        d = json.load(f)
+    assert d["source"] == conf["source"] and d["data"] == "tsbs_cpu"
+    assert d["reduced"] == conf["reduced"] == ["history"]
+    assert (d["series"], d["hosts"], d["fill_columns"]) == (
+        1_000_000, 100_000, 720)
+    # accepted metrics whose readers find nothing where no fused program
+    # and no grouping runs: listed for the cells that do report them
+    fused = [w["name"] for w in bench["workloads"]
+             if w["name"] not in gathering]
+    for name in ("groupids_mean_ms", "kernel_host_mean_ms"):
+        assert per_layer[name]["workloads"] == fused, name
+    assert per_layer["device_ahead_mean"]["workloads"] == [
+        c for c in fused if c != "dash_live"]

@@ -6,11 +6,12 @@ function, step slice and aggregate map phase as one program under the shard
 lock, with what the host knows as that call's host arguments. The bodies are
 the stepwise form's, so the answers have to be that form's BIT FOR BIT: every
 range function the general kernels serve and three the grid kernels serve,
-under no aggregate and four, over 1, 8 and 64 rows, on a grid store and on
-one off the grid. What the selection can observe keeps the stepwise form
-elsewhere (a compressed-resident block, a line store, a histogram, cold
-chunks, a churned cohort, rows a fused kernel takes), and nothing lazy
-outlives the lock.
+under no aggregate and four, over 1, 8 and 64 rows, on a grid store, on one
+off the grid and on one born in its delta8 form (PR 44: its picked rows are
+decoded inside the program, and its answers are the raw store's too). What
+the selection can observe keeps the stepwise form elsewhere (a quant16
+block, a line store, a histogram, cold chunks, a churned cohort, rows a
+fused kernel takes), and nothing lazy outlives the lock.
 """
 
 import numpy as np
@@ -56,7 +57,12 @@ def value(h: int, k: int) -> float:
 LES = np.array([1.0, 2.0, np.inf])
 
 
-def build(off_grid: bool, dtype: str = "float32", **cfg):
+def value_int(h: int, k: int) -> float:
+    # value's integer part: a delta of at most 96, what an int8 holds
+    return float((k * (3 + h % 7) + h * 13) % 97)
+
+
+def build(off_grid: bool, dtype: str = "float32", value=value, **cfg):
     """A gauge store on its grid, or (``off_grid``) a prom-histogram store
     whose scrapes come late and are missed now and then: a layout store has
     no line form, so its stamps stay a resident s64 block off any grid, and
@@ -91,8 +97,13 @@ def stores():
     below)."""
     old = fusedresident.mode()
     fusedresident.set_mode("off")
-    out = {"grid": build(False), "off-grid": build(True)}
+    out = {"grid": build(False), "off-grid": build(True),
+           "narrow": build(False, value=value_int, narrow_resident=True),
+           "narrow-raw": build(False, value=value_int)}
     g, o = out["grid"][1].store, out["off-grid"][1].store
+    nb = out["narrow"][1].store
+    assert nb._inplace and nb.narrow_operands()[0] == "delta8"
+    assert nb.rehydrates == 0 and nb.ts is None and nb.val is None
     assert g.grid_info() is not None and g.grid_row_gather() is not None
     assert o.grid_info() is None and o.ts is not None and o.res is None
     assert o.extra["sum"].shape == (o.S, C)
@@ -122,7 +133,7 @@ def same_bits(a, b):
     assert x.tobytes() == y.tobytes()
 
 
-@pytest.mark.parametrize("kind", ["grid", "off-grid"])
+@pytest.mark.parametrize("kind", ["grid", "off-grid", "narrow"])
 @pytest.mark.parametrize("rows", [1, 8, 64])
 @pytest.mark.parametrize("agg", list(AGGS))
 @pytest.mark.parametrize("fn", rangefns.RANGE_FNS)
@@ -130,10 +141,11 @@ def test_one_program_answers_the_stepwise_forms_bits(stores, monkeypatch, fn,
                                                      agg, rows, kind):
     """On the grid store the eight functions the grid kernels have run the
     composed grid kernel and the other twelve the general one; off the grid
-    all twenty run the general one."""
+    all twenty run the general one; the delta8 store is a grid store whose
+    gather decodes, and answers as its raw twin does."""
     ms, _sh = stores[kind]
-    kernel = ("grid" if kind == "grid" and fn in gridfns.GRID_FNS
-              else "periodic")
+    on_grid = kind != "off-grid"
+    kernel = "grid" if on_grid and fn in gridfns.GRID_FNS else "periodic"
     text = AGGS[agg].format(TEXT[fn].format(sel=selector(rows, kind)))
     one, spans = run(ms, text, False, monkeypatch)
     (gat,) = spans
@@ -143,12 +155,17 @@ def test_one_program_answers_the_stepwise_forms_bits(stores, monkeypatch, fn,
     assert any(k[:4] == ("leaf", kernel, fn, op) for k in plan_cache.keys())
     steps, sspans = run(ms, text, True, monkeypatch)
     (sgat,) = sspans
-    assert sgat.tags["programs"] == qexec.STEPWISE_PROGRAMS[kind == "grid"] > 1
+    assert sgat.tags["programs"] == qexec.STEPWISE_PROGRAMS[on_grid] > 1
+    assert gat.tags["decode"] == sgat.tags["decode"] == (
+        "delta8" if kind == "narrow" else "raw")
     assert one.exec_path == steps.exec_path == "local-gather"
     assert one.matrix.num_series == (min(rows, 3) if agg == "count-by" else
                                      rows if agg == "none" else 1)
     assert not np.isnan(np.asarray(one.matrix.values)).all()
     same_bits(one, steps)
+    if kind == "narrow":
+        raw, _ = run(stores["narrow-raw"][0], text, False, monkeypatch)
+        same_bits(one, raw)
 
 
 # -- what keeps the stepwise form ------------------------------------------------
@@ -156,6 +173,9 @@ def test_one_program_answers_the_stepwise_forms_bits(stores, monkeypatch, fn,
 FEW, K = 12, 30
 PICK = (3, 5, 7, 10)
 WINDOW = 60_000
+
+
+FORMS = ("delta8", "delta16", "quant16")     # of a compressed-resident block
 
 
 def gather_forms() -> dict:
@@ -170,7 +190,9 @@ def stream(kind: str):
     them, and f32 holds their sums exactly)."""
     rng = np.random.default_rng(42)
     stamps = BASE + np.arange(K)[None, :] * IV + np.zeros((FEW, 1), np.int64)
-    vals = np.cumsum(rng.integers(1, 50, (FEW, K)), axis=1).astype(np.float64)
+    # increments an int8 holds; for "quant16" past it and inside u16's span
+    top = {"quant16": 1000, "delta16": 20000}.get(kind, 50)
+    vals = np.cumsum(rng.integers(1, top, (FEW, K)), axis=1).astype(np.float64)
     if kind == "line-holes":
         h, k = np.mgrid[:FEW, :K]
         stamps = np.where((h + k) % 11 == 10, -1,
@@ -191,7 +213,7 @@ def feed(kind: str, tmp_path):
     sh = ms.setup("prometheus", schema, 0, StoreConfig(
         max_series_per_shard=FEW, samples_per_series=C,
         flush_batch_size=10**9, groups_per_shard=1, dtype="float32",
-        narrow_resident=kind == "compressed"), sink=sink)
+        narrow_resident=kind in FORMS), sink=sink)
     for k in range(K):
         b = (RecordBuilder(schema, bucket_les=LES) if kind == "histogram"
              else RecordBuilder(schema))
@@ -205,8 +227,9 @@ def feed(kind: str, tmp_path):
         sh.ingest(b.build())
         sh.flush()
     st = sh.store
-    if kind == "compressed":
-        assert st.is_narrow_resident
+    if kind in FORMS:
+        assert st.is_narrow_resident and st.ts is None
+        assert st.narrow_operands()[0] == kind
     elif kind == "line-holes":
         assert st.res is not None and st.ts is None and st.hole_cells > 0
     elif kind == "histogram":
@@ -231,10 +254,37 @@ def want_sum_of_sums(stamps, vals, out_ts):
     return out
 
 
-@pytest.mark.parametrize("kind", ["compressed", "line-holes", "histogram",
+@pytest.mark.parametrize("kind", ["delta8", "delta16"])
+def test_a_delta_block_is_decoded_inside_the_one_program(kind, tmp_path):
+    """A store held as deltas on a grid — born so (delta8) or rebuilt so
+    after its first rows outgrew a byte (delta16) — joins the one program:
+    its gather span says which form it read, and the answer is the plain
+    reference's."""
+    ms, sh, stamps, vals = feed(kind, tmp_path)
+    assert sh.store._inplace
+    alt = "|".join(f"h{h}" for h in PICK)
+    text = f'sum(sum_over_time(m{{host=~"{alt}"}}[1m]))'
+    before = gather_forms()
+    tracer.drain()
+    r = QueryEngine(ms, "prometheus").query_range(text, BASE + 14 * IV + 137,
+                                                  END, STEP)
+    (g,) = [s for s in tracer.drain() if s.name == SPAN_QUERY_GATHER]
+    assert g.tags["programs"] == 1 and g.tags["decode"] == kind
+    assert g.tags["bytes"] == len(PICK) * C * (1 if kind == "delta8" else 2)
+    assert gather_forms()["one"] == before["one"] + 1
+    want = want_sum_of_sums(stamps, vals, np.asarray(r.matrix.out_ts))
+    got = np.asarray(r.matrix.values, np.float64)
+    np.testing.assert_allclose(got.reshape(want.shape), want, rtol=1e-6)
+    # born delta8 it was never decoded; the other declined a byte once (or
+    # passed through quant16 while its span was small) and was rebuilt
+    assert sh.store.is_narrow_resident
+    assert (sh.store.rehydrates == 0) == (kind == "delta8")
+
+
+@pytest.mark.parametrize("kind", ["quant16", "line-holes", "histogram",
                                   "paged"])
 def test_what_the_selection_observes_keeps_the_stepwise_form(kind, tmp_path):
-    """A compressed-resident block decodes row by row on the host's say, a
+    """A quant16 block decodes row by row on the host's say, a
     line store's stamps are laid together from host and device state, a
     histogram's kernels are others, cold chunks come from the sink: these
     leaves gather on their own (``programs`` > 1; the paged route gathers

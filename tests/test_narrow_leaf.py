@@ -216,6 +216,7 @@ def test_the_select_span_says_matchers_resolution_and_route_and_the_gather_its_r
         # that program's dispatch, beside the select span, not inside it
         assert gat.parent_id == leaf.span_id == sel.parent_id
         assert gat.tags == {"shard": 0, "rows": 3, "padded": 8,
+                            "decode": "raw",
                             "bytes": 3 * sh.store.C * (4 + 8),
                             "programs": 1}
         assert sel.tags["series"] == 3 and sel.tags["route"] == "gather"

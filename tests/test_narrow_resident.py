@@ -162,7 +162,8 @@ def test_continuous_data_declines_compression():
 
 
 def test_narrow_resident_compact_and_odp(tmp_path):
-    """Compaction rehydrates; ODP reads decode once per batch."""
+    """Compaction shifts the delta form in place; ODP reads decode once per
+    batch."""
     from filodb_tpu.core.store import FileColumnStore
     ms = TimeSeriesMemStore()
     sink = FileColumnStore(str(tmp_path))
@@ -179,8 +180,9 @@ def test_narrow_resident_compact_and_odp(tmp_path):
     sh.flush_all_groups()
     assert sh.store.is_narrow_resident
     sh.store.compact(START + 20 * INTERVAL)
-    assert not sh.store.is_narrow_resident   # rehydrated for the shift
-    sh.flush()          # nothing staged — the quiesced shard MUST re-compress
+    # the delta form ages out as it is: anchors move on, nothing is decoded
+    assert sh.store.is_narrow_resident and sh.store.rehydrates == 0
+    sh.flush()          # nothing staged — and nothing to re-compress
     assert sh.store.is_narrow_resident
     pids = sh.part_ids_from_filters([], START, START + 40 * INTERVAL)
     assert sh.needs_paging(pids, START)
@@ -194,10 +196,14 @@ def test_two_phase_compress_aborts_on_racing_mutation():
     """A mutation landing between the (unlocked) build and the swap must
     abort the commit — the stale compressed state would drop the race's
     samples. The next flush re-attempts on the new epoch."""
-    ms, sh = _build(True)
+    # a store born RAW that residency is then asked of: the rebuild is the
+    # only way it becomes narrow (a store born narrow never rebuilds)
+    import dataclasses
+    ms, sh = _build(False)
+    sh.config = dataclasses.replace(sh.config, narrow_resident=True)
     st = sh.store
-    assert st.is_narrow_resident
-    # rehydrate via an append, then race the re-compression
+    assert not st.is_narrow_resident
+    # an append, then race the compression
     b = RecordBuilder(GAUGE)
     b.add({"_metric_": "m", "host": "h0", "grp": "g0"},
           START + (N + 1) * INTERVAL, 7.0)

@@ -505,3 +505,98 @@ def test_a_gathered_leafs_one_program_compiles_for_v5e_with_no_block_temp(
     assert mem.temp_size_in_bytes < (64 << 20), mem
     assert mem.argument_size_in_bytes < S * C * 4 + S * 4 + (1 << 16)
     assert mem.output_size_in_bytes < (1 << 16)
+
+
+# -- the delta8 store at 12 h (PR 44): 2^20 x 4,608 one-byte columns ------------
+
+C12H = 4608
+
+
+def test_the_flush_of_a_delta8_store_at_twelve_hours_runs_in_place(one_chip):
+    """The append of ``tsbs_cpu_100k_12h``: the per-row select writes an
+    int8 delta into the 4.83 GB block in place — no f32 or s64 block, no
+    second copy."""
+    from filodb_tpu.core import chunkstore
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    compiled = chunkstore._dense_set.lower(
+        sds((S, C12H), jnp.int8), sds((S,), i32), sds((S,), jnp.int8)).compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    assert mem.alias_size_in_bytes >= S * C12H
+    assert mem.temp_size_in_bytes < (64 << 20), mem
+    assert f"f32[{S},{C12H}]" not in text and "s64[" not in text
+
+
+def test_a_delta8_store_ages_out_in_row_blocks(one_chip):
+    """``compact`` of the in-place form: one block of BLOCK_ROWS rows a
+    program, the block donated, the temporaries a block's (the shifted
+    deltas and their indices), never the store's."""
+    from filodb_tpu.core import chunkstore
+    rows = chunkstore.BLOCK_ROWS
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    compiled = chunkstore._compact_delta_block.lower(
+        sds((S, C12H), jnp.int8), sds((S,), f32), sds((S,), i32),
+        sds((S,), i32), sds((), i32), rows).compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    assert mem.alias_size_in_bytes >= S * C12H
+    assert mem.temp_size_in_bytes < rows * C12H * 12, mem
+    assert f"[{S},{C12H}]" not in text.replace(f"s8[{S},{C12H}]", "")
+    # the sum that check_filled and a rebuild's mirrors read: no f32 block
+    summed = chunkstore._row_last.lower(
+        sds((S, C12H), jnp.int8), sds((S,), f32)).compile()
+    assert summed.memory_analysis().temp_size_in_bytes < (256 << 20)
+    assert f"f32[{S},{C12H}]" not in summed.as_text()
+
+
+@pytest.mark.parametrize("P", [1, 8])
+def test_a_gathered_leaf_over_a_delta8_store_decodes_inside_its_one_program(
+        one_chip, P):
+    """The one program of ``tsbs_single_12h``'s leaf: 1 or 8 rows of 2^20 x
+    4,608 gathered as int8, decoded (anchor + running sum, the pool laid
+    over), their stamps derived, ``max_over_time`` over 736 padded steps,
+    the slice to 721 and the ``max`` aggregate's map phase. Its store-sized
+    operand is the int8 block alone, and it holds no temporary of a block's
+    size."""
+    import functools
+
+    from filodb_tpu.core import chunkstore
+    from filodb_tpu.query import exec as qexec
+    T, Tpad = 721, 736
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    with jax.enable_x64(True):
+        i64, f64 = jnp.int64, jnp.float64
+        spec, ints, floats, _dev = qexec._pack_operands((
+            np.zeros((3, P), np.int64), np.zeros(Tpad, np.int64),
+            np.int64(60_000), np.float64(0), np.float64(0),
+            np.zeros(P, np.int32)))
+        body = functools.partial(
+            qexec._leaf_body,
+            lambda dv, anchor, pool, slot, n, picked:
+            chunkstore._gather_grid_delta(dv, anchor, pool, slot, n, picked,
+                                          C12H),
+            "periodic", "max_over_time", "max", 1, T, spec, 1)
+        compiled = jax.jit(body).lower(
+            (sds((S, C12H), jnp.int8), sds((S,), f32), sds((1, C12H), f32),
+             sds((S,), i32), sds((S,), i32)), sds(ints.shape, i64),
+            sds(floats.shape, f64), ()).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert f"f32[{S},{C12H}]" not in text and f"s64[{S}," not in text
+    assert mem.temp_size_in_bytes < (64 << 20), mem
+    assert mem.argument_size_in_bytes < S * C12H + 3 * S * 4 + (1 << 16)
+    assert mem.output_size_in_bytes < (1 << 16)
+
+
+def test_the_fill_of_a_delta8_store_walks_a_row_block_a_program(one_chip):
+    """``benchmark/data/tsbs_cpu_d8/fill.py`` at the deployment's size: one
+    block of 2^16 rows walked 4,415 scrapes, its int8 deltas written into
+    the donated block; the temporaries are a block's copies, under 1.5 GB
+    beside 4.83 resident."""
+    import importlib
+    fill = importlib.import_module("benchmark.data.tsbs_cpu_d8.fill")
+    walk_rows, _fill_n = fill._programs(4416, fill.ROWS)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    compiled = walk_rows.lower(
+        sds((S, C12H), jnp.int8), sds((S,), i32), sds((), jnp.uint32),
+        sds((), i32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= S * C12H
+    assert mem.temp_size_in_bytes < (1536 << 20), mem
