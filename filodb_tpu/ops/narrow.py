@@ -196,7 +196,7 @@ def build_narrow_delta(val, n):
 @functools.lru_cache(1)
 def _cast_delta_i8_call():
     # donation declared only where XLA honors it (the CPU backend warns and
-    # ignores it — same gate as parallel/distributed._donate_argnums)
+    # ignores it)
     donate = () if jax.default_backend() == "cpu" else (0,)
     return jax.jit(lambda dv16: dv16.astype(jnp.int8), donate_argnums=donate)
 

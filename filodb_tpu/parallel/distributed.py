@@ -14,8 +14,9 @@ scatter-gather: no serialization, no per-shard dispatch.
 
 One program form: the per-shard body (the fused tiling plan / the two-step
 kernels) wraps in ``shard_map`` and jits with EXPLICIT
-``in_shardings``/``out_shardings`` (``NamedSharding`` per operand) plus
-donation of the per-query group-id globals where the backend honors it.
+``in_shardings``/``out_shardings`` (``NamedSharding`` per operand). No
+operand is donated: the store's blocks are the store's, and the group-id
+rows and the window plan are kept from query to query (:class:`MeshLeafMemo`).
 Declaring both sides is mandatory: implicit propagation would silently
 re-gather sharded store operands (filolint ``mesh-sharding-undeclared``
 enforces this statically). The virtual CPU mesh of the test suite compiles
@@ -48,29 +49,22 @@ shards only.
 from __future__ import annotations
 
 import functools
+from collections import OrderedDict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..ops import aggregators, fusedgrid, rangefns
+from ..ops import aggregators, decodereg, fusedgrid, fusedresident, rangefns
 from ..utils.metrics import (FILODB_QUERY_MESH_FALLBACK,
+                             FILODB_QUERY_MESH_PREPARED,
                              FILODB_QUERY_MESH_SERVED, registry)
 
 
 def make_mesh(devices=None, axis: str = "shard") -> Mesh:
     devices = devices if devices is not None else jax.devices()
     return Mesh(np.asarray(devices), (axis,))
-
-
-def _donate_argnums(donate: tuple) -> tuple:
-    """Donation is declared only where XLA can honor it: the CPU backend
-    lacks buffer donation (jax warns and ignores it), so CI keeps clean
-    logs while TPU/GPU runs reuse the per-query group-id buffers."""
-    if jax.default_backend() == "cpu":
-        return ()
-    return donate
 
 
 def count_mesh_served(route: str) -> None:
@@ -84,11 +78,16 @@ def count_mesh_fallback(reason: str) -> None:
                      {"reason": reason}).increment()
 
 
+def count_mesh_prepared(part: str, outcome: str) -> None:
+    registry.counter(FILODB_QUERY_MESH_PREPARED,
+                     {"part": part, "outcome": outcome}).increment()
+
+
 def _is_pspec(x) -> bool:
     return isinstance(x, P)
 
 
-def _sharded_jit(mesh: Mesh, in_specs, out_specs, donate: tuple = ()):
+def _sharded_jit(mesh: Mesh, in_specs, out_specs):
     """The mesh programs' jit applicator: every ``PartitionSpec`` leaf in
     the operand trees becomes an explicit ``NamedSharding`` on ``mesh`` and
     BOTH ``in_shardings`` and ``out_shardings`` are declared (the jax_graft
@@ -99,21 +98,32 @@ def _sharded_jit(mesh: Mesh, in_specs, out_specs, donate: tuple = ()):
                             is_leaf=_is_pspec)
     in_shardings = to_shardings(in_specs)
     out_shardings = to_shardings(out_specs)
-    donate = _donate_argnums(donate)
 
     def wrap(fn):
         return jax.jit(fn, in_shardings=in_shardings,
-                       out_shardings=out_shardings, donate_argnums=donate)
+                       out_shardings=out_shardings)
     return wrap
 
 
 class DistributedStore:
     """Global sharded view over per-shard device stores.
 
-    Each TimeSeriesShard's SeriesStore already lives on one mesh device; this
-    assembles the per-device blocks into global arrays [NDEV, S, C] sharded on
-    the "shard" mesh axis with ``make_array_from_single_device_arrays`` — zero
-    copy, the shards' HBM blocks become one logical array.
+    Each TimeSeriesShard's SeriesStore already lives on one mesh device; a
+    slot's global array is ``[NDEV * S, ...]`` sharded on its FIRST axis over
+    the "shard" mesh axis, assembled with
+    ``make_array_from_single_device_arrays``: a device's block IS the
+    shard's resident array. No program runs and no byte moves — the
+    ``[NDEV, S, C]`` form this had before PR 46 put an eager
+    ``reshape((1, S, C))`` in front of the assembly, which is a program
+    whose result cannot alias its operand: one copy of the whole value block
+    a shard a query. Inside ``shard_map`` a ``per_device`` body therefore
+    reads its ``[S, ...]`` block as it lies.
+
+    Everything here is host work on array handles, so it is what a mesh leaf
+    does under the shard locks (query/engine.py ``_try_mesh``): capture,
+    assemble, one pjit call. What costs a trip through the runtime — the
+    group-id rows' upload, the window plan — comes ready or from
+    :class:`MeshLeafMemo`.
 
     Shards-per-device >= 1: with ``ns == slots * ndev`` shards placed
     round-robin (shard i on device i % ndev — standalone's placement), slot j
@@ -133,15 +143,15 @@ class DistributedStore:
         self.S, self.C = s0.S, s0.C
         self.sharding = NamedSharding(mesh, P("shard"))
 
-    def _global(self, per_shard_arrays, extra_shape, dtype):
-        ndev = len(per_shard_arrays)
-        shape = (ndev,) + extra_shape
-        arrs = [a.reshape((1,) + extra_shape) for a in per_shard_arrays]
+    def _global(self, per_shard_arrays):
+        """One slot's global over the devices' own blocks (device order)."""
+        a0 = per_shard_arrays[0]
         return jax.make_array_from_single_device_arrays(
-            shape, self.sharding, arrs)
+            (len(per_shard_arrays) * a0.shape[0],) + a0.shape[1:],
+            self.sharding, list(per_shard_arrays))
 
     def _slot(self, j: int):
-        return [self.shards[j * self.ndev + d] for d in range(self.ndev)]
+        return self.shards[j * self.ndev:(j + 1) * self.ndev]
 
     def arrays(self):
         """Per-slot tuples of (ts, val, n) global arrays. Narrow-resident
@@ -154,12 +164,8 @@ class DistributedStore:
         out = []
         for j in range(self.slots):
             closed = [s.store.closed_arrays() for s in self._slot(j)]
-            out.append((
-                self._global([c[0] for c in closed],
-                             (self.S, self.C), jnp.int64),
-                self._global([c[1] for c in closed],
-                             (self.S, self.C), None),
-                self._global([c[2] for c in closed], (self.S,), jnp.int32)))
+            out.append(tuple(self._global([c[k] for c in closed])
+                             for k in range(3)))
         return out
 
     def value_arrays(self):
@@ -168,10 +174,8 @@ class DistributedStore:
         out = []
         for j in range(self.slots):
             ss = self._slot(j)
-            out.append((
-                self._global([s.store.value_block() for s in ss],
-                             (self.S, self.C), None),
-                self._global([s.store.n for s in ss], (self.S,), jnp.int32)))
+            out.append((self._global([s.store.value_block() for s in ss]),
+                        self._global([s.store.n for s in ss])))
         return out
 
     def narrow_arrays(self):
@@ -202,36 +206,42 @@ class DistributedStore:
             ss = self._slot(j)
             ops = per_shard[j * self.ndev:(j + 1) * self.ndev]
             out.append((
-                self._global([o[0] for o in ops], (self.S, self.C), None),
-                tuple(self._global([o[r] for o in ops], (self.S,), None)
+                self._global([o[0] for o in ops]),
+                tuple(self._global([o[r] for o in ops])
                       for r in range(1, nrows + 1)),
-                self._global([s.store.n for s in ss], (self.S,), jnp.int32)))
+                self._global([s.store.n for s in ss])))
         return kind, tuple(out)
 
-    def global_gids(self, group_ids_per_shard):
-        """Per-slot global [NDEV, S] gid arrays, device_put to each shard's
-        device (caller passes one [S] array per shard, shard order). Built
-        fresh per dispatch, so the mesh programs may DONATE them."""
-        out = []
-        for j in range(self.slots):
-            arrs = []
-            for d in range(self.ndev):
-                sh = self.shards[j * self.ndev + d]
-                g = group_ids_per_shard[j * self.ndev + d]
+    def place_gids(self, group_ids_per_shard) -> tuple:
+        """One ``[S]`` int32 row per shard (shard order), each uploaded
+        straight to its shard's own device: the rows a :class:`MeshLeafMemo`
+        keeps. A row that is a device array already is taken as it is."""
+        rows = []
+        for sh, g in zip(self.shards, group_ids_per_shard):
+            if not isinstance(g, jax.Array):
                 # n is resident under every residency state (ts may be elided)
-                dev = list(sh.store.n.devices())[0]
-                arrs.append(jax.device_put(jnp.asarray(g, jnp.int32), dev))
-            out.append(self._global(arrs, (self.S,), jnp.int32))
-        return out
+                g = jax.device_put(np.asarray(g, np.int32),
+                                   next(iter(sh.store.n.devices())))
+            rows.append(g)
+        return tuple(rows)
+
+    def global_gids(self, group_ids_per_shard):
+        """Per-slot global ``[NDEV * S]`` gid arrays over the shards' rows
+        (:meth:`place_gids` puts a host row on its device first). The mesh
+        programs only read them: a memo's rows serve every query."""
+        rows = self.place_gids(group_ids_per_shard)
+        return [self._global(rows[j * self.ndev:(j + 1) * self.ndev])
+                for j in range(self.slots)]
 
 
 def _slot_matrix(fn, slot_tvn, slot_gids, out_ts, window_ms, a0, a1):
-    """Yield the per-slot [S, T] matrix + [S] gids of THIS device's blocks."""
+    """Yield the per-slot [S, T] matrix + [S] gids of THIS device's blocks
+    (a device's share of a ``[NDEV * S, ...]`` global is its own block)."""
     for (ts, val, n), gids in zip(slot_tvn, slot_gids):
         acc = jnp.float64 if val.dtype == jnp.float64 else jnp.float32
-        mat = rangefns._periodic(fn, ts[0], val[0], n[0], out_ts, window_ms,
+        mat = rangefns._periodic(fn, ts, val, n, out_ts, window_ms,
                                  a0, a1, w_cap=256, acc=acc)
-        yield mat, gids[0]
+        yield mat, gids
 
 
 def _stack_parts(slot_parts):
@@ -251,7 +261,7 @@ def _stack_parts(slot_parts):
 
 
 def _dist_program(kernel: str, statics: tuple, slot_shapes: tuple, build,
-                  mesh: Mesh, in_specs, out_specs, donate: tuple = ()):
+                  mesh: Mesh, in_specs, out_specs):
     """Mesh twin of the in-process kernel routing: every ``dist_*``
     collective below is a per-key program in the SAME process-global
     compiled-plan cache (query/plancache.py), keyed on its statics plus the
@@ -260,7 +270,7 @@ def _dist_program(kernel: str, statics: tuple, slot_shapes: tuple, build,
     first mesh query compiles here, every repeat (and every warmup-covered
     shape) hits.
 
-    The entry jits with the explicit boundary shardings (and donation) from
+    The entry jits with the explicit boundary shardings from
     ``_sharded_jit`` — both spec trees are REQUIRED parameters, the runtime
     twin of filolint's ``mesh-sharding-undeclared`` rule."""
     from ..query.plancache import plan_cache
@@ -268,7 +278,7 @@ def _dist_program(kernel: str, statics: tuple, slot_shapes: tuple, build,
                                    mesh.devices.size)
     return plan_cache.program(
         kernel, key, build,
-        wrap=_sharded_jit(mesh, in_specs, out_specs, donate))
+        wrap=_sharded_jit(mesh, in_specs, out_specs))
 
 
 def _tvn_shapes(slot_tvn) -> tuple:
@@ -289,7 +299,7 @@ def dist_aggregate(slot_tvn, slot_gids, out_ts, window_ms, a0, a1,
         _tvn_shapes(slot_tvn),
         lambda: functools.partial(_dist_aggregate_impl, fn, op, num_groups,
                                   mesh),
-        mesh, in_specs=_TWOSTEP_IN_SPECS, out_specs=P("shard"), donate=(1,)
+        mesh, in_specs=_TWOSTEP_IN_SPECS, out_specs=P("shard")
     )(slot_tvn, slot_gids, out_ts, window_ms, a0, a1)
 
 
@@ -322,7 +332,7 @@ def dist_quantile_sketch(slot_tvn, slot_gids, out_ts, window_ms, a0, a1,
         _tvn_shapes(slot_tvn),
         lambda: functools.partial(_dist_quantile_sketch_impl, fn, num_groups,
                                   mesh),
-        mesh, in_specs=_TWOSTEP_IN_SPECS, out_specs=P("shard"), donate=(1,)
+        mesh, in_specs=_TWOSTEP_IN_SPECS, out_specs=P("shard")
     )(slot_tvn, slot_gids, out_ts, window_ms, a0, a1)
 
 
@@ -384,8 +394,7 @@ def dist_topk(slot_tvn, slot_gids, out_ts, window_ms, a0, a1,
         lambda: functools.partial(_dist_topk_impl, fn, k, bottom, num_groups,
                                   mesh, ndev),
         mesh, in_specs=_TWOSTEP_IN_SPECS,
-        out_specs=(P("shard"), P("shard"), P("shard"), P("shard")),
-        donate=(1,)
+        out_specs=(P("shard"), P("shard"), P("shard"), P("shard"))
     )(slot_tvn, slot_gids, out_ts, window_ms, a0, a1)
 
 
@@ -511,7 +520,7 @@ def dist_fused_aggregate(slot_vals, slot_ns, slot_gids, band, ohlo, lo, hi, rel,
         lambda: functools.partial(_dist_fused_aggregate_impl, fn, op,
                                   num_groups, mesh, window_ms, interval_ms,
                                   S, C, Tp, c0, Ck, variant),
-        mesh, in_specs=_FUSED_IN_SPECS, out_specs=P("shard"), donate=(2,)
+        mesh, in_specs=_FUSED_IN_SPECS, out_specs=P("shard")
     )(slot_vals, slot_ns, slot_gids, band, ohlo, lo, hi, rel)
 
 
@@ -537,9 +546,9 @@ def _dist_fused_aggregate_impl(fn: str, op: str, num_groups: int, mesh: Mesh,
     def per_device(slot_vals, slot_ns, slot_gids, band, ohlo, lo, hi, rel):
         slot_parts = []
         for val, n, gids in zip(slot_vals, slot_ns, slot_gids):
-            o = call(val[0].astype(jnp.float32),
-                     fusedgrid.lane_major(n[0].astype(jnp.int32), Sb),
-                     fusedgrid.lane_major(gids[0].astype(jnp.int32), Sb),
+            o = call(val.astype(jnp.float32),
+                     fusedgrid.lane_major(n.astype(jnp.int32), Sb),
+                     fusedgrid.lane_major(gids.astype(jnp.int32), Sb),
                      band, ohlo, lo, hi, rel)
             slot_parts.append(_fused_parts(op, o))
         return _stack_parts(slot_parts)
@@ -570,8 +579,7 @@ def dist_fused_aggregate_narrow(slot_blocks, slot_rows, slot_ns,
         lambda: functools.partial(_dist_fused_narrow_impl, fn, op,
                                   num_groups, mesh, window_ms, interval_ms,
                                   S, C, Tp, kind, c0, Ck, variant),
-        mesh, in_specs=_FUSED_NARROW_IN_SPECS, out_specs=P("shard"),
-        donate=(3,)
+        mesh, in_specs=_FUSED_NARROW_IN_SPECS, out_specs=P("shard")
     )(slot_blocks, slot_rows, slot_ns, slot_gids, band, ohlo, lo, hi, rel)
 
 
@@ -598,9 +606,9 @@ def _dist_fused_narrow_impl(fn: str, op: str, num_groups: int, mesh: Mesh,
         slot_parts = []
         for blk, rows, n, gids in zip(slot_blocks, slot_rows, slot_ns,
                                       slot_gids):
-            o = call(blk[0], *(fusedgrid.lane_major(r[0], Sb) for r in rows),
-                     fusedgrid.lane_major(n[0].astype(jnp.int32), Sb),
-                     fusedgrid.lane_major(gids[0].astype(jnp.int32), Sb),
+            o = call(blk, *(fusedgrid.lane_major(r, Sb) for r in rows),
+                     fusedgrid.lane_major(n.astype(jnp.int32), Sb),
+                     fusedgrid.lane_major(gids.astype(jnp.int32), Sb),
                      band, ohlo, lo, hi, rel)
             slot_parts.append(_fused_parts(op, o))
         return _stack_parts(slot_parts)
@@ -612,6 +620,143 @@ def _dist_fused_narrow_impl(fn: str, op: str, num_groups: int, mesh: Mesh,
         out_specs=P("shard"),
         check_vma=False,
     )(slot_blocks, slot_rows, slot_ns, slot_gids, band, ohlo, lo, hi, rel)
+
+
+def _takes_fused(fn: str, op: str, S: int, C: int, T: int, G: int) -> bool:
+    """The query's and the shape's side of the fused gate (the stores' side
+    is ``MeshQueryExecutor._fused_grid``)."""
+    return (fusedresident.tag() != "off"
+            and fn in fusedgrid.FUSED_FNS | fusedgrid.FUSED_WINDOW_FNS
+            and op in fusedgrid.FUSED_OPS
+            and fusedgrid.fusable(S, C, T, G))
+
+
+def _steps(out_ts: np.ndarray):
+    """The step grid bucketed (padded to a multiple of 32, repeating the
+    last step): the general programs jit-compile per output shape and ad-hoc
+    dashboards would otherwise recompile per query — the same compile-space
+    bucketing as the in-process path. A HOST array: it goes up replicated
+    inside the one pjit call."""
+    from ..query.exec import _pad_steps
+    return _pad_steps(np.asarray(out_ts, np.int64))
+
+
+def _host_args(window_ms: int, args) -> tuple:
+    """(window, a0, a1) as host scalars of the general programs' call."""
+    return (np.int64(window_ms), np.float64(args[0]), np.float64(args[1]))
+
+
+def _plan_key(C: int, out_ts: np.ndarray, window_ms: int, base_ts: int,
+              interval_ms: int, fn: str, kind: str) -> tuple:
+    """:func:`_mesh_operands`' arguments after the mesh for one query on one
+    grid: all the query's but ``(base_ts, interval_ms)`` and the decode
+    variant ``kind``, which the stores decide."""
+    Tp = (max(len(out_ts), 1) + 127) // 128 * 128
+    return (C, Tp,
+            np.ascontiguousarray(np.asarray(out_ts, np.int64)).tobytes(),
+            int(window_ms), int(base_ts), int(interval_ms),
+            "window" if fn in fusedgrid.FUSED_WINDOW_FNS else "rate",
+            decodereg.variant(kind).full_columns)
+
+
+@functools.lru_cache(maxsize=32)
+def _mesh_operands(mesh: Mesh, C: int, Tp: int, out_ts_key: bytes,
+                   window_ms: int, base_ts: int, interval_ms: int,
+                   fn_kind: str, full_cols: bool):
+    """The window plan of one step grid (``fusedgrid.host_operands``: band,
+    one-hot and edge operands, megabytes that only the grid decides) placed
+    REPLICATED on the mesh, as the fused programs' ``in_shardings`` ask for
+    it — the mesh twin of ``fusedgrid._device_operands``, whose arrays sit
+    on the default device and would be spread to the others inside every
+    dispatch. Cached per grid; an ad-hoc explorer never repeats one, so the
+    leaf asks for it BEFORE it takes the shard locks
+    (:meth:`MeshLeafMemo.prepare_plan`) and hits here under them."""
+    *arrs, c0, Ck = fusedgrid.host_operands(
+        C, Tp, np.frombuffer(out_ts_key, np.int64), window_ms, base_ts,
+        interval_ms, fn_kind, full_cols)
+    return tuple(jax.device_put(arrs, NamedSharding(mesh, P()))) + (c0, Ck)
+
+
+class MeshLeafMemo:
+    """What a mesh leaf computes from the QUERY, the INDEX state or the
+    STORES' shape and from no sample, kept by the engine from one query to
+    the next so that the leaf holds every shard's lock for validation, array
+    capture and ONE pjit call (query/engine.py ``_try_mesh``). Each trip
+    through the runtime under four locks is a hold of all four — and the
+    rate of a lock-bound mesh is 1000 / hold.
+
+    - **The window plan.** Its key is the query's except for the stores'
+      grid and decode variant. ``seen`` is what the last fused dispatch
+      observed of those; :meth:`prepare_plan`, called before the locks,
+      builds the plan for it. Under the locks the executor's own lookup then
+      hits (``plan=ready``); a grid or variant that moved meanwhile builds
+      there as it always did (``plan=built``).
+    - **The group-id rows.** The shared numbering of the groups and the
+      shards' dense rows depend only on the shards' kept ``ShardSelection``s
+      (core/selection.py: one object a selector an index state) and on
+      ``(by, without)``: an LRU of ``GIDS`` entries keyed by the selections'
+      identity holds the group keys and the rows, each resident on its own
+      shard's device. An entry keeps its selections alive, so an identity is
+      never reused while it is a key. A selection the shard does not keep
+      (``stamp is None``: narrow, a time mask that bites, recovering) is
+      never a key.
+
+    :meth:`gids` and :meth:`keep_gids` run under every shard's lock (one
+    mesh leaf at a time); :meth:`prepare_plan` runs under none and touches
+    only ``seen``, one tuple swapped whole."""
+
+    # (selector, grouping) pairs kept: 4 B a series a chip each, and the
+    # selections of an index state that has passed until they age out
+    GIDS = 8
+
+    def __init__(self, mesh: Mesh):
+        self.mesh = mesh
+        self.seen = None    # (S, C, base_ts, interval_ms, decode variant)
+        self._gids: OrderedDict = OrderedDict()
+
+    def __len__(self) -> int:
+        return len(self._gids)
+
+    def prepare_plan(self, fn: str, op: str, out_ts: np.ndarray,
+                     window_ms: int):
+        """Build (or find) the window plan of this query on the grid the
+        last fused dispatch saw; the key to hand the executor, None where
+        nothing was seen yet or the query takes no fused program."""
+        seen = self.seen
+        if seen is None:
+            return None
+        S, C, base_ts, interval_ms, kind = seen
+        if not _takes_fused(fn, op, S, C, len(out_ts), 8):
+            return None
+        key = _plan_key(C, out_ts, window_ms, base_ts, interval_ms, fn, kind)
+        _mesh_operands(self.mesh, *key)
+        return key
+
+    @staticmethod
+    def _gids_key(selections, by, without):
+        if any(sel.stamp is None for sel in selections):
+            return None
+        return tuple(map(id, selections)), tuple(by), tuple(without)
+
+    def gids(self, selections, by, without):
+        """``(group keys, device rows)`` kept for these selections under this
+        grouping, None where there is none (or can be none: a bypass)."""
+        key = self._gids_key(selections, by, without)
+        kept = self._gids.get(key)
+        if kept is None:
+            return None
+        self._gids.move_to_end(key)
+        return kept[1:]
+
+    def keep_gids(self, selections, by, without, keys, rows) -> bool:
+        """Keep what a leaf just built; False for a bypass (never kept)."""
+        key = self._gids_key(selections, by, without)
+        if key is None:
+            return False
+        self._gids[key] = (tuple(selections), tuple(keys), tuple(rows))
+        if len(self._gids) > self.GIDS:
+            self._gids.popitem(last=False)
+        return True
 
 
 class LazyMeshResult:
@@ -666,14 +811,21 @@ class MeshQueryExecutor:
     grid-aligned to one common (base, interval) with a single uniform start
     cohort, and the shapes fit the fused kernel's VMEM gate, the per-shard
     map phase runs the single-pass fused Pallas kernel; otherwise the
-    general two-step kernels. ``last_path`` records the route taken and
-    ``last_block`` the fused kernel's column block ``(c0, columns)``, None
-    on every other route."""
+    general two-step kernels. ``last_path`` records the route taken,
+    ``last_block`` the fused kernel's column block ``(c0, columns)`` and
+    ``last_plan`` whether its window plan came ``ready`` (the key the
+    caller prepared before the locks) or was ``built`` here — both None on
+    every other route. Every method is host work on handles and ONE pjit
+    call: the step grid, window and arguments of the general programs go in
+    as host values of that call."""
 
-    def __init__(self, dstore: DistributedStore):
+    def __init__(self, dstore: DistributedStore,
+                 memo: MeshLeafMemo | None = None):
         self.dstore = dstore
+        self.memo = memo
         self.last_path: str | None = None
         self.last_block: tuple[int, int] | None = None
+        self.last_plan: str | None = None
 
     def _fused_grid(self):
         """Common (base_ts, interval_ms) when every shard qualifies for the
@@ -694,20 +846,15 @@ class MeshQueryExecutor:
 
     def aggregate(self, fn: str, op: str, out_ts: np.ndarray, window_ms: int,
                   group_ids_per_shard: list[np.ndarray], num_groups: int,
-                  args=(0.0, 0.0), fetch: bool = True):
+                  args=(0.0, 0.0), fetch: bool = True, prepared=None):
         slot_gids = tuple(self.dstore.global_gids(group_ids_per_shard))
         G = _pow2(num_groups)
         S, C, T = self.dstore.S, self.dstore.C, len(out_ts)
-        from ..ops import fusedresident
         variant = fusedresident.tag()
-        grid = (self._fused_grid()
-                if variant != "off"
-                and fn in fusedgrid.FUSED_FNS | fusedgrid.FUSED_WINDOW_FNS
-                and op in fusedgrid.FUSED_OPS
-                and fusedgrid.fusable(S, C, T, G) else None)
+        grid = (self._fused_grid() if _takes_fused(fn, op, S, C, T, G)
+                else None)
         if grid is not None:
             base_ts, interval_ms = grid
-            Tp = (max(T, 1) + 127) // 128 * 128
             # narrow-resident shards stream their 1-2B/sample state through
             # the fused kernel; stores with cohort-pool rows (or raw
             # residency) feed it the f32 view instead (a transient decode
@@ -716,14 +863,18 @@ class MeshQueryExecutor:
             # decode via a column-prefix cumsum, so they pin full columns
             narrow = self.dstore.narrow_arrays()
             kind = narrow[0] if narrow is not None else "raw"
-            from ..ops import decodereg
-            # cached per query shape — the [C, Tp] bands are megabytes that
-            # never change per shape (same cache as single-chip)
-            band, ohlo, lo, hi, rel, c0, Ck = fusedgrid._device_operands(
-                C, Tp, np.ascontiguousarray(np.asarray(out_ts, np.int64)).tobytes(),
-                int(window_ms), base_ts, int(interval_ms),
-                "window" if fn in fusedgrid.FUSED_WINDOW_FNS else "rate",
-                decodereg.variant(kind).full_columns)
+            # the [C, Tp] bands are megabytes that only the step grid
+            # decides: ready where the caller prepared this very key before
+            # it took the locks, built (and uploaded) here otherwise
+            key = _plan_key(C, out_ts, window_ms, base_ts, interval_ms, fn,
+                            kind)
+            self.last_plan = "ready" if key == prepared else "built"
+            count_mesh_prepared("plan", self.last_plan)
+            band, ohlo, lo, hi, rel, c0, Ck = _mesh_operands(
+                self.dstore.mesh, *key)
+            if self.memo is not None:
+                self.memo.seen = (S, C, base_ts, interval_ms, kind)
+            Tp = key[1]
             with jax.enable_x64(False):
                 if narrow is not None:
                     slots = narrow[1]
@@ -753,15 +904,10 @@ class MeshQueryExecutor:
             res = LazyMeshResult(out, op, num_groups, T)
             return res.resolve() if fetch else res
         slot_tvn = tuple(self.dstore.arrays())
-        # bucket the step count (pad to a multiple of 32, repeating the last
-        # step): dist_aggregate jit-compiles per output shape and ad-hoc
-        # dashboards would otherwise recompile per query — the same compile-
-        # space bucketing as the in-process path
-        from ..query.exec import _pad_steps
-        out_eval, T = _pad_steps(np.asarray(out_ts, np.int64))
-        out = dist_aggregate(slot_tvn, slot_gids, jnp.asarray(out_eval),
-                             jnp.int64(window_ms), jnp.float64(args[0]),
-                             jnp.float64(args[1]), fn, op, G, self.dstore.mesh)
+        out_eval, T = _steps(out_ts)
+        out = dist_aggregate(slot_tvn, slot_gids, out_eval,
+                             *_host_args(window_ms, args),
+                             fn, op, G, self.dstore.mesh)
         self.last_path = "twostep"
         res = LazyMeshResult(out, op, num_groups, T)
         return res.resolve() if fetch else res
@@ -774,14 +920,12 @@ class MeshQueryExecutor:
         the in-process SketchPartial merge)."""
         slot_tvn = tuple(self.dstore.arrays())
         slot_gids = tuple(self.dstore.global_gids(group_ids_per_shard))
-        from ..query.exec import _pad_steps
-        out_eval, T = _pad_steps(np.asarray(out_ts, np.int64))
+        out_eval, T = _steps(out_ts)
         # pow2-bucket the group count: a churning by() cardinality must not
         # compile a fresh program per distinct G (same rule as aggregate())
         Gp = _pow2(num_groups)
-        out = dist_quantile_sketch(slot_tvn, slot_gids, jnp.asarray(out_eval),
-                                   jnp.int64(window_ms), jnp.float64(args[0]),
-                                   jnp.float64(args[1]), fn, Gp,
+        out = dist_quantile_sketch(slot_tvn, slot_gids, out_eval,
+                                   *_host_args(window_ms, args), fn, Gp,
                                    self.dstore.mesh)
         self.last_path = "sketch"
 
@@ -801,13 +945,11 @@ class MeshQueryExecutor:
         the caller maps (shard, row) back to series keys."""
         slot_tvn = tuple(self.dstore.arrays())
         slot_gids = tuple(self.dstore.global_gids(group_ids_per_shard))
-        from ..query.exec import _pad_steps
-        out_eval, T = _pad_steps(np.asarray(out_ts, np.int64))
+        out_eval, T = _steps(out_ts)
         Gp = _pow2(num_groups)    # compile-space bucketing, as aggregate()
-        outs = dist_topk(slot_tvn, slot_gids, jnp.asarray(out_eval),
-                         jnp.int64(window_ms), jnp.float64(args[0]),
-                         jnp.float64(args[1]), fn, int(k), bool(bottom),
-                         Gp, self.dstore.mesh, self.dstore.ndev)
+        outs = dist_topk(slot_tvn, slot_gids, out_eval,
+                         *_host_args(window_ms, args), fn, int(k),
+                         bool(bottom), Gp, self.dstore.mesh, self.dstore.ndev)
         self.last_path = "topk"
 
         class LazyTopK:
@@ -832,13 +974,14 @@ def warm_mesh_shape(fn: str, op: str, S: int, C: int, steps: int,
     (``query.warmup_shapes`` entries with ``mesh: true`` — plancache.warmup
     calls this). Warms the general two-step program always and the fused
     program (the ACTIVE ``query.fused_kernels`` variant) when the shape
-    qualifies, so the warmed executable is the serving executable.
+    qualifies, with operands in the serving leaf's own form (a device's
+    ``[S, ...]`` block of a ``[NDEV * S, ...]`` global, the window plan
+    replicated on the mesh, host step grid and scalars), so the warmed
+    executable is the serving executable.
     ``residency`` names a decode variant
     (ops/decodereg.py) to warm the narrow-streaming program for in addition
     to the raw one — the first dashboard hit on a compressed-resident fleet
     then compiles nothing."""
-    from ..ops import fusedresident
-    from ..query.exec import _pad_steps
     mesh = make_mesh()
     ndev = mesh.devices.size
     if ndev < 2:
@@ -846,55 +989,43 @@ def warm_mesh_shape(fn: str, op: str, S: int, C: int, steps: int,
     sharding = NamedSharding(mesh, P("shard"))
     devs = list(mesh.devices.ravel())
 
-    def gput(extra_shape, dt):
-        arrs = [jax.device_put(jnp.zeros((1,) + extra_shape, dt), d)
-                for d in devs]
+    def gput(block_shape, dt):
+        arrs = [jax.device_put(jnp.zeros(block_shape, dt), d) for d in devs]
         return jax.make_array_from_single_device_arrays(
-            (ndev,) + extra_shape, sharding, arrs)
+            (ndev * block_shape[0],) + block_shape[1:], sharding, arrs)
 
     out_ts = np.int64(window_ms) + np.arange(steps, dtype=np.int64) * step_ms
-    out_eval, _T = _pad_steps(out_ts)
+    out_eval, _T = _steps(out_ts)
     Gp = _pow2(groups)
     val = gput((S, C), dtype)
     n = gput((S,), jnp.int32)
     ts = gput((S, C), jnp.int64)
+    gids = gput((S,), jnp.int32)
 
-    def gids():
-        # gid globals are donated: build a fresh one per call
-        return gput((S,), jnp.int32)
-
-    dist_aggregate(((ts, val, n),), (gids(),), jnp.asarray(out_eval),
-                   jnp.int64(window_ms), jnp.float64(0.0), jnp.float64(0.0),
+    dist_aggregate(((ts, val, n),), (gids,), out_eval,
+                   *_host_args(window_ms, (0.0, 0.0)),
                    fn, op, Gp, mesh)
     variant = fusedresident.tag()
-    if (grid and variant != "off" and dtype == jnp.float32
-            and fn in fusedgrid.FUSED_FNS | fusedgrid.FUSED_WINDOW_FNS
-            and op in fusedgrid.FUSED_OPS
-            and fusedgrid.fusable(S, C, steps, Gp)):
-        Tp = (max(steps, 1) + 127) // 128 * 128
-        band, ohlo, lo, hi, rel, c0, Ck = fusedgrid._device_operands(
-            C, Tp, np.ascontiguousarray(out_ts).tobytes(), int(window_ms),
-            0, int(interval_ms),
-            "window" if fn in fusedgrid.FUSED_WINDOW_FNS else "rate")
+    if (grid and dtype == jnp.float32
+            and _takes_fused(fn, op, S, C, steps, Gp)):
+        key = _plan_key(C, out_ts, window_ms, 0, interval_ms, fn, "raw")
+        Tp = key[1]
+        band, ohlo, lo, hi, rel, c0, Ck = _mesh_operands(mesh, *key)
         with jax.enable_x64(False):
             dist_fused_aggregate(
-                (val,), (n,), (gids(),), band, ohlo, lo, hi, rel,
+                (val,), (n,), (gids,), band, ohlo, lo, hi, rel,
                 fn, op, Gp, mesh, int(window_ms), int(interval_ms),
                 S, C, Tp, c0, Ck, variant)
             if residency != "raw":
-                from ..ops import decodereg
                 var = decodereg.variant(residency)
-                bandn, ohlon, lon, hin, reln, c0n, Ckn = (
-                    fusedgrid._device_operands(
-                        C, Tp, np.ascontiguousarray(out_ts).tobytes(),
-                        int(window_ms), 0, int(interval_ms),
-                        "window" if fn in fusedgrid.FUSED_WINDOW_FNS
-                        else "rate", var.full_columns))
+                bandn, ohlon, lon, hin, reln, c0n, Ckn = _mesh_operands(
+                    mesh, *_plan_key(C, out_ts, window_ms, 0, interval_ms,
+                                     fn, residency))
                 blk = gput((S, C), var.block_dtype)
                 rows = tuple(gput((S,), jnp.float32)
                              for _ in range(var.row_operands))
                 dist_fused_aggregate_narrow(
-                    (blk,), (rows,), (n,), (gids(),),
+                    (blk,), (rows,), (n,), (gids,),
                     bandn, ohlon, lon, hin, reln,
                     fn, op, Gp, mesh, int(window_ms), int(interval_ms),
                     S, C, Tp, residency, c0n, Ckn, variant)

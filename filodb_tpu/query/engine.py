@@ -381,6 +381,9 @@ class QueryEngine:
         # jax.sharding.Mesh with one device per shard: aggregate queries
         # execute via shard_map + psum instead of the host scatter-gather
         self.mesh = mesh
+        # what a mesh leaf needs ready as it takes every shard's lock
+        self._mesh_memo = (distributed.MeshLeafMemo(mesh)
+                           if mesh is not None else None)
         self.cluster = cluster
         self.node = node
         self.endpoint_resolver = endpoint_resolver
@@ -1268,7 +1271,8 @@ class QueryEngine:
                     # be elided forms that derive on the same device
                     or list(st.n.devices())[0] != devs[i % ndev]):
                 return None
-        return MeshQueryExecutor(DistributedStore(self.mesh, shards))
+        return MeshQueryExecutor(DistributedStore(self.mesh, shards),
+                                 self._mesh_memo)
 
     def _try_mesh(self, plan: L.LogicalPlan,
                   ctx: QueryContext | None = None) -> QueryResult | None:
@@ -1314,14 +1318,31 @@ class QueryEngine:
         filters = list(raw.filters)
         from_ms = raw.range_selector.from_ms
         to_ms = raw.range_selector.to_ms
-        uniq: dict[RangeVectorKey, int] = {}
-        gids_list: list[np.ndarray] = []
-        # all shard locks held across eligibility, gid construction AND
-        # kernel dispatch: a concurrent ingest flush donates (invalidates)
-        # any shard's store buffers mid-stream otherwise (same rule as the
-        # in-process leaf) — and a flush's compress_commit landing between
-        # an unlocked eligibility check and dispatch would swap the raw
-        # blocks for compressed state mid-plan (the 500s VERDICT flagged)
+        memo = self._mesh_memo
+        # BEFORE the locks, what no sample decides. Staged rows land under
+        # their own shard's lock, the flush's wait for the device with them
+        # (the peek is unlocked, like ``flush()``'s own; each select below
+        # flushes again under all the locks, and finds nothing but a
+        # container that arrived in between: a query sees every row staged
+        # before it took the locks, as it did). The window plan of this step
+        # grid is built and placed on the mesh for the grid the last
+        # dispatch saw
+        for sh in shards:
+            if sh._staged:
+                sh.flush()
+        prepared = memo.prepare_plan(fn, op, out_ts, window)
+        # all shard locks held across eligibility, the selects, array
+        # capture AND the dispatch: a concurrent ingest flush donates
+        # (invalidates) any shard's store buffers mid-stream otherwise
+        # (same rule as the in-process leaf) — and a flush's
+        # compress_commit landing between an unlocked eligibility check and
+        # dispatch would swap the raw blocks for compressed state mid-plan
+        # (the 500s VERDICT flagged). Every lock is held for the whole of
+        # it, so what is done under them is kept to validation, handles and
+        # ONE pjit call: four memo-hit selects, the group-id rows from the
+        # engine's memo (built, uploaded and kept here only for a selector
+        # or grouping it has not seen in this index state), the globals'
+        # assembly (no program), the epochs' capture, the call
         waited, held = lock_wait_ns(), lock_hold_ns()
         with contextlib.ExitStack() as stack:
             # the mesh route's one leaf: every shard's lock, taken in order
@@ -1340,47 +1361,45 @@ class QueryEngine:
             ex = self._mesh_executor(shards)
             if ex is None:
                 return None      # residency/shape changed: host path
-            matched_total = 0    # committed to ctx.stats only when the mesh
-            for sh in shards:    # path actually serves (a later fallback to
-                # the host path must not double-count its own leaf counts)
+            picks = []
+            for sh in shards:
                 with span(SPAN_QUERY_SELECT, shard=sh.shard_num) as sel:
                     picked, sel["memo"] = sh.selection(
                         filters, from_ms, to_ms, GATHER_THRESHOLD)
-                    pids = picked.pids
-                    sel["series"] = len(pids)
-                    paging = sh.needs_paging(pids, from_ms)
+                    sel["series"] = len(picked.pids)
+                    paging = sh.needs_paging(picked.pids, from_ms)
                 if paging:
                     # cold data: host ODP path handles it
                     distributed.count_mesh_fallback("paging")
                     return None
-                matched_total += len(pids)
-                g = np.full(sh.store.S, _EXCLUDED_GID, np.int32)
-                if len(pids):
-                    if not plan.by and not plan.without:
-                        g[pids] = 0
-                        uniq.setdefault(RangeVectorKey(()), 0)
-                    else:
-                        # the shard's own groups from its label columns
-                        # (vid pools are per shard; once per index state:
-                        # the selection memo), then G keys — not the
-                        # series — mapped onto the shared numbering
-                        with span(SPAN_QUERY_GROUPIDS, keys=len(pids),
-                                  route="index") as tags:
-                            local, tags["memo"] = picked.grouping(
-                                plan.by, plan.without)
-                            shared = np.fromiter(
-                                (uniq.setdefault(gk, len(uniq))
-                                 for gk in local.keys),
-                                np.int32, count=len(local.keys))
-                            g[pids] = shared[local.gids]
-                            tags["groups"] = len(uniq)
-                        count_groupids("index")
-                gids_list.append(g)
-            if not uniq:
+                picks.append(picked)
+            # committed to ctx.stats only when the mesh path actually serves
+            # (a later fallback to the host path must not double-count its
+            # own leaf counts)
+            matched_total = sum(len(p.pids) for p in picks)
+            if not matched_total:
                 self._set_path(ctx, "mesh-empty")
                 return QueryResult(ResultMatrix(
                     out_ts, np.zeros((0, len(out_ts))), []))
-            G = len(uniq)
+            with span(SPAN_QUERY_GROUPIDS, keys=matched_total,
+                      route="index") as tags:
+                kept = memo.gids(picks, plan.by, plan.without)
+                if kept is not None:
+                    how = "memo"
+                    group_keys, gids_list = kept
+                else:
+                    group_keys, rows = self._mesh_group_rows(
+                        picks, plan.by, plan.without)
+                    # each row straight to its own shard's device, once
+                    gids_list = ex.dstore.place_gids(rows)
+                    how = ("built" if memo.keep_gids(
+                        picks, plan.by, plan.without, group_keys, gids_list)
+                        else "bypass")
+                tags["memo"] = leaf["gids"] = how
+                tags["groups"] = len(group_keys)
+            distributed.count_mesh_prepared("gids", how)
+            count_groupids("index")
+            G = len(group_keys)
             a0 = args[0] if len(args) > 0 else 0.0
             a1 = args[1] if len(args) > 1 else 0.0
             # any partition release invalidates (shard, row) -> key
@@ -1428,12 +1447,15 @@ class QueryEngine:
                                op == "bottomk", args=(a0, a1))
             else:
                 lazy = ex.aggregate(fn, op, out_ts, window, gids_list,
-                                    G, args=(a0, a1), fetch=False)
+                                    G, args=(a0, a1), fetch=False,
+                                    prepared=prepared)
             # the program that ran and, for a fused one, its column block:
-            # what ties a device event to this query
+            # what ties a device event to this query — and whether its
+            # window plan was ready as the locks were taken
             kern["kernel"] = f"pjit-{ex.last_path}"
             if ex.last_block is not None:
                 kern["c0"], kern["cols"] = ex.last_block
+                leaf["plan"] = ex.last_plan
             if ctx is not None:     # committed: the mesh path serves this
                 ctx.stats.add("series_matched", matched_total)
                 if ex.last_path.startswith("fused"):
@@ -1446,15 +1468,43 @@ class QueryEngine:
         distributed.count_mesh_served(ex.last_path)
         if op in ("topk", "bottomk"):
             m = self._present_mesh_topk(lazy, ticket, shards, epochs, out_ts,
-                                        list(uniq))
+                                        list(group_keys))
         else:
             with span(SPAN_QUERY_KERNEL, phase="fetch"):
                 vals = lazy.resolve()
                 ticket.fetched()
-            m = ResultMatrix(out_ts, vals, list(uniq))
+            m = ResultMatrix(out_ts, vals, list(group_keys))
         from .exec import check_sample_limit
         check_sample_limit(m.num_series, len(out_ts), self.config.sample_limit)
         return QueryResult(m)
+
+    @staticmethod
+    def _mesh_group_rows(picks, by, without):
+        """(group keys, one dense ``[S]`` int32 row a shard) of a mesh leaf:
+        the groups numbered over ALL shards in first-appearance order (shard
+        order), rows outside a shard's selection excluded. A function of the
+        shards' selections and the grouping alone — what
+        ``distributed.MeshLeafMemo`` keeps."""
+        uniq: dict[RangeVectorKey, int] = {}
+        rows = []
+        for picked in picks:
+            pids = picked.pids
+            g = np.full(picked.shard.store.S, _EXCLUDED_GID, np.int32)
+            if len(pids):
+                if not by and not without:
+                    g[pids] = uniq.setdefault(RangeVectorKey(()), 0)
+                else:
+                    # the shard's own groups from its label columns (vid
+                    # pools are per shard; once per index state: the
+                    # selection memo), then G keys — not the series —
+                    # mapped onto the shared numbering
+                    local, _how = picked.grouping(by, without)
+                    shared = np.fromiter(
+                        (uniq.setdefault(gk, len(uniq)) for gk in local.keys),
+                        np.int32, count=len(local.keys))
+                    g[pids] = shared[local.gids]
+            rows.append(g)
+        return tuple(uniq), rows
 
     def _present_mesh_topk(self, lazy, ticket, shards, epochs, out_ts,
                            group_keys) -> ResultMatrix:

@@ -67,6 +67,7 @@ FILODB_QUERY_FUSED_FALLBACK = "filodb_query_fused_fallback"
 FILODB_QUERY_FUSED_FALL_TILES = "filodb_query_fused_fall_tiles"
 FILODB_QUERY_MESH_SERVED = "filodb_query_mesh_served"
 FILODB_QUERY_MESH_FALLBACK = "filodb_query_mesh_fallback"
+FILODB_QUERY_MESH_PREPARED = "filodb_query_mesh_prepared"
 FILODB_QUERY_NEGATIVE_CACHE_HITS = "filodb_query_negative_cache_hits"
 FILODB_QUERY_NEGATIVE_CACHE_EVICTIONS = \
     "filodb_query_negative_cache_evictions"
@@ -278,6 +279,17 @@ METRICS_SPEC: dict[str, tuple[str, str]] = {
         "counter", "Mesh-eligible queries that fell back to the host "
                    "scatter-gather path after eligibility, tagged by reason "
                    "(paging / order_stat_caps / topk_caps)."),
+    FILODB_QUERY_MESH_PREPARED: (
+        "counter", "What a mesh leaf found ready as it took every shard's "
+                   "lock, by part and outcome: plan (a fused program's "
+                   "window operands) = ready (built before the locks for "
+                   "the grid the last dispatch saw) or built (under them: "
+                   "the first fused query, a grid or decode variant that "
+                   "changed); gids (the shards' group-id rows on their "
+                   "devices) = memo, built (a new selector or grouping, an "
+                   "index that changed) or bypass (a selection the shards "
+                   "do not keep). ready + memo: the locks were held for a "
+                   "dispatch alone."),
     FILODB_QUERY_NEGATIVE_CACHE_HITS: (
         "counter", "Range queries answered from the TTL-bounded negative "
                    "result cache: a recent execution proved the selection "

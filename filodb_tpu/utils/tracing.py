@@ -138,7 +138,11 @@ TRACE_SPEC: dict[str, str] = {
                      "waited for shard locks inside it, lock_hold_ms = what "
                      "it held them for, counted as each is released; on the "
                      "mesh route that is the sum over the locks it took and "
-                     "locks = how many).",
+                     "locks = how many; there also gids = memo | built | "
+                     "bypass: whether the shards' group-id rows came from "
+                     "the engine's memo, and on a fused program plan = "
+                     "ready | built: whether its window plan was placed "
+                     "before the locks were taken).",
     SPAN_QUERY_SELECT: "Index select + array capture of one leaf; per shard "
                        "on the mesh route (tags: shard, series, memo = hit "
                        "| miss | bypass of the shard's selection memo, "
@@ -181,7 +185,11 @@ TRACE_SPEC: dict[str, str] = {
                          "materialized key (route=walk); a global "
                          "aggregate opens none (tags: keys, groups, "
                          "route; on route=index memo = hit | miss | bypass: "
-                         "whether the selection memo had the grouping).",
+                         "whether the selection memo had the grouping; the "
+                         "mesh route opens ONE for every leaf, global "
+                         "aggregates too — its shards' dense rows under the "
+                         "shared numbering — with memo = memo | built | "
+                         "bypass of the engine's row memo).",
     SPAN_QUERY_KERNEL: "Host side of one fused kernel: phase=dispatch is "
                        "the call under the shard lock, phase=fetch the "
                        "blocking fetch of its result outside it (dispatch "

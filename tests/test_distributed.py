@@ -4,6 +4,7 @@ one process)."""
 
 import jax
 import numpy as np
+import pytest
 
 from filodb_tpu.core.memstore import StoreConfig, TimeSeriesMemStore
 from filodb_tpu.core.record import RecordBuilder
@@ -225,7 +226,7 @@ def test_store_blocks_stay_on_their_devices():
         assert list(s.store.ts.devices())[0] == devs[i]
     dstore = DistributedStore(mesh, shards)
     ((ts_g, val_g, n_g),) = dstore.arrays()
-    assert ts_g.shape == (8, 16, 64)
+    assert ts_g.shape == (8 * 16, 64)
     assert len(ts_g.sharding.device_set) == 8
 
 
@@ -363,3 +364,281 @@ def test_host_composed_reduce_bit_stable_across_step_buckets():
     for steps in _SWEEP_STEPS[:-1]:
         for k, v in got[steps].items():
             np.testing.assert_array_equal(v, got[100][k][:steps])
+
+
+# -- PR 46: one dispatch under the shard locks --------------------------------
+#
+# A slot's global is [NDEV * S, ...] over the shards' own resident arrays (no
+# reshape, no program); the group-id rows come from the engine's
+# MeshLeafMemo, resident on their own devices; the window plan is built
+# before the locks for the grid the last dispatch saw. Under the locks: four
+# selects, handles, ONE pjit call.
+
+KEEP_OVER = 2          # selections wider than this are kept (shards hold 6)
+
+
+def _served_mesh(monkeypatch, narrow=False, keep=True):
+    """Four shards on four devices, six integer counters each, behind a
+    mesh engine and the host scatter-gather oracle over the SAME stores."""
+    from filodb_tpu.query import exec as qexec
+    from filodb_tpu.query.engine import QueryEngine
+    if keep:
+        monkeypatch.setattr(qexec, "GATHER_THRESHOLD", KEEP_OVER)
+    mesh = make_mesh(jax.devices()[:4])
+    ms = TimeSeriesMemStore()
+    cfg = StoreConfig(max_series_per_shard=16, samples_per_series=64,
+                      flush_batch_size=10**9, dtype="float32",
+                      narrow_resident=narrow)
+    shards = [ms.setup("prometheus", GAUGE, i, cfg, device=dev)
+              for i, dev in enumerate(mesh.devices.ravel())]
+    rng = np.random.default_rng(7)
+    for i in range(24):
+        b = RecordBuilder(GAUGE)
+        vals = np.cumsum(rng.integers(1, 50, N)).astype(np.float64)
+        for t in range(N):
+            b.add({"_metric_": "m", "host": f"h{i}", "grp": f"g{i % 4}"},
+                  START + t * INTERVAL, float(vals[t]))
+        ms.ingest("prometheus", i % 4, b.build())
+    ms.flush_all()
+    eng = QueryEngine(ms, "prometheus", mesh=mesh)
+    host = QueryEngine(ms, "prometheus")
+    for e in (eng, host):
+        e.result_cache = e.fragment_cache = None
+    return eng, host, shards
+
+
+def _series(result):
+    return {k: (t.tolist(), np.asarray(v))
+            for k, t, v in result.matrix.iter_series()}
+
+
+def _same_bits(got, want, what=""):
+    got, want = _series(got), _series(want)
+    assert set(got) == set(want), what
+    for k in want:
+        assert got[k][0] == want[k][0], (what, k)
+        np.testing.assert_array_equal(got[k][1], want[k][1], err_msg=what)
+
+
+def _ask(eng, q, rng):
+    """(result, the spans of this one query) — the tracer's ring is drained
+    on both sides, so an oracle's leaves never mix in."""
+    from filodb_tpu.utils.tracing import tracer
+    tracer.drain()
+    res = eng.query_range(q, *rng)
+    return res, tracer.drain()
+
+
+def _leaf(spans):
+    leaf, = [s for s in spans if s.name == "query.exec.leaf"]
+    return leaf
+
+
+def _prepared_counts():
+    from filodb_tpu.utils.metrics import FILODB_QUERY_MESH_PREPARED, registry
+    return {(part, how): registry.counter(
+        FILODB_QUERY_MESH_PREPARED, {"part": part, "outcome": how}).value
+        for part, how in (("plan", "ready"), ("plan", "built"),
+                          ("gids", "memo"), ("gids", "built"),
+                          ("gids", "bypass"))}
+
+
+def _moved(before):
+    return {k: v - before[k] for k, v in _prepared_counts().items()
+            if v != before[k]}
+
+
+# the five program forms, each by a text whose host path makes the same
+# per-shard partials (the rate family's host leaf takes the grid kernels
+# where the general mesh programs take the general ones: close, not equal)
+FORMS = {
+    "twostep": ("max by (grp) (sum_over_time(m[2m]))", "twostep", False),
+    "sketch": ("quantile(0.9, rate(m[2m])) by (grp)", "sketch", False),
+    "topk": ("topk(2, sum_over_time(m[2m])) by (grp)", "topk", False),
+    "fused": ("sum by (grp) (rate(m[2m]))", "fused", False),
+    "fused-narrow": ("sum by (grp) (rate(m[2m]))", "fused-narrow", True),
+}
+RANGE = (START + 300_000, START + 500_000, 20_000)
+
+
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_every_program_form_answers_the_host_paths_bits(form, monkeypatch):
+    """The global layout is the shards' own blocks side by side: every
+    ``per_device`` body reads its block whole, and the answer is the host
+    scatter-gather's bit for bit — grouped, so a row read from a
+    neighbour's block would land in the wrong group."""
+    q, route, narrow = FORMS[form]
+    eng, host, _shards = _served_mesh(monkeypatch, narrow)
+    got = eng.query_range(q, *RANGE)
+    assert got.exec_path == f"mesh[pjit]-{route}", got.exec_path
+    want = host.query_range(q, *RANGE)
+    assert not want.exec_path.startswith("mesh"), want.exec_path
+    _same_bits(got, want, q)
+    # ... and again from the memo's rows, at another step grid
+    shifted = (RANGE[0] + 7_000, RANGE[1] + 7_000, RANGE[2])
+    _same_bits(eng.query_range(q, *shifted), host.query_range(q, *shifted), q)
+
+
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_the_locks_are_held_for_one_dispatch_alone(form, monkeypatch):
+    """The second query of a selector and grouping, on a step grid nobody
+    asked before: while a shard lock is owned nothing goes through
+    ``jax.device_put``, ``jnp.asarray`` or an eager ``reshape`` — the rows
+    come from the memo, the plan was placed before the locks, the globals
+    are assembled from handles, and the program's host values ride its one
+    call. The first query, which builds the rows, is what the watch sees."""
+    import jax.numpy as jnp
+    q, route, narrow = FORMS[form]
+    eng, host, shards = _served_mesh(monkeypatch, narrow)
+    trips = []
+
+    def watch(owner, name):
+        real = getattr(owner, name)
+
+        def spy(*a, **kw):
+            if any(sh.lock._is_owned() for sh in shards):
+                trips.append(name)
+            return real(*a, **kw)
+        monkeypatch.setattr(owner, name, spy)
+
+    watch(jax, "device_put")
+    watch(jnp, "asarray")
+    watch(type(shards[0].store.n), "reshape")
+    eng.query_range(q, *RANGE)
+    assert "device_put" in trips, "the watch sees the rows' upload"
+    del trips[:]
+    before = _prepared_counts()
+    shifted = (RANGE[0] + 7_000, RANGE[1] + 7_000, RANGE[2])
+    got, spans = _ask(eng, q, shifted)
+    assert got.exec_path == f"mesh[pjit]-{route}"
+    assert trips == []
+    assert [s.tags["phase"] for s in spans
+            if s.name == "query.exec.kernel"] == ["dispatch", "fetch"]
+    leaf = _leaf(spans)
+    assert leaf.tags["gids"] == "memo"
+    if route.startswith("fused"):
+        assert leaf.tags["plan"] == "ready"
+        assert _moved(before) == {("plan", "ready"): 1, ("gids", "memo"): 1}
+    else:
+        assert "plan" not in leaf.tags
+        assert _moved(before) == {("gids", "memo"): 1}
+    monkeypatch.undo()
+    _same_bits(got, host.query_range(q, *shifted), q)
+
+
+def _new_series(shards):
+    b = RecordBuilder(GAUGE)
+    for t in range(N):
+        b.add({"_metric_": "m", "host": "late", "grp": "g9"},
+              START + t * INTERVAL, float(3 * t))
+    shards[1].ingest(b.build())
+
+
+def _off_grid_stamp(shards):
+    b = RecordBuilder(GAUGE)
+    b.add({"_metric_": "m", "host": "h2", "grp": "g2"},
+          START + N * INTERVAL + 3_333, 1e6)
+    shards[2].ingest(b.build())
+
+
+def _stale_grid(eng):
+    S, C, base, iv, kind = eng._mesh_memo.seen
+    eng._mesh_memo.seen = (S, C, base - iv, iv, kind)
+
+
+@pytest.mark.parametrize("change,route,tags,moved", [
+    # the index moved: new selections, so the rows are built (and kept)
+    ("series", "fused", {"plan": "ready", "gids": "built"},
+     {("plan", "ready"): 1, ("gids", "built"): 1}),
+    # a shard left the grid: the general program, no plan at all
+    ("stamp", "twostep", {"gids": "memo"}, {("gids", "memo"): 1}),
+    # the plan was made for a grid the stores are not on
+    ("grid", "fused", {"plan": "built", "gids": "memo"},
+     {("plan", "built"): 1, ("gids", "memo"): 1}),
+])
+def test_a_change_between_preparation_and_the_locks(change, route, tags,
+                                                    moved, monkeypatch):
+    """What was prepared is checked under the locks against what the leaf
+    finds there: a series or an off-grid stamp that lands after the plan was
+    prepared, or a plan prepared for another grid, gives the host path's
+    answer, and the leaf's tags and ``/metrics`` say what was built."""
+    q = "sum by (grp) (rate(m[2m]))"
+    eng, host, shards = _served_mesh(monkeypatch)
+    _same_bits(eng.query_range(q, *RANGE), host.query_range(q, *RANGE))
+    memo = eng._mesh_memo
+    real = memo.prepare_plan
+
+    def prepare_then_change(*a):
+        if change == "grid":
+            _stale_grid(eng)
+        key = real(*a)
+        assert key is not None
+        if change == "series":
+            _new_series(shards)
+        elif change == "stamp":
+            _off_grid_stamp(shards)
+        return key
+
+    monkeypatch.setattr(memo, "prepare_plan", prepare_then_change)
+    before = _prepared_counts()
+    later = (RANGE[0] + 120_000, RANGE[1] + 120_000, RANGE[2])
+    got, spans = _ask(eng, q, later)
+    assert got.exec_path == f"mesh[pjit]-{route}", got.exec_path
+    leaf = _leaf(spans)
+    assert {k: leaf.tags[k] for k in ("plan", "gids") if k in leaf.tags} \
+        == tags
+    assert _moved(before) == moved
+    want = host.query_range(q, *later)
+    if route == "fused":
+        _same_bits(got, want)
+        assert len(_series(got)) == (5 if change == "series" else 4)
+    else:
+        # the host's leaf over an off-grid shard and the general mesh
+        # program are different lowerings of one sum: the tier-1 bound
+        got, want = _series(got), _series(want)
+        assert set(got) == set(want)
+        for k in want:
+            np.testing.assert_allclose(got[k][1], want[k][1], rtol=2e-4,
+                                       atol=1e-4)
+
+
+def test_the_row_memo_is_bounded_and_keeps_no_bypass():
+    from types import SimpleNamespace
+
+    from filodb_tpu.parallel.distributed import MeshLeafMemo
+    memo = MeshLeafMemo(make_mesh(jax.devices()[:2]))
+    kept = [SimpleNamespace(stamp=(1, 0, 16)) for _ in range(2)]
+    for i in range(MeshLeafMemo.GIDS + 3):
+        assert memo.keep_gids(kept, (f"l{i}",), (), (f"k{i}",), (i, i))
+        assert len(memo) == min(i + 1, MeshLeafMemo.GIDS)
+    assert memo.gids(kept, ("l0",), ()) is None          # the oldest went
+    newest = f"l{MeshLeafMemo.GIDS + 2}"
+    assert memo.gids(kept, (newest,), ()) == ((f"k{newest[1:]}",),
+                                              (int(newest[1:]),) * 2)
+    # a hit is the most recent: it outlives GIDS - 1 newcomers
+    assert memo.gids(kept, ("l3",), ()) is not None
+    for i in range(MeshLeafMemo.GIDS - 1):
+        memo.keep_gids(kept, (), (f"w{i}",), ("k",), (0, 0))
+    assert memo.gids(kept, ("l3",), ()) is not None
+    # other selections of the same selector (a later index state) miss
+    assert memo.gids([SimpleNamespace(stamp=(2, 0, 16)) for _ in range(2)],
+                     ("l3",), ()) is None
+    # one selection the shard does not keep: never a key, never kept
+    mixed = [kept[0], SimpleNamespace(stamp=None)]
+    size = len(memo)
+    assert not memo.keep_gids(mixed, ("l3",), (), ("k",), (0, 0))
+    assert memo.gids(mixed, ("l3",), ()) is None and len(memo) == size
+
+
+def test_a_narrow_selection_builds_its_rows_every_query(monkeypatch):
+    """Selections the shards do not keep (here: six series a shard under the
+    shipped GATHER_THRESHOLD) are ``bypass``: built and uploaded a query, as
+    before, and the memo stays empty."""
+    eng, host, _shards = _served_mesh(monkeypatch, keep=False)
+    q = "sum by (grp) (rate(m[2m]))"
+    for shift in (0, 20_000):
+        r = (RANGE[0] + shift, RANGE[1] + shift, RANGE[2])
+        got, spans = _ask(eng, q, r)
+        _same_bits(got, host.query_range(q, *r))
+        assert _leaf(spans).tags["gids"] == "bypass"
+    assert len(eng._mesh_memo) == 0

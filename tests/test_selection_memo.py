@@ -481,15 +481,24 @@ def test_spans_are_recorded_on_a_hit(route, monkeypatch):
     members = _spans_of_last()
     sel = [s for s in members if s.name == SPAN_QUERY_SELECT]
     gid = [s for s in members if s.name == SPAN_QUERY_GROUPIDS]
-    assert len(sel) == len(gid) == shards
+    assert len(sel) == shards
     for s in sel:
         assert s.tags["memo"] == "hit" and s.tags["series"] > KEEP_OVER
-    for s in gid:
-        assert s.tags["memo"] == "hit" and s.tags["route"] == "index"
-        assert s.tags["keys"] > KEEP_OVER
+    if route == "mesh":
+        # one span for the leaf: the shards' rows under the shared numbering
+        # come from the engine's memo (parallel/distributed.MeshLeafMemo),
+        # which sits above the shards' groupings and does not ask them
+        (g,), hits = gid, {("select", "hit"): shards}
+        assert g.tags["memo"] == "memo" and g.tags["route"] == "index"
+        assert g.tags["keys"] > shards * KEEP_OVER
+    else:
+        assert len(gid) == shards
+        for s in gid:
+            assert s.tags["memo"] == "hit" and s.tags["route"] == "index"
+            assert s.tags["keys"] > KEEP_OVER
+        hits = {("select", "hit"): shards, ("groupids", "hit"): shards}
     assert gid[-1].tags["groups"] == groups
-    assert delta(before) == {("select", "hit"): shards,
-                             ("groupids", "hit"): shards}
+    assert delta(before) == hits
 
 
 def test_metrics_page_shows_the_memo_counter(monkeypatch):

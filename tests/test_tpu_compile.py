@@ -409,37 +409,75 @@ def test_dense_flush_of_a_histogram_block_runs_in_place(one_chip):
     assert mem.temp_size_in_bytes < (64 << 20), mem
 
 
-def test_mesh_fused_program_compiles_for_four_chips(topo):
-    """One pjit ``dist_fused`` program on the described 2x2: four shards of
-    2^18 x 768 f32, one per device, explicit NamedShardings both ways, the
-    Mosaic kernel inside shard_map."""
+def _mesh_fused_compiled(topo, residency):
+    """One pjit ``dist_fused`` / ``dist_fused_narrow`` program lowered and
+    compiled for the described 2x2 from operands in the serving leaf's own
+    form (``DistributedStore``): four shards of 2^18 x 768, a device's block
+    of each ``[NDEV * S, ...]`` global its shard's resident array, the
+    window plan replicated, explicit NamedShardings both ways, the Mosaic
+    kernel inside shard_map."""
     per, C, Tp, G = 1 << 18, 768, 128, 8
     mesh = Mesh(np.asarray(topo.devices), ("shard",))
     nd = mesh.devices.size
     assert nd == 4
-    impl = distributed._dist_fused_aggregate_impl
-    fn = lambda *a: impl("rate", "sum", G, mesh, WINDOW, IV, per, C, Tp,  # noqa: E731
-                         0, 0, "pallas", *a)
-    wrap = distributed._sharded_jit(mesh, distributed._FUSED_IN_SPECS,
-                                    P("shard"))
     sh = NamedSharding(mesh, P("shard"))
     rep = NamedSharding(mesh, P())
     sds = jax.ShapeDtypeStruct
-    args = ((sds((nd, per, C), f32, sharding=sh),),
-            (sds((nd, per), i32, sharding=sh),),
-            (sds((nd, per), i32, sharding=sh),),
-            sds((C, Tp), bf16, sharding=rep), sds((C, Tp), bf16, sharding=rep),
+    rows = lambda dt: sds((nd * per,), dt, sharding=sh)  # noqa: E731
+    plan = (sds((C, Tp), bf16, sharding=rep), sds((C, Tp), bf16, sharding=rep),
             sds((1, Tp), i32, sharding=rep), sds((1, Tp), i32, sharding=rep),
             sds((1, Tp), i32, sharding=rep))
+    if residency == "raw":
+        impl = distributed._dist_fused_aggregate_impl
+        fn = lambda *a: impl("rate", "sum", G, mesh, WINDOW, IV, per, C, Tp,  # noqa: E731
+                             0, 0, "pallas", *a)
+        specs = distributed._FUSED_IN_SPECS
+        args = ((sds((nd * per, C), f32, sharding=sh),),
+                (rows(i32),), (rows(i32),)) + plan
+    else:
+        var = decodereg.variant(residency)
+        impl = distributed._dist_fused_narrow_impl
+        fn = lambda *a: impl("rate", "sum", G, mesh, WINDOW, IV, per, C, Tp,  # noqa: E731
+                             residency, 0, 0, "pallas", *a)
+        specs = distributed._FUSED_NARROW_IN_SPECS
+        args = ((sds((nd * per, C), var.block_dtype, sharding=sh),),
+                (tuple(rows(f32) for _ in range(var.row_operands)),),
+                (rows(i32),), (rows(i32),)) + plan
+    wrap = distributed._sharded_jit(mesh, specs, P("shard"))
     with jax.enable_x64(False):
         compiled = wrap(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    return compiled, per, C
+
+
+def test_mesh_fused_program_compiles_for_four_chips(topo):
+    compiled, per, C = _mesh_fused_compiled(topo, "raw")
     # each device holds its own shard and nothing of the others'
     assert compiled.memory_analysis().argument_size_in_bytes \
         < 1.05 * per * C * 4 + (8 << 20)
     # ... and turns no per-row operand of it to a column (256 MB of
     # temporaries a device, two copies a slot a query, before)
     _no_column_and_no_temp(compiled, per)
+
+
+@pytest.mark.parametrize("residency", ["raw", "delta8"])
+def test_mesh_fused_program_copies_no_block(topo, residency):
+    """A device's block of the global IS the shard's resident array, so the
+    program reads it in place (PR 46): no ``copy`` instruction at all — the
+    ``copy f32[1,262144,768]`` of the chip's traces, 2.4 ms and 805 MB a
+    query a chip, was the eager ``reshape((1, S, C))`` that stood in front
+    of the ``[NDEV, S, C]`` assembly, a program of its own — and no
+    temporary the size of a value block, nor of a hundredth of one."""
+    compiled, per, C = _mesh_fused_compiled(topo, residency)
+    text = compiled.as_text()
+    copies = [ln.strip() for ln in text.splitlines()
+              if re.search(r"= \S+ copy\(", ln)]
+    assert not copies, copies[:3]
+    assert not re.search(r"\[1,%d,%d\]" % (per, C), text)
+    block = per * C * np.dtype(decodereg.variant(residency).block_dtype).itemsize
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < block // 100, mem
+    assert mem.alias_size_in_bytes == 0, mem      # nothing donated
 
 
 def test_a_narrow_gather_of_a_grid_store_takes_no_part_of_the_stamp_block(
