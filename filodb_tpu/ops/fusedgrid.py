@@ -78,11 +78,11 @@ def _roundup(x: int, m: int) -> int:
 # read the first four only. A slot is a block of Tp lanes, ``ohe [Ca, 6
 # Tp]`` — or, where the steps fit half a block (:func:`slots_per_block`),
 # half of one: slots 2p and 2p + 1 share block p of ``ohe [Ca, 3 x 128]``,
-# step t on lane t and on lane 64 + t, and the window functions' CLOSED band
-# rides as slot 4 beside their four (raw_hist_weights packs its halves so).
+# step t on lane t and on lane 64 + t (raw_hist_weights packs its halves
+# so). ``ohe`` is int8 and holds PICKS only — every column one-hot or all
+# zeros (:func:`pick_exact`); a band adds cells and rides in ``band``.
 EDGE_SLOTS = 6
 EDGE_SLOTS_WINDOW = 4
-BAND_SLOT = 4           # the packed window form's band
 _NEVER = 1 << 30        # an edge bound no start + residual reaches
 
 
@@ -149,6 +149,34 @@ def dot_exact01(x, w, left: bool = False):
                        precision=jax.lax.Precision.DEFAULT,
                        preferred_element_type=f32)
     return dot(hi) + dot(mid) + dot(lo)
+
+
+def _dot_i8(x, w):
+    """``x [M, K] int8 @ w [K, N] int8 -> int32``: ONE MXU pass at the int8
+    rate, exact (DEFAULT spelled out, as :func:`dot_exact01` does)."""
+    return jnp.dot(x, w, precision=jax.lax.Precision.DEFAULT,
+                   preferred_element_type=jnp.int32)
+
+
+def pick_exact(x, w):
+    """``x [M, K] f32`` PICKED by ``w [K, N]`` int8, every column one-hot or
+    all zeros: ``out[m, j] = x[m, k]`` where ``w[k, j]`` is the column's
+    one 1. A pick copies and never adds, so it needs no floating point: the
+    four BYTES of each f32 go through the MXU as int8, ``int8 x int8 ->
+    int32`` (four passes at twice the bf16 rate, where the three-piece
+    split of :func:`dot_exact01` runs three, and none of the split's vector
+    work over ``[M, K]``). The tile is read as its own bytes — ``[4 M, K]``
+    int8, row ``4 m + b`` byte ``b`` of row ``m``: a relabelling of the
+    registers it lies in, no operation — one product picks all four, and
+    the ``[4 M, N]`` int32 result narrowed to int8 IS the picked f32s,
+    relabelled back. Exact for EVERY bit pattern — denormals, -0.0,
+    infinities and NaN payloads, which the split loses. A byte travels as
+    the SIGNED int8 of its bits and the narrowing keeps the low eight, so
+    no offset has to be undone; a column of zeros (a padded step, a cell
+    below 0 or at / above ``C``, the unused half of a packed block) sums
+    nothing and reads +0.0 to the bit, as it did."""
+    picked = _dot_i8(pltpu.bitcast(x, jnp.int8), w)
+    return pltpu.bitcast(picked.astype(jnp.int8), jnp.float32)
 
 
 def lane_major(x, Sb: int):
@@ -278,12 +306,13 @@ def _line_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     (gridfns.grid_edges with the line's spread): the band matmul sums
     them, as on the grid. The two cells below ``lo`` and the two above
     ``hi`` are in the window for some rows and not for others: their
-    values and residuals are picked by one-hot products (``ohe``; the
-    residuals are small integers, exact in ONE bf16 pass) and each row
-    decides them by comparing ``start + residual`` with the step's bound
-    for that cell (``eb``, small integers: differences of stamps, never
-    stamps). Stamps rise along a row, so the cells a row holds stay one
-    contiguous run ``[f_idx, l_idx]``.
+    values and residuals are picked by one-hot products (``ohe``, int8:
+    the values byte by byte, :func:`pick_exact`; the residuals as the int8
+    they are, one product) and each row decides them by comparing ``start
+    + residual`` with the step's bound for that cell (``eb``, small
+    integers: differences of stamps, never stamps). Stamps rise along a
+    row, so the cells a row holds stay one contiguous run ``[f_idx,
+    l_idx]``.
 
     ``ohe`` says by its width how its slots lie (see ``EDGE_SLOTS``): a
     block each, or two a block. A packed slot's plane comes out with the
@@ -305,38 +334,36 @@ def _line_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     Tp = lo.shape[1]
     window = fn in FUSED_WINDOW_FNS
     per = EDGE_SLOTS * Tp // ohe.shape[1]         # edge slots a block
-    packed = per == 2
     lcol = jax.lax.broadcasted_iota(i32, (Sb, Ca), 1)
     col = lcol + c0
     valid = col < n
     v = jnp.where(valid, v, 0.0)
-    rf = jnp.where(valid, res.astype(i32).astype(f32), 0.0)
 
+    # the blocks that hold the function's slots: values and residuals are
+    # picked from the same
     slots = EDGE_SLOTS_WINDOW if window else EDGE_SLOTS
-    # the window fns' values go through every block they have: the picks
-    # and, packed, the band with them
-    w = ohe if packed else ohe[:, :slots * Tp]
-    rp = jnp.dot(rf.astype(jnp.bfloat16), w[:, :slots // per * Tp],
-                 precision=jax.lax.Precision.DEFAULT,
-                 preferred_element_type=f32)              # [Sb, slots/per Tp]
+    w = ohe[:, :slots // per * Tp]
+    # the residuals ARE int8: the tile goes to the MXU as the int8 it is,
+    # i32 [Sb, slots/per Tp]; a cell at or past the row's count reads 0
+    # (and every reader below asks has(), or hi <= n - 1, first)
+    rp = _dot_i8(jnp.where(valid, res, jnp.int8(0)), w)
 
     def pick(x, j):       # slot j's plane, step t on lane t
         blk = x[:, j // per * Tp:(j // per + 1) * Tp]
         return roll(blk, Tp // 2) if j % per else blk
 
-    a = start.astype(f32)                                     # [Sb, 1]
-    ebf = eb.astype(f32)
     # a2 < a1 < lo <= hi < b1 < b2; a cell the row does not have is out.
     # Each low cell is decided on its own: a row that ENDED in a2 (n == lo
     # - 1) has no a1 and may still hold a2 in the window. A row's cells
-    # are a prefix, so above the sure range b2 needs b1
+    # are a prefix, so above the sure range b2 needs b1. Stamps' differences
+    # in integers: a start is under 2^20, a bound at most _NEVER
     def has(cell):
         return (cell >= 0) & (cell < n)
 
-    m_a2 = has(lo - 2) & (a + pick(rp, 0) >= ebf[0:1])
-    m_a1 = has(lo - 1) & (a + pick(rp, 1) >= ebf[1:2])
-    m_b1 = has(hi + 1) & (a + pick(rp, 2) <= ebf[2:3])
-    m_b2 = m_b1 & (hi + 2 < n) & (a + pick(rp, 3) <= ebf[3:4])
+    m_a2 = has(lo - 2) & (start + pick(rp, 0) >= eb[0:1])
+    m_a1 = has(lo - 1) & (start + pick(rp, 1) >= eb[1:2])
+    m_b1 = has(hi + 1) & (start + pick(rp, 2) <= eb[2:3])
+    m_b2 = m_b1 & (hi + 2 < n) & (start + pick(rp, 3) <= eb[3:4])
 
     f_sure = jnp.maximum(lo, 0)                               # [1, Tp]
     l_sure = jnp.minimum(hi, n - 1)                           # [Sb, Tp]
@@ -349,8 +376,8 @@ def _line_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
         ok = cnt >= 1
         if fn == "count_over_time":
             return jnp.where(ok, cnt_f, 0.0), ok.astype(f32)
-        vp = dot_exact01(v, w)
-        s = pick(vp, BAND_SLOT) if packed else dot_exact01(v, band)
+        vp = pick_exact(v, w)
+        s = dot_exact01(v, band)          # a band ADDS cells: three pieces
         for j, m in enumerate((m_a2, m_a1, m_b1, m_b2)):
             s = s + jnp.where(m, pick(vp, j), 0.0)
         if fn == "avg_over_time":
@@ -362,7 +389,7 @@ def _line_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     def step(x):              # one increment, counter-corrected like inc
         return jnp.maximum(x, 0.0) if is_counter else x
 
-    vp = dot_exact01(v, w)
+    vp = pick_exact(v, w)         # a lane no column feeds: 0.0, as it was
     v_a2, v_a1, v_b1, v_b2, v_lo, v_hi = (pick(vp, j) for j in range(6))
     prev = roll(v, 1)
     # after the picks' matmul, so that the test runs while the MXU does. A
@@ -373,7 +400,7 @@ def _line_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     if form == "tel":
         # a padded step has hi = -1, and an empty range would pick garbage
         delta = jnp.where(hi > f_sure, v_hi - v_lo, 0.0)
-        r_end = 0.0           # no row of an unfallen tile ends under hi
+        r_end = 0             # no row of an unfallen tile ends under hi
     else:
         mask = valid & (col > 0)
         if c0:
@@ -382,8 +409,8 @@ def _line_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
         delta = dot_exact01(inc, band)                        # (lo, hi]
         # the residual of the row's own last cell, where it ends under the
         # window
-        r_end = jnp.sum(jnp.where(col == n - 1, rf, 0.0), axis=1,
-                        keepdims=True)
+        r_end = jnp.sum(jnp.where(col == n - 1, res.astype(i32), 0),
+                        axis=1, keepdims=True)
     delta = (delta
              + jnp.where(m_a1 & (lo < n), step(v_lo - v_a1), 0.0)
              + jnp.where(m_a2 & m_a1, step(v_a1 - v_a2), 0.0)
@@ -395,8 +422,8 @@ def _line_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     r_l = jnp.where(m_b2, pick(rp, 3), jnp.where(
         m_b1, pick(rp, 2), jnp.where(hi <= n - 1, pick(rp, 5), r_end)))
     # stamps relative to the base, in integers until they are differences
-    t_f = f_idx * interval_ms + start + r_f.astype(i32)
-    t_l = l_idx * interval_ms + start + r_l.astype(i32)
+    t_f = f_idx * interval_ms + start + r_f
+    t_l = l_idx * interval_ms + start + r_l
     dur_start = (t_f - (rel - window_ms)).astype(f32) / 1000.0
     dur_end = (rel - t_l).astype(f32) / 1000.0
     sampled = (t_l - t_f).astype(f32) / 1000.0
@@ -424,9 +451,11 @@ def _hole_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     first sample at or after ``lo`` and the last at or before ``hi`` are
     read from FILLED planes — a hole takes the next (the previous)
     sample's value, residual and distance, two shifts by one and two cells
-    reaching over a run of three — picked at ``lo`` and ``hi``. A product
-    still has a 0/1 operand: values in three bf16 passes, residuals,
-    distances and validity in one.
+    reaching over a run of three — picked at ``lo`` and ``hi``. Every pick
+    is an int8 product: values byte by byte (:func:`pick_exact`),
+    residuals and distances as the int8 they are; what ADDS cells stays
+    bf16 against a 0/1 band: the pairs' increments in three passes,
+    validity in one.
 
     The two ``form``s of :func:`_line_contrib`: "tel" takes the sure
     range's delta as its last sample's value less its first's (the two
@@ -450,39 +479,38 @@ def _hole_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     v = jnp.where(valid, v, 0.0)
     okf = valid.astype(f32).astype(bf16)
 
-    def dot1(x, w):           # one pass: small integers against 0/1
-        return jnp.dot(x, w, precision=jax.lax.Precision.DEFAULT,
+    def count(band):          # validity x band: a SUM, one bf16 pass
+        return jnp.dot(okf, band, precision=jax.lax.Precision.DEFAULT,
                        preferred_element_type=f32)
 
     def pick(x, j):           # slot j's plane, step t on lane t
         blk = x[:, j // per * Tp:(j // per + 1) * Tp]
         return roll(blk, Tp // 2) if j % per else blk
 
-    # the four edge cells' residuals, RES_HOLE where the cell has no sample
-    rh = jnp.where(valid, ri, RES_HOLE).astype(f32).astype(bf16)
-    rp = dot1(rh, ohe[:, :4 // per * Tp])
-    a = start.astype(f32)                                     # [Sb, 1]
-    ebf = eb.astype(f32)
+    # the four edge cells' residuals as the int8 they are: RES_HOLE where
+    # the cell has no sample, and for a cell at or past the row's count
+    # (which fails holds() by its count too); i32 [Sb, 4/per Tp]
+    w4 = ohe[:, :EDGE_SLOTS_WINDOW // per * Tp]
+    rp = _dot_i8(jnp.where(col < n, res, jnp.int8(RES_HOLE)), w4)
 
     def holds(cell, j):       # the cell exists, is the row's, has a sample
         return (cell >= 0) & (cell < n) & (pick(rp, j) != RES_HOLE)
 
-    m_a2 = holds(lo - 2, 0) & (a + pick(rp, 0) >= ebf[0:1])
-    m_a1 = holds(lo - 1, 1) & (a + pick(rp, 1) >= ebf[1:2])
-    m_b1 = holds(hi + 1, 2) & (a + pick(rp, 2) <= ebf[2:3])
-    m_b2 = holds(hi + 2, 3) & (a + pick(rp, 3) <= ebf[3:4])
+    m_a2 = holds(lo - 2, 0) & (start + pick(rp, 0) >= eb[0:1])
+    m_a1 = holds(lo - 1, 1) & (start + pick(rp, 1) >= eb[1:2])
+    m_b1 = holds(hi + 1, 2) & (start + pick(rp, 2) <= eb[2:3])
+    m_b2 = holds(hi + 2, 3) & (start + pick(rp, 3) <= eb[3:4])
     edges = (m_a2.astype(f32) + m_a1.astype(f32) + m_b1.astype(f32)
              + m_b2.astype(f32))
 
     if window:
         # the closed band counts the sure range's samples
-        cnt_f = dot1(okf, band) + edges
+        cnt_f = count(band) + edges
         ok = cnt_f >= 1.0
         if fn == "count_over_time":
             return jnp.where(ok, cnt_f, 0.0), ok.astype(f32)
-        w = ohe if packed else ohe[:, :EDGE_SLOTS_WINDOW * Tp]
-        vp = dot_exact01(v, w)
-        s = pick(vp, BAND_SLOT) if packed else dot_exact01(v, band)
+        vp = pick_exact(v, w4)
+        s = dot_exact01(v, band)          # a band ADDS cells: three pieces
         for j, m in enumerate((m_a2, m_a1, m_b1, m_b2)):
             s = s + jnp.where(m, pick(vp, j), 0.0)
         if fn == "avg_over_time":
@@ -491,7 +519,8 @@ def _hole_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
 
     # filled planes. ``meta``: a sample's residual + 128 (1..255), 0 for
     # none, and 256 a cell of distance once filled from a neighbour
-    step1, step2 = 256, 512
+    shift = 8
+    step1, step2 = 1 << shift, 2 << shift
     meta = jnp.where(valid, ri + 128, 0)
 
     def fill(m0, x0, back: bool):
@@ -524,35 +553,35 @@ def _hole_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     def step(x):              # one increment, counter-corrected like inc
         return jnp.maximum(x, 0.0) if is_counter else x
 
-    mid_cnt = dot1(okf, band)                                 # [lo, hi - 1]
+    mid_cnt = count(band)                                     # [lo, hi - 1]
 
     def parts(m):     # (distance in cells, residual: RES_HOLE for none)
-        m = m.astype(f32)
-        k = jnp.floor(m * (1.0 / step1))
-        return k.astype(bf16), (m - k * step1 - 128.0).astype(bf16)
+        return ((m >> shift).astype(jnp.int8),
+                ((m & (step1 - 1)) - 128).astype(jnp.int8))
 
     if packed:        # lo and hi share block 2: hi's half comes down
         blk_lo = blk_hi = ohe[:, 2 * Tp:3 * Tp]
-        vbp = dot_exact01(vb, ohe)
+        vbp = pick_exact(vb, ohe)
 
         def down(x):
             return roll(x, Tp // 2)
     else:
         blk_lo, blk_hi = ohe[:, 4 * Tp:5 * Tp], ohe[:, 5 * Tp:]
-        vbp = dot_exact01(vb, ohe[:, :5 * Tp])
+        vbp = pick_exact(vb, ohe[:, :5 * Tp])
 
         def down(x):
             return x
+    # distances (0..3) and residuals of the filled planes at lo / hi: i32
     (kb, rb), (kf, rf) = parts(mb), parts(mf)
-    kl, rl = dot1(kb, blk_lo), dot1(rb, blk_lo)
-    kh, rhi = down(dot1(kf, blk_hi)), down(dot1(rf, blk_hi))
-    v_hi = down(dot_exact01(vf, blk_hi))
+    kl, rl = _dot_i8(kb, blk_lo), _dot_i8(rb, blk_lo)
+    kh, rhi = down(_dot_i8(kf, blk_hi)), down(_dot_i8(rf, blk_hi))
+    v_hi = down(pick_exact(vf, blk_hi))
     v_a2, v_a1, v_b1, v_b2, v_lo = (pick(vbp, j) for j in range(5))
 
     f_sure = jnp.maximum(lo, 0)                               # [1, Tp]
     some = hi >= f_sure                 # the sure range holds a cell
     # hi itself holds a sample: the band [lo, hi - 1] leaves it out
-    at_hi = some & (hi < n) & (rhi != RES_HOLE) & (kh == 0.0)
+    at_hi = some & (hi < n) & (rhi != RES_HOLE) & (kh == 0)
     cnt_f = mid_cnt + at_hi.astype(f32) + edges
     mid = mid_cnt + at_hi.astype(f32) >= 1.0
     cnt = cnt_f.astype(i32)
@@ -579,12 +608,12 @@ def _hole_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
         g = jnp.where(valid & (nxt_m > 0), step(nxt_v - v), 0.0)
         last = jnp.max(jnp.where(valid, col, -1), axis=1, keepdims=True)
         r_end = jnp.sum(jnp.where(col == last, jnp.where(valid, ri, 0), 0),
-                        axis=1, keepdims=True).astype(f32)
+                        axis=1, keepdims=True)
         # the pair that leaves the sure range's last sample H: inside the
         # band when hi is a hole (H < hi) and H has a next sample, and in
         # the window only if that sample is (b1, else b2: vb at hi + 1 is
         # its value)
-        over = mid & (kh > 0.0) & reach & (hi < last)
+        over = mid & (kh > 0) & reach & (hi < last)
         delta_mid = dot_exact01(g, band) - jnp.where(
             over, step(v_b1 - v_hi), 0.0)
     delta = (delta_mid
@@ -599,14 +628,14 @@ def _hole_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     # A row that ended under the window: its own last sample's cell and
     # residual, where the fill from hi does not reach it
     c_f = jnp.where(m_a2, lo - 2, jnp.where(m_a1, lo - 1,
-                                            f_sure + kl.astype(i32)))
+                                            f_sure + kl))
     r_f = jnp.where(m_a2, pick(rp, 0), jnp.where(m_a1, pick(rp, 1), rl))
     c_l = jnp.where(m_b2, hi + 2, jnp.where(m_b1, hi + 1, jnp.where(
-        reach, hi - kh.astype(i32), last)))
+        reach, hi - kh, last)))
     r_l = jnp.where(m_b2, pick(rp, 3), jnp.where(m_b1, pick(rp, 2), jnp.where(
         reach, rhi, r_end)))
-    t_f = c_f * interval_ms + start + r_f.astype(i32)
-    t_l = c_l * interval_ms + start + r_l.astype(i32)
+    t_f = c_f * interval_ms + start + r_f
+    t_l = c_l * interval_ms + start + r_l
     dur_start = (t_f - (rel - window_ms)).astype(f32) / 1000.0
     dur_end = (rel - t_l).astype(f32) / 1000.0
     sampled = (t_l - t_f).astype(f32) / 1000.0
@@ -653,8 +682,9 @@ def tile_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     ``band`` and ``ohlo`` are 0/1 in bf16 and every product with them is
     :func:`dot_exact01`'s: three MXU passes, exact, whatever the default
     matmul precision. ``line = (start, res, eb)`` is a line store's tile
-    (see :func:`_line_contrib`; ``ohlo`` is then ``ohe``); None is the
-    grid, where column c IS cell c of every row. ``holes``: the line store
+    (see :func:`_line_contrib`; ``ohlo`` is then ``ohe``, int8, and its
+    products :func:`pick_exact`'s); None is the grid, where column c IS
+    cell c of every row. ``holes``: the line store
     has cells without a sample (:func:`_hole_contrib`). The rate family
     on a line store (:func:`counts_falls`) has two ``form``s of a tile,
     "tel" and "band", and returns a third value, a scalar: whether the
@@ -867,20 +897,29 @@ def build_pallas(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
     if line:
         in_specs += [const((8, Tp))]
     # scoped VMEM, stated from the footprint instead of the 16 MiB default:
-    # the value tile and both bf16 bands double-buffered, the accumulators,
-    # and the working set of tile_contrib (decoded tile, shifted copy,
-    # increments, and of v and the increments each the f32 remainder and
-    # three bf16 pieces; a dozen [Sb, Tp] planes). At the caps (C=1024,
-    # Tp=512, G=64) the default runs out ("Ran out of memory in memory
-    # space vmem", compiled for v5e)
+    # the value tile and both 0/1 operands double-buffered (``band`` /
+    # ``ohlo`` bf16; a line plan's ``ohe`` int8), the accumulators, and the
+    # working set of tile_contrib (decoded tile, shifted copy, increments,
+    # and of v and the increments each the f32 remainder and three bf16
+    # pieces; a dozen [Sb, Tp] planes). At the caps (C=1024, Tp=512, G=64)
+    # the default runs out ("Ran out of memory in memory space vmem",
+    # compiled for v5e)
     footprint = (2 * (Sb * Ca * jnp.dtype(var.block_dtype).itemsize
-                      + Ca * (Tp + We) * 2)
+                      + Ca * Tp * 2 + Ca * We * (1 if line else 2))
                  + 2 * n_out * G * Tp * 4
                  + 9 * Sb * Ca * 4 + 12 * Sb * Tp * 4)
-    if line:        # residual tile and its bf16 copy, the picked planes
-        footprint += 2 * Sb * Ca + Sb * Ca * 4 + 4 * Sb * We * 4
-    if holes:       # the filled planes and their pieces
-        footprint += 14 * Sb * Ca * 4
+    if line:
+        # the residual tile (int8, double-buffered); of pick_exact the
+        # values read as their bytes [4 Sb, Ca] int8, the product [4 Sb,
+        # We] int32 and its narrowing, the picked f32 planes [Sb, We]; the
+        # residuals' own picks [Sb, We] int32
+        footprint += 2 * Sb * Ca + Sb * Ca * 4 + 6 * Sb * We * 4
+    if holes:
+        # the filled planes and their pieces; the distances and residuals
+        # of both as int8 [Sb, Ca] and their four picks [Sb, Tp] int32; the
+        # plane filled forward picked at hi (bytes, product, narrowing)
+        footprint += (14 * Sb * Ca * 4 + 4 * Sb * Ca + 4 * Sb * Tp * 4
+                      + Sb * Ca * 4 + 5 * Sb * Tp * 4)
     return pl.pallas_call(
         body,
         grid=(S // Sb,),
@@ -1098,10 +1137,11 @@ def host_operands(C: int, Tp: int, out_ts: np.ndarray, window_ms: int,
     cells every row holds, ``ohe`` replaces ``ohlo`` (one-hot slots of the
     cells lo-2, lo-1, hi+1, hi+2, max(lo, 0), hi: ``[C, 6 Tp]``, or two
     slots a block, ``[C, 3 x 128]``, where :func:`slots_per_block` says
-    so, the window fns' band then in slot 4), and ``eb [8, Tp]`` i32
-    follows ``rel``: per step the least ``start + residual`` that puts cell
-    lo-2 (row 0) or lo-1 (row 1) in the window and the most that puts hi+1
-    (row 2) or hi+2 (row 3) in it. ``holes``: the same operands for
+    so; INT8 and picks only, :func:`pick_exact` — the window fns' closed
+    band is ``band``), and ``eb [8, Tp]`` i32 follows ``rel``: per step the
+    least ``start + residual`` that puts cell lo-2 (row 0) or lo-1 (row 1)
+    in the window and the most that puts hi+1 (row 2) or hi+2 (row 3) in
+    it. ``holes``: the same operands for
     :func:`_hole_contrib`, but for the rate family's band, the cells ``[lo,
     hi - 1]``, and the active columns, three cells a side (a filled plane
     reaches over a run of holes)."""
@@ -1128,11 +1168,8 @@ def host_operands(C: int, Tp: int, out_ts: np.ndarray, window_ms: int,
         return (band, ohlo, lo_p, hi_p, rel_p, c0, Ca)
     cells = (lo - 2, lo - 1, hi + 1, hi + 2, np.maximum(lo, 0), hi)
     slot = Tp // slots_per_block(T)       # lanes from one slot to the next
-    ohe = np.zeros((C, EDGE_SLOTS * slot), bf16)
+    ohe = np.zeros((C, EDGE_SLOTS * slot), np.int8)
     steps = np.arange(T)
-    if slot < Tp and fn_kind == "window":
-        cells = cells[:EDGE_SLOTS_WINDOW]
-        ohe[:, BAND_SLOT * slot:BAND_SLOT * slot + T] = band[:, :T]
     for j, cell in enumerate(cells):
         real = (cell >= 0) & (cell < C)
         ohe[cell[real], j * slot + steps[real]] = 1

@@ -4,7 +4,9 @@ of a dispatch, on whatever device JAX has.
 The witness that two trees answer to the bit ON THE CHIP, where the tier-1
 tests (interpret mode, XLA:CPU) cannot look: run it on both in ONE chip call
 and compare the last line's checksum (PR 38: parent and change,
-``f66a4ebe79e1f9dd`` at the default size; PERF.md §6).
+``f66a4ebe79e1f9dd`` at the default size, ``--full`` ``a39bd4b1db98ece5``
+since PR 40; both again when the line form's picks went int8, PR 47;
+PERF.md §6).
 
 Usage:
     python scripts/fused_bits.py [--root <tree>] [--tag <name>] [--rows N]

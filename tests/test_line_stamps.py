@@ -233,6 +233,38 @@ def test_a_row_that_ended_keeps_its_last_sample_in_the_window(backend, fn):
         assert err(present(agg, parts), aggregate(agg, want, gids, G)) < 1.0
 
 
+@pytest.mark.parametrize("holes", [False, True])
+@pytest.mark.parametrize("fn", ["rate", "delta", "avg_over_time",
+                                "count_over_time"])
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_what_lies_past_a_rows_count_moves_no_bit(backend, fn, holes):
+    """The picks are int8 products of the residual block itself: whatever
+    int8 a cell at or past a row's count holds — a hole's mark among them
+    — is masked before the product and every reader asks the count first,
+    in both modes of the line program, and the partial state is the clean
+    block's to the bit. The values' cells past the count are masked as
+    they were: garbage there too."""
+    _t, _v, n, st, out_ts = the_ended_store()
+    info = st.line_info()
+    gids = jnp.asarray((np.arange(S) % G).astype(np.int32))
+    rng = np.random.default_rng(5)
+    past = np.arange(C)[None, :] >= np.asarray(st.n)[:S, None]
+    assert past[list(ENDED_ROWS)].any(1).all()
+    res = np.where(past, rng.integers(-128, 128, (S, C)),
+                   np.asarray(info.res)).astype(np.int8)
+    val = np.where(past, rng.normal(0, 1e6, (S, C)),
+                   np.asarray(st.val)).astype(np.float32)
+    assert (res[past] == chunkstore.RES_HOLE).any()
+
+    def run(v, r):
+        parts = fusedgrid.fused_grid_aggregate(
+            "stddev", fn, jnp.asarray(v), st.n, gids, G, out_ts[:61], WINDOW,
+            info.base_ts, info.interval_ms, variant=backend,
+            line=(info.start, jnp.asarray(r)), holes=holes)
+        return {k: np.asarray(a).tobytes() for k, a in parts.items()}
+    assert run(val, res) == run(st.val, info.res)
+
+
 # -- C: two edge slots a 128-lane block (up to 64 steps) against one ----------
 
 STEP_COUNTS = (1, 61, 64, 65, 128)       # packed, packed, packed; one a block
@@ -334,17 +366,46 @@ def test_the_slot_layout_is_part_of_a_line_programs_key_and_tag():
                 C, 128, ended_steps(128)[:T], WINDOW, info.base_ts, IV, kind,
                 line=True)
             assert band.shape == (ca, 128) and ohe.shape == (ca, width)
-            assert band.dtype == ohe.dtype == jnp.bfloat16
+            # picks only, int8: every column one-hot or zeros, whatever
+            # the function; a band adds cells and is the bf16 ``band``
+            assert band.dtype == jnp.bfloat16 and ohe.dtype == np.int8
             assert eb.shape == (8, 128) and lo.shape == (1, 128)
-            ones = np.asarray(ohe, np.float32).sum(0)
-            if width == 3 * 128 and kind == "window":
-                # four one-hot slots, then the closed band in the fifth
-                assert (ones[:4 * 64] <= 1).all() and not ones[5 * 64:].any()
-                np.testing.assert_array_equal(
-                    np.asarray(ohe, np.float32)[:, 256:256 + T],
-                    np.asarray(band, np.float32)[:, :T])
-            else:
-                assert (ones <= 1).all() and ones.sum() > 4 * T
+            ones = ohe.astype(np.int64).sum(0)
+            assert (ones <= 1).all() and ones.sum() > 4 * T
+            assert set(np.unique(ohe)) <= {0, 1}
+
+
+@pytest.mark.parametrize("T", [61, 65])
+@pytest.mark.parametrize("kind", ["rate", "window"])
+def test_a_line_plan_holds_its_picks_as_int8_and_its_band_as_the_band(
+        kind, T):
+    """``host_operands(..., line=True)`` since the picks run as int8
+    products (fusedgrid.pick_exact): ``ohe`` is int8 and holds the six
+    one-hot slots and nothing else, whatever the function — entry for entry
+    what the bf16 operand held beside the packed window form's band — and
+    that band is where every line program is passed one, in ``band``: the
+    closed band for the window functions, the open one for the rate
+    family."""
+    out_ts = ended_steps(128)[:T]
+    band, ohe, lo_p, hi_p, _rel, _eb, c0, ca = fusedgrid.host_operands(
+        C, 128, out_ts, WINDOW, BASE, IV, kind, line=True)
+    assert ohe.dtype == np.int8 and band.dtype == jnp.bfloat16
+    lo, hi = gridfns.grid_edges(out_ts, WINDOW, BASE, IV,
+                                fusedgrid.line_spread(IV))
+    np.testing.assert_array_equal(lo_p[0, :T], lo)
+    np.testing.assert_array_equal(hi_p[0, :T], hi)
+    slot = 128 // fusedgrid.slots_per_block(T)
+    want = np.zeros((C, fusedgrid.EDGE_SLOTS * slot), np.int8)
+    cells = (lo - 2, lo - 1, hi + 1, hi + 2, np.maximum(lo, 0), hi)
+    for j, cell in enumerate(cells):
+        for t in range(T):
+            if 0 <= cell[t] < C:
+                want[cell[t], j * slot + t] = 1
+    assert ohe.shape == (ca, want.shape[1])
+    assert ohe.tobytes() == want[c0:c0 + ca].tobytes()
+    closed = np.zeros((C, 128), np.float32)
+    closed[:, :T] = gridfns.band_matrix(C, lo, hi, kind == "rate", np.float32)
+    assert band.astype(np.float32).tobytes() == closed[c0:c0 + ca].tobytes()
 
 
 # -- D: the telescoped delta, and the tiles that fall back to the band ---------

@@ -98,12 +98,13 @@ def test_scalar_kernel_compiles_for_v5e(one_chip, fn, sumsq, C, Tp, G,
 def _line_args(sh, C, Tp, per=1):
     """A line store's operands (fusedgrid._line_contrib): each row's start
     packed above its count, the int8 residual block beside the values,
-    ``ohe`` for ``ohlo`` with ``per`` edge slots a block, the edge bounds
-    last."""
+    ``ohe`` (int8: picks only) for ``ohlo`` with ``per`` edge slots a
+    block, the edge bounds last."""
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sh)  # noqa: E731
     return [sds((S, C), f32), sds(ROWS, i32), sds(ROWS, i32),
             sds((S, C), jnp.int8),
-            sds((C, Tp), bf16), sds((C, fusedgrid.EDGE_SLOTS // per * Tp), bf16),
+            sds((C, Tp), bf16),
+            sds((C, fusedgrid.EDGE_SLOTS // per * Tp), jnp.int8),
             sds((1, Tp), i32), sds((1, Tp), i32), sds((1, Tp), i32),
             sds((8, Tp), i32)]
 
@@ -115,7 +116,7 @@ def _line_args(sh, C, Tp, per=1):
     ("count_over_time", False, 128, 8, 1),
     ("delta", True, 512, 64, 1),         # every cap at once
     # up to 64 steps two edge slots share a block: the 64-lane roll that
-    # brings a half down, the window fns' band beside their picks
+    # brings a half down; the window fns' band is the ``band`` operand
     ("rate", False, 128, 8, 2),
     ("rate", False, 128, 64, 2),
     ("sum_over_time", True, 128, 8, 2),
@@ -210,7 +211,8 @@ def _store_args(sh, rows, C, Tp, residency="raw", per=0):
     if per:
         args += [sds((rows,), i32), sds((rows, C), jnp.int8)]
     We = fusedgrid.EDGE_SLOTS // per * Tp if per else Tp
-    args += [sds((C, Tp), bf16), sds((C, We), bf16),
+    # a line plan's ``ohe`` holds picks only and goes up as int8
+    args += [sds((C, Tp), bf16), sds((C, We), jnp.int8 if per else bf16),
              sds((1, Tp), i32), sds((1, Tp), i32), sds((1, Tp), i32)]
     return args + ([sds((8, Tp), i32)] if per else [])
 
@@ -253,21 +255,65 @@ def test_the_whole_fused_program_holds_no_column_and_no_temp(
     _no_column_and_no_temp(compiled, S)
 
 
-@pytest.mark.parametrize("fn,per,holes,dots", [
-    ("rate", 2, False, (8, 11)),         # adhoc_prom's: 12 and 15 passes
-    ("increase", 1, False, (8, 11)),
-    ("delta", 2, False, (8, 11)),
-    ("rate", 2, True, (16, 19)),         # adhoc_prom_miss's: 19 and 22
-    ("rate", 1, True, (16, 19)),
-    ("delta", 2, True, (16, 19)),
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs included."""
+    for e in jaxpr.eqns:
+        yield e
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _eqns(sub)
+
+
+def _dots(jaxpr, C):
+    """A branch's ``dot_general``s: ``(int8 x int8 -> int32, bf16 x bf16 ->
+    f32, MXU passes of the tile's own products in bf16-pass equivalents)``
+    — a product over the tile's ``C`` columns takes a pass a 128-lane block
+    of its weight and ``SB`` rows of its left side (a value's byte pick has
+    four times the tile's: fusedgrid.pick_exact), at half the price in
+    int8; the group fold's products contract over the rows and are not
+    counted."""
+    i8 = b16 = 0
+    passes = 0.0
+    for e in _eqns(jaxpr):
+        if e.primitive.name != "dot_general":
+            continue
+        lhs, rhs = (v.aval for v in e.invars)
+        out, = (v.aval for v in e.outvars)
+        kinds = (lhs.dtype, rhs.dtype, out.dtype)
+        assert kinds in ((jnp.int8, jnp.int8, i32), (bf16, bf16, f32)), kinds
+        assert e.params["precision"] in (
+            jax.lax.Precision.DEFAULT,
+            (jax.lax.Precision.DEFAULT, jax.lax.Precision.DEFAULT)), e
+        i8, b16 = i8 + (lhs.dtype == jnp.int8), b16 + (lhs.dtype == bf16)
+        if lhs.shape[1] == C:
+            passes += (lhs.shape[0] / SB * rhs.shape[1] / 128
+                       / (2 if lhs.dtype == jnp.int8 else 1))
+    return i8, b16, passes
+
+
+@pytest.mark.parametrize("fn,per,holes,tel,band", [
+    # (int8 products, bf16 products, the tile's passes in bf16 equivalents)
+    # of the telescoped branch and of the band form's: the PICKS are int8 —
+    # the residuals as they are 1, the values' four bytes in one product 1;
+    # hole mode: the four edge residuals 1, the two filled planes' values
+    # 2, their distances and residuals 4 — and what is bf16 ADDS: the
+    # fold's three pieces and its counts 4, the hole mode's validity band
+    # 1, the band form's increments x band 3
+    ("rate", 2, False, (2, 4, 7.5), (2, 7, 10.5)),      # adhoc_prom's
+    ("increase", 1, False, (2, 4, 15), (2, 7, 18)),
+    ("delta", 2, False, (2, 4, 7.5), (2, 7, 10.5)),
+    ("rate", 2, True, (7, 5, 12), (7, 8, 15)),          # adhoc_prom_miss's
+    ("rate", 1, True, (7, 5, 17), (7, 8, 20)),
+    ("delta", 2, True, (7, 5, 12), (7, 8, 15)),
 ])
 def test_a_line_rate_program_compiles_with_both_forms_of_its_tile(
-        one_chip, fn, per, holes, dots):
+        one_chip, fn, per, holes, tel, band):
     """The telescoped tile and the band form are both in the program, each
-    under its branch (fusedgrid.fallen_fold), the band form three products
-    the dearer (a product over ``ohe``'s blocks is one ``dot_general``); the
-    count of tiles that ran it is the last output, ``[1]`` i32 in SMEM. And
-    the whole still holds no ``copy``, no ``[S, 1]`` array and no
+    under its branch (fusedgrid.fallen_fold), the band form three bf16
+    products the dearer (a product over ``ohe``'s blocks is one
+    ``dot_general``); every pick is ``int8 x int8 -> int32`` and no bf16
+    product has a one-hot for its weight (fusedgrid.pick_exact); the count
+    of tiles that ran the band form is the last output, ``[1]`` i32 in
+    SMEM. And the whole still holds no ``copy``, no ``[S, 1]`` array and no
     temporary."""
     C, Tp, G = 768, 128, 8
     prog = fusedgrid.fused_program(fn, False, WINDOW, IV, S, SB, C, Tp, G,
@@ -276,12 +322,24 @@ def test_a_line_rate_program_compiles_with_both_forms_of_its_tile(
     with jax.enable_x64(False):
         jaxpr = jax.make_jaxpr(prog)(*args)
     call, = (e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call")
-    branches = [[str(b).count("dot_general") for b in e.params["branches"]]
+    branches = [[_dots(b.jaxpr, C) for b in e.params["branches"]]
                 for e in call.params["jaxpr"].eqns
                 if e.primitive.name == "cond"]
     # (program_id == 0; the tile before fell: nothing | telescoped; this
     # one fell: nothing | band), branches listed false first
-    assert branches == [[0, 0], [dots[0], 0], [0, dots[1]]]
+    none = (0, 0, 0.0)
+    assert branches == [[none, none], [tel, none], [none, band]]
+    # no conversion of the int8 residual tile to a float is left, at once
+    # or by way of int32 (the parent's ``res.astype(i32).astype(f32)``)
+    converts = [e for e in _eqns(call.params["jaxpr"])
+                if e.primitive.name == "convert_element_type"]
+    of_int8 = {e.outvars[0] for e in converts
+               if e.invars[0].aval.dtype == jnp.int8}
+    floated = [e for e in converts
+               if jnp.issubdtype(e.params["new_dtype"], jnp.floating)
+               and (e.invars[0].aval.dtype == jnp.int8
+                    or e.invars[0] in of_int8)]
+    assert of_int8 and not floated, floated
     outs = call.params["grid_mapping"].block_mappings_output
     assert [str(o.transformed_block_aval) for o in outs] == [
         "Ref<vmem>{float32[8,128]}"] * 2 + ["Ref<smem>{int32[1]}"]
