@@ -146,11 +146,6 @@ CONFIG_SPEC: dict[str, tuple[str, Any, str]] = {
         "Total resident bytes admitted to the fragment cache (fragments "
         "vary wildly in size, so the entry bound alone would not bound "
         "memory); LRU-evicted with eviction accounting."),
-    "query.fragment_max_steps": (
-        "int", 4096,
-        "Steps kept per fragment entry — older (head) steps trim first, "
-        "exactly the ones a sliding dashboard window evicts; bounds "
-        "per-entry growth under streaming subscriptions."),
     "query.subscribe_poll": (
         "duration", "100ms",
         "Watermark poll cadence between /api/v1/subscribe increments "
@@ -235,13 +230,6 @@ CONFIG_SPEC: dict[str, tuple[str, Any, str]] = {
         "Missed grid ticks re-evaluated after a restart or stall, newest "
         "last; the re-publish dedupes via deterministic (rule, eval_ts) "
         "pub-ids, so catch-up is exactly-once."),
-    "rules.streaming": (
-        "bool", True,
-        "Evaluate rules as streaming-query subscribers (query/"
-        "incremental.py): each tick takes its grid step from a per-rule "
-        "subscription and catch-up spans evaluate as ONE range query "
-        "instead of one full-window evaluation per missed tick (off = "
-        "instant evaluation per tick)."),
     "rules.webhook_url": (
         "str|null", None,
         "Alert notification webhook (POST JSON on firing/resolved "
@@ -297,10 +285,6 @@ CONFIG_SPEC: dict[str, tuple[str, Any, str]] = {
         "split-brain window (clients claim a new epoch on failover; a "
         "restarted deposed leader truncates its divergent tail and "
         "catches up on REJOIN)."),
-    "ingest.decode_ahead": (
-        "int", 2,
-        "Containers decoded ahead of the device scatter "
-        "(IngestionConsumer double buffering; 0 = serial)."),
     "ingest.gateway_port": (
         "int|null", None,
         "Enables the Influx line-protocol TCP gateway on the standalone "
@@ -516,5 +500,4 @@ class Config:
                 q["negative_cache_ttl"]) / 1000.0,
             fragment_cache_size=int(q["fragment_cache_size"]),
             fragment_cache_bytes=int(q["fragment_cache_bytes"]),
-            fragment_max_steps=int(q["fragment_max_steps"]),
         )

@@ -89,8 +89,8 @@ def partial_aggregate(op: str, values, group_ids, num_groups: int,
 
 
 def resolve_partials(parts):
-    """Normalize a partials carrier: a lazily-fetched device bundle (e.g.
-    fusedgrid.PaddedPartials) resolves to its host dict here — at present/
+    """Normalize a partials carrier: a fused program's handle, not fetched
+    (diagnostics.Dispatched), resolves to its host dict here — at present/
     merge time, outside any shard lock."""
     return parts.resolve() if hasattr(parts, "resolve") else parts
 

@@ -35,9 +35,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..utils.diagnostics import inflight
+from ..utils.diagnostics import dispatching
 from ..utils.metrics import FILODB_QUERY_FUSED_FALL_TILES, registry
-from ..utils.tracing import SPAN_QUERY_KERNEL, span
 from . import decodereg, gridfns
 
 FUSED_FNS = {"rate", "increase", "delta"}
@@ -1221,50 +1220,24 @@ def fusable(S: int, C: int, T: int, num_groups: int) -> bool:
             and (S % 512 == 0 or (S <= 512 and S % 8 == 0)))
 
 
-class PaddedPartials:
-    """Device-resident padded kernel outputs, fetched lazily: the leaf holds
-    the shard lock while dispatching — blocking there on a device_get would
-    stall every ingest/query thread for the whole streaming pass. resolve()
-    runs at present/merge time, outside the lock."""
+def _line_fall_tags(tiles: int, variant: str, falls) -> dict:
+    """A line rate program's fetch-span tags from its fetched count of
+    fallen tiles (its LAST output) and its grid steps."""
+    return {"tiles": tiles,
+            "fall_tiles": count_fall_tiles(falls, "line", variant)}
 
-    def __init__(self, outs, op: str, num_groups: int, T: int, ticket,
-                 tiles: int | None = None, variant: str = "pallas"):
-        self._outs = outs
-        self._op = op
-        self._ng = num_groups
-        self._T = T
-        # the dispatch's place in the in-flight count (diagnostics
-        # .inflight): given back by the fetch, or with this bundle if it is
-        # dropped unfetched
-        self._ticket = ticket
-        # a program that counts its fallen tiles (counts_falls): its grid
-        # steps, and the count is its LAST output; None for every other
-        self._tiles, self._variant = tiles, variant
-        self.fall_tags: dict = {}
 
-    def parts_of(self, outs) -> dict:
-        """Partial dict from ALREADY-FETCHED outputs (callers batching many
-        bundles into one device_get use this instead of resolve()). A line
-        rate program's fallen tiles are counted in /metrics here, and left
-        in ``fall_tags`` (``fall_tiles``, ``tiles``) for the fetch span."""
-        self._ticket.fetched()
-        if self._tiles is not None:
-            *outs, falls = outs
-            self.fall_tags = {"tiles": self._tiles, "fall_tiles":
-                              count_fall_tiles(falls, "line", self._variant)}
-        s, c = outs[0][:self._ng, :self._T], outs[1][:self._ng, :self._T]
-        if self._op in ("count", "group"):
-            return {"count": c}
-        parts = {"sum": s, "count": c}
-        if len(outs) > 2:
-            parts["sumsq"] = outs[2][:self._ng, :self._T]
-        return parts
-
-    def resolve(self) -> dict:
-        with span(SPAN_QUERY_KERNEL, phase="fetch") as tags:
-            parts = self.parts_of(jax.device_get(self._outs))
-            tags.update(self.fall_tags)
-        return parts
+def _padded_parts(op: str, num_groups: int, T: int, outs) -> dict:
+    """The partial dict of a fused program's FETCHED padded outputs (less
+    a line rate program's count of fallen tiles): what its handle
+    (``diagnostics.Dispatched``) answers with."""
+    s, c = outs[0][:num_groups, :T], outs[1][:num_groups, :T]
+    if op in ("count", "group"):
+        return {"count": c}
+    parts = {"sum": s, "count": c}
+    if len(outs) > 2:
+        parts["sumsq"] = outs[2][:num_groups, :T]
+    return parts
 
 
 def fused_grid_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
@@ -1278,10 +1251,11 @@ def fused_grid_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
     counts, gids [S] i32 dense group ids (< num_groups). Returns the same
     partial-state dict as ``aggregators.partial_aggregate(op, ...)`` with
     [num_groups, T] arrays, combinable via ``combine_partials`` / psum.
-    With ``fetch=False`` returns a :class:`PaddedPartials` whose ``resolve()``
-    does the (blocking) host fetch later. ``narrow=(kind, operands)`` streams
-    a registered narrow block (ops/decodereg.py) instead of ``val``: kind
-    names the decode variant ("quant16" | "delta16" | "delta8") and
+    With ``fetch=False`` returns the dispatch's handle
+    (``diagnostics.Dispatched``) whose ``resolve()`` does the (blocking) host
+    fetch later, outside the leaf's shard lock. ``narrow=(kind, operands)``
+    streams a registered narrow block (ops/decodereg.py) instead of ``val``:
+    kind names the decode variant ("quant16" | "delta16" | "delta8") and
     ``operands = (block, *row_operands)`` its device arrays — 1/4 to 1/2 the
     HBM bytes; the caller must already have zeroed ``n`` for rows whose
     narrow encoding is not bit-exact. ``line = (start, res)`` says that
@@ -1326,10 +1300,8 @@ def fused_grid_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
     # ``holes``: which mode of the line program ran (0 | 1)
     tags = {"stamps": "grid"} if line is None else {
         "stamps": "line", "packed": per, "holes": int(holes)}
-    ticket = inflight.dispatched()
-    with span(SPAN_QUERY_KERNEL, phase="dispatch", ahead=ticket.ahead,
-              kernel=kernel_tag(variant), rows=S, c0=c0, cols=Ck, steps=T,
-              groups=num_groups, **tags), \
+    with dispatching(kernel=kernel_tag(variant), rows=S, c0=c0, cols=Ck,
+                     steps=T, groups=num_groups, **tags) as padded, \
             jax.enable_x64(False):
         if nops is not None:
             outs = call(*nops, jnp.asarray(n), jnp.asarray(gids), *ops)
@@ -1338,10 +1310,12 @@ def fused_grid_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
         else:
             outs = call(val, jnp.asarray(n), jnp.asarray(gids), *ops)
     # partial state is tiny ([G, Tp]): ONE host fetch finishes the query — the
-    # slice/present/combine chain as device ops would cost a round-trip each
-    padded = PaddedPartials(outs, op, num_groups, T, ticket,
-                            S // Sb if counts_falls(fn, per) else None,
-                            variant)
+    # slice/present/combine chain as device ops would cost a round-trip each.
+    # A program that counts its fallen tiles (counts_falls) says so on its
+    # fetch span, beside its grid steps, and in /metrics
+    padded.holds(outs, functools.partial(_padded_parts, op, num_groups, T),
+                 functools.partial(_line_fall_tags, S // Sb, variant)
+                 if counts_falls(fn, per) else None)
     return padded.resolve() if fetch else padded
 
 

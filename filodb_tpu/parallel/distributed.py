@@ -251,7 +251,7 @@ def _stack_parts(slot_parts):
     deliberately NOT a device collective: psum's reduction order is
     implementation-defined (and shape-dependent), and an in-program f32
     fold rounds differently from the host reduce's f64 accumulator. The
-    caller (LazyMeshResult.resolve) folds these blocks on host in SHARD
+    caller (fold_in_shard_order) folds these blocks on host in SHARD
     order — slot-major, device-minor, shard ``j*ndev + d`` — with the same
     float64 accumulation as the scatter-gather merge (exec._merge_partials),
     so the mesh answer is bit-EQUAL to the host-loop path, not merely
@@ -307,7 +307,7 @@ def _dist_aggregate_impl(fn: str, op: str, num_groups: int, mesh: Mesh,
                          slot_tvn, slot_gids, out_ts, window_ms, a0, a1):
     """One compiled distributed query step: range function per resident slot
     block + STABLE segment partials per device, stacked for the host-order
-    fold (LazyMeshResult.resolve presents them with the SAME reduce + host
+    fold (fold_in_shard_order presents them with the SAME reduce + host
     presenter the scatter-gather path uses — bit parity by construction)."""
 
     def per_device(slot_tvn, slot_gids):
@@ -534,7 +534,7 @@ def _dist_fused_aggregate_impl(fn: str, op: str, num_groups: int, mesh: Mesh,
     state stacked for the host-order fold — the multi-chip twin of
     ``fusedgrid.fused_grid_aggregate`` (ref: AggrOverRangeVectors.scala:62 —
     the same AggregateMapReduce map phase runs identically on every data
-    node; LazyMeshResult.resolve IS the reduce node, in the host merge's
+    node; fold_in_shard_order IS the reduce node, in the host merge's
     shard order and precision). Band/edge operands are replicated; each
     device streams only its resident [S, C] blocks, one kernel pass per
     slot."""
@@ -759,47 +759,56 @@ class MeshLeafMemo:
         return True
 
 
-class LazyMeshResult:
-    """Device-resident distributed result; ``resolve()`` does the blocking
-    host fetch. The engine dispatches under the shard locks but fetches
-    outside them (same contract as the in-process leaf: a slow collective
-    must not stall ingest on every shard for its full wall time).
+def fold_in_shard_order(op: str, num_groups: int, T: int | None,
+                        host: dict) -> np.ndarray:
+    """The mesh route's reduce node, on FETCHED partial state: what the
+    dispatch's handle (``diagnostics.Dispatched``) answers with. The engine
+    dispatches under the shard locks but fetches outside them (same
+    contract as the in-process leaf: a slow collective must not stall
+    ingest on every shard for its full wall time).
 
     The mesh program returns UNFOLDED partial state (dict of
     [NDEV, NSLOT, G, T] globals — each device's stacked per-slot partials);
-    resolve() folds them in SHARD order (slot-major, device-minor: shard
+    this folds them in SHARD order (slot-major, device-minor: shard
     ``j*ndev + d``) with the same float64 accumulation as the scatter-gather
     merge (exec._merge_partials), then presents with the SAME
     ``aggregators.present_partials`` host presenter the host-loop reduce
     uses — so the presented values carry no device/host dtype-promotion or
     fold-order skew and match the host path bit-for-bit."""
+    merged: dict[str, np.ndarray] = {}
+    for name, g in host.items():          # g: [NDEV, NSLOT, G, T]
+        ndev, nslot = g.shape[0], g.shape[1]
+        acc = g[0, 0].astype(np.float64)  # shard 0 seeds, exactly as the
+        for j in range(nslot):            # host merge's first base does
+            for d in range(ndev):
+                if j == 0 and d == 0:
+                    continue
+                a = g[d, j]               # shard j*ndev + d
+                if name == "min":
+                    acc = np.minimum(acc, a)
+                elif name == "max":
+                    acc = np.maximum(acc, a)
+                else:
+                    acc = acc + a
+        merged[name] = acc
+    vals = aggregators.present_partials(op, merged)[:num_groups]
+    return vals[:, :T] if T is not None else vals
 
-    def __init__(self, parts: dict, op: str, num_groups: int, T: int | None):
-        self._parts = parts
-        self._op = op
-        self._ng = num_groups
-        self._T = T
 
-    def resolve(self) -> np.ndarray:
-        host = {k: np.asarray(v) for k, v in self._parts.items()}
-        merged: dict[str, np.ndarray] = {}
-        for name, g in host.items():          # g: [NDEV, NSLOT, G, T]
-            ndev, nslot = g.shape[0], g.shape[1]
-            acc = g[0, 0].astype(np.float64)  # shard 0 seeds, exactly as the
-            for j in range(nslot):            # host merge's first base does
-                for d in range(ndev):
-                    if j == 0 and d == 0:
-                        continue
-                    a = g[d, j]               # shard j*ndev + d
-                    if name == "min":
-                        acc = np.minimum(acc, a)
-                    elif name == "max":
-                        acc = np.maximum(acc, a)
-                    else:
-                        acc = acc + a
-            merged[name] = acc
-        vals = aggregators.present_partials(self._op, merged)[:self._ng]
-        return vals[:, :self._T] if self._T is not None else vals
+def _present_sketch(num_groups: int, T: int, q: float, counts) -> np.ndarray:
+    # ``counts``: the first device's copy of the psummed sketch, fetched
+    return aggregators.present_quantile_sketch(
+        counts[0][:num_groups, :, :T], q)
+
+
+def _present_topk(num_groups: int, T: int, outs):
+    v, r, sh, ok = (o[0][:num_groups] for o in outs)
+    # [G, T, k] -> [G, k, T]; un-padded steps only
+    mv = np.moveaxis(v, 2, 1)[:, :, :T]
+    return (np.where(np.moveaxis(ok, 2, 1)[:, :, :T], mv, np.nan),
+            np.moveaxis(sh, 2, 1)[:, :, :T],
+            np.moveaxis(r, 2, 1)[:, :, :T],
+            np.moveaxis(ok, 2, 1)[:, :, :T])
 
 
 class MeshQueryExecutor:
@@ -817,7 +826,9 @@ class MeshQueryExecutor:
     caller prepared before the locks) or was ``built`` here — both None on
     every other route. Every method is host work on handles and ONE pjit
     call: the step grid, window and arguments of the general programs go in
-    as host values of that call."""
+    as host values of that call. Each returns what the caller's dispatch
+    handle holds (``diagnostics.Dispatched.holds``): ``(device outputs, the
+    pure function from those outputs, fetched, to the answer)``."""
 
     def __init__(self, dstore: DistributedStore,
                  memo: MeshLeafMemo | None = None):
@@ -901,23 +912,22 @@ class MeshQueryExecutor:
             self.last_path = ("fused-narrow" if narrow is not None
                               else "fused") + sfx
             self.last_block = (int(c0), int(Ck))
-            res = LazyMeshResult(out, op, num_groups, T)
-            return res.resolve() if fetch else res
-        slot_tvn = tuple(self.dstore.arrays())
-        out_eval, T = _steps(out_ts)
-        out = dist_aggregate(slot_tvn, slot_gids, out_eval,
-                             *_host_args(window_ms, args),
-                             fn, op, G, self.dstore.mesh)
-        self.last_path = "twostep"
-        res = LazyMeshResult(out, op, num_groups, T)
-        return res.resolve() if fetch else res
+        else:
+            slot_tvn = tuple(self.dstore.arrays())
+            out_eval, T = _steps(out_ts)
+            out = dist_aggregate(slot_tvn, slot_gids, out_eval,
+                                 *_host_args(window_ms, args),
+                                 fn, op, G, self.dstore.mesh)
+            self.last_path = "twostep"
+        answer = functools.partial(fold_in_shard_order, op, num_groups, T)
+        return answer(jax.device_get(out)) if fetch else (out, answer)
 
     def quantile(self, fn: str, out_ts: np.ndarray, window_ms: int,
                  group_ids_per_shard: list[np.ndarray], num_groups: int,
                  q: float, args=(0.0, 0.0)):
-        """Distributed quantile: sketch counts psum over the mesh; returns a
-        LazySketch whose resolve() presents [G, T] on host (same presenter as
-        the in-process SketchPartial merge)."""
+        """Distributed quantile: sketch counts psum over the mesh; the answer
+        presents [G, T] on host (same presenter as the in-process
+        SketchPartial merge)."""
         slot_tvn = tuple(self.dstore.arrays())
         slot_gids = tuple(self.dstore.global_gids(group_ids_per_shard))
         out_eval, T = _steps(out_ts)
@@ -928,21 +938,16 @@ class MeshQueryExecutor:
                                    *_host_args(window_ms, args), fn, Gp,
                                    self.dstore.mesh)
         self.last_path = "sketch"
-
-        class LazySketch:
-            def resolve(self_inner) -> np.ndarray:
-                counts = np.asarray(
-                    out.addressable_shards[0].data[0])[:num_groups, :, :T]
-                return aggregators.present_quantile_sketch(counts, q)
-        return LazySketch()
+        return (out.addressable_shards[0].data,
+                functools.partial(_present_sketch, num_groups, T, q))
 
     def topk(self, fn: str, out_ts: np.ndarray, window_ms: int,
              group_ids_per_shard: list[np.ndarray], num_groups: int,
              k: int, bottom: bool, args=(0.0, 0.0)):
         """Distributed topk/bottomk: local candidates + ONE all_gather of
-        fixed-size blocks + global re-select, all on the mesh. Returns a lazy
-        handle resolving to (values [G, k, T], shard_ids, rows, present) —
-        the caller maps (shard, row) back to series keys."""
+        fixed-size blocks + global re-select, all on the mesh. The answer is
+        (values [G, k, T], shard_ids, rows, present) — the caller maps
+        (shard, row) back to series keys."""
         slot_tvn = tuple(self.dstore.arrays())
         slot_gids = tuple(self.dstore.global_gids(group_ids_per_shard))
         out_eval, T = _steps(out_ts)
@@ -951,19 +956,8 @@ class MeshQueryExecutor:
                          *_host_args(window_ms, args), fn, int(k),
                          bool(bottom), Gp, self.dstore.mesh, self.dstore.ndev)
         self.last_path = "topk"
-
-        class LazyTopK:
-            def resolve(self_inner):
-                v, r, sh, ok = (np.asarray(
-                    o.addressable_shards[0].data[0])[:num_groups]
-                    for o in outs)
-                # [G, T, k] -> [G, k, T]; un-padded steps only
-                mv = np.moveaxis(v, 2, 1)[:, :, :T]
-                return (np.where(np.moveaxis(ok, 2, 1)[:, :, :T], mv, np.nan),
-                        np.moveaxis(sh, 2, 1)[:, :, :T],
-                        np.moveaxis(r, 2, 1)[:, :, :T],
-                        np.moveaxis(ok, 2, 1)[:, :, :T])
-        return LazyTopK()
+        return (tuple(o.addressable_shards[0].data for o in outs),
+                functools.partial(_present_topk, num_groups, T))
 
 
 def warm_mesh_shape(fn: str, op: str, S: int, C: int, steps: int,

@@ -11,12 +11,13 @@ in-process scatter-gather ExecPlan tree.
 
 from __future__ import annotations
 
-import contextlib
+import functools
 import threading
 import time
 
 from collections import OrderedDict, deque
 from dataclasses import dataclass, field
+from typing import NamedTuple, Sequence
 
 import numpy as np
 
@@ -32,15 +33,16 @@ from ..utils.metrics import (FILODB_QUERY_LATENCY_MS,
                              FILODB_QUERY_RESULT_CACHE_MISSES,
                              FILODB_QUERY_SLOW, registry)
 from ..promql import parser as promql
-from ..utils.diagnostics import inflight, lock_hold_ns, lock_wait_ns
+from ..utils.diagnostics import (Dispatched, dispatching, lock_hold_ns,
+                                 lock_wait_ns)
 from ..utils.tracing import (SPAN_QUERY, SPAN_QUERY_ADMIT,
                              SPAN_QUERY_EXECUTE, SPAN_QUERY_FRAGMENT,
-                             SPAN_QUERY_GROUPIDS, SPAN_QUERY_KERNEL,
-                             SPAN_QUERY_LEAF, SPAN_QUERY_PARSE,
+                             SPAN_QUERY_GROUPIDS, SPAN_QUERY_PARSE,
                              SPAN_QUERY_PLAN, SPAN_QUERY_SELECT, span,
                              tracer)
 from . import logical as L
-from .exec import QueryContext, count_groupids
+from .exec import (LeafFrame, QueryContext, SelectRawPartitionsExec,
+                   check_sample_limit, count_groupids)
 from .planner import QueryPlanner
 from .rangevector import (QueryError, QueryResult, QueryStats,
                           RangeVectorKey, ResultMatrix)
@@ -62,6 +64,15 @@ MESH_TOPK_MAX_GROUPS = 16
 # rows outside the selection: a group id no kernel's one-hot/segment scatter
 # ever matches (OOB scatter updates drop; one-hot comparisons never equal it)
 _EXCLUDED_GID = 1 << 30
+
+
+class _Dispatch(NamedTuple):
+    """What a device route's leaf (fused-hist, mesh) leaves its lock(s)
+    with, for the fetch outside them."""
+    route: str                  # the exec path; the mesh's program name
+    group_keys: Sequence[RangeVectorKey]
+    result: Dispatched | None   # None: an empty selection, nothing dispatched
+    epochs: Sequence[int] = ()  # mesh: the shards' release epochs before it
 
 
 def _walk_plans(plan):
@@ -139,10 +150,9 @@ class QueryConfig:
     negative_cache_ttl_s: float = 30.0
     # incremental serving: per-step fragment cache entries per engine
     # (query.fragment_cache_size; 0 disables — the library default), with a
-    # total byte bound and a per-entry step bound (query.fragment_cache_*)
+    # total byte bound (query.fragment_cache_*)
     fragment_cache_size: int = 0
     fragment_cache_bytes: int = 64 << 20
-    fragment_max_steps: int = 4096
 
 
 class QueryResultCache:
@@ -413,8 +423,7 @@ class QueryEngine:
             from .incremental import FragmentCache
             self.fragment_cache = FragmentCache(
                 self.config.fragment_cache_size,
-                self.config.fragment_cache_bytes,
-                self.config.fragment_max_steps, tags={"dataset": dataset})
+                self.config.fragment_cache_bytes, tags={"dataset": dataset})
         else:
             self.fragment_cache = None
         # a failed peer epoch probe arms this cooldown: until it passes,
@@ -737,7 +746,6 @@ class QueryEngine:
         if hit is None:
             return None
         from ..parallel.cluster import stitch_matrices
-        from .exec import check_sample_limit
         with span(SPAN_QUERY_FRAGMENT, dataset=self.dataset,
                   reused=hit.reused_steps) as tags:
             parts = [ResultMatrix(hit.keep_ts, hit.keep_vals, hit.keys)]
@@ -1059,7 +1067,6 @@ class QueryEngine:
             return None
         if sh.store.grid_info() is None:
             return None              # off-grid store: general path outright
-        from .exec import SelectRawPartitionsExec, check_sample_limit
         step = max(inner.step_ms, 1)
         out_ts = np.arange(inner.start_ms, inner.end_ms + 1, step,
                            dtype=np.int64)
@@ -1076,52 +1083,42 @@ class QueryEngine:
         # — commit the probe's stats only when the fused route serves (the
         # same only-when-committed rule as the mesh path)
         pctx = _dc_replace(ctx, stats=QueryStats())
-        waited, held = lock_wait_ns(), lock_hold_ns()
-        # the route's one leaf: lock wait (a tag), select, group ids and
-        # the kernel's dispatch inside it, as SelectRawPartitionsExec's
-        with span(SPAN_QUERY_LEAF, shard=sh.shard_num) as ltags:
-            try:
-                with sh.lock:
-                    # rare off-pattern outcomes (cold data, churn minority)
-                    # re-run the leaf on the general path — acceptable on
-                    # the slow path; the common aligned case pays it once
-                    got = self._fused_hist_dispatch(
-                        sh, leaf, pctx, ctx, plan, agg, inner, out_ts)
-            finally:
-                ltags["lock_wait_ms"] = (lock_wait_ns() - waited) / 1e6
-                ltags["lock_hold_ms"] = (lock_hold_ns() - held) / 1e6
+        # the route's one leaf: select, group ids and the kernel's dispatch
+        # inside it, as SelectRawPartitionsExec's. Rare off-pattern outcomes
+        # (cold data, churn minority) re-run the leaf on the general path —
+        # acceptable on the slow path; the common aligned case pays it once
+        with LeafFrame(shard=sh.shard_num) as frame:
+            got = frame.locked([sh], lambda: self._fused_hist_dispatch(
+                leaf, pctx, ctx, plan, agg, inner, out_ts))
         if got is None:
             return None
-        out, falls, path, uniq, G, T, ticket = got
-        self._set_path(ctx, path)
+        self._set_path(ctx, got.route)
         ctx.stats.merge(pctx.stats)             # committed: fused serves
-        if out is None:
+        return self._fetched(got, out_ts)
+
+    def _fetched(self, got: "_Dispatch", out_ts, present=None) -> QueryResult:
+        """The blocking fetch of what a device route's leaf dispatched,
+        outside its lock(s) (the in-process leaf's rule), as the answer."""
+        if got.result is None:
             return QueryResult(ResultMatrix(
                 out_ts, np.zeros((0, len(out_ts))), []))
-        # the blocking fetch, outside the lock (the in-process leaf's rule)
-        with span(SPAN_QUERY_KERNEL, phase="fetch") as ftags:
-            vals = np.asarray(out)[:G, :T]
-            ticket.fetched()
-            if falls is not None:
-                ftags["fall_tiles"] = fusedresident.count_fall_tiles(falls)
-        m = ResultMatrix(out_ts, vals, list(uniq))
-        check_sample_limit(m.num_series, T, self.config.sample_limit)
+        m = (present or ResultMatrix)(out_ts, got.result.resolve(),
+                                      list(got.group_keys))
+        check_sample_limit(m.num_series, len(out_ts), self.config.sample_limit)
         return QueryResult(m)
 
-    def _fused_hist_dispatch(self, sh, leaf, pctx, ctx, plan, agg, inner,
-                             out_ts):
+    def _fused_hist_dispatch(self, leaf, pctx, ctx, plan, agg, inner,
+                             out_ts) -> "_Dispatch | None":
         """``_try_fused_hist`` under the shard lock: select, group ids and
         the dispatch of one of three programs, chosen from the store's
         shape — the tiled kernel over the 2D-delta state
         (``fused-hist-narrow[...]``), the tiled kernel over the raw f32
         block (``fused-hist[...]``), or the untiled composition (bare
-        ``fused-hist``) for what neither gate takes. Returns ``(out,
-        fall_tiles, path, group keys, G, T)`` — ``out`` the [G, T] device
-        array, not fetched, None for an empty selection; ``fall_tiles`` the
-        raw tier's count of correction matmuls, on the device too, None
-        from the other programs — with, last, the dispatch's handle in the
-        in-flight count, for the fetch to give back — or None: general
-        path."""
+        ``fused-hist``) for what neither gate takes. Returns the exec path,
+        the group keys and the dispatch's handle, which answers with the
+        host ``[G, T]`` values (``result`` None: an empty selection, nothing
+        dispatched; the raw tier's handle also counts its correction
+        matmuls, ``fall_tiles``) — or None: general path."""
         from ..ops import fusedresident, gridfns
         from .exec import (SeriesSelection, _grouping_for, _pad_steps,
                            _pow2)
@@ -1142,7 +1139,7 @@ class QueryEngine:
         gids, uniq, G, gids_dev = _grouping_for(data.keys, data.rows, R,
                                                 agg.by, agg.without)
         if not uniq:
-            return None, None, "fused-hist", uniq, G, T, None
+            return _Dispatch("fused-hist", uniq, None)
         base_ts, interval_ms = data.grid
         les = np.asarray(data.bucket_les, np.float64)
         Gp = _pow2(G)
@@ -1152,9 +1149,7 @@ class QueryEngine:
         ktags = {"kernel": "xla", "rows": R, "cols": data.val.shape[1],
                  "steps": T, "groups": G, "buckets": len(les),
                  "variant": "hist-untiled"}
-        ticket = inflight.dispatched()
-        with span(SPAN_QUERY_KERNEL, phase="dispatch",
-                  ahead=ticket.ahead) as tags:
+        with dispatching() as result:
             if data.hist_narrow is not None:
                 # hist-resident store: one fused program off the i8/i16
                 # 2D-delta block — the [S, C, B] f32 temp never exists.
@@ -1233,8 +1228,14 @@ class QueryEngine:
                 out = gridfns.fused_hist_quantile_grid(
                     q, les, data.val, data.n, gids, Gp, out_eval, window,
                     fn, base_ts, interval_ms, stale_ms=ctx.stale_ms)
-            tags.update(ktags)
-        return out, falls, path, uniq, G, T, ticket
+            result.tags.update(ktags)
+        if falls is None:
+            result.holds((out,), lambda outs: outs[0][:G, :T])
+        else:       # the raw tier's count of correction matmuls rides along
+            result.holds(
+                (out, falls), lambda outs: outs[0][:G, :T],
+                lambda k: {"fall_tiles": fusedresident.count_fall_tiles(k)})
+        return _Dispatch(path, uniq, result)
 
     # -- mesh dispatch (ref: queryengine2/QueryEngine.scala:59-67 — the
     # planner routes every query through per-shard dispatchers; here the
@@ -1343,21 +1344,7 @@ class QueryEngine:
         # engine's memo (built, uploaded and kept here only for a selector
         # or grouping it has not seen in this index state), the globals'
         # assembly (no program), the epochs' capture, the call
-        waited, held = lock_wait_ns(), lock_hold_ns()
-        with contextlib.ExitStack() as stack:
-            # the mesh route's one leaf: every shard's lock, taken in order
-            leaf = stack.enter_context(span(SPAN_QUERY_LEAF, shard="all",
-                                            route="mesh", locks=len(shards)))
-
-            def note_hold():
-                # as the stack unwinds: after the locks' release (a hold is
-                # counted there), before the leaf span closes
-                leaf["lock_hold_ms"] = (lock_hold_ns() - held) / 1e6
-
-            stack.callback(note_hold)
-            for sh in shards:
-                stack.enter_context(sh.lock)
-            leaf["lock_wait_ms"] = (lock_wait_ns() - waited) / 1e6
+        def dispatch(leaf_tags: dict) -> "_Dispatch | None":
             ex = self._mesh_executor(shards)
             if ex is None:
                 return None      # residency/shape changed: host path
@@ -1378,9 +1365,7 @@ class QueryEngine:
             # own leaf counts)
             matched_total = sum(len(p.pids) for p in picks)
             if not matched_total:
-                self._set_path(ctx, "mesh-empty")
-                return QueryResult(ResultMatrix(
-                    out_ts, np.zeros((0, len(out_ts))), []))
+                return _Dispatch("empty", (), None)
             with span(SPAN_QUERY_GROUPIDS, keys=matched_total,
                       route="index") as tags:
                 kept = memo.gids(picks, plan.by, plan.without)
@@ -1395,7 +1380,7 @@ class QueryEngine:
                     how = ("built" if memo.keep_gids(
                         picks, plan.by, plan.without, group_keys, gids_list)
                         else "bypass")
-                tags["memo"] = leaf["gids"] = how
+                tags["memo"] = leaf_tags["gids"] = how
                 tags["groups"] = len(group_keys)
             distributed.count_mesh_prepared("gids", how)
             count_groupids("index")
@@ -1416,67 +1401,71 @@ class QueryEngine:
             # query of a new (fn, op, G-bucket, T-bucket) shape still traces
             # and compiles here — step-count bucketing inside the executor
             # bounds that compile space exactly like the in-process path.
-            # The span closes with the stack, just before the locks release
-            # a return without a fetch below (a cap) drops the handle, and
-            # the count with it
-            ticket = inflight.dispatched()
-            kern = stack.enter_context(span(
-                SPAN_QUERY_KERNEL, phase="dispatch", ahead=ticket.ahead,
-                steps=len(out_ts), rows=sum(sh.store.S for sh in shards),
-                groups=G))
-            if op == "quantile":
-                # same safety gates as the in-process order-stat map: group
-                # cap + dense-sketch memory cap (every device allocates the
-                # [Gp, W, T] counts; the host route falls back to the exact
-                # matrix instead of dying in HBM)
-                from ..ops import aggregators as _agg
-                from .exec import _SKETCH_BYTES_CAP, AggregateMapReduce, _pow2
-                if (G > AggregateMapReduce.ORDER_STAT_MAX_GROUPS
-                        or _pow2(G) * _agg.SKETCH_WIDTH
-                        * (len(out_ts) + 31) * 4 > _SKETCH_BYTES_CAP):
-                    distributed.count_mesh_fallback("order_stat_caps")
-                    return None
-                lazy = ex.quantile(fn, out_ts, window, gids_list, G,
-                                   float(plan.params[0]), args=(a0, a1))
-            elif op in ("topk", "bottomk"):
-                k = max(int(plan.params[0]), 0)
-                if k == 0 or G > MESH_TOPK_MAX_GROUPS:
-                    distributed.count_mesh_fallback("topk_caps")
-                    return None
-                lazy = ex.topk(fn, out_ts, window, gids_list, G, k,
-                               op == "bottomk", args=(a0, a1))
-            else:
-                lazy = ex.aggregate(fn, op, out_ts, window, gids_list,
-                                    G, args=(a0, a1), fetch=False,
-                                    prepared=prepared)
-            # the program that ran and, for a fused one, its column block:
-            # what ties a device event to this query — and whether its
-            # window plan was ready as the locks were taken
-            kern["kernel"] = f"pjit-{ex.last_path}"
-            if ex.last_block is not None:
-                kern["c0"], kern["cols"] = ex.last_block
-                leaf["plan"] = ex.last_plan
-            if ctx is not None:     # committed: the mesh path serves this
-                ctx.stats.add("series_matched", matched_total)
-                if ex.last_path.startswith("fused"):
-                    # stats symmetry with the in-process fused route
-                    # (exec.py): cluster stats equal the single-node oracle
-                    ctx.stats.add("fused_kernels")
+            # A return without a result below (a cap) drops the handle, and
+            # its place in the in-flight count with it
+            with dispatching(steps=len(out_ts),
+                             rows=sum(sh.store.S for sh in shards),
+                             groups=G) as result:
+                if op == "quantile":
+                    # same safety gates as the in-process order-stat map:
+                    # group cap + dense-sketch memory cap (every device
+                    # allocates the [Gp, W, T] counts; the host route falls
+                    # back to the exact matrix instead of dying in HBM)
+                    from ..ops import aggregators as _agg
+                    from .exec import (_SKETCH_BYTES_CAP, AggregateMapReduce,
+                                       _pow2)
+                    if (G > AggregateMapReduce.ORDER_STAT_MAX_GROUPS
+                            or _pow2(G) * _agg.SKETCH_WIDTH
+                            * (len(out_ts) + 31) * 4 > _SKETCH_BYTES_CAP):
+                        distributed.count_mesh_fallback("order_stat_caps")
+                        return None
+                    held = ex.quantile(fn, out_ts, window, gids_list, G,
+                                       float(plan.params[0]), args=(a0, a1))
+                elif op in ("topk", "bottomk"):
+                    k = max(int(plan.params[0]), 0)
+                    if k == 0 or G > MESH_TOPK_MAX_GROUPS:
+                        distributed.count_mesh_fallback("topk_caps")
+                        return None
+                    held = ex.topk(fn, out_ts, window, gids_list, G, k,
+                                   op == "bottomk", args=(a0, a1))
+                else:
+                    held = ex.aggregate(fn, op, out_ts, window, gids_list,
+                                        G, args=(a0, a1), fetch=False,
+                                        prepared=prepared)
+                result.holds(*held)
+                # the program that ran and, for a fused one, its column
+                # block: what ties a device event to this query — and
+                # whether its window plan was ready as the locks were taken
+                result.tags["kernel"] = f"pjit-{ex.last_path}"
+                if ex.last_block is not None:
+                    result.tags["c0"], result.tags["cols"] = ex.last_block
+                    leaf_tags["plan"] = ex.last_plan
+                if ctx is not None:     # committed: the mesh path serves this
+                    ctx.stats.add("series_matched", matched_total)
+                    if ex.last_path.startswith("fused"):
+                        # stats symmetry with the in-process fused route
+                        # (exec.py): cluster stats equal the single-node
+                        # oracle
+                        ctx.stats.add("fused_kernels")
+            return _Dispatch(ex.last_path, group_keys, result, epochs)
+
+        # the mesh route's one leaf: every shard's lock, taken in order
+        with LeafFrame(shard="all", route="mesh", locks=len(shards)) as leaf:
+            got = leaf.locked(shards, lambda: dispatch(leaf.tags))
+        if got is None:
+            return None
+        if got.result is None:
+            self._set_path(ctx, "mesh-empty")
+            return self._fetched(got, out_ts)
         # the strings the ledger, chip_smoke.MESH_PREFIX and the benchmark's
         # expected routes read
-        self._set_path(ctx, f"mesh[pjit]-{ex.last_path}")
-        distributed.count_mesh_served(ex.last_path)
+        self._set_path(ctx, f"mesh[pjit]-{got.route}")
+        distributed.count_mesh_served(got.route)
+        present = None
         if op in ("topk", "bottomk"):
-            m = self._present_mesh_topk(lazy, ticket, shards, epochs, out_ts,
-                                        list(group_keys))
-        else:
-            with span(SPAN_QUERY_KERNEL, phase="fetch"):
-                vals = lazy.resolve()
-                ticket.fetched()
-            m = ResultMatrix(out_ts, vals, list(group_keys))
-        from .exec import check_sample_limit
-        check_sample_limit(m.num_series, len(out_ts), self.config.sample_limit)
-        return QueryResult(m)
+            present = functools.partial(self._present_mesh_topk, shards,
+                                        got.epochs)
+        return self._fetched(got, out_ts, present)
 
     @staticmethod
     def _mesh_group_rows(picks, by, without):
@@ -1506,17 +1495,15 @@ class QueryEngine:
             rows.append(g)
         return tuple(uniq), rows
 
-    def _present_mesh_topk(self, lazy, ticket, shards, epochs, out_ts,
+    def _present_mesh_topk(self, shards, epochs, out_ts, winners,
                            group_keys) -> ResultMatrix:
-        """Map the mesh topk's (shard, row) winners back to series keys and
-        present them Prometheus-style (union of selected series, values at
+        """Map the mesh topk's fetched (shard, row) winners back to series keys
+        and present them Prometheus-style (union of selected series, values at
         steps where each made the cut). Key resolution re-takes each winner
         shard's lock and validates its release epoch — a purge/eviction
         since dispatch could have re-assigned the row to a new series."""
-        from .exec import QueryError, TopKPartial, _present_topk
-        with span(SPAN_QUERY_KERNEL, phase="fetch"):
-            vals, shard_ids, rows, ok = lazy.resolve()
-            ticket.fetched()
+        from .exec import TopKPartial, _present_topk
+        vals, shard_ids, rows, ok = winners
         G, k, T = vals.shape
         flat_ok = ok.ravel()
         pairs = (shard_ids.ravel()[flat_ok].astype(np.int64) << 32) \

@@ -20,6 +20,7 @@ TPU-native execution shape:
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from dataclasses import dataclass, field
 
@@ -30,7 +31,8 @@ import numpy as np
 from ..core.filters import Filter
 from ..core.selection import ShardSelection
 from ..ops import aggregators, binop, instantfns, rangefns
-from ..utils.diagnostics import lock_hold_ns, lock_wait_ns
+from ..utils.diagnostics import (explain_deleted_buffer, lock_hold_ns,
+                                 lock_wait_ns)
 from ..utils.metrics import (FILODB_GROUPIDS, FILODB_INDEX_RESOLVE,
                              FILODB_QUERY_REFUSED,
                              FILODB_QUERY_LEAF, FILODB_QUERY_LEAF_GATHER,
@@ -378,7 +380,7 @@ class GatheredWindow:
     plan cache, and what the host knows — the picked rows, the steps, the
     window, the function's arguments, the group ids — goes in as host
     values, the call's own arguments. Must not leave the shard lock
-    undispatched (``_execute_leaf``), like ``FusedWindowData``."""
+    undispatched (``LeafFrame.locked``), like ``FusedWindowData``."""
     sel: GatheredRows
     out_ts: np.ndarray
     kernel: str               # "grid" | "periodic": PSM.apply's own choice
@@ -1529,6 +1531,68 @@ class ExecPlan:
         raise NotImplementedError
 
 
+class LeafFrame:
+    """The protocol of a query leaf under its shard lock(s), written once
+    for the in-process leaf (``SelectRawPartitionsExec``), the fused-hist
+    route and the mesh route (query/engine.py), which keep their own work —
+    select, group ids, the choice of program::
+
+        with LeafFrame(shard=...) as leaf:      # query.exec.leaf opens
+            ...                                 # what must precede the lock
+            got = leaf.locked(shards, body)     # body() under the lock(s)
+
+    The thread's lock counters are read before the span opens, and the span
+    opens BEFORE the first lock is asked for: a waiting thread is not what
+    the host was doing, so the wait is a tag of the leaf (``lock_wait_ms``),
+    not a span; the hold beside it (``lock_hold_ms``) is the lock's time
+    this leaf took. Both are differences of the counters, stamped at the
+    leaf's END on every route: after the locks' release (a hold is counted
+    there), before the span closes, a wait met after the long hold
+    (``_paged_batches``' re-locks) included. The mesh route used to stamp
+    its wait as it took its last lock and reads the same: nothing waits for
+    a shard lock once all are held."""
+
+    def __init__(self, **tags):
+        self._span = span(SPAN_QUERY_LEAF, **tags)
+
+    def __enter__(self):
+        self._waited, self._held = lock_wait_ns(), lock_hold_ns()
+        self.tags = self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self.tags["lock_wait_ms"] = (lock_wait_ns() - self._waited) / 1e6
+        self.tags["lock_hold_ms"] = (lock_hold_ns() - self._held) / 1e6
+        return self._span.__exit__(*exc)
+
+    def locked(self, shards, body):
+        """``body()`` under every one of ``shards``' locks, taken in the
+        order given (shard order) and held across array capture AND kernel
+        dispatch: a concurrent ingest flush donates (invalidates) the store
+        buffers, its compress_commit swaps a store's form (see
+        TimeSeriesShard.lock). So nothing lazy leaves them — a window
+        view's dispatch or rows' gather after the release would race that
+        donation; the blocking FETCH does, in the dispatch's handle
+        (``diagnostics.Dispatched``)."""
+        try:
+            with contextlib.ExitStack() as locks:
+                for sh in shards:
+                    locks.enter_context(sh.lock)
+                result = body()
+                if isinstance(result, (FusedWindowData, GatheredWindow)):
+                    result = result.materialize()
+                elif isinstance(result, GatheredRows):
+                    result = result.gathered()
+                return result
+        except RuntimeError as e:
+            # use-after-donation detective (ref: BlockDetective): name the
+            # donation site instead of jax's opaque "Array has been deleted"
+            if "deleted" in str(e):
+                explain_deleted_buffer(e, *(sh.store.detective for sh in shards
+                                            if sh.store is not None))
+            raise
+
+
 @dataclass
 class SelectRawPartitionsExec(ExecPlan):
     """The only data-reading leaf (ref: SelectRawPartitionsExec.scala)."""
@@ -1546,55 +1610,27 @@ class SelectRawPartitionsExec(ExecPlan):
         return _shard_of_ctx(ctx, self.shard, self.column)
 
     def execute(self, ctx: QueryContext):
-        waited, held = lock_wait_ns(), lock_hold_ns()
-        with span(SPAN_QUERY_LEAF, shard=self.shard) as tags:
-            try:
-                return self._execute_leaf(ctx)
-            finally:
-                # a waiting thread is not what the host was doing: the wait
-                # for the shard lock is a tag of the leaf, not a span; the
-                # hold beside it is the lock's time this leaf took
-                tags["lock_wait_ms"] = (lock_wait_ns() - waited) / 1e6
-                tags["lock_hold_ms"] = (lock_hold_ns() - held) / 1e6
-
-    def _execute_leaf(self, ctx: QueryContext):
-        # hold the shard lock across array capture AND the transformer chain's
-        # kernel dispatch: a concurrent ingest flush donates (invalidates) the
-        # store buffers (see TimeSeriesShard.lock)
-        shard, _col = self._shard_of(ctx)
-        if getattr(shard, "recovering", False):
-            # partial data: the count crosses the peer wire with the other
-            # stats, so the ROOT node knows an empty selection proves
-            # nothing (its negative cache must skip this query)
-            ctx.stats.add("recovering_shards")
-        # step-varying scalar operands resolve BEFORE the lock: their
-        # subplans take other shards' locks (nested acquisition would ABBA-
-        # deadlock two concurrent mirror-image queries)
-        for t in self.transformers:
-            if isinstance(t, ScalarOperationMapper):
-                t.prepare(ctx)
-        try:
-            with shard.lock:
-                result = super().execute(ctx)
-                if isinstance(result, (FusedWindowData, GatheredWindow)):
-                    # a lazy window view must not escape the lock: its kernel
-                    # dispatch would race a concurrent ingest flush's donation
-                    result = result.materialize()
-                elif isinstance(result, GatheredRows):
-                    result = result.gathered()      # nor rows not gathered
-        except RuntimeError as e:
-            # use-after-donation detective (ref: BlockDetective): name the
-            # donation site instead of jax's opaque "Array has been deleted"
-            if shard.store is not None and "deleted" in str(e):
-                from ..utils.diagnostics import explain_deleted_buffer
-                explain_deleted_buffer(e, shard.store.detective)
-            raise
-        if isinstance(result, _WideODP):
-            # batched paging runs OUTSIDE the long-held lock: each batch
-            # re-locks only around its store snapshot, so ingest is not
-            # stalled for the duration of a wide historical scan
-            return self._paged_batches(ctx, shard, result.pids, _col)
-        return result
+        with LeafFrame(shard=self.shard) as leaf:
+            shard, _col = self._shard_of(ctx)
+            if getattr(shard, "recovering", False):
+                # partial data: the count crosses the peer wire with the other
+                # stats, so the ROOT node knows an empty selection proves
+                # nothing (its negative cache must skip this query)
+                ctx.stats.add("recovering_shards")
+            # step-varying scalar operands resolve BEFORE the lock: their
+            # subplans take other shards' locks (nested acquisition would
+            # ABBA-deadlock two concurrent mirror-image queries)
+            for t in self.transformers:
+                if isinstance(t, ScalarOperationMapper):
+                    t.prepare(ctx)
+            result = leaf.locked([shard],
+                                 functools.partial(super().execute, ctx))
+            if isinstance(result, _WideODP):
+                # batched paging runs OUTSIDE the long-held lock: each batch
+                # re-locks only around its store snapshot, so ingest is not
+                # stalled for the duration of a wide historical scan
+                return self._paged_batches(ctx, shard, result.pids, _col)
+            return result
 
     def _paged_selection(self, shard, pids, keys, cold=None,
                          column=None) -> SeriesSelection:
@@ -2051,12 +2087,13 @@ def _merge_partials(op: str, partials: list[AggPartial]) -> AggPartial:
     out_ts = partials[0].out_ts
     les = partials[0].bucket_les
     T = len(out_ts) * (len(les) if les is not None else 1)
-    # ONE batched host fetch for every shard's (tiny) partial arrays; lazy
-    # device bundles (PaddedPartials) contribute their raw outputs to the
-    # same fetch — calling their resolve() here would round-trip per shard
+    # ONE batched host fetch for every shard's (tiny) partial arrays; a
+    # fused program's handle (diagnostics.Dispatched) contributes its raw
+    # outputs to the same fetch — its resolve() here would round-trip per
+    # shard
     raw = [p.parts for p in partials]
     with span(SPAN_QUERY_KERNEL, phase="fetch") as ftags:
-        fetched = jax.device_get([r._outs if hasattr(r, "parts_of") else r
+        fetched = jax.device_get([r.outs if hasattr(r, "parts_of") else r
                                   for r in raw])
         # parts_of() takes each bundle out of the in-flight count; the line
         # rate programs among them say how many of their tiles fell

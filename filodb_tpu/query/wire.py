@@ -452,7 +452,7 @@ def _resolved_parts(parts) -> dict[str, np.ndarray]:
     fetch=False): resolve to host numpy before hitting the wire."""
     import jax
     if hasattr(parts, "parts_of"):
-        parts = parts.parts_of(jax.device_get(parts._outs))
+        parts = parts.parts_of(jax.device_get(parts.outs))
     return {k: np.asarray(v) for k, v in parts.items()}
 
 

@@ -35,7 +35,8 @@ class RuleEvaluator:
         self.engine = engine
         self.publisher = publisher
         self.alert_manager = alert_manager
-        # rules.streaming: rules consume per-step increments from a
+        # streaming (what RulesManager runs; the polled form is the tests'
+        # reference): rules consume per-step increments from a
         # QuerySubscription (query/incremental.py) — the degenerate
         # subscriber of the streaming-query machinery. Each tick takes its
         # grid step; a catch-up span prefetches as ONE range query instead

@@ -20,7 +20,7 @@ class RulesManager:
                  sink=None, dataset: str = "", webhook_url: str | None = None,
                  webhook_retries: int = 3, webhook_backoff_s: float = 1.0,
                  max_concurrent: int = 2, max_catchup: int = 2,
-                 clock_ms=None, streaming: bool = False):
+                 clock_ms=None):
         self.groups = list(groups)
         self.state = RuleStateStore(sink, dataset)
         self.notifier = (WebhookNotifier(webhook_url, webhook_retries,
@@ -32,7 +32,7 @@ class RulesManager:
                                    notifier=self.notifier)
         self.evaluator = RuleEvaluator(engine, publisher=publisher,
                                        alert_manager=self.alerts,
-                                       streaming=streaming)
+                                       streaming=True)
         self.scheduler = RuleGroupScheduler(
             self.groups, self.evaluator, self.state,
             max_concurrent=max_concurrent, max_catchup=max_catchup,
@@ -54,8 +54,7 @@ class RulesManager:
                        cfg["rules.webhook_backoff"]) / 1000.0,
                    max_concurrent=int(cfg["rules.max_concurrent"]),
                    max_catchup=int(cfg["rules.max_catchup"]),
-                   clock_ms=clock_ms,
-                   streaming=bool(cfg["rules.streaming"]))
+                   clock_ms=clock_ms)
 
     def start(self) -> "RulesManager":
         self.scheduler.start()

@@ -115,7 +115,7 @@ class IngestionConsumer(threading.Thread):
 
     def __init__(self, shard, bus: FileBus, schemas, manager: ShardManager,
                  dataset: str, poll_s: float = 0.5, purge_interval_s: float = 600.0,
-                 decode_ahead: int = 2, accept=None):
+                 accept=None):
         super().__init__(daemon=True, name=f"ingest-{dataset}-{shard.shard_num}")
         self.shard = shard
         self.bus = bus
@@ -124,7 +124,6 @@ class IngestionConsumer(threading.Thread):
         self.dataset = dataset
         self.poll_s = poll_s
         self.purge_interval_s = purge_interval_s
-        self.decode_ahead = decode_ahead
         # shared-partition demux: with fewer broker partitions than shards
         # several shards replay one partition; ``accept(container)`` keeps
         # only this shard's containers (offsets still advance past skips)
@@ -202,8 +201,7 @@ class IngestionConsumer(threading.Thread):
                     # poll (the common case) must not create a thread
                     first = next(src, None)
                     if first is not None:
-                        if self.decode_ahead:
-                            src = _DecodeAhead(src, self.decode_ahead)
+                        src = _DecodeAhead(src, 2)
                         # one span per consumer DRAIN (not per container):
                         # the scatter leg of the ingest path, tagged with
                         # how much it moved
@@ -385,7 +383,6 @@ class FiloServer:
                                   self.manager, dataset,
                                   purge_interval_s=parse_duration_ms(
                                       cfg.get("store.purge_interval", "10m")) / 1000.0,
-                                  decode_ahead=cfg.get("ingest.decode_ahead", 2),
                                   accept=accept)
             with self._shards_lock:
                 if self._quarantined:       # raced quarantine: do not start

@@ -21,11 +21,14 @@ All checks are off by default (zero overhead beyond an ``if``); enable with
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import threading
 import time
 import traceback
+
+import jax
 
 log = logging.getLogger(__name__)
 
@@ -179,30 +182,72 @@ def lock_hold_ns() -> int:
 
 
 class Dispatched:
-    """One counted dispatch (see :class:`InflightPrograms`): ``ahead`` is
-    the count as it entered. ``fetched()`` takes it out of the count, once;
-    a handle dropped unfetched (an error between dispatch and fetch) takes
+    """One counted dispatch (see :class:`InflightPrograms`) and, from
+    ``holds()`` on, the handle of its result: the program's outputs on the
+    device, not fetched. A leaf dispatches under its shard lock(s); a
+    blocking fetch there would stall every ingest and query thread behind
+    the lock for the program's whole run, so the fetch (``resolve()``, or a
+    caller's own batched ``device_get`` followed by ``parts_of()``) runs at
+    present/merge time, after the lock's release.
+
+    ``ahead`` is the in-flight count as this dispatch entered it, ``tags``
+    the dispatch span's while :func:`dispatching` has it open. ``fetched()``
+    takes the dispatch out of the count, once; a handle dropped unfetched
+    (an error, or a route that gives up between dispatch and fetch) takes
     it out as it is collected."""
 
-    __slots__ = ("_owner", "_key", "ahead")
+    __slots__ = ("_owner", "_key", "ahead", "tags", "outs", "_answer",
+                 "_falls", "fall_tags")
 
     def __init__(self, owner: "InflightPrograms", key: int, ahead: int):
         self._owner, self._key, self.ahead = owner, key, ahead
+        self.tags: dict = {}
+        self.outs = self._answer = self._falls = None
+        self.fall_tags: dict = {}
 
     def fetched(self) -> None:
         self._owner._open.pop(self._key, None)      # atomic; idempotent
 
     __del__ = fetched
 
+    def holds(self, outs, answer, falls=None) -> "Dispatched":
+        """Take the dispatched program's device outputs (a pytree) and
+        ``answer``, the route's pure function from the FETCHED outputs to
+        what its consumer reads. ``falls`` is given by a program that counts
+        its fallen tiles: that count is the LAST of ``outs``, and
+        ``falls(fetched count)`` the tags it puts on the fetch span
+        (``fall_tiles``, and ``tiles`` for a line rate program), counted in
+        ``/metrics`` on the way."""
+        self.outs, self._answer, self._falls = outs, answer, falls
+        return self
+
+    def parts_of(self, fetched):
+        """The answer from ALREADY-FETCHED outputs: for a caller that
+        batches many handles into one ``device_get`` (the reduce node) and
+        opens the fetch span itself, adding up their ``fall_tags``."""
+        self.fetched()
+        if self._falls is not None:
+            *fetched, falls = fetched
+            self.fall_tags = self._falls(falls)
+        return self._answer(fetched)
+
+    def resolve(self):
+        """The blocking fetch, in its ``query.exec.kernel`` span."""
+        from .tracing import SPAN_QUERY_KERNEL, span    # imports this module
+        with span(SPAN_QUERY_KERNEL, phase="fetch") as tags:
+            answer = self.parts_of(jax.device_get(self.outs))
+            tags.update(self.fall_tags)
+        return answer
+
 
 class InflightPrograms:
     """The device queue as the host sees it: fused programs that were
     dispatched and whose result no one has fetched yet, process-wide. A
-    dispatch site takes ``dispatched()`` BEFORE it hands the program to the
-    device — ``ahead`` of the handle it gets is what the device runs before
-    this program — and keeps the handle with the program's result; the
-    fetch site calls ``fetched()`` on it. The flush's programs are not
-    counted: no one fetches them."""
+    dispatch takes ``dispatched()`` BEFORE it hands the program to the
+    device (:func:`dispatching`, the one caller) — ``ahead`` of the handle
+    it gets is what the device runs before this program — and the handle
+    keeps the program's result; its fetch calls ``fetched()``. The flush's
+    programs are not counted: no one fetches them."""
 
     def __init__(self):
         self._lock = threading.Lock()       # serializes count-then-insert
@@ -230,6 +275,21 @@ class InflightPrograms:
 
 
 inflight = InflightPrograms()
+
+
+@contextlib.contextmanager
+def dispatching(**tags):
+    """The one way a query's program goes to the device: a place in
+    ``inflight`` taken BEFORE the hand-over, and ``query.exec.kernel``
+    ``phase="dispatch"`` open around it with ``ahead`` and the site's own
+    ``tags``. Yields the :class:`Dispatched`; what a site learns only as it
+    chooses its program it adds to ``.tags`` inside the block, and the
+    program's outputs it gives to ``.holds()``."""
+    from .tracing import SPAN_QUERY_KERNEL, span        # imports this module
+    d = inflight.dispatched()
+    with span(SPAN_QUERY_KERNEL, phase="dispatch", ahead=d.ahead,
+              **tags) as d.tags:
+        yield d
 
 
 class DiagnosticsError(AssertionError):
@@ -482,12 +542,16 @@ class DonationDetective:
                 f"(donation #{self.count}) by:\n{self._last_site}")
 
 
-def explain_deleted_buffer(exc: BaseException, detective: DonationDetective):
+def explain_deleted_buffer(exc: BaseException,
+                           *detectives: DonationDetective):
     """If ``exc`` is jax's use-after-donation error AND diagnostics are on,
-    re-raise with the donation provenance attached; otherwise return False
-    (the production path re-raises the original exception untouched)."""
-    if not enabled or "Array has been deleted" not in str(exc):
+    re-raise with the donation provenance attached — of the store that
+    donated last, where a leaf held several; otherwise return False (the
+    production path re-raises the original exception untouched)."""
+    if (not enabled or not detectives
+            or "Array has been deleted" not in str(exc)):
         return False
+    detective = max(detectives, key=lambda d: d._last_when)
     raise RuntimeError(
         "use-after-donation: a captured device array was invalidated by a "
         "concurrent store mutation. Query code must capture arrays AND "

@@ -101,7 +101,9 @@ def main(argv=None) -> int:
         for _ in range(3):
             t0 = time.perf_counter()
             ps = [go() for _ in range(K)]
-            jax.block_until_ready([p._outs for p in ps])
+            # a tree before PR 48 keeps them in ``_outs``
+            jax.block_until_ready([getattr(p, "outs", None) or p._outs
+                                   for p in ps])
             best = min(best, (time.perf_counter() - t0) / K * 1e3)
         times.append(best)
         print(a.tag, name, round(best, 3), "ms",

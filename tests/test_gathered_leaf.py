@@ -391,7 +391,7 @@ def fused_off():
 def test_a_lazy_gathered_view_never_outlives_the_shard_lock(chain, fused_off,
                                                             monkeypatch):
     """Whatever follows the selection, the leaf's one program is dispatched
-    while its thread holds the shard lock, ``_execute_leaf`` hands back
+    while its thread holds the shard lock, the leaf's ``execute`` hands back
     nothing lazy, and a flush right after it — which donates the store's
     buffers — changes no answer."""
     ms, sh = build(False)
@@ -400,7 +400,7 @@ def test_a_lazy_gathered_view_never_outlives_the_shard_lock(chain, fused_off,
 
     held, leaves = [], []
     dispatch = qexec.GatheredWindow._dispatch
-    leaf = qexec.SelectRawPartitionsExec._execute_leaf
+    leaf = qexec.SelectRawPartitionsExec.execute
 
     def dispatch_seen(self, *a, **kw):
         held.append(sh.lock._is_owned())
@@ -423,7 +423,7 @@ def test_a_lazy_gathered_view_never_outlives_the_shard_lock(chain, fused_off,
         return out
 
     monkeypatch.setattr(qexec.GatheredWindow, "_dispatch", dispatch_seen)
-    monkeypatch.setattr(qexec.SelectRawPartitionsExec, "_execute_leaf",
+    monkeypatch.setattr(qexec.SelectRawPartitionsExec, "execute",
                         leaf_then_flush)
     tracer.drain()
     got = QueryEngine(ms, "prometheus").query_range(text, START, END, STEP)

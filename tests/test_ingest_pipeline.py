@@ -334,7 +334,7 @@ def test_windowed_producer_to_consumer_end_to_end(tmp_path):
             "num_shards": 1,
             "bus_addr": f"127.0.0.1:{broker.port}",
             "http": {"port": 0},
-            "ingest": {"publish_window": 8, "decode_ahead": 2},
+            "ingest": {"publish_window": 8},
             "store": {"max_series_per_shard": 64, "samples_per_series": 64,
                       "flush_batch_size": 10**9},
         })

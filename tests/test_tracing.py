@@ -4,6 +4,7 @@ intervals recorded after the fact; and the served query's spans through an
 in-process server: one root, the profiler's clock, lock wait and hold, the
 device queue ahead of a dispatch, the gc hook, the heartbeat."""
 
+import contextlib
 import gc
 import glob
 import json
@@ -288,8 +289,8 @@ BASE = 1_700_000_000_000
 N_SERIES, N_SAMPLES = 16, 90
 
 
-@pytest.fixture()
-def served():
+@contextlib.contextmanager
+def _served():
     """A FiloServer with the shipped defaults (scheduler, tracing on) and
     16 series in 4 groups on a 10 s grid; yields (server, get)."""
     from filodb_tpu.config import Config
@@ -321,6 +322,12 @@ def served():
         yield srv, get
     finally:
         srv.shutdown()
+
+
+@pytest.fixture()
+def served():
+    with _served() as srv_get:
+        yield srv_get
 
 
 def _trace_of_last_query():
@@ -388,26 +395,15 @@ def test_http_query_is_one_trace_rooted_at_the_request(served):
     assert disp.tags["ahead"] == 0 and diagnostics.inflight.count == 0
 
 
-@pytest.mark.parametrize("end_s, fall_tiles", [
-    (800, 0),     # every window inside the rows' 90 samples
-    (950, 1),     # the last ones past them: the one tile with rows in it
-])
-def test_fused_hist_route_is_a_leaf_with_its_parts_and_a_fetch(end_s,
-                                                               fall_tiles):
-    """The fused-hist route (query/engine.py ``_try_fused_hist``) records
-    what the ExecPlan leaf records: ``query.exec.leaf`` (tags
-    ``lock_wait_ms``, ``lock_hold_ms``) > select, group ids, kernel
-    dispatch (``ahead``, the tiled raw hist kernel's tags, ``packed``
-    among them), and the kernel's fetch
-    beside the leaf, after it and outside the lock (tag ``fall_tiles``: the
-    tiles that ran the correction matmul) — the sums the benchmark's means
-    are read from."""
+@contextlib.contextmanager
+def _hist_served():
+    """A FiloServer over one prom-histogram shard: 16 series in 4 groups,
+    8 buckets, 90 samples on a 10 s grid (dataset ``hists``)."""
     import numpy as np
 
     from filodb_tpu.config import Config
     from filodb_tpu.core.record import RecordBuilder
     from filodb_tpu.core.schemas import PROM_HISTOGRAM
-    from filodb_tpu.ops import fusedresident
     from filodb_tpu.standalone import FiloServer
     srv = FiloServer(Config({
         "num_shards": 1, "http": {"port": 0}, "dataset": "hists",
@@ -424,7 +420,27 @@ def test_fused_hist_route_is_a_leaf_with_its_parts_and_a_fetch(end_s,
                       np.cumsum(np.arange(8) + i + 1.0) * (t + 1))
         srv.memstore.ingest("hists", 0, b.build())
         srv.memstore.flush_all()
+        yield srv
+    finally:
+        srv.shutdown()
 
+
+@pytest.mark.parametrize("end_s, fall_tiles", [
+    (800, 0),     # every window inside the rows' 90 samples
+    (950, 1),     # the last ones past them: the one tile with rows in it
+])
+def test_fused_hist_route_is_a_leaf_with_its_parts_and_a_fetch(end_s,
+                                                               fall_tiles):
+    """The fused-hist route (query/engine.py ``_try_fused_hist``) records
+    what the ExecPlan leaf records: ``query.exec.leaf`` (tags
+    ``lock_wait_ms``, ``lock_hold_ms``) > select, group ids, kernel
+    dispatch (``ahead``, the tiled raw hist kernel's tags, ``packed``
+    among them), and the kernel's fetch
+    beside the leaf, after it and outside the lock (tag ``fall_tiles``: the
+    tiles that ran the correction matmul) — the sums the benchmark's means
+    are read from."""
+    from filodb_tpu.ops import fusedresident
+    with _hist_served() as srv:
         def get(shift_ms):
             q = urllib.parse.urlencode({
                 "query": "histogram_quantile(0.9, sum by (g)(rate(h[2m])))",
@@ -443,8 +459,6 @@ def test_fused_hist_route_is_a_leaf_with_its_parts_and_a_fetch(end_s,
         assert body["status"] == "success" \
             and body["stats"]["exec_path"] == path
         members = _trace_of_last_query()
-    finally:
-        srv.shutdown()
     by = {}
     for s in members:
         by.setdefault(s.name, []).append(s)
@@ -718,6 +732,112 @@ def test_mesh_leaf_tags_the_sum_over_its_locks_and_how_many():
     (disp,) = [k for k in by[SPAN_QUERY_KERNEL]
                if k.tags["phase"] == "dispatch"]
     assert disp.tags["ahead"] == 0 and diagnostics.inflight.count == 0
+
+
+def _engine_of(route, stack):
+    """(engine, promql, start) of one route to the device; servers and
+    stores leave with ``stack``."""
+    from filodb_tpu.query.engine import QueryEngine
+
+    from .test_distributed import START, build_f32_store
+    if route == "fused-hist":
+        srv = stack.enter_context(_hist_served())
+        return (srv.engines["hists"],
+                "histogram_quantile(0.9, sum by (g)(rate(h[2m])))", BASE)
+    if route.startswith("mesh"):
+        mesh, ms, _shards = build_f32_store()
+        return (QueryEngine(ms, "prometheus", mesh=mesh),
+                {"mesh": "sum by (grp)(rate(m[5m]))",
+                 "mesh-topk": "topk(2, rate(m[5m]))",
+                 "mesh-quantile": "quantile(0.5, rate(m[5m]))"}[route], START)
+    srv, _get = stack.enter_context(_served())
+    return (srv.engines["prometheus"],
+            {"fused": "sum by (g)(rate(m[2m]))",
+             # a narrow selection: its rows gathered, then the fused kernel
+             "gathered": 'sum by (host)(rate(m{g="g0"}[2m]))'}[route], BASE)
+
+
+@pytest.mark.parametrize("route, path, selects", [
+    ("fused", "local-fused[pallas-interpret]", 1),
+    ("gathered", "local-fused[pallas-interpret]", 1),
+    ("fused-hist", "fused-hist[pallas-interpret]", 1),
+    ("mesh", "mesh[pjit]-fused", 8),
+    ("mesh-topk", "mesh[pjit]-topk", 8),
+    ("mesh-quantile", "mesh[pjit]-sketch", 8),
+])
+def test_every_route_to_the_device_is_the_one_leaf_protocol(route, path,
+                                                            selects):
+    """The in-process leaf (wide and gathered), the fused-hist route and the
+    mesh route record the SAME tree (``exec.LeafFrame``, ``diagnostics
+    .dispatching`` / ``Dispatched``): ``query.exec.leaf`` with the lock's
+    wait and hold > select(s), group ids, ``kernel[dispatch]`` with
+    ``ahead``; then ``kernel[fetch]`` beside the leaf, after the locks'
+    release; and the in-flight count back where it started."""
+    with contextlib.ExitStack() as stack:
+        eng, promql, t0 = _engine_of(route, stack)
+        eng.result_cache = eng.fragment_cache = None
+        start, end = t0 + 300_000, t0 + 500_000
+        eng.query_range(promql, start, end, 20_000)         # compiled
+        assert diagnostics.inflight.count == 0
+        tracer.drain()
+        r = eng.query_range(promql, start + 1_000, end + 1_000, 20_000)
+        spans = tracer.drain()
+    assert r.exec_path == path and diagnostics.inflight.count == 0
+    by = {}
+    for s in spans:
+        by.setdefault(s.name, []).append(s)
+    (leaf,) = by[SPAN_QUERY_LEAF]
+    kern = {k.tags["phase"]: k for k in by[SPAN_QUERY_KERNEL]}
+    assert sorted(kern) == ["dispatch", "fetch"] \
+        and len(by[SPAN_QUERY_KERNEL]) == 2
+    disp, fetch = kern["dispatch"], kern["fetch"]
+    inside = by[SPAN_QUERY_SELECT] + [disp] + (
+        [] if route in ("mesh-topk", "mesh-quantile")       # no by()
+        else by[SPAN_QUERY_GROUPIDS])
+    assert len(by[SPAN_QUERY_SELECT]) == selects
+    assert all(s.parent_id == leaf.span_id for s in inside)
+    assert all(s.start_ns >= leaf.start_ns for s in inside)
+    assert sum(s.duration_us for s in inside) <= leaf.duration_us + 3
+    assert leaf.tags["lock_wait_ms"] == 0
+    assert 0 < leaf.tags["lock_hold_ms"] <= max(selects, 1) \
+        * (leaf.duration_us + 1) / 1e3
+    assert disp.tags["ahead"] == 0
+    assert fetch.parent_id == leaf.parent_id
+    assert fetch.start_ns >= leaf.start_ns + (leaf.duration_us - 2) * 1e3
+
+
+@pytest.mark.parametrize("promql, reason", [
+    ("topk(0, rate(m[5m]))", "topk_caps"),
+    ("quantile(0.5, rate(m[5m]))", "order_stat_caps"),
+])
+def test_a_mesh_leaf_that_gives_up_after_its_ticket_gives_the_place_back(
+        promql, reason, monkeypatch):
+    """The mesh's caps are met inside the dispatch span, after the place in
+    the in-flight count was taken: the route returns without a result, the
+    handle is dropped, the count is back, and the host path answers."""
+    from filodb_tpu.parallel import distributed
+    from filodb_tpu.query import exec as qexec
+    from filodb_tpu.query.engine import QueryEngine
+
+    from .test_distributed import START, build_f32_store
+    mesh, ms, _shards = build_f32_store()
+    eng = QueryEngine(ms, "prometheus", mesh=mesh)
+    monkeypatch.setattr(qexec.AggregateMapReduce, "ORDER_STAT_MAX_GROUPS", 0)
+    fell = []
+    monkeypatch.setattr(distributed, "count_mesh_fallback", fell.append)
+    assert diagnostics.inflight.count == 0
+    tracer.drain()
+    r = eng.query_range(promql, START + 300_000, START + 500_000, 20_000)
+    assert fell == [reason] and not r.exec_path.startswith("mesh")
+    assert diagnostics.inflight.count == 0
+    spans = tracer.drain()
+    # the mesh's leaf closed with its tags, and no fetch followed its dispatch
+    mesh_leaf = next(s for s in spans if s.name == SPAN_QUERY_LEAF
+                     and s.tags.get("route") == "mesh")
+    assert {"lock_wait_ms", "lock_hold_ms"} <= set(mesh_leaf.tags)
+    (disp,) = [s for s in spans if s.name == SPAN_QUERY_KERNEL
+               and s.parent_id == mesh_leaf.span_id]
+    assert disp.tags["phase"] == "dispatch" and disp.tags["ahead"] == 0
 
 
 def test_a_dispatch_is_in_flight_until_fetched_batched_or_dropped():
