@@ -35,6 +35,10 @@ from ..utils import diagnostics
 log = logging.getLogger(__name__)
 
 TS_PAD = np.int64(1) << np.int64(62)   # sentinel > any real timestamp
+# the stamp of a cell BEFORE a row's birth cell (see the text on how the
+# store keeps time): below any real stamp, so a row stays sorted and no
+# window or lookback ever reaches it
+TS_UNBORN = -(np.int64(1) << np.int64(62))
 
 
 class DecodeRefused(RuntimeError):
@@ -44,8 +48,23 @@ class DecodeRefused(RuntimeError):
 
 # How the store keeps time. While every series is scraped on one common grid
 # (sample k at ``first + k * interval``, every first stamp on the grid, no
-# scrape missed) column k of a row is the row's k-th sample, the s64 block
-# ``ts`` holds the stamps and the shard is in its GRID form. The first stamp
+# scrape missed) the s64 block ``ts`` holds the stamps and the shard is in
+# its GRID form. A scalar store that was not born narrow (``aligned``) keeps
+# that form in TIME-ALIGNED CELLS: column c is cell c of the shard's grid
+# for EVERY row, ``cell0 + c * interval``. A series that appears later (a
+# redeployed pod, a new label set) is written from its BIRTH CELL on,
+# ``born[row]`` (host mirror and device i32 beside ``n``); ``n[row]`` stays
+# the cells the row USES, its birth cell's predecessors among them, as it
+# counts holes in the line form. The cells before a birth hold
+# ``TS_UNBORN`` and 0.0 and are read by nothing: the fused and grid kernels
+# take ``born <= col < n`` as a row's samples, the general kernels find no
+# window that reaches a stamp that low. So the start cohort of such a store
+# is uniform by construction (``grid_cohorts``), one fused program answers
+# rows born at any number of cells, compaction shifts every row by the
+# same cells (``born`` with them) and a reused slot starts at its new
+# owner's birth cell. The other grid forms (a layout store, a store born
+# narrow) keep every row from column 0, where column k of a row is the
+# row's k-th sample and a late row is one more start COHORT. The first stamp
 # that is off that grid — a target with its own scrape phase, a scrape
 # stamped late — or the first missed scrape turns the scalar store into its
 # LINE form, once: column c is then CELL c of the row's line (``line0[row] +
@@ -198,6 +217,65 @@ def _free_rows(ts, n, pids):
     return ts, n
 
 
+@functools.partial(jax.jit, donate_argnums=(0,), static_argnums=(3,))
+def _mark_unborn(block, born_new, fill, carry: bool):
+    """The cells before a birth: every row with ``born_new[row] > 0`` (0: a
+    row this call leaves alone) is given ``fill`` in its columns below
+    that cell. ``carry``: the row's one sample sits in column 0 (it came
+    before the shard's interval was known) and moves to its birth cell.
+    Elementwise and donated: in place on a block of any size."""
+    col = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1)
+    b = born_new[:, None]
+    out = jnp.where(col < b, jnp.asarray(fill, block.dtype), block)
+    if carry:
+        out = jnp.where((col == b) & (b > 0), block[:, :1], out)
+    return out
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
+def _compact_aligned(ts, val, n, born, k):
+    """``_compact`` of a store in time-aligned cells: every row moves left
+    by the SAME ``k`` cells (one roll, no gather), ``born`` with it; a row
+    whose last cell is dropped is empty."""
+    new_n = jnp.maximum(n - k, 0)
+    new_born = jnp.maximum(born - k, 0)
+    gone = new_n <= new_born
+    new_n = jnp.where(gone, 0, new_n)
+    new_born = jnp.where(gone, 0, new_born)
+    col = jax.lax.broadcasted_iota(jnp.int32, ts.shape, 1)
+    kept = col < new_n[:, None]
+    ts = jnp.where(kept, jnp.roll(ts, -k, axis=1), TS_PAD)
+    ts = jnp.where(col < new_born[:, None], TS_UNBORN, ts)
+    val = jnp.where(kept & (col >= new_born[:, None]),
+                    jnp.roll(val, -k, axis=1), 0)
+    return ts, val, new_n, new_born
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1), static_argnums=(4,))
+def _unalign_block(val, n, born, r0, rows):
+    """Rows ``r0 .. r0 + rows`` of a value block in time-aligned cells
+    moved left to their own column 0 (the line form's layout: column k is
+    the row's k-th cell), their counts less their birth cells."""
+    C = val.shape[1]
+    zero = jnp.zeros((), r0.dtype)
+    blk = jax.lax.dynamic_slice(val, (r0, zero), (rows, C))
+    kb = jax.lax.dynamic_slice(born, (r0,), (rows,))
+    nb = jax.lax.dynamic_slice(n, (r0,), (rows,))
+    return (jax.lax.dynamic_update_slice(val, _shift_left(blk, kb, nb),
+                                         (r0, zero)),
+            jax.lax.dynamic_update_slice(n, nb - kb, (r0,)))
+
+
+@jax.jit
+def _close_births(ts, val, n, born):
+    """(ts, val, n) with every row's samples a sorted prefix: a store in
+    time-aligned cells moved left by its birth cells (``closed_arrays``)."""
+    keep = n - born
+    col = jax.lax.broadcasted_iota(jnp.int32, ts.shape, 1)
+    return (jnp.where(col < keep[:, None], _shift_left(ts, born, n), TS_PAD),
+            _shift_left(val, born, n), keep)
+
+
 def _pow2_rows(m: int) -> int:
     """The next power of two at or over ``m``: the shapes of the pool's
     few-row programs."""
@@ -314,7 +392,8 @@ PREFIX_MAX = float(1 << 23)
 # rows a blocked pass of the in-place form handles at a time: its
 # temporaries are a block's, at most 2^15 x C x (1 B shifted + 4 B index)
 BLOCK_ROWS = 1 << 15
-REHYDRATE_CAUSES = ("append", "compact", "free", "off_grid", "cohort_gate")
+REHYDRATE_CAUSES = ("append", "compact", "free", "off_grid", "cohort_gate",
+                    "births")
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
@@ -484,12 +563,23 @@ def _gather_grid(val, n, picked, C):
     stamps derived (``SeriesStore.grid_row_gather``): ``picked`` is int64
     ``[3, P]`` — store rows, each row's first stamp (-1: a pad row, or one
     without a sample), the interval — one upload for all a gather needs
-    from the host."""
+    from the host. ``[4, P]`` where the store holds a row born late
+    (``SeriesStore.born_late``): ``(ts, val, n, born)``."""
     rid = picked[0].astype(jnp.int32)
     first = picked[1]
     n_g = jnp.where(first >= 0, jnp.take(n, rid), 0).astype(jnp.int32)
-    return (_derive_ts_impl(first, n_g, picked[2, 0], C),
-            jnp.take(val, rid, axis=0), n_g)
+    if picked.shape[0] == 3:
+        return (_derive_ts_impl(first, n_g, picked[2, 0], C),
+                jnp.take(val, rid, axis=0), n_g)
+    # time-aligned cells with late births: a fourth row, the birth cells.
+    # The rows stay in their cells (the grid kernel reads ``born <= col <
+    # n``, and ``born`` comes back beside ``n``); the stamps start at the
+    # birth cell, TS_UNBORN before it
+    born = jnp.where(first >= 0, picked[3], 0).astype(jnp.int32)
+    col = jax.lax.broadcasted_iota(jnp.int64, (first.shape[0], C), 1)
+    ts = _derive_ts_impl(first - born * picked[2, 0], n_g, picked[2, 0], C)
+    return (jnp.where(col < born[:, None], TS_UNBORN, ts),
+            jnp.take(val, rid, axis=0), n_g, born)
 
 
 @functools.partial(jax.jit, static_argnums=(6,))
@@ -675,6 +765,22 @@ class SeriesStore:
                     self.extra[nm] = jax.device_put(
                         jnp.zeros((S, capacity), dtype), dev)
         self.n = jax.device_put(jnp.zeros(S, jnp.int32), dev)
+        # time-aligned cells (see the text on how the store keeps time):
+        # the form of a scalar store that was not born narrow, for as long
+        # as it is on the grid. ``born`` is each row's birth cell (host;
+        # ``born_dev`` its device twin, uploaded when it changed),
+        # ``born_late`` the rows that hold one past 0 — the STATE that
+        # picks the kernels' births mode, as ``hole_cells`` picks the hole
+        # mode — ``_cell0_off`` the cells compaction has dropped (column
+        # 0's stamp is ``grid_base + _cell0_off * interval``) and
+        # ``births`` what /metrics counts: rows given a birth cell past 0,
+        # and late rows that a form without birth cells took as a minority
+        self.aligned = not nbuckets and layout is None and not born_narrow
+        self.born = np.zeros(S, np.int32)
+        self._born_dev = self._late_mask = None
+        self.born_late = 0
+        self._cell0_off = 0
+        self.births = {"aligned": 0, "minority": 0}
         # host mirrors: ingest-path bookkeeping without device->host syncs
         self.n_host = np.zeros(S, np.int32)
         self.last_ts = np.full(S, -(1 << 62), np.int64)
@@ -924,6 +1030,11 @@ class SeriesStore:
         the data itself (not eligibility) caused the None."""
         prep_val = None
         self.residency_decline = None
+        if self.born_late:
+            # the narrow forms and the derived stamps read every row from
+            # column 0: a store that holds a row born late stays raw
+            self.residency_decline = "births"
+            return None
         if self._narrow is None and self._nhist is None:
             if self.dtype != jnp.float32 or self.val is None:
                 return None
@@ -1212,6 +1323,11 @@ class SeriesStore:
         occ = np.arange(len(r)) - np.repeat(
             boundaries, np.diff(np.concatenate([boundaries, [len(r)]])))
         cols = self.n_host[r] + occ
+        late = None
+        if len(r) and self.aligned and self.res is None and self.grid_ok:
+            # time-aligned cells: a row this batch starts is written from
+            # its birth cell on
+            cols, late = self._place_births(r, t, occ, cols, boundaries)
         over = cols >= self.C
         if over.any():
             self.stats.capacity_dropped += int(over.sum())
@@ -1219,12 +1335,20 @@ class SeriesStore:
             occ = occ[~over]
             stale = None if stale is None else stale[~over]
         m = len(r)
-        if m == 0:
+        if m == 0 and late is None:
             return 0
         inplace = self._inplace
+        if inplace and late is not None:
+            # the delta form in place reads every row from column 0
+            self._rehydrate("births")
+            inplace = False
         if not inplace:
             self._rehydrate("append")   # mutations write the raw f32 block
         self._pre_donate("SeriesStore.append")
+        if late is not None:
+            self._settle_births(*late)
+        if m == 0:
+            return 0
         # host bookkeeping
         # (the batch is sorted by row: its rows are its runs)
         first_pos = np.flatnonzero(np.concatenate([[True], r[1:] != r[:-1]]))
@@ -1237,6 +1361,14 @@ class SeriesStore:
         self.line0[newly] = self.first_ts[newly]
         if len(newly):
             self._cohorts = None   # new starts can change the cohort summary
+            if self.grid_interval and not (self.aligned and self.res is None):
+                # a form without birth cells: a row that starts a whole
+                # interval or more after the oldest one is a minority
+                live = self.n_host > 0
+                if live.any():
+                    self.births["minority"] += int(
+                        (self.first_ts[newly] - self.first_ts[live].min()
+                         >= self.grid_interval).sum())
         # line form: the stamp block written below is the residual block
         # (markers and runs past the bound are named only where there are
         # any: a batch without them is held as it ever was)
@@ -1344,6 +1476,101 @@ class SeriesStore:
         self.stats.samples_appended += ingested
         self._appends_since_sync += 1
         return ingested
+
+    def _cell0(self) -> int:
+        """The stamp of column 0 of a store in time-aligned cells."""
+        return int(self.grid_base) + self._cell0_off * int(self.grid_interval)
+
+    @property
+    def born_dev(self):
+        """``born`` on the device, i32 [S]: uploaded once a change."""
+        if self._born_dev is None:
+            self._born_dev = jax.device_put(self.born.copy(),
+                                            next(iter(self.n.devices())))
+        return self._born_dev
+
+    def late_mask(self) -> np.ndarray:
+        """bool [S]: the rows born past the grid's first cell, one pass per
+        change of ``born`` (kept beside the device copy)."""
+        if self._late_mask is None:
+            self._late_mask = self.born > 0
+        return self._late_mask
+
+    def _place_births(self, r, t, occ, cols, first_pos):
+        """Time-aligned cells: the rows a sorted batch STARTS are written
+        from their birth cell — the cell of their first stamp on the
+        shard's grid — so from here on they count as rows that have used
+        the cells before it (``n_host = born``: every later rule of the
+        append, the next column, the line, the phase, holds for them as
+        for a row that was always there). Host bookkeeping only; returns
+        the batch's columns and, where a row was placed past cell 0, what
+        ``_settle_births`` does on the device. The shard's rule for its
+        interval is the store's own (``_interval_of``: the first batch that
+        shows a second sample of any series): a row that came BEFORE it was
+        known sits in column 0 with its one sample and is moved to its cell
+        now (``early``). A first stamp that is on no cell of the grid,
+        before cell 0 or past the last column (the capacity of such a store
+        is a span of TIME) is left as it came: ``_track_stamps`` then turns
+        the store to its line form, where a row starts where it starts."""
+        if self.grid_base is None:
+            self.grid_base = int(t.min())
+        uniq = r[first_pos]
+        iv = self.grid_interval
+        if iv is None:
+            iv = self._interval_of(r, t, uniq, first_pos)
+            if iv is None or iv <= 0:
+                return cols, None
+            self.grid_interval = iv
+            self._phases_due = True
+        cell0 = self._cell0()
+
+        def placed(rows, first):
+            """(rows, cells) of those whose first stamp has a cell past 0."""
+            d = first - cell0
+            b = d // iv
+            keep = (d % iv == 0) & (b > 0) & (b < self.C)
+            return rows[keep], b[keep].astype(np.int32), keep
+
+        early = early_b = None
+        if self._phases_due:
+            rows = np.flatnonzero((self.n_host == 1) & (self.line0 != cell0))
+            rows, b, _keep = placed(rows, self.line0[rows])
+            if len(rows):
+                early, early_b = rows, b
+                self.born[early] = early_b
+                self.n_host[early] = early_b + 1
+                self.line0[early] = cell0
+        fresh = np.flatnonzero(self.n_host[uniq] == 0)
+        rows, b, keep = placed(uniq[fresh], t[first_pos[fresh]])
+        if len(rows):
+            self.born[rows] = b
+            self.n_host[rows] = b
+            self.line0[rows] = cell0
+            self.first_ts[rows] = t[first_pos[fresh]][keep]
+        if early is None and not len(rows):
+            return cols, None
+        placed = len(rows) + (0 if early is None else len(early))
+        self.born_late += placed
+        self.births["aligned"] += placed
+        self._born_dev = self._late_mask = self._cohorts = None
+        return self.n_host[r] + occ, (rows, b, early, early_b)
+
+    def _settle_births(self, rows, b, early, early_b) -> None:
+        """The device's part of ``_place_births``, before the batch is
+        written: the cells before each birth marked (TS_UNBORN, 0.0 — what
+        a reused slot's old owner left there goes), a row that came early
+        moved to its cell, and the counts raised by the cells skipped."""
+        add = np.zeros(self.S, np.int32)
+        for who, cell, carry in ((rows, b, False), (early, early_b, True)):
+            if who is None or not len(who):
+                continue
+            vec = np.zeros(self.S, np.int32)
+            vec[who] = cell
+            add[who] = cell
+            vec = jnp.asarray(vec)
+            self.ts = _mark_unborn(self.ts, vec, TS_UNBORN, carry)
+            self.val = _mark_unborn(self.val, vec, 0, carry)
+        self.n = _add_counts(self.n, jnp.asarray(add))
 
     def _append_delta(self, r, cols, v, occ, uniq, first_pos, last,
                       counts) -> None:
@@ -1711,15 +1938,20 @@ class SeriesStore:
             self._phases_due = False
         off = t - (self.line0[r] + cols.astype(np.int64) * iv)
         if self.res is None:
+            # every line on the shard's phase; in time-aligned cells every
+            # line IS the grid's (a row that starts before cell 0 has none)
+            stray = (self.line0[phases] != self._cell0() if self.aligned
+                     else (self.line0[phases] - self.grid_base) % iv)
             if (not off.any() and stale is None
                     and (cols == self._next_cols(r, cols)).all()
-                    and not ((self.line0[phases] - self.grid_base)
-                             % iv).any()):
+                    and not stray.any()):
                 return None
             if self.nbuckets or self.layout is not None:
                 self.grid_ok = False
                 return None
-            self._to_line()
+            moved = self._to_line()
+            if moved is not None:       # the rows left their aligned cells
+                cols -= moved[r].astype(cols.dtype)
         shift = (off + iv // 2) // iv          # cells off the given column
         fits = (shift == 0) & (np.abs(off) <= RES_MAX)
         if gap is not None:
@@ -1746,11 +1978,28 @@ class SeriesStore:
         return np.where(hole, RES_HOLE, np.where(out, 0, off)).astype(
             RES_DTYPE)
 
-    def _to_line(self) -> None:
+    def _to_line(self):
         """Grid form -> line form, once: every stamp so far is ON its row's
         line (the grid invariant), so the residual block starts as zeros
-        and the s64 block is dropped."""
+        and the s64 block is dropped. Returns the cells each row moved left
+        by where the store held rows born late (None: none did)."""
         dev = next(iter(self.n.devices()))
+        moved = None
+        if self.born_late:
+            # out of the time-aligned cells: the line form holds every row
+            # from its own column 0, its line starting at its first stamp
+            moved = self.born
+            S = self.S
+            rows = S if S <= BLOCK_ROWS else min(BLOCK_ROWS, S & -S)
+            for r0 in range(0, S, rows):
+                self.val, self.n = _unalign_block(
+                    self.val, self.n, self.born_dev,
+                    jax.device_put(np.int32(r0), dev), rows)
+            self.n_host = self.n_host - moved
+            self.line0 = self.first_ts.copy()
+            self.births["minority"] += self.born_late
+            self.born = np.zeros(S, np.int32)
+            self.born_late, self._born_dev, self._late_mask = 0, None, None
         self.res = jax.device_put(jnp.zeros((self.S, self.C), RES_DTYPE), dev)
         self.ts = None
         self.grid_ok = False
@@ -1759,6 +2008,7 @@ class SeriesStore:
                  "(%d rows live, interval %d ms, residual %s)",
                  int((self.n_host > 0).sum()), self.grid_interval,
                  np.dtype(RES_DTYPE).name)
+        return moved
 
     def _demote(self, rows: np.ndarray, reasons: np.ndarray) -> None:
         """Take ``rows`` off their lines: their exact stamps so far (line +
@@ -1819,11 +2069,16 @@ class SeriesStore:
         (common interval, on-grid timestamps, per-series contiguity), else None.
 
         Series may START at different grid cells — churn (a new pod appearing
-        mid-stream) no longer demotes the shard: per-series start cells come
-        from :meth:`grid_offsets`, and the query layer runs the band-matmul
-        path on the majority start cohort, correcting minority rows via the
-        general kernels. Compaction shifts every row's offset uniformly, so
-        the majority cohort survives it."""
+        mid-stream) does not take the shard off the grid. A store in
+        time-aligned cells (``aligned``: see the text on how the store keeps
+        time) writes such a row from its birth cell, ``born[row]``, and its
+        start cohort stays uniform: the query layer runs ONE band program in
+        its births mode (``born_late``) and corrects nothing. The other grid
+        forms (a layout store, a store born narrow) hold every row from
+        column 0: per-series start cells come from :meth:`grid_offsets`, and
+        the query layer runs the band-matmul path on the majority start
+        cohort, correcting minority rows via the general kernels. Compaction
+        shifts every row's offset uniformly, so either rule survives it."""
         if not self.grid_ok or not self.grid_interval:
             return None
         if not (self.n_host > 0).any():
@@ -1884,20 +2139,27 @@ class SeriesStore:
     def grid_row_picks(self, rows: np.ndarray, live: int) -> np.ndarray:
         """All that ``_gather_grid`` needs from the host, int64 ``[3, P]``:
         the pow2-padded row ids ``rows``, each row's first stamp (-1 past
-        the first ``live``: a pad row) and the interval. A host array: a
+        the first ``live``: a pad row) and the interval — and, where the
+        store holds a row born late, each row's birth cell. A host array: a
         leaf that composes the gather into its one program
         (query/exec.py ``GatheredRows``) hands it over as that program's
         argument."""
-        picked = np.full((3, len(rows)), -1, np.int64)
+        picked = np.full((4 if self.born_late else 3, len(rows)), -1,
+                         np.int64)
         picked[0] = rows
         picked[1, :live] = self.first_ts[rows[:live]]
         picked[2] = self._interval()
+        if self.born_late:      # a fourth row: the birth cells
+            picked[3] = self.born[rows]
         return picked
 
     def grid_offsets(self, rows: np.ndarray) -> np.ndarray:
-        """Start cell of each given row (its first sample's grid cell index
-        relative to ``grid_base``); 0 for empty rows."""
+        """Start cell of each given row (the grid cell of its column 0
+        relative to ``grid_base``: its first sample's, or, in time-aligned
+        cells, the shard's own for every row); 0 for empty rows."""
         first = self.first_ts[rows]
+        if self.aligned:        # every row's cells start at the grid's
+            return np.where(first >= 0, self._cell0_off, 0).astype(np.int64)
         return np.where(first >= 0,
                         (first - self.grid_base) // self.grid_interval,
                         0).astype(np.int64)
@@ -1911,6 +2173,9 @@ class SeriesStore:
             live = self.n_host > 0
             if not live.any():
                 self._cohorts = ("uniform", 0)
+            elif self.aligned and self.grid_interval:
+                # time-aligned cells: uniform by construction
+                self._cohorts = ("uniform", self._cell0_off)
             else:
                 offs = self.grid_offsets(np.arange(self.S))
                 lv = offs[live]
@@ -1962,6 +2227,9 @@ class SeriesStore:
             return self._compact_delta(int(cutoff_ts))
         self._rehydrate("compact")     # the shift gathers the raw f32 block
         self._pre_donate("SeriesStore.compact")
+        if self.aligned and self.res is None and self.grid_ok \
+                and self.grid_interval:
+            return self._compact_cells(int(cutoff_ts))
         line = self.res is not None
         old_n = self.n_host
         if self.extra:
@@ -1981,6 +2249,28 @@ class SeriesStore:
             new_first = np.array(self.ts[:, 0])
             self.first_ts = np.where(self.n_host > 0, new_first, -1)
             self.line0 = self.first_ts.copy()
+        self._cohorts = None
+        self.stats.compactions += 1
+
+    def _compact_cells(self, cutoff_ts: int) -> None:
+        """``compact`` in time-aligned cells: the cells before the first
+        one at or after ``cutoff_ts`` go, the same number for every row
+        (``_compact_aligned``), and ``born`` moves with them."""
+        iv = int(self.grid_interval)
+        k = int(np.clip(-(-(cutoff_ts - self._cell0()) // iv), 0, self.C))
+        if k:
+            self.ts, self.val, self.n, born = _compact_aligned(
+                self.ts, self.val, self.n, self.born_dev, jnp.int32(k))
+            self._cell0_off += k
+            n, b = np.maximum(self.n_host - k, 0), np.maximum(self.born - k, 0)
+            kept = n > b
+            self.n_host = np.where(kept, n, 0).astype(np.int32)
+            self.born = np.where(kept, b, 0).astype(np.int32)
+            self._born_dev, self._late_mask = born, None
+            self.born_late = int((self.born > 0).sum())
+            self.first_ts = np.where(
+                kept, self._cell0() + self.born.astype(np.int64) * iv, -1)
+            self.line0 = np.where(kept, self._cell0(), -1)
         self._cohorts = None
         self.stats.compactions += 1
 
@@ -2099,6 +2389,11 @@ class SeriesStore:
         self.first_ts[part_ids] = -1
         self.line0[part_ids] = -1
         self.last_ts[part_ids] = -(1 << 62)
+        late = int((self.born[part_ids] > 0).sum())
+        if late:        # a reused slot starts at its next owner's birth cell
+            self.born[part_ids] = 0
+            self.born_late -= late
+            self._born_dev = self._late_mask = None
         self._cohorts = None
 
     # -- query access -------------------------------------------------------
@@ -2131,6 +2426,8 @@ class SeriesStore:
         """int32 [S]: the samples each row holds — its used cells less its
         holes; what ``closed_arrays`` / ``snapshot_arrays`` rows are cut
         to."""
+        if self.born_late:
+            return self.n_host - self.born
         return self.n_host - self.holes_host if self.hole_cells \
             else self.n_host
 
@@ -2142,6 +2439,13 @@ class SeriesStore:
         v = self.column_array(column)
         if isinstance(v, _Deferred):
             v = v.materialize()
+        if self.born_late:
+            # time-aligned cells: one shift per state of the store
+            kept = self._closed
+            if kept is None or kept[0] != column or kept[1] is not self.n:
+                kept = self._closed = (column, self.n, _close_births(
+                    self.ts_block(), v, self.n, self.born_dev))
+            return kept[2]
         if not self.hole_cells:
             return self.ts_block(), v, self.n
         kept = self._closed

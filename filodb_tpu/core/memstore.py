@@ -1571,8 +1571,9 @@ class TimeSeriesShard:
         for i, p in enumerate(pids):
             p = int(p)
             cnt = int(self.store.n_host[p])
-            hot_t = np.asarray(ts_host[i, :cnt])
-            hot_v = np.asarray(val_host[i, :cnt])
+            b = int(self.store.born[p])     # the cells before a birth: none
+            hot_t = np.asarray(ts_host[i, b:cnt])
+            hot_v = np.asarray(val_host[i, b:cnt])
             if self.store.hole_cells:       # a hole is no sample
                 hot_t, hot_v = hot_t[hot_t < TS_PAD], hot_v[hot_t < TS_PAD]
             boundary = hot_t[0] if len(hot_t) else (1 << 62)

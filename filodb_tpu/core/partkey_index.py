@@ -129,6 +129,10 @@ class PartKeyIndex:
         # (two O(S) gathers per query) is provably a no-op
         self._max_start = -(1 << 62)
         self._num_ended = 0
+        # bumps where an entry's end time moves without a postings mutation
+        # (``update_end_time``): with ``epoch`` it says that every start and
+        # end time is what it was (a kept time-masked selection's validity)
+        self._time_epoch = 0
         # regex fast path (ref: PartKeyLuceneIndex automata over TERMS, :34):
         # matchers evaluate against each label's DISTINCT value pool, never
         # per series. The trigram pre-filter narrows to terms carrying the
@@ -177,6 +181,11 @@ class PartKeyIndex:
         """Bumps on any postings mutation: what a cached filter result, here
         or in the shard's selection memo, is validated against."""
         return self._epoch
+
+    @property
+    def time_epoch(self) -> int:
+        """Bumps when an end time moves and ``epoch`` does not."""
+        return self._time_epoch
 
     def all_live_through(self, end_time: int) -> bool:
         """True when no entry has ever ended and none starts after
@@ -432,6 +441,7 @@ class PartKeyIndex:
         if was_live != (end_time == self.LIVE_END):
             self._num_ended += 1 if was_live else -1
         self._end[part_id] = end_time
+        self._time_epoch += 1
 
     def start_time(self, part_id: int) -> int:
         return self._start[part_id]
@@ -599,6 +609,25 @@ class PartKeyIndex:
     def part_ids_from_filters(self, filters: list[Filter], start_time: int,
                               end_time: int, limit: int | None = None) -> np.ndarray:
         """Part ids matching all filters and alive in [start_time, end_time]."""
+        result = self.part_ids_in_span(filters, start_time, end_time)[0]
+        if limit is not None:
+            result = result[:limit]
+        return result.astype(np.int32)
+
+    def part_ids_in_span(self, filters: list[Filter], start_time: int,
+                         end_time: int) -> tuple[np.ndarray, tuple | None]:
+        """(the part ids matching all filters and alive in [start_time,
+        end_time], the SPAN of query ranges that select the same ids).
+        The span is None where the time mask is the identity
+        (``all_live_through(end_time)``: any range that says the same
+        selects the same), else ``(end_lo, end_hi, start_lo, start_hi)``:
+        the newest start time the mask let in and the oldest it kept out,
+        the newest end time it kept out and the oldest it let in — a range
+        with ``end_lo <= end < end_hi`` and ``start_lo < start <= start_hi``
+        meets every matching entry's start and end on the same side, so it
+        selects these ids for as long as ``epoch`` and ``time_epoch``
+        stand. Four reductions over the two gathers the mask has made
+        anyway."""
         ckey = tuple(filters)
         hit = self._filter_cache.get(ckey)
         if hit is not None and hit[0] == self._epoch:
@@ -609,13 +638,18 @@ class PartKeyIndex:
             if len(self._filter_cache) > 512:
                 self._filter_cache.clear()
             self._filter_cache[ckey] = (self._epoch, result)
-        if len(result) and not self.all_live_through(end_time):
-            starts = self._start.view()[result]
-            ends = self._end.view()[result]
-            result = result[(starts <= end_time) & (ends >= start_time)]
-        if limit is not None:
-            result = result[:limit]
-        return result.astype(np.int32)
+        if not len(result) or self.all_live_through(end_time):
+            return result, None
+        starts = self._start.view()[result]
+        ends = self._end.view()[result]
+        born = starts <= end_time
+        alive = ends >= start_time
+        lo, hi = -(1 << 62), self.LIVE_END
+        span = (int(np.max(starts, where=born, initial=lo)),
+                int(np.min(starts, where=~born, initial=hi)),
+                int(np.max(ends, where=~alive, initial=lo)),
+                int(np.min(ends, where=alive, initial=hi)))
+        return result[born & alive], span
 
     def _eval_filters(self, filters: list[Filter]) -> np.ndarray:
         """Postings set algebra for a filter set (no time masking — results

@@ -13,20 +13,29 @@ shard already keeps say that nothing they depend on has moved:
 - ``TimeSeriesShard._release_epoch`` — any slot released (purge, eviction),
   so the slot-epoch snapshot below is what a fresh gather would read;
 - the store's row count;
-- and, per query, that the time mask is the identity
-  (``PartKeyIndex.all_live_through``): no series has ended and none starts
-  after the query's end.
+- ``PartKeyIndex.time_epoch`` — an end time moved (the purge's marks);
+- and, per query, that its range meets every matching series' start and
+  end on the side the kept selection's range met them: where the time mask
+  is the identity (``PartKeyIndex.all_live_through``: no series has ended
+  and none starts after the query's end) any range that says the same;
+  where it bites, the SPAN of ranges ``PartKeyIndex.part_ids_in_span``
+  gave with the ids — on a fleet that redeploys a selector has one kept
+  selection per set of births and ends its queries' ranges see (twelve
+  update events in two hours: a handful), each with its own groupings and
+  row mask.
 
 Only selections that stay part ids at the leaf are kept (wider than the
 caller's ``keep_over``): a narrow one is a few keys and a gather. Nothing
 here is configured; what does not fit takes the path it took before, and
 ``filodb_selection_memo_total{outcome="bypass", reason=...}`` says why.
 
-Bounds: ``SELECTIONS`` selectors a shard, ``GROUPINGS`` groupings a
-selector, both LRU. At 2^20 series a selector holds 8 MB on the host (part
-ids, slot epochs) and a grouping 8 MB on the host (group id per series, the
-dense row array) and 4 MB on the device: at most 4 x (8 + 4 x 8) = 160 MB
-of host memory and 64 MB of HBM a shard.
+Bounds: ``SELECTIONS`` kept selections a shard (a selector under one span
+of ranges each), ``GROUPINGS`` groupings a selection, both LRU. At 2^20
+series a selection holds 8 MB on the host (part ids, slot epochs; 1 MB more
+and 1 MB on the device where it is not the whole shard: its row mask) and
+a grouping 8 MB on the host (group id per series, the dense row array) and
+4 MB on the device: at most 4 x (9 + 4 x 8) = 164 MB of host memory and
+68 MB of HBM a shard.
 
 Everything is read and written under the shard lock the caller holds.
 """
@@ -40,8 +49,8 @@ import numpy as np
 from ..utils.diagnostics import assert_owned
 from ..utils.metrics import FILODB_SELECTION_MEMO, registry
 
-SELECTIONS = 4      # selectors a shard keeps
-GROUPINGS = 4       # (by, without) groupings a selector keeps
+SELECTIONS = 4      # selections a shard keeps (a selector under one span each)
+GROUPINGS = 4       # (by, without) groupings a selection keeps
 
 
 def count_memo(part: str, outcome: str, reason: str | None = None) -> None:
@@ -88,19 +97,26 @@ class ShardSelection:
     them. ``stamp`` is the index state it was built in, None for a selection
     the shard does not keep (it then lives as long as its query)."""
 
-    __slots__ = ("shard", "pids", "is_all", "stamp", "_release", "_epochs",
-                 "_groupings", "_cells")
+    __slots__ = ("shard", "pids", "is_all", "stamp", "why", "_release",
+                 "_epochs", "_groupings", "_cells", "_rows", "_late")
 
-    def __init__(self, shard, pids: np.ndarray, stamp=None):
+    def __init__(self, shard, pids: np.ndarray, stamp=None, why=None):
         self.shard = shard
         # a view: a caller's own array stays the caller's to write
         self.pids = _frozen(np.asarray(pids, np.int32).view())
         self.is_all = len(self.pids) == len(shard.index)
         self.stamp = stamp
+        # why the select that built it was no hit — ``recovering``, else
+        # ``time_mask`` (it ran the index's pass over every matching
+        # entry's start and end time; kept for its span unless narrow),
+        # else ``narrow``: the select span's ``memo_why``
+        self.why = why
         self._release = shard._release_epoch
         self._epochs = None
         self._groupings: OrderedDict = OrderedDict()
         self._cells = None      # (store state, hole cells, used cells)
+        self._rows = None       # (S, host [S], device [S]): the row mask
+        self._late = None       # (the store's late mask, selected rows in it)
 
     def cells(self, store) -> tuple[int, int]:
         """(hole cells, used cells) of the selected rows, from the counts
@@ -113,6 +129,28 @@ class ShardSelection:
             kept = self._cells = (state, int(store.holes_host[rows].sum()),
                                   int(store.n_host[rows].sum()))
         return kept[1], kept[2]
+
+    def row_mask(self, S: int):
+        """(host, device) bool ``[S]``, true at the selected rows: what a
+        wide leaf zeroes the other rows' counts with. Built on first use,
+        rebuilt when the store's height changes."""
+        m = self._rows
+        if m is None or m[0] != S:
+            import jax.numpy as jnp
+            host = np.zeros(S, bool)
+            host[self.pids] = True
+            m = self._rows = (S, _frozen(host), jnp.asarray(host))
+        return m[1], m[2]
+
+    def late_rows(self, late: np.ndarray) -> int:
+        """How many of the selected rows ``late`` marks (the store's
+        ``late_mask()``: bool ``[S]``, another array whenever a birth cell
+        moved): one pass per such array, not one per query."""
+        kept = self._late
+        if kept is None or kept[0] is not late:
+            rows = self.row_mask(len(late))[0]
+            kept = self._late = (late, int(np.count_nonzero(rows & late)))
+        return kept[1]
 
     def snapshot(self) -> None:
         """Capture the slot epochs of the selected series (once; a kept
@@ -164,29 +202,41 @@ class SelectionMemo:
         sh = self._shard
         assert_owned(sh.lock, "selection")
         idx = sh.index
-        why = None
-        if sh.recovering:
-            why = "recovering"
-        elif not idx.all_live_through(end_ms):
-            why = "time_mask"
         key = tuple(filters)
-        stamp = (idx.epoch, sh._release_epoch,
+        stamp = (idx.epoch, idx.time_epoch, sh._release_epoch,
                  sh.store.S if sh.store is not None else 0)
-        if why is None:
-            kept = self._kept.get(key)
-            if kept is not None and kept.stamp == stamp:
-                self._kept.move_to_end(key)
-                count_memo("select", "hit")
-                return kept, "hit"
-        pids = idx.part_ids_from_filters(filters, start_ms, end_ms)
-        if why is None and len(pids) <= keep_over:
-            why = "narrow"
-        if why is not None:
+        if not sh.recovering:
+            masked = not idx.all_live_through(end_ms)
+            for at, kept in self._kept.items():
+                if (at[0] == key and kept.stamp == stamp
+                        and _spans(at[1], masked, start_ms, end_ms)):
+                    self._kept.move_to_end(at)
+                    count_memo("select", "hit")
+                    return kept, "hit"
+        pids, span = idx.part_ids_in_span(filters, start_ms, end_ms)
+        pids = pids.astype(np.int32, copy=False)
+        narrow = len(pids) <= keep_over
+        why = ("recovering" if sh.recovering
+               else "time_mask" if span is not None
+               else "narrow" if narrow else None)
+        if sh.recovering or narrow:
             count_memo("select", "bypass", why)
-            return ShardSelection(sh, pids), "bypass"
-        sel = self._kept[key] = ShardSelection(sh, pids, stamp)
-        self._kept.move_to_end(key)
+            return ShardSelection(sh, pids, why=why), "bypass"
+        for at in [at for at, kept in self._kept.items()
+                   if kept.stamp != stamp]:
+            del self._kept[at]          # of a state that does not come back
+        sel = self._kept[key, span] = ShardSelection(sh, pids, stamp, why)
         if len(self._kept) > SELECTIONS:
             self._kept.popitem(last=False)
-        count_memo("select", "miss")
+        count_memo("select", "miss", why)
         return sel, "miss"
+
+
+def _spans(span, masked: bool, start_ms: int, end_ms: int) -> bool:
+    """Does a kept selection's span (``PartKeyIndex.part_ids_in_span``)
+    hold the range [start_ms, end_ms]? ``masked``: the time mask bites at
+    ``end_ms``."""
+    if span is None:
+        return not masked
+    end_lo, end_hi, start_lo, start_hi = span
+    return end_lo <= end_ms < end_hi and start_lo < start_ms <= start_hi

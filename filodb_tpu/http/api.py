@@ -240,6 +240,7 @@ class FiloHttpServer:
                                      FILODB_SHARD_LOCK_WAIT_SECONDS,
                                      FILODB_INGEST_STALE_MARKERS,
                                      FILODB_SHARD_NUM_SERIES,
+                                     FILODB_STORE_BIRTHS,
                                      FILODB_STORE_HOLE_CELLS,
                                      FILODB_STORE_REHYDRATE,
                                      FILODB_STORE_RESIDENT_BYTES_PER_SAMPLE,
@@ -272,6 +273,11 @@ class FiloHttpServer:
                         c.increment(rows - c.value)
                     registry.gauge(FILODB_STORE_HOLE_CELLS, shard).update(
                         float(st.hole_cells))
+                    for how, rows in st.births.items():
+                        c = registry.counter(
+                            FILODB_STORE_BIRTHS,
+                            {**shard, "aligned": str(how == "aligned").lower()})
+                        c.increment(rows - c.value)
                     for why, times in st.rehydrated.items():
                         c = registry.counter(FILODB_STORE_REHYDRATE,
                                              {**shard, "cause": why})

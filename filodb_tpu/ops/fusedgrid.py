@@ -667,7 +667,7 @@ def _extrapolate(fn, window_ms, delta, f_v, dur_start, dur_end, sampled,
 
 def tile_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
                  v, n, band, ohlo, lo, hi, rel, roll, line=None,
-                 holes: bool = False, form: str = "band"):
+                 holes: bool = False, form: str = "band", born=None):
     """Shared per-tile window math of the fused tier: decoded values
     ``v [Sb, Ca]`` -> ``(contrib [Sb, Tp]`` with absent cells zeroed,
     ``okf [Sb, Tp]`` presence as f32). ONE definition per tiling plan for
@@ -687,7 +687,14 @@ def tile_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     has cells without a sample (:func:`_hole_contrib`). The rate family
     on a line store (:func:`counts_falls`) has two ``form``s of a tile,
     "tel" and "band", and returns a third value, a scalar: whether the
-    tile fell (:func:`tile_fell`; :func:`fallen_fold` runs the two)."""
+    tile fell (:func:`tile_fell`; :func:`fallen_fold` runs the two).
+    ``born [Sb, 1]`` i32 is the grid program's BIRTHS mode (a store in
+    time-aligned cells that holds a row born late, core/chunkstore.py):
+    a row's samples are its cells ``born <= col < n``, its first cell in a
+    window ``max(lo, born)``, the cell AT its birth has no increment, and
+    where it is born inside a window the window's first value is the
+    row's own first (one masked reduce along the row: ``ohlo`` picks a
+    step's cell, not a row's). None is the program as it ever was."""
     if line is not None:
         contrib = _hole_contrib if holes else _line_contrib
         return contrib(fn, window_ms, interval_ms, c0, v, n, band,
@@ -697,10 +704,14 @@ def tile_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     lcol = jax.lax.broadcasted_iota(jnp.int32, (Sb, Ca), 1)
     col = lcol + c0                                           # global cell
     valid = col < n
+    if born is not None:
+        valid &= col >= born
     v = jnp.where(valid, v, 0.0)
 
     last_cell = n - 1                                         # [Sb, 1]
     f_idx = jnp.maximum(lo, 0)                                # [1, Tp]
+    if born is not None:
+        f_idx = jnp.maximum(f_idx, born)                      # [Sb, Tp]
     l_idx = jnp.minimum(hi, last_cell)                        # [Sb, Tp]
     cnt = jnp.maximum(l_idx - f_idx + 1, 0)
     cnt_f = cnt.astype(f32)
@@ -724,13 +735,18 @@ def tile_contrib(fn: str, window_ms: int, interval_ms: int, c0: int,
     prev = roll(v, 1)
     raw = v - prev
     inc = jnp.maximum(raw, 0.0) if is_counter else raw
-    mask = valid & (col > 0)
+    mask = valid & (col > (0 if born is None else born))
     if c0:
         mask &= lcol > 0
     inc = jnp.where(mask, inc, 0.0)
 
     delta = dot_exact01(inc, band)                            # [Sb, Tp]
     f_v = dot_exact01(v, ohlo)
+    if born is not None:
+        # born inside the window: the row's own first value (its birth
+        # cell lies among the tile's columns wherever ``cnt`` is not 0)
+        own = jnp.sum(jnp.where(col == born, v, 0.0), axis=1, keepdims=True)
+        f_v = jnp.where(born > lo, own, f_v)
 
     relf = rel.astype(f32)                                    # [1, Tp]
     f_rel = (f_idx * interval_ms).astype(f32)
@@ -749,7 +765,7 @@ decode_narrow_tile = decodereg.decode_quant16
 
 def _kernel_body(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
                  Sb: int, Ca: int, Tp: int, G: int, residency: str, c0: int,
-                 line: int, holes: bool, *refs):
+                 line: int, holes: bool, births: bool, *refs):
     """``Ca`` is the streamed column width and ``c0`` its global offset into
     the store: a sub-range query streams (and matmuls) only its active
     columns (see active_columns); full-range queries have c0=0, Ca=C.
@@ -777,10 +793,12 @@ def _kernel_body(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
 
     def tile(form="band"):
         """The tile, every ref read here: inside the branch that runs it."""
-        n, on_line = _column(n_ref[:]), None                  # [Sb, 1] i32
+        n, on_line, born = _column(n_ref[:]), None, None      # [Sb, 1] i32
         if line:
             n, start = unpack_start(n)
             on_line = (start, res_ref[:], eb_ref[:])
+        elif births:        # the birth cell rides where a line's start does
+            n, born = unpack_start(n)
         # decode in VMEM: the registered pallas twin of the residency
         # variant
         v = var.pallas(val_ref[:], *(_column(r[:]) for r in rowrefs))
@@ -790,7 +808,7 @@ def _kernel_body(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
             fn, window_ms, interval_ms, c0, v, n, band_ref[:], ohlo_ref[:],
             lo_ref[:], hi_ref[:], rel_ref[:],
             roll=lambda x, k: pltpu.roll(x, jnp.int32(k), 1), line=on_line,
-            holes=holes, form=form)
+            holes=holes, form=form, born=born)
 
     def fold(contrib, okf):
         # per-group fold on the MXU: [G, Sb] one-hot x [Sb, Tp]
@@ -828,7 +846,7 @@ def _kernel_body(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
 def build_pallas(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
                  S: int, Sb: int, C: int, Tp: int, G: int, interpret: bool,
                  residency: str = "raw", c0: int = 0, Ck: int = 0,
-                 line: int = 0, holes: bool = False):
+                 line: int = 0, holes: bool = False, births: bool = False):
     """The raw (traceable) fused-kernel pallas_call — also invoked inside
     ``shard_map`` by the mesh executor (parallel/distributed.py), where each
     shard runs this same map phase on its resident block and the partial
@@ -855,10 +873,13 @@ def build_pallas(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
     sample; the same operands, read by :func:`_hole_contrib`. A line
     program of the rate family (:func:`counts_falls`) returns one output
     more, last: the tiles that ran the band form (:func:`fallen_fold`),
-    ``[1]`` i32 in SMEM."""
+    ``[1]`` i32 in SMEM. ``births``: the grid program's births mode
+    (:func:`tile_contrib`): the same operands, each row's birth cell
+    packed above its count as a line's start is."""
     var = decodereg.variant(residency)
     assert not var.full_columns or c0 == 0, (residency, c0)
     assert not line or residency == "raw", residency
+    assert not (line and births)
     n_out = 3 if needs_sumsq else 2
     Ca = Ck if Ck else C
     out_shape = tuple(jax.ShapeDtypeStruct((G, Tp), jnp.float32)
@@ -875,7 +896,7 @@ def build_pallas(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
         scratch = []
     body = functools.partial(_kernel_body, fn, needs_sumsq, window_ms,
                              interval_ms, Sb, Ca, Tp, G, residency, c0, line,
-                             holes)
+                             holes, births)
     const = functools.partial(pl.BlockSpec, index_map=lambda i: (0, 0),
                               memory_space=pltpu.VMEM)
     # a per-row operand, [S / Sb, 1, Sb] (lane_major): tile i's [1, Sb]
@@ -919,6 +940,10 @@ def build_pallas(fn: str, needs_sumsq: bool, window_ms: int, interval_ms: int,
         # plane filled forward picked at hi (bytes, product, narrowing)
         footprint += (14 * Sb * Ca * 4 + 4 * Sb * Ca + 4 * Sb * Tp * 4
                       + Sb * Ca * 4 + 5 * Sb * Tp * 4)
+    if births:
+        # the birth cell's mask and the masked values it reduces, and the
+        # first cell a row, [Sb, Tp], where it was a [1, Tp] row
+        footprint += 2 * Sb * Ca * 4 + 2 * Sb * Tp * 4
     return pl.pallas_call(
         body,
         grid=(S // Sb,),
@@ -966,7 +991,7 @@ def active_columns(C: int, lo: np.ndarray, hi: np.ndarray) -> tuple[int, int]:
 def build_xla_tiles(fn: str, needs_sumsq: bool, window_ms: int,
                     interval_ms: int, S: int, Sb: int, C: int, Tp: int,
                     G: int, residency: str = "raw", c0: int = 0, Ck: int = 0,
-                    line: int = 0, holes: bool = False):
+                    line: int = 0, holes: bool = False, births: bool = False):
     """XLA-fused twin of :func:`build_pallas`, built from the SAME tiling
     plan: one ``lax.scan`` walks the identical [Sb, Ca] row tiles through
     the identical :func:`tile_contrib` math and accumulates the same [G, Tp]
@@ -991,16 +1016,18 @@ def build_xla_tiles(fn: str, needs_sumsq: bool, window_ms: int,
     def fold(carry, xs, band, ohlo, lo, hi, rel, *eb):
         blk_t, *rest = xs
         rows_t = [_column(r) for r in rest[:R + 1]]
-        n_t, g_t, tile = rows_t[R], rest[R + 1], None
+        n_t, g_t, tile, born_t = rows_t[R], rest[R + 1], None, None
         if line:
             n_t, start_t = unpack_start(n_t)
             tile = (start_t, rest[R + 2], eb[0])
+        elif births:
+            n_t, born_t = unpack_start(n_t)
 
         def one(form="band"):
             v = var.xla(blk_t, *rows_t[:R])
             contrib, okf, *fell = tile_contrib(
                 fn, window_ms, interval_ms, c0, v, n_t, band, ohlo, lo, hi,
-                rel, roll, line=tile, holes=holes, form=form)
+                rel, roll, line=tile, holes=holes, form=form, born=born_t)
             return group_fold(g_t, G, contrib, okf, needs_sumsq), *fell
 
         if not falls:
@@ -1041,29 +1068,37 @@ def fused_program(fn: str, needs_sumsq: bool, window_ms: int,
                   interval_ms: int, S: int, Sb: int, C: int, Tp: int, G: int,
                   residency: str = "raw", c0: int = 0, Ck: int = 0,
                   variant: str = "pallas", line: int = 0,
-                  holes: bool = False):
+                  holes: bool = False, births: bool = False):
     """The whole fused program of one query as a traceable function of the
     store's own arrays — ``n``, ``gids``, a line store's ``start`` and the
     narrow variants' per-row operands all ``[S]``: the casts, the pack of
     start above count and the ``[S] -> [S / Sb, 1, Sb]`` reshapes
     (:func:`lane_major`, no byte moves) live inside the one jit, so a query
-    is one dispatch and no relayout. ``residency`` .. ``holes`` as
-    :func:`build_pallas` and :func:`_build_call` have them."""
+    is one dispatch and no relayout. ``residency`` .. ``births`` as
+    :func:`build_pallas` and :func:`_build_call` have them; in the births
+    mode the store's ``born [S]`` follows ``gids``."""
     R = decodereg.variant(residency).row_operands
     if variant == "xla":
         call = build_xla_tiles(fn, needs_sumsq, window_ms, interval_ms,
                                S, Sb, C, Tp, G, residency, c0, Ck, line,
-                               holes)
+                               holes, births)
     else:
         call = build_pallas(fn, needs_sumsq, window_ms, interval_ms,
                             S, Sb, C, Tp, G, variant != "pallas",
-                            residency, c0, Ck, line, holes)
+                            residency, c0, Ck, line, holes, births)
 
     def rows(n, gids):
         return (lane_major(n.astype(jnp.int32), Sb),
                 lane_major(gids.astype(jnp.int32), Sb))
 
-    if residency != "raw":
+    if births:
+        def wrapped(blk, *rest):
+            if residency == "raw":
+                blk = blk.astype(jnp.float32)
+            n, gids, born = rest[R:R + 3]
+            return call(blk, *(lane_major(r, Sb) for r in rest[:R]),
+                        *rows(pack_start(n, born), gids), *rest[R + 3:])
+    elif residency != "raw":
         def wrapped(blk, *rest):
             return call(blk, *(lane_major(r, Sb) for r in rest[:R]),
                         *rows(rest[R], rest[R + 1]), *rest[R + 2:])
@@ -1079,7 +1114,7 @@ def fused_program(fn: str, needs_sumsq: bool, window_ms: int,
 
 def _build_call(*statics):
     """The compiled fused program via the explicit plan cache
-    (query/plancache.py). ``statics`` are :func:`fused_program`'s fifteen
+    (query/plancache.py). ``statics`` are :func:`fused_program`'s sixteen
     arguments in its order, and the key IS them: fn/op statics, the padded
     [S, C, Tp, G] shape buckets, the ``residency`` decode variant ("raw" |
     "quant16" | "delta16" | "delta8"), and the backend ``variant`` as
@@ -1087,15 +1122,18 @@ def _build_call(*statics):
     every (residency, backend) pair is a distinct program and caches as a
     distinct kernel variant. ``line`` as :func:`build_pallas` has it: Tp is
     128 for 1..128 steps, so a packed line program is told apart here; so
-    is the mode that reads around ``holes``."""
+    is the mode that reads around ``holes``, and the grid program's
+    ``births`` mode."""
     from ..query.plancache import plan_cache
     # a grid program's key is what it was before there was a line form, and
     # an unpacked line program's what it was before there was a packed one
-    *key, line, holes = statics
+    *key, line, holes, births = statics
     if line:
         key += ("line",) if line == 1 else ("line", line)
     if holes:
         key += ("holes",)
+    if births:
+        key += ("births",)
     return plan_cache.program("fused-grid", tuple(key),
                               lambda: fused_program(*statics))
 
@@ -1244,7 +1282,7 @@ def fused_grid_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
                          out_ts: np.ndarray, window_ms: int,
                          base_ts: int, interval_ms: int, fetch: bool = True,
                          narrow=None, variant: str = "pallas", line=None,
-                         holes: bool = False):
+                         holes: bool = False, born=None, born_late: int = 0):
     """One-pass ``op(fn(metric[window]))`` partials over a grid-aligned block.
 
     val [S, C] f32 (S a multiple of 512 or a power of two), n [S] i32 valid
@@ -1264,7 +1302,11 @@ def fused_grid_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
     ``line_info``); the caller checked :func:`line_fusable` and zeroed
     ``n`` for the rows off their line. ``holes`` says that the line store
     has HOLES (``line_info().holes``): the program that reads around them
-    runs, and no other store's.
+    runs, and no other store's. ``born`` (device i32 [S], the store's
+    ``born_dev``) says that the grid store holds a row born late: the
+    program's births mode runs (:func:`tile_contrib`), and no other
+    store's; ``born_late`` is the dispatch span's tag, the SELECTED rows
+    whose birth cell is past the grid's first.
     """
     assert fn in FUSED_FNS | FUSED_WINDOW_FNS and op in FUSED_OPS
     if narrow is not None:
@@ -1280,6 +1322,7 @@ def fused_grid_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
     G = _roundup(max(num_groups, 8), 8)
     per = slots_per_block(T) if line is not None else 0
     holes = line is not None and bool(holes)
+    births = born is not None and line is None
 
     *ops, c0, Ck = _device_operands(
         C, Tp, np.ascontiguousarray(np.asarray(out_ts, np.int64)).tobytes(),
@@ -1290,7 +1333,7 @@ def fused_grid_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
     needs_sumsq = op in ("stddev", "stdvar")
     call = _build_call(fn, needs_sumsq, int(window_ms), int(interval_ms),
                        S, Sb, C, Tp, G, kind, c0, Ck, kernel_tag(variant), per,
-                       holes)
+                       holes, births)
     # the framework runs with x64 on (int64 timestamps); Mosaic rejects the
     # i64 scalars x64 tracing injects (grid index maps, roll shifts), and the
     # kernel itself is pure f32/i32 — so trace the call with x64 off.
@@ -1298,17 +1341,21 @@ def fused_grid_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
     # the bytes the kernel streams from inside (rows x cols from c0 on);
     # ``packed``: edge slots a block of a line program (1 | 2)
     # ``holes``: which mode of the line program ran (0 | 1)
-    tags = {"stamps": "grid"} if line is None else {
-        "stamps": "line", "packed": per, "holes": int(holes)}
+    # ``births``: which mode of the grid program ran (0 | 1), ``born_late``
+    # the selected rows born past the grid's first cell
+    tags = ({"stamps": "grid", "births": int(births),
+             "born_late": int(born_late)} if line is None else {
+        "stamps": "line", "packed": per, "holes": int(holes)})
+    rows = (jnp.asarray(n), jnp.asarray(gids)) + ((born,) if births else ())
     with dispatching(kernel=kernel_tag(variant), rows=S, c0=c0, cols=Ck,
                      steps=T, groups=num_groups, **tags) as padded, \
             jax.enable_x64(False):
         if nops is not None:
-            outs = call(*nops, jnp.asarray(n), jnp.asarray(gids), *ops)
+            outs = call(*nops, *rows, *ops)
         elif line is not None:
-            outs = call(val, jnp.asarray(n), jnp.asarray(gids), *line, *ops)
+            outs = call(val, *rows, *line, *ops)
         else:
-            outs = call(val, jnp.asarray(n), jnp.asarray(gids), *ops)
+            outs = call(val, *rows, *ops)
     # partial state is tiny ([G, Tp]): ONE host fetch finishes the query — the
     # slice/present/combine chain as device ops would cost a round-trip each.
     # A program that counts its fallen tiles (counts_falls) says so on its

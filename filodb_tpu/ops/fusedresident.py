@@ -132,7 +132,8 @@ def count_fallback(shape: str) -> None:
 def scalar_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
                      out_ts: np.ndarray, window_ms: int, base_ts: int,
                      interval_ms: int, fetch: bool = True, narrow=None,
-                     line=None, holes: bool = False):
+                     line=None, holes: bool = False, born=None,
+                     born_late: int = 0):
     """Mode-routed one-pass ``op(fn(metric[w]))`` partials (see
     fusedgrid.fused_grid_aggregate for operand contracts;
     ``narrow=(kind, operands)`` streams a registered narrow block —
@@ -142,7 +143,7 @@ def scalar_aggregate(op: str, fn: str, val, n, gids, num_groups: int,
     out = fusedgrid.fused_grid_aggregate(
         op, fn, val, n, gids, num_groups, out_ts, window_ms, base_ts,
         interval_ms, fetch=fetch, narrow=narrow, variant=_mode, line=line,
-        holes=holes)
+        holes=holes, born=born, born_late=born_late)
     count_served(scalar_shape_of(fn) or "rate_sum")
     return out
 

@@ -80,8 +80,11 @@ def _plan(kernel: str, key: tuple, build):
 
 
 def _grid_kernel(fn, val, n, band, band_open, onehot_lo, onehot_hi, lo, hi,
-                 rel_out, window_ms, interval_ms, stale_ms):
+                 rel_out, window_ms, interval_ms, stale_ms, born=None):
     """val [S, C]: sample k of each series at column k == grid cell k.
+    ``born [S]`` i32: the rows of a store in time-aligned cells that holds
+    a row born late (core/chunkstore.py) — a row's samples are its cells
+    ``born <= c < n``; None is the kernel as it ever was.
 
     All device-side time arithmetic is int32 *grid-relative* milliseconds
     (rel_out = out_ts - base_ts): no int64 emulation on TPU. The wrapper
@@ -91,10 +94,14 @@ def _grid_kernel(fn, val, n, band, band_open, onehot_lo, onehot_hi, lo, hi,
     S, C = val.shape
     acc = val.dtype
     valid = jnp.arange(C, dtype=jnp.int32)[None, :] < n[:, None]
+    if born is not None:
+        valid &= jnp.arange(C, dtype=jnp.int32)[None, :] >= born[:, None]
     v = jnp.where(valid, val, 0).astype(acc)
 
     last_cell = n[:, None] - 1                                    # [S, 1] i32
     f_idx = jnp.maximum(lo, 0)[None, :]                           # [1, T] i32
+    if born is not None:
+        f_idx = jnp.maximum(f_idx, born[:, None])                 # [S, T]
     l_idx = jnp.minimum(hi[None, :], last_cell)
     cnt = jnp.maximum(l_idx - f_idx + 1, 0)
     cnt_f = cnt.astype(acc)
@@ -128,6 +135,10 @@ def _grid_kernel(fn, val, n, band, band_open, onehot_lo, onehot_hi, lo, hi,
         inc = jnp.maximum(raw_inc, 0.0) if is_counter else raw_inc
         delta = inc @ band_open                                   # MXU, (lo_t, hi_t]
         f_v = v @ onehot_lo                                       # raw first value
+        if born is not None:        # born inside the window: its own first
+            own = jnp.take_along_axis(
+                v, jnp.clip(born[:, None], 0, C - 1), axis=1)
+            f_v = jnp.where(born[:, None] > lo[None, :], own, f_v)
         f_rel = f_idx * interval_ms                               # [1, T] i32
         l_rel = l_idx * interval_ms                               # [S, T] i32
         win_start = rel_out[None, :] - window_ms
@@ -608,15 +619,20 @@ def histogram_quantile_np(q, les, counts):
 
 
 def periodic_samples_grid(val, n, out_ts: np.ndarray, window_ms: int, fn: str,
-                          base_ts: int, interval_ms: int, stale_ms: int = 300_000):
-    """Grid-path periodic samples over a uniform-start shard: [S, T] output."""
+                          base_ts: int, interval_ms: int, stale_ms: int = 300_000,
+                          born=None):
+    """Grid-path periodic samples over a uniform-start shard: [S, T] output.
+    ``born``: the rows' birth cells where the store holds a row born late
+    (``_grid_kernel``'s births mode, a program of its own)."""
     k = _plan("grid",
-              (fn,) + tuple(val.shape) + (len(out_ts), str(val.dtype)),
+              (fn,) + tuple(val.shape) + (len(out_ts), str(val.dtype))
+              + (() if born is None else ("births",)),
               lambda: functools.partial(_grid_kernel, fn))
-    return k(val, jnp.asarray(n, jnp.int32),
-             *grid_kernel_operands(val.shape[1], val.dtype, out_ts,
-                                   window_ms, fn, base_ts, interval_ms,
-                                   stale_ms))
+    ops = grid_kernel_operands(val.shape[1], val.dtype, out_ts, window_ms,
+                               fn, base_ts, interval_ms, stale_ms)
+    if born is not None:
+        ops += (jnp.asarray(born, jnp.int32),)
+    return k(val, jnp.asarray(n, jnp.int32), *ops)
 
 
 def grid_kernel_operands(C: int, val_dtype, out_ts: np.ndarray,

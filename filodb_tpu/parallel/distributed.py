@@ -850,7 +850,10 @@ class MeshQueryExecutor:
             if gi is None:
                 return None
             kind, off = st.grid_cohorts()
-            if kind != "uniform" or off != 0:
+            # (a row born late in time-aligned cells: the mesh's fused
+            # programs have no births mode, the general ones read the
+            # rows moved left, ``closed_arrays``)
+            if kind != "uniform" or off != 0 or st.born_late:
                 return None
             grids.add(gi)
         return grids.pop() if len(grids) == 1 else None

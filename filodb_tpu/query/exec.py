@@ -124,6 +124,14 @@ class SeriesSelection:
     # stamp in ``ts`` lies past TS_PAD, core/chunkstore.py): the general
     # kernels are told (ops/rangefns.py ``_open_holes``)
     holes: bool = False
+    # the store keeps its grid in time-aligned cells and holds a row born
+    # late (core/chunkstore.py ``born_late``): the birth cell of every
+    # array row, device i32 [R] — the grid and fused kernels then run in
+    # their births mode, where a row's samples are its cells ``born <= c <
+    # n`` — and how many of the SELECTED rows were born past the grid's
+    # first cell (the dispatch span's ``born_late``). None / 0 otherwise
+    born: object | None = None
+    born_late: int = 0
 
 
 @dataclass
@@ -182,7 +190,7 @@ def _gather_rows_padded(ts, val, n, rows: np.ndarray, grid_gather=None):
     pad = np.zeros(P, np.int32)
     pad[:M] = rows
     if grid_gather is not None:
-        return grid_gather(pad, M, val, n) + (P,)
+        return grid_gather(pad, M, val, n)[:3] + (P,)
     rid = jnp.asarray(pad)
 
     # deferred (compressed-resident) blocks gather row-wise — a minority fix
@@ -276,6 +284,7 @@ class GatheredRows:
     rows: np.ndarray | None   # identity map [0..len(keys)) where P is padded
     grid: tuple | None
     span_tags: dict           # the gather span's: shard, rows, padded, bytes
+    born_late: int = 0        # the picked rows born late (picked is [4, P])
     _sel: SeriesSelection | None = None
 
     # SeriesSelection's fields that are never set on this form
@@ -292,10 +301,12 @@ class GatheredRows:
         if self._sel is None:
             with span(SPAN_QUERY_GATHER, **self.span_tags) as tags:
                 count_gather(tags, STEPWISE_PROGRAMS[self.on_grid])
-                ts, val, n = self.gather_body()(
+                # a store that holds a row born late: ``born`` beside n
+                ts, val, n, *born = self.gather_body()(
                     *self.store_ops, *map(jnp.asarray, self.host_ops))
             self._sel = SeriesSelection(ts, val, n, self.keys, self.rows,
-                                        self.grid)
+                                        self.grid, born=born[0] if born
+                                        else None, born_late=self.born_late)
         return self._sel
 
     def gather_body(self):
@@ -361,7 +372,7 @@ class FusedWindowData:
         base_ts, interval_ms = self.sel.grid
         vals = gridfns.periodic_samples_grid(
             _dval(self.sel.val), self.sel.n, out_eval, self.window, self.fn,
-            base_ts, interval_ms, stale_ms=self.stale_ms)
+            base_ts, interval_ms, stale_ms=self.stale_ms, born=self.sel.born)
         minority = self.sel.grid_minority
         if minority is not None and len(minority):
             vals = _correct_minority_cohort(self.sel, vals, out_eval,
@@ -476,9 +487,10 @@ def _leaf_body(gather, kernel, fn, op, num_groups, T, spec, n_host,
     from ..ops import gridfns
     ops = _unpack_operands(spec, ints, floats, device)
     gids = None if op is None else ops.pop()
-    ts, val, n = gather(*store_ops, *ops[:n_host])
+    # (a fourth value where the picked rows come with birth cells)
+    ts, val, n, *born = gather(*store_ops, *ops[:n_host])
     if kernel == "grid":
-        vals = gridfns._grid_kernel(fn, val, n, *ops[n_host:])
+        vals = gridfns._grid_kernel(fn, val, n, *ops[n_host:], *born)
     else:
         vals = rangefns.periodic_body(fn)(ts, val, n, *ops[n_host:])
     vals = vals[:, :T]
@@ -627,7 +639,8 @@ class PeriodicSamplesMapper(Transformer):
             vals = gridfns.periodic_samples_grid(_dval(data.val), data.n,
                                                  out_eval, window,
                                                  fn, base_ts, interval_ms,
-                                                 stale_ms=ctx.stale_ms)
+                                                 stale_ms=ctx.stale_ms,
+                                                 born=data.born)
             if minority is not None and len(minority):
                 vals = _correct_minority_cohort(data, vals, out_eval, window,
                                                 fn, a0, a1)
@@ -1015,7 +1028,8 @@ class AggregateMapReduce(Transformer):
             n_eff, gids_dev, Gp,
             data.out_ts, data.window, base_ts, interval_ms, fetch=False,
             narrow=narrow, line=line,
-            holes=line is not None and sel.line.holes)
+            holes=line is not None and sel.line.holes,
+            born=sel.born, born_late=sel.born_late)
         ctx.stats.add("fused_kernels")
         ctx.kernels.add((narrow[0] if narrow is not None else "raw",
                          fusedresident.tag()))
@@ -1724,6 +1738,8 @@ class SelectRawPartitionsExec(ExecPlan):
         resolved = shard.index.filter_misses
         picked, tags["memo"] = shard.selection(
             list(self.filters), self.start_ms, self.end_ms, GATHER_THRESHOLD)
+        if picked.why is not None and tags["memo"] != "hit":
+            tags["memo_why"] = picked.why   # why this select was no hit
         tags["matchers"] = "+".join(sorted(f.KIND for f in self.filters))
         tags["resolve"] = ("miss" if shard.index.filter_misses != resolved
                            else "hit")
@@ -1773,9 +1789,14 @@ class SelectRawPartitionsExec(ExecPlan):
                 jnp.full((8, 8), 1 << 62, jnp.int64),
                 jnp.zeros(vshape, store.dtype), jnp.zeros(8, jnp.int32),
                 [], None, None, les)
-        # mixed start cohorts (churn): shift the grid base to the majority
-        # cohort's start cell; the few minority rows are recorded so PSM can
-        # recompute them generally. Too much churn => general path outright.
+        # Churn. A store in time-aligned cells (core/chunkstore.py
+        # ``aligned``) is ONE start cohort whenever its rows were born, and
+        # there is nothing to do here a query: the kernels take ``born``
+        # beside ``n`` while the store holds a row born late. The other
+        # grid forms hold mixed start cohorts: the grid base moves to the
+        # majority cohort's start cell and the few minority rows are
+        # recorded so PSM can recompute them generally. Too much churn =>
+        # general path outright.
         minority_sel = None
         if grid is not None:
             base, iv = grid
@@ -1816,6 +1837,7 @@ class SelectRawPartitionsExec(ExecPlan):
         if store.res is not None:
             tags["hole_cells"], tags["used_cells"] = picked.cells(store)
         from ..core.chunkstore import _Deferred
+        births = grid is not None and store.born_late > 0
         if len(pids) <= GATHER_THRESHOLD and len(pids) < 0.5 * max(total, 1):
             # narrow selection: gather rows once, padded to a power of two
             ctx.stats.add("blocks_raw")
@@ -1848,7 +1870,7 @@ class SelectRawPartitionsExec(ExecPlan):
                     else (pad, np.int32(M)),
                     on_g, gops[1] if on_g else None, decode,
                     (P, val.shape[1]), val.dtype, keys, sel_rows, grid,
-                    gtags)
+                    gtags, int((store.born[pids] > 0).sum()) if births else 0)
             with span(SPAN_QUERY_GATHER, **gtags) as gtags:
                 count_gather(gtags, STEPWISE_PROGRAMS[on_g])
                 sel_ts, sel_val, sel_n, _ = _gather_rows_padded(
@@ -1862,12 +1884,15 @@ class SelectRawPartitionsExec(ExecPlan):
         # (store.S is the PHYSICAL padded row count; the full-selection test
         # is against the logical series count)
         count_leaf(ctx, tags, "wide")
+        born_late = store.born_late if births else 0
         if picked.is_all:
             n_eff = n
         else:
-            mask = np.zeros(store.S, bool)
-            mask[pids] = True
-            n_eff = jnp.where(jnp.asarray(mask), n, 0)
+            # the selection's own row mask, kept with it: a kept selection
+            # (core/selection.py) uploads it once, not once a query
+            n_eff = jnp.where(picked.row_mask(store.S)[1], n, 0)
+            if births:      # one pass per change of a birth cell
+                born_late = picked.late_rows(store.late_mask())
         g_min = (pids[minority_sel].astype(np.int32)
                  if minority_sel is not None else None)
         narrow = None
@@ -1900,7 +1925,8 @@ class SelectRawPartitionsExec(ExecPlan):
                                  or isinstance(val, _Deferred)):
             line = None
         return SeriesSelection(ts, val, n_eff, keys, pids, grid, les,
-                               g_min, narrow, hist_narrow, line, holes)
+                               g_min, narrow, hist_narrow, line, holes,
+                               store.born_dev if births else None, born_late)
 
 
 def _execute_children(children, ctx):

@@ -97,6 +97,7 @@ FILODB_STORE_RESIDENT_BYTES_PER_SAMPLE = \
     "filodb_store_resident_bytes_per_sample"
 FILODB_QUERY_REFUSED = "filodb_query_refused"
 FILODB_STORE_ROWS_DEMOTED = "filodb_store_rows_demoted"
+FILODB_STORE_BIRTHS = "filodb_store_births"
 FILODB_STORE_ROWS_OFF_LINE = "filodb_store_rows_off_line"
 FILODB_STORE_HOLE_CELLS = "filodb_store_hole_cells"
 FILODB_INGEST_STALE_MARKERS = "filodb_ingest_stale_markers"
@@ -183,9 +184,11 @@ METRICS_SPEC: dict[str, tuple[str, str]] = {
         "counter", "Reads of a shard's selection memo (core/selection.py) "
                    "by part (select = a selector's part ids and slot "
                    "epochs, groupids = a by/without's group ids, keys and "
-                   "device array) and outcome: hit, miss (built and kept), "
-                   "bypass (not kept; reason = narrow, time_mask or "
-                   "recovering)."),
+                   "device array) and outcome: hit, miss (built and kept; "
+                   "reason = time_mask where the select ran the index's "
+                   "time-masked pass: kept for its span of ranges), bypass "
+                   "(not kept; reason = recovering, time_mask or narrow, "
+                   "the first that holds)."),
     FILODB_QUERY_LEAF: (
         "counter", "Data-reading leaves by how they took their rows: route "
                    "= gather (a narrow selection: keys materialized, rows "
@@ -374,14 +377,18 @@ METRICS_SPEC: dict[str, tuple[str, str]] = {
         "counter", "Flushes where a store configured for compressed "
                    "residency tried to compress and the data refused the "
                    "ok-contract (cohort gate breached), tagged "
-                   "reason=resets|non-integer|range — distinguishes "
+                   "reason=resets|non-integer|range — or holds a row born "
+                   "late in time-aligned cells (reason=births: the narrow "
+                   "forms read every row from column 0) — distinguishes "
                    "\"compressed\" from \"tried and fell back to raw\"."),
     FILODB_STORE_REHYDRATE: (
         "counter", "Times a compressed-resident store was decoded back to "
                    "its raw f32 + s64 blocks, tagged cause=append|compact|"
                    "free (a mutation of a form that cannot take it: "
                    "quant16, delta16 off a grid, a histogram), off_grid "
-                   "(the delta form's first stamp off the scrape grid) or "
+                   "(the delta form's first stamp off the scrape grid), "
+                   "births (a series born late into a delta form that was "
+                   "adopted from time-aligned cells) or "
                    "cohort_gate (more rows in the raw pool than "
                    "store.narrow_cohort_gate allows). The delta form on a "
                    "grid appends, ages out and frees as it is: 0 there."),
@@ -407,6 +414,13 @@ METRICS_SPEC: dict[str, tuple[str, str]] = {
                    "past the bound a line keeps, a "
                    "row on no cell of the line); a demoted row is "
                    "answered by the general kernels."),
+    FILODB_STORE_BIRTHS: (
+        "counter", "Series that appeared after the oldest cell their store "
+                   "holds, tagged aligned=true (a store in time-aligned "
+                   "cells gave the row a birth cell past 0: one cohort, "
+                   "the kernels' births mode) | false (a form without "
+                   "birth cells took it as a minority start cohort, or "
+                   "left its aligned cells for the line form)."),
     FILODB_STORE_ROWS_OFF_LINE: (
         "gauge", "Live rows of a shard's store that the line kernel skips "
                  "now: demoted rows and rows that start in another cell."),

@@ -146,6 +146,12 @@ TRACE_SPEC: dict[str, str] = {
     SPAN_QUERY_SELECT: "Index select + array capture of one leaf; per shard "
                        "on the mesh route (tags: shard, series, memo = hit "
                        "| miss | bypass of the shard's selection memo, "
+                       "memo_why = recovering | time_mask | narrow: why "
+                       "this select was no hit (time_mask = it ran the "
+                       "index's pass over every matching series' start "
+                       "and end time; wider than a gather it is a miss, "
+                       "kept for the span of ranges that pass leaves the "
+                       "same), "
                        "demoted = selected rows the fused kernel skips and "
                        "the general kernels answer; on a line store "
                        "hole_cells = the selected rows' cells without a "
@@ -199,7 +205,11 @@ TRACE_SPEC: dict[str, str] = {
                        "first (the flush's programs are not in it: no one "
                        "fetches them); kernel, rows, c0, cols, steps, "
                        "groups, stamps "
-                       "= grid | line, how the store keeps time, and on "
+                       "= grid | line, how the store keeps time, on grid "
+                       "births = 0 | 1, whether the program's births mode "
+                       "ran (the store holds a row born past its grid's "
+                       "first cell), and born_late = the SELECTED rows born "
+                       "so, and on "
                        "line packed = 1 | 2, the edge slots a 128-lane "
                        "block of the kernel's one-hot operand, and holes "
                        "= 0 | 1, whether the mode that reads around cells "

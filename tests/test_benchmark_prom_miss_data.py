@@ -72,7 +72,9 @@ def test_the_prom_cells_are_as_named_whatever_follows_them():
         lists = metrics[name]["workloads"]
         assert lists.index("adhoc_prom") + 1 == lists.index(
             "adhoc_prom_miss"), name
-    assert metrics["demoted_rows_pct"]["workloads"] == [
+    # (the two cells lead the list; a later cell appended after them, as
+    # ISSUE 49 asks of ``adhoc_churn``, changes nothing they report)
+    assert metrics["demoted_rows_pct"]["workloads"][:2] == [
         "adhoc_prom", "adhoc_prom_miss"]
     assert metrics["hole_cells_pct"] == {
         "name": "hole_cells_pct", "unit": "%", "better": "lower",

@@ -316,14 +316,20 @@ def test_what_the_selection_observes_keeps_the_stepwise_form(kind, tmp_path):
     np.testing.assert_allclose(got.reshape(want.shape), want, rtol=1e-6)
 
 
-def test_a_churned_cohort_and_a_fused_kernels_rows_gather_on_their_own():
+@pytest.mark.parametrize("aligned", [False, True],
+                         ids=["cohort-rule", "aligned-cells"])
+def test_a_churned_cohort_and_a_fused_kernels_rows_gather_on_their_own(
+        aligned):
     """Two more that the selection can observe: rows of a minority start
-    cohort are recomputed from the gathered rows, and a fused aggregate's
-    kernel takes gathered rows — both through the stepwise gather."""
+    cohort are recomputed from the gathered rows (the cohort rule: a store
+    without birth cells), and a fused aggregate's kernel takes gathered
+    rows — both through the stepwise gather. In time-aligned cells a row
+    born late is no minority: its leaf is one program."""
     ms = TimeSeriesMemStore()
     sh = ms.setup("prometheus", GAUGE, 0, StoreConfig(
         max_series_per_shard=HOSTS, samples_per_series=C,
         flush_batch_size=10**9, dtype="float32"))
+    sh.store.aligned = aligned
     for k in range(SCRAPES):
         b = RecordBuilder(GAUGE)
         for h in range(HOSTS):
@@ -343,7 +349,8 @@ def test_a_churned_cohort_and_a_fused_kernels_rows_gather_on_their_own():
 
     # rows 3..17, host 5 among them: one of eight off the majority's start
     r, forms = programs_of(f"max(max_over_time({selector(8)}[1m]))")
-    assert forms == [qexec.STEPWISE_PROGRAMS[True]]
+    assert forms == [1 if aligned else qexec.STEPWISE_PROGRAMS[True]]
+    assert sh.store.born_late == (1 if aligned else 0)
     # without it: one cohort, one program, and the same answer where host 5
     # is not the largest
     _r, forms = programs_of('max(max_over_time(m{host=~"h3|h7|h9"}[1m]))')

@@ -67,8 +67,13 @@ def test_grid_last_sample_staleness():
     assert got[1, 1] == series[1][1][100]     # last sample at/before t=1000s is cell 100
 
 
-def test_store_grid_tracking_aligned():
+@pytest.mark.parametrize("aligned", [True, False],
+                         ids=["aligned-cells", "cohort-rule"])
+def test_store_grid_tracking_aligned(aligned):
     st = SeriesStore(max_series=4, capacity=32)
+    # the cohort rule is what a store without birth cells keeps (a layout
+    # store, a store born narrow and what it falls back to)
+    st.aligned = aligned
     for k in range(3):
         st.append(np.array([0, 1], np.int32),
                   np.array([BASE + k * IV] * 2, np.int64),
@@ -79,7 +84,14 @@ def test_store_grid_tracking_aligned():
     st.append(np.array([2], np.int32), np.array([BASE + 3 * IV], np.int64),
               np.array([9.0]))
     assert st.grid_info() == (BASE, IV)
+    if aligned:     # time-aligned cells: ONE cohort, the row has a birth cell
+        assert st.grid_cohorts() == ("uniform", 0)
+        assert st.grid_offsets(np.arange(3)).tolist() == [0, 0, 0]
+        assert st.born[:3].tolist() == [0, 0, 3] and st.born_late == 1
+        assert st.n_host[:3].tolist() == [3, 3, 4]
+        return
     assert st.grid_offsets(np.arange(3)).tolist() == [0, 0, 3]
+    assert st.grid_cohorts()[0] == "mixed" and st.born_late == 0
 
 
 def test_store_grid_survives_compaction():
@@ -180,7 +192,9 @@ def _series_by_host(result):
             for k, _, v in result.matrix.iter_series()}
 
 
-def test_engine_grid_path_survives_churn_and_compaction():
+@pytest.mark.parametrize("aligned", [True, False],
+                         ids=["aligned-cells", "cohort-rule"])
+def test_engine_grid_path_survives_churn_and_compaction(aligned):
     """New series appearing mid-stream (a new pod) and compaction must keep
     the shard on the MXU grid path, with results matching the general path
     bit-for-bit: majority cohort via band matmuls, churned rows corrected."""
@@ -193,6 +207,7 @@ def test_engine_grid_path_survives_churn_and_compaction():
     cfg = StoreConfig(max_series_per_shard=8, samples_per_series=64,
                       flush_batch_size=10**9, dtype="float64")
     shard = ms.setup("prometheus", GAUGE, 0, cfg)
+    shard.store.aligned = aligned       # (the cohort rule: see above)
     b = RecordBuilder(GAUGE)
     for t in range(50):
         for s in range(3):
@@ -202,7 +217,10 @@ def test_engine_grid_path_survives_churn_and_compaction():
     shard.ingest(b.build())
     shard.flush()
     assert shard.store.grid_info() is not None
-    assert shard.store.grid_offsets(np.arange(4)).tolist() == [0, 0, 0, 20]
+    late = [0, 0, 0, 20]
+    assert shard.store.grid_offsets(np.arange(4)).tolist() == (
+        [0] * 4 if aligned else late)
+    assert shard.store.born[:4].tolist() == (late if aligned else [0] * 4)
     eng = QueryEngine(ms, "prometheus")
     q = ("rate(m[2m])", BASE + 250_000, BASE + 480_000, 30_000)
     r1 = eng.query_range(*q)

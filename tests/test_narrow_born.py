@@ -65,7 +65,12 @@ class Audit:
 
 
 def twins(s=S, c=C):
-    return SeriesStore(s, c, born_narrow=True), SeriesStore(s, c)
+    """A store born narrow and its raw twin: the raw form such a store
+    falls back to, which keeps every row from column 0 as it does (no
+    birth cells: ``aligned`` is a store's from its birth)."""
+    raw = SeriesStore(s, c)
+    raw.aligned = False
+    return SeriesStore(s, c, born_narrow=True), raw
 
 
 def same(a, b):

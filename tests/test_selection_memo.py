@@ -157,8 +157,9 @@ def _release(shard, sel):
     with shard.lock:                        # what eviction calls
         shard._release_partitions_locked(np.asarray([1, 2], np.int32))
     # a tombstone is an ended entry: the time mask bites until the slot is
-    # reused (the test ``part_ids_from_filters`` makes)
-    return "bypass", "time_mask"
+    # reused (the test ``part_ids_from_filters`` makes), and what it leaves
+    # is kept for the span of ranges that it leaves the same
+    return "miss", "time_mask"
 
 
 def _release_and_reuse(shard, sel):
@@ -170,12 +171,12 @@ def _release_and_reuse(shard, sel):
 def _end_time(shard, sel):
     with shard.lock:
         shard.index.update_end_time(3, BASE + 20_000)
-    return "bypass", "time_mask"
+    return "miss", "time_mask"
 
 
 def _starts_after_the_query(shard, sel):
     ingest(shard, [61], LATE + 500_000)     # starts after the query's end
-    return "bypass", "time_mask"
+    return "miss", "time_mask"
 
 
 def _recovering(shard, sel):
@@ -222,6 +223,108 @@ def test_a_changed_index_state_is_not_served_from_the_memo(event):
         assert select(shard)[0] is not after
     else:
         assert select(shard) == (after, "hit")
+
+
+# -- under a time mask that bites: one kept selection per span of ranges ------
+
+def _fleet():
+    """48 series from BASE, six born 1,000 s later, six 2,000 s later, and
+    series 3 and 4 ended (marked, as the purge does) at BASE + 20 s."""
+    _ms, shard = mk_shard(cap=128)
+    ingest(shard, range(48, 54), BASE + 1_000_000)
+    ingest(shard, range(54, 60), BASE + 2_000_000)
+    with shard.lock:
+        shard.index.update_end_time(3, BASE + 20_000)
+        shard.index.update_end_time(4, BASE + 20_000)
+    return shard
+
+
+def _ranged(shard, start, end):
+    with shard.lock:
+        return shard.selection(list(M), start, end, KEEP_OVER)
+
+
+def _brute(shard, start, end) -> list:
+    idx = shard.index
+    return [p for p in range(len(idx)) if idx.is_live(p)
+            and idx.labels_of(p)["_metric_"] == "m"
+            and idx.start_time(p) <= end and idx.end_time(p) >= start]
+
+
+RANGES = {      # (start, end) -> the series a brute pass over the index picks
+    "before-both-births": (BASE, BASE + 999_999),
+    "on-the-first-birth": (BASE, BASE + 1_000_000),
+    "between-the-births": (BASE + 5_000, BASE + 1_999_999),
+    "on-the-second-birth": (BASE + 20_000, BASE + 2_000_000),
+    "past-the-ends": (BASE + 20_001, BASE + 1_500_000),
+    "past-the-ends-and-births": (BASE + 30_000, LATE),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RANGES))
+def test_a_kept_time_masked_selection_is_the_brute_pass(case):
+    shard = _fleet()
+    for name in sorted(RANGES):             # every span kept or LRU'd first
+        _ranged(shard, *RANGES[name])
+    start, end = RANGES[case]
+    sel, how = _ranged(shard, start, end)
+    assert sel.pids.tolist() == _brute(shard, start, end)
+    again, how = _ranged(shard, start, end)
+    assert how == "hit" and again is sel
+
+
+def test_ranges_of_one_span_share_a_selection_and_the_next_span_does_not():
+    shard = _fleet()
+    before = counts()
+    a, how = _ranged(shard, BASE + 30_000, BASE + 1_000_000)
+    assert how == "miss" and a.why == "time_mask"
+    assert delta(before) == {("select", "miss:time_mask"): 1}
+    # anywhere from the first birth up to the second, from past the ends on
+    for start, end in ((BASE + 20_001, BASE + 1_000_000),
+                       (BASE + 700_000, BASE + 1_999_999),
+                       (BASE + 1_500_000, BASE + 1_600_000)):
+        assert _ranged(shard, start, end) == (a, "hit")
+    # one millisecond over either edge is another selection
+    asked = {id(a): (BASE + 30_000, BASE + 1_000_000)}
+    for start, end in ((BASE + 30_000, BASE + 2_000_000),
+                       (BASE + 20_000, BASE + 1_000_000),
+                       (BASE + 30_000, BASE + 999_999)):
+        sel, how = _ranged(shard, start, end)
+        assert how == "miss" and id(sel) not in asked
+        assert sel.pids.tolist() == _brute(shard, start, end) != a.pids.tolist()
+        asked[id(sel)] = (start, end)
+    # and all four are kept: each has its groupings and its row mask
+    for start, end in asked.values():
+        sel, how = _ranged(shard, start, end)
+        assert how == "hit" and asked[id(sel)] == (start, end)
+    host, dev = a.row_mask(shard.store.S)
+    assert host.sum() == len(a.pids) and host[a.pids].all()
+    assert np.asarray(dev).tolist() == host.tolist()
+    assert a.row_mask(shard.store.S)[1] is dev and not host.flags.writeable
+
+
+def test_an_end_time_that_moves_unkeeps_every_span():
+    shard = _fleet()
+    a, _ = _ranged(shard, BASE + 30_000, BASE + 1_000_000)
+    with shard.lock:
+        shard.index.update_end_time(5, BASE + 1_200_000)    # inside a's span
+    b, how = _ranged(shard, BASE + 30_000, BASE + 1_000_000)
+    assert how == "miss" and b is not a and b.pids.tolist() == a.pids.tolist()
+    assert len(shard._selections) == 1      # a, of a state that is gone, went
+    c, how = _ranged(shard, BASE + 1_200_001, BASE + 1_300_000)
+    assert how == "miss" and 5 not in c.pids and 5 in b.pids
+
+
+def test_late_rows_are_counted_once_a_mask():
+    shard = _fleet()
+    a, _ = _ranged(shard, BASE + 30_000, BASE + 1_000_000)
+    late = np.zeros(shard.store.S, bool)
+    late[a.pids[-6:]] = True
+    late[3] = True                          # ended before the range: not selected
+    assert a.late_rows(late) == 6 and a._late[0] is late
+    late[a.pids[0]] = True                  # the same array: not looked at again
+    assert a.late_rows(late) == 6
+    assert a.late_rows(late.copy()) == 7
 
 
 def test_release_of_other_series_leaves_an_old_snapshot_readable():

@@ -696,3 +696,75 @@ def test_the_fill_of_a_delta8_store_walks_a_row_block_a_program(one_chip):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= S * C12H
     assert mem.temp_size_in_bytes < (1536 << 20), mem
+
+
+# -- births: a store in time-aligned cells that holds rows born late ---------------
+
+@pytest.mark.parametrize("fn,sumsq,G,variant", [
+    ("rate", False, 8, "pallas"),            # adhoc_churn's four texts
+    ("avg_over_time", False, 8, "pallas"),
+    ("sum_over_time", True, 8, "pallas"),
+    ("count_over_time", False, 8, "pallas"),  # the read-back's count probe
+    ("rate", False, 64, "pallas"),
+    ("rate", False, 8, "xla"),                # the twin
+])
+def test_the_births_mode_of_the_fused_grid_program_compiles_for_v5e(
+        one_chip, fn, sumsq, G, variant):
+    """promdev_churn_1m's program at 2^20 x 768 (fusedgrid.tile_contrib's
+    births mode): the grid program's operands and one more, the store's
+    ``born [S]``, packed above the count inside the program — no s64, no
+    column, no temporary the grid program has not."""
+    C, Tp = 768, 128
+    prog = fusedgrid.fused_program(fn, sumsq, WINDOW, IV, S, SB, C, Tp, G,
+                                   "raw", 0, 0, variant, 0, False, True)
+    args = _store_args(one_chip, S, C, Tp)
+    args.insert(3, jax.ShapeDtypeStruct((S,), i32, sharding=one_chip))
+    if variant == "xla":
+        with jax.enable_x64(False):
+            compiled = jax.jit(prog).lower(*args).compile()
+        assert "s64[" not in compiled.as_text()
+        assert compiled.memory_analysis().temp_size_in_bytes < S * C * 5
+        return
+    compiled = _compile(prog, args)
+    assert "s64[" not in compiled.as_text()
+    _no_column_and_no_temp(compiled, S)
+
+
+def test_the_fill_of_a_churned_store_runs_in_place(one_chip):
+    """``benchmark/data/churn/fill.py`` at the deployment's size: values and
+    stamps of every row from its birth to its end in donated elementwise
+    programs — the value block in place with no temporary to speak of, the
+    stamp block with the s64 split and nothing else (under 2 GB is asked of
+    every other program of this store; the split is 6 GB, the flush's
+    own)."""
+    import importlib
+    fill = importlib.import_module("benchmark.data.churn.fill")
+    fill_val, fill_ts, fill_n = fill.programs()
+    C = 768
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    with jax.enable_x64(True):
+        val = fill_val.lower(sds((S, C), f32), sds((S,), jnp.uint32),
+                             sds((S,), i32), sds((S,), i32),
+                             sds((), jnp.uint32)).compile()
+        ts = fill_ts.lower(sds((S, C), jnp.int64), sds((S,), i32),
+                           sds((S,), i32), sds((), jnp.int64)).compile()
+    mem = val.memory_analysis()
+    assert mem.alias_size_in_bytes >= S * C * 4
+    assert mem.temp_size_in_bytes < (2 << 30), mem
+    mem = ts.memory_analysis()
+    assert mem.alias_size_in_bytes >= S * C * 8
+    assert mem.temp_size_in_bytes < S * C * 8 + (1 << 30), mem
+
+
+def test_the_marks_before_a_birth_are_written_in_place(one_chip):
+    """``chunkstore._mark_unborn`` at 2^20 x 768, the one program a birth
+    adds to a flush: elementwise and donated, the value block in place."""
+    from filodb_tpu.core import chunkstore
+    C = 768
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    with jax.enable_x64(True):
+        val = chunkstore._mark_unborn.lower(
+            sds((S, C), f32), sds((S,), i32), 0, False).compile()
+    mem = val.memory_analysis()
+    assert mem.alias_size_in_bytes >= S * C * 4
+    assert mem.temp_size_in_bytes < (64 << 20), mem

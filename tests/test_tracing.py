@@ -1152,3 +1152,59 @@ def test_missed_scrapes_show_in_the_flush_the_select_and_the_dispatch(
         "line", int(missed > 0))
     store = srv.memstore.shards_of("prometheus")[0].store
     assert store.hole_cells == missed == store.stats.stale_markers
+
+
+def test_births_show_in_the_dispatch_the_select_and_slash_metrics(served):
+    """PR 49's tags, over HTTP: a store without a row born late runs the
+    grid program it ran before and says ``births`` 0; a series that appears
+    later is given a birth cell, the NEXT query's one dispatch says
+    ``births`` 1 and how many of its selected rows were born late, its
+    select span ``demoted`` 0 and why the memo did not serve it, and
+    ``/metrics`` counts the series given a birth cell."""
+    from filodb_tpu.core.record import RecordBuilder
+    from filodb_tpu.core.schemas import GAUGE
+    srv, get = served
+    tracer.drain()
+    get()
+    (sel,) = [s for s in _trace_of_last_query() if s.name == SPAN_QUERY_SELECT]
+    (disp,) = [s for s in _trace_of_last_query()
+               if s.name == SPAN_QUERY_KERNEL
+               and s.tags["phase"] == "dispatch"]
+    assert (disp.tags["stamps"], disp.tags["births"],
+            disp.tags["born_late"]) == ("grid", 0, 0)
+    # (sixteen series are a selection the memo does not keep, and says so)
+    assert sel.tags["demoted"] == 0 and sel.tags["memo_why"] == "narrow"
+    tracer.drain()
+    # scrapes 90..95 of every series and of three NEW ones, born at 92
+    b = RecordBuilder(GAUGE)
+    for t in range(N_SAMPLES, N_SAMPLES + 6):
+        for i in range(N_SERIES + 3):
+            if i >= N_SERIES and t < N_SAMPLES + 2:
+                continue
+            b.add({"_metric_": "m", "host": f"h{i}", "g": f"g{i % 4}"},
+                  BASE + t * 10_000, float(i + 3 * t))
+    srv.memstore.ingest("prometheus", 0, b.build())
+    srv.memstore.flush_all()
+    store = srv.memstore.shards_of("prometheus")[0].store
+    assert store.born_late == 3 and store.grid_cohorts() == ("uniform", 0)
+    assert store.born[N_SERIES:N_SERIES + 3].tolist() == [N_SAMPLES + 2] * 3
+    body = get(shift_ms=150_001)            # off the cached grid
+    assert body["stats"]["exec_path"].startswith("local-fused[")
+    spans = _trace_of_last_query()
+    (sel,) = [s for s in spans if s.name == SPAN_QUERY_SELECT]
+    disp = [s for s in spans if s.name == SPAN_QUERY_KERNEL
+            and s.tags["phase"] == "dispatch"]
+    assert len(disp) == 1                   # ONE program
+    assert (disp[0].tags["births"], disp[0].tags["born_late"]) == (1, 3)
+    assert sel.tags["demoted"] == 0
+    # the query ends before the newest birth: the select runs the index's
+    # time-masked pass and says so (sixteen series: still not kept)
+    tracer.drain()
+    get(shift_ms=-150_001)
+    (sel,) = [s for s in _trace_of_last_query() if s.name == SPAN_QUERY_SELECT]
+    assert (sel.tags["memo"], sel.tags["memo_why"]) == ("bypass", "time_mask")
+    url = f"http://127.0.0.1:{srv.http.port}/metrics"
+    with urllib.request.urlopen(url, timeout=30) as r:
+        lines = r.read().decode().splitlines()
+    assert 'filodb_store_births_total{aligned="true",shard="0"} 3' in lines
+    assert 'filodb_store_births_total{aligned="false",shard="0"} 0' in lines
